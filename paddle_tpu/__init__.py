@@ -17,6 +17,7 @@ Execution model: programs are symbolic op graphs compiled by whole-program
 ``core/executor.py``); parallelism is mesh sharding (see ``parallel/``).
 """
 
+from . import compile_cache  # noqa: F401
 from .core import framework
 from .core.framework import (  # noqa: F401
     Program, Variable, Parameter,
@@ -57,6 +58,8 @@ from . import analysis  # noqa: F401
 from . import dlpack  # noqa: F401
 from . import parallel  # noqa: F401
 from .version import __version__  # noqa: F401
+
+compile_cache.place()
 
 # convenience re-exports matching fluid's top level
 from .clip import set_gradient_clip  # noqa: F401

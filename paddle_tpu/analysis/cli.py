@@ -138,7 +138,7 @@ def analyze_zoo_model(builder, train=True, with_cost=False):
     return out
 
 
-# the 6 BASELINE model configs (BENCH_r05.json matrix); bert_dygraph is
+# the 6 BASELINE model configs (bench.py's matrix); bert_dygraph is
 # estimated on the static-equivalent program (same architecture — the
 # dygraph build has no Program IR to walk)
 BASELINE_CONFIGS = ("deepfm", "seq2048", "resnet50", "bert_dygraph",

@@ -13,7 +13,6 @@ have TPU meaning are mapped (reduce_strategy -> parameter sharding a la
 ZeRO), the rest are no-ops documented as subsumed by XLA.
 """
 
-import jax
 
 __all__ = ["CompiledProgram", "BuildStrategy", "ExecutionStrategy"]
 
@@ -121,12 +120,18 @@ class CompiledProgram:
         # analysis passes are subsumed by XLA; keep chainable API
         return self
 
-    def _resolve_mesh(self):
+    def _resolve_mesh(self, place):
+        """The mesh given, else a 1-D mesh over ``places``, else over
+        every device of the executor's ``place`` backend (a TPUPlace
+        executor never spreads over CPU devices)."""
         if self._mesh is not None:
             return self._mesh
         from jax.sharding import Mesh
         import numpy as np
-        devices = self._places or jax.devices()
+        from .executor import as_jax_devices
+
+        devices = (as_jax_devices(self._places) if self._places
+                   else place.jax_devices())
         axis = self._pp_axis or self._dp_axis or "dp"
         self._mesh = Mesh(np.array(devices), (axis,))
         return self._mesh

@@ -28,7 +28,7 @@ import numpy as np
 
 from . import framework
 from .framework import Variable
-from .op_registry import run_op, RNG_KEY, RNG0_KEY, ENV0_KEY
+from .op_registry import run_op, placed, RNG_KEY, RNG0_KEY, ENV0_KEY
 from ..obs import registry as obs_registry
 from ..obs import trace as obs_trace
 
@@ -43,34 +43,62 @@ __all__ = ["Executor", "Scope", "global_scope", "scope_guard",
 # ---------------------------------------------------------------------------
 
 class _Place:
+    backend = None  # None: JAX's default backend and default placement
+
     def __init__(self, device_id=0):
         self.device_id = device_id
 
     def __repr__(self):
         return "%s(%d)" % (type(self).__name__, self.device_id)
 
+    def jax_devices(self):
+        """Every device of this place's backend (the default backend for
+        XLAPlace / CUDAPlace). A place that names a backend never answers
+        with devices of another one: no such backend is an error."""
+        if self.backend is None:
+            return jax.devices()
+        try:
+            return jax.devices(self.backend)
+        except RuntimeError as e:
+            raise RuntimeError(
+                "%r: JAX finds no %s device in this process (%s)"
+                % (self, self.backend, e)) from None
+
     def jax_device(self):
-        devs = jax.devices(self.backend) if self.backend else jax.devices()
-        return devs[self.device_id % len(devs)]
+        devs = self.jax_devices()
+        if not 0 <= self.device_id < len(devs):
+            raise RuntimeError("%r: this process has %d such device(s)"
+                               % (self, len(devs)))
+        return devs[self.device_id]
+
+
+def as_jax_devices(places):
+    """jax devices of a list that may name them as fluid places."""
+    return [p.jax_device() if isinstance(p, _Place) else p for p in places]
 
 
 class XLAPlace(_Place):
     """The default accelerator place (TPU when available)."""
-    backend = None
 
 
 class TPUPlace(_Place):
+    """One TPU chip. Raises where JAX finds no TPU — an Executor on a
+    TPUPlace never runs on the CPU."""
     backend = "tpu"
+
+    def __init__(self, device_id=0):
+        super().__init__(device_id)
+        self.jax_device()
 
 
 class CPUPlace(_Place):
+    """The host CPU, also where a chip is attached."""
     backend = "cpu"
 
 
 class CUDAPlace(_Place):
     """API-compat alias: maps to the default accelerator (no CUDA on TPU
     builds; kept so reference scripts port without edits)."""
-    backend = None
 
 
 # ---------------------------------------------------------------------------
@@ -144,21 +172,15 @@ def _as_array(value, var=None):
     return arr
 
 
-def _make_rng_key(seed):
+def _make_rng_key(seed, platform):
     """Threaded PRNG key. On TPU the counter-based ``rbg`` generator is used
     by default: it maps onto the hardware RNG instruction and is far cheaper
     than threefry for the per-step dropout masks (threefry lowers to long
     scalar-ish bit-mix chains that steal MXU-adjacent cycles). Override with
     PADDLE_TPU_RNG=threefry for bit-exact parity with stock jax keys."""
-    import os
-
     choice = os.environ.get("PADDLE_TPU_RNG", "")
     if not choice:
-        try:
-            on_tpu = jax.devices()[0].platform == "tpu"
-        except Exception:
-            on_tpu = False
-        choice = "rbg" if on_tpu else "threefry"
+        choice = "rbg" if platform == "tpu" else "threefry"
     if choice == "threefry":
         return jax.random.PRNGKey(seed)
     return jax.random.key(seed, impl=choice)
@@ -197,10 +219,10 @@ def build_step_fn(program, fetch_names, persist_names, pp_cfg=None,
             produced.update(op.output_arg_names)
         persist_set &= produced
     amp = bool(getattr(program, "_amp_bf16", False))
-    # measured on-chip (NOTES_r3.md): per-param updates cost ~8us each in
-    # isolation — the profile's ~100us/update is scheduling stall, which
-    # concat-batching makes WORSE (796 dynamic-update-slices). Keep the
-    # batcher opt-in for experiments.
+    # per-param updates are cheap in isolation; what a profile shows per
+    # update is scheduling stall, which concat-batching makes WORSE (one
+    # dynamic-update-slice per operand). Keep the batcher opt-in for
+    # experiments.
     plan, skip = ({}, set())
     if fuse_opt and env_flag("PADDLE_TPU_FUSED_OPT"):
         plan, skip = plan_opt_fusion(ops)
@@ -264,6 +286,12 @@ def _xla_compiler_options():
 class Executor:
     def __init__(self, place=None):
         self.place = place if place is not None else XLAPlace(0)
+        # the device a backend-naming place pins the step to, resolved
+        # once so a backend JAX lacks raises HERE, not at the first run;
+        # None (XLAPlace/CUDAPlace) leaves placement to JAX, which follows
+        # committed arguments — how predictor clones pin themselves
+        self._device = (self.place.jax_device() if self.place.backend
+                        else None)
         self._cache = {}
         # program variants already verified -> strictness (1 = warn-mode,
         # 2 = raising). A warn-mode pass must NOT suppress a later strict
@@ -324,7 +352,7 @@ class Executor:
         if isinstance(program, CompiledProgram):
             from .compiler import BuildStrategy
 
-            mesh = program._resolve_mesh()
+            mesh = program._resolve_mesh(self.place)
             dp_axis = program._dp_axis
             sp_axis = program._sp_axis
             seq_feeds = program._seq_feeds
@@ -356,6 +384,14 @@ class Executor:
         fetch_list = fetch_list or []
         fetch_names = [v.name if isinstance(v, Variable) else str(v)
                        for v in fetch_list]
+        # where this step runs: over the CompiledProgram's mesh, else over
+        # the mesh_scope() block a plain Program with sharded ops is run
+        # inside of (they shard_map over it), else on the place's device
+        from ..parallel.mesh import scoped_mesh
+
+        span = mesh if mesh is not None else scoped_mesh()
+        placement = (span.devices.flat[0].platform if span is not None
+                     else self._platform(), span is not None)
 
         # normalize feed values
         feed_arrays = {}
@@ -373,7 +409,7 @@ class Executor:
             else:
                 import secrets
                 seed = secrets.randbits(31)
-            scope.set(RNG_KEY, _make_rng_key(seed))
+            scope.set(RNG_KEY, _make_rng_key(seed, placement[0]))
 
         persist_names = sorted({v.name for v in program.list_vars()
                                 if v.persistable})
@@ -418,7 +454,7 @@ class Executor:
             (n, a.shape, str(a.dtype)) for n, a in feed_arrays.items()))
         key = (id(program), program._version, feed_sig, tuple(fetch_names),
                state_in_names, id(scope), mesh, dp_axis, sp_axis, seq_feeds,
-               pp, zero_state, grad_scale, donate_state)
+               pp, zero_state, grad_scale, donate_state, placement)
         entry = self._cache.get(key) if use_program_cache else None
         if verify is None:
             mode = os.environ.get("PADDLE_TPU_VERIFY", "").strip().lower()
@@ -453,13 +489,41 @@ class Executor:
             entry = self._compile(program, tuple(sorted(feed_arrays)),
                                   fetch_names, state_in_names, persist_names,
                                   mesh, dp_axis, sp_axis, seq_feeds, pp,
-                                  zero_state, grad_scale, donate_state)
+                                  zero_state, grad_scale, donate_state,
+                                  placement)
             if use_program_cache:
                 self._cache[key] = entry
-        jfn = entry
+        jfn, in_shardings = entry
 
         state = {n: scope.get(n) for n in state_in_names}
         rng = scope.get(RNG_KEY)
+        if in_shardings is not None:
+            # a scope initialised by a one-device startup run holds arrays
+            # committed to that device: lay them out as the mesh step
+            # wants them (arrays already in place pass through untouched)
+            state, rng = jax.tree.map(
+                lambda a, sh: a if not isinstance(a, jax.Array)
+                or a.sharding == sh else jax.device_put(a, sh),
+                (state, rng), (in_shardings[0], in_shardings[2]))
+        elif span is not None:
+            # plain Program inside mesh_scope(): its shard_maps span the
+            # mesh, so arrays a one-device startup run committed elsewhere
+            # are replicated over it first
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            everywhere = NamedSharding(span, PartitionSpec())
+            state, rng = jax.tree.map(
+                lambda a: a if not isinstance(a, jax.Array)
+                or a.sharding.device_set == everywhere.device_set
+                else jax.device_put(a, everywhere), (state, rng))
+        elif self._device is not None:
+            # the place decides where the step runs: jit follows its
+            # committed arguments, so committing the feeds and the rng key
+            # puts the step — and with it every state array it writes
+            # back — on the place's device. State committed elsewhere is
+            # an error from jit, never a silent move.
+            feed_arrays = jax.device_put(feed_arrays, self._device)
+            rng = jax.device_put(rng, self._device)
         # abstract snapshot for lowered_hlo_text (state buffers are
         # donated below, so keep avals, not arrays)
         self._last_call = (jfn, jax.tree.map(
@@ -512,16 +576,28 @@ class Executor:
         self._mfu_cache[key] = roof
         return roof
 
-    def lowered_hlo_text(self):
+    def _platform(self):
+        """Platform an un-meshed step runs on: the place's device, else
+        (XLAPlace / CUDAPlace) JAX's default backend."""
+        if self._device is not None:
+            return self._device.platform
+        return jax.default_backend()
+
+    def lowered_hlo_text(self, optimized=True):
         """Optimized HLO text of the step this executor LAST ran —
         the compiled-module inspection surface for multi-chip sharding
         assertions (``parallel/sharding_check.py``; ref analog:
         ``multi_devices_graph_check_pass.cc`` asserting SSA-graph
-        structure). Re-lowers from cached avals; call after ``run``."""
+        structure). Re-lowers from cached avals; call after ``run``.
+        ``optimized=False`` stops before XLA compiles: the StableHLO the
+        step lowers to, enough to see which custom calls it carries and
+        a trace away instead of a whole compile."""
         if not getattr(self, "_last_call", None):
             raise RuntimeError("no prior run() to inspect")
         jfn, (state, feed_arrays, rng) = self._last_call
-        return jfn.lower(state, feed_arrays, rng).compile().as_text()
+        lowered = jfn.lower(state, feed_arrays, rng)
+        return lowered.compile().as_text() if optimized \
+            else lowered.as_text()
 
     def close(self):
         """Parity with ``Executor::Close`` (``executor.cc:139``): release the
@@ -560,7 +636,7 @@ class Executor:
             else:  # random_seed=0 = nondeterministic, same as run()
                 import secrets
                 seed = secrets.randbits(31)
-            scope.set(RNG_KEY, _make_rng_key(seed))
+            scope.set(RNG_KEY, _make_rng_key(seed, self._platform()))
         env[RNG_KEY] = scope.get(RNG_KEY)
         env[RNG0_KEY] = env[RNG_KEY]
         env[ENV0_KEY] = dict(env)
@@ -569,7 +645,9 @@ class Executor:
         try:
             for op in gb.ops:
                 before = {n: env.get(n) for n in op.output_arg_names}
-                run_op(env, op)
+                with placed(self._platform()), \
+                        jax.default_device(self._device):
+                    run_op(env, op)
                 for n in op.output_arg_names:
                     v = env.get(n)
                     if v is None or v is before.get(n):
@@ -716,9 +794,8 @@ class Executor:
         return in_shardings, out_shardings
 
     def _compile(self, program, feed_names, fetch_names, state_in_names,
-                 persist_names, mesh, dp_axis, sp_axis=None, seq_feeds=None,
-                 pp=None, zero_state=False, grad_scale=None,
-                 donate_state=True):
+                 persist_names, mesh, dp_axis, sp_axis, seq_feeds, pp,
+                 zero_state, grad_scale, donate_state, placement):
         pp_cfg = None
         if pp is not None:
             pp_axis, pp_boundaries, pp_nmicro = pp
@@ -727,17 +804,24 @@ class Executor:
                       "n_micro": pp_nmicro, "feed_names": list(feed_names)}
         # the infer_only narrowing only applies off-mesh: _mesh_shardings
         # sizes its out_shardings for the echoed state dict
-        step = build_step_fn(program, fetch_names, persist_names,
-                             pp_cfg=pp_cfg, fuse_opt=mesh is None,
-                             grad_scale=grad_scale,
-                             infer_only=not donate_state and mesh is None)
+        inner = build_step_fn(program, fetch_names, persist_names,
+                              pp_cfg=pp_cfg, fuse_opt=mesh is None,
+                              grad_scale=grad_scale,
+                              infer_only=not donate_state and mesh is None)
+
+        def step(state, feed, rng):
+            # trace-time: tells the Pallas gates where THIS step runs,
+            # whoever triggers the trace (run, lowered_hlo_text)
+            with placed(*placement):
+                return inner(state, feed, rng)
+
         donate = (0,) if donate_state else ()
         extra = _xla_compiler_options()
         if mesh is None:
-            return jax.jit(step, donate_argnums=donate, **extra)
+            return jax.jit(step, donate_argnums=donate, **extra), None
         in_shardings, out_shardings = self._mesh_shardings(
             program, feed_names, fetch_names, state_in_names, persist_names,
             mesh, dp_axis, sp_axis, seq_feeds, zero_state)
         return jax.jit(step, donate_argnums=donate,
                        in_shardings=in_shardings,
-                       out_shardings=out_shardings, **extra)
+                       out_shardings=out_shardings, **extra), in_shardings

@@ -10,6 +10,7 @@ op list is traced into a single XLA computation, so "kernel dispatch" and
 the reference's per-op kernel launch + ir fuse passes.
 """
 
+import contextlib
 import threading
 
 import jax
@@ -137,15 +138,55 @@ def env_flag(name):
         "1", "true", "yes", "on")
 
 
-def single_tpu():
-    """True when running on exactly one TPU device — the only config where
-    a Pallas custom call doesn't fight GSPMD (under a mesh it would force
-    gathers of sharded operands). Shared gate for the fused kernels."""
+# ---------------------------------------------------------------------------
+# Placement (trace-time state, like AMP below). The Pallas gates must know
+# where the computation being traced will RUN, which is not what
+# ``jax.devices()`` says about the process: a CPUPlace executor on a TPU host
+# runs on the CPU, a one-chip program on a four-chip host still has its chip
+# to itself, and a step compiled for a described (not attached) TPU topology
+# is a TPU step. The Executor sets this around every step it traces, from
+# its place and its mesh; outside an Executor (dygraph, a bare
+# ``build_step_fn`` + ``jax.jit``) the answer is JAX's default backend.
+# ---------------------------------------------------------------------------
+
+class _Placement(threading.local):
+    platform = None   # None: not placed by an Executor -> default backend
+    meshed = False    # True: the step is partitioned over a device mesh
+
+
+PLACEMENT = _Placement()
+
+
+@contextlib.contextmanager
+def placed(platform, meshed=False):
+    """Declare where the computation traced inside the block will run."""
+    prev = (PLACEMENT.platform, PLACEMENT.meshed)
+    PLACEMENT.platform, PLACEMENT.meshed = platform, bool(meshed)
     try:
-        dev = jax.devices()[0]
-    except Exception:
-        return False
-    return dev.platform == "tpu" and jax.device_count() == 1
+        yield
+    finally:
+        PLACEMENT.platform, PLACEMENT.meshed = prev
+
+
+def placed_platform():
+    """Platform name ('tpu' / 'cpu' / ...) the traced computation runs on."""
+    return PLACEMENT.platform or jax.default_backend()
+
+
+def single_tpu():
+    """True when the traced computation runs on ONE TPU device — the only
+    placement where a Pallas custom call doesn't fight GSPMD (under a mesh
+    it would force gathers of sharded operands). Shared gate for every
+    Pallas kernel family."""
+    return placed_platform() == "tpu" and not PLACEMENT.meshed
+
+
+def placement_reason():
+    """Human detail for a gate's 'platform' refusal."""
+    if PLACEMENT.meshed:
+        return ("the step is partitioned over a mesh (GSPMD would gather "
+                "the custom call's sharded operands)")
+    return "placed on %r, not a TPU" % placed_platform()
 
 
 def run_op(env, op):
@@ -176,14 +217,8 @@ def run_op(env, op):
         note = ("  [operator '%s' inputs: %s -> outputs: %s]"
                 % (op.type, ", ".join(shapes),
                    list(op.output_arg_names)))
-        if hasattr(e, "add_note"):  # py3.11+: keep type AND context
-            e.add_note(note)
-            raise
-        try:  # pre-3.11 fallback; multi-arg ctors can't be rebuilt
-            wrapped = type(e)(str(e) + "\n" + note)
-        except Exception:
-            wrapped = RuntimeError(str(e) + "\n" + note)
-        raise wrapped from e
+        e.add_note(note)  # keeps the exception's type AND the context
+        raise
     if cond_name is not None:
         # Switch-case guard: keep prior value where the case doesn't fire
         pred = env[cond_name].reshape(())

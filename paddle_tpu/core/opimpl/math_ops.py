@@ -153,10 +153,10 @@ def _prelu(env, op):
 @register("gelu")
 def _gelu(env, op):
     from ..op_registry import amp_enabled, env_flag
-    # tanh-approx under AMP (the standard TPU BERT choice): erf's
-    # polynomial lowering costs ~0.9 ms/layer at BERT-base shapes and its
-    # vjp chain re-fuses into dW matmul operands (NOTES_r4.md); exact erf
-    # stays the default for f32 runs and under PADDLE_TPU_AMP_F32_ACTS
+    # tanh-approx under AMP (the standard TPU BERT choice): erf lowers to
+    # a long polynomial and its vjp chain re-fuses into dW matmul
+    # operands; exact erf stays the default for f32 runs and under
+    # PADDLE_TPU_AMP_F32_ACTS
     approx = op.attr("approximate",
                      amp_enabled()
                      and not env_flag("PADDLE_TPU_AMP_F32_ACTS"))
@@ -391,7 +391,7 @@ def _cumsum(env, op):
 
 # index outputs are int32, not int64: without x64 mode jax truncates an
 # explicit int64 request to int32 anyway, emitting a UserWarning per trace
-# (the resnet50 bench tail in BENCH_r05.json) — request the real dtype
+# — request the real dtype
 @register("argmax")
 def _argmax(env, op):
     put(env, op.output("Out"),

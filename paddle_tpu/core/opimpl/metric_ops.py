@@ -14,8 +14,8 @@ from ..op_registry import register, get, put
 @register("accuracy")
 def _accuracy(env, op):
     # int32 on purpose: int64 is unavailable without x64 mode, and an
-    # explicit astype(int64) emits a truncation UserWarning on every bench
-    # step (BENCH_r05.json tail) while silently computing in int32 anyway
+    # explicit astype(int64) emits a truncation UserWarning on every
+    # trace while silently computing in int32 anyway
     pred_idx = get(env, op.input("Indices")).astype(jnp.int32)  # [N, k] ids
     label = get(env, op.input("Label")).astype(jnp.int32)
     if label.ndim == 1:

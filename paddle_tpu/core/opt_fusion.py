@@ -1,10 +1,10 @@
 """Batched ("foreach") optimizer updates.
 
 The reference runs one CUDA kernel per parameter update (``operators/
-optimizers/adam_op.h`` etc.); on TPU one *fusion* per parameter costs a
-fixed ~50-100us of dispatch/DMA setup, so a transformer-base's ~160 small
-updates burn ~25ms/step against ~2ms of actual HBM traffic (profiled,
-NOTES_r3.md). This pass batches all dense update ops of the same family and
+optimizers/adam_op.h`` etc.); on TPU one *fusion* per parameter pays a
+fixed dispatch/DMA setup, so a transformer-base's ~160 small updates cost
+far more than their HBM traffic. This pass batches all dense update ops of
+the same family and
 hyperparameters into ONE update over the ravel+concat of their operands,
 then splits the results back — pure trace-time rewriting, no Program or
 checkpoint-format change (parameters remain individual vars).

@@ -40,7 +40,38 @@ import subprocess
 import sys
 import time
 
-__all__ = ["launch", "reap_procs"]
+__all__ = ["launch", "reap_procs", "local_tpu_chips", "one_chip_env"]
+
+
+def local_tpu_chips():
+    """TPU chips attached to this host, counted from their device files.
+    A supervisor must not ask JAX: a chip belongs to one process at a
+    time, and a parent that initialises JAX takes the chips its children
+    need."""
+    import glob
+
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def one_chip_env(slot, n_procs, env):
+    """Environment overlay that gives local child ``slot`` of ``n_procs``
+    INDEPENDENT children its own chip, to be set before the child imports
+    JAX (libtpu reads it at start-up). Empty where there is nothing to
+    divide: a host without chips, children held to the CPU
+    (``JAX_PLATFORMS=cpu`` in ``env``), or one child (it may drive every
+    chip). More children than chips is refused: they would all reach for
+    the same chips and fail or hang."""
+    chips = local_tpu_chips()
+    if chips == 0 or n_procs <= 1 or env.get("JAX_PLATFORMS") == "cpu":
+        return {}
+    if n_procs > chips:
+        raise RuntimeError(
+            "%d processes need a TPU chip each, this host has %d"
+            % (n_procs, chips))
+    return {"TPU_VISIBLE_CHIPS": str(slot),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
 
 
 def reap_procs(procs, sig=signal.SIGTERM, grace_s=10.0):
@@ -126,6 +157,18 @@ def launch(argv=None):
     node_rank = ips.index(args.node_ip)
     local_ids = range(node_rank * args.nproc_per_node,
                       (node_rank + 1) * args.nproc_per_node)
+
+    if (args.nproc_per_node > 1 and local_tpu_chips()
+            and os.environ.get("JAX_PLATFORMS") != "cpu"):
+        # the workers form ONE jax.distributed world, which libtpu builds
+        # from whole hosts: N local processes would each reach for every
+        # chip of this host, and chips handed out one apiece
+        # (one_chip_env) are separate one-chip worlds with no collective
+        # between them
+        sys.exit("--nproc_per_node=%d on a TPU host: one process drives "
+                 "all %d local chips; launch one process per host "
+                 "(--cluster_node_ips), or set JAX_PLATFORMS=cpu for a "
+                 "CPU world" % (args.nproc_per_node, local_tpu_chips()))
 
     if args.log_dir:
         os.makedirs(args.log_dir, exist_ok=True)

@@ -35,18 +35,14 @@ import numpy as np
 _INTERPRET = False  # tests flip this to run kernels on CPU
 
 
-def _use_pallas(q):
+def _use_pallas():
     if _INTERPRET:
         return True
-    from ..core.op_registry import env_flag
+    from ..core.op_registry import env_flag, single_tpu
 
     if env_flag("PADDLE_TPU_NO_FLASH"):  # A/B escape hatch
         return False
-    try:
-        dev = jax.devices()[0]
-    except Exception:
-        return False
-    return dev.platform == "tpu"
+    return single_tpu()
 
 
 # ---------------------------------------------------------------------------
@@ -552,17 +548,15 @@ def _flash_bwd_impl(q, k, v, bias, seed, causal, scale, dropout_rate,
 #
 # The head-split streaming path below reshapes [B,T,H*D] -> [B*H,T,D] around
 # the custom calls, and XLA materializes those relayouts as real HBM copies
-# (~36 ms/step at the seq-2048 bench config — NOTES_r5.md; 7 copies per
-# attention site). These kernels keep the packed layout the projection
+# (7 per attention site). These kernels keep the packed layout the projection
 # matmuls produce END TO END: the grid stays (batch, block), each program
 # loops the heads over static lane slices (like the dense kernels), and the
 # online-softmax k-loop streams K/V blocks exactly as the head-split
 # kernels do. The price is VMEM: K/V (fwd) and q/do/dq-f32 (bwd) are
 # full-T refs of width H*D rather than D, which caps the single-chip
-# packed path near T~2-3k for transformer-base — precisely the bench
-# regime; longer contexts keep the head-split path (gate:
-# _packed_stream_fits; PADDLE_TPU_SPLIT_STREAM=1 forces the old path for
-# A/B).
+# packed path near T~1k for bf16 transformer-base; longer contexts keep
+# the head-split path (gate: _packed_stream_fits;
+# PADDLE_TPU_SPLIT_STREAM=1 forces the old path for A/B).
 
 def _packed_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref,
                        lse_ref, *, num_heads, block_k, causal, scale,
@@ -950,21 +944,23 @@ def _packed_stream_bwd(num_heads, causal, scale, dropout_rate, res, g):
 _packed_stream_attention.defvjp(_packed_stream_fwd, _packed_stream_bwd)
 
 _PACKED_STREAM = True  # module A/B switch (tests also flip it)
-# ~16 MB VMEM/core; the estimate below is conservative already (the
-# revisited dq and the constant-index q/do/K/V refs are NOT
-# double-buffered by Mosaic), so leave only headroom for transients.
-# bf16 seq-2048 transformer-base lands at ~12.8 MB — inside the gate by
-# design (that bench config is what this path exists for).
+# The chip's compiler gives one kernel 16 MiB of scoped VMEM and refuses
+# the whole program when a kernel asks for more. What a kernel asks for
+# also depends on the program around it: the bf16 T=2048 H*D=512 backward
+# was refused at 16.66M compiled alone and at 19.16M inside the seq-2048
+# train step. So 3 MiB stay free for what the surrounding step adds.
 _STREAM_VMEM_BUDGET = 13 * 1024 * 1024
 
 
 def _packed_stream_fits(t, t_k, hd, esize, num_heads, dropout=0.0):
-    """Conservative VMEM bound for the packed streaming kernels: the bwd
-    is the larger step — full-T q/do (+f32 dq accumulator) plus the
-    double-buffered K/V/dK/dV blocks and the stats rows. The bwd estimate
-    uses the geometry the backward will ACTUALLY allocate: the
-    PADDLE_TPU_FLASH_BLOCK_BWD override engages only when dropout is off
-    (fwd/bwd must share block geometry for mask regeneration), so the
+    """VMEM the packed streaming kernels allocate, against the budget.
+    Mosaic double-buffers every operand whose block index changes
+    anywhere in the grid — the full-T q/do (bwd) and K/V (fwd) blocks
+    change with the batch index, so they count twice like the streamed
+    blocks do; only the revisited f32 dq accumulator is held once. The
+    bwd estimate uses the geometry the backward will ACTUALLY allocate:
+    the PADDLE_TPU_FLASH_BLOCK_BWD override engages only when dropout is
+    off (fwd/bwd must share block geometry for mask regeneration), so the
     gate mirrors _packed_stream_bwd_impl's ``bwd=(dropout == 0.0)``."""
     block_q, block_k = _block_sizes(t, t_k)
     bq_b, bk_b = _block_sizes(t, t_k, bwd=(dropout == 0.0))
@@ -973,14 +969,18 @@ def _packed_stream_fits(t, t_k, hd, esize, num_heads, dropout=0.0):
     def pad(x, m):
         return ((x + m - 1) // m) * m
 
-    fwd = (2 * pad(t_k, block_k) * hd * esize   # K/V resident
-           + 4 * block_q * hd * esize           # q/o double-buffered
-           + nh_pad * pad(t, block_q) * 4)
+    tk_pad = pad(t_k, block_k)
+    fwd = (4 * tk_pad * hd * esize              # K/V, two buffers each
+           + 4 * block_q * hd * esize           # q/o blocks, two each
+           + 2 * nh_pad * pad(t, block_q) * 4   # lse out
+           + 2 * 8 * tk_pad * 4)                # key bias
     t_pad_b = pad(t, bq_b)
-    bwd = (2 * t_pad_b * hd * esize             # q/do resident
+    tk_pad_b = pad(t_k, bk_b)
+    bwd = (4 * t_pad_b * hd * esize             # q/do, two buffers each
            + t_pad_b * hd * 4                   # f32 dq accumulator
-           + 8 * bk_b * hd * esize              # k/v/dk/dv double-buffered
-           + 3 * nh_pad * t_pad_b * 4)
+           + 8 * bk_b * hd * esize              # k/v/dk/dv blocks, two each
+           + 4 * nh_pad * t_pad_b * 4           # lse/delta
+           + 4 * 8 * tk_pad_b * 4)              # key bias + its grad
     return max(fwd, bwd) <= _STREAM_VMEM_BUDGET
 
 
@@ -1024,8 +1024,7 @@ def _dense_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref,
 
     # several batch elements per grid step: at T<=512 one element is only
     # a few us of compute, so the per-step fixed cost (DMA issue, loop
-    # bookkeeping) dominates a G=1 grid (measured flat 5.5us/step
-    # regardless of in-kernel math, NOTES_r3.md)
+    # bookkeeping) dominates a G=1 grid
     for g in range(g_blk):
         mb = mask
         if bias_ref is not None:
@@ -1379,8 +1378,11 @@ def kernel_plan(q_shape, k_shape, num_heads, esize, causal=False,
     d = hd // max(num_heads, 1)
     reasons = []
     if not platform_ok:
+        from ..core.op_registry import placement_reason
+
         reasons.append(GateReason(
-            "platform", "not on TPU (or PADDLE_TPU_NO_FLASH=1)"))
+            "platform", "%s (or PADDLE_TPU_NO_FLASH=1)"
+            % placement_reason()))
     if bias_kind == "rich":
         reasons.append(GateReason(
             "bias", "non-key-mask bias shape: only the additive "
@@ -1447,7 +1449,7 @@ def plan_for(q, k, bias, num_heads, causal, dropout_rate, rng):
                        causal=causal, dropout_rate=float(dropout_rate),
                        bias_kind=bias_kind,
                        rng_available=rng is not None,
-                       platform_ok=_use_pallas(q))
+                       platform_ok=_use_pallas())
 
 
 def flash_attention(q, k, v, num_heads, bias=None, causal=False,
@@ -1499,7 +1501,7 @@ def flash_attention(q, k, v, num_heads, bias=None, causal=False,
     if plan.kernel == "packed_stream":
         # copy-free streaming path: the packed layout goes straight into
         # the kernels — no [B,T,H,D] head-split relayouts around the
-        # custom calls (the ~36 ms/step at the seq-2048 bench config)
+        # custom calls
         return _packed_stream_attention(q, k, v, key_bias, seed, num_heads,
                                         causal, scale, float(dropout_rate))
 

@@ -4,9 +4,8 @@ The reference pairs a [.., D] x [D, V] projection with
 ``softmax_with_cross_entropy_op.cc`` (+ ``label_smooth_op.cc``), which
 materializes the [.., V] logits (and a soft-label tensor) in memory. On TPU
 that tensor dominates the loss head: for transformer-base at batch 128 /
-seq 256 / V=30k the logits are 2 GB in bf16 (4 GB f32) and the profile
-shows ~25 ms/step of pure HBM traffic + layout copies around them
-(NOTES_r3.md).
+seq 256 / V=30k the logits are 2 GB in bf16 (4 GB f32), pure HBM traffic
+plus the layout copies around them.
 
 Here the projection and the CE reduction fuse into one Pallas kernel: the
 logits tile lives in VMEM, is consumed by an online (max, sumexp, sum,
@@ -328,14 +327,18 @@ def linear_smooth_ce(x, w, b, y, eps):
             and not env_flag("PADDLE_TPU_NO_BF16_CE")):  # A/B escape hatch
         return _bf16_ce(x2, w, b, y2, float(eps)).reshape(lead)
 
-    # reference path (CPU / mesh): plain projection + closed-form smooth CE
+    return ce_reference(x2, w, b, y2, eps).reshape(lead)
+
+
+def ce_reference(x2, w, b, y2, eps):
+    """The unfused path (CPU / mesh) and the kernels' numerics oracle:
+    plain projection to f32 [T, V] logits + closed-form smooth CE."""
     logits = jnp.dot(x2, w, preferred_element_type=jnp.float32)
     if b is not None:
         logits = logits + b.astype(jnp.float32)
-    v = w.shape[1]
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     logit_y = jnp.take_along_axis(logits, y2[:, None], axis=-1)[:, 0]
     loss = lse - (1.0 - eps) * logit_y
     if eps:
         loss = loss - eps * jnp.mean(logits, axis=-1)
-    return loss.reshape(lead)
+    return loss

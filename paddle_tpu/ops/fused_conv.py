@@ -108,14 +108,13 @@ def gate(x_shape, w_shape, strides, paddings, dilations, groups, esize,
                 "exceed the %.0f MB VMEM budget"
                 % (int(c), h * w, int(o), _VMEM_BUDGET / 2**20)))
     if not static_only and not reasons and not _INTERPRET:
-        from ..core.op_registry import env_flag, single_tpu
+        from ..core.op_registry import (env_flag, placement_reason,
+                                         single_tpu)
 
         if env_flag("PADDLE_TPU_NO_FUSED_CONV"):  # A/B escape hatch
             reasons.append(GateReason("env", "PADDLE_TPU_NO_FUSED_CONV=1"))
         elif not single_tpu():
-            reasons.append(GateReason(
-                "platform", "not a single TPU (a mesh would make the "
-                "custom call fight GSPMD)"))
+            reasons.append(GateReason("platform", placement_reason()))
     if reasons:
         return GateDecision(False, "unfused_replay",
                             fallback="pallas_fused_conv", reasons=reasons)

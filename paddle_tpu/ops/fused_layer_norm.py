@@ -2,9 +2,8 @@
 
 XLA lowers the composed layer_norm into ~5 HBM passes over the [T, D]
 activation per train step (fwd: stats read + normalize read; bwd: two
-row-reduction reads + apply read — profiled as the 52 ``f32[B,T]`` stat
-fusions + 66 ``multiply_reduce`` fusions on transformer-base,
-NOTES_r3.md). With the row block VMEM-resident, the fused kernels do ONE
+row-reduction reads + apply read). With the row block VMEM-resident, the
+fused kernels do ONE
 read + one write in each direction, plus in-kernel dgamma/dbeta
 accumulation across the sequential grid.
 

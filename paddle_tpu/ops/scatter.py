@@ -56,6 +56,11 @@ _INTERPRET = False  # tests flip this to run the kernel on CPU
 # at 14; whether Mosaic fits it is part of the pending measurement).
 _DEFAULT_VMEM_MB = 10
 _CHUNK = 1024  # (rows, vals) slots processed per grid step
+# The whole int32 row-id vector is the kernel's scalar-prefetch operand and
+# must sit in the chip's 1 MiB of SMEM beside Mosaic's own scalars; the
+# compiler refuses the program otherwise. _SMEM_IDS_BYTES is what the gate
+# lets the ids take.
+_SMEM_IDS_BYTES = 768 * 1024
 
 
 def _vmem_budget():
@@ -124,16 +129,21 @@ def gate(v, k, n, dtype, static_only=False):
                 "MB VMEM, budget is %.1f MB "
                 "(PADDLE_TPU_SCATTER_VMEM_MB raises it)"
                 % (v, k, need / 2**20, budget / 2**20)))
+        ids_bytes = 4 * _padded_ids(n)
+        if ids_bytes > _SMEM_IDS_BYTES:
+            reasons.append(GateReason(
+                "smem", "%d prefetched row ids need %.0f KiB of SMEM, the "
+                "kernel may take %.0f KiB"
+                % (n, ids_bytes / 1024, _SMEM_IDS_BYTES / 1024)))
     if not static_only and not reasons and not _INTERPRET:
-        from ..core.op_registry import env_flag, single_tpu
+        from ..core.op_registry import (env_flag, placement_reason,
+                                         single_tpu)
 
         if env_flag("PADDLE_TPU_NO_PALLAS_SCATTER"):  # A/B escape hatch
             reasons.append(GateReason(
                 "env", "PADDLE_TPU_NO_PALLAS_SCATTER=1"))
         elif not single_tpu():
-            reasons.append(GateReason(
-                "platform", "not a single TPU (a mesh would make the "
-                "custom call fight GSPMD)"))
+            reasons.append(GateReason("platform", placement_reason()))
     if reasons:
         return GateDecision(False, "xla_at_add", fallback="pallas_rowbin",
                             reasons=reasons)
@@ -191,13 +201,24 @@ def _scatter_kernel(rows_ref, vals_ref, tab_in_ref, out_ref, *, chunk, p, k,
     jax.lax.fori_loop(0, chunk, body, 0)
 
 
+def _chunk(n):
+    return min(_CHUNK, n) if n % _CHUNK else _CHUNK
+
+
+def _padded_ids(n):
+    """Length of the id vector the kernel prefetches: n rounded up to
+    whole grid steps."""
+    chunk = _chunk(n)
+    return -(-n // chunk) * chunk
+
+
 def _scatter_packed_call(bp, rows, vals, p, k, vp):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n = rows.shape[0]
-    chunk = min(_CHUNK, n) if n % _CHUNK else _CHUNK
-    n_pad = -(-n // chunk) * chunk
+    chunk = _chunk(n)
+    n_pad = _padded_ids(n)
     if n_pad != n:
         rows = jnp.concatenate(
             [rows, jnp.full((n_pad - n,), vp * p, jnp.int32)])

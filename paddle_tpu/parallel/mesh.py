@@ -14,9 +14,11 @@ import numpy as np
 import jax
 from jax.sharding import Mesh
 
-__all__ = ["make_mesh", "get_mesh", "set_mesh", "mesh_scope", "DistStrategy"]
+__all__ = ["make_mesh", "get_mesh", "set_mesh", "mesh_scope", "scoped_mesh",
+           "DistStrategy"]
 
 _current_mesh = None
+_scoped_mesh = None  # the mesh of the innermost open mesh_scope()
 
 
 def make_mesh(axes=None, devices=None):
@@ -47,16 +49,23 @@ def set_mesh(mesh):
     return mesh
 
 
+def scoped_mesh():
+    """The mesh of the ``mesh_scope`` block we are inside of, else None —
+    unlike :func:`get_mesh`, not what an earlier ``set_mesh`` (a
+    transpile) left behind for the rest of the process."""
+    return _scoped_mesh
+
+
 @contextlib.contextmanager
 def mesh_scope(mesh):
-    global _current_mesh
-    prev = _current_mesh
-    _current_mesh = mesh
+    global _current_mesh, _scoped_mesh
+    prev = (_current_mesh, _scoped_mesh)
+    _current_mesh = _scoped_mesh = mesh
     try:
         with mesh:
             yield mesh
     finally:
-        _current_mesh = prev
+        _current_mesh, _scoped_mesh = prev
 
 
 class DistStrategy:

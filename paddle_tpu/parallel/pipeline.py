@@ -38,8 +38,6 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, axis="pp"):
       x: [n_micro, mb, ...] microbatched input (replicated).
       Returns [n_micro, mb, ...] outputs after all stages.
     """
-    from jax.experimental.shard_map import shard_map
-
     n = mesh.shape[axis]
     n_micro = x.shape[0]
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -71,7 +69,7 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, axis="pp"):
 
     param_specs = jax.tree_util.tree_map(
         lambda a: P(axis, *([None] * (a.ndim - 1))), stacked_params)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),
@@ -175,8 +173,6 @@ def pipeline_program_loss(base_env, fwd_ops, loss_name, cfg, run_op,
     Per-microbatch losses are averaged (the data-parallel convention); ops
     with cross-batch statistics (batch_norm) see microbatch stats.
     """
-    from jax.experimental.shard_map import shard_map
-
     mesh = cfg["mesh"]
     axis = cfg["axis"]
     n_stages = mesh.shape[axis]
@@ -352,11 +348,11 @@ def pipeline_program_loss(base_env, fwd_ops, loss_name, cfg, run_op,
         env_specs = {k: P() for k in array_env}
         feed_specs = {k: P() for k in stacked_feeds}
         rng_spec = P()
-        loss = shard_map(
+        loss = jax.shard_map(
             device_body, mesh=mesh,
             in_specs=(env_specs, feed_specs, rng_spec),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(array_env, stacked_feeds, rng0)
         return loss, {loss_name: loss}
 

@@ -41,8 +41,6 @@ def ring_attention(q, k, v, mesh, axis="sp", causal=False, scale=None):
     Each of the N ring steps: attend to the currently-held K/V block, then
     ppermute K/V to the next neighbor. Causal masking uses global positions
     derived from the ring step."""
-    from jax.experimental.shard_map import shard_map
-
     n = mesh.shape[axis]
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
@@ -83,7 +81,7 @@ def ring_attention(q, k, v, mesh, axis="sp", causal=False, scale=None):
         out = acc / jnp.maximum(l_i, 1e-30)[..., None]
         return out.astype(q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(None, None, axis, None),) * 3,
         out_specs=P(None, None, axis, None),

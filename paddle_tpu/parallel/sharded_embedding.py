@@ -90,8 +90,6 @@ def comm_bytes_model(n_ids, width, n_shards, esize=4):
 def _psum_lookup(table, ids, mesh, axis):
     """Legacy formulation: each shard contributes the rows it owns, zeros
     elsewhere — one reduce over the axis, O(mp * n * D) total volume."""
-    from jax.experimental.shard_map import shard_map
-
     n_shards = mesh.shape[axis]
     v = table.shape[0]
     rows_per = v // n_shards
@@ -110,7 +108,7 @@ def _psum_lookup(table, ids, mesh, axis):
         rows = rows * mask[..., None].astype(rows.dtype)
         return jax.lax.psum(rows, axis)
 
-    return shard_map(
+    return jax.shard_map(
         local_lookup, mesh=mesh,
         in_specs=(P(axis, None), P()),
         out_specs=P(),
@@ -121,8 +119,6 @@ def _alltoall_lookup(table, ids, mesh, axis):
     """Id-routed formulation (see module docstring). ``ids`` arrives
     replicated (P()); each shard serves the slice it is responsible for
     and the output is re-replicated by one tiled all_gather."""
-    from jax.experimental.shard_map import shard_map
-
     m = mesh.shape[axis]
     v, d = table.shape
     rows_per = v // m
@@ -169,11 +165,11 @@ def _alltoall_lookup(table, ids, mesh, axis):
         out = jax.lax.all_gather(got, axis, axis=0, tiled=True)
         return out[:n]
 
-    return shard_map(
+    return jax.shard_map(
         routed, mesh=mesh,
         in_specs=(P(axis, None), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(table, ids)
 
 

@@ -78,9 +78,10 @@ def collect_allgather_shapes(hlo_text):
 
 _COLLECTIVE_PRIMS = ("psum", "all_to_all", "all_gather", "psum_scatter",
                      "ppermute", "all_gather_invariant")
-# shard_map's check_rep machinery rewrites psum to its rep-tracking
-# variant "psum2" in the jaxpr — report it under the canonical name
-_PRIM_ALIASES = {"psum2": "psum"}
+# under shard_map's varying-axes check a psum of a per-shard (varying)
+# value appears in the jaxpr as "psum_invariant" — report it under the
+# canonical name
+_PRIM_ALIASES = {"psum_invariant": "psum"}
 
 
 def collect_jaxpr_collectives(jaxpr):

@@ -84,7 +84,8 @@ class ServingEngine:
         ``ProgramPredictor`` or ``StableHLOPredictor``).
 
         Placement (ISSUE 14): ``placement="per_device"`` pins replicas
-        round-robin over ``devices`` (default ``jax.devices()``) via
+        round-robin over ``devices`` (jax devices or fluid places; default
+        ``jax.devices()``) via
         ``clone(device=...)`` — each replica's weights live on its own
         chip instead of all landing on device 0. ``mp=k`` serves a
         tensor-parallel predictor sharded over a k-device ``("mp",)``
@@ -317,7 +318,12 @@ class ServingEngine:
             return [model]
         import jax
 
-        devices = list(devices) if devices is not None else jax.devices()
+        # fluid places name their device; none given = JAX's default
+        # backend (a TPUPlace never resolves to a CPU device)
+        from ..core.executor import as_jax_devices
+
+        devices = (as_jax_devices(devices) if devices is not None
+                   else jax.devices())
         if mp > 1:
             import numpy as np
             from jax.sharding import Mesh

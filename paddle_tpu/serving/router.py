@@ -72,7 +72,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
-from ..distributed.launch import reap_procs
+from ..distributed.launch import one_chip_env, reap_procs
 from ..obs import flight, trace
 from ..reliability import faults
 from ..reliability.policy import CircuitBreaker, Deadline, RetryError, \
@@ -230,18 +230,21 @@ class Router:
                 "--model", str(self.model), "--host", self.host,
                 "--port", "0", *self.worker_args]
 
-    def _spawn_env(self):
+    def _spawn_env(self, index):
         env = dict(os.environ)
         env["PYTHONPATH"] = _REPO_ROOT + os.pathsep \
             + env.get("PYTHONPATH", "")
         env.update(self.worker_env)
+        # on a TPU host every worker gets its own chip before it imports
+        # JAX; more workers than chips is refused here, not left to hang
+        env.update(one_chip_env(index, self.num_workers, env))
         return env
 
     def _spawn_worker(self, w):
         """Start one worker process and block until its READY line (or
         raise). Called at start() and from the respawn path."""
         proc = subprocess.Popen(
-            self._spawn_cmd(), env=self._spawn_env(),
+            self._spawn_cmd(), env=self._spawn_env(w.index),
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
         deadline = time.monotonic() + self.spawn_timeout_s
         address = None

@@ -312,13 +312,17 @@ def test_seq2048_record_carries_stream_config(monkeypatch):
     monkeypatch.delenv("PADDLE_TPU_FLASH_BLOCK", raising=False)
     monkeypatch.delenv("PADDLE_TPU_SPLIT_STREAM", raising=False)
     rec = bench._bench_static("transformer", on_tpu=False,
-                              seq_override=2048)
+                              seq_override=1024)
     cfg = rec["config"]
     assert cfg["flash_block"] == 512
-    assert cfg["packed_stream"] is True  # bf16 seq-2048 fits the gate
+    assert cfg["packed_stream"] is True  # bf16 seq-1024 fits the gate
+    # seq-2048 does not: its packed backward is past the chip's VMEM
+    rec = bench._bench_static("transformer", on_tpu=False,
+                              seq_override=2048)
+    assert rec["config"]["packed_stream"] is False
     monkeypatch.setenv("PADDLE_TPU_SPLIT_STREAM", "1")
     rec2 = bench._bench_static("transformer", on_tpu=False,
-                               seq_override=2048)
+                               seq_override=1024)
     assert rec2["config"]["packed_stream"] is False
 
 

@@ -427,11 +427,18 @@ def test_fused_conv_vmem_refusal_is_a_finding():
 def test_flash_kernel_plan_gates():
     from paddle_tpu.ops import flash_attention as fa
 
-    # the seq-2048 bench shape (bf16): the copy-free packed path
-    plan = fa.kernel_plan((16, 2048, 512), (16, 2048, 512), 8, 2,
+    # bf16 transformer-base at T=1024: the copy-free packed path
+    plan = fa.kernel_plan((32, 1024, 512), (32, 1024, 512), 8, 2,
                           causal=False, dropout_rate=0.1,
                           platform_ok=True)
     assert plan.kernel == "packed_stream" and plan.admitted
+    # the seq-2048 bench shape: its packed backward is past the chip's
+    # scoped VMEM, so it streams head-split and says why
+    plan = fa.kernel_plan((16, 2048, 512), (16, 2048, 512), 8, 2,
+                          causal=False, dropout_rate=0.1,
+                          platform_ok=True)
+    assert plan.kernel == "head_split_stream" and plan.admitted
+    assert plan.blocked_only_by("vmem")
     # f32 at a much longer context: falls back to head-split + copies,
     # and says the VMEM budget is why
     plan2 = fa.kernel_plan((16, 16384, 1024), (16, 16384, 1024), 8, 4,

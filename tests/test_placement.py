@@ -1,0 +1,234 @@
+"""Where a computation runs is decided by its place and its mesh, never
+guessed from ``jax.devices()``: places, the trace-time placement the Pallas
+gates read, one process per chip, the compile cache's directory, and the
+entry points that refuse to run without a TPU."""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu.core import op_registry
+from paddle_tpu.distributed import launch
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(args, **env):
+    """Run a repo entry point in a child held to the CPU (unless ``env``
+    says otherwise)."""
+    full_env = {**os.environ, "JAX_PLATFORMS": "cpu", **env}
+    return subprocess.run([sys.executable, *args], cwd=_REPO, env=full_env,
+                          capture_output=True, text=True, timeout=300)
+
+
+# -- places -----------------------------------------------------------------
+
+def test_tpu_place_raises_without_tpu():
+    with pytest.raises(RuntimeError, match="no tpu device"):
+        fluid.TPUPlace(0)
+
+
+@pytest.mark.parametrize("place,device", [
+    (fluid.CPUPlace(), jax.devices("cpu")[0]),
+    (fluid.CPUPlace(3), jax.devices("cpu")[3]),
+    (fluid.XLAPlace(0), jax.devices()[0]),
+    (fluid.CUDAPlace(0), jax.devices()[0]),
+])
+def test_place_names_its_device(place, device):
+    assert place.jax_device() == device
+
+
+def test_place_out_of_range_raises():
+    with pytest.raises(RuntimeError, match="8 such device"):
+        fluid.CPUPlace(8).jax_device()
+
+
+def test_executor_state_lives_on_its_place():
+    """The scope's arrays land on the place's device, not on device 0."""
+    x = fluid.layers.data("x", shape=[4])
+    loss = fluid.layers.mean(fluid.layers.fc(x, size=3))
+    fluid.optimizer.SGD(0.1).minimize(loss)
+    exe = fluid.Executor(fluid.CPUPlace(2))
+    exe.run(fluid.default_startup_program())
+    exe.run(feed={"x": np.ones((2, 4), "f4")}, fetch_list=[loss])
+    scope = fluid.global_scope()
+    want = {jax.devices("cpu")[2]}
+    for p in fluid.default_main_program().global_block().all_parameters():
+        assert scope.get(p.name).devices() == want, p.name
+
+
+def test_serving_engine_devices_take_places(tmp_path):
+    """``ServingEngine(devices=[places])`` pins each replica where its
+    place says, not on the default device."""
+    from paddle_tpu.serving import ServingEngine
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data("x", shape=[4])
+        prob = fluid.layers.softmax(fluid.layers.fc(
+            x, size=3, param_attr=fluid.ParamAttr(name="pl_fc.w")))
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        model_dir = str(tmp_path / "model")
+        fluid.io.save_inference_model(model_dir, ["x"], [prob], exe,
+                                      main_program=main)
+    eng = ServingEngine(model_dir, num_replicas=2, ladder=(1, 2),
+                        placement="per_device",
+                        devices=[fluid.CPUPlace(5), fluid.CPUPlace(6)])
+    try:
+        eng.predict({"x": np.ones((1, 4), "f4")}, timeout_s=60.0)
+        devs = [next(iter(w.predictor._scope.get("pl_fc.w").devices()))
+                for w in eng._workers]
+    finally:
+        eng.shutdown()
+    assert devs == [jax.devices("cpu")[5], jax.devices("cpu")[6]]
+
+
+# -- the placement the gates read -------------------------------------------
+
+def test_gates_follow_the_declared_placement():
+    """One TPU chip gets its kernels whatever the host holds; a meshed
+    step and a CPU step do not, and the plan says why."""
+    from paddle_tpu.ops import flash_attention as fa
+    from paddle_tpu.ops import fused_conv, scatter
+
+    assert not op_registry.single_tpu()  # this process: CPU
+    with op_registry.placed("tpu"):
+        assert op_registry.single_tpu()
+        assert fa._use_pallas()
+        assert scatter.gate(1000, 16, 1024, "float32").admitted
+    with op_registry.placed("tpu", meshed=True):
+        assert not op_registry.single_tpu()
+        plan = fa.kernel_plan((2, 128, 64), (2, 128, 64), 4, 2,
+                              platform_ok=fa._use_pallas())
+        assert plan.kernel == "reference"
+        assert "mesh" in plan.reasons[0].detail
+        decision = fused_conv.gate((2, 8, 8, 8), (8, 8, 1, 1), (1, 1),
+                                   (0, 0), (1, 1), 1, 2, False)
+        assert decision.blocked_only_by("platform")
+    assert op_registry.placed_platform() == "cpu"  # restored
+
+
+def test_executor_declares_placement_while_tracing():
+    """The step traced by a CPUPlace executor sees 'cpu, not meshed'; the
+    same program under with_data_parallel sees 'meshed'."""
+    seen = []
+
+    @op_registry.register("_probe_placement")
+    def _probe(env, op):
+        seen.append((op_registry.placed_platform(),
+                     op_registry.PLACEMENT.meshed))
+        op_registry.put(env, op.output("Out"),
+                        op_registry.get(env, op.input("X")))
+
+    try:
+        x = fluid.layers.data("x", shape=[4])
+        block = fluid.default_main_program().global_block()
+        out = block.create_var(name="probe_out", shape=[-1, 4],
+                               dtype="float32")
+        block.append_op("_probe_placement", {"X": x}, {"Out": out}, {})
+        exe = fluid.Executor(fluid.CPUPlace())
+        feed = {"x": np.ones((8, 4), "f4")}
+        exe.run(feed=feed, fetch_list=[out])
+        compiled = fluid.CompiledProgram(
+            fluid.default_main_program()).with_data_parallel()
+        exe.run(compiled, feed=feed, fetch_list=[out])
+    finally:
+        del op_registry.OP_IMPLS["_probe_placement"]
+    assert seen == [("cpu", False), ("cpu", True)]
+
+
+# -- one process for each chip ----------------------------------------------
+
+@pytest.mark.parametrize("chips,n_procs,env,want", [
+    (0, 4, {}, {}),                              # no chips: nothing to do
+    (4, 1, {}, {}),                              # one child drives them all
+    (4, 4, {"JAX_PLATFORMS": "cpu"}, {}),        # children held to the CPU
+    (4, 2, {}, {"TPU_VISIBLE_CHIPS": "1",
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_BOUNDS": "1,1,1"}),
+])
+def test_one_chip_env(monkeypatch, chips, n_procs, env, want):
+    monkeypatch.setattr(launch, "local_tpu_chips", lambda: chips)
+    assert launch.one_chip_env(1, n_procs, env) == want
+
+
+def test_one_chip_env_refuses_more_children_than_chips(monkeypatch):
+    monkeypatch.setattr(launch, "local_tpu_chips", lambda: 1)
+    with pytest.raises(RuntimeError, match="this host has 1"):
+        launch.one_chip_env(0, 2, {})
+
+
+def test_launch_refuses_many_local_processes_on_a_tpu_host(monkeypatch):
+    monkeypatch.setattr(launch, "local_tpu_chips", lambda: 4)
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(SystemExit, match="one process drives all 4"):
+        launch.launch(["--nproc_per_node=2", "train.py"])
+
+
+# -- the compile cache is placed from outside -------------------------------
+
+@pytest.mark.parametrize("env,want", [
+    ({"JAX_PLATFORMS": ""}, os.path.join(_REPO, ".jax_cache")),
+    ({"JAX_PLATFORMS": "", "JAX_COMPILATION_CACHE_DIR": "/tmp/some/cache"},
+     "/tmp/some/cache"),
+    ({"JAX_PLATFORMS": "cpu"}, "None"),  # a CPU-held process: no default
+], ids=["default", "placed_from_outside", "cpu_held"])
+def test_compile_cache_directory(env, want):
+    # importing the package initialises no backend, so the child needs no
+    # device for the platforms it is (not) held to
+    out = _run(["-c", "import paddle_tpu.compile_cache as c; "
+                      "print(c.directory())"], **env)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip().splitlines()[-1] == want
+
+
+# -- entry points that need the chip say so ---------------------------------
+
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    out = _run(["chip_smoke.py"])
+    assert out.returncode not in (0, None)
+    assert out.stdout == ""  # no result line that could be read as a run
+    assert '"ok": false' in out.stderr
+
+
+def test_bench_refuses_to_run_without_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("BENCH_FORCE_CPU", None)
+    out = subprocess.run([sys.executable, "bench.py", "--model", "deepfm"],
+                         cwd=_REPO, env=env, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode != 0 and out.stdout == ""
+    assert "no TPU" in out.stderr
+
+
+def test_bench_cpu_smoke_record_names_its_platform():
+    out = _run(["bench.py", "--model", "deepfm"], BENCH_FORCE_CPU="1",
+               BENCH_STEPS="1")
+    assert out.returncode == 0, out.stderr[-2000:]
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    assert (rec["platform"], rec["device_kind"]) == ("cpu", "cpu")
+    assert rec["device_count"] >= 1
+    # no utilization against an invented peak
+    assert rec["vs_baseline"] is None and rec["config"]["peak_flops"] is None
+
+
+def test_peak_flops_raises_on_unknown_device_kind():
+    import bench
+
+    class Device:
+        platform = "tpu"
+        device_kind = "TPU v9 imaginary"
+
+    with pytest.raises(ValueError, match="no published peak"):
+        bench._peak_flops(Device())
+    with pytest.raises(ValueError, match="no published peak"):
+        bench._peak_flops(jax.devices("cpu")[0])
+    Device.device_kind = "TPU v5 lite"
+    assert bench._peak_flops(Device()) == 197e12

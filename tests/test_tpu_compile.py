@@ -1,0 +1,382 @@
+"""Ask the chip's compiler, without the chip.
+
+libtpu compiles for a TPU that is described and not attached, so every
+default-on Pallas kernel family is lowered here at the shape its BASELINE
+config runs and compiled for one chip of a ``v5e:2x2`` host. That catches
+what interpret mode cannot: a kernel that asks for more scoped VMEM or SMEM
+than the chip has, a slice Mosaic cannot tile, a program that does not fit
+HBM. A compile is not a run — numerics and time on the chip are
+``chip_smoke.py``'s business.
+
+The gates are steered from here with ``op_registry.placed("tpu")`` (code
+that asks JAX for its devices still sees the CPU). The whole train step of
+every BASELINE config is compiled the same way in the ``slow`` cases.
+Describing the topology takes libtpu's process lock: one such process at a
+time.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from paddle_tpu.core.op_registry import placed  # noqa: E402
+from paddle_tpu.ops import flash_attention as fa  # noqa: E402
+from paddle_tpu.ops import fused_ce, fused_conv, fused_layer_norm  # noqa: E402
+from paddle_tpu.ops import scatter  # noqa: E402
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topology():
+    """A described (not attached) four-chip v5e host. (conftest.py keeps
+    the persistent compile cache off: an entry written by a compile for
+    it cannot be read back without a chip.)"""
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip("libtpu cannot describe a v5e here: %s" % e)
+
+
+@pytest.fixture(scope="module")
+def chip(topology):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topology.devices[0])
+
+
+def _compile(chip, fn, *avals, **jit_kw):
+    """Compile ``fn`` for the described chip; returns the compiled object."""
+    def on(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+
+    with placed("tpu"):
+        lowered = jax.jit(fn, **jit_kw).lower(*jax.tree.map(on, avals))
+    return lowered.compile()
+
+
+def _kernel_calls(compiled):
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# ---------------------------------------------------------------------------
+# flash attention: fwd + bwd with in-kernel dropout
+# ---------------------------------------------------------------------------
+
+_FLASH_CASES = [
+    # id, (B, T, H*D, heads), causal, key bias, expected plan
+    ("bert_b128_t128", (128, 128, 768, 12), False, True, "dense_vmem"),
+    ("transformer_b128_t256_enc", (128, 256, 512, 8), False, True,
+     "dense_vmem"),
+    ("transformer_b128_t256_dec", (128, 256, 512, 8), True, False,
+     "dense_vmem"),
+    ("stream_b32_t1024", (32, 1024, 512, 8), False, True, "packed_stream"),
+    ("seq2048_b16_enc", (16, 2048, 512, 8), False, True,
+     "head_split_stream"),
+    ("seq2048_b16_dec", (16, 2048, 512, 8), True, False,
+     "head_split_stream"),
+]
+
+
+@pytest.mark.parametrize("shape,causal,with_bias,plan",
+                         [c[1:] for c in _FLASH_CASES],
+                         ids=[c[0] for c in _FLASH_CASES])
+def test_flash_attention_compiles(chip, shape, causal, with_bias, plan):
+    b, t, hd, heads = shape
+    bias = sds((b, t), F32) if with_bias else None
+    with placed("tpu"):
+        decision = fa.kernel_plan((b, t, hd), (b, t, hd), heads, 2,
+                                  causal=causal, dropout_rate=0.1,
+                                  bias_kind="key" if with_bias else None)
+    assert decision.kernel == plan, decision
+
+    def loss(q, k, v, bias, g, key_data):
+        out = fa.flash_attention(q, k, v, heads, bias=bias, causal=causal,
+                                 dropout_rate=0.1,
+                                 rng=jax.random.wrap_key_data(key_data))
+        return jnp.sum(out.astype(F32) * g.astype(F32))
+
+    x = sds((b, t, hd), BF16)
+    compiled = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
+                        x, x, x, bias, x, sds((2,), jnp.uint32))
+    assert _kernel_calls(compiled) == 2  # one forward, one fused backward
+
+
+def test_packed_stream_gate_counts_what_mosaic_allocates():
+    """The packed backward at the seq-2048 bench shape is what the chip's
+    compiler refused (16.66M of 16M scoped VMEM alone, 19.16M inside the
+    step): the gate refuses it too, and admits T=1024 (compiled above)."""
+    assert not fa._packed_stream_fits(2048, 2048, 512, 2, 8, dropout=0.1)
+    assert fa._packed_stream_fits(1024, 1024, 512, 2, 8, dropout=0.1)
+
+
+# ---------------------------------------------------------------------------
+# fused CE, fused LN
+# ---------------------------------------------------------------------------
+
+def test_fused_ce_compiles(chip):
+    """Batch 256 x seq 256 of transformer-base: 1.97e9 logits, past the
+    1.5e9 threshold where the fused path engages."""
+    t, d, v = 65536, 512, 30000
+    assert t * v >= fused_ce._FUSED_MIN_LOGITS
+
+    def loss(x, w, b, y):
+        return jnp.sum(fused_ce._fused(x, w, b, y, 0.1))
+
+    compiled = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
+                        sds((t, d), BF16), sds((d, v), BF16),
+                        sds((v,), BF16), sds((t,), I32))
+    assert _kernel_calls(compiled) >= 1  # fwd kernel; bwd is an XLA scan
+
+
+def test_fused_layer_norm_compiles(chip):
+    t, d = 128 * 256, 512
+
+    def loss(x, g, b):
+        y, _, _ = fused_layer_norm._fused_ln(x, g, b, 1e-5)
+        return jnp.sum(y.astype(F32))
+
+    compiled = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
+                        sds((t, d), BF16), sds((d,), F32), sds((d,), F32))
+    assert _kernel_calls(compiled) == 2
+
+
+# ---------------------------------------------------------------------------
+# fused conv + BN + ReLU at ResNet-50 batch-128 bottleneck geometries
+# ---------------------------------------------------------------------------
+
+_CONV_CASES = [
+    # id, C_in, C_out, kernel, stride, H=W (input), residual
+    ("c2_1x1_64to256_res", 64, 256, 1, 1, 56, True),
+    ("c2_3x3_64", 64, 64, 3, 1, 56, False),
+    ("c3_1x1_s2_256to512", 256, 512, 1, 2, 56, False),
+    ("c4_3x3_256", 256, 256, 3, 1, 14, False),
+    ("c5_1x1_512to2048_res", 512, 2048, 1, 1, 7, True),
+]
+
+
+@pytest.mark.parametrize("c,o,ksize,stride,hw,with_res",
+                         [c[1:] for c in _CONV_CASES],
+                         ids=[c[0] for c in _CONV_CASES])
+def test_fused_conv_compiles(chip, c, o, ksize, stride, hw, with_res):
+    n = 128
+    pad = (ksize - 1) // 2
+    out_hw = hw // stride
+    x_shape, w_shape = (n, c, hw, hw), (o, c, ksize, ksize)
+    with placed("tpu"):
+        decision = fused_conv.gate(x_shape, w_shape, (stride, stride),
+                                   (pad, pad), (1, 1), 1, 2, with_res)
+    assert decision.admitted, decision
+
+    def loss(x, w, gamma, beta, mean, var, res):
+        y = fused_conv.fused_conv_bn_act(
+            x, w, gamma, beta, mean, var, strides=(stride, stride),
+            paddings=(pad, pad), eps=1e-5, momentum=0.9, act="relu",
+            residual=res)[0]
+        return jnp.sum(y.astype(F32))
+
+    ch = sds((o,), F32)
+    res = sds((n, o, out_hw, out_hw), BF16) if with_res else None
+    compiled = _compile(chip, jax.value_and_grad(loss, argnums=(0, 1, 2, 3)),
+                        sds(x_shape, BF16), sds(w_shape, BF16), ch, ch, ch,
+                        ch, res)
+    assert _kernel_calls(compiled) == 2  # conv+moments, apply
+
+
+# ---------------------------------------------------------------------------
+# scatter: no shape the gate admits is refused by the compiler
+# ---------------------------------------------------------------------------
+
+def test_scatter_compiles_at_largest_admitted_shape(chip):
+    from chip_smoke import largest_scatter_table
+
+    k = 32
+    v = largest_scatter_table(k)  # the shape chip_smoke.py runs
+    n = scatter._SMEM_IDS_BYTES // 4  # the id bound, exactly
+    with placed("tpu"):
+        decision = scatter.gate(v, k, n, "float32")
+    assert decision.admitted and decision.kernel == "pallas_rowbin", decision
+    compiled = _compile(chip, scatter.scatter_add_rows,
+                        sds((v, k), F32), sds((n,), I32), sds((n, k), F32))
+    assert _kernel_calls(compiled) == 1
+
+
+@pytest.mark.parametrize("n", [scatter._SMEM_IDS_BYTES // 4 + 1,
+                               32768 * 26],
+                         ids=["one_past_bound", "deepfm_bench_ids"])
+def test_scatter_gate_bounds_prefetched_ids(n):
+    """Past the SMEM bound the plan falls to the XLA scatter with an
+    ``smem`` reason. 851,968 ids is what DeepFM's bench batch sends, and
+    the compiler refused it: 3.4 MB of ids into 1 MiB of SMEM."""
+    with placed("tpu"):
+        decision = scatter.gate(50000, 32, n, "float32")
+    assert not decision.admitted and decision.kernel == "xla_at_add"
+    assert decision.blocked_only_by("smem"), decision
+
+
+# ---------------------------------------------------------------------------
+# whole train steps (slow): every BASELINE config through build_step_fn
+# ---------------------------------------------------------------------------
+
+def _abstract_step(build):
+    """A training program built the way ``bench._bench_static`` builds one
+    (``build()`` returns the model spec and the batch; Adam runs under
+    ``fluid.amp.decorate``), as (abstract (state, feed, rng), program,
+    loss name, persistable names) — no array is ever made."""
+    import paddle_tpu as fluid
+    from paddle_tpu.core.executor import build_step_fn
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        spec, batch = build()
+        fluid.amp.decorate(
+            fluid.optimizer.Adam(learning_rate=1e-4)).minimize(spec.loss)
+    rng = jax.eval_shape(lambda: jax.random.key(0, impl="rbg"))
+    init_names = sorted({v.name for v in startup.list_vars()
+                         if v.persistable})
+    with placed("tpu"):
+        _, state, _ = jax.eval_shape(
+            build_step_fn(startup, (), init_names), {}, {}, rng)
+    persist = sorted({v.name for v in main.list_vars() if v.persistable})
+    state = {n: state[n] for n in persist if n in state}
+    gb = main.global_block()
+    feed = {}
+    for name, value in spec.sample_batch(2, np.random.RandomState(0)).items():
+        value = np.asarray(value)
+        dtype = gb.var(name).dtype if gb.has_var(name) else value.dtype
+        feed[name] = sds((batch,) + value.shape[1:],
+                         jax.dtypes.canonicalize_dtype(np.dtype(dtype)))
+    return (state, feed, rng), main, spec.loss.name, persist
+
+
+_HBM_BYTES = 16 * 1024 ** 3
+
+_STEP_CASES = [
+    # id, model, seq override, has Pallas kernels, attention plan
+    ("transformer_b128_s256", "transformer", None, True, "dense_vmem"),
+    ("bert_b128_s128", "bert", None, True, "dense_vmem"),
+    ("resnet50_b128", "resnet50", None, True, None),
+    # the [100000, 32] fused table is over the scatter kernel's VMEM
+    # budget and its 851,968 ids over the SMEM bound: XLA scatter
+    ("deepfm_b32768", "deepfm", None, False, None),
+    ("transformer_b16_s2048", "transformer", 2048, True,
+     "head_split_stream[vmem]"),
+]
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("model,seq,has_kernels,attn_plan",
+                         [c[1:] for c in _STEP_CASES],
+                         ids=[c[0] for c in _STEP_CASES])
+def test_whole_train_step_compiles(chip, model, seq, has_kernels, attn_plan):
+    import bench
+    from paddle_tpu.core.executor import build_step_fn
+
+    avals, program, loss, persist = _abstract_step(
+        lambda: bench._build(model, True, seq)[:2])
+    step = build_step_fn(program, (loss,), persist)
+    compiled = _compile(chip, step, *avals, donate_argnums=(0,))
+    mem = compiled.memory_analysis()
+    need = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    print("%s seq=%s: %d tpu_custom_call, %.2f GB temporaries + %.2f GB "
+          "arguments" % (model, seq, _kernel_calls(compiled),
+                         mem.temp_size_in_bytes / 1e9,
+                         mem.argument_size_in_bytes / 1e9))
+    assert need < _HBM_BYTES
+    assert (_kernel_calls(compiled) > 0) == has_kernels
+    if attn_plan is not None:
+        from chip_smoke import kernel_plans
+
+        plans = kernel_plans(program)["flash_attention"]
+        assert set(plans) == {attn_plan}, plans
+
+
+@pytest.mark.slow
+def test_bert_dygraph_train_step_compiles(chip):
+    """BASELINE config 4: BERT-base through the dygraph build, the jitted
+    functional train step ``bench._bench_bert_dygraph`` times."""
+    import paddle_tpu as fluid
+    from paddle_tpu.models import bert_dygraph
+
+    batch, seq_len = 128, 128
+    model, _, _, _ = bert_dygraph.bert_base_dygraph(seq_len=seq_len,
+                                                    amp=True)
+    feeds = bert_dygraph.sample_batch(2, seq_len, 30522,
+                                      np.random.RandomState(0))
+    with fluid.dygraph.guard():
+        model(*feeds)  # materialises the lazily built parameters
+    step, params, opt_state = bert_dygraph.make_train_step(model)
+    feed_avals = tuple(
+        sds((batch,) + np.asarray(f).shape[1:],
+            jax.dtypes.canonicalize_dtype(np.asarray(f).dtype))
+        for f in feeds)
+    compiled = _compile(
+        chip, step, jax.eval_shape(lambda: params),
+        jax.eval_shape(lambda: opt_state),
+        jax.eval_shape(lambda: jax.random.PRNGKey(0)), *feed_avals,
+        donate_argnums=(0, 1))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < _HBM_BYTES
+    assert _kernel_calls(compiled) > 0
+
+
+@pytest.mark.slow
+def test_meshed_train_step_compiles_for_four_chips(topology):
+    """What ``chip_smoke.py --multichip`` runs on the mesh: transformer-base
+    (no dropout, global batch 128) under ``with_data_parallel`` over a
+    ('dp', 'mp') = 2x2 mesh of the four described chips, through the
+    Executor's own sharding rules. The partitioner that matters is the
+    chip's: the mp-annotated FFN weight stays sharded, the gradient
+    all-reduce is there, and no Pallas call is (the gates refuse a meshed
+    step)."""
+    import paddle_tpu as fluid
+    from jax.sharding import Mesh
+    from paddle_tpu import models
+    from paddle_tpu.core.executor import build_step_fn
+    from paddle_tpu.parallel import sharding_check
+
+    mesh = Mesh(np.array(topology.devices).reshape(2, 2), ("dp", "mp"))
+    (state, feed, rng), program, loss, persist = _abstract_step(
+        lambda: (models.transformer.transformer_base(
+            seq_len=256, dropout_rate=0.0), 128))
+    in_sh, out_sh = fluid.Executor(fluid.CPUPlace())._mesh_shardings(
+        program, tuple(sorted(feed)), (loss,), tuple(sorted(state)),
+        persist, mesh, "dp", None)
+
+    def on(aval, sharding):
+        return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                    sharding=sharding)
+
+    step = build_step_fn(program, (loss,), persist, fuse_opt=False)
+    with placed("tpu", meshed=True):
+        lowered = jax.jit(step, donate_argnums=(0,), in_shardings=in_sh,
+                          out_shardings=out_sh).lower(
+            *jax.tree.map(on, (state, feed, rng), in_sh))
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    mem = compiled.memory_analysis()
+    print("2x2 mesh: %d all-reduce, %.2f GB temporaries + %.2f GB arguments "
+          "per chip" % (hlo.count(" all-reduce("),
+                        mem.temp_size_in_bytes / 1e9,
+                        mem.argument_size_in_bytes / 1e9))
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < _HBM_BYTES
+    assert "all-reduce" in hlo
+    assert "tpu_custom_call" not in hlo
+    sharding_check.assert_param_sharded(hlo, "enc0_ffn_fc1.w", (512, 2048))
+    from chip_smoke import kernel_plans
+
+    assert set(kernel_plans(program)["flash_attention"]) == {
+        "reference[platform]"}

@@ -53,8 +53,8 @@ BUCKETS = [
     # (label, regex over "op_name || src")
     # attention-adjacent relayouts FIRST: transposes/copies emitted from
     # flash_attention.py are the [B,T,H,D] head-split copies around the
-    # streaming custom calls (~36 ms/step at seq-2048 pre-r6,
-    # NOTES_r5.md) — the packed streaming path exists to zero this bucket
+    # streaming custom calls — the packed streaming path exists to zero
+    # this bucket
     ("attn-layout-copy",
      r"(?=.*flash_attention)(?=.*(transpose|copy|reshape))"),
     ("attention-kernel", r"flash_attention|attn_fwd|attn_bwd"),

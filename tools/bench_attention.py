@@ -1,7 +1,7 @@
 """A/B harness for the dense attention kernel at bench shapes.
 
 Times fwd and fwd+bwd of the repo kernel on the real chip. Calls are
-chained on-device inside one jit (output fed back as input) so tunnel
+chained on-device inside one jit (output fed back as input) so
 dispatch latency cancels out; reported per-iteration time is
 (t(N iters) - t(1 iter)) / (N - 1).
 

@@ -58,37 +58,26 @@ def capture(model, steps, batch=None, seq=None):
     return trace_dir, main_prog, batch
 
 
-def _load_xspace(trace_dir):
-    from tensorflow.tsl.profiler.protobuf import xplane_pb2
+def _device_planes(trace_dir):
+    """Device planes of the newest xplane proto under ``trace_dir``, read
+    with ``jax.profiler.ProfileData`` (nothing but JAX)."""
+    from jax.profiler import ProfileData
 
     paths = sorted(glob.glob(os.path.join(
         trace_dir, "plugins/profile/*/*.xplane.pb")))
     if not paths:
         raise SystemExit("no xplane found under " + trace_dir)
-    space = xplane_pb2.XSpace()
-    with open(paths[-1], "rb") as f:
-        space.ParseFromString(f.read())
-    return space
+    return [plane for plane in ProfileData.from_file(paths[-1]).planes
+            if "TPU" in plane.name or "/device" in plane.name.lower()]
 
 
 def parse_xplane(trace_dir):
     """Parse the newest xplane proto under ``trace_dir`` into
     (plane_name, line_name, op_name, seconds) rows. Shared by
     ``tools/attribute_transformer.py``."""
-    space = _load_xspace(trace_dir)
-
-    rows = []
-    for plane in space.planes:
-        if "TPU" not in plane.name and "/device" not in plane.name.lower():
-            continue
-        emeta = plane.event_metadata
-        for line in plane.lines:
-            for ev in line.events:
-                md = emeta.get(ev.metadata_id)
-                name = md.name if md else str(ev.metadata_id)
-                rows.append((plane.name, line.name, name,
-                             ev.duration_ps / 1e12))
-    return rows
+    return [(plane.name, line.name, ev.name, ev.duration_ns / 1e9)
+            for plane in _device_planes(trace_dir)
+            for line in plane.lines for ev in line.events]
 
 
 def parse_xplane_bytes(trace_dir):
@@ -96,30 +85,21 @@ def parse_xplane_bytes(trace_dir):
     (summed over occurrences) on the sync op line. Returns {} when the
     platform/profiler version doesn't record them."""
     try:
-        space = _load_xspace(trace_dir)
+        planes = _device_planes(trace_dir)
     except SystemExit:
         return {}
     agg = defaultdict(int)
-    for plane in space.planes:
-        if "TPU" not in plane.name and "/device" not in plane.name.lower():
-            continue
-        emeta = plane.event_metadata
-        smeta = plane.stat_metadata
+    for plane in planes:
         for line in plane.lines:
             if line.name != "XLA Ops":
                 continue
             for ev in line.events:
-                md = emeta.get(ev.metadata_id)
-                name = md.name if md else str(ev.metadata_id)
-                for st in ev.stats:
-                    sm = smeta.get(st.metadata_id)
+                for stat, value in ev.stats:
                     # EXACT name: ops also carry per-memory-space
                     # breakdown stats ("bytes accessed0", ...) that would
                     # double-count against the aggregate
-                    if sm is None or sm.name.lower() != "bytes accessed":
-                        continue
-                    agg[name.split(" =")[0].lstrip("%")] += (
-                        st.uint64_value or st.int64_value)
+                    if stat.lower() == "bytes accessed":
+                        agg[ev.name.split(" =")[0].lstrip("%")] += int(value)
     return dict(agg)
 
 
