@@ -12,8 +12,8 @@ Each line: {"metric", "value", "unit", "vs_baseline", "obs"}. ``obs``
 carries the record's telemetry view (ISSUE 17): whether the measured
 loop ran under ``paddle_tpu.obs.trace`` (``BENCH_TRACE=1`` turns it on
 and the field then points at the ``trace-<pid>.jsonl`` capture for
-``tools/trace_view.py``), the span count the config contributed, and
-the live MFU gauge's roofline-vs-measured agreement. ``vs_baseline``
+``tools/trace_view.py``) and the span count the config contributed.
+``vs_baseline``
 is model FLOPs utilization (MFU) relative to the BASELINE.json
 north-star target of 45% MFU (>1.0 beats the target); for the
 row-latency-bound DeepFM config it is throughput vs 45% of the
@@ -121,11 +121,9 @@ def _obs_begin():
     ``BENCH_TRACE=1`` the process tracer is started (once) with its
     capture directed at ``BENCH_TRACE_DIR`` or a fresh temp dir, so the
     measured loop's executor/engine spans land in a ``trace-<pid>.jsonl``
-    the record can point at. The MFU gauge is reset either way so the
-    record's ``mfu_vs_model`` covers exactly this config's steps.
-    Returns the span mark ``_obs_record`` subtracts."""
+    the record can point at. Returns the span mark ``_obs_record``
+    subtracts."""
     from paddle_tpu.obs import trace
-    from paddle_tpu.obs.registry import MFU
 
     if os.environ.get("BENCH_TRACE") == "1" and trace.active() is None:
         import tempfile
@@ -133,25 +131,18 @@ def _obs_begin():
         trace_dir = (os.environ.get("BENCH_TRACE_DIR")
                      or tempfile.mkdtemp(prefix="paddle-tpu-bench-trace-"))
         trace.start(trace_dir=trace_dir)
-    MFU.reset()
     tracer = trace.active()
     return len(tracer.spans) + tracer.dropped if tracer else 0
 
 
 def _obs_record(mark=0):
     """The record's ``obs`` field: whether the measured loop ran under
-    tracing, where the capture landed (feed it to tools/trace_view.py),
-    how many spans this config contributed, and the live MFU gauge's
-    model-agreement figure from ``Executor.run`` (None when untraced —
-    the gauge only fills under tracing, where the executor blocks on the
-    fetch for an honest step time)."""
+    tracing, where the capture landed (feed it to tools/trace_view.py)
+    and how many spans this config contributed."""
     from paddle_tpu.obs import trace
-    from paddle_tpu.obs.registry import MFU
 
-    snap = MFU.snapshot()
     obs = {"traced": trace.active() is not None,
-           "trace_path": None, "span_count": 0,
-           "mfu_vs_model": snap.get("mfu_vs_model")}
+           "trace_path": None, "span_count": 0}
     tracer = trace.active()
     if tracer is not None:
         trace.flush()

@@ -1,0 +1,12 @@
+"""Program -> step: host milliseconds of every device_put and re-layout of
+feeds, rng and state in one ``exe.run``: the program's own span
+``paddle_tpu.executor.feed_put`` on the profiler's trace (opened in
+``Executor.run``), read in the profiled step whose
+``paddle_tpu.executor.run`` span is the median one, so that the four phases
+add up to that span."""
+
+from benchmark import program_spans
+
+
+def read(ctx):
+    return program_spans.phase_ms(ctx, "executor.feed_put")

@@ -1,0 +1,193 @@
+"""The program's own account of a run: its host spans in the profiler's
+trace, and the record its executor keeps of each compile.
+
+Spans. While a ``jax.profiler`` trace is taken, every span the program opens
+(``paddle_tpu/obs/trace.py``: ``Executor.run`` and its phases ``prepare``,
+``feed_put``, ``dispatch``, ``writeback``; ``trace``, ``lower``,
+``backend_compile`` when a variant is staged) is also an event
+``paddle_tpu.<span>`` on the host plane of that trace, on the clock the
+device events are on. ``trace_reduce.load`` keeps only the benchmark's own
+``bench.*`` annotations, so this file reads the run's xplane for the
+``paddle_tpu.*`` ones (``load``) and reduces plain rows (``ProgramSpans``:
+the phases of the median call, and the first device's idle gaps put down to
+the innermost span that covers them), tested on ``tests/benchmark/recorded_program_spans.json``.
+
+Compile record. ``Executor.compile_records`` holds one dict a compiled
+variant: seconds of tracing, lowering and backend compile, whether the
+persistent cache served the executable, its ``memory_analysis()``, the
+gates' decisions. ``compile_record`` finds the training step's.
+
+A program without these (the parent of the PR that brought them) gives
+``None`` everywhere: the metric is then left out of the line.
+"""
+
+import glob
+import json
+import os
+import statistics
+
+PREFIX = "paddle_tpu."
+NO_SPAN = "(no program span)"
+
+
+def load(xplane_path):
+    """[(name, start_ns, duration_ns)] of the ``paddle_tpu.*`` events on the
+    host planes of an ``.xplane.pb``."""
+    from jax.profiler import ProfileData
+
+    rows = []
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(PREFIX):
+                    rows.append((ev.name, float(ev.start_ns),
+                                 float(ev.duration_ns)))
+    return rows
+
+
+def _clip(start, end, lo, hi):
+    return max(start, lo), min(end, hi)
+
+
+class ProgramSpans:
+    """``rows``: host rows as ``load`` gives them. ``busy``: merged, sorted
+    [start, end) intervals in which an operation runs on the first device
+    (``trace_reduce.Trace.busy[0]``), or None where there is no device
+    trace. ``steps``: how many steps the traced window holds."""
+
+    def __init__(self, rows, busy=None, steps=1):
+        self.rows = list(rows)
+        self.busy = busy
+        self.steps = steps
+
+    def durations_ms(self, span):
+        return [dur / 1e6 for name, _, dur in self.rows
+                if name == PREFIX + span]
+
+    def median_ms(self, span):
+        """Median milliseconds of the events of one span; None if the trace
+        holds none."""
+        found = self.durations_ms(span)
+        return statistics.median(found) if found else None
+
+    def median_run(self):
+        """(start, end) of the ``executor.run`` event whose duration is the
+        median one (the lower middle of an even number); None if the trace
+        holds none."""
+        runs = sorted((dur, start) for name, start, dur in self.rows
+                      if name == PREFIX + "executor.run")
+        if not runs:
+            return None
+        dur, start = runs[(len(runs) - 1) // 2]
+        return start, start + dur
+
+    def phase_ms(self, span):
+        """Milliseconds of ``span`` inside the median ``executor.run`` of the
+        trace: the phases of one and the same call, so that they add up to
+        that call (medians taken phase by phase over five steps do not: each
+        would come from another step). None if the trace holds no such run
+        or the run no such span."""
+        run = self.median_run()
+        if run is None:
+            return None
+        found = [dur for name, start, dur in self.rows
+                 if name == PREFIX + span and run[0] <= start
+                 and start + dur <= run[1]]
+        return sum(found) / 1e6 if found else None
+
+    def idle_gaps(self):
+        """[start, end) between consecutive busy intervals of the first
+        device: inside the traced window, no operation running."""
+        if not self.busy:
+            return []
+        return [[end, start] for (_, end), (start, _)
+                in zip(self.busy, self.busy[1:]) if start > end]
+
+    def idle_by_span(self):
+        """{span name: idle seconds of the first device under it}: every
+        nanosecond of every idle gap goes to the innermost program span
+        that covers it (the shortest one, where several do), or to
+        ``NO_SPAN``. None without a device trace."""
+        if self.busy is None:
+            return None
+        out = {}
+        for lo, hi in self.idle_gaps():
+            inside = [(s, s + d, d, name) for name, s, d in self.rows
+                      if s < hi and s + d > lo]
+            cuts = sorted({lo, hi} | {t for s, e, _, _ in inside
+                                      for t in _clip(s, e, lo, hi)})
+            for a, b in zip(cuts, cuts[1:]):
+                covering = [(d, name) for s, e, d, name in inside
+                            if s <= a and e >= b]
+                name = min(covering)[1] if covering else NO_SPAN
+                out[name] = out.get(name, 0.0) + (b - a) / 1e9
+        return out
+
+    def idle_ms_a_step_under(self, prefix):
+        """First-device idle milliseconds a step that fall inside a span
+        whose name starts with ``prefix``. None without a device trace or
+        without any program span in the trace."""
+        if self.busy is None or not self.rows:
+            return None
+        by_span = self.idle_by_span()
+        return sum(v for k, v in by_span.items()
+                   if k.startswith(PREFIX + prefix)) / self.steps * 1e3
+
+
+def newest_xplane(directory):
+    found = sorted(glob.glob(os.path.join(
+        directory, "plugins", "profile", "*", "*.xplane.pb")),
+        key=os.path.getmtime)
+    return found[-1] if found else None
+
+
+def of(ctx):
+    """The ``ProgramSpans`` of a traced run, read once a run from the
+    xplane the ``train`` job wrote under ``benchmark_out/trace`` and kept
+    in ``ctx``."""
+    if "program_spans" not in ctx:
+        xplane = newest_xplane(ctx["run"].path("benchmark_out", "trace"))
+        trace = ctx.get("trace")
+        busy = getattr(trace, "busy", None)
+        ctx["program_spans"] = ProgramSpans(
+            load(xplane) if xplane else [], busy[0] if busy else None,
+            getattr(trace, "steps", 1))
+    return ctx["program_spans"]
+
+
+def phase_ms(ctx, span):
+    return of(ctx).phase_ms(span)
+
+
+def compile_record(ctx):
+    """The compile record of the training step's variant (the one that
+    fetches the loss; the first, should a later call have staged it again),
+    or None where the executor keeps no records."""
+    trainer = ctx.get("trainer")
+    records = getattr(getattr(trainer, "exe", None), "compile_records", None)
+    if not records:
+        return None
+    if not ctx.get("compile_records_printed"):
+        ctx["compile_records_printed"] = True
+        for r in records:  # the run's log keeps every variant's record
+            print("compile record  " + json.dumps(
+                {k: (round(v, 3) if isinstance(v, float) else v)
+                 for k, v in r.items() if k != "feed_names"},
+                sort_keys=True))
+    loss = trainer.loss.name
+    for record in records:
+        if loss in record.get("fetch_names", ()):
+            return record
+    return None
+
+
+def compile_seconds(ctx, phase):
+    """Seconds of one phase (``trace_s``, ``lower_s``,
+    ``backend_compile_s``) of the training step's compile, on the chip. A
+    rehearsal's are the CPU backend's and are not reported."""
+    if ctx["run"].devices[0].platform != "tpu":
+        return None
+    record = compile_record(ctx)
+    return None if record is None else record.get(phase)

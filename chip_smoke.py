@@ -160,6 +160,10 @@ def sizes(rehearse):
 # helpers
 # ---------------------------------------------------------------------------
 
+# a Pallas call in a compiled step's HLO text
+TPU_CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
+
+
 def kernel_plans(program):
     """{op type: [kernel, ...]} as recorded at trace time by every op that
     chose between a Pallas kernel and a fallback
@@ -312,11 +316,9 @@ def phase_train(ctx):
                                                   ctx["platform"]))
     plans = kernel_plans(main).get("flash_attention", [])
     check(plans, "no attention site recorded a kernel plan")
-    # the StableHLO the step lowers to (the optimized text would cost a
-    # second XLA compile of the step): the loss depends on every one of
-    # these calls, so none of them is dead code XLA could drop
-    n_custom = exe.lowered_hlo_text(optimized=False).count(
-        "tpu_custom_call")
+    # the text of the executable that ran, kept from its one staging:
+    # the Pallas calls XLA left in the step
+    n_custom = exe.lowered_hlo_text().count(TPU_CUSTOM_CALL)
     if ctx["on_chip"]:
         check(n_custom > 0, "no tpu_custom_call in the lowered train step")
         check(not any(p.startswith("reference") for p in plans),
@@ -615,8 +617,8 @@ def _static_config(ctx, name, build, kw, batch):
     rec = {"config": name, "batch": batch, "model_kw": kw,
            "first_step_s": round(first_s, 1),
            "losses": [round(x, 4) for x in losses],
-           "tpu_custom_calls": exe.lowered_hlo_text(
-               optimized=False).count("tpu_custom_call")}
+           "tpu_custom_calls": exe.lowered_hlo_text().count(
+               TPU_CUSTOM_CALL)}
     plans = kernel_plans(main)
     if plans:
         rec["kernel_plans"] = {t: tally(names)

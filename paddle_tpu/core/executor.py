@@ -20,17 +20,21 @@ Randomness is a threaded functional PRNG key stored in the scope under
 """
 
 import os
+import time
 import warnings
 
 import jax
+import jax.monitoring
 import jax.numpy as jnp
 import numpy as np
 
 from . import framework
 from .framework import Variable
 from .op_registry import run_op, placed, RNG_KEY, RNG0_KEY, ENV0_KEY
-from ..obs import registry as obs_registry
 from ..obs import trace as obs_trace
+# the span primitive's second sink (jax.profiler.TraceAnnotation) is
+# installed by the module that owns the profiler surface
+from .. import profiler  # noqa: F401
 
 __all__ = ["Executor", "Scope", "global_scope", "scope_guard",
            "XLAPlace", "TPUPlace", "CPUPlace", "CUDAPlace"]
@@ -283,6 +287,43 @@ def _xla_compiler_options():
     return {"compiler_options": opts} if opts else {}
 
 
+# Whether JAX's persistent compilation cache served an executable is only
+# told through ``jax.monitoring``; counted here, read round a compile.
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_cache_events = {_CACHE_HIT: 0, _CACHE_MISS: 0}
+
+
+def _on_jax_event(event, **kwargs):
+    if event in _cache_events:
+        _cache_events[event] += 1
+
+
+jax.monitoring.register_event_listener(_on_jax_event)
+
+
+class _Variant:
+    """One compiled variant of a program: the jitted step and its input
+    layout (made when the variant is first seen), and what staging it
+    leaves behind — the ``jax.stages`` objects ``run`` calls and
+    ``lowered_hlo_text`` reads."""
+
+    __slots__ = ("jfn", "in_shardings", "about", "lowered", "compiled")
+
+    def __init__(self, jfn, in_shardings, about):
+        self.jfn = jfn
+        self.in_shardings = in_shardings
+        self.about = about  # what the compile record says of the variant
+        self.lowered = None
+        self.compiled = None
+
+
+def _host_bytes(arrays):
+    """Bytes of the numpy values among ``arrays``: what a put moves from
+    the host."""
+    return sum(a.nbytes for a in arrays if isinstance(a, np.ndarray))
+
+
 class Executor:
     def __init__(self, place=None):
         self.place = place if place is not None else XLAPlace(0)
@@ -297,9 +338,20 @@ class Executor:
         # 2 = raising). A warn-mode pass must NOT suppress a later strict
         # verify=True of the same variant.
         self._verified = {}
-        # per-variant static roofline estimates feeding the live MFU
-        # gauge (obs.registry.MFU) when a step runs under tracing
-        self._mfu_cache = {}
+        self._last = None  # the variant of the last run (lowered_hlo_text)
+        # One record a compiled variant, always on (it happens once a
+        # variant): seconds of tracing, lowering and backend compile,
+        # whether the persistent cache served the executable, the
+        # executable's memory_analysis() and the decision of every gated
+        # kernel site met during the trace. Plain data; read it.
+        self.compile_records = []
+        # plain counters beside it: calls of run, calls that found their
+        # variant compiled and calls that staged one, and state arrays a
+        # call had to move to the step's layout (0 in a steady loop)
+        self.runs = 0
+        self.variant_hits = 0
+        self.variant_misses = 0
+        self.state_relayouts = 0
 
     # -- public API ---------------------------------------------------------
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
@@ -342,6 +394,64 @@ class Executor:
                 return self._run_checked(program, feed or {},
                                          fetch_list or [], scope,
                                          return_numpy)
+        # The host phases of a call, children of ``executor.run`` (the
+        # span the serving trace stitches on): recorded in the obs tracer
+        # and, as ``paddle_tpu.executor.*``, on whatever jax.profiler trace
+        # is being taken. Nothing here waits for the device except the
+        # np.asarray of ``return_numpy=True``, under ``writeback``.
+        ordinal = self.runs
+        self.runs += 1
+        with obs_trace.span("executor.run") as run_sp:
+            with obs_trace.span("executor.prepare"):
+                entry, hit, scope, state_in_names, feed_arrays, span = \
+                    self._prepare(program, feed, fetch_list, scope,
+                                  use_program_cache, donate_state, verify)
+            if run_sp:
+                run_sp.set(ordinal=ordinal, variant_hit=hit)
+            with obs_trace.span("executor.feed_put") as sp:
+                put = _host_bytes(feed_arrays.values()) if sp else 0
+                state, feed_arrays, rng, moved = self._feed_put(
+                    entry, scope, state_in_names, feed_arrays, span)
+                self.state_relayouts += moved
+                if sp:
+                    sp.set(bytes=put, state_relayouts=moved)
+            if entry.compiled is None:
+                self._stage(entry, state, feed_arrays, rng, ordinal)
+            self._last = entry
+            with obs_trace.span("executor.dispatch"):
+                try:
+                    fetches, new_state, rng_out = entry.compiled(
+                        state, feed_arrays, rng)
+                except (TypeError, ValueError):
+                    # an argument no longer has the shape, type or
+                    # placement the variant was staged for (a state array
+                    # replaced in the scope): what jit answers with a
+                    # retrace. The check precedes execution, so nothing
+                    # was donated; stage again for what is there, or raise
+                    # what that raises.
+                    self._stage(entry, state, feed_arrays, rng, ordinal,
+                                restaged=True)
+                    fetches, new_state, rng_out = entry.compiled(
+                        state, feed_arrays, rng)
+            with obs_trace.span("executor.writeback") as sp:
+                scope.set(RNG_KEY, rng_out)
+                for n, v in new_state.items():
+                    scope.set(n, v)
+                if sp:
+                    sp.set(fetch="numpy" if return_numpy else "device")
+                if return_numpy:
+                    return [np.asarray(f) for f in fetches]
+                return list(fetches)
+
+    def _prepare(self, program, feed, fetch_list, scope, use_program_cache,
+                 donate_state, verify):
+        """``executor.prepare``: unwrap the CompiledProgram, normalise the
+        feeds, seed the rng, name the state, look the variant up (making
+        its jitted step and layout when it is new, staging nothing) and
+        verify. Returns (variant, found compiled, scope, state names, feed
+        arrays, the mesh the step spans or None)."""
+        from .compiler import CompiledProgram
+
         mesh = None
         dp_axis = None
         sp_axis = None
@@ -485,7 +595,11 @@ class Executor:
                 for d in check_resources(program, batch=batch).diagnostics:
                     warnings.warn("program verification: %s" % d)
             self._verified[key] = strictness
-        if entry is None:
+        hit = entry is not None
+        if hit:
+            self.variant_hits += 1
+        else:
+            self.variant_misses += 1
             entry = self._compile(program, tuple(sorted(feed_arrays)),
                                   fetch_names, state_in_names, persist_names,
                                   mesh, dp_axis, sp_axis, seq_feeds, pp,
@@ -493,18 +607,32 @@ class Executor:
                                   placement)
             if use_program_cache:
                 self._cache[key] = entry
-        jfn, in_shardings = entry
+        return entry, hit, scope, state_in_names, feed_arrays, span
 
+    def _feed_put(self, entry, scope, state_in_names, feed_arrays, span):
+        """``executor.feed_put``: every device_put and re-layout of feeds,
+        rng and state. Returns (state, feeds, rng, state arrays moved)."""
         state = {n: scope.get(n) for n in state_in_names}
         rng = scope.get(RNG_KEY)
+        in_shardings = entry.in_shardings
+        moved = 0
+
+        def lay_out(a, sh):
+            nonlocal moved
+            if not isinstance(a, jax.Array) or a.sharding == sh:
+                return a
+            moved += 1
+            return jax.device_put(a, sh)
+
         if in_shardings is not None:
             # a scope initialised by a one-device startup run holds arrays
             # committed to that device: lay them out as the mesh step
             # wants them (arrays already in place pass through untouched)
             state, rng = jax.tree.map(
-                lambda a, sh: a if not isinstance(a, jax.Array)
-                or a.sharding == sh else jax.device_put(a, sh),
-                (state, rng), (in_shardings[0], in_shardings[2]))
+                lay_out, (state, rng), (in_shardings[0], in_shardings[2]))
+            # and the host's batch goes to the chips here, not inside the
+            # call of the step
+            feed_arrays = jax.device_put(feed_arrays, in_shardings[1])
         elif span is not None:
             # plain Program inside mesh_scope(): its shard_maps span the
             # mesh, so arrays a one-device startup run committed elsewhere
@@ -512,10 +640,16 @@ class Executor:
             from jax.sharding import NamedSharding, PartitionSpec
 
             everywhere = NamedSharding(span, PartitionSpec())
-            state, rng = jax.tree.map(
-                lambda a: a if not isinstance(a, jax.Array)
-                or a.sharding.device_set == everywhere.device_set
-                else jax.device_put(a, everywhere), (state, rng))
+
+            def replicate(a):
+                nonlocal moved
+                if not isinstance(a, jax.Array) \
+                        or a.sharding.device_set == everywhere.device_set:
+                    return a
+                moved += 1
+                return jax.device_put(a, everywhere)
+
+            state, rng = jax.tree.map(replicate, (state, rng))
         elif self._device is not None:
             # the place decides where the step runs: jit follows its
             # committed arguments, so committing the feeds and the rng key
@@ -524,57 +658,7 @@ class Executor:
             # an error from jit, never a silent move.
             feed_arrays = jax.device_put(feed_arrays, self._device)
             rng = jax.device_put(rng, self._device)
-        # abstract snapshot for lowered_hlo_text (state buffers are
-        # donated below, so keep avals, not arrays)
-        self._last_call = (jfn, jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
-            if hasattr(a, "shape") else a, (state, feed_arrays, rng)))
-        sp = obs_trace.span("executor.run")
-        if sp:
-            # under tracing the step is timed honestly: block on the
-            # fetches so async dispatch can't hide device time, then feed
-            # the measured wall next to the static roofline (MFU gauge)
-            roof = self._static_roofline(key, program, feed_arrays)
-            with sp:
-                fetches, new_state, rng_out = jfn(state, feed_arrays, rng)
-                jax.block_until_ready(fetches)
-                if roof is not None:
-                    sp.set(roofline_s=roof.get("roofline_s"),
-                           bound=roof.get("bound"))
-            if roof is not None:
-                obs_registry.MFU.record(sp.duration, roof)
-        else:
-            fetches, new_state, rng_out = jfn(state, feed_arrays, rng)
-        scope.set(RNG_KEY, rng_out)
-        for n, v in new_state.items():
-            scope.set(n, v)
-        if return_numpy:
-            return [np.asarray(f) for f in fetches]
-        return list(fetches)
-
-    def _static_roofline(self, key, program, feed_arrays):
-        """Cached ``analysis/cost.py`` roofline for this compiled
-        variant — priced ONCE per cache key, then a dict lookup per
-        step. Returns None for programs the cost engine can't price
-        (never an error: the gauge is advisory)."""
-        if key in self._mfu_cache:
-            return self._mfu_cache[key]
-        roof = None
-        try:
-            from ..analysis.cost import estimate_program
-
-            batch = None
-            for a in feed_arrays.values():
-                if getattr(a, "ndim", 0) >= 1:
-                    batch = int(a.shape[0])
-                    break
-            est = estimate_program(program, batch=batch,
-                                   feed_names=sorted(feed_arrays))
-            roof = est.roofline()
-        except Exception:
-            roof = None
-        self._mfu_cache[key] = roof
-        return roof
+        return state, feed_arrays, rng, moved
 
     def _platform(self):
         """Platform an un-meshed step runs on: the place's device, else
@@ -588,24 +672,21 @@ class Executor:
         the compiled-module inspection surface for multi-chip sharding
         assertions (``parallel/sharding_check.py``; ref analog:
         ``multi_devices_graph_check_pass.cc`` asserting SSA-graph
-        structure). Re-lowers from cached avals; call after ``run``.
-        ``optimized=False`` stops before XLA compiles: the StableHLO the
-        step lowers to, enough to see which custom calls it carries and
-        a trace away instead of a whole compile."""
-        if not getattr(self, "_last_call", None):
+        structure). It is the text of the executable that ran, kept from
+        its one staging: nothing is traced, lowered or compiled again.
+        Call after ``run``. ``optimized=False`` gives the StableHLO the
+        step lowered to, before XLA compiled it."""
+        if self._last is None:
             raise RuntimeError("no prior run() to inspect")
-        jfn, (state, feed_arrays, rng) = self._last_call
-        lowered = jfn.lower(state, feed_arrays, rng)
-        return lowered.compile().as_text() if optimized \
-            else lowered.as_text()
+        return (self._last.compiled if optimized
+                else self._last.lowered).as_text()
 
     def close(self):
         """Parity with ``Executor::Close`` (``executor.cc:139``): release the
         compiled-program cache."""
         self._cache.clear()
         self._verified.clear()
-        self._mfu_cache.clear()
-        self._last_call = None
+        self._last = None
 
     # -- debug run-mode -----------------------------------------------------
     def _run_checked(self, program, feed, fetch_list, scope, return_numpy):
@@ -810,18 +891,64 @@ class Executor:
                               infer_only=not donate_state and mesh is None)
 
         def step(state, feed, rng):
-            # trace-time: tells the Pallas gates where THIS step runs,
-            # whoever triggers the trace (run, lowered_hlo_text)
+            # trace-time: tells the Pallas gates where THIS step runs
             with placed(*placement):
                 return inner(state, feed, rng)
 
         donate = (0,) if donate_state else ()
         extra = _xla_compiler_options()
+        about = {"fetch_names": list(fetch_names),
+                 "feed_names": list(feed_names),
+                 "ops": len(program.global_block().ops),
+                 "meshed": mesh is not None}
         if mesh is None:
-            return jax.jit(step, donate_argnums=donate, **extra), None
+            return _Variant(jax.jit(step, donate_argnums=donate, **extra),
+                            None, about)
         in_shardings, out_shardings = self._mesh_shardings(
             program, feed_names, fetch_names, state_in_names, persist_names,
             mesh, dp_axis, sp_axis, seq_feeds, zero_state)
-        return jax.jit(step, donate_argnums=donate,
-                       in_shardings=in_shardings,
-                       out_shardings=out_shardings, **extra), in_shardings
+        return _Variant(jax.jit(step, donate_argnums=donate,
+                                in_shardings=in_shardings,
+                                out_shardings=out_shardings, **extra),
+                        in_shardings, about)
+
+    def _stage(self, entry, state, feed_arrays, rng, ordinal,
+               restaged=False):
+        """Trace, lower and compile a variant's step for the arguments of
+        this call, each stage once and under its span, keep what ``run``
+        calls and ``lowered_hlo_text`` reads, and append the variant's
+        compile record."""
+        from ..ops import gates
+
+        hits, misses = _cache_events[_CACHE_HIT], _cache_events[_CACHE_MISS]
+        t0 = time.perf_counter()
+        with obs_trace.span("executor.trace") as sp, \
+                gates.collect() as met:
+            traced = entry.jfn.trace(state, feed_arrays, rng)
+            decisions = gates.tally(met)
+            if sp:
+                sp.set(gates=decisions)
+        t1 = time.perf_counter()
+        with obs_trace.span("executor.lower"):
+            entry.lowered = traced.lower()
+        t2 = time.perf_counter()
+        with obs_trace.span("executor.backend_compile") as sp:
+            entry.compiled = entry.lowered.compile()
+            # the persistent cache is asked once for a step: a hit means
+            # the executable was loaded, a miss that XLA compiled it, and
+            # neither that the cache is off
+            cache = ("hit" if _cache_events[_CACHE_HIT] > hits else
+                     "miss" if _cache_events[_CACHE_MISS] > misses else "off")
+            if sp:
+                sp.set(persistent_cache=cache)
+        t3 = time.perf_counter()
+        memory = entry.compiled.memory_analysis()
+        self.compile_records.append(dict(
+            entry.about, ordinal=ordinal, restaged=restaged,
+            trace_s=t1 - t0, lower_s=t2 - t1, backend_compile_s=t3 - t2,
+            persistent_cache=cache, gates=decisions,
+            memory=None if memory is None else {
+                "temp_bytes": int(memory.temp_size_in_bytes),
+                "argument_bytes": int(memory.argument_size_in_bytes),
+                "output_bytes": int(memory.output_size_in_bytes),
+                "alias_bytes": int(memory.alias_size_in_bytes)}))

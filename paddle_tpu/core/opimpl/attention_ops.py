@@ -13,6 +13,7 @@ from ..op_registry import register, get, put, next_rng
 @register("flash_attention")
 def _flash_attention_op(env, op):
     from ...ops.flash_attention import flash_attention, plan_for
+    from ...ops.gates import note
 
     from ..op_registry import mxu_cast
 
@@ -29,6 +30,7 @@ def _flash_attention_op(env, op):
     # trace-time record: which attention kernel this op actually takes
     # and why a demotion happened (ISSUE 15 no-silent-fallback contract)
     op.attrs["_kernel_choice"] = plan.to_dict()
+    note("flash_attention", plan)
     out = flash_attention(q, k, v, op.attr("num_heads", 1), bias=bias,
                           causal=op.attr("causal", False),
                           dropout_rate=dropout, rng=rng, plan=plan)

@@ -272,6 +272,7 @@ def _fused_conv2d(env, op):
     replays the absorbed original ops verbatim, so the rewrite is
     numerics-neutral by construction on the fallback path."""
     from ...ops import fused_conv
+    from ...ops.gates import note
     from ..op_registry import mxu_cast, run_op
     from ..framework import Operator
 
@@ -293,6 +294,7 @@ def _fused_conv2d(env, op):
     # trace-time record: which kernel this op actually takes, and why a
     # refusal fell back (the ISSUE 15 no-silent-fallback contract)
     op.attrs["_kernel_choice"] = decision.to_dict()
+    note("fused_conv2d", decision)
     if not decision:
         for sub in op.attr("orig_ops") or ():
             if is_test and not sub.attr("is_test", False) \
@@ -401,7 +403,9 @@ def _layer_norm(env, op):
         # direction instead of XLA's ~5 — ops/fused_layer_norm.py)
         from ...ops.fused_layer_norm import fused_layer_norm, _use_fused
 
-        if _use_fused(x.shape[-1]):
+        from ...ops.gates import note
+
+        if note("layer_norm", _use_fused(x.shape[-1])):
             y, mean, var = fused_layer_norm(x, scale, bias, eps)
             put(env, op.output("Y"), y)
             put(env, op.output("Mean"), mean)

@@ -11,11 +11,14 @@ millisecond):
     frame header, and Perfetto/chrome-trace export. One
     ``RouterClient.predict`` yields ONE stitched trace spanning the
     router door, the dispatch hop, the worker queue, the engine
-    micro-batch, and ``Executor.run``.
+    micro-batch, and ``Executor.run`` with its host phases
+    (``executor.prepare`` / ``feed_put`` / ``dispatch`` / ``writeback``).
+    The same spans are the program's own on any ``jax.profiler`` trace:
+    ``paddle_tpu/profiler.py`` installs the ``TraceAnnotation`` sink, so
+    this package still imports nothing of JAX.
   * :mod:`~paddle_tpu.obs.registry` — named Counter/Gauge/Histogram
     primitives with Prometheus-text exposition, unifying
-    ``ServingMetrics``' ad-hoc counters; plus the live MFU/roofline
-    gauge ``Executor.run`` feeds under tracing.
+    ``ServingMetrics``' ad-hoc counters.
   * :mod:`~paddle_tpu.obs.flight` — a bounded ring buffer of
     reliability events (fault-site decisions, breaker transitions,
     respawns, EDF displacements, deadline refusals, per-request

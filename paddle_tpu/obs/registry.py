@@ -4,9 +4,7 @@ A :class:`Registry` holds :class:`Counter`, :class:`Gauge`, and
 histogram entries by name and renders them in the Prometheus text
 format (``# HELP`` / ``# TYPE`` + samples). ``ServingMetrics`` builds
 its ~20 ad-hoc counters on one of these (satellite 2), the router's
-ping path and the worker ``stats`` verb serve the rendered text, and
-:data:`MFU` is the process-wide model-vs-measured gauge that
-``Executor.run`` feeds under tracing.
+ping path and the worker ``stats`` verb serve the rendered text.
 
 Stdlib-only and import-light on purpose: this module must not import
 jax, ``profiler``, or anything under ``serving`` — histograms are
@@ -188,79 +186,3 @@ class Registry:
             for sample_name, v in m.samples():
                 lines.append("%s %s" % (sample_name, _fmt(v)))
         return "\n".join(lines) + "\n" if lines else ""
-
-
-class MfuGauge:
-    """Live model-vs-measured agreement fed by ``Executor.run``.
-
-    Under tracing the executor records each step's measured wall time
-    next to the ``analysis/cost.py`` roofline estimate for the same
-    program+batch. ``mfu_vs_model`` is roofline/measured (1.0 = the
-    static model explains the step exactly; <1 = slower than modeled),
-    and ``mfu`` is achieved-FLOPs over the matmul ceiling.
-    """
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self.reset()
-
-    def reset(self):
-        with self._lock:
-            self._steps = 0
-            self._measured_s = 0.0
-            self._roofline_s = 0.0
-            self._flops = 0.0
-            self._peak_flops = 0.0
-            self._bound = None
-            self._last_measured_s = 0.0
-
-    def record(self, measured_s, roofline):
-        """Record one executed step against its roofline dict."""
-        if measured_s <= 0.0 or not roofline:
-            return
-        with self._lock:
-            self._steps += 1
-            self._measured_s += measured_s
-            self._last_measured_s = measured_s
-            self._roofline_s += roofline.get("roofline_s") or 0.0
-            self._flops += roofline.get("flops") or 0.0
-            ceil = roofline.get("ceilings") or {}
-            self._peak_flops = ceil.get("matmul_flops") or self._peak_flops
-            self._bound = roofline.get("bound", self._bound)
-
-    def snapshot(self):
-        with self._lock:
-            if self._steps == 0:
-                return {"steps": 0}
-            measured = self._measured_s
-            out = {
-                "steps": self._steps,
-                "measured_s": measured,
-                "last_measured_s": self._last_measured_s,
-                "roofline_s": self._roofline_s,
-                "mfu_vs_model": (self._roofline_s / measured) if measured > 0 else 0.0,
-                "bound": self._bound,
-            }
-            if self._peak_flops > 0 and measured > 0:
-                out["mfu"] = (self._flops / measured) / self._peak_flops
-            return out
-
-    def prometheus_lines(self):
-        snap = self.snapshot()
-        if not snap.get("steps"):
-            return []
-        lines = [
-            "# TYPE paddle_tpu_mfu_vs_model gauge",
-            "paddle_tpu_mfu_vs_model %s" % _fmt(snap["mfu_vs_model"]),
-            "# TYPE paddle_tpu_executor_steps_traced counter",
-            "paddle_tpu_executor_steps_traced %s" % _fmt(snap["steps"]),
-        ]
-        if "mfu" in snap:
-            lines.append("# TYPE paddle_tpu_mfu gauge")
-            lines.append("paddle_tpu_mfu %s" % _fmt(snap["mfu"]))
-        return lines
-
-
-# Process-wide MFU gauge; Executor.run feeds it, metrics exposition and
-# bench read it.
-MFU = MfuGauge()

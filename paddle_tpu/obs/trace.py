@@ -23,6 +23,14 @@ Design goals, in priority order:
    works without an active tracer so a hop that merely forwards does
    not need tracing enabled to preserve the id.
 
+4. **One primitive, two sinks.** A span opened through :func:`span` is
+   recorded in the tracer when one is active AND, when a module that
+   imports JAX has installed its factory (:func:`install_annotator`;
+   ``paddle_tpu/profiler.py`` does), on the host plane of whatever
+   ``jax.profiler`` trace is being taken, as ``paddle_tpu.<name>`` —
+   the profiler's own clock, so a device idle gap can be put down to a
+   span of the program. This module never imports JAX itself.
+
 Spans are recorded into a bounded in-memory list and flushed as JSON
 lines to ``<dir>/trace-<pid>.jsonl`` when ``PADDLE_TPU_TRACE=<dir>``
 (or an explicit ``trace_dir=``) is set — one file per process, stitched
@@ -52,14 +60,25 @@ def _new_id() -> str:
     return "%016x" % random.getrandbits(_ID_BITS)
 
 
+def _annotation_tags(tags):
+    """Tags as a profiler annotation takes them: numbers and strings."""
+    return {k: v if isinstance(v, (int, float, str)) else json.dumps(
+        v, sort_keys=True, default=str) for k, v in tags.items()}
+
+
 class Span:
-    """One timed, named region. Truthy; use as a context manager."""
+    """One timed, named region. Truthy; use as a context manager.
+
+    ``tracer`` is None for a span that only the profiler's trace (or a
+    caller that asked for a ``timed`` span) sees: it then keeps its own
+    ``perf_counter`` times and is recorded nowhere else."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0", "t1",
-                 "tags", "tid", "_tracer")
+                 "tags", "tid", "_tracer", "_ann")
 
-    def __init__(self, tracer, name):
+    def __init__(self, tracer, name, ann=None):
         self._tracer = tracer
+        self._ann = ann
         self.name = name
         self.trace_id = None
         self.span_id = None
@@ -89,13 +108,30 @@ class Span:
         return True
 
     def __enter__(self):
-        self._tracer._enter(self)
+        tracer = self._tracer
+        if self._ann is None and tracer is not None \
+                and _ANNOTATE is not None:
+            self._ann = _ANNOTATE(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        if tracer is not None:
+            tracer._enter(self)
+        else:
+            self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if exc_type is not None:
             self.set(error=exc_type.__name__)
-        self._tracer._exit(self)
+        if self._tracer is not None:
+            self._tracer._exit(self)
+        else:
+            self.t1 = time.perf_counter()
+        ann = self._ann
+        if ann is not None:
+            if self.tags:
+                ann.set_metadata(**_annotation_tags(self.tags))
+            ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -261,6 +297,18 @@ class Tracer:
 
 _TRACER = None
 _atexit_installed = False
+# name -> an entered-and-left annotation object (``__enter__``,
+# ``__exit__``, ``set_metadata(**tags)``) on the host plane of the
+# profiler trace being taken, or None while none is being taken
+_ANNOTATE = None
+
+
+def install_annotator(factory):
+    """Give every span a second sink: ``factory(name)`` is asked once a
+    span and answers None while no profiler trace is being taken. Called
+    by the module that imports JAX; ``None`` uninstalls."""
+    global _ANNOTATE
+    _ANNOTATE = factory
 
 
 def start(clock=None, trace_dir=None, max_spans=65536):
@@ -301,12 +349,21 @@ def _atexit_flush():
         t.flush()
 
 
-def span(name, parent=None):
-    """Open a span under the global tracer; falsy no-op when disabled."""
+def span(name, parent=None, timed=False):
+    """Open a span of the program: under the global tracer when one is
+    active, on the profiler's trace when one is being taken, both when
+    both are. With neither it is the falsy no-op, unless the caller keeps
+    an aggregate of its own from ``duration`` and asks for ``timed``."""
     t = _TRACER
-    if t is None:
-        return _NULL_SPAN
-    return t.span(name, parent=parent)
+    if t is not None:
+        return t.span(name, parent=parent)
+    if _ANNOTATE is not None:
+        ann = _ANNOTATE(name)
+        if ann is not None:
+            return Span(None, name, ann)
+    if timed:
+        return Span(None, name)
+    return _NULL_SPAN
 
 
 def current():

@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .kernel_names import named_pallas_call
+
 _INTERPRET = False  # tests flip this to run kernels on CPU
 
 
@@ -423,7 +425,8 @@ def _flash_fwd_impl(q, k, v, bias, seed, causal, scale, dropout_rate):
             b_ref = None
         kernel(q_ref, k_ref, v_ref, b_ref, s_ref, o_ref, l_ref)
 
-    out, lse = pl.pallas_call(
+    out, lse = named_pallas_call(
+        "head_split_stream.fwd",
         kernel_entry,
         grid=(bh, t_pad // block_q),
         in_specs=in_specs,
@@ -525,7 +528,8 @@ def _flash_bwd_impl(q, k, v, bias, seed, causal, scale, dropout_rate,
     out_specs2.append(pl.BlockSpec((None, t_pad, d),
                                    lambda b, ki: (b, 0, 0)))
     out_shape2.append(jax.ShapeDtypeStruct((bh, t_pad, d), jnp.float32))
-    res = pl.pallas_call(
+    res = named_pallas_call(
+        "head_split_stream.bwd",
         dkv_entry,
         grid=(bh, tk_pad // block_k),
         in_specs=in_specs2,
@@ -799,7 +803,8 @@ def _packed_stream_fwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
             b_ref = None
         kernel(q_ref, k_ref, v_ref, b_ref, s_ref, o_ref, l_ref)
 
-    out, lse = pl.pallas_call(
+    out, lse = named_pallas_call(
+        "packed_stream.fwd",
         entry,
         grid=(b, t_pad // block_q),
         in_specs=in_specs,
@@ -899,7 +904,8 @@ def _packed_stream_bwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
     out_specs.append(pl.BlockSpec((None, t_pad, hd),
                                   lambda b, ki: (b, 0, 0)))
     out_shape.append(jax.ShapeDtypeStruct((b, t_pad, hd), jnp.float32))
-    res = pl.pallas_call(
+    res = named_pallas_call(
+        "packed_stream.bwd",
         entry,
         grid=(b, tk_pad // block_k),
         in_specs=in_specs,
@@ -1186,7 +1192,8 @@ def _dense_fwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
     in_specs.append(pl.BlockSpec((1, 1), lambda bi: (0, 0)))
     args.append(jnp.asarray([[seed]], jnp.uint32))
     nh_pad = max(num_heads, 8)  # sublane-tiled stats block
-    out, lse = pl.pallas_call(
+    out, lse = named_pallas_call(
+        "dense_vmem.fwd",
         entry,
         grid=(b // g,),
         in_specs=in_specs,
@@ -1262,7 +1269,8 @@ def _dense_bwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
     if bias is not None:
         out_specs.append(pl.BlockSpec((g, 8, tk_pad), lambda bi: (bi, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((b, 8, tk_pad), jnp.float32))
-    res = pl.pallas_call(
+    res = named_pallas_call(
+        "dense_vmem.bwd",
         entry,
         grid=(b // g,),
         in_specs=in_specs,

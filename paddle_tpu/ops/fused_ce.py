@@ -24,6 +24,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .kernel_names import named_pallas_call
+
 _INTERPRET = False  # tests flip this to run the kernel on CPU
 
 
@@ -36,17 +38,30 @@ _FUSED_MIN_LOGITS = 1.5e9
 
 
 def _use_fused(x, w):
+    """Structured gate (``ops.gates.GateDecision``): the fused kernel, or
+    the plain projection + CE with the reason (env switch, placement, or
+    fewer logits than the threshold)."""
+    from .gates import GateDecision, GateReason
+
+    def refuse(check, detail):
+        return GateDecision(False, "xla_projection_ce",
+                            fallback="fused_ce",
+                            reasons=[GateReason(check, detail)])
+
     if _INTERPRET:
-        return True
+        return GateDecision(True, "fused_ce")
     from ..core.op_registry import env_flag, single_tpu
 
     if env_flag("PADDLE_TPU_NO_FUSED_CE"):  # A/B escape hatch
-        return False
+        return refuse("env", "PADDLE_TPU_NO_FUSED_CE is set")
     if not single_tpu():
-        return False
+        return refuse("platform", "the step is not placed on one TPU chip")
     n_logits = (x.size // x.shape[-1]) * w.shape[1]
-    return (n_logits >= _FUSED_MIN_LOGITS
-            or env_flag("PADDLE_TPU_FUSED_CE"))
+    if n_logits >= _FUSED_MIN_LOGITS or env_flag("PADDLE_TPU_FUSED_CE"):
+        return GateDecision(True, "fused_ce")
+    return refuse("size", "%.3g logits, under the %.3g from which "
+                  "recomputing the projection pays"
+                  % (n_logits, _FUSED_MIN_LOGITS))
 
 
 # ---------------------------------------------------------------------------
@@ -147,7 +162,8 @@ def _fwd_impl(x, w, b, y, eps):
             rest = refs[3:]
         kernel(x_ref, w_ref, b_ref, y_ref, *rest)
 
-    loss, lse = pl.pallas_call(
+    loss, lse = named_pallas_call(
+        "fused_ce.fwd",
         entry,
         grid=(nt, nv),
         in_specs=in_specs,
@@ -312,7 +328,9 @@ def linear_smooth_ce(x, w, b, y, eps):
     x2 = x.reshape(-1, d)
     y2 = y.reshape(-1).astype(jnp.int32)
 
-    if _use_fused(x, w):
+    from .gates import note
+
+    if note("fused_ce", _use_fused(x, w)):
         loss = _fused(x2, w, b, y2, float(eps))
         return loss.reshape(lead)
 

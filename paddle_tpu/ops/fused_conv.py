@@ -45,6 +45,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .kernel_names import named_pallas_call
+
 _INTERPRET = False  # tests flip this to run the kernels on CPU
 
 # conservative per-program VMEM budget: double-buffered x/co/y blocks plus
@@ -234,7 +236,8 @@ def _conv_moments(x2, wt, taps, shift_pad, width):
     nt, o, _ = wt.shape
     kernel = functools.partial(_conv_moments_kernel, taps=taps,
                                shift_pad=shift_pad, width=width, hw=hw)
-    co, s1, s2 = pl.pallas_call(
+    co, s1, s2 = named_pallas_call(
+        "fused_conv.fwd",
         kernel,
         grid=(n,),
         in_specs=[
@@ -278,7 +281,8 @@ def _apply(co, scale, shift, residual, relu):
             res_ref = None
         _apply_kernel(co_ref, sc_ref, sh_ref, res_ref, y_ref, relu=relu)
 
-    return pl.pallas_call(
+    return named_pallas_call(
+        "fused_conv.apply",
         entry,
         grid=(n,),
         in_specs=in_specs,
@@ -316,7 +320,8 @@ def _conv_apply(x2, wt, scale, shift, residual, relu, taps, shift_pad,
             res_ref = None
         kernel(x_ref, w_ref, sc_ref, sh_ref, res_ref, y_ref)
 
-    return pl.pallas_call(
+    return named_pallas_call(
+        "fused_conv.infer",
         entry,
         grid=(n,),
         in_specs=in_specs,

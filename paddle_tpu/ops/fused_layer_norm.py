@@ -20,12 +20,23 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .kernel_names import named_pallas_call
+
 _INTERPRET = False  # tests flip this to run the kernels on CPU
 
 
 def _use_fused(d):
+    """Structured gate (``ops.gates.GateDecision``): the fused kernels, or
+    XLA's composed layer norm with the reason."""
+    from .gates import GateDecision, GateReason
+
+    def refuse(check, detail):
+        return GateDecision(False, "xla_layer_norm",
+                            fallback="fused_layer_norm",
+                            reasons=[GateReason(check, detail)])
+
     if _INTERPRET:
-        return True
+        return GateDecision(True, "fused_layer_norm")
     from ..core.op_registry import env_flag, single_tpu
 
     # OPT-IN (PADDLE_TPU_FUSED_LN=1): measured net-negative on the bench
@@ -34,8 +45,12 @@ def _use_fused(d):
     # the custom call breaks those fusions. Kept for chips/configs where
     # the separate-stats passes dominate.
     if not env_flag("PADDLE_TPU_FUSED_LN"):
-        return False
-    return single_tpu() and d <= 4096
+        return refuse("env", "opt-in: PADDLE_TPU_FUSED_LN is not set")
+    if not single_tpu():
+        return refuse("platform", "the step is not placed on one TPU chip")
+    if d > 4096:
+        return refuse("vmem", "row width %d is over 4096" % d)
+    return GateDecision(True, "fused_layer_norm")
 
 
 def _fwd_kernel(x_ref, g_ref, b_ref, y_ref, mu_ref, var_ref, *, eps, d):
@@ -119,7 +134,8 @@ def _fwd_impl(x, g, b, eps):
             i += 1
         kernel(refs[0], g_ref, b_ref, *refs[i:])
 
-    y, mu, var = pl.pallas_call(
+    y, mu, var = named_pallas_call(
+        "fused_layer_norm.fwd",
         entry,
         grid=(tp // bt,),
         in_specs=in_specs,
@@ -180,7 +196,8 @@ def _bwd_impl(x, g, mu, var, dy, eps):
         kernel(x_ref, g_ref, dy_ref, mu_ref, var_ref, dx_ref, dg_ref,
                db_ref)
 
-    dx, dg, db = pl.pallas_call(
+    dx, dg, db = named_pallas_call(
+        "fused_layer_norm.bwd",
         entry,
         grid=(tp // bt,),
         in_specs=in_specs,

@@ -12,10 +12,18 @@ WHY. A :class:`GateDecision` carries the chosen kernel plus one
   * the static resource pass (``analysis/resources.py``) evaluates the
     SAME gates shape-only (``static_only=True`` skips the platform
     checks) and surfaces refusals as findings with op provenance;
-  * bench records keep reporting the honest kernel name.
+  * bench records keep reporting the honest kernel name;
+  * every gated site also hands its decision to :func:`note`, and
+    ``Executor._stage`` gathers them (:func:`collect`) round the trace of
+    a step: a tag on its ``executor.trace`` span and an entry of the
+    variant's compile record. That count survives the fusion pass, which
+    rewrites the ops ``_kernel_choice`` is recorded on.
 """
 
-__all__ = ["GateReason", "GateDecision"]
+import contextlib
+import threading
+
+__all__ = ["GateReason", "GateDecision", "collect", "note", "tally"]
 
 
 class GateReason:
@@ -87,3 +95,41 @@ class GateDecision:
 
     def __repr__(self):
         return "GateDecision(%s)" % self.describe()
+
+
+# ---------------------------------------------------------------------------
+# decisions taken during one trace
+# ---------------------------------------------------------------------------
+
+_gathering = threading.local()
+
+
+@contextlib.contextmanager
+def collect():
+    """Gather ``(site, GateDecision)`` of every gated site evaluated on
+    this thread inside the block (an op the autodiff replay traces again
+    is evaluated, and counted, again)."""
+    before = getattr(_gathering, "rows", None)
+    _gathering.rows = rows = []
+    try:
+        yield rows
+    finally:
+        _gathering.rows = before
+
+
+def note(site, decision):
+    """A gated site's decision, handed on to whoever gathers; returns it."""
+    rows = getattr(_gathering, "rows", None)
+    if rows is not None:
+        rows.append((site, decision))
+    return decision
+
+
+def tally(rows):
+    """``{site: {decision as one line: times taken}}`` of gathered rows."""
+    out = {}
+    for site, decision in rows:
+        by = out.setdefault(site, {})
+        line = decision.describe()
+        by[line] = by.get(line, 0) + 1
+    return out

@@ -42,6 +42,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .kernel_names import named_pallas_call
+
 __all__ = ["scatter_add_rows", "gate", "use_pallas", "packed_vmem_bytes"]
 
 _LANES = 128
@@ -164,7 +166,9 @@ def record_choice(op, v, k, n, dtype):
     consuming op's attrs (``_kernel_choice``) — trace-time, so a built
     program carries which kernel its sparse updates actually take and
     why (the ISSUE 15 no-silent-fallback contract)."""
-    decision = gate(v, k, n, dtype)
+    from .gates import note
+
+    decision = note("scatter_add_rows", gate(v, k, n, dtype))
     if op is not None:
         op.attrs["_kernel_choice"] = decision.to_dict()
     return decision
@@ -231,7 +235,8 @@ def _scatter_packed_call(bp, rows, vals, p, k, vp):
                   pl.BlockSpec((vp, p * k), lambda i, rr: (0, 0))],
         out_specs=pl.BlockSpec((vp, p * k), lambda i, rr: (0, 0)),
     )
-    return pl.pallas_call(
+    return named_pallas_call(
+        "pallas_rowbin.scatter",
         functools.partial(_scatter_kernel, chunk=chunk, p=p, k=k, vp=vp),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((vp, p * k), bp.dtype),

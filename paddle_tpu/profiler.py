@@ -3,15 +3,23 @@
 
 TPU-native: jax.profiler XPlane traces (viewable in TensorBoard/Perfetto —
 the chrome-trace parity) + a lightweight host-event aggregator giving the
-reference's sorted-table report."""
+reference's sorted-table report.
+
+This is the module that gives the program's spans (``obs.trace.span``:
+``Executor.run``'s phases, the serving path, ``record_event``) their second
+sink: while a ``jax.profiler`` trace is being taken, by whoever started it,
+each span is also a ``jax.profiler.TraceAnnotation`` named
+``paddle_tpu.<span>`` on the host plane of that trace, on the clock the
+device events are on."""
 
 import bisect
 import contextlib
 import threading
-import time
 from collections import defaultdict, deque
 
 import jax
+
+from .obs import trace as obs_trace
 
 __all__ = ["profiler", "start_profiler", "stop_profiler", "reset_profiler",
            "record_event", "Histogram"]
@@ -22,6 +30,19 @@ _events = defaultdict(lambda: [0.0, 0])  # name -> [total_s, count]
 _events_lock = threading.Lock()
 _trace_dir = None
 _enabled = False
+
+SPAN_PREFIX = "paddle_tpu."
+
+
+def _annotation(name):
+    """The span primitive's profiler sink: None while no trace is taken
+    (one C++ flag read), else the annotation to enter."""
+    if jax.profiler.TraceAnnotation.is_enabled():
+        return jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
+    return None
+
+
+obs_trace.install_annotator(_annotation)
 
 
 def start_profiler(state="All", trace_dir=None):
@@ -72,18 +93,20 @@ def _report(sorted_key="total"):
 
 @contextlib.contextmanager
 def record_event(name):
-    """RAII host event (ref ``RecordEvent`` ``profiler.h:41``); also opens a
-    jax.named_scope so the device trace carries the same label."""
-    t0 = time.perf_counter()
+    """RAII host event (ref ``RecordEvent`` ``profiler.h:41``): a span of
+    the program like any other (``obs.trace.span``: the tracer, the
+    profiler's trace), whose duration also feeds the sorted-table report
+    while the profiler is on; also opens a jax.named_scope so the device
+    trace carries the same label."""
+    sp = obs_trace.span(name, timed=_enabled)
     try:
-        with jax.named_scope(name.replace("/", "_")):
+        with sp, jax.named_scope(name.replace("/", "_")):
             yield
     finally:
-        if _enabled:
-            dt = time.perf_counter() - t0
+        if _enabled and sp:
             with _events_lock:
                 ev = _events[name]
-                ev[0] += dt
+                ev[0] += sp.duration
                 ev[1] += 1
 
 

@@ -11,9 +11,8 @@ warm-up mean a shape leaked past the bucketing). Exposed three ways:
     do not rename);
   * ``report()`` — a formatted table shaped like ``profiler._report``;
   * ``prometheus_text()`` — the registry's Prometheus exposition (every
-    counter under ``paddle_tpu_serving_*``, gauges, latency summaries)
-    plus the live MFU gauge, served from the router's ping path and the
-    worker ``stats`` verb.
+    counter under ``paddle_tpu_serving_*``, gauges, latency summaries),
+    served from the router's ping path and the worker ``stats`` verb.
 
 Every counter is a named :class:`~paddle_tpu.obs.registry.Counter` in a
 per-instance :class:`~paddle_tpu.obs.registry.Registry` — the observe_*
@@ -21,7 +20,6 @@ API and snapshot shape are unchanged from the pre-registry version.
 """
 
 from ..obs.registry import Registry
-from ..obs.registry import MFU as _MFU
 from ..profiler import Histogram
 
 __all__ = ["ServingMetrics"]
@@ -272,14 +270,8 @@ class ServingMetrics:
         return snap
 
     def prometheus_text(self):
-        """Prometheus exposition: this instance's registry plus the
-        process-wide MFU/roofline gauge (populated when ``Executor.run``
-        executes under tracing)."""
-        text = self.registry.prometheus_text()
-        mfu = _MFU.prometheus_lines()
-        if mfu:
-            text += "\n".join(mfu) + "\n"
-        return text
+        """Prometheus exposition of this instance's registry."""
+        return self.registry.prometheus_text()
 
     def report(self):
         """Formatted table in the ``profiler._report`` house style."""

@@ -1,5 +1,7 @@
 """Telemetry plane (ISSUE 17): tracing, metrics registry, flight
-recorder, and the live MFU gauge.
+recorder; and (ISSUE 24) the Executor's own spans through the one span
+primitive, on the obs tracer and on a ``jax.profiler`` trace, with the
+compile record beside them.
 
 The expensive acceptance drills live here too: one ``RouterClient.
 predict`` against a REAL router subprocess must produce ONE stitched
@@ -22,7 +24,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.obs import flight, trace
-from paddle_tpu.obs.registry import MFU, Registry
+from paddle_tpu.obs.registry import Registry
 from paddle_tpu.serving import (DeadlineExceededError, Router, RouterClient,
                                 ServerOverloadedError, WorkerFailedError)
 from paddle_tpu.serving import rpc
@@ -247,13 +249,7 @@ def test_prometheus_exposition_grammar():
     m.observe_batch(actual=4, bucket=8, cache_hit=False)
     m.observe_decode_step(live=3, bucket=4, generated=3)
     m.bind_gauges(lambda: 2, lambda: 5)
-    MFU.reset()
-    MFU.record(0.004, {"roofline_s": 0.002, "flops": 1e9, "bound": "hbm",
-                       "ceilings": {"matmul_flops": 1e12}})
-    try:
-        text = m.prometheus_text()
-    finally:
-        MFU.reset()
+    text = m.prometheus_text()
     assert text.endswith("\n")
     for line in text.rstrip("\n").split("\n"):
         assert _PROM_LINE.match(line), "bad exposition line: %r" % line
@@ -262,8 +258,7 @@ def test_prometheus_exposition_grammar():
     assert "paddle_tpu_serving_in_flight 5" in text
     assert 'paddle_tpu_serving_latency_seconds{quantile="0.5"}' in text
     assert "paddle_tpu_serving_latency_seconds_count 2" in text
-    assert "paddle_tpu_mfu_vs_model 0.5" in text
-    assert "paddle_tpu_mfu " in text
+    assert "paddle_tpu_mfu" not in text  # the gauge is gone (ISSUE 24)
     # a TYPE line precedes every sample family
     assert text.index("# TYPE paddle_tpu_serving_requests_completed "
                       "counter") < text.index(
@@ -342,58 +337,409 @@ def test_registry_snapshot_consistency():
         "spec_accepted", "spec_rejected", "spec_accept_rate"}
 
 
-# -- MFU gauge vs the static cost model -------------------------------------
+# -- the Executor's own spans, through the one primitive (ISSUE 24) ----------
 
-def test_mfu_gauge_agrees_with_static_model_on_fc_program():
-    import paddle_tpu as fluid
-    from paddle_tpu.analysis.cost import estimate_program
-
-    main_prog, startup = fluid.Program(), fluid.Program()
-    main_prog.random_seed = startup.random_seed = 11
-    scope = fluid.Scope()
-    with fluid.program_guard(main_prog, startup), fluid.scope_guard(scope):
-        fluid.unique_name.switch()
-        x = fluid.layers.data("x", shape=[8])
-        prob = fluid.layers.softmax(fluid.layers.fc(x, size=4))
-        exe = fluid.Executor(fluid.XLAPlace(0))
-        exe.run(startup)
-        feed = {"x": np.full((8, 8), 0.5, "float32")}
-        MFU.reset()
-        trace.start()
-        try:
-            for _ in range(3):
-                exe.run(main_prog, feed=feed, fetch_list=[prob])
-        finally:
-            trace.stop()
-    snap = MFU.snapshot()
-    MFU.reset()
-    assert snap["steps"] == 3
-    expected = estimate_program(
-        main_prog, batch=8, feed_names=["x"]).roofline()
-    # the recorded roofline is EXACTLY the static model's (same code
-    # path), so model-vs-measured agreement is what the gauge adds
-    assert snap["roofline_s"] / 3 == pytest.approx(
-        expected["roofline_s"], rel=1e-9)
-    assert snap["measured_s"] > 0
-    assert snap["mfu_vs_model"] > 0
-    assert 0 < snap["mfu"] < 1  # tiny fc on CPU is nowhere near peak
+PHASES = ["executor.prepare", "executor.feed_put", "executor.dispatch",
+          "executor.writeback"]
+STAGES = ["executor.trace", "executor.lower", "executor.backend_compile"]
 
 
-def test_executor_records_no_mfu_when_tracing_disabled():
+def _fc_executor(width=8):
+    """An executor that has run the startup program of a tiny fc model
+    (conftest gives every test fresh default programs and scope)."""
     import paddle_tpu as fluid
 
-    main_prog, startup = fluid.Program(), fluid.Program()
-    scope = fluid.Scope()
-    with fluid.program_guard(main_prog, startup), fluid.scope_guard(scope):
-        fluid.unique_name.switch()
-        x = fluid.layers.data("x", shape=[4])
-        y = fluid.layers.fc(x, size=2)
-        exe = fluid.Executor(fluid.XLAPlace(0))
-        exe.run(startup)
-        MFU.reset()
-        exe.run(main_prog, feed={"x": np.ones((2, 4), "float32")},
-                fetch_list=[y])
-    assert MFU.snapshot() == {"steps": 0}
+    x = fluid.layers.data("x", shape=[width])
+    out = fluid.layers.fc(x, size=4)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    feed = {"x": np.full((2, width), 0.5, "float32")}
+    return exe, feed, out
+
+
+def _ticking_clock():
+    """A fake clock that moves by one second at every reading: spans that
+    follow each other read consecutive numbers."""
+    import itertools
+
+    ticks = itertools.count()
+    return lambda: float(next(ticks))
+
+
+def test_executor_phases_nest_under_run_and_cover_it():
+    exe, feed, out = _fc_executor()
+    tracer = trace.start(clock=_ticking_clock())
+    try:
+        exe.run(feed=feed, fetch_list=[out])  # stages the variant
+        exe.run(feed=feed, fetch_list=[out])  # finds it compiled
+    finally:
+        trace.stop()
+    runs = [s for s in tracer.spans if s["name"] == "executor.run"]
+    assert [r["tags"]["variant_hit"] for r in runs] == [False, True]
+    assert runs[1]["tags"]["ordinal"] == runs[0]["tags"]["ordinal"] + 1
+    expected = [PHASES[:2] + STAGES + PHASES[2:], PHASES]
+    for run, names in zip(runs, expected):
+        kids = sorted((s for s in tracer.spans
+                       if s["parent_id"] == run["span_id"]),
+                      key=lambda s: s["t0"])
+        assert [k["name"] for k in kids] == names
+        # the clock is read by the spans alone, one tick a reading: the
+        # children follow each other without a gap and leave the run span
+        # nothing but its own two readings
+        assert kids[0]["t0"] == run["t0"] + 1
+        for before, after in zip(kids, kids[1:]):
+            assert after["t0"] == before["t0"] + before["dur"] + 1
+        assert kids[-1]["t0"] + kids[-1]["dur"] + 1 == run["t0"] + run["dur"]
+        assert all(k["trace_id"] == run["trace_id"] for k in kids)
+    by_name = {s["name"]: s for s in tracer.spans
+               if s["parent_id"] == runs[1]["span_id"]}
+    assert by_name["executor.feed_put"]["tags"] == {
+        "bytes": feed["x"].nbytes, "state_relayouts": 0}
+    assert by_name["executor.writeback"]["tags"] == {"fetch": "numpy"}
+
+
+def _host_events(trace_dir, prefix="paddle_tpu."):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    return [(ev.name, ev.start_ns, ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith(prefix)]
+
+
+def test_executor_spans_reach_a_jax_profiler_trace(tmp_path):
+    """With no tracer at all: whoever takes a jax.profiler trace finds the
+    program's spans on its host plane, children inside their run."""
+    import jax
+
+    exe, feed, out = _fc_executor()
+    exe.run(feed=feed, fetch_list=[out])
+    assert trace.active() is None
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            exe.run(feed=feed, fetch_list=[out], return_numpy=False)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    runs = [e for e in events if e[0] == "paddle_tpu.executor.run"]
+    assert len(runs) == 3
+    for phase in PHASES:
+        found = [e for e in events if e[0] == "paddle_tpu." + phase]
+        assert len(found) == 3, phase
+        for (_, start, dur), (_, r0, rdur) in zip(sorted(found, key=lambda
+                                                          e: e[1]),
+                                                  sorted(runs, key=lambda
+                                                         e: e[1])):
+            assert r0 <= start and start + dur <= r0 + rdur, phase
+    # and once the trace is stopped a span is the no-op again
+    assert not trace.span("executor.run")
+
+
+def test_no_span_path_waits_for_the_device(tmp_path, monkeypatch):
+    """Tracing must not change the schedule it observes: with a tracer and
+    a profiler trace both live, a run that fetches device arrays never
+    blocks."""
+    import inspect
+
+    import jax
+
+    from paddle_tpu import profiler
+    from paddle_tpu.core import executor as executor_mod
+
+    exe, feed, out = _fc_executor()
+
+    def refuse(*a, **k):
+        raise AssertionError("a span path waited for the device")
+
+    monkeypatch.setattr(jax, "block_until_ready", refuse)
+    trace.start()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            got, = exe.run(feed=feed, fetch_list=[out], return_numpy=False)
+        with profiler.record_event("stage"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+        trace.stop()
+    assert isinstance(got, jax.Array)
+    for module in (executor_mod, trace, profiler):
+        assert "block_until_ready" not in inspect.getsource(module)
+
+
+def test_spans_of_one_run_cost_nothing_retained_and_under_50us_when_off():
+    """No tracer, no profiler trace: the spans one exe.run opens (the run,
+    its four phases, tags guarded as the executor guards them) allocate
+    nothing that stays and take well under 50 microseconds together."""
+    from paddle_tpu import profiler
+
+    assert trace.active() is None
+
+    def one_run():
+        with trace.span("executor.run") as run_sp:
+            with trace.span("executor.prepare"):
+                pass
+            if run_sp:
+                run_sp.set(ordinal=1, variant_hit=True)
+            with trace.span("executor.feed_put") as sp:
+                if sp:
+                    sp.set(bytes=1)
+            with trace.span("executor.dispatch"):
+                pass
+            with trace.span("executor.writeback") as sp:
+                if sp:
+                    sp.set(fetch="device")
+
+    def loop(n=2000):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            one_run()
+        return (time.perf_counter() - t0) / n
+
+    loop(200)
+    tracemalloc.start()
+    try:
+        before = tracemalloc.take_snapshot()
+        loop(200)
+        after = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    leaks = [s for s in after.compare_to(before, "lineno")
+             if s.traceback[0].filename in (trace.__file__,
+                                            profiler.__file__)
+             and s.size_diff > 0]
+    assert not leaks, "spans that are off allocated: %s" % leaks
+    per_run = min(loop() for _ in range(3))
+    assert per_run < 50e-6, "%.1f us a run" % (per_run * 1e6)
+
+
+def test_lowered_hlo_text_after_run_traces_lowers_and_compiles_nothing():
+    import jax.monitoring
+
+    exe, feed, out = _fc_executor()
+    exe.run(feed=feed, fetch_list=[out])
+    seen = []
+    listening = [True]
+
+    def on(event, duration, **kwargs):
+        if listening[0]:
+            seen.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        compiled = exe.lowered_hlo_text(optimized=True)
+        stablehlo = exe.lowered_hlo_text(optimized=False)
+    finally:
+        listening[0] = False
+    assert "HloModule" in compiled and "stablehlo" in stablehlo
+    assert not [e for e in seen if "jaxpr_trace_duration" in e
+                or "jaxpr_to_mlir" in e or "backend_compile" in e], seen
+
+
+def test_compile_record_fields_and_counters_hit_and_miss():
+    exe, feed, out = _fc_executor()
+    assert (exe.runs, exe.variant_hits, exe.variant_misses) == (1, 0, 1)
+    for _ in range(3):
+        exe.run(feed=feed, fetch_list=[out])
+    assert (exe.runs, exe.variant_hits, exe.variant_misses) == (4, 2, 2)
+    other = {"x": np.ones((5, 8), "float32")}  # another batch: a variant
+    exe.run(feed=other, fetch_list=[out])
+    assert (exe.runs, exe.variant_hits, exe.variant_misses) == (5, 2, 3)
+    assert exe.state_relayouts == 0
+    startup, first, second = exe.compile_records
+    assert startup["fetch_names"] == [] and startup["ordinal"] == 0
+    for record, ordinal in ((first, 1), (second, 4)):
+        assert record["fetch_names"] == [out.name]
+        assert record["feed_names"] == ["x"]
+        assert record["ordinal"] == ordinal and not record["restaged"]
+        assert record["ops"] > 0 and record["meshed"] is False
+        for phase in ("trace_s", "lower_s", "backend_compile_s"):
+            assert record[phase] > 0
+        # conftest turns the persistent cache off
+        assert record["persistent_cache"] == "off"
+        assert set(record["memory"]) == {"temp_bytes", "argument_bytes",
+                                         "output_bytes", "alias_bytes"}
+        assert record["memory"]["argument_bytes"] > 0
+        assert record["gates"] == {}
+    # the records outlive close(); the kept executable does not
+    exe.close()
+    assert len(exe.compile_records) == 3
+    with pytest.raises(RuntimeError):
+        exe.lowered_hlo_text()
+
+
+def test_compile_record_says_whether_the_persistent_cache_served(tmp_path):
+    import jax
+    import paddle_tpu as fluid
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in keys}
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    cc.reset_cache()
+    try:
+        x = fluid.layers.data("x", shape=[6])
+        out = fluid.layers.fc(x, size=3)
+        feed = {"x": np.ones((2, 6), "float32")}
+        served = []
+        for _ in range(2):  # a second executor stages the same step anew
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(fluid.default_startup_program())
+            exe.run(feed=feed, fetch_list=[out])
+            served.append(exe.compile_records[-1]["persistent_cache"])
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    assert served == ["miss", "hit"]
+
+
+def test_record_event_is_a_span_of_the_same_primitive():
+    from paddle_tpu import profiler
+
+    profiler.reset_profiler()
+    tracer = trace.start(clock=_ticking_clock())
+    try:
+        with trace.span("outer"):
+            with profiler.record_event("stage/a"):
+                pass
+    finally:
+        trace.stop()
+    inner, outer = tracer.spans
+    assert inner["name"] == "stage/a" and outer["name"] == "outer"
+    assert inner["parent_id"] == outer["span_id"]
+    assert "stage/a" not in profiler._report()  # the profiler is off
+    # the table is fed from the span's own duration, tracer or not
+    profiler.start_profiler()
+    try:
+        with profiler.record_event("stage/b"):
+            time.sleep(0.002)
+    finally:
+        report = profiler.stop_profiler(silent=True)
+    row, = [line.split() for line in report.splitlines()
+            if line.startswith("stage/b")]
+    assert row[1] == "1" and float(row[2]) >= 2.0
+    profiler.reset_profiler()
+
+
+@pytest.mark.parametrize("site", ["fused_ce", "fused_layer_norm"])
+def test_fused_ce_and_layer_norm_gates_answer_with_their_reason(
+        site, monkeypatch):
+    import jax.numpy as jnp
+    from paddle_tpu.core.op_registry import placed
+    from paddle_tpu.ops import fused_ce, fused_layer_norm
+    from paddle_tpu.ops.gates import GateDecision
+
+    for env in ("PADDLE_TPU_FUSED_LN", "PADDLE_TPU_FUSED_CE",
+                "PADDLE_TPU_NO_FUSED_CE"):
+        monkeypatch.delenv(env, raising=False)
+    if site == "fused_ce":
+        w = jnp.zeros((512, 30000), jnp.bfloat16)
+        small = jnp.zeros((128 * 256, 512), jnp.bfloat16)  # cell 1's head
+        large = jnp.zeros((256 * 256, 512), jnp.bfloat16)
+        here = fused_ce._use_fused(small, w)
+        assert isinstance(here, GateDecision) and not here
+        assert here.blocked_only_by("platform")
+        with placed("tpu"):
+            refused = fused_ce._use_fused(small, w)
+            admitted = fused_ce._use_fused(large, w)
+        assert not refused and refused.kernel == "xla_projection_ce"
+        assert refused.blocked_only_by("size")
+        assert "9.83e+08 logits" in refused.describe()
+        assert admitted and admitted.kernel == "fused_ce"
+        monkeypatch.setenv("PADDLE_TPU_NO_FUSED_CE", "1")
+        with placed("tpu"):
+            assert fused_ce._use_fused(large, w).blocked_only_by("env")
+    else:
+        with placed("tpu"):
+            opt_in = fused_layer_norm._use_fused(512)
+        assert isinstance(opt_in, GateDecision) and not opt_in
+        assert opt_in.blocked_only_by("env")
+        monkeypatch.setenv("PADDLE_TPU_FUSED_LN", "1")
+        assert fused_layer_norm._use_fused(512).blocked_only_by("platform")
+        with placed("tpu"):
+            assert fused_layer_norm._use_fused(512).kernel == \
+                "fused_layer_norm"
+            assert fused_layer_norm._use_fused(8192).blocked_only_by("vmem")
+
+
+def test_gate_decisions_ride_the_trace_span_and_the_compile_record():
+    import paddle_tpu as fluid
+
+    x = fluid.layers.data("x", shape=[4, 16])
+    out = fluid.layers.layer_norm(x, begin_norm_axis=2)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    tracer = trace.start()
+    try:
+        exe.run(feed={"x": np.ones((2, 4, 16), "float32")},
+                fetch_list=[out])
+    finally:
+        trace.stop()
+    staged, = [s for s in tracer.spans if s["name"] == "executor.trace"]
+    gates = staged["tags"]["gates"]
+    assert list(gates) == ["layer_norm"]
+    (line, times), = gates["layer_norm"].items()
+    assert line.startswith("fell back to xla_layer_norm") and times == 1
+    assert exe.compile_records[-1]["gates"] == gates
+    compiled, = [s for s in tracer.spans
+                 if s["name"] == "executor.backend_compile"]
+    assert compiled["tags"] == {"persistent_cache": "off"}
+
+
+def test_replaced_state_array_is_staged_again_not_refused():
+    """A state array that no longer has the type the variant was staged
+    for: jit would retrace; the kept executable cannot, so the executor
+    stages the variant again and says so in its record."""
+    import jax.numpy as jnp
+    import paddle_tpu as fluid
+
+    exe, feed, out = _fc_executor()
+    want, = exe.run(feed=feed, fetch_list=[out])
+    scope = fluid.global_scope()
+    weight = fluid.default_main_program().global_block() \
+        .all_parameters()[0].name
+    scope.set(weight, jnp.asarray(scope.get(weight), jnp.bfloat16))
+    got, = exe.run(feed=feed, fetch_list=[out])
+    np.testing.assert_allclose(got, want, rtol=2e-2)
+    assert [r["restaged"] for r in exe.compile_records] == [
+        False, False, True]
+
+
+def test_state_relayouts_counts_the_arrays_a_call_had_to_move():
+    """A scope filled by a one-device startup run, then a step over a
+    four-device mesh: the first call lays every state array out over the
+    mesh, a steady loop moves none."""
+    import jax
+    import paddle_tpu as fluid
+
+    x = fluid.layers.data("x", shape=[8])
+    loss = fluid.layers.mean(fluid.layers.fc(x, size=4))
+    fluid.optimizer.SGD(0.1).minimize(loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    meshed = fluid.CompiledProgram(
+        fluid.default_main_program()).with_data_parallel(
+        loss_name=loss.name, places=jax.devices("cpu")[:4])
+    feed = {"x": np.ones((8, 8), "float32")}
+    exe.run(meshed, feed=feed, fetch_list=[loss])
+    moved = exe.state_relayouts
+    assert moved >= 2  # the fc's weight and bias at the least
+    for _ in range(2):
+        exe.run(meshed, feed=feed, fetch_list=[loss])
+    assert exe.state_relayouts == moved
+    assert exe.compile_records[-1]["meshed"] is True
 
 
 # -- flight recorder --------------------------------------------------------

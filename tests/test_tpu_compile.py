@@ -16,6 +16,7 @@ time.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -68,6 +69,32 @@ def _kernel_calls(compiled):
     return compiled.as_text().count("tpu_custom_call")
 
 
+_CUSTOM_CALL = re.compile(
+    r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*custom_call_target=\"tpu_custom_call\""
+    r".*?op_name=\"([^\"]*)\"", re.M)
+
+
+def _kernel_names(compiled):
+    """[(HLO instruction name, op_name path)] of the compiled module's
+    Pallas calls. A device event on the chip is called by the first; the
+    benchmark joins it to the second."""
+    return _CUSTOM_CALL.findall(compiled.as_text())
+
+
+def _assert_named(compiled, expected):
+    """Every Pallas call of the compiled module carries one of ``expected``
+    (``<family>.<part>``): its instruction is called ``<name>.<n>``
+    and its op_name path holds ``<name>`` as a component; and each
+    expected name is there."""
+    found = set()
+    for instruction, op_name in _kernel_names(compiled):
+        name, = [e for e in expected
+                 if instruction == e or instruction.startswith(e + ".")]
+        assert name in op_name.split("/"), (name, op_name)
+        found.add(name)
+    assert found == set(expected)
+
+
 def sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
@@ -113,6 +140,7 @@ def test_flash_attention_compiles(chip, shape, causal, with_bias, plan):
     compiled = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
                         x, x, x, bias, x, sds((2,), jnp.uint32))
     assert _kernel_calls(compiled) == 2  # one forward, one fused backward
+    _assert_named(compiled, {plan + ".fwd", plan + ".bwd"})
 
 
 def test_packed_stream_gate_counts_what_mosaic_allocates():
@@ -140,6 +168,7 @@ def test_fused_ce_compiles(chip):
                         sds((t, d), BF16), sds((d, v), BF16),
                         sds((v,), BF16), sds((t,), I32))
     assert _kernel_calls(compiled) >= 1  # fwd kernel; bwd is an XLA scan
+    _assert_named(compiled, {"fused_ce.fwd"})
 
 
 def test_fused_layer_norm_compiles(chip):
@@ -152,6 +181,7 @@ def test_fused_layer_norm_compiles(chip):
     compiled = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
                         sds((t, d), BF16), sds((d,), F32), sds((d,), F32))
     assert _kernel_calls(compiled) == 2
+    _assert_named(compiled, {"fused_layer_norm.fwd", "fused_layer_norm.bwd"})
 
 
 # ---------------------------------------------------------------------------
@@ -194,6 +224,7 @@ def test_fused_conv_compiles(chip, c, o, ksize, stride, hw, with_res):
                         sds(x_shape, BF16), sds(w_shape, BF16), ch, ch, ch,
                         ch, res)
     assert _kernel_calls(compiled) == 2  # conv+moments, apply
+    _assert_named(compiled, {"fused_conv.fwd", "fused_conv.apply"})
 
 
 # ---------------------------------------------------------------------------
@@ -212,6 +243,7 @@ def test_scatter_compiles_at_largest_admitted_shape(chip):
     compiled = _compile(chip, scatter.scatter_add_rows,
                         sds((v, k), F32), sds((n,), I32), sds((n, k), F32))
     assert _kernel_calls(compiled) == 1
+    _assert_named(compiled, {"pallas_rowbin.scatter"})
 
 
 @pytest.mark.parametrize("n", [scatter._SMEM_IDS_BYTES // 4 + 1,
@@ -225,6 +257,55 @@ def test_scatter_gate_bounds_prefetched_ids(n):
         decision = scatter.gate(50000, 32, n, "float32")
     assert not decision.admitted and decision.kernel == "xla_at_add"
     assert decision.blocked_only_by("smem"), decision
+
+
+# ---------------------------------------------------------------------------
+# names: what a trace on the chip can tell a kernel by (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+def _attention_grad(b, t, hd, heads):
+    def loss(q, k, v, g):
+        out = fa.flash_attention(q, k, v, heads, causal=True)
+        return jnp.sum(out.astype(F32) * g.astype(F32))
+
+    x = sds((b, t, hd), BF16)
+    return jax.grad(loss, argnums=(0, 1, 2)), (x, x, x, x)
+
+
+def _conv_infer():
+    def infer(x, w, gamma, beta, mean, var):
+        return fused_conv.fused_conv_bn_act(
+            x, w, gamma, beta, mean, var, strides=(1, 1), paddings=(1, 1),
+            eps=1e-5, momentum=0.9, act="relu", is_test=True)[0]
+
+    ch = sds((64,), F32)
+    return infer, (sds((8, 64, 56, 56), BF16), sds((64, 64, 3, 3), BF16),
+                   ch, ch, ch, ch)
+
+
+_NAME_CASES = [
+    # family, (function, abstract arguments), the names its calls carry;
+    # small shapes: a name does not depend on the size
+    ("dense_vmem", lambda: _attention_grad(8, 128, 256, 2),
+     {"dense_vmem.fwd", "dense_vmem.bwd"}),
+    ("packed_stream", lambda: _attention_grad(2, 1024, 256, 2),
+     {"packed_stream.fwd", "packed_stream.bwd"}),
+    ("head_split_stream", lambda: _attention_grad(1, 2048, 512, 8),
+     {"head_split_stream.fwd", "head_split_stream.bwd"}),
+    ("fused_conv_infer", _conv_infer, {"fused_conv.infer"}),
+]
+
+
+@pytest.mark.parametrize("build,names", [c[1:] for c in _NAME_CASES],
+                         ids=[c[0] for c in _NAME_CASES])
+def test_compiled_hlo_carries_each_kernel_family_by_name(chip, build, names):
+    """The v5e-compiled step names every Pallas call ``<family>.<part>``,
+    in the instruction's own name and in its op_name path, so that a trace
+    tells forward from backward and one family from another. (The fused
+    CE, layer norm, train-mode conv and scatter kernels are asserted where
+    they are compiled above.)"""
+    fn, avals = build()
+    _assert_named(_compile(chip, fn, *avals), names)
 
 
 # ---------------------------------------------------------------------------
