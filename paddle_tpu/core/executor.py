@@ -918,16 +918,18 @@ class Executor:
         this call, each stage once and under its span, keep what ``run``
         calls and ``lowered_hlo_text`` reads, and append the variant's
         compile record."""
-        from ..ops import gates
+        from ..ops import gates, kernel_names
 
         hits, misses = _cache_events[_CACHE_HIT], _cache_events[_CACHE_MISS]
         t0 = time.perf_counter()
         with obs_trace.span("executor.trace") as sp, \
-                gates.collect() as met:
+                gates.collect() as met, \
+                kernel_names.collect_traces() as bodies:
             traced = entry.jfn.trace(state, feed_arrays, rng)
             decisions = gates.tally(met)
+            kernel_traces = kernel_names.tally_traces(bodies)
             if sp:
-                sp.set(gates=decisions)
+                sp.set(gates=decisions, kernel_traces=kernel_traces)
         t1 = time.perf_counter()
         with obs_trace.span("executor.lower"):
             entry.lowered = traced.lower()
@@ -947,6 +949,7 @@ class Executor:
             entry.about, ordinal=ordinal, restaged=restaged,
             trace_s=t1 - t0, lower_s=t2 - t1, backend_compile_s=t3 - t2,
             persistent_cache=cache, gates=decisions,
+            kernel_traces=kernel_traces,
             memory=None if memory is None else {
                 "temp_bytes": int(memory.temp_size_in_bytes),
                 "argument_bytes": int(memory.argument_size_in_bytes),

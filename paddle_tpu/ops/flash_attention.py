@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel_names import named_pallas_call
+from .kernel_names import named_pallas_call, traced_once
 
 _INTERPRET = False  # tests flip this to run kernels on CPU
 
@@ -389,14 +389,25 @@ def _block_sizes(t, t_k, bwd=False):
     return min(cap, r(t)), min(cap, r(t_k))
 
 
-def _flash_fwd_impl(q, k, v, bias, seed, causal, scale, dropout_rate):
-    """q,k,v: [BH, T, D]; bias [BH, Tk] additive per-key or None.
+def _bwd_block_sizes(t, t_k, dropout_rate):
+    """Block sizes of a streaming backward. Dropout masks regenerate per
+    (bh, q-block, k-block) tile, so it may only use other block sizes
+    than the forward when dropout is off."""
+    return _block_sizes(t, t_k, bwd=(dropout_rate == 0.0))
+
+
+@traced_once("head_split_stream.fwd",
+             ("causal", "scale", "dropout_rate", "blocks", "interpret"))
+def _flash_fwd_impl(q, k, v, bias, seed, causal, scale, dropout_rate,
+                    blocks, interpret):
+    """q,k,v: [BH, T, D]; bias [BH, Tk] additive per-key or None;
+    ``blocks``: :func:`_block_sizes` of the forward.
     Returns (out [BH, T, D], lse [BH, T])."""
     from jax.experimental import pallas as pl
 
     bh, t, d = q.shape
     t_k = k.shape[1]
-    block_q, block_k = _block_sizes(t, t_k)
+    block_q, block_k = blocks
     qp, kp, vp = _pad_t(q, block_q), _pad_t(k, block_k), _pad_t(v, block_k)
     t_pad, tk_pad = qp.shape[1], kp.shape[1]
 
@@ -440,21 +451,21 @@ def _flash_fwd_impl(q, k, v, bias, seed, causal, scale, dropout_rate):
             jax.ShapeDtypeStruct((bh, t_pad, d), q.dtype),
             jax.ShapeDtypeStruct((bh, 8, t_pad), jnp.float32),
         ],
-        interpret=_INTERPRET,
+        interpret=interpret,
     )(*args)
     return out[:, :t], lse[:, 0, :t]
 
 
-def _flash_bwd_impl(q, k, v, bias, seed, causal, scale, dropout_rate,
-                    out, lse, do):
+@traced_once("head_split_stream.bwd",
+             ("causal", "scale", "dropout_rate", "blocks", "interpret"))
+def _flash_bwd_impl(q, k, v, bias, seed, out, lse, do, causal, scale,
+                    dropout_rate, blocks, interpret):
+    """``blocks``: :func:`_bwd_block_sizes`."""
     from jax.experimental import pallas as pl
 
     bh, t, d = q.shape
     t_k = k.shape[1]
-    # dropout masks regenerate per (bh, q-block, k-block) tile: the bwd
-    # may only use different block sizes than fwd when dropout is off
-    block_q, block_k = _block_sizes(t, t_k,
-                                    bwd=(dropout_rate == 0.0))
+    block_q, block_k = blocks
     qp, kp, vp = _pad_t(q, block_q), _pad_t(k, block_k), _pad_t(v, block_k)
     dop = _pad_t(do, block_q)
     t_pad, tk_pad = qp.shape[1], kp.shape[1]
@@ -535,7 +546,7 @@ def _flash_bwd_impl(q, k, v, bias, seed, causal, scale, dropout_rate,
         in_specs=in_specs2,
         out_specs=out_specs2,
         out_shape=out_shape2,
-        interpret=_INTERPRET,
+        interpret=interpret,
     )(*args2)
     if biasp is not None:
         dk, dv, db, dq = res
@@ -765,15 +776,19 @@ def _packed_bwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, do_ref,
             db_total[:, 0].astype(db_ref.dtype)
 
 
+@traced_once("packed_stream.fwd",
+             ("num_heads", "causal", "scale", "dropout_rate", "blocks",
+              "interpret"))
 def _packed_stream_fwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
-                            dropout_rate):
-    """q,k,v: packed [B, T, H*D]; bias [B, Tk] or None.
+                            dropout_rate, blocks, interpret):
+    """q,k,v: packed [B, T, H*D]; bias [B, Tk] or None; ``blocks``:
+    :func:`_block_sizes` of the forward.
     Returns (out [B, T, H*D], lse [B, nh_pad, T])."""
     from jax.experimental import pallas as pl
 
     b, t, hd = q.shape
     t_k = k.shape[1]
-    block_q, block_k = _block_sizes(t, t_k)
+    block_q, block_k = blocks
     qp, kp, vp = _pad_t(q, block_q), _pad_t(k, block_k), _pad_t(v, block_k)
     t_pad, tk_pad = qp.shape[1], kp.shape[1]
     nh_pad = max(num_heads, 8)
@@ -816,19 +831,23 @@ def _packed_stream_fwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
             jax.ShapeDtypeStruct((b, t_pad, hd), q.dtype),
             jax.ShapeDtypeStruct((b, nh_pad, t_pad), jnp.float32),
         ],
-        interpret=_INTERPRET,
+        interpret=interpret,
     )(*args)
     return out[:, :t], lse[:, :, :t]
 
 
-def _packed_stream_bwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
-                            dropout_rate, out, lse, do):
+@traced_once("packed_stream.bwd",
+             ("num_heads", "causal", "scale", "dropout_rate", "blocks",
+              "interpret"))
+def _packed_stream_bwd_impl(q, k, v, bias, seed, out, lse, do, num_heads,
+                            causal, scale, dropout_rate, blocks, interpret):
+    """``blocks``: :func:`_bwd_block_sizes`."""
     from jax.experimental import pallas as pl
 
     b, t, hd = q.shape
     t_k = k.shape[1]
     d = hd // num_heads
-    block_q, block_k = _block_sizes(t, t_k, bwd=(dropout_rate == 0.0))
+    block_q, block_k = blocks
     qp, kp, vp = _pad_t(q, block_q), _pad_t(k, block_k), _pad_t(v, block_k)
     dop = _pad_t(do, block_q)
     t_pad, tk_pad = qp.shape[1], kp.shape[1]
@@ -911,7 +930,7 @@ def _packed_stream_bwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=_INTERPRET,
+        interpret=interpret,
     )(*args)
     if biasp is not None:
         dk, dv, db, dq = res
@@ -925,23 +944,24 @@ def _packed_stream_bwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def _packed_stream_attention(q, k, v, bias, seed, num_heads, causal, scale,
                              dropout_rate):
-    out, _ = _packed_stream_fwd_impl(q, k, v, bias, seed, num_heads, causal,
-                                     scale, dropout_rate)
-    return out
+    return _packed_stream_fwd(q, k, v, bias, seed, num_heads, causal, scale,
+                              dropout_rate)[0]
 
 
 def _packed_stream_fwd(q, k, v, bias, seed, num_heads, causal, scale,
                        dropout_rate):
-    out, lse = _packed_stream_fwd_impl(q, k, v, bias, seed, num_heads,
-                                       causal, scale, dropout_rate)
+    out, lse = _packed_stream_fwd_impl(
+        q, k, v, bias, seed, num_heads, causal, scale, dropout_rate,
+        _block_sizes(q.shape[1], k.shape[1]), _INTERPRET)
     return out, (q, k, v, bias, seed, out, lse)
 
 
 def _packed_stream_bwd(num_heads, causal, scale, dropout_rate, res, g):
     q, k, v, bias, seed, out, lse = res
     dq, dk, dv, db = _packed_stream_bwd_impl(
-        q, k, v, bias, seed, num_heads, causal, scale, dropout_rate, out,
-        lse, g)
+        q, k, v, bias, seed, out, lse, g, num_heads, causal, scale,
+        dropout_rate, _bwd_block_sizes(q.shape[1], k.shape[1], dropout_rate),
+        _INTERPRET)
     dbias = db.astype(bias.dtype) if bias is not None else None
     return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
             dbias, None)
@@ -967,9 +987,9 @@ def _packed_stream_fits(t, t_k, hd, esize, num_heads, dropout=0.0):
     bwd estimate uses the geometry the backward will ACTUALLY allocate:
     the PADDLE_TPU_FLASH_BLOCK_BWD override engages only when dropout is
     off (fwd/bwd must share block geometry for mask regeneration), so the
-    gate mirrors _packed_stream_bwd_impl's ``bwd=(dropout == 0.0)``."""
+    gate asks :func:`_bwd_block_sizes` as ``_packed_stream_bwd`` does."""
     block_q, block_k = _block_sizes(t, t_k)
-    bq_b, bk_b = _block_sizes(t, t_k, bwd=(dropout == 0.0))
+    bq_b, bk_b = _bwd_block_sizes(t, t_k, dropout)
     nh_pad = max(num_heads, 8)
 
     def pad(x, m):
@@ -1153,15 +1173,17 @@ def _pick_g(b, per_elem_bytes, budget=4 * 1024 * 1024):
     return 1
 
 
+@traced_once("dense_vmem.fwd",
+             ("num_heads", "causal", "scale", "dropout_rate", "interpret"))
 def _dense_fwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
-                    dropout_rate):
+                    dropout_rate, interpret):
     """q,k,v: packed [B, T, H*D]; bias [B, Tk] or None.
     Returns (out [B, T, H*D], lse [B, H, T_pad])."""
     from jax.experimental import pallas as pl
 
     b, t, hd = q.shape
     t_k = k.shape[1]
-    m = 8 if _INTERPRET else 128
+    m = 8 if interpret else 128
     qp = _pad_last(q, m)
     kp, vp = _pad_last(k, m), _pad_last(v, m)
     t_pad, tk_pad = qp.shape[1], kp.shape[1]
@@ -1205,18 +1227,20 @@ def _dense_fwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
             jax.ShapeDtypeStruct((b, t_pad, hd), q.dtype),
             jax.ShapeDtypeStruct((b, nh_pad, t_pad), jnp.float32),
         ],
-        interpret=_INTERPRET,
+        interpret=interpret,
     )(*args)
     return out[:, :t], lse
 
 
-def _dense_bwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
-                    dropout_rate, out, lse, do):
+@traced_once("dense_vmem.bwd",
+             ("num_heads", "causal", "scale", "dropout_rate", "interpret"))
+def _dense_bwd_impl(q, k, v, bias, seed, out, lse, do, num_heads, causal,
+                    scale, dropout_rate, interpret):
     from jax.experimental import pallas as pl
 
     b, t, hd = q.shape
     t_k = k.shape[1]
-    m = 8 if _INTERPRET else 128
+    m = 8 if interpret else 128
     qp, kp, vp = _pad_last(q, m), _pad_last(k, m), _pad_last(v, m)
     dop, outp = _pad_last(do, m), _pad_last(out, m)
     t_pad, tk_pad = qp.shape[1], kp.shape[1]
@@ -1276,7 +1300,7 @@ def _dense_bwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=_INTERPRET,
+        interpret=interpret,
     )(*args)
     if bias is not None:
         dq, dk, dv, db = res
@@ -1290,21 +1314,21 @@ def _dense_bwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def _dense_attention(q, k, v, bias, seed, num_heads, causal, scale,
                      dropout_rate):
-    out, _ = _dense_fwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
-                             dropout_rate)
-    return out
+    return _dense_fwd(q, k, v, bias, seed, num_heads, causal, scale,
+                      dropout_rate)[0]
 
 
 def _dense_fwd(q, k, v, bias, seed, num_heads, causal, scale, dropout_rate):
     out, lse = _dense_fwd_impl(q, k, v, bias, seed, num_heads, causal,
-                               scale, dropout_rate)
+                               scale, dropout_rate, _INTERPRET)
     return out, (q, k, v, bias, seed, out, lse)
 
 
 def _dense_bwd(num_heads, causal, scale, dropout_rate, res, g):
     q, k, v, bias, seed, out, lse = res
-    dq, dk, dv, db = _dense_bwd_impl(q, k, v, bias, seed, num_heads, causal,
-                                     scale, dropout_rate, out, lse, g)
+    dq, dk, dv, db = _dense_bwd_impl(q, k, v, bias, seed, out, lse, g,
+                                     num_heads, causal, scale, dropout_rate,
+                                     _INTERPRET)
     dbias = db.astype(bias.dtype) if bias is not None else None
     return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
             dbias, None)
@@ -1336,21 +1360,21 @@ def _dense_fits(t, t_k, hd, esize):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _flash_attention(q, k, v, bias, seed, causal, scale, dropout_rate):
-    out, _ = _flash_fwd_impl(q, k, v, bias, seed, causal, scale,
-                             dropout_rate)
-    return out
+    return _flash_fwd(q, k, v, bias, seed, causal, scale, dropout_rate)[0]
 
 
 def _flash_fwd(q, k, v, bias, seed, causal, scale, dropout_rate):
-    out, lse = _flash_fwd_impl(q, k, v, bias, seed, causal, scale,
-                               dropout_rate)
+    out, lse = _flash_fwd_impl(
+        q, k, v, bias, seed, causal, scale, dropout_rate,
+        _block_sizes(q.shape[1], k.shape[1]), _INTERPRET)
     return out, (q, k, v, bias, seed, out, lse)
 
 
 def _flash_bwd(causal, scale, dropout_rate, res, g):
     q, k, v, bias, seed, out, lse = res
-    dq, dk, dv, db = _flash_bwd_impl(q, k, v, bias, seed, causal, scale,
-                                     dropout_rate, out, lse, g)
+    dq, dk, dv, db = _flash_bwd_impl(
+        q, k, v, bias, seed, out, lse, g, causal, scale, dropout_rate,
+        _bwd_block_sizes(q.shape[1], k.shape[1], dropout_rate), _INTERPRET)
     dbias = db.astype(bias.dtype) if bias is not None else None
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), \
         dbias, None

@@ -45,7 +45,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .kernel_names import named_pallas_call
+from .kernel_names import named_pallas_call, traced_once
 
 _INTERPRET = False  # tests flip this to run the kernels on CPU
 
@@ -229,7 +229,8 @@ def _w_taps(w):
     return w.transpose(2, 3, 0, 1).reshape(kh * kw, o, c)
 
 
-def _conv_moments(x2, wt, taps, shift_pad, width):
+@traced_once("fused_conv.fwd", ("taps", "shift_pad", "width", "interpret"))
+def _conv_moments(x2, wt, taps, shift_pad, width, interpret):
     from jax.experimental import pallas as pl
 
     n, c, hw = x2.shape
@@ -254,12 +255,13 @@ def _conv_moments(x2, wt, taps, shift_pad, width):
             jax.ShapeDtypeStruct((o, 1), jnp.float32),
             jax.ShapeDtypeStruct((o, 1), jnp.float32),
         ],
-        interpret=_INTERPRET,
+        interpret=interpret,
     )(x2, wt)
     return co, s1[:, 0], s2[:, 0]
 
 
-def _apply(co, scale, shift, residual, relu):
+@traced_once("fused_conv.apply", ("relu", "interpret"))
+def _apply(co, scale, shift, residual, relu, interpret):
     from jax.experimental import pallas as pl
 
     n, o, hw = co.shape
@@ -288,12 +290,14 @@ def _apply(co, scale, shift, residual, relu):
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, o, hw), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, o, hw), co.dtype),
-        interpret=_INTERPRET,
+        interpret=interpret,
     )(*args)
 
 
+@traced_once("fused_conv.infer",
+             ("relu", "taps", "shift_pad", "width", "co_dtype", "interpret"))
 def _conv_apply(x2, wt, scale, shift, residual, relu, taps, shift_pad,
-                width, co_dtype):
+                width, co_dtype, interpret):
     from jax.experimental import pallas as pl
 
     n, c, hw = x2.shape
@@ -327,7 +331,7 @@ def _conv_apply(x2, wt, scale, shift, residual, relu, taps, shift_pad,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, o, hw), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, o, hw), x2.dtype),
-        interpret=_INTERPRET,
+        interpret=interpret,
     )(*args)
 
 
@@ -393,14 +397,16 @@ def _fused_train_fwd_impl(x2, w, gamma, beta, residual, height, width, eps,
     o, c, kh, kw = w.shape
     taps, shift_pad = _tap_geometry(kh, kw, (kh - 1) // 2, (kw - 1) // 2,
                                     width)
-    co, s1, s2 = _conv_moments(x2, _w_taps(w), taps, shift_pad, width)
+    co, s1, s2 = _conv_moments(x2, _w_taps(w), taps, shift_pad, width,
+                               _INTERPRET)
     n = x2.shape[0] * x2.shape[2]
     bm = s1 / n
     bv = jnp.maximum(s2 / n - bm * bm, 0.0)
     inv = jax.lax.rsqrt(bv + eps)
     scale = gamma.astype(jnp.float32) * inv
     shift = beta.astype(jnp.float32) - bm * scale
-    y = _apply(co, scale, shift, residual, relu=(act == "relu"))
+    y = _apply(co, scale, shift, residual, relu=(act == "relu"),
+               interpret=_INTERPRET)
     return y, bm, bv, co
 
 
@@ -449,7 +455,7 @@ def _fused_infer(x2, w, gamma, beta, mean, var, residual, height, width,
     return _conv_apply(x2, _w_taps(w), scale, shift, residual,
                        relu=(act == "relu"), taps=taps,
                        shift_pad=shift_pad, width=width,
-                       co_dtype=x2.dtype)
+                       co_dtype=x2.dtype, interpret=_INTERPRET)
 
 
 def _fused_infer_fwd(x2, w, gamma, beta, mean, var, residual, height, width,

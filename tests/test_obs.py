@@ -567,6 +567,7 @@ def test_compile_record_fields_and_counters_hit_and_miss():
                                          "output_bytes", "alias_bytes"}
         assert record["memory"]["argument_bytes"] > 0
         assert record["gates"] == {}
+        assert record["kernel_traces"] == {}  # no Pallas kernel in the step
     # the records outlive close(); the kept executable does not
     exe.close()
     assert len(exe.compile_records) == 3
@@ -696,6 +697,51 @@ def test_gate_decisions_ride_the_trace_span_and_the_compile_record():
     compiled, = [s for s in tracer.spans
                  if s["name"] == "executor.backend_compile"]
     assert compiled["tags"] == {"persistent_cache": "off"}
+
+
+def test_kernel_traces_ride_the_trace_span_and_the_compile_record():
+    """Two attention layers of one signature, trained: the forward body is
+    traced once for four sites (each layer in the forward pass and in the
+    autodiff replay) and the backward once for two. The same program on
+    the reference path (no kernel) reports nothing."""
+    import jax
+    import paddle_tpu as fluid
+    import paddle_tpu.ops.flash_attention as fa
+
+    x = fluid.layers.data("x", shape=[8, 16])
+    h = x
+    for _ in range(2):
+        h = fluid.layers.multi_head_attention(h, h, h, d_model=16, n_head=2)
+    loss = fluid.layers.mean(h)
+    fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    feed = {"x": np.ones((2, 8, 16), "float32")}
+
+    def staged():
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(fluid.default_startup_program())
+        tracer = trace.start()
+        try:
+            exe.run(feed=feed, fetch_list=[loss])
+        finally:
+            trace.stop()
+        span, = [s for s in tracer.spans if s["name"] == "executor.trace"]
+        assert exe.compile_records[-1]["kernel_traces"] == \
+            span["tags"]["kernel_traces"]
+        return span["tags"]["kernel_traces"]
+
+    assert staged() == {}  # CPU-placed: the gate takes the reference
+    jax.clear_caches()  # "once per process": not what a test before traced
+    fa._INTERPRET = True
+    try:
+        assert staged() == {
+            "dense_vmem.bwd": {"traced": 1, "reused": 1},
+            "dense_vmem.fwd": {"traced": 1, "reused": 3}}
+        # a second executor stages the same step anew: every site reuses
+        assert staged() == {
+            "dense_vmem.bwd": {"traced": 0, "reused": 2},
+            "dense_vmem.fwd": {"traced": 0, "reused": 4}}
+    finally:
+        fa._INTERPRET = False
 
 
 def test_replaced_state_array_is_staged_again_not_refused():

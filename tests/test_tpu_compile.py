@@ -345,24 +345,69 @@ def _abstract_step(build):
 
 _HBM_BYTES = 16 * 1024 ** 3
 
+def _step_kernel_names(compiled):
+    """{``<family>.<part>``: sites} of a compiled step's Pallas calls, by
+    the instruction's own name (``dense_vmem.bwd.20``: what a device event
+    on the chip is called)."""
+    sites = {}
+    for instruction, _ in _kernel_names(compiled):
+        name = re.sub(r"\.\d+$", "", instruction)
+        sites[name] = sites.get(name, 0) + 1
+    return sites
+
+
+def test_transformer_base_step_keeps_its_kernel_sites_and_traces_each_once(
+        chip):
+    """The benchmark's ``tbase.train.s256`` step (128 x 256, no dropout),
+    compiled for one chip: 18 forward and 18 backward attention sites,
+    each instruction still called ``dense_vmem.<part>.<n>``, from at most
+    three traced bodies a part (encoder self, decoder causal self,
+    decoder cross; ISSUE 25)."""
+    from paddle_tpu.core.executor import build_step_fn
+    from paddle_tpu.models import transformer
+    from paddle_tpu.ops.kernel_names import collect_traces, tally_traces
+
+    jax.clear_caches()  # "once per process": not what a test before traced
+    avals, program, loss, persist = _abstract_step(
+        lambda: (transformer.transformer_base(
+            src_vocab=30000, trg_vocab=30000, d_model=512, d_ff=2048,
+            n_head=8, n_layer=6, dropout_rate=0.0, label_smooth_eps=0.1,
+            seq_len=256), 128))
+    step = build_step_fn(program, (loss,), persist)
+    with collect_traces() as bodies:
+        compiled = _compile(chip, step, *avals, donate_argnums=(0,))
+    assert _kernel_calls(compiled) == 36
+    assert _step_kernel_names(compiled) == {"dense_vmem.fwd": 18,
+                                            "dense_vmem.bwd": 18}
+    traces = tally_traces(bodies)
+    # each forward site is traced in the forward pass and in the replay
+    assert {k: v["traced"] + v["reused"] for k, v in traces.items()} == {
+        "dense_vmem.fwd": 36, "dense_vmem.bwd": 18}
+    assert all(1 <= v["traced"] <= 3 for v in traces.values()), traces
+
+
 _STEP_CASES = [
-    # id, model, seq override, has Pallas kernels, attention plan
-    ("transformer_b128_s256", "transformer", None, True, "dense_vmem"),
-    ("bert_b128_s128", "bert", None, True, "dense_vmem"),
-    ("resnet50_b128", "resnet50", None, True, None),
+    # id, model, seq override, its Pallas calls by name, attention plan
+    ("transformer_b128_s256", "transformer", None,
+     {"dense_vmem.fwd": 18, "dense_vmem.bwd": 18}, "dense_vmem"),
+    ("bert_b128_s128", "bert", None,
+     {"dense_vmem.fwd": 12, "dense_vmem.bwd": 12}, "dense_vmem"),
+    ("resnet50_b128", "resnet50", None,
+     {"fused_conv.fwd": 49, "fused_conv.apply": 49}, None),
     # the [100000, 32] fused table is over the scatter kernel's VMEM
     # budget and its 851,968 ids over the SMEM bound: XLA scatter
-    ("deepfm_b32768", "deepfm", None, False, None),
-    ("transformer_b16_s2048", "transformer", 2048, True,
+    ("deepfm_b32768", "deepfm", None, {}, None),
+    ("transformer_b16_s2048", "transformer", 2048,
+     {"head_split_stream.fwd": 18, "head_split_stream.bwd": 18},
      "head_split_stream[vmem]"),
 ]
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("model,seq,has_kernels,attn_plan",
+@pytest.mark.parametrize("model,seq,kernels,attn_plan",
                          [c[1:] for c in _STEP_CASES],
                          ids=[c[0] for c in _STEP_CASES])
-def test_whole_train_step_compiles(chip, model, seq, has_kernels, attn_plan):
+def test_whole_train_step_compiles(chip, model, seq, kernels, attn_plan):
     import bench
     from paddle_tpu.core.executor import build_step_fn
 
@@ -377,7 +422,8 @@ def test_whole_train_step_compiles(chip, model, seq, has_kernels, attn_plan):
                          mem.temp_size_in_bytes / 1e9,
                          mem.argument_size_in_bytes / 1e9))
     assert need < _HBM_BYTES
-    assert (_kernel_calls(compiled) > 0) == has_kernels
+    assert _step_kernel_names(compiled) == kernels
+    assert _kernel_calls(compiled) == sum(kernels.values())
     if attn_plan is not None:
         from chip_smoke import kernel_plans
 
