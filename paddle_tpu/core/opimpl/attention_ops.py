@@ -23,6 +23,20 @@ def _flash_attention_op(env, op):
     bias = get(env, op.input("Bias"))
     out_dtype = q.dtype
     q, k, v = mxu_cast(q, k, v)
+    heads = op.attr("num_heads", 1)
+    kv_heads = op.attr("num_kv_heads", 0) or heads
+    if kv_heads != heads:
+        # grouped-query heads: query head h reads key/value head
+        # h // (heads / kv_heads). K and V are repeated to the query heads
+        # before the kernels, which take one head count; a call with equal
+        # counts is untouched
+        def repeat(x):
+            b, t, hd = x.shape
+            x = x.reshape(b, t, kv_heads, hd // kv_heads)
+            return jnp.repeat(x, heads // kv_heads, axis=2).reshape(
+                b, t, -1)
+
+        k, v = repeat(k), repeat(v)
     dropout = op.attr("dropout_rate", 0.0)
     rng = next_rng(env) if dropout > 0.0 else None
     plan = plan_for(q, k, bias, op.attr("num_heads", 1),
@@ -35,6 +49,33 @@ def _flash_attention_op(env, op):
                           causal=op.attr("causal", False),
                           dropout_rate=dropout, rng=rng, plan=plan)
     put(env, op.output("Out"), out.astype(out_dtype))
+
+
+@register("gated_delta_rule")
+def _gated_delta_rule(env, op):
+    """The Gated DeltaNet core (``ops/gated_delta.py``): Q, K [B, T, Hk*Dk]
+    and V [B, T, Hv*Dv] after the causal convolution, the raw decay and
+    write-strength projections A, B [B, T, Hv], the heads' ALog and DtBias;
+    L2-normalised q/k, the delta rule over a [Dk, Dv] state a head in
+    chunks of ``chunk`` tokens. Out [B, T, Hv*Dv] in V's dtype."""
+    from ...ops import gated_delta
+    from ...ops.gates import GateDecision, GateReason, note
+    from ..op_registry import amp_enabled
+
+    v = get(env, op.input("V"))
+    chunk = int(op.attr("chunk", 64))
+    note("gated_delta_rule", GateDecision(True, "chunked_scan_xla", reasons=[
+        GateReason("shape", "%d chunks of %d tokens, the WY form through "
+                   "XLA, backward by autodiff of the chunk scan; no Pallas "
+                   "kernel" % (-(-v.shape[1] // chunk), chunk),
+                   blocking=False)]))
+    out = gated_delta.gated_delta_attention(
+        get(env, op.input("Q")), get(env, op.input("K")), v,
+        get(env, op.input("A")), get(env, op.input("B")),
+        get(env, op.input("ALog")), get(env, op.input("DtBias")),
+        int(op.attr("num_k_heads")), int(op.attr("num_v_heads")), chunk,
+        mxu_dtype=jnp.bfloat16 if amp_enabled() else None)
+    put(env, op.output("Out"), out.astype(v.dtype))
 
 
 @register("kv_cache_write")

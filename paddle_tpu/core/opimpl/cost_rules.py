@@ -487,3 +487,93 @@ register_cost("lars_momentum")(_opt_cost(4))
 register_cost("adam", "adamax", "adadelta", "rmsprop",
               "decayed_adagrad", "lamb")(_opt_cost(6))  # p/m/v rw
 register_cost("ftrl")(_opt_cost(6))
+
+
+# ---------------------------------------------------------------------------
+# the hybrid linear-attention / routed-experts block (models/qwen3_next.py)
+# ---------------------------------------------------------------------------
+
+@register_cost("rms_norm")
+def _rms_norm_cost(ctx, op):
+    # like layer_norm: one statistic, read + write forward, x-hat re-read
+    # backward; the gate, where given, is one more stream each way
+    n = _nel(ctx, op.input("X"))
+    if n is None:
+        ctx.add(op, unresolved=True)
+        return
+    e = ctx.esize(op.input("X"))
+    streams = 3 if op.input("Gate") is not None else 2
+    ctx.add(op, flops=6 * n, hbm_bytes=streams * n * e,
+            bwd_flops=8 * n, bwd_hbm_bytes=(streams + 1) * n * e)
+
+
+@register_cost("rotary", "causal_conv1d")
+def _rowwise_pass_cost(ctx, op):
+    # an element-wise pass XLA does not fold into a matmul (it sits
+    # between a reshape and a kernel): read + write each way
+    n = _nel(ctx, op.input("X"))
+    if n is None:
+        ctx.add(op, unresolved=True)
+        return
+    e = ctx.esize(op.input("X"))
+    taps = 1
+    if op.type == "causal_conv1d":
+        ws = ctx.shape(op.input("Filter"))
+        taps = int(ws[1]) if ws is not None else 4
+    ctx.add(op, flops=2 * taps * n, hbm_bytes=2 * n * e,
+            bwd_flops=4 * taps * n, bwd_hbm_bytes=3 * n * e)
+
+
+def _gated_delta_core_flops(tokens, heads, dk, dv, chunk):
+    """Forward multiply-adds x 2 of the chunked delta rule's core: a token
+    and head, the in-chunk products (k k^T, q k^T: 2 * C * Dk; the
+    triangular solve: C * (Dv + Dk); scores times values: C * Dv) and the
+    products with the carried state (read twice and written once: 3 * Dk *
+    Dv)."""
+    a_token_head = 2 * chunk * dk + chunk * (dv + dk) + chunk * dv \
+        + 3 * dk * dv
+    return 2.0 * tokens * heads * a_token_head
+
+
+@register_cost("gated_delta_rule")
+def _gated_delta_rule_cost(ctx, op):
+    qs, vs = ctx.shape(op.input("Q")), ctx.shape(op.input("V"))
+    if qs is None or vs is None or len(vs) != 3:
+        ctx.add(op, unresolved=True)
+        return
+    b, t, hvd = vs
+    hk, hv = int(op.attr("num_k_heads")), int(op.attr("num_v_heads"))
+    dk, dv = qs[-1] // hk, hvd // hv
+    chunk = int(op.attr("chunk", 64))
+    f = _gated_delta_core_flops(b * t, hv, dk, dv, chunk)
+    e = ctx.esize(op.input("V"))
+    # q, k, v read and the output written; the state of every chunk is
+    # kept for the backward pass in float32
+    stream = (2 * b * t * qs[-1] + 2 * b * t * hvd) * e
+    states = b * (-(-t // chunk)) * hv * dk * dv * 4
+    ctx.add(op, flops=f, hbm_bytes=stream + states,
+            bwd_flops=2 * f, bwd_hbm_bytes=2 * stream + states)
+
+
+@register_cost("routed_experts")
+def _routed_experts_cost(ctx, op):
+    xs = ctx.shape(op.input("X"))
+    gs = ctx.shape(op.input("ExpertGate"))
+    rs = ctx.shape(op.input("Router"))
+    if xs is None or gs is None or rs is None or -1 in xs:
+        ctx.add(op, unresolved=True)
+        return
+    tokens, d = _prod(xs[:-1]), xs[-1]
+    held, f = gs[0], gs[1]
+    experts, top_k = rs[1], int(op.attr("top_k"))
+    e = ctx.esize(op.input("X"))
+    # the expected share of the picks falls on the held experts
+    rows = tokens * top_k * held / float(experts)
+    flops = 2.0 * tokens * d * experts + 2.0 * rows * 3 * d * f
+    nbytes = 3 * held * d * f * e + 2 * tokens * d * e + 2 * rows * d * e
+    ss = ctx.shape(op.input("SharedGate"))
+    if ss is not None:
+        flops += 2.0 * tokens * (3 * d * ss[1] + d)
+        nbytes += 3 * d * ss[1] * e
+    ctx.add(op, flops=flops, hbm_bytes=nbytes, bwd_flops=2 * flops,
+            bwd_hbm_bytes=2 * nbytes)

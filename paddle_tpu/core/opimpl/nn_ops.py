@@ -10,6 +10,7 @@ and fuses the elementwise epilogues.
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..op_registry import register, get, put, next_rng
 
@@ -812,21 +813,122 @@ def _pixel_shuffle(env, op):
     put(env, op.output("Out"), out)
 
 
-@register("moe_ffn")
-def _moe_ffn(env, op):
-    """Mixture-of-experts FFN (see ``parallel/moe.py``; new capability vs
-    the reference — SURVEY.md §2.5D lists expert parallelism as absent)."""
-    from ...parallel.moe import moe_ffn_apply
+@register("rms_norm")
+def _rms_norm(env, op):
+    """RMS norm over the trailing ``norm_dim`` elements (the whole last axis,
+    or one head of a packed [.., H*D] axis): ``x * rsqrt(mean(x^2) + eps)``
+    in float32, times ``Scale`` (``1 + Scale`` when ``zero_centered``), times
+    ``silu(Gate)`` when a gate is given (the gated norm on a Gated DeltaNet
+    output). Y is stored in X's dtype."""
+    x = get(env, op.input("X"))
+    scale = get(env, op.input("Scale"))
+    gate = get(env, op.input("Gate"))
+    eps = op.attr("epsilon", 1e-6)
+    dim = int(op.attr("norm_dim", 0)) or x.shape[-1]
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, dim))
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    if scale is not None:
+        w = scale.astype(jnp.float32)
+        y = y * (1.0 + w if op.attr("zero_centered", False) else w)
+    y = y.reshape(x.shape)
+    if gate is not None:
+        y = y * jax.nn.silu(gate.astype(jnp.float32))
+    put(env, op.output("Y"), y.astype(x.dtype))
+
+
+@register("rotary")
+def _rotary(env, op):
+    """Rotary positions on the first ``rotary_dim`` dims of every head of a
+    packed [B, T, H*D] tensor, rotate-half pairing (j, j + rotary_dim/2),
+    position = index along T; the other dims pass through. float32 angles
+    and products, stored in X's dtype."""
+    x = get(env, op.input("X"))
+    heads = int(op.attr("num_heads"))
+    rot = int(op.attr("rotary_dim"))
+    theta = float(op.attr("theta", 10000.0))
+    b, t, hd = x.shape
+    d = hd // heads
+    half = rot // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rot)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.cos(angle)[None, :, None, :]
+    sin = jnp.sin(angle)[None, :, None, :]
+    xh = x.reshape(b, t, heads, d)
+    x1 = xh[..., :half].astype(jnp.float32)
+    x2 = xh[..., half:rot].astype(jnp.float32)
+    out = jnp.concatenate(
+        [(x1 * cos - x2 * sin).astype(x.dtype),
+         (x2 * cos + x1 * sin).astype(x.dtype), xh[..., rot:]], axis=-1)
+    put(env, op.output("Out"), out.reshape(b, t, hd))
+
+
+@register("causal_conv1d")
+def _causal_conv1d(env, op):
+    """Causal depthwise convolution along T without bias: X [B, T, C],
+    Filter [C, K]; ``out[t] = sum_j Filter[:, j] * x[t - (K-1) + j]`` with
+    zeros before the row's start, then SiLU when ``act == 'silu'``. A sum
+    of K shifted products (K is 4), float32 accumulation."""
+    x = get(env, op.input("X"))
+    w = get(env, op.input("Filter")).astype(jnp.float32)
+    k = w.shape[1]
+    t = x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    out = sum(xp[:, j:j + t].astype(jnp.float32) * w[:, j]
+              for j in range(k))
+    if op.attr("act", "") == "silu":
+        out = jax.nn.silu(out)
+    put(env, op.output("Out"), out.astype(x.dtype))
+
+
+@register("routed_experts")
+def _routed_experts(env, op):
+    """The MoE block of a layer on a chip that holds a share of the routed
+    experts (``parallel/moe.py``): router over all ``num_experts``, top-k
+    with renormalised weights, the held experts' SwiGLU products in a static
+    pass and binned blocks (no token dropped), plus the shared expert behind
+    its sigmoid gate. ``Load`` [held] int32: the tokens each held expert took
+    in this step, a persistable counter written inside the step."""
+    from ...ops.gates import GateDecision, GateReason, note
+    from ...parallel import moe
+    from ..op_registry import mxu_cast
 
     x = get(env, op.input("X"))
-    gate_w = get(env, op.input("GateW"))
-    w1 = get(env, op.input("W1"))
-    b1 = get(env, op.input("B1"))
-    w2 = get(env, op.input("W2"))
-    b2 = get(env, op.input("B2"))
-    act = {"relu": jax.nn.relu, "gelu": jax.nn.gelu}[op.attr("act", "relu")]
-    out, aux = moe_ffn_apply(
-        x, gate_w, w1, b1, w2, b2, k=op.attr("k", 2),
-        capacity_factor=op.attr("capacity_factor", 1.25), activation=act)
-    put(env, op.output("Out"), out)
-    put(env, op.output("AuxLoss"), aux)
+    router = get(env, op.input("Router"))
+    wg, wu, wd = mxu_cast(get(env, op.input("ExpertGate")),
+                          get(env, op.input("ExpertUp")),
+                          get(env, op.input("ExpertDown")))
+    top_k = int(op.attr("top_k"))
+    lo = int(op.attr("first_expert", 0))
+    tokens = int(np.prod(x.shape[:-1]))
+    rows = moe.block_rows_for(tokens * top_k)
+    note("routed_experts", GateDecision(True, "slab_and_blocks", reasons=[
+        GateReason("shape", "%d of %d experts held, %d assignments: a "
+                   "static pass of %d rows an expert, beyond it blocks of "
+                   "%d rows under a dynamic trip count; no capacity factor"
+                   % (wg.shape[0], router.shape[1], tokens * top_k,
+                      moe.slab_rows_for(tokens * top_k, router.shape[1],
+                                        rows), rows), blocking=False)]))
+    xc = mxu_cast(x)
+    routed, counts = moe.routed_experts(
+        xc, router, wg, wu, wd, top_k, lo,
+        renormalize=op.attr("norm_topk_prob", True))
+    out = routed
+    sg = get(env, op.input("SharedGate"))
+    if sg is not None:
+        su, sd, gate_w = mxu_cast(get(env, op.input("SharedUp")),
+                                  get(env, op.input("SharedDown")),
+                                  get(env, op.input("SharedExpertGate")))
+        sg = mxu_cast(sg)
+        f32 = jnp.float32
+
+        def mm(a, w):
+            return jnp.matmul(a.astype(w.dtype), w,
+                              preferred_element_type=f32)
+
+        h = jax.nn.silu(mm(xc, sg)) * mm(xc, su)
+        shared = mm(h, sd)
+        if gate_w is not None:
+            shared = shared * jax.nn.sigmoid(mm(xc, gate_w))
+        out = out + shared
+    put(env, op.output("Out"), out.astype(x.dtype))
+    put(env, op.output("Load"), counts)

@@ -874,3 +874,93 @@ def _cached_attention_chunk_shape(ctx, op):
         ctx.set(op.output("Out"), qs, dt)
         return
     ctx.set(op.output("Out"), tuple(qs[:-1]) + (vs[-1],), dt)
+
+
+# ---------------------------------------------------------------------------
+# the hybrid linear-attention / routed-experts block (models/qwen3_next.py)
+# ---------------------------------------------------------------------------
+
+@register_shape("rms_norm")
+def _rms_norm_shape(ctx, op):
+    xv = op.input("X")
+    xs = ctx.shape(xv)
+    ctx.set(op.output("Y"), xs, ctx.dtype(xv))
+    dim = int(op.attr("norm_dim", 0))
+    ss = ctx.shape(op.input("Scale"))
+    if xs is None:
+        return
+    last = xs[-1]
+    if dim and last != -1 and last % dim:
+        raise ShapeError("rms_norm norm_dim %d does not divide the last "
+                         "axis of %s" % (dim, list(xs)))
+    want = dim or last
+    if ss is not None and want != -1 and tuple(ss) != (want,):
+        raise ShapeError("rms_norm Scale has shape %s, the normalised "
+                         "slice has %d elements" % (list(ss), want))
+
+
+@register_shape("rotary")
+def _rotary_shape(ctx, op):
+    xv = op.input("X")
+    xs = ctx.shape(xv)
+    ctx.set(op.output("Out"), xs, ctx.dtype(xv))
+    if xs is None or len(xs) != 3 or xs[-1] == -1:
+        return
+    heads, rot = int(op.attr("num_heads")), int(op.attr("rotary_dim"))
+    if xs[-1] % heads or rot % 2 or rot > xs[-1] // heads:
+        raise ShapeError("rotary: %d heads with %d rotary dims do not fit "
+                         "a last axis of %d" % (heads, rot, xs[-1]))
+
+
+@register_shape("causal_conv1d")
+def _causal_conv1d_shape(ctx, op):
+    xv = op.input("X")
+    xs, ws = ctx.shape(xv), ctx.shape(op.input("Filter"))
+    ctx.set(op.output("Out"), xs, ctx.dtype(xv))
+    if xs is not None and ws is not None and xs[-1] != -1 \
+            and ws[0] != xs[-1]:
+        raise ShapeError("causal_conv1d Filter %s does not match %d "
+                         "channels" % (list(ws), xs[-1]))
+
+
+@register_shape("gated_delta_rule")
+def _gated_delta_rule_shape(ctx, op):
+    vv = op.input("V")
+    qs, ks, vs = (ctx.shape(op.input(n)) for n in ("Q", "K", "V"))
+    ctx.set(op.output("Out"), vs, ctx.dtype(vv))
+    if qs is None or ks is None or vs is None:
+        return
+    hk, hv = int(op.attr("num_k_heads")), int(op.attr("num_v_heads"))
+    if tuple(qs) != tuple(ks):
+        raise ShapeError("gated_delta_rule Q %s and K %s differ"
+                         % (list(qs), list(ks)))
+    if hv % hk or (qs[-1] != -1 and qs[-1] % hk) \
+            or (vs[-1] != -1 and vs[-1] % hv):
+        raise ShapeError("gated_delta_rule: %d key and %d value heads do "
+                         "not fit Q %s, V %s" % (hk, hv, list(qs), list(vs)))
+    for slot in ("A", "B"):
+        s = ctx.shape(op.input(slot))
+        if s is not None and s[-1] != -1 and s[-1] != hv:
+            raise ShapeError("gated_delta_rule %s has %d columns for %d "
+                             "value heads" % (slot, s[-1], hv))
+
+
+@register_shape("routed_experts")
+def _routed_experts_shape(ctx, op):
+    xv = op.input("X")
+    xs = ctx.shape(xv)
+    ctx.set(op.output("Out"), xs, ctx.dtype(xv))
+    gs = ctx.shape(op.input("ExpertGate"))
+    rs = ctx.shape(op.input("Router"))
+    if gs is not None:
+        ctx.set(op.output("Load"), (gs[0],), np.dtype("int32"))
+    if xs is None or gs is None or rs is None:
+        return
+    if xs[-1] != -1 and (gs[2] != xs[-1] or rs[0] != xs[-1]):
+        raise ShapeError("routed_experts: X %s against Router %s and "
+                         "ExpertGate %s" % (list(xs), list(rs), list(gs)))
+    first, top_k = int(op.attr("first_expert", 0)), int(op.attr("top_k"))
+    if first < 0 or first + gs[0] > rs[1] or top_k > rs[1]:
+        raise ShapeError("routed_experts holds experts [%d, %d) and takes "
+                         "the top %d of a router over %d"
+                         % (first, first + gs[0], top_k, rs[1]))

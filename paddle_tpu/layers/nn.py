@@ -39,7 +39,8 @@ __all__ = [
     "split", "warpctc", "nce", "hsigmoid", "cumsum",
     "linear_chain_crf", "crf_decoding",
     "dynamic_lstm", "dynamic_gru", "lstm", "gru_unit",
-    "moe_ffn",
+    "rms_norm", "rotary", "gated_attention", "causal_conv1d",
+    "gated_delta_net", "routed_experts",
     "beam_search", "beam_search_gather", "beam_search_decode",
 ]
 
@@ -1575,51 +1576,256 @@ def gru_unit(input, hidden, size, param_attr=None, bias_attr=None,
     return new_hidden
 
 
-def moe_ffn(input, num_experts, d_ff, k=2, capacity_factor=1.25, act="relu",
-            param_attr=None, name=None):
-    """Mixture-of-experts feed-forward block (new capability — the reference
-    has no MoE, SURVEY.md §2.5D). Expert weights are sharded over the ``ep``
-    mesh axis; GSPMD lowers dispatch to ICI all-to-alls (see
-    ``parallel/moe.py``). Returns ``(out, aux_loss)`` — add
-    ``scale(aux_loss, small_coeff)`` into the training loss for load
-    balancing."""
-    if act not in ("relu", "gelu"):
-        raise ValueError("moe_ffn act must be 'relu' or 'gelu', got %r"
-                         % (act,))
-    helper = LayerHelper("moe_ffn", param_attr=param_attr, name=name)
-    d = input.shape[-1]
-    dtype = _dtype(input)
+def rms_norm(input, epsilon=1e-6, zero_centered=False, norm_dim=None,
+             gate=None, param_attr=None, name=None):
+    """RMS norm over the last axis, or over each trailing group of
+    ``norm_dim`` elements of it (one head of a packed [.., H*D] axis, with
+    one weight of ``norm_dim`` shared by the heads): ``x * rsqrt(mean(x^2) +
+    epsilon) * w`` in float32; ``zero_centered`` applies ``1 + w`` (the
+    weight then starts at 0). ``gate``: a tensor shaped like ``input``; the
+    result is multiplied by ``silu(gate)`` (the gated norm)."""
+    helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
+    dim = int(norm_dim or input.shape[-1])
+    w = helper.create_parameter(
+        helper.param_attr, shape=[dim], dtype=_dtype(input),
+        default_initializer=ConstantInitializer(
+            0.0 if zero_centered else 1.0))
+    out = helper.create_variable_for_type_inference(dtype=_dtype(input),
+                                                    shape=input.shape)
+    inputs = {"X": input, "Scale": w}
+    if gate is not None:
+        inputs["Gate"] = gate
+    helper.append_op("rms_norm", inputs, {"Y": out},
+                     {"epsilon": float(epsilon),
+                      "zero_centered": bool(zero_centered),
+                      "norm_dim": dim})
+    return out
 
-    def p(tag, shape, sharding=None, init=None):
+
+def rotary(x, num_heads, rotary_dim, theta=10000.0, name=None):
+    """Rotary positions on the first ``rotary_dim`` dims of each head of a
+    packed [B, T, H*D] tensor (rotate-half pairing, position = index along
+    T); the rest of each head passes through."""
+    helper = LayerHelper("rotary", name=name)
+    out = helper.create_variable_for_type_inference(dtype=_dtype(x),
+                                                    shape=x.shape)
+    helper.append_op("rotary", {"X": x}, {"Out": out},
+                     {"num_heads": int(num_heads),
+                      "rotary_dim": int(rotary_dim), "theta": float(theta)})
+    return out
+
+
+def _named(name, tag):
+    return None if name is None else name + "." + tag
+
+
+def _projection(helper, x, dout, name, sharding=None):
+    """x [.., D] times a [D, dout] weight called ``name``, no bias."""
+    w = helper.create_parameter(
+        ParamAttr(name=name, initializer=XavierInitializer(),
+                  sharding=sharding),
+        shape=[x.shape[-1], dout], dtype=_dtype(x))
+    out = helper.create_variable_for_type_inference(
+        dtype=_dtype(x), shape=tuple(x.shape[:-1]) + (dout,))
+    helper.append_op("matmul", {"X": x, "Y": w}, {"Out": out}, {})
+    return out
+
+
+def gated_attention(x, num_heads, num_kv_heads, head_dim, rotary_dim,
+                    rope_theta=10000.0, epsilon=1e-6, name=None):
+    """Causal self-attention with grouped-query heads and an output gate.
+    ``q_proj`` gives query and gate, interleaved a head ([.., H, 2*D], the
+    halves of the last axis); ``num_kv_heads`` key/value heads serve
+    ``num_heads`` query heads (head ``h`` reads ``h // (H / Hkv)``); q and k
+    take a zero-centred RMS norm over the head dimension and rotary
+    positions on its first ``rotary_dim`` dims; the attention output is
+    multiplied by ``sigmoid(gate)`` before ``o_proj``. x: [B, T, D_model].
+    Parameters: ``<name>.{q_proj,k_proj,v_proj,o_proj,q_norm.w,k_norm.w}``."""
+    from . import ops as op_layers
+    from . import tensor
+
+    helper = LayerHelper("gated_attention", name=name)
+    b, t = x.shape[0], x.shape[1]
+    qg = _projection(helper, x, num_heads * head_dim * 2,
+                     _named(name, "q_proj"), (None, "mp"))
+    qg = tensor.reshape(qg, [-1, t, num_heads, 2 * head_dim])
+    q, g = split(qg, 2, dim=-1)
+    q = tensor.reshape(q, [-1, t, num_heads * head_dim])
+    g = tensor.reshape(g, [-1, t, num_heads * head_dim])
+    k = _projection(helper, x, num_kv_heads * head_dim,
+                    _named(name, "k_proj"), (None, "mp"))
+    v = _projection(helper, x, num_kv_heads * head_dim,
+                    _named(name, "v_proj"), (None, "mp"))
+    q = rms_norm(q, epsilon, zero_centered=True, norm_dim=head_dim,
+                 param_attr=ParamAttr(name=_named(name, "q_norm.w")))
+    k = rms_norm(k, epsilon, zero_centered=True, norm_dim=head_dim,
+                 param_attr=ParamAttr(name=_named(name, "k_norm.w")))
+    q = rotary(q, num_heads, rotary_dim, rope_theta)
+    k = rotary(k, num_kv_heads, rotary_dim, rope_theta)
+    ctx = helper.create_variable_for_type_inference(
+        dtype=_dtype(x), shape=(b, t, num_heads * head_dim))
+    helper.append_op("flash_attention", {"Q": q, "K": k, "V": v},
+                     {"Out": ctx},
+                     {"num_heads": int(num_heads),
+                      "num_kv_heads": int(num_kv_heads),
+                      "dropout_rate": 0.0, "causal": True})
+    ctx = elementwise_mul(ctx, op_layers.sigmoid(g))
+    wo = helper.create_parameter(
+        ParamAttr(name=_named(name, "o_proj"),
+                  initializer=XavierInitializer(), sharding=("mp", None)),
+        shape=[num_heads * head_dim, x.shape[-1]], dtype=_dtype(x))
+    out = helper.create_variable_for_type_inference(dtype=_dtype(x),
+                                                    shape=x.shape)
+    helper.append_op("matmul", {"X": ctx, "Y": wo}, {"Out": out}, {})
+    return out
+
+
+def causal_conv1d(x, kernel=4, act="silu", param_attr=None, name=None):
+    """Causal depthwise convolution along T of x [B, T, C], filter
+    [C, kernel] without bias, then ``act`` ('silu' or None)."""
+    helper = LayerHelper("causal_conv1d", param_attr=param_attr, name=name)
+    c = int(x.shape[-1])
+    lim = (3.0 / kernel) ** 0.5
+    w = helper.create_parameter(
+        helper.param_attr, shape=[c, int(kernel)], dtype=_dtype(x),
+        default_initializer=UniformInitializer(-lim, lim))
+    out = helper.create_variable_for_type_inference(dtype=_dtype(x),
+                                                    shape=x.shape)
+    helper.append_op("causal_conv1d", {"X": x, "Filter": w}, {"Out": out},
+                     {"act": act or ""})
+    return out
+
+
+def gated_delta_net(x, num_k_heads, num_v_heads, head_k_dim, head_v_dim,
+                    conv_kernel=4, epsilon=1e-6, chunk=64, name=None):
+    """Gated DeltaNet linear attention (x: [B, T, D_model]): ``in_proj_qkvz``
+    gives, a key head, q and k of ``head_k_dim`` and the v and z of its
+    ``num_v_heads / num_k_heads`` value heads (the published per-group
+    interleaving); ``in_proj_ba`` the write strengths b and decays a of
+    those value heads. [q|k|v] pass a causal depthwise convolution with
+    SiLU, then the gated delta rule (op ``gated_delta_rule``,
+    ``ops/gated_delta.py``); the output takes an RMS norm over the head
+    dimension gated by ``silu(z)``, then ``out_proj``. Parameters:
+    ``<name>.{in_proj_qkvz,in_proj_ba,conv,A_log,dt_bias,norm.w,out_proj}``."""
+    from . import tensor
+
+    helper = LayerHelper("gated_delta_net", name=name)
+    b, t = x.shape[0], x.shape[1]
+    rep = num_v_heads // num_k_heads
+    kd, vd = num_k_heads * head_k_dim, num_v_heads * head_v_dim
+    group = 2 * head_k_dim + 2 * rep * head_v_dim
+    qkvz = _projection(helper, x, num_k_heads * group,
+                       _named(name, "in_proj_qkvz"), (None, "mp"))
+    qkvz = tensor.reshape(qkvz, [-1, t, num_k_heads, group])
+    q, k, v, z = split(qkvz, [head_k_dim, head_k_dim, rep * head_v_dim,
+                              rep * head_v_dim], dim=-1)
+    q = tensor.reshape(q, [-1, t, kd])
+    k = tensor.reshape(k, [-1, t, kd])
+    v = tensor.reshape(v, [-1, t, vd])
+    z = tensor.reshape(z, [-1, t, vd])
+    ba = _projection(helper, x, 2 * num_v_heads, _named(name, "in_proj_ba"))
+    ba = tensor.reshape(ba, [-1, t, num_k_heads, 2 * rep])
+    bb, aa = split(ba, 2, dim=-1)
+    bb = tensor.reshape(bb, [-1, t, num_v_heads])
+    aa = tensor.reshape(aa, [-1, t, num_v_heads])
+    mixed = causal_conv1d(
+        tensor.concat([q, k, v], axis=-1), conv_kernel, "silu",
+        param_attr=ParamAttr(name=_named(name, "conv")))
+    q, k, v = split(mixed, [kd, kd, vd], dim=-1)
+    # decays start slow (a state that remembers hundreds of tokens), as
+    # state-space layers are started: A in (0.05, 1], softplus(dt_bias) in
+    # about (0.02, 0.3)
+    a_log = helper.create_parameter(
+        ParamAttr(name=_named(name, "A_log")), shape=[num_v_heads],
+        dtype="float32", default_initializer=UniformInitializer(-3.0, 0.0))
+    dt_bias = helper.create_parameter(
+        ParamAttr(name=_named(name, "dt_bias")), shape=[num_v_heads],
+        dtype="float32", default_initializer=UniformInitializer(-4.0, -1.0))
+    core = helper.create_variable_for_type_inference(
+        dtype=_dtype(x), shape=(b, t, vd))
+    helper.append_op(
+        "gated_delta_rule",
+        {"Q": q, "K": k, "V": v, "A": aa, "B": bb, "ALog": a_log,
+         "DtBias": dt_bias}, {"Out": core},
+        {"num_k_heads": int(num_k_heads), "num_v_heads": int(num_v_heads),
+         "chunk": int(chunk)})
+    core = rms_norm(core, epsilon, norm_dim=head_v_dim, gate=z,
+                    param_attr=ParamAttr(name=_named(name, "norm.w")))
+    wo = helper.create_parameter(
+        ParamAttr(name=_named(name, "out_proj"),
+                  initializer=XavierInitializer(), sharding=("mp", None)),
+        shape=[vd, x.shape[-1]], dtype=_dtype(x))
+    out = helper.create_variable_for_type_inference(dtype=_dtype(x),
+                                                    shape=x.shape)
+    helper.append_op("matmul", {"X": core, "Y": wo}, {"Out": out}, {})
+    return out
+
+
+def routed_experts(x, num_experts, top_k, moe_intermediate_size,
+                   shared_intermediate_size=0, experts_held=None,
+                   norm_topk_prob=True, name=None):
+    """Mixture-of-experts block with routed and shared experts on a chip that
+    holds a share of the routed ones (``parallel/moe.py``): a router over
+    all ``num_experts``, the ``top_k`` largest softmax weights (divided by
+    their sum with ``norm_topk_prob``), SwiGLU experts of width
+    ``moe_intermediate_size``. ``experts_held``: ``(first, count)`` or a
+    ``range`` of the expert ids this chip holds (default: all); the routed
+    sum runs over the picks that fall on them, and no token is dropped
+    whatever the routing. ``shared_intermediate_size`` > 0 adds one shared
+    SwiGLU expert behind a sigmoid gate. Returns ``(out, load)``; ``load``
+    [count] int32 is a persistable counter of the tokens each held expert
+    took in the last step. Parameters: ``<name>.router``,
+    ``<name>.experts.{gate,up,down}`` ([count, out, in], the published
+    per-expert layout), ``<name>.shared.{gate_proj,up_proj,down_proj}``,
+    ``<name>.shared_gate``."""
+    from . import tensor
+
+    helper = LayerHelper("routed_experts", name=name)
+    d, f = int(x.shape[-1]), int(moe_intermediate_size)
+    dtype = _dtype(x)
+    if experts_held is None:
+        first, held = 0, int(num_experts)
+    elif isinstance(experts_held, range):
+        first, held = experts_held.start, len(experts_held)
+    else:
+        first, held = (int(n) for n in experts_held)
+    if first < 0 or held < 1 or first + held > num_experts:
+        raise ValueError("experts_held [%d, %d) is not within the %d experts"
+                         % (first, first + held, num_experts))
+
+    def p(tag, shape, fan_in, fan_out):
+        lim = (6.0 / (fan_in + fan_out)) ** 0.5
         return helper.create_parameter(
-            ParamAttr(name=None if name is None else name + "." + tag,
-                      initializer=init or XavierInitializer(),
-                      sharding=sharding),
+            ParamAttr(name=_named(name, tag),
+                      initializer=UniformInitializer(-lim, lim)),
             shape=shape, dtype=dtype)
 
-    # per-expert Xavier fans ([D,F], not the stacked 3-D shape — the default
-    # initializer would read shape[2:] as a conv receptive field and start
-    # experts ~sqrt(D)x too small)
-    lim = (6.0 / (d + d_ff)) ** 0.5
-    xavier2d = UniformInitializer(-lim, lim)
-    gate_w = p("gate", [d, num_experts])
-    w1 = p("w1", [num_experts, d, d_ff], sharding=("ep", None, None),
-           init=xavier2d)
-    b1 = p("b1", [num_experts, d_ff], sharding=("ep", None))
-    w2 = p("w2", [num_experts, d_ff, d], sharding=("ep", None, None),
-           init=xavier2d)
-    b2 = p("b2", [num_experts, d], sharding=("ep", None))
-    out = helper.create_variable_for_type_inference(
-        dtype=dtype, shape=input.shape)
-    aux = helper.create_variable_for_type_inference(dtype="float32",
-                                                    shape=())
+    inputs = {
+        "X": x,
+        "Router": p("router", [d, num_experts], d, num_experts),
+        "ExpertGate": p("experts.gate", [held, f, d], d, f),
+        "ExpertUp": p("experts.up", [held, f, d], d, f),
+        "ExpertDown": p("experts.down", [held, d, f], f, d),
+    }
+    if shared_intermediate_size:
+        fs = int(shared_intermediate_size)
+        inputs.update({
+            "SharedGate": p("shared.gate_proj", [d, fs], d, fs),
+            "SharedUp": p("shared.up_proj", [d, fs], d, fs),
+            "SharedDown": p("shared.down_proj", [fs, d], fs, d),
+            "SharedExpertGate": p("shared_gate", [d, 1], d, 1),
+        })
+    out = helper.create_variable_for_type_inference(dtype=dtype,
+                                                    shape=x.shape)
+    load = tensor.create_global_var(
+        shape=[held], value=0, dtype="int32", persistable=True,
+        name=_named(name, "load"))
+    load.stop_gradient = True
     helper.append_op(
-        "moe_ffn",
-        {"X": input, "GateW": gate_w, "W1": w1, "B1": b1, "W2": w2,
-         "B2": b2},
-        {"Out": out, "AuxLoss": aux},
-        {"k": k, "capacity_factor": capacity_factor, "act": act})
-    return out, aux
+        "routed_experts", inputs, {"Out": out, "Load": load},
+        {"top_k": int(top_k), "first_expert": first,
+         "norm_topk_prob": bool(norm_topk_prob)})
+    return out, load
 
 
 def row_conv(input, future_context_size, param_attr=None, act=None,

@@ -22,13 +22,7 @@ __all__ = ["transformer_base", "transformer_flops_per_token",
            "lm_step_config"]
 
 
-def _ffn(x, d_model, d_ff, name, moe_experts=0, moe_k=2, aux_losses=None):
-    if moe_experts:
-        out, aux = layers.moe_ffn(x, num_experts=moe_experts, d_ff=d_ff,
-                                  k=moe_k, name=name + "_moe")
-        if aux_losses is not None:
-            aux_losses.append(aux)
-        return out
+def _ffn(x, d_model, d_ff, name):
     h = layers.fc(x, size=d_ff, num_flatten_dims=2, act="relu",
                   param_attr=ParamAttr(name=name + "_fc1.w",
                                        sharding=(None, "mp")),
@@ -67,9 +61,7 @@ def _embed(ids, pos, vocab_size, d_model, dropout_rate, name):
 
 def transformer_base(src_vocab=30000, trg_vocab=30000, seq_len=256,
                      d_model=512, d_ff=2048, n_head=8, n_layer=6,
-                     dropout_rate=0.1, label_smooth_eps=0.1,
-                     moe_experts=0, moe_k=2):
-    aux_losses = []
+                     dropout_rate=0.1, label_smooth_eps=0.1):
     src = layers.data("src_ids", shape=[seq_len], dtype="int64")
     trg = layers.data("trg_ids", shape=[seq_len], dtype="int64")
     lbl = layers.data("lbl_ids", shape=[seq_len], dtype="int64")
@@ -87,8 +79,7 @@ def transformer_base(src_vocab=30000, trg_vocab=30000, seq_len=256,
                 x, x, x, attn_bias=src_bias, d_model=d_model, n_head=n_head,
                 dropout_rate=dropout_rate, name=nm + "_attn"),
             dropout_rate, nm + "_attn")
-        enc = _prenorm(enc, lambda x: _ffn(x, d_model, d_ff, nm + "_ffn",
-                                           moe_experts, moe_k, aux_losses),
+        enc = _prenorm(enc, lambda x: _ffn(x, d_model, d_ff, nm + "_ffn"),
                        dropout_rate, nm + "_ffn")
         block_outs.append(enc.name)
     enc = layers.layer_norm(enc, begin_norm_axis=2)
@@ -106,8 +97,7 @@ def transformer_base(src_vocab=30000, trg_vocab=30000, seq_len=256,
                 x, enc, enc, attn_bias=src_bias, d_model=d_model,
                 n_head=n_head, dropout_rate=dropout_rate, name=nm + "_cross"),
             dropout_rate, nm + "_cross")
-        dec = _prenorm(dec, lambda x: _ffn(x, d_model, d_ff, nm + "_ffn",
-                                           moe_experts, moe_k, aux_losses),
+        dec = _prenorm(dec, lambda x: _ffn(x, d_model, d_ff, nm + "_ffn"),
                        dropout_rate, nm + "_ffn")
         block_outs.append(dec.name)
     dec = layers.layer_norm(dec, begin_norm_axis=2)
@@ -123,13 +113,6 @@ def transformer_base(src_vocab=30000, trg_vocab=30000, seq_len=256,
     tok_loss = layers.elementwise_mul(ce, mask)
     loss = layers.elementwise_div(layers.reduce_sum(tok_loss),
                                   layers.reduce_sum(mask))
-    if aux_losses:
-        total_aux = aux_losses[0]
-        for a in aux_losses[1:]:
-            total_aux = layers.elementwise_add(total_aux, a)
-        loss = layers.elementwise_add(
-            loss, layers.scale(total_aux, scale=0.01))
-
     return ModelSpec(
         loss,
         feeds={"src_ids": FeedSpec([seq_len], "int64", 0, src_vocab),

@@ -1384,6 +1384,119 @@ _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
 # ---------------------------------------------------------------------------
+# segmented streaming: a causal square too long for the head-split kernels'
+# VMEM (they hold the whole K/V forward and the whole q/do/dq backward of a
+# head) is cut into ``segment``-long pieces along T. Query segment i meets
+# key segment j <= i through the SAME head-split kernels: causal on the
+# diagonal, unmasked below it, never above. Forward, the partial outputs are
+# merged by their row logsumexp; backward, every pair is given the merged
+# output and logsumexp, with which the kernel's probabilities and its
+# ``delta`` are the whole row's, so the pairs' dq add up over j and their
+# dk/dv over i.
+# ---------------------------------------------------------------------------
+
+def _head_split_fits(t, t_k, d, esize, blocks=None):
+    """VMEM the head-split streaming kernels allocate for one head against
+    the budget (cf. :func:`_packed_stream_fits`): forward the whole K/V,
+    two buffers each; backward the whole q/do, two buffers each, and the
+    float32 dq, also counted twice; and in both the [block, block] float32
+    score tiles the body keeps (the chip's compiler counted 16.14M for the
+    backward at T=2048, D=256, blocks of 512: this model says 17.25M)."""
+    block_q, block_k = blocks or _block_sizes(t, t_k)
+
+    def pad(x, m):
+        return ((x + m - 1) // m) * m
+
+    tk_pad, t_pad = pad(t_k, block_k), pad(t, block_q)
+    tile = block_q * block_k * 4
+    fwd = (4 * tk_pad * d * esize + 4 * block_q * d * esize
+           + 2 * 8 * t_pad * 4 + 4 * tile + block_q * d * 4)
+    bwd = (4 * t_pad * d * esize + 2 * t_pad * d * 4
+           + 8 * block_k * d * esize + 4 * 8 * t_pad * 4
+           + 6 * tile + 2 * block_k * d * 4)
+    return max(fwd, bwd) <= _STREAM_VMEM_BUDGET
+
+
+def _segment_plan(t, d, esize):
+    """(segment, block): the longest segment that divides T, and the
+    largest block for it, whose head-split kernels fit; None if there is
+    none. A wide head takes smaller blocks than the default."""
+    least = 8 if _INTERPRET else 128
+    for segment in (4096, 2048, 1024, 512, 256, 128, 64, 32, 16):
+        if segment >= t or t % segment:
+            continue
+        block = _block_sizes(segment, segment)[0]
+        while block >= least and segment % block == 0:
+            if _head_split_fits(segment, segment, d, esize, (block, block)):
+                return segment, block
+            if block % (2 * least):
+                break
+            block //= 2
+    return None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _segmented_attention(q, k, v, scale, plan):
+    """``plan``: (segment, block) of :func:`_segment_plan`."""
+    return _segmented_fwd(q, k, v, scale, plan)[0]
+
+
+def _segments(x, segment):
+    return [x[:, i:i + segment] for i in range(0, x.shape[1], segment)]
+
+
+def _segmented_fwd(q, k, v, scale, plan):
+    segment, block = plan
+    blocks = (block, block)
+    qs, ks, vs = (_segments(x, segment) for x in (q, k, v))
+    outs, lses = [], []
+    for i, q_i in enumerate(qs):
+        parts = [_flash_fwd_impl(q_i, ks[j], vs[j], None, jnp.uint32(0),
+                                 i == j, scale, 0.0, blocks, _INTERPRET)
+                 for j in range(i + 1)]
+        lse = jnp.stack([p[1] for p in parts])            # [j, BH, S]
+        total = jax.scipy.special.logsumexp(lse, axis=0)
+        weight = jnp.exp(lse - total[None])
+        out = sum(p[0].astype(jnp.float32) * weight[j][..., None]
+                  for j, p in enumerate(parts))
+        outs.append(out.astype(q.dtype))
+        lses.append(total)
+    out = jnp.concatenate(outs, axis=1)
+    return out, (q, k, v, out, jnp.concatenate(lses, axis=1))
+
+
+def _segmented_bwd(scale, plan, res, g):
+    q, k, v, out, lse = res
+    segment, block = plan
+    blocks = (block, block)
+    qs, ks, vs, os_, ls, gs = (_segments(x, segment)
+                               for x in (q, k, v, out, lse, g))
+    n = len(qs)
+    dq = [None] * n
+    dk = [None] * n
+    dv = [None] * n
+
+    def add(acc, x):
+        x = x.astype(jnp.float32)
+        return x if acc is None else acc + x
+
+    for i in range(n):
+        for j in range(i + 1):
+            dq_ij, dk_ij, dv_ij, _ = _flash_bwd_impl(
+                qs[i], ks[j], vs[j], None, jnp.uint32(0), os_[i], ls[i],
+                gs[i], i == j, scale, 0.0, blocks, _INTERPRET)
+            dq[i] = add(dq[i], dq_ij)
+            dk[j] = add(dk[j], dk_ij)
+            dv[j] = add(dv[j], dv_ij)
+    return (jnp.concatenate(dq, axis=1).astype(q.dtype),
+            jnp.concatenate(dk, axis=1).astype(k.dtype),
+            jnp.concatenate(dv, axis=1).astype(v.dtype))
+
+
+_segmented_attention.defvjp(_segmented_fwd, _segmented_bwd)
+
+
+# ---------------------------------------------------------------------------
 # public entry: packed [B, T, H*D] layout used by the layers API
 # ---------------------------------------------------------------------------
 
@@ -1394,8 +1507,9 @@ def kernel_plan(q_shape, k_shape, num_heads, esize, causal=False,
     ``ops.gates.GateDecision`` (ISSUE 15): ``kernel`` is which path runs
     — ``dense_vmem`` (whole-sequence VMEM-resident, packed layout),
     ``packed_stream`` (copy-free streaming), ``head_split_stream``
-    (legacy streaming + the [B,T,H,D] relayout copies), or
-    ``reference`` — and ``reasons`` records every check that demoted the
+    (legacy streaming + the [B,T,H,D] relayout copies),
+    ``segmented_stream`` (the head-split kernels on segments of a causal
+    square whose head does not fit them whole), or ``reference`` — and ``reasons`` records every check that demoted the
     choice. This IS the dispatch logic :func:`flash_attention` runs
     (single source); the static resource pass evaluates it shape-only
     with ``platform_ok=True``.
@@ -1458,6 +1572,20 @@ def kernel_plan(q_shape, k_shape, num_heads, esize, causal=False,
             "env", "packed streaming disabled "
             "(PADDLE_TPU_SPLIT_STREAM / module A/B switch)",
             blocking=False))
+    if not _head_split_fits(t, t_k, d, esize) and causal \
+            and bias_kind is None and dropout_rate == 0.0:
+        segmented = _segment_plan(t, d, esize)
+        if segmented is not None:
+            reasons.append(GateReason(
+                "vmem", "one head's K/V (forward) or q/do/dq (backward) at "
+                "T=%d D=%d exceeds the %.0f MB VMEM budget: the head-split "
+                "kernels run on %d x %d segments of T in blocks of %d, "
+                "merged by logsumexp"
+                % (t, d, _STREAM_VMEM_BUDGET / 2**20, segmented[0],
+                   segmented[0], segmented[1])))
+            return GateDecision(True, "segmented_stream",
+                                fallback="head_split_stream",
+                                reasons=reasons)
     return GateDecision(True, "head_split_stream",
                         fallback="packed_stream", reasons=reasons)
 
@@ -1554,7 +1682,11 @@ def flash_attention(q, k, v, num_heads, bias=None, causal=False,
     vf = vh.reshape(b * num_heads, t_k, d)
     bf = (jnp.repeat(key_bias, num_heads, axis=0)
           if key_bias is not None else None)
-    out = _flash_attention(qf, kf, vf, bf, seed, causal, scale,
-                           float(dropout_rate))
+    if plan.kernel == "segmented_stream":
+        out = _segmented_attention(qf, kf, vf, scale,
+                                   _segment_plan(t, d, q.dtype.itemsize))
+    else:
+        out = _flash_attention(qf, kf, vf, bf, seed, causal, scale,
+                               float(dropout_rate))
     out = out.reshape(b, num_heads, t, d)
     return out.transpose(0, 2, 1, 3).reshape(b, t, hd)

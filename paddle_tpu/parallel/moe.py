@@ -1,74 +1,262 @@
-"""Mixture-of-Experts FFN with expert parallelism over an ``ep`` mesh axis.
+"""Routed experts: top-k token-choice routing over ``num_experts`` with the
+chip holding a contiguous share of them, no token ever dropped.
 
-Absent from the 2019 reference (SURVEY.md §2.5D: "Expert parallelism / MoE —
-no") but first-class here. TPU-native design (GShard-style): top-k token-
-choice gating with a static capacity, dispatch/combine expressed as dense
-einsums — the expert dimension of the weights carries a ``('ep', ...)``
-sharding spec, so GSPMD lowers the dispatch einsum to an all-to-all over ICI
-(no manual collectives; static shapes throughout).
+One routing implementation. The router scores every expert (its width is
+the whole model's), the ``top_k`` largest are taken and, with
+``renormalize``, their weights divided by their sum over ALL the picks.
+The chip then computes only the picks that fall on the experts it holds
+(``[lo, lo + held)``): what the absent experts would add is left out, which
+is this chip's addend of the layer's sum over the chips that share it.
+
+The work is binned, not one-hot: every (token, pick) assignment that falls
+on a held expert gets a row. The first ``slab_rows`` rows of each expert
+(four times its mean load, so what seeded weights send it at the first
+step) are one STATIC pass an expert: a row of the slab, three dense matrix
+products (SwiGLU), the expert's weight gradients written once. What a
+routing sends an expert beyond its slab goes to a table laid out expert
+after expert, each expert's rows padded up to a multiple of ``block_rows``;
+a block belongs to ONE expert, the table is sized for the worst case (every
+pick of every token held), and a loop with a DYNAMIC trip count walks only
+the blocks that hold rows, so the worst case costs memory for an index table
+(ints), not time, and no routing can overflow anything. A row costs the
+same in either place (my chip runs, PR 26); the slab's empty rows are paid
+for at every step, and in return a step's time does not follow the routing
+until an expert passes its slab. Reverse-mode autodiff cannot pass through
+a loop of dynamic length, so the experts' products are a ``custom_vjp``
+whose backward walks the same rows again (recomputing their hidden
+activations).
+
+Expert weights are stacked in the published per-expert layout
+``[held, out, in]``: ``gate``/``up`` [held, F, D], ``down`` [held, D, F].
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["moe_dispatch", "moe_ffn_apply"]
+__all__ = ["route_topk", "bin_assignments", "held_experts", "routed_experts",
+           "block_rows_for", "slab_rows_for"]
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def moe_dispatch(gate_logits, k=2, capacity_factor=1.25):
-    """Top-k gating with static expert capacity.
-
-    gate_logits: [T, E]. Returns (dispatch [T, E, C] one-hot, combine
-    [T, E, C] weights, aux_loss scalar). Tokens over capacity are dropped
-    (their combine weights are 0) — the standard static-shape formulation.
-    """
-    t, e = gate_logits.shape
-    c = max(1, int(capacity_factor * k * t / e))
-    probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-
-    # load-balancing auxiliary loss (Shazeer et al.): mean prob * mean
-    # assignment fraction per expert
-    top1 = jnp.argmax(probs, axis=-1)
-    frac_tokens = jnp.mean(jax.nn.one_hot(top1, e, dtype=jnp.float32), axis=0)
-    frac_probs = jnp.mean(probs, axis=0)
-    aux_loss = e * jnp.sum(frac_tokens * frac_probs)
-
-    dispatch = jnp.zeros((t, e, c), jnp.float32)
-    combine = jnp.zeros((t, e, c), jnp.float32)
-    masked = probs
-    used = jnp.zeros((e,), jnp.float32)  # slots consumed in earlier rounds
-    for _ in range(k):
-        choice = jnp.argmax(masked, axis=-1)  # [T]
-        gate = jnp.take_along_axis(masked, choice[:, None], axis=-1)[:, 0]
-        onehot = jax.nn.one_hot(choice, e, dtype=jnp.float32)  # [T, E]
-        # position within the chosen expert's buffer, offset by the slots
-        # already filled in previous rounds (GShard formulation — without
-        # the offset, round-2 tokens collide with round-1 slots)
-        pos = (jnp.cumsum(onehot, axis=0) - 1.0 + used[None, :]) * onehot
-        pos_id = jnp.sum(pos, axis=-1).astype(jnp.int32)  # [T]
-        in_cap = (pos_id < c).astype(jnp.float32)
-        slot = jax.nn.one_hot(pos_id, c, dtype=jnp.float32)  # [T, C]
-        d = onehot[:, :, None] * slot[:, None, :] * in_cap[:, None, None]
-        dispatch = dispatch + d
-        combine = combine + d * gate[:, None, None]
-        used = used + jnp.sum(onehot, axis=0)
-        masked = masked * (1.0 - onehot)  # exclude chosen expert next round
-    return dispatch, combine, aux_loss
+def block_rows_for(assignments):
+    """Rows of a block, from the number of (token, pick) assignments: 256 at
+    training sizes, smaller for small inputs so that several blocks and a
+    padded tail are exercised."""
+    rows = 8
+    while rows < 256 and rows * 32 < assignments:
+        rows *= 2
+    return rows
 
 
-def moe_ffn_apply(x, gate_w, w1, b1, w2, b2, k=2, capacity_factor=1.25,
-                  activation=jax.nn.relu):
-    """MoE feed-forward. x: [..., D]; gate_w: [D, E]; w1: [E, D, F];
-    w2: [E, F, D]. Returns (out [..., D], aux_loss)."""
-    lead = x.shape[:-1]
-    d = x.shape[-1]
-    xt = x.reshape(-1, d)  # [T, D]
-    logits = xt @ gate_w
-    dispatch, combine, aux = moe_dispatch(logits, k, capacity_factor)
-    # dispatch tokens to expert buffers: [E, C, D] — with w1/w2 sharded on
-    # the expert axis, GSPMD turns this einsum into the a2a dispatch
-    expert_in = jnp.einsum("tec,td->ecd", dispatch.astype(x.dtype), xt)
-    h = activation(jnp.einsum("ecd,edf->ecf", expert_in, w1)
-                   + b1[:, None, :])
-    expert_out = jnp.einsum("ecf,efd->ecd", h, w2) + b2[:, None, :]
-    out = jnp.einsum("tec,ecd->td", combine.astype(x.dtype), expert_out)
-    return out.reshape(lead + (d,)), aux
+def slab_rows_for(assignments, num_experts, block_rows):
+    """Rows of an expert's static pass: the power of two at or above four
+    times its mean load, at least a block."""
+    rows = block_rows
+    while rows * num_experts < 4 * assignments:
+        rows *= 2
+    return rows
+
+
+def route_topk(x, router_w, top_k, renormalize=True):
+    """x: [T, D]; router_w: [D, E]. Softmax over all E in float32, the
+    ``top_k`` largest. Returns (weights [T, k] float32, experts [T, k]
+    int32)."""
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                        router_w.astype(jnp.float32), precision=_HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, top_k)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts.astype(jnp.int32)
+
+
+def _max_rows(assignments, held, block_rows):
+    rows = assignments + held * (block_rows - 1)
+    return -(-rows // block_rows) * block_rows
+
+
+def bin_assignments(experts, lo, held, block_rows, slab_rows):
+    """Lay the assignments that fall on held experts out in rows.
+
+    experts: [T, k] int. The first ``slab_rows`` assignments of each held
+    expert go to its row of the slab, the rest to a table laid out expert
+    after expert, each expert's rows padded up to a multiple of
+    ``block_rows``. Returns (slab_assign [held, slab_rows] int32 and
+    row_assign [R] int32: the flat assignment ``t * k + j`` in each row,
+    ``T * k`` for an empty row; block_expert [R / block_rows] int32: the
+    held expert (0-based) a block of the table belongs to; blocks: how many
+    blocks hold rows; counts [held] int32: the tokens each held expert
+    took)."""
+    flat = experts.reshape(-1).astype(jnp.int32) - lo
+    a = flat.shape[0]
+    max_rows = _max_rows(a, held, block_rows)
+    hit = flat[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :]
+    running = jnp.cumsum(hit.astype(jnp.int32), axis=0)        # [A, held]
+    counts = running[-1]
+    rank = jnp.sum(jnp.where(hit, running - 1, 0), axis=1)     # in its expert
+    is_held = (flat >= 0) & (flat < held)
+    expert = jnp.clip(flat, 0, held - 1)
+    in_slab = is_held & (rank < slab_rows)
+    order = jnp.arange(a, dtype=jnp.int32)
+    slab_assign = jnp.full((held * slab_rows,), a, jnp.int32).at[
+        jnp.where(in_slab, expert * slab_rows + rank, held * slab_rows)
+    ].set(order, mode="drop").reshape(held, slab_rows)
+    over = jnp.maximum(counts - slab_rows, 0)
+    padded = -(-over // block_rows) * block_rows
+    ends = jnp.cumsum(padded)
+    starts = ends - padded
+    dest = jnp.where(is_held & ~in_slab,
+                     starts[expert] + rank - slab_rows, max_rows)
+    row_assign = jnp.full((max_rows,), a, jnp.int32).at[dest].set(
+        order, mode="drop")
+    first_row = jnp.arange(max_rows // block_rows, dtype=jnp.int32) \
+        * block_rows
+    block_expert = jnp.minimum(
+        jnp.searchsorted(ends, first_row, side="right"),
+        held - 1).astype(jnp.int32)
+    return (slab_assign, row_assign, block_expert, ends[-1] // block_rows,
+            counts)
+
+
+def _rows(x, weights_flat, ids, top_k):
+    """What a run of table rows ``ids`` reads: (token [R], past the end for
+    an empty row so that a scatter drops it, as ``ids`` itself is past the
+    assignments' end there; weight [R], 0 for an empty row; x rows
+    [R, D])."""
+    a, t = weights_flat.shape[0], x.shape[0]
+    valid = ids < a
+    tokens = jnp.where(valid, ids // top_k, t)
+    w = jnp.where(valid, weights_flat[jnp.minimum(ids, a - 1)], 0.0)
+    return tokens, w, x[jnp.minimum(tokens, t - 1)]
+
+
+def _mm(x, w, dims):
+    """Product with float32 accumulation, operands in the weights' dtype."""
+    return jax.lax.dot_general(x.astype(w.dtype), w, (dims, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _rows_fwd(x, weights_flat, ids, top_k, wg_e, wu_e, wd_e, out):
+    tokens, w, xb = _rows(x, weights_flat, ids, top_k)
+    g = _mm(xb, wg_e, ((1,), (1,)))                           # [R, F]
+    u = _mm(xb, wu_e, ((1,), (1,)))
+    y = _mm(jax.nn.silu(g) * u, wd_e, ((1,), (1,)))           # [R, D]
+    return out.at[tokens].add(y * w[:, None], mode="drop")
+
+
+def _rows_bwd(x, weights_flat, dout, ids, top_k, wg_e, wu_e, wd_e, dx, dw):
+    """Returns (dx, dw with this run's rows added, this run's addends to
+    the expert's weight gradients)."""
+    tokens, w, xb = _rows(x, weights_flat, ids, top_k)
+    g = _mm(xb, wg_e, ((1,), (1,)))
+    u = _mm(xb, wu_e, ((1,), (1,)))
+    sg = jax.nn.sigmoid(g)
+    h = g * sg * u
+    dyb = dout[jnp.minimum(tokens, x.shape[0] - 1)]           # [R, D]
+    y = _mm(h, wd_e, ((1,), (1,)))
+    dw = dw.at[ids].add(jnp.sum(y * dyb, -1), mode="drop")
+    dy = (dyb * w[:, None]).astype(wd_e.dtype)
+    dh = _mm(dy, wd_e, ((1,), (0,)))                          # [R, F]
+    dg = (dh * u * sg * (1.0 + g * (1.0 - sg))).astype(wg_e.dtype)
+    du = (dh * g * sg).astype(wg_e.dtype)
+    xbc = xb.astype(wg_e.dtype)
+    dxb = _mm(dg, wg_e, ((1,), (0,))) + _mm(du, wu_e, ((1,), (0,)))
+    return (dx.at[tokens].add(dxb, mode="drop"), dw,
+            _mm(dg.T, xbc, ((1,), (0,))), _mm(du.T, xbc, ((1,), (0,))),
+            _mm(dy.T, h.astype(wd_e.dtype), ((1,), (0,))))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def held_experts(x, weights, experts, wg, wu, wd, lo, block_rows,
+                 slab_rows):
+    """The held experts' part of the routed sum. x: [T, D]; weights,
+    experts: [T, k] from :func:`route_topk`; wg, wu: [held, F, D]; wd:
+    [held, D, F]; ``lo``: id of the first held expert. Returns (out [T, D]
+    float32, counts [held] int32)."""
+    (out, counts), _ = _held_fwd(x, weights, experts, wg, wu, wd, lo,
+                                 block_rows, slab_rows)
+    return out, counts
+
+
+def _held_fwd(x, weights, experts, wg, wu, wd, lo, block_rows, slab_rows):
+    held = wg.shape[0]
+    top_k = weights.shape[1]
+    slab_assign, row_assign, block_expert, blocks, counts = bin_assignments(
+        experts, lo, held, block_rows, slab_rows)
+    weights_flat = weights.reshape(-1).astype(jnp.float32)
+
+    def slab(out, per):
+        ids, wg_e, wu_e, wd_e = per
+        return _rows_fwd(x, weights_flat, ids, top_k, wg_e, wu_e, wd_e,
+                         out), None
+
+    out, _ = jax.lax.scan(slab, jnp.zeros(x.shape, jnp.float32),
+                          (slab_assign, wg, wu, wd))
+
+    def body(bi, out):
+        ids = jax.lax.dynamic_slice(row_assign, (bi * block_rows,),
+                                    (block_rows,))
+        e = block_expert[bi]
+        return _rows_fwd(x, weights_flat, ids, top_k, wg[e], wu[e], wd[e],
+                         out)
+
+    out = jax.lax.fori_loop(0, blocks, body, out)
+    saved = (x, weights, wg, wu, wd, slab_assign, row_assign, block_expert,
+             blocks)
+    return (out, counts), saved
+
+
+def _held_bwd(lo, block_rows, slab_rows, saved, cotangent):
+    (x, weights, wg, wu, wd, slab_assign, row_assign, block_expert,
+     blocks) = saved
+    dout = cotangent[0].astype(jnp.float32)
+    top_k = weights.shape[1]
+    weights_flat = weights.reshape(-1).astype(jnp.float32)
+    f32 = jnp.float32
+
+    def slab(carry, per):
+        ids, wg_e, wu_e, wd_e = per
+        dx, dw, *grads = _rows_bwd(x, weights_flat, dout, ids, top_k, wg_e,
+                                   wu_e, wd_e, *carry)
+        return (dx, dw), tuple(grads)
+
+    (dx, dw), (dwg, dwu, dwd) = jax.lax.scan(
+        slab, (jnp.zeros(x.shape, f32), jnp.zeros(weights_flat.shape, f32)),
+        (slab_assign, wg, wu, wd))
+
+    def body(bi, carry):
+        dx, dw, dwg, dwu, dwd = carry
+        ids = jax.lax.dynamic_slice(row_assign, (bi * block_rows,),
+                                    (block_rows,))
+        e = block_expert[bi]
+        dx, dw, g_e, u_e, d_e = _rows_bwd(x, weights_flat, dout, ids, top_k,
+                                          wg[e], wu[e], wd[e], dx, dw)
+        return (dx, dw, dwg.at[e].add(g_e), dwu.at[e].add(u_e),
+                dwd.at[e].add(d_e))
+
+    dx, dw, dwg, dwu, dwd = jax.lax.fori_loop(
+        0, blocks, body, (dx, dw, dwg, dwu, dwd))
+    return (dx.astype(x.dtype), dw.reshape(weights.shape).astype(
+        weights.dtype), None, dwg.astype(wg.dtype), dwu.astype(wu.dtype),
+        dwd.astype(wd.dtype))
+
+
+held_experts.defvjp(_held_fwd, _held_bwd)
+
+
+def routed_experts(x, router_w, wg, wu, wd, top_k, lo=0, renormalize=True,
+                   block_rows=None, slab_rows=None):
+    """x: [..., D]. Returns (routed [..., D] float32: the held experts' part
+    of the layer's routed sum; counts [held] int32)."""
+    lead, d = x.shape[:-1], x.shape[-1]
+    xt = x.reshape(-1, d)
+    weights, experts = route_topk(xt, router_w, top_k, renormalize)
+    a = xt.shape[0] * top_k
+    rows = block_rows or block_rows_for(a)
+    slab = slab_rows or slab_rows_for(a, router_w.shape[1], rows)
+    out, counts = held_experts(xt, weights, experts, wg, wu, wd, int(lo),
+                               int(rows), int(slab))
+    return out.reshape(lead + (d,)), counts

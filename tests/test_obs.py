@@ -758,7 +758,9 @@ def test_replaced_state_array_is_staged_again_not_refused():
         .all_parameters()[0].name
     scope.set(weight, jnp.asarray(scope.get(weight), jnp.bfloat16))
     got, = exe.run(feed=feed, fetch_list=[out])
-    np.testing.assert_allclose(got, want, rtol=2e-2)
+    # atol: an output that happens to lie near 0 moves by the weight's
+    # bfloat16 rounding, which no relative bound covers
+    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-3)
     assert [r["restaged"] for r in exe.compile_records] == [
         False, False, True]
 

@@ -361,43 +361,6 @@ def test_pipeline_differentiable():
     assert float(jnp.abs(g["w"]).sum()) > 0
 
 
-def test_moe_ffn_trains_with_ep_mesh():
-    mesh = _mesh((2, 4), ("dp", "ep"))
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        spec = models.transformer.transformer_base(
-            src_vocab=64, trg_vocab=64, seq_len=16, d_model=32, d_ff=64,
-            n_head=2, n_layer=2, dropout_rate=0.0, moe_experts=4)
-        fluid.optimizer.Adam(learning_rate=3e-3).minimize(spec.loss)
-    scope = fluid.Scope()
-    with fluid.scope_guard(scope):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        cp = fluid.CompiledProgram(main).with_data_parallel(
-            loss_name=spec.loss.name, mesh=mesh)
-        batch = spec.sample_batch(4, np.random.RandomState(3))
-        first = last = None
-        for _ in range(6):
-            lv, = exe.run(cp, feed=batch, fetch_list=[spec.loss])
-            first = first if first is not None else float(lv)
-            last = float(lv)
-    assert np.isfinite(last) and last < first
-
-
-def test_moe_dispatch_weights_sum():
-    from paddle_tpu.parallel.moe import moe_dispatch
-
-    rng = np.random.RandomState(0)
-    logits = jnp.array(rng.randn(16, 4).astype("f4"))
-    dispatch, combine, aux = moe_dispatch(logits, k=2, capacity_factor=2.0)
-    # with ample capacity every token lands in exactly k expert slots
-    np.testing.assert_allclose(np.asarray(dispatch.sum(axis=(1, 2))), 2.0)
-    # and no (expert, slot) pair receives more than one token (GShard
-    # cross-round slot offset — collisions silently blend tokens)
-    assert float(np.asarray(dispatch.sum(axis=0)).max()) <= 1.0 + 1e-6
-    assert float(aux) > 0
-
-
 # ---------------------------------------------------------------------------
 # pipeline parallelism through the Program path (CompiledProgram.with_pipeline)
 # ---------------------------------------------------------------------------

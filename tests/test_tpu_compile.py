@@ -143,6 +143,64 @@ def test_flash_attention_compiles(chip, shape, causal, with_bias, plan):
     _assert_named(compiled, {plan + ".fwd", plan + ".bwd"})
 
 
+def test_wide_head_at_8192_compiles_in_segments(chip):
+    """``qwen3next.train.s8192``'s attention: 16 heads of 256 at T = 8192,
+    causal, no dropout. One head's K/V is past the head-split kernels' VMEM
+    (the chip's compiler refused their backward on 2048-token segments in
+    blocks of 512 at 16.14M of 16M, inside the step), so the plan cuts T
+    into 2048-token segments in blocks of 256: ten forward and ten backward
+    calls of the same named kernels."""
+    b, t, hd, heads = 1, 8192, 4096, 16
+    with placed("tpu"):
+        decision = fa.kernel_plan((b, t, hd), (b, t, hd), heads, 2,
+                                  causal=True)
+    assert decision.kernel == "segmented_stream", decision
+    assert fa._segment_plan(t, hd // heads, 2) == (2048, 256)
+    assert not fa._head_split_fits(2048, 2048, 256, 2, (512, 512))
+
+    def loss(q, k, v, g):
+        out = fa.flash_attention(q, k, v, heads, causal=True)
+        return jnp.sum(out.astype(F32) * g.astype(F32))
+
+    x = sds((b, t, hd), BF16)
+    compiled = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)), x, x, x, x)
+    assert _kernel_calls(compiled) == 20
+    _assert_named(compiled, {"head_split_stream.fwd",
+                             "head_split_stream.bwd"})
+
+
+def test_gated_delta_core_and_routed_experts_fit_at_published_widths(chip):
+    """The chunked delta rule of one layer (32 heads of 128 x 128 state,
+    T = 8192) keeps under 1 GB of temporaries with its groups of chunks
+    recomputed (2.9 GB before), and the binned expert blocks (16 of 512
+    experts, top-10) under 0.5 GB: what lets four layers fit one chip."""
+    from paddle_tpu.ops import gated_delta
+    from paddle_tpu.parallel import moe
+
+    t = 8192
+
+    def core(q, k, v, a, b, a_log, dt_bias):
+        return jnp.sum(gated_delta.gated_delta_attention(
+            q, k, v, a, b, a_log, dt_bias, 16, 32, 64, BF16))
+
+    compiled = _compile(
+        chip, jax.grad(core, argnums=(0, 1, 2, 3, 4, 5, 6)),
+        sds((1, t, 2048), BF16), sds((1, t, 2048), BF16),
+        sds((1, t, 4096), BF16), sds((1, t, 32), BF16),
+        sds((1, t, 32), BF16), sds((32,), F32), sds((32,), F32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+    def experts(x, router, wg, wu, wd):
+        return jnp.sum(moe.routed_experts(x, router, wg, wu, wd, 10, 0)[0])
+
+    compiled = _compile(
+        chip, jax.grad(experts, argnums=(0, 1, 2, 3, 4)),
+        sds((t, 2048), BF16), sds((2048, 512), F32),
+        sds((16, 512, 2048), BF16), sds((16, 512, 2048), BF16),
+        sds((16, 2048, 512), BF16))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
 def test_packed_stream_gate_counts_what_mosaic_allocates():
     """The packed backward at the seq-2048 bench shape is what the chip's
     compiler refused (16.66M of 16M scoped VMEM alone, 19.16M inside the
