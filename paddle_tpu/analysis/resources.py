@@ -8,7 +8,8 @@ not a correctness property of the program):
 
   * **vmem-gate** — the Pallas kernel family's admission gates
     (``ops/fused_conv.gate``, ``ops/scatter.gate``,
-    ``ops/flash_attention.kernel_plan``) evaluated SHAPE-ONLY
+    ``ops/flash_attention.kernel_plan``, ``ops/gated_delta.kernel_plan``)
+    evaluated SHAPE-ONLY
     (``static_only`` / ``platform_ok=True``): a program that will
     silently fall off its fused kernel on the bench chip is reported at
     build time as a finding with op provenance and the gate's structured
@@ -58,7 +59,10 @@ def check_vmem_gates(region, batch=None, amp=False, diags=None):
       * sparse-update ``scatter``/optimizer tables and ``flash_attention``
         sites blocked ONLY by the VMEM budget — the actionable class
         (raise the budget or shrink the shape; everything else about the
-        shape qualifies)."""
+        shape qualifies);
+      * ``gated_delta_rule`` refused for any static reason: the site then
+        runs XLA's chunk scan (a head dimension that is no multiple of
+        128, another chunk than 64)."""
     from .cost import CostCtx
 
     diags = [] if diags is None else diags
@@ -71,6 +75,8 @@ def check_vmem_gates(region, batch=None, amp=False, diags=None):
             _check_flash(ctx, op, reg.name, diags)
         elif op.type in ("lookup_table", "sharded_lookup_table"):
             _check_sparse_table(ctx, op, reg.name, diags)
+        elif op.type == "gated_delta_rule":
+            _check_gated_delta(ctx, op, reg.name, diags)
     return diags
 
 
@@ -116,6 +122,20 @@ def _check_flash(ctx, op, region, diags):
     if plan.kernel in ("reference", "head_split_stream") and \
             plan.blocked_only_by("vmem"):
         diags.append(_gate_diag(op, plan, region, "packed_stream"))
+
+
+def _check_gated_delta(ctx, op, region, diags):
+    from ..ops import gated_delta
+
+    qs, vs = ctx.shape(op.input("Q")), ctx.shape(op.input("V"))
+    if qs is None or vs is None or len(qs) != 3 or len(vs) != 3:
+        return
+    hk, hv = int(op.attr("num_k_heads")), int(op.attr("num_v_heads"))
+    plan = gated_delta.kernel_plan(
+        vs[1], hk, hv, qs[-1] // hk, vs[-1] // hv,
+        int(op.attr("chunk", 64)), platform_ok=True)
+    if not plan:
+        diags.append(_gate_diag(op, plan, region, "gated_delta"))
 
 
 def _check_sparse_table(ctx, op, region, diags):

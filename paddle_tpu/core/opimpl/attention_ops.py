@@ -57,24 +57,29 @@ def _gated_delta_rule(env, op):
     and V [B, T, Hv*Dv] after the causal convolution, the raw decay and
     write-strength projections A, B [B, T, Hv], the heads' ALog and DtBias;
     L2-normalised q/k, the delta rule over a [Dk, Dv] state a head in
-    chunks of ``chunk`` tokens. Out [B, T, Hv*Dv] in V's dtype."""
+    chunks of ``chunk`` tokens. Out [B, T, Hv*Dv] in V's dtype. On one TPU,
+    at head dimensions that are multiples of 128 and chunk 64, the
+    ``gated_delta`` Pallas kernels run (``gated_delta.kernel_plan``);
+    elsewhere (CPU, a meshed step, other shapes) the chunked ``jnp`` form,
+    and the site's decision says which and why."""
     from ...ops import gated_delta
-    from ...ops.gates import GateDecision, GateReason, note
+    from ...ops.gates import note
     from ..op_registry import amp_enabled
 
-    v = get(env, op.input("V"))
+    q, v = get(env, op.input("Q")), get(env, op.input("V"))
     chunk = int(op.attr("chunk", 64))
-    note("gated_delta_rule", GateDecision(True, "chunked_scan_xla", reasons=[
-        GateReason("shape", "%d chunks of %d tokens, the WY form through "
-                   "XLA, backward by autodiff of the chunk scan; no Pallas "
-                   "kernel" % (-(-v.shape[1] // chunk), chunk),
-                   blocking=False)]))
+    hk, hv = int(op.attr("num_k_heads")), int(op.attr("num_v_heads"))
+    plan = gated_delta.kernel_plan(
+        v.shape[1], hk, hv, q.shape[-1] // hk, v.shape[-1] // hv, chunk,
+        platform_ok=gated_delta._use_pallas())
+    op.attrs["_kernel_choice"] = plan.to_dict()
+    note("gated_delta_rule", plan)
     out = gated_delta.gated_delta_attention(
-        get(env, op.input("Q")), get(env, op.input("K")), v,
+        q, get(env, op.input("K")), v,
         get(env, op.input("A")), get(env, op.input("B")),
         get(env, op.input("ALog")), get(env, op.input("DtBias")),
-        int(op.attr("num_k_heads")), int(op.attr("num_v_heads")), chunk,
-        mxu_dtype=jnp.bfloat16 if amp_enabled() else None)
+        hk, hv, chunk, mxu_dtype=jnp.bfloat16 if amp_enabled() else None,
+        plan=plan)
     put(env, op.output("Out"), out.astype(v.dtype))
 
 

@@ -181,40 +181,126 @@ def test_gated_delta_net_against_plain_recurrence():
         {"x": _randn(rng, 2, T, 64)}, perturb=0.1)
 
 
-@pytest.mark.parametrize("t,chunk,group", [(24, 8, 16), (T, 8, 2),
-                                           (T, 8, 1), (7, 8, 16)])
+def _delta_rule(form, chunk, group, mxu, rep):
+    """q, k [B, T, Hk, Dk], v [B, T, Hv, Dv], g, beta [B, T, Hv] -> out, by
+    the token-by-token recurrence, the chunked ``jnp`` form (both on key
+    heads repeated to the value heads) or the ``gated_delta`` kernels."""
+    def repeated(fn):
+        return lambda q, k, *rest: fn(jnp.repeat(q, rep, 2),
+                                      jnp.repeat(k, rep, 2), *rest)
+
+    if form == "recurrence":
+        return repeated(gated_delta.recurrent_gated_delta_rule)
+    if form == "jnp":
+        return repeated(lambda *a: gated_delta.chunk_gated_delta_rule(
+            *a, chunk=chunk, group=group, mxu_dtype=mxu))
+    return lambda *a: gated_delta.kernel_gated_delta_rule(
+        *a, chunk=chunk, mxu_dtype=mxu)
+
+
+@pytest.mark.parametrize("form,t,chunk,group,heads,mxu,repeat_keys", [
+    ("jnp", 24, 8, 16, (3, 3), None, False),
+    ("jnp", T, 8, 2, (3, 3), None, False),
+    ("jnp", T, 8, 1, (3, 3), None, False),
+    ("jnp", 7, 8, 16, (3, 3), None, False),
+    # the Pallas kernels, interpret mode: a block of four chunks and a tail
+    # that is no whole chunk; less than one chunk; two groups of key heads;
+    # chunks of 64 (the inverse's merges) over three blocks; bfloat16
+    # operands; and keys that repeat inside a chunk, where a power of A
+    # would grow and the substitution does not
+    ("kernel", T, 8, 16, (3, 3), None, False),
+    ("kernel", 7, 8, 16, (3, 3), None, False),
+    ("kernel", 2 * T, 8, 16, (2, 4), None, False),
+    ("kernel", 150, 32, 16, (1, 2), None, False),
+    ("kernel", 300, 64, 16, (1, 1), None, False),
+    ("kernel", 2 * T, 8, 16, (2, 4), jnp.bfloat16, False),
+    ("kernel", 150, 64, 16, (1, 2), None, True),
+    ("jnp", 150, 64, 16, (1, 2), None, True),
+])
 def test_chunked_delta_rule_equals_the_recurrence_under_slow_decays(
-        t, chunk, group):
+        form, t, chunk, group, heads, mxu, repeat_keys, monkeypatch):
     """Decays near 0 (a state that lives for hundreds of tokens), so that
     the last chunk's outputs still depend on the first chunk's writes: an
-    error in the state carried from chunk to chunk, or from one recomputed
-    group of chunks to the next, cannot hide."""
+    error in the state carried from chunk to chunk, from one recomputed
+    group of chunks to the next, or from one grid step of the kernels to
+    the next, cannot hide. Outputs and all five input gradients, against
+    the recurrence and, for the kernels, against the chunked ``jnp`` form
+    as well (with bfloat16 operands against that alone: the two round at
+    the same places)."""
+    monkeypatch.setattr(gated_delta, "_INTERPRET", True)
     rng = np.random.default_rng(6)
-    b, h, dk, dv = 2, 3, 16, 8
-    q, k = _randn(rng, b, t, h, dk), _randn(rng, b, t, h, dk)
+    hk, hv = heads
+    b, dk, dv = 2, 16, 8
+    q, k = _randn(rng, b, t, hk, dk), _randn(rng, b, t, hk, dk)
+    if repeat_keys:     # every key three times in a row
+        k = np.repeat(k[:, ::3], 3, axis=1)[:, :t]
     q /= np.linalg.norm(q, axis=-1, keepdims=True)
     k /= np.linalg.norm(k, axis=-1, keepdims=True)
-    v = _randn(rng, b, t, h, dv)
-    g = -0.01 * np.abs(_randn(rng, b, t, h))
-    beta = rng.uniform(0.2, 1.0, (b, t, h)).astype(np.float32)
+    v = _randn(rng, b, t, hv, dv)
+    g = -0.01 * np.abs(_randn(rng, b, t, hv))
+    beta = rng.uniform(0.2, 1.0, (b, t, hv)).astype(np.float32)
     args = (q, k, v, g, beta)
-    want = gated_delta.recurrent_gated_delta_rule(*args)
-    got = gated_delta.chunk_gated_delta_rule(*args, chunk=chunk,
-                                             group=group)
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
-    # the first chunk's values reach the last token
-    reach = jax.grad(lambda v: jnp.sum(
-        gated_delta.recurrent_gated_delta_rule(q, k, v, g, beta)[:, -1]))(v)
-    assert float(jnp.abs(reach[:, 0]).max()) > 1e-3
+    ours = _delta_rule(form, chunk, group, mxu, hv // hk)
+    recurrence = _delta_rule("recurrence", chunk, group, None, hv // hk)
+    want = recurrence(*args)
     cot = _randn(rng, *want.shape)
-    wanted = jax.grad(lambda *a: jnp.sum(
-        gated_delta.recurrent_gated_delta_rule(*a) * cot),
-        argnums=(0, 1, 2, 3, 4))(*args)
-    gotten = jax.grad(lambda *a: jnp.sum(
-        gated_delta.chunk_gated_delta_rule(*a, chunk=chunk, group=group)
-        * cot), argnums=(0, 1, 2, 3, 4))(*args)
-    for name, a, w in zip("q k v g beta".split(), gotten, wanted):
-        np.testing.assert_allclose(a, w, rtol=2e-3, atol=2e-4, err_msg=name)
+    # the first chunk's values reach the last token
+    reach = jax.grad(lambda v: jnp.sum(recurrence(q, k, v, g, beta)[:, -1]))(
+        v)
+    assert float(jnp.abs(reach[:, 0]).max()) > (1e-3 if t < 300 else 1e-6)
+
+    def with_grads(fn):
+        return (fn(*args),) + jax.grad(
+            lambda *a: jnp.sum(fn(*a) * cot), argnums=(0, 1, 2, 3, 4))(*args)
+
+    gotten = with_grads(ours)
+    against = [(with_grads(recurrence), 1e-4, 1e-5, 2e-3, 2e-4)]
+    if form == "kernel":
+        chunked = with_grads(_delta_rule("jnp", chunk, group, mxu, hv // hk))
+        against.append((chunked, 1e-4, 1e-5, 2e-3, 2e-4))
+        if mxu is not None:     # bfloat16: only the form that rounds alike
+            against = [(chunked, 2e-2, 2e-2, 2e-2, 5e-2)]
+    for wanted, rtol, atol, grad_rtol, grad_atol in against:
+        np.testing.assert_allclose(gotten[0], wanted[0], rtol=rtol,
+                                   atol=atol)
+        for name, a, w in zip("q k v g beta".split(), gotten[1:],
+                              wanted[1:]):
+            np.testing.assert_allclose(a, w, rtol=grad_rtol, atol=grad_atol,
+                                       err_msg=name)
+
+
+@pytest.mark.parametrize("mxu", [None, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_gated_delta_kernels_equal_the_chunked_form_on_packed_heads(
+        mxu, monkeypatch):
+    """The op's entry (packed heads, raw q and k that the kernels
+    L2-normalise themselves, gates made round them) through the
+    ``gated_delta`` kernels against the chunked ``jnp`` form: the output and
+    the gradients of all seven inputs, two value heads a key head, a block
+    and a tail."""
+    from paddle_tpu.ops.gates import GateDecision
+
+    monkeypatch.setattr(gated_delta, "_INTERPRET", True)
+    rng = np.random.default_rng(3)
+    b, t = 2, 45
+    args = tuple(jnp.asarray(x) for x in (
+        _randn(rng, b, t, 32), _randn(rng, b, t, 32), _randn(rng, b, t, 32),
+        _randn(rng, b, t, 4), _randn(rng, b, t, 4),
+        0.3 * _randn(rng, 4) - 2.0, 0.3 * _randn(rng, 4) - 3.0))
+    cot = _randn(rng, b, t, 32)
+
+    def with_grads(admitted):
+        def fn(*a):
+            return gated_delta.gated_delta_attention(
+                *a, 2, 4, 8, mxu, plan=GateDecision(admitted, "forced"))
+        return (fn(*args),) + jax.grad(
+            lambda *a: jnp.sum(fn(*a) * cot), argnums=tuple(range(7)))(*args)
+
+    tol = dict(rtol=2e-4, atol=2e-6) if mxu is None else \
+        dict(rtol=2e-2, atol=1e-2)
+    for name, got, want in zip("out q k v a b a_log dt_bias".split(),
+                               with_grads(True), with_grads(False)):
+        np.testing.assert_allclose(got, want, err_msg=name, **tol)
 
 
 # -- routed experts ---------------------------------------------------------
@@ -398,6 +484,47 @@ def test_kernel_plan_segments_a_wide_head_at_8192_and_leaves_the_rest():
                           causal=True).kernel == "head_split_stream"
     assert fa.kernel_plan((16, 2048, 512), (16, 2048, 512), 8, 2,
                           causal=False).kernel == "head_split_stream"
+
+
+def test_gated_delta_gate_admits_the_published_shape_and_says_why_not():
+    """The cell's shape (T 8192, 16 key and 32 value heads of 128, chunk
+    64) is admitted shape-only; a head of 64, another chunk and a placement
+    that is no single TPU are refused, each with its reason."""
+    plan = gated_delta.kernel_plan(8192, 16, 32, 128, 128, 64)
+    assert plan.admitted and plan.kernel == "gated_delta"
+    assert plan.describe() == "kernel gated_delta"
+    narrow = gated_delta.kernel_plan(8192, 16, 32, 64, 128, 64)
+    assert not narrow and narrow.kernel == "chunked_scan_xla"
+    assert narrow.blocked_only_by("geometry") and "128" in narrow.describe()
+    assert gated_delta.kernel_plan(8192, 16, 32, 128, 128,
+                                   32).blocked_only_by("geometry")
+    from paddle_tpu.core.op_registry import placed
+
+    for where in (dict(platform="tpu", meshed=True), dict(platform="cpu")):
+        with placed(**where):
+            off = gated_delta.kernel_plan(
+                8192, 16, 32, 128, 128, 64,
+                platform_ok=gated_delta._use_pallas())
+        assert off.kernel == "chunked_scan_xla"
+        assert off.blocked_only_by("platform")
+        assert ("mesh" in off.describe()) == bool(where.get("meshed"))
+    with placed("tpu"):
+        assert gated_delta.kernel_plan(
+            8192, 16, 32, 128, 128, 64,
+            platform_ok=gated_delta._use_pallas()).admitted
+    # the shape-only pass reads the same gate
+    from paddle_tpu.analysis import resources
+
+    for head, refused in ((128, False), (64, True)):
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup), fluid.unique_name.guard():
+            layers.gated_delta_net(layers.data("x", shape=[8192, 256]), 16,
+                                   32, head, head, name="g")
+        finds = [d for d in resources.check_resources(main, batch=1).warnings
+                 if d.check == "vmem-gate"]
+        assert bool(finds) == refused
+        assert all("gated_delta_rule" in d.message
+                   and "chunked_scan_xla" in d.message for d in finds)
 
 
 def test_segmented_stream_equals_the_reference(monkeypatch):
