@@ -274,8 +274,7 @@ def _bench_static(model, on_tpu, seq_override=None):
         # roofline basis: embedding-bound CTR is per-ROW-LATENCY-bound on
         # TPU, so the floor sums the MLP's MXU time with the measured
         # per-row gather/scatter latencies. The constants are SOURCED
-        # from ROW_OP_FLOORS.json (tools/bench_gather.py --write; the
-        # CHIP_CEILING.json pattern) via models/deepfm.py row_op_floors —
+        # from ROW_OP_FLOORS.json via models/deepfm.py row_op_floors —
         # tests/test_bench_contract.py pins the sourcing.
         config["row_latency_s_per_example"] = \
             spec.extras["row_latency_s_per_example"]
@@ -292,7 +291,6 @@ def _bench_static(model, on_tpu, seq_override=None):
         # platform, and the analytic ICI bytes of both lookup
         # formulations at the bench id count — the re-derivable honesty
         # line for the O(n*D + n) vs O(mp*n*D) claim.
-        from paddle_tpu.core.op_registry import env_flag
         from paddle_tpu.ops import scatter as scatter_mod
         from paddle_tpu.parallel import sharded_embedding as semb
 
@@ -308,14 +306,8 @@ def _bench_static(model, on_tpu, seq_override=None):
             n_ids=n_ids, width=ft["width"], mp=ref_mp)
         # the sparse backward densifies at the PARAM dtype (f32 master
         # table) regardless of AMP — gate the kernel claim on that
-        if scatter_mod.use_pallas(ft["vocab"], ft["width"], n_ids,
-                                  "float32"):
-            config["scatter_kernel"] = (
-                "pallas_sorted_segment"
-                if env_flag("PADDLE_TPU_SCATTER_SORT") else
-                "pallas_rowbin")
-        else:
-            config["scatter_kernel"] = "xla_at_add"
+        config["scatter_kernel"] = scatter_mod.gate(
+            ft["vocab"], ft["width"], n_ids, "float32").kernel
     elif peak is not None:
         flops_per_step = (spec.flops_per_example or 0) * batch
         vsb = (flops_per_step * steps / dt) / peak / 0.45
@@ -330,30 +322,22 @@ def _bench_static(model, on_tpu, seq_override=None):
         # the HBM-bound config: its roofline is judged against the
         # matrix-derived ceiling, so the operative constant rides in the
         # record (tests/test_bench_contract.py pins the sourcing)
-        from paddle_tpu.core.epilogue_fusion import fusion_enabled
-
         ceil = _chip_ceiling()
         config["hbm_gbs"] = ceil.get("hbm_operative_gbs")
         config["hbm_ceiling_source"] = "CHIP_CEILING.json"
-        config["fused_conv"] = fusion_enabled()
+        config["fused_conv"] = True  # build_step_fn fuses unless pipelined
     if model == "transformer" and seq_len is not None and seq_len > 512:
         # the streaming-attention config: record the kernel geometry and
         # which streaming path (packed copy-free vs legacy head-split)
         # produced the number
-        from paddle_tpu.core.op_registry import env_flag
         from paddle_tpu.ops import flash_attention as fa
 
-        config["flash_block"] = int(
-            os.environ.get("PADDLE_TPU_FLASH_BLOCK", 512))
-        config["packed_stream"] = bool(
-            fa._PACKED_STREAM
-            and not env_flag("PADDLE_TPU_SPLIT_STREAM")
-            # The gate inputs mirror the FIXED bench config (transformer-
-            # base: H*D=512, 8 heads, dropout 0.1) — the field describes
-            # this bench line, not an arbitrary model's gate decision
-            and fa._packed_stream_fits(
-                seq_len, seq_len, 512, 2 if amp_on else 4, 8,
-                dropout=0.1))
+        config["flash_block"] = fa._block_sizes(seq_len, seq_len)[0]
+        # The gate inputs mirror the FIXED bench config (transformer-
+        # base: H*D=512, 8 heads) — the field describes this bench line,
+        # not an arbitrary model's gate decision
+        config["packed_stream"] = fa._packed_stream_fits(
+            seq_len, seq_len, 512, 2 if amp_on else 4, 8)
     return {"metric": metric, "value": round(examples_per_sec, 1),
             "unit": unit,
             "vs_baseline": None if vsb is None else round(vsb, 4),

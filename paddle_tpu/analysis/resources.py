@@ -10,7 +10,7 @@ not a correctness property of the program):
     (``ops/fused_conv.gate``, ``ops/scatter.gate``,
     ``ops/flash_attention.kernel_plan``, ``ops/gated_delta.kernel_plan``)
     evaluated SHAPE-ONLY
-    (``static_only`` / ``platform_ok=True``): a program that will
+    (``static_only`` / no ``platform``): a program that will
     silently fall off its fused kernel on the bench chip is reported at
     build time as a finding with op provenance and the gate's structured
     reasons, instead of a quiet perf cliff.
@@ -118,7 +118,7 @@ def _check_flash(ctx, op, region, diags):
         qs, ks, op.attr("num_heads", 1), esize,
         causal=op.attr("causal", False),
         dropout_rate=op.attr("dropout_rate", 0.0) or 0.0,
-        bias_kind=bias_kind, rng_available=True, platform_ok=True)
+        bias_kind=bias_kind, rng_available=True)
     if plan.kernel in ("reference", "head_split_stream") and \
             plan.blocked_only_by("vmem"):
         diags.append(_gate_diag(op, plan, region, "packed_stream"))
@@ -133,7 +133,7 @@ def _check_gated_delta(ctx, op, region, diags):
     hk, hv = int(op.attr("num_k_heads")), int(op.attr("num_v_heads"))
     plan = gated_delta.kernel_plan(
         vs[1], hk, hv, qs[-1] // hk, vs[-1] // hv,
-        int(op.attr("chunk", 64)), platform_ok=True)
+        int(op.attr("chunk", 64)))
     if not plan:
         diags.append(_gate_diag(op, plan, region, "gated_delta"))
 

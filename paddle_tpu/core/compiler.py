@@ -76,9 +76,7 @@ class CompiledProgram:
 
         ``sequence_feeds``: with ``sp_axis`` set, the feed names whose dim 1
         is the sequence axis to shard — model specs carry them as
-        ``spec.sequence_feeds``. With None, feeds shard on dp only,
-        unless PADDLE_TPU_SP_HEURISTIC=1 opts into the longest-dim-1
-        shape guess (a warning names the classified feeds)."""
+        ``spec.sequence_feeds``. With None, feeds shard on dp only."""
         self._build_strategy = build_strategy or BuildStrategy()
         self._exec_strategy = exec_strategy or ExecutionStrategy()
         self._dp_axis = dp_axis

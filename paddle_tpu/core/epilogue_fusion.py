@@ -28,25 +28,16 @@ deliberately NOT a dataflow sub-region.
 Wired in at executor trace time (``executor.build_step_fn``) — including
 the ``autodiff``/``autodiff_vjp`` replay lists, so the backward
 recomputation fuses too — and exposed as :func:`fuse_program` for
-verifier-level use (``tests/test_analysis.py``). ``PADDLE_TPU_FUSE_CONV=0``
-disables the rewrite wholesale.
+verifier-level use (``tests/test_analysis.py``).
 """
-
-import os
 
 from ..analysis.dataflow import build_region
 from .framework import Operator, Parameter
 
 __all__ = ["FusionSite", "FusionRefusal", "FusionReport", "fuse_ops",
-           "fuse_program", "fusion_enabled"]
+           "fuse_program"]
 
 _REPLAY_OPS = ("autodiff", "autodiff_vjp")
-
-
-def fusion_enabled():
-    """Default-on; PADDLE_TPU_FUSE_CONV=0 (or false/off) disables."""
-    return os.environ.get("PADDLE_TPU_FUSE_CONV", "").strip().lower() \
-        not in ("0", "false", "off", "no")
 
 
 class FusionSite:

@@ -30,7 +30,8 @@ import numpy as np
 
 from . import framework
 from .framework import Variable
-from .op_registry import run_op, placed, RNG_KEY, RNG0_KEY, ENV0_KEY
+from .op_registry import run_op, RNG_KEY, RNG0_KEY, ENV0_KEY
+from ..ops.gates import placed
 from ..obs import trace as obs_trace
 # the span primitive's second sink (jax.profiler.TraceAnnotation) is
 # installed by the module that owns the profiler surface
@@ -177,38 +178,31 @@ def _as_array(value, var=None):
 
 
 def _make_rng_key(seed, platform):
-    """Threaded PRNG key. On TPU the counter-based ``rbg`` generator is used
-    by default: it maps onto the hardware RNG instruction and is far cheaper
-    than threefry for the per-step dropout masks (threefry lowers to long
-    scalar-ish bit-mix chains that steal MXU-adjacent cycles). Override with
-    PADDLE_TPU_RNG=threefry for bit-exact parity with stock jax keys."""
-    choice = os.environ.get("PADDLE_TPU_RNG", "")
-    if not choice:
-        choice = "rbg" if platform == "tpu" else "threefry"
-    if choice == "threefry":
-        return jax.random.PRNGKey(seed)
-    return jax.random.key(seed, impl=choice)
+    """Threaded PRNG key. On TPU the counter-based ``rbg`` generator: it
+    maps onto the hardware RNG instruction and is far cheaper than threefry
+    for the per-step dropout masks (threefry lowers to long scalar-ish
+    bit-mix chains that steal MXU-adjacent cycles). Elsewhere stock jax
+    threefry keys."""
+    if platform == "tpu":
+        return jax.random.key(seed, impl="rbg")
+    return jax.random.PRNGKey(seed)
 
 
 def build_step_fn(program, fetch_names, persist_names, pp_cfg=None,
-                  fuse_opt=True, grad_scale=None, infer_only=False):
+                  grad_scale=None, infer_only=False):
     """Trace a program's global block into one pure function
     ``(state, feed, rng) -> (fetches, new_state, rng')`` — the unit the
     Executor jits, ``__graft_entry__`` exposes, and bench.py times.
     ``pp_cfg`` routes the autodiff replay through the pipeline engine
-    (see ``parallel/pipeline.py``). ``fuse_opt`` batches dense optimizer
-    updates into one flattened kernel (see ``opt_fusion.py``); the mesh
-    path disables it to keep per-tensor GSPMD sharding propagation.
-    ``infer_only`` narrows ``new_state`` to persistables some op actually
-    writes: an inference program then returns NO state, so running it
-    without donation (see ``Executor.run(donate_state=False)``) neither
-    invalidates nor copies the shared weights."""
-    from .op_registry import env_flag
-    from .opt_fusion import plan_opt_fusion, run_fused_group
-    from .epilogue_fusion import fuse_ops, fusion_enabled
+    (see ``parallel/pipeline.py``). ``infer_only`` narrows ``new_state``
+    to persistables some op actually writes: an inference program then
+    returns NO state, so running it without donation (see
+    ``Executor.run(donate_state=False)``) neither invalidates nor copies
+    the shared weights."""
+    from .epilogue_fusion import fuse_ops
 
     ops = list(program.global_block().ops)
-    if fusion_enabled() and pp_cfg is None:
+    if pp_cfg is None:
         # conv->BN(+add)->relu epilogue fusion (the build_strategy.cc
         # analog), applied to the traced op list — the user's program is
         # not mutated, and the autodiff replay lists are rewritten too so
@@ -223,13 +217,6 @@ def build_step_fn(program, fetch_names, persist_names, pp_cfg=None,
             produced.update(op.output_arg_names)
         persist_set &= produced
     amp = bool(getattr(program, "_amp_bf16", False))
-    # per-param updates are cheap in isolation; what a profile shows per
-    # update is scheduling stall, which concat-batching makes WORSE (one
-    # dynamic-update-slice per operand). Keep the batcher opt-in for
-    # experiments.
-    plan, skip = ({}, set())
-    if fuse_opt and env_flag("PADDLE_TPU_FUSED_OPT"):
-        plan, skip = plan_opt_fusion(ops)
 
     def step(state, feed, rng):
         from .op_registry import AMP, PP_KEY
@@ -252,13 +239,7 @@ def build_step_fn(program, fetch_names, persist_names, pp_cfg=None,
         prev_amp = AMP.enabled
         AMP.enabled = amp  # trace-time flag: fwd + autodiff replay
         try:
-            for i, op in enumerate(ops):
-                if i in skip:
-                    continue
-                if i in plan:
-                    with jax.named_scope("fused_" + op.type):
-                        run_fused_group(env, plan[i])
-                    continue
+            for op in ops:
                 run_op(env, op)
         finally:
             AMP.enabled = prev_amp
@@ -806,42 +787,14 @@ class Executor:
 
         state_shard = {n: param_shardings.get(n, repl) for n in state_in_names}
 
-        sp_size = dict(zip(mesh.axis_names, mesh.devices.shape)).get(sp_axis)
-
         # sequence-parallel feeds: axis 1 of [B,S,...] sequence feeds -> sp
         # (ring-attention-style context sharding; GSPMD all-gathers where an
         # op needs the full sequence). Callers name the sequence feeds
         # explicitly via with_data_parallel(sequence_feeds=...) — model
-        # specs carry them as ``spec.sequence_feeds``. The shape-based
-        # guess (feeds whose dim 1 equals the longest candidate dim) is
-        # OPT-IN via PADDLE_TPU_SP_HEURISTIC=1: a [B,S] integer feed at a
-        # different length would shard wrong, so guessing must be asked
-        # for. Without either, feeds shard on dp only.
-        from .op_registry import env_flag
-
+        # specs carry them as ``spec.sequence_feeds``. Without them, feeds
+        # shard on dp only.
         gb = program.global_block()
         sp_names = set(seq_feeds or ())
-        if (sp_size is not None and seq_feeds is None
-                and env_flag("PADDLE_TPU_SP_HEURISTIC")):
-            seq_dim = None
-            dims = [gb.var(n).shape[1] for n in feed_names
-                    if gb.has_var(n) and gb.var(n).shape is not None
-                    and len(gb.var(n).shape) >= 2 and gb.var(n).shape[1] > 1]
-            if dims:
-                seq_dim = max(dims)
-                if seq_dim % sp_size != 0:
-                    seq_dim = None
-            if seq_dim is not None:
-                for n in feed_names:
-                    shp = gb.var(n).shape if gb.has_var(n) else None
-                    if shp is not None and len(shp) >= 2 and shp[1] == seq_dim:
-                        sp_names.add(n)
-            if sp_names:
-                warnings.warn(
-                    "sequence-parallel heuristic sharded feeds %s over the "
-                    "'%s' axis; pass sequence_feeds=[...] to "
-                    "with_data_parallel to choose explicitly"
-                    % (sorted(sp_names), sp_axis))
 
         def feed_spec(name):
             if dp_axis is None or dp_axis not in mesh_axes:
@@ -886,8 +839,7 @@ class Executor:
         # the infer_only narrowing only applies off-mesh: _mesh_shardings
         # sizes its out_shardings for the echoed state dict
         inner = build_step_fn(program, fetch_names, persist_names,
-                              pp_cfg=pp_cfg, fuse_opt=mesh is None,
-                              grad_scale=grad_scale,
+                              pp_cfg=pp_cfg, grad_scale=grad_scale,
                               infer_only=not donate_state and mesh is None)
 
         def step(state, feed, rng):

@@ -10,7 +10,6 @@ op list is traced into a single XLA computation, so "kernel dispatch" and
 the reference's per-op kernel launch + ir fuse passes.
 """
 
-import contextlib
 import threading
 
 import jax
@@ -136,57 +135,6 @@ def env_flag(name):
 
     return os.environ.get(name, "").strip().lower() in (
         "1", "true", "yes", "on")
-
-
-# ---------------------------------------------------------------------------
-# Placement (trace-time state, like AMP below). The Pallas gates must know
-# where the computation being traced will RUN, which is not what
-# ``jax.devices()`` says about the process: a CPUPlace executor on a TPU host
-# runs on the CPU, a one-chip program on a four-chip host still has its chip
-# to itself, and a step compiled for a described (not attached) TPU topology
-# is a TPU step. The Executor sets this around every step it traces, from
-# its place and its mesh; outside an Executor (dygraph, a bare
-# ``build_step_fn`` + ``jax.jit``) the answer is JAX's default backend.
-# ---------------------------------------------------------------------------
-
-class _Placement(threading.local):
-    platform = None   # None: not placed by an Executor -> default backend
-    meshed = False    # True: the step is partitioned over a device mesh
-
-
-PLACEMENT = _Placement()
-
-
-@contextlib.contextmanager
-def placed(platform, meshed=False):
-    """Declare where the computation traced inside the block will run."""
-    prev = (PLACEMENT.platform, PLACEMENT.meshed)
-    PLACEMENT.platform, PLACEMENT.meshed = platform, bool(meshed)
-    try:
-        yield
-    finally:
-        PLACEMENT.platform, PLACEMENT.meshed = prev
-
-
-def placed_platform():
-    """Platform name ('tpu' / 'cpu' / ...) the traced computation runs on."""
-    return PLACEMENT.platform or jax.default_backend()
-
-
-def single_tpu():
-    """True when the traced computation runs on ONE TPU device — the only
-    placement where a Pallas custom call doesn't fight GSPMD (under a mesh
-    it would force gathers of sharded operands). Shared gate for every
-    Pallas kernel family."""
-    return placed_platform() == "tpu" and not PLACEMENT.meshed
-
-
-def placement_reason():
-    """Human detail for a gate's 'platform' refusal."""
-    if PLACEMENT.meshed:
-        return ("the step is partitioned over a mesh (GSPMD would gather "
-                "the custom call's sharded operands)")
-    return "placed on %r, not a TPU" % placed_platform()
 
 
 def run_op(env, op):
@@ -377,8 +325,7 @@ def amp_harmonize(x, y):
     transformer-base). Demoting the f32 side keeps the activation stream
     bf16-resident; normalization/softmax statistics still upcast
     internally (see ``_layer_norm``)."""
-    if (AMP.enabled and not env_flag("PADDLE_TPU_AMP_F32_ACTS")
-            and hasattr(x, "dtype") and hasattr(y, "dtype")):
+    if AMP.enabled and hasattr(x, "dtype") and hasattr(y, "dtype"):
         if x.dtype == jnp.bfloat16 and y.dtype == jnp.float32:
             return x, y.astype(jnp.bfloat16)
         if x.dtype == jnp.float32 and y.dtype == jnp.bfloat16:
@@ -388,22 +335,11 @@ def amp_harmonize(x, y):
 
 def amp_out_cast(x):
     """Cast an f32 activation SOURCE (embedding gather output) to bf16
-    under AMP, mirroring bf16-stored matmul outputs."""
-    if (AMP.enabled and not env_flag("PADDLE_TPU_AMP_F32_ACTS")
-            and hasattr(x, "dtype") and x.dtype == jnp.float32):
+    under AMP, mirroring the matmuls, whose outputs are stored in their
+    bf16 operands' dtype (the MXU accumulates fp32 internally either way;
+    bf16-resident activations halve the HBM traffic between layers, and
+    normalizations and softmax-family ops upcast to fp32 for their
+    statistics)."""
+    if AMP.enabled and hasattr(x, "dtype") and x.dtype == jnp.float32:
         return x.astype(jnp.bfloat16)
     return x
-
-
-def mxu_acc_dtype(x):
-    """Preferred output dtype for MXU matmuls under AMP.
-
-    The MXU always accumulates fp32 internally; the question is only the
-    STORED dtype. bf16-resident activations halve the HBM traffic between
-    layers (measured +4.6% on the transformer bench) — normalizations and
-    softmax-family ops upcast to fp32 for their statistics, keeping the
-    "fp32 math where it matters" contract. Set
-    PADDLE_TPU_AMP_F32_ACTS=1 to restore fp32-stored matmul outputs."""
-    if AMP.enabled and env_flag("PADDLE_TPU_AMP_F32_ACTS"):
-        return jnp.float32
-    return None

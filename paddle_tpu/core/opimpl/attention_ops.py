@@ -69,9 +69,7 @@ def _gated_delta_rule(env, op):
     q, v = get(env, op.input("Q")), get(env, op.input("V"))
     chunk = int(op.attr("chunk", 64))
     hk, hv = int(op.attr("num_k_heads")), int(op.attr("num_v_heads"))
-    plan = gated_delta.kernel_plan(
-        v.shape[1], hk, hv, q.shape[-1] // hk, v.shape[-1] // hv, chunk,
-        platform_ok=gated_delta._use_pallas())
+    plan = gated_delta.plan_for(q, v, hk, hv, chunk)
     op.attrs["_kernel_choice"] = plan.to_dict()
     note("gated_delta_rule", plan)
     out = gated_delta.gated_delta_attention(

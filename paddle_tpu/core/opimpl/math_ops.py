@@ -152,14 +152,11 @@ def _prelu(env, op):
 
 @register("gelu")
 def _gelu(env, op):
-    from ..op_registry import amp_enabled, env_flag
+    from ..op_registry import amp_enabled
     # tanh-approx under AMP (the standard TPU BERT choice): erf lowers to
     # a long polynomial and its vjp chain re-fuses into dW matmul
-    # operands; exact erf stays the default for f32 runs and under
-    # PADDLE_TPU_AMP_F32_ACTS
-    approx = op.attr("approximate",
-                     amp_enabled()
-                     and not env_flag("PADDLE_TPU_AMP_F32_ACTS"))
+    # operands; exact erf stays the default for f32 runs
+    approx = op.attr("approximate", amp_enabled())
     put(env, op.output("Out"),
         jax.nn.gelu(get(env, op.input("X")), approximate=approx))
 
@@ -297,9 +294,9 @@ def _mul(env, op):
     xs, ys = x.shape, y.shape
     x2 = x.reshape((_prod(xs[:xnc]), _prod(xs[xnc:])))
     y2 = y.reshape((_prod(ys[:ync]), _prod(ys[ync:])))
-    from ..op_registry import mxu_cast, mxu_acc_dtype
+    from ..op_registry import mxu_cast
     x2, y2 = mxu_cast(x2, y2)
-    out = jnp.matmul(x2, y2, preferred_element_type=mxu_acc_dtype(x2))
+    out = jnp.matmul(x2, y2)
     out_shape = xs[:xnc] + ys[ync:]
     put(env, op.output("Out"), out.reshape(out_shape))
 
@@ -312,9 +309,9 @@ def _matmul(env, op):
         x = jnp.swapaxes(x, -1, -2)
     if op.attr("transpose_Y", False):
         y = jnp.swapaxes(y, -1, -2)
-    from ..op_registry import mxu_cast, mxu_acc_dtype
+    from ..op_registry import mxu_cast
     x, y = mxu_cast(x, y)
-    out = jnp.matmul(x, y, preferred_element_type=mxu_acc_dtype(x))
+    out = jnp.matmul(x, y)
     alpha = op.attr("alpha", 1.0)
     if alpha != 1.0:
         out = out * alpha

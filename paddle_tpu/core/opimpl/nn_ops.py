@@ -399,19 +399,6 @@ def _layer_norm(env, op):
     bias = get(env, op.input("Bias"))
     eps = op.attr("epsilon", 1e-5)
     begin = op.attr("begin_norm_axis", 1)
-    if begin == x.ndim - 1:
-        # last-axis normalization: fused Pallas fwd+bwd (one HBM pass per
-        # direction instead of XLA's ~5 — ops/fused_layer_norm.py)
-        from ...ops.fused_layer_norm import fused_layer_norm, _use_fused
-
-        from ...ops.gates import note
-
-        if note("layer_norm", _use_fused(x.shape[-1])):
-            y, mean, var = fused_layer_norm(x, scale, bias, eps)
-            put(env, op.output("Y"), y)
-            put(env, op.output("Mean"), mean)
-            put(env, op.output("Variance"), var)
-            return
     axes = tuple(range(begin, x.ndim))
     # stats in fp32 even for bf16-resident activations (AMP); Y stored in
     # the input dtype so the residual stream stays bf16 (cf. batch_norm)
@@ -582,7 +569,7 @@ def _fused_linear_smooth_ce(env, op):
     projection + ``softmax_with_cross_entropy_op.cc`` pairing for the big-
     vocab loss heads."""
     from ...ops.fused_ce import linear_smooth_ce
-    from ..op_registry import mxu_cast
+    from ..op_registry import amp_enabled, mxu_cast
 
     x = get(env, op.input("X"))
     w = get(env, op.input("W"))
@@ -592,7 +579,7 @@ def _fused_linear_smooth_ce(env, op):
         ids = ids.squeeze(-1)
     x, w, b = mxu_cast(x, w, b)
     put(env, op.output("Loss"), linear_smooth_ce(
-        x, w, b, ids, op.attr("epsilon", 0.0)))
+        x, w, b, ids, op.attr("epsilon", 0.0), amp=amp_enabled()))
 
 
 @register("sigmoid_cross_entropy_with_logits")

@@ -168,7 +168,7 @@ def _adam(env, op):
         # branch on the densified grad. On TPU this is also the fast path:
         # one scatter-add (~15 ns/row) replaces the lazy branch's 3 row
         # gathers + 3 row scatters (measured 45 -> ~12 ms/step on the
-        # DeepFM bench, tools/bench_gather.py has the per-op rates).
+        # DeepFM bench, an earlier installation).
         g = _densify(g.astype(p.dtype), rows, p.shape, op)
         rows = None
     if rows is not None:

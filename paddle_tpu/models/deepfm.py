@@ -9,7 +9,7 @@ embeddings live in ONE fused ``[V, W]`` table (emb in cols 0..K-1, w1 in
 col K, zero-frozen padding up to W = the next power of two, which divides
 128 so the packed-row gather applies — ops/rowops.py). Embedding-bound
 CTR steps are PER-ROW-LATENCY-bound on TPU (gather ~2 ns/row packed,
-scatter-add ~15 ns/row regardless of width — tools/bench_gather.py), so
+scatter-add ~15 ns/row regardless of width — ROW_OP_FLOORS.json), so
 one fused table halves the row ops of the classic two-table formulation
 at the cost of inert padding columns (zero-init, zero-grad, frozen)."""
 
@@ -47,15 +47,12 @@ class _PaddedTableInitializer(Initializer):
         block.append_op("elementwise_mul", {"X": var, "Y": mask},
                         {"Out": var}, {})
 
-# Fallback row-op latencies: the round-5 v5e measurements
-# (tools/bench_gather.py). These are NOT the operative constants — the
-# roofline sources them live from ROW_OP_FLOORS.json (the
-# CHIP_CEILING.json pattern: ``tools/bench_gather.py --write`` commits a
-# re-measurement and every subsequent bench record picks it up; the
-# sourcing is pinned by tests/test_bench_contract.py). The 15 ns/row
-# scatter figure is the floor ISSUE 13's Pallas kernel (ops/scatter.py)
-# exists to challenge — a bench-chip --write run either drops it or
-# earns it its name (NOTES_r7.md).
+# Fallback row-op latencies: the round-5 v5e measurements. These are NOT
+# the operative constants — the roofline sources them live from
+# ROW_OP_FLOORS.json (the sourcing is pinned by
+# tests/test_bench_contract.py). The 15 ns/row scatter figure is the floor
+# ISSUE 13's Pallas kernel (ops/scatter.py) exists to challenge; no cell
+# measures either yet (ROADMAP R9, W5).
 _GATHER_NS_PER_ROW = 2.0
 _SCATTER_NS_PER_ROW = 15.0
 

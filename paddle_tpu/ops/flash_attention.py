@@ -26,25 +26,16 @@ PRNG primitives are TPU-only).
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import gates
+from .gates import GateDecision, GateReason
 from .kernel_names import named_pallas_call, traced_once
 
 _INTERPRET = False  # tests flip this to run kernels on CPU
-
-
-def _use_pallas():
-    if _INTERPRET:
-        return True
-    from ..core.op_registry import env_flag, single_tpu
-
-    if env_flag("PADDLE_TPU_NO_FLASH"):  # A/B escape hatch
-        return False
-    return single_tpu()
 
 
 # ---------------------------------------------------------------------------
@@ -360,40 +351,22 @@ def _pad_vec(x, m):
     return jnp.pad(x, ((0, 0), (0, r))) if r else x
 
 
-def _block_sizes(t, t_k, bwd=False):
-    """Mosaic wants the lane (last) dim of 1-D stats blocks divisible by
-    128, so real-TPU blocks are 128-multiples; interpret mode uses
-    8-multiples to exercise the padded-edge logic cheaply.
-    PADDLE_TPU_FLASH_BLOCK (and _BWD for the backward kernels) override
-    the default caps (A/B knobs). NOTE: the _BWD override only engages
-    when dropout is OFF — dropout masks regenerate per (bh, q-block,
-    k-block) tile, so fwd and bwd must share block geometry. 512 is the
-    measured sweet spot at T=2048 for BOTH directions
-    (tools/attn_device_time.py: fwd 4.46 -> 2.18 ms vs 256-blocks, bwd
-    8.76 -> 5.86; 128 is 2.5x worse, 1024 regresses bwd) — bigger
-    blocks amortize the per-iteration MXU/VPU serialization across 4x
-    the elements."""
-    m = 8 if _INTERPRET else 128
-    default = 64 if _INTERPRET else 512   # small interpret cap keeps the
-    try:                                  # multi-block paths exercised
-        cap = int(os.environ.get("PADDLE_TPU_FLASH_BLOCK", default))
-        if bwd:
-            cap = int(os.environ.get("PADDLE_TPU_FLASH_BLOCK_BWD", cap))
-    except ValueError:
-        raise ValueError("PADDLE_TPU_FLASH_BLOCK(_BWD) must be integers")
+def _block_sizes(t, t_k):
+    """Block sizes of the streaming kernels, forward and backward alike
+    (dropout masks regenerate per (bh, q-block, k-block) tile, so the two
+    share block geometry). Mosaic wants the lane (last) dim of 1-D stats
+    blocks divisible by 128, so real-TPU blocks are 128-multiples capped
+    at 512 (bigger blocks amortize the per-iteration MXU/VPU
+    serialization; on an earlier installation 128 was 2.5x worse at
+    T=2048 and 1024 regressed the backward); interpret mode uses
+    8-multiples capped at 64, which exercises the padded-edge and
+    multi-block paths cheaply."""
+    m, cap = (8, 64) if _INTERPRET else (128, 512)
 
     def r(x):
         return ((x + m - 1) // m) * m
 
-    cap = max(m, r(cap) if cap % m else cap)  # Mosaic lane divisibility
     return min(cap, r(t)), min(cap, r(t_k))
-
-
-def _bwd_block_sizes(t, t_k, dropout_rate):
-    """Block sizes of a streaming backward. Dropout masks regenerate per
-    (bh, q-block, k-block) tile, so it may only use other block sizes
-    than the forward when dropout is off."""
-    return _block_sizes(t, t_k, bwd=(dropout_rate == 0.0))
 
 
 @traced_once("head_split_stream.fwd",
@@ -460,7 +433,7 @@ def _flash_fwd_impl(q, k, v, bias, seed, causal, scale, dropout_rate,
              ("causal", "scale", "dropout_rate", "blocks", "interpret"))
 def _flash_bwd_impl(q, k, v, bias, seed, out, lse, do, causal, scale,
                     dropout_rate, blocks, interpret):
-    """``blocks``: :func:`_bwd_block_sizes`."""
+    """``blocks``: :func:`_block_sizes`, the forward's."""
     from jax.experimental import pallas as pl
 
     bh, t, d = q.shape
@@ -570,8 +543,7 @@ def _flash_bwd_impl(q, k, v, bias, seed, out, lse, do, causal, scale,
 # kernels do. The price is VMEM: K/V (fwd) and q/do/dq-f32 (bwd) are
 # full-T refs of width H*D rather than D, which caps the single-chip
 # packed path near T~1k for bf16 transformer-base; longer contexts keep
-# the head-split path (gate: _packed_stream_fits;
-# PADDLE_TPU_SPLIT_STREAM=1 forces the old path for A/B).
+# the head-split path (gate: _packed_stream_fits).
 
 def _packed_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref,
                        lse_ref, *, num_heads, block_k, causal, scale,
@@ -841,7 +813,7 @@ def _packed_stream_fwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
               "interpret"))
 def _packed_stream_bwd_impl(q, k, v, bias, seed, out, lse, do, num_heads,
                             causal, scale, dropout_rate, blocks, interpret):
-    """``blocks``: :func:`_bwd_block_sizes`."""
+    """``blocks``: :func:`_block_sizes`, the forward's."""
     from jax.experimental import pallas as pl
 
     b, t, hd = q.shape
@@ -960,8 +932,7 @@ def _packed_stream_bwd(num_heads, causal, scale, dropout_rate, res, g):
     q, k, v, bias, seed, out, lse = res
     dq, dk, dv, db = _packed_stream_bwd_impl(
         q, k, v, bias, seed, out, lse, g, num_heads, causal, scale,
-        dropout_rate, _bwd_block_sizes(q.shape[1], k.shape[1], dropout_rate),
-        _INTERPRET)
+        dropout_rate, _block_sizes(q.shape[1], k.shape[1]), _INTERPRET)
     dbias = db.astype(bias.dtype) if bias is not None else None
     return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
             dbias, None)
@@ -969,7 +940,6 @@ def _packed_stream_bwd(num_heads, causal, scale, dropout_rate, res, g):
 
 _packed_stream_attention.defvjp(_packed_stream_fwd, _packed_stream_bwd)
 
-_PACKED_STREAM = True  # module A/B switch (tests also flip it)
 # The chip's compiler gives one kernel 16 MiB of scoped VMEM and refuses
 # the whole program when a kernel asks for more. What a kernel asks for
 # also depends on the program around it: the bf16 T=2048 H*D=512 backward
@@ -978,35 +948,28 @@ _PACKED_STREAM = True  # module A/B switch (tests also flip it)
 _STREAM_VMEM_BUDGET = 13 * 1024 * 1024
 
 
-def _packed_stream_fits(t, t_k, hd, esize, num_heads, dropout=0.0):
+def _packed_stream_fits(t, t_k, hd, esize, num_heads):
     """VMEM the packed streaming kernels allocate, against the budget.
     Mosaic double-buffers every operand whose block index changes
     anywhere in the grid — the full-T q/do (bwd) and K/V (fwd) blocks
     change with the batch index, so they count twice like the streamed
-    blocks do; only the revisited f32 dq accumulator is held once. The
-    bwd estimate uses the geometry the backward will ACTUALLY allocate:
-    the PADDLE_TPU_FLASH_BLOCK_BWD override engages only when dropout is
-    off (fwd/bwd must share block geometry for mask regeneration), so the
-    gate asks :func:`_bwd_block_sizes` as ``_packed_stream_bwd`` does."""
+    blocks do; only the revisited f32 dq accumulator is held once."""
     block_q, block_k = _block_sizes(t, t_k)
-    bq_b, bk_b = _bwd_block_sizes(t, t_k, dropout)
     nh_pad = max(num_heads, 8)
 
     def pad(x, m):
         return ((x + m - 1) // m) * m
 
-    tk_pad = pad(t_k, block_k)
+    t_pad, tk_pad = pad(t, block_q), pad(t_k, block_k)
     fwd = (4 * tk_pad * hd * esize              # K/V, two buffers each
            + 4 * block_q * hd * esize           # q/o blocks, two each
-           + 2 * nh_pad * pad(t, block_q) * 4   # lse out
+           + 2 * nh_pad * t_pad * 4             # lse out
            + 2 * 8 * tk_pad * 4)                # key bias
-    t_pad_b = pad(t, bq_b)
-    tk_pad_b = pad(t_k, bk_b)
-    bwd = (4 * t_pad_b * hd * esize             # q/do, two buffers each
-           + t_pad_b * hd * 4                   # f32 dq accumulator
-           + 8 * bk_b * hd * esize              # k/v/dk/dv blocks, two each
-           + 4 * nh_pad * t_pad_b * 4           # lse/delta
-           + 4 * 8 * tk_pad_b * 4)              # key bias + its grad
+    bwd = (4 * t_pad * hd * esize               # q/do, two buffers each
+           + t_pad * hd * 4                     # f32 dq accumulator
+           + 8 * block_k * hd * esize           # k/v/dk/dv blocks, two each
+           + 4 * nh_pad * t_pad * 4             # lse/delta
+           + 4 * 8 * tk_pad * 4)                # key bias + its grad
     return max(fwd, bwd) <= _STREAM_VMEM_BUDGET
 
 
@@ -1374,7 +1337,7 @@ def _flash_bwd(causal, scale, dropout_rate, res, g):
     q, k, v, bias, seed, out, lse = res
     dq, dk, dv, db = _flash_bwd_impl(
         q, k, v, bias, seed, out, lse, g, causal, scale, dropout_rate,
-        _bwd_block_sizes(q.shape[1], k.shape[1], dropout_rate), _INTERPRET)
+        _block_sizes(q.shape[1], k.shape[1]), _INTERPRET)
     dbias = db.astype(bias.dtype) if bias is not None else None
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), \
         dbias, None
@@ -1502,7 +1465,7 @@ _segmented_attention.defvjp(_segmented_fwd, _segmented_bwd)
 
 def kernel_plan(q_shape, k_shape, num_heads, esize, causal=False,
                 dropout_rate=0.0, bias_kind=None, rng_available=True,
-                platform_ok=True):
+                platform=None):
     """The attention dispatch decision as a structured
     ``ops.gates.GateDecision`` (ISSUE 15): ``kernel`` is which path runs
     — ``dense_vmem`` (whole-sequence VMEM-resident, packed layout),
@@ -1511,24 +1474,18 @@ def kernel_plan(q_shape, k_shape, num_heads, esize, causal=False,
     ``segmented_stream`` (the head-split kernels on segments of a causal
     square whose head does not fit them whole), or ``reference`` — and ``reasons`` records every check that demoted the
     choice. This IS the dispatch logic :func:`flash_attention` runs
-    (single source); the static resource pass evaluates it shape-only
-    with ``platform_ok=True``.
+    (single source). ``platform``: what ``gates.platform_reason`` says
+    of where the step runs; the static resource pass evaluates the gate
+    shape-only and leaves it ``None``.
 
     ``bias_kind``: None | 'key' (padding-mask form) | 'rich' (anything
     else — reference path only)."""
-    from ..core.op_registry import env_flag
-    from .gates import GateDecision, GateReason
-
     b, t, hd = q_shape
     t_k = k_shape[1]
     d = hd // max(num_heads, 1)
     reasons = []
-    if not platform_ok:
-        from ..core.op_registry import placement_reason
-
-        reasons.append(GateReason(
-            "platform", "%s (or PADDLE_TPU_NO_FLASH=1)"
-            % placement_reason()))
+    if platform is not None:
+        reasons.append(platform)
     if bias_kind == "rich":
         reasons.append(GateReason(
             "bias", "non-key-mask bias shape: only the additive "
@@ -1557,21 +1514,13 @@ def kernel_plan(q_shape, k_shape, num_heads, esize, causal=False,
             "anchor the diagonal differently from the reference" % (t, t_k)))
         return GateDecision(False, "reference", fallback="packed_stream",
                             reasons=reasons)
-    if _PACKED_STREAM and not env_flag("PADDLE_TPU_SPLIT_STREAM"):
-        if _packed_stream_fits(t, t_k, hd, esize, num_heads,
-                               float(dropout_rate)):
-            return GateDecision(True, "packed_stream")
-        reasons.append(GateReason(
-            "vmem", "packed streaming working set for T=%d Tk=%d H*D=%d "
-            "exceeds the %.0f MB VMEM budget — falls back to the "
-            "head-split path (+[B,T,H,D] relayout copies around every "
-            "attention site)" % (t, t_k, hd,
-                                 _STREAM_VMEM_BUDGET / 2**20)))
-    else:
-        reasons.append(GateReason(
-            "env", "packed streaming disabled "
-            "(PADDLE_TPU_SPLIT_STREAM / module A/B switch)",
-            blocking=False))
+    if _packed_stream_fits(t, t_k, hd, esize, num_heads):
+        return GateDecision(True, "packed_stream")
+    reasons.append(GateReason(
+        "vmem", "packed streaming working set for T=%d Tk=%d H*D=%d "
+        "exceeds the %.0f MB VMEM budget — falls back to the "
+        "head-split path (+[B,T,H,D] relayout copies around every "
+        "attention site)" % (t, t_k, hd, _STREAM_VMEM_BUDGET / 2**20)))
     if not _head_split_fits(t, t_k, d, esize) and causal \
             and bias_kind is None and dropout_rate == 0.0:
         segmented = _segment_plan(t, d, esize)
@@ -1592,7 +1541,7 @@ def kernel_plan(q_shape, k_shape, num_heads, esize, causal=False,
 
 def plan_for(q, k, bias, num_heads, causal, dropout_rate, rng):
     """:func:`kernel_plan` for concrete arrays: classifies the bias form
-    and evaluates the live platform gate. Used by the op impl (which
+    and asks where the step is placed. Used by the op impl (which
     records the decision in the op's attrs) and by
     :func:`flash_attention` itself."""
     b, _, _ = q.shape
@@ -1609,7 +1558,7 @@ def plan_for(q, k, bias, num_heads, causal, dropout_rate, rng):
                        causal=causal, dropout_rate=float(dropout_rate),
                        bias_kind=bias_kind,
                        rng_available=rng is not None,
-                       platform_ok=_use_pallas())
+                       platform=gates.platform_reason(_INTERPRET))
 
 
 def flash_attention(q, k, v, num_heads, bias=None, causal=False,

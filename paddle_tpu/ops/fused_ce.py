@@ -24,6 +24,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from . import gates
+from .gates import GateDecision, GateReason
 from .kernel_names import named_pallas_call
 
 _INTERPRET = False  # tests flip this to run the kernel on CPU
@@ -39,29 +41,23 @@ _FUSED_MIN_LOGITS = 1.5e9
 
 def _use_fused(x, w):
     """Structured gate (``ops.gates.GateDecision``): the fused kernel, or
-    the plain projection + CE with the reason (env switch, placement, or
-    fewer logits than the threshold)."""
-    from .gates import GateDecision, GateReason
-
-    def refuse(check, detail):
+    the plain projection + CE with the reason (placement, or fewer logits
+    than the threshold)."""
+    def refuse(reason):
         return GateDecision(False, "xla_projection_ce",
-                            fallback="fused_ce",
-                            reasons=[GateReason(check, detail)])
+                            fallback="fused_ce", reasons=[reason])
 
     if _INTERPRET:
         return GateDecision(True, "fused_ce")
-    from ..core.op_registry import env_flag, single_tpu
-
-    if env_flag("PADDLE_TPU_NO_FUSED_CE"):  # A/B escape hatch
-        return refuse("env", "PADDLE_TPU_NO_FUSED_CE is set")
-    if not single_tpu():
-        return refuse("platform", "the step is not placed on one TPU chip")
+    platform = gates.platform_reason()
+    if platform is not None:
+        return refuse(platform)
     n_logits = (x.size // x.shape[-1]) * w.shape[1]
-    if n_logits >= _FUSED_MIN_LOGITS or env_flag("PADDLE_TPU_FUSED_CE"):
+    if n_logits >= _FUSED_MIN_LOGITS:
         return GateDecision(True, "fused_ce")
-    return refuse("size", "%.3g logits, under the %.3g from which "
-                  "recomputing the projection pays"
-                  % (n_logits, _FUSED_MIN_LOGITS))
+    return refuse(GateReason(
+        "size", "%.3g logits, under the %.3g from which recomputing the "
+        "projection pays" % (n_logits, _FUSED_MIN_LOGITS)))
 
 
 # ---------------------------------------------------------------------------
@@ -320,29 +316,22 @@ def _bf16_ce_bwd(eps, res, g):
 _bf16_ce.defvjp(_bf16_ce_fwd, _bf16_ce_bwd)
 
 
-def linear_smooth_ce(x, w, b, y, eps):
+def linear_smooth_ce(x, w, b, y, eps, amp=False):
     """x: [..., D] activations; w: [D, V]; b: [V] or None; y: [...] int
-    labels. Returns per-position f32 loss of shape ``x.shape[:-1]``."""
+    labels; ``amp``: the step is traced under AMP (the op impl says).
+    Returns per-position f32 loss of shape ``x.shape[:-1]``."""
     lead = x.shape[:-1]
     d = x.shape[-1]
     x2 = x.reshape(-1, d)
     y2 = y.reshape(-1).astype(jnp.int32)
 
-    from .gates import note
-
-    if note("fused_ce", _use_fused(x, w)):
+    if gates.note("fused_ce", _use_fused(x, w)):
         loss = _fused(x2, w, b, y2, float(eps))
         return loss.reshape(lead)
 
-    from ..core.op_registry import amp_enabled, env_flag, single_tpu
-    # engage on op-registry AMP, or when the caller already runs bf16
-    # activations (the dygraph build's per-layer casts); the F32_ACTS
-    # escape hatch disables it in BOTH cases (mxu_cast hands this op a
-    # bf16 x under static AMP regardless of that flag)
-    wants_bf16 = ((amp_enabled() or x.dtype == jnp.bfloat16)
-                  and not env_flag("PADDLE_TPU_AMP_F32_ACTS"))
-    if (wants_bf16 and single_tpu()
-            and not env_flag("PADDLE_TPU_NO_BF16_CE")):  # A/B escape hatch
+    # bf16-stored logits under AMP, or when the caller already runs bf16
+    # activations (the dygraph build's per-layer casts)
+    if (amp or x.dtype == jnp.bfloat16) and gates.single_tpu():
         return _bf16_ce(x2, w, b, y2, float(eps)).reshape(lead)
 
     return ce_reference(x2, w, b, y2, eps).reshape(lead)

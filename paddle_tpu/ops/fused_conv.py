@@ -45,6 +45,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .gates import GateDecision, GateReason, platform_reason
 from .kernel_names import named_pallas_call, traced_once
 
 _INTERPRET = False  # tests flip this to run the kernels on CPU
@@ -88,8 +89,6 @@ def gate(x_shape, w_shape, strides, paddings, dilations, groups, esize,
     kernels (mirrors the other fused ops' gates). ``static_only=True``
     evaluates only the geometry/VMEM checks — the platform-independent
     view the static resource pass wants."""
-    from .gates import GateDecision, GateReason
-
     reasons = []
     if not supported_geometry(x_shape, w_shape, strides, paddings,
                               dilations, groups):
@@ -109,14 +108,10 @@ def gate(x_shape, w_shape, strides, paddings, dilations, groups, esize,
                 "vmem", "[C=%d, HW=%d] image + [O=%d] output blocks "
                 "exceed the %.0f MB VMEM budget"
                 % (int(c), h * w, int(o), _VMEM_BUDGET / 2**20)))
-    if not static_only and not reasons and not _INTERPRET:
-        from ..core.op_registry import (env_flag, placement_reason,
-                                         single_tpu)
-
-        if env_flag("PADDLE_TPU_NO_FUSED_CONV"):  # A/B escape hatch
-            reasons.append(GateReason("env", "PADDLE_TPU_NO_FUSED_CONV=1"))
-        elif not single_tpu():
-            reasons.append(GateReason("platform", placement_reason()))
+    if not static_only and not reasons:
+        platform = platform_reason(_INTERPRET)
+        if platform is not None:
+            reasons.append(platform)
     if reasons:
         return GateDecision(False, "unfused_replay",
                             fallback="pallas_fused_conv", reasons=reasons)

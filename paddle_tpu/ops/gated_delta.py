@@ -57,6 +57,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .gates import GateDecision, GateReason, platform_reason
 from .kernel_names import named_pallas_call, traced_once
 
 __all__ = ["chunk_gated_delta_rule", "recurrent_gated_delta_rule",
@@ -798,20 +799,16 @@ def _working_set(rep, dk, dv, chunk):
     return blocks + states + tiles
 
 
-def kernel_plan(t, num_k_heads, num_v_heads, dk, dv, chunk,
-                platform_ok=True):
+def kernel_plan(t, num_k_heads, num_v_heads, dk, dv, chunk, platform=None):
     """Which path a ``gated_delta_rule`` site takes, as a
     ``ops.gates.GateDecision``: ``gated_delta`` (the Pallas kernels) or
     ``chunked_scan_xla`` (the chunked ``jnp`` form) with the blocking
-    reasons. The op and the shape-only pass (``analysis/resources.py``,
-    ``platform_ok=True``) read the same gate."""
-    from .gates import GateDecision, GateReason
-
+    reasons. ``platform``: what ``gates.platform_reason`` says of where the
+    step runs (:func:`plan_for`); the shape-only pass
+    (``analysis/resources.py``) reads the same gate and leaves it ``None``."""
     reasons = []
-    if not platform_ok:
-        from ..core.op_registry import placement_reason
-
-        reasons.append(GateReason("platform", placement_reason()))
+    if platform is not None:
+        reasons.append(platform)
     if dk % 128 or dv % 128:
         reasons.append(GateReason(
             "geometry", "head dimensions %d and %d are not multiples of 128 "
@@ -840,12 +837,12 @@ def kernel_plan(t, num_k_heads, num_v_heads, dk, dv, chunk,
         "chunks" % (-(-t // chunk), chunk, 2 * _PAIRS), blocking=False)])
 
 
-def _use_pallas():
-    if _INTERPRET:
-        return True
-    from ..core.op_registry import single_tpu
-
-    return single_tpu()
+def plan_for(q, v, num_k_heads, num_v_heads, chunk):
+    """:func:`kernel_plan` of a site's packed q [B, T, Hk*Dk] and v
+    [B, T, Hv*Dv], where the step being traced is placed."""
+    return kernel_plan(v.shape[1], num_k_heads, num_v_heads,
+                       q.shape[-1] // num_k_heads, v.shape[-1] // num_v_heads,
+                       chunk, platform=platform_reason(_INTERPRET))
 
 
 _L2_EPS = 1e-6
@@ -872,8 +869,7 @@ def gated_delta_attention(q, k, v, a, b, a_log, dt_bias, num_k_heads,
     dv = v.shape[-1] // num_v_heads
     rep = num_v_heads // num_k_heads
     if plan is None:
-        plan = kernel_plan(t, num_k_heads, num_v_heads, dk, dv, chunk,
-                           platform_ok=_use_pallas())
+        plan = plan_for(q, v, num_k_heads, num_v_heads, chunk)
 
     def gates(a, b):
         beta = jax.nn.sigmoid(b.astype(f32))

@@ -23,12 +23,16 @@ WHY. A :class:`GateDecision` carries the chosen kernel plus one
 import contextlib
 import threading
 
-__all__ = ["GateReason", "GateDecision", "collect", "note", "tally"]
+import jax
+
+__all__ = ["GateReason", "GateDecision", "collect", "note", "tally",
+           "PLACEMENT", "placed", "placed_platform", "single_tpu",
+           "placement_reason", "platform_reason"]
 
 
 class GateReason:
     """One gate check's outcome: the check name ('vmem' / 'geometry' /
-    'dtype' / 'platform' / 'env' / ...), a human detail string, and
+    'dtype' / 'platform' / ...), a human detail string, and
     whether this check blocked admission."""
 
     __slots__ = ("check", "detail", "blocking")
@@ -133,3 +137,63 @@ def tally(rows):
         line = decision.describe()
         by[line] = by.get(line, 0) + 1
     return out
+
+
+# ---------------------------------------------------------------------------
+# Placement (trace-time state). The Pallas gates must know where the
+# computation being traced will RUN, which is not what ``jax.devices()`` says
+# about the process: a CPUPlace executor on a TPU host runs on the CPU, a
+# one-chip program on a four-chip host still has its chip to itself, and a
+# step compiled for a described (not attached) TPU topology is a TPU step.
+# The Executor sets this around every step it traces, from its place and its
+# mesh; outside an Executor (dygraph, a bare ``build_step_fn`` + ``jax.jit``)
+# the answer is JAX's default backend.
+# ---------------------------------------------------------------------------
+
+class _Placement(threading.local):
+    platform = None   # None: not placed by an Executor -> default backend
+    meshed = False    # True: the step is partitioned over a device mesh
+
+
+PLACEMENT = _Placement()
+
+
+@contextlib.contextmanager
+def placed(platform, meshed=False):
+    """Declare where the computation traced inside the block will run."""
+    prev = (PLACEMENT.platform, PLACEMENT.meshed)
+    PLACEMENT.platform, PLACEMENT.meshed = platform, bool(meshed)
+    try:
+        yield
+    finally:
+        PLACEMENT.platform, PLACEMENT.meshed = prev
+
+
+def placed_platform():
+    """Platform name ('tpu' / 'cpu' / ...) the traced computation runs on."""
+    return PLACEMENT.platform or jax.default_backend()
+
+
+def single_tpu():
+    """True when the traced computation runs on ONE TPU device — the only
+    placement where a Pallas custom call doesn't fight GSPMD (under a mesh
+    it would force gathers of sharded operands)."""
+    return placed_platform() == "tpu" and not PLACEMENT.meshed
+
+
+def placement_reason():
+    """Human detail for a gate's 'platform' refusal."""
+    if PLACEMENT.meshed:
+        return ("the step is partitioned over a mesh (GSPMD would gather "
+                "the custom call's sharded operands)")
+    return "placed on %r, not a TPU" % placed_platform()
+
+
+def platform_reason(interpret=False):
+    """The one placement rule every Pallas kernel family shares: ``None``
+    where a kernel may run (one TPU device, or ``interpret``: a family's
+    test mode, which runs its kernels on the CPU), else the blocking
+    'platform' :class:`GateReason`."""
+    if interpret or single_tpu():
+        return None
+    return GateReason("platform", placement_reason())

@@ -3,7 +3,7 @@
 A row of a ``[V, K<128]`` f32 table occupies one (8,128) tile row padded
 to 128 lanes, so XLA's row gather fetches 512 bytes per row to return
 ``4*K`` useful ones, and per-row DMA latency dominates: measured 8 ns/row
-(7.8 GB/s useful) on v5e regardless of K — see ``tools/bench_gather.py``.
+(7.8 GB/s useful) on v5e regardless of K (round 5, an earlier installation).
 
 ``packed_take`` reshapes the table so ``P = 128 // K`` logical rows share
 one physical 128-lane row; each gathered 512-byte burst then carries P

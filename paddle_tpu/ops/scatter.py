@@ -2,9 +2,9 @@
 
 The backward of every embedding-bound model is a row scatter-add
 (``grad_table.at[ids].add(grad_rows)``), and round 5 measured XLA's
-lowering at ~15 ns/row regardless of row width (``tools/bench_gather.py``
-``s_k16``/``s_k128``) — a per-row HBM read-modify-write DMA each, declared
-a "chip property" in ``models/deepfm.py``. This module is the purpose-built
+lowering at ~15 ns/row regardless of row width — a per-row HBM
+read-modify-write DMA each, declared a "chip property" in
+``models/deepfm.py``. This module is the purpose-built
 challenge to that claim (ROADMAP item 3): for tables whose PACKED layout
 fits VMEM, the scatter runs as a Pallas kernel that
 
@@ -22,14 +22,11 @@ DMAs — at the DeepFM bench shape (V=100k, K=16, N=212992) that is ~26 MB
 of streaming vs 212992 latency-bound DMAs, a ~50x headroom if the VMEM
 accumulate loop keeps up. The sorted-segment formulation the ISSUE names
 (sort ids, segment-reduce duplicates, one dense store per unique row) is
-kept as an A/B variant (``sort=True`` / ``PADDLE_TPU_SCATTER_SORT=1``):
-sorting buys store locality but costs an argsort (~7 ms/step at the bench
-shape — see ``control_ops`` merge note), so the default path is unsorted
-and duplicate-safe by serial accumulation. ``tools/bench_gather.py``
-measures both against ``.at[ids].add`` and ``--write`` commits the winner
-to ``ROW_OP_FLOORS.json`` (the ``CHIP_CEILING.json`` pattern); until a
-bench-chip run lands, the 15 ns/row floor stands and the pallas entries
-are null (committed-negative-result form, NOTES_r7.md).
+kept as a variant a caller names (``sort=True``): sorting buys store
+locality but costs an argsort (~7 ms/step at the bench shape — see
+``control_ops`` merge note), so the default path is unsorted and
+duplicate-safe by serial accumulation. Neither has been timed on the chip
+against ``.at[ids].add``: no cell runs a sparse step (ROADMAP R9, D17).
 
 Reference capability: ``operators/math/selected_rows_functor.cc`` MergeAdd
 + the SelectedRows optimizer kernels — the reference's answer to sparse
@@ -42,6 +39,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .gates import GateDecision, GateReason, platform_reason
 from .kernel_names import named_pallas_call
 
 __all__ = ["scatter_add_rows", "gate", "use_pallas", "packed_vmem_bytes"]
@@ -52,28 +50,14 @@ _INTERPRET = False  # tests flip this to run the kernel on CPU
 # The packed table + one double-buffered vals block must fit comfortably;
 # leave headroom for the vals stream and compiler temporaries. 10 MB
 # admits the [100k, 16] f32 microbench table (6.4 MB packed) but NOT the
-# DeepFM bench's [100k, 32] f32 fused table (12.8 MB packed) —
-# PADDLE_TPU_SCATTER_VMEM_MB raises the budget toward the 16 MB/core
-# ceiling for the on-chip A/B (tools/bench_gather.py s_pallas_w32 runs
-# at 14; whether Mosaic fits it is part of the pending measurement).
-_DEFAULT_VMEM_MB = 10
+# DeepFM bench's [100k, 32] f32 fused table (12.8 MB packed).
+_VMEM_BUDGET = 10 * 1024 * 1024
 _CHUNK = 1024  # (rows, vals) slots processed per grid step
 # The whole int32 row-id vector is the kernel's scalar-prefetch operand and
 # must sit in the chip's 1 MiB of SMEM beside Mosaic's own scalars; the
 # compiler refuses the program otherwise. _SMEM_IDS_BYTES is what the gate
 # lets the ids take.
 _SMEM_IDS_BYTES = 768 * 1024
-
-
-def _vmem_budget():
-    import os
-
-    try:
-        mb = float(os.environ.get("PADDLE_TPU_SCATTER_VMEM_MB",
-                                  _DEFAULT_VMEM_MB))
-    except ValueError:
-        mb = _DEFAULT_VMEM_MB
-    return int(mb * 1024 * 1024)
 
 
 def packed_vmem_bytes(v, k, esize):
@@ -98,7 +82,6 @@ def gate(v, k, n, dtype, static_only=False):
     custom call fight GSPMD) or under the test interpreter.
     ``static_only=True`` evaluates ONLY the shape/dtype/VMEM checks —
     the platform-independent view the static resource pass wants."""
-    from .gates import GateDecision, GateReason
     from .rowops import pack_factor
 
     reasons = []
@@ -124,36 +107,25 @@ def gate(v, k, n, dtype, static_only=False):
     if not reasons:
         need = packed_vmem_bytes(v, k, esize) \
             + 2 * _CHUNK * max(k, _LANES) * esize
-        budget = _vmem_budget()
-        if need > budget:
+        if need > _VMEM_BUDGET:
             reasons.append(GateReason(
                 "vmem", "packed [%d, %d] table + vals stream needs %.1f "
-                "MB VMEM, budget is %.1f MB "
-                "(PADDLE_TPU_SCATTER_VMEM_MB raises it)"
-                % (v, k, need / 2**20, budget / 2**20)))
+                "MB VMEM, budget is %.1f MB"
+                % (v, k, need / 2**20, _VMEM_BUDGET / 2**20)))
         ids_bytes = 4 * _padded_ids(n)
         if ids_bytes > _SMEM_IDS_BYTES:
             reasons.append(GateReason(
                 "smem", "%d prefetched row ids need %.0f KiB of SMEM, the "
                 "kernel may take %.0f KiB"
                 % (n, ids_bytes / 1024, _SMEM_IDS_BYTES / 1024)))
-    if not static_only and not reasons and not _INTERPRET:
-        from ..core.op_registry import (env_flag, placement_reason,
-                                         single_tpu)
-
-        if env_flag("PADDLE_TPU_NO_PALLAS_SCATTER"):  # A/B escape hatch
-            reasons.append(GateReason(
-                "env", "PADDLE_TPU_NO_PALLAS_SCATTER=1"))
-        elif not single_tpu():
-            reasons.append(GateReason("platform", placement_reason()))
+    if not static_only and not reasons:
+        platform = platform_reason(_INTERPRET)
+        if platform is not None:
+            reasons.append(platform)
     if reasons:
         return GateDecision(False, "xla_at_add", fallback="pallas_rowbin",
                             reasons=reasons)
-    from ..core.op_registry import env_flag
-
-    kernel = ("pallas_sorted_segment"
-              if env_flag("PADDLE_TPU_SCATTER_SORT") else "pallas_rowbin")
-    return GateDecision(True, kernel)
+    return GateDecision(True, "pallas_rowbin")
 
 
 def use_pallas(v, k, n, dtype):
@@ -248,22 +220,22 @@ def _scatter_packed_call(bp, rows, vals, p, k, vp):
 def _sorted_merge(rows, vals, sentinel):
     """The ISSUE's sorted-segment formulation: sort ids, segment-reduce
     duplicates, leaving one dense store per unique row (duplicate slots
-    parked on the dropped sentinel). A/B variant — the argsort costs more
-    than the serial-accumulate default saves at the bench shapes."""
+    parked on the dropped sentinel). The argsort costs more than the
+    serial-accumulate default saves at the bench shapes."""
     from ..core.op_registry import merge_sparse_rows
 
     return merge_sparse_rows(rows, vals, sentinel)
 
 
-def scatter_add_rows(base, rows, vals, sort=None):
+def scatter_add_rows(base, rows, vals, sort=False):
     """``base.at[rows].add(vals, mode="drop")`` for a 2-D ``[V, K]``
     table — via the VMEM-resident Pallas kernel when :func:`use_pallas`
     admits the shape, else the XLA scatter. Exact: out-of-range rows
     drop, duplicate rows accumulate.
 
     rows: [N] integer; vals: [N, K] (or broadcastable leading shape that
-    flattens to it). ``sort=True`` (or PADDLE_TPU_SCATTER_SORT=1) routes
-    through the sorted-segment merge first.
+    flattens to it). ``sort=True`` routes through the sorted-segment
+    merge first.
     """
     v, k = base.shape
     rows = rows.reshape(-1).astype(jnp.int32)
@@ -276,10 +248,6 @@ def scatter_add_rows(base, rows, vals, sort=None):
 
     p = pack_factor(k)
     vp = -(-v // p)
-    if sort is None:
-        from ..core.op_registry import env_flag
-
-        sort = env_flag("PADDLE_TPU_SCATTER_SORT")
     # exact ``.at[].add(mode="drop")`` index semantics: negative rows in
     # [-V, 0) wrap python-style, anything else out of range parks on the
     # sentinel (dropped by the kernel) — so the packed row/sub

@@ -24,54 +24,40 @@ shard_map with two formulations:
   but without ragged collectives overflowed rows would silently drop;
   this framework does not trade correctness for bytes — see NOTES_r7.md
   for the full accounting.)
-* **psum-of-partials** (``PADDLE_TPU_EMB_PSUM=1`` A/B fallback, and the
-  auto-selected path for degenerate slices): every shard gathers ALL n
+* **psum-of-partials** (the path chosen for degenerate slices, and for a
+  caller that names it with ``strategy="psum"``): every shard gathers ALL n
   ids against its local slice (zeros for rows it doesn't own) and one
   psum merges the [n, D] partials — mp redundant full-output gathers and
   O(mp * n * D) total reduced volume, which is what capped mp=8+ scaling
   (ROADMAP item 3).
 
-``choose_strategy`` picks per call: psum only when forced by env or when
-the per-shard slice is too small for the sort/route overhead to amortize
-(``cap < PADDLE_TPU_EMB_MIN_CHUNK``, default 8 — the capacity-factor
-heuristic's degenerate regime). ``comm_bytes_model`` is the analytic
-bytes line the bench record carries (ISSUE 13 acceptance).
+``choose_strategy`` picks per call: psum only when the per-shard slice is
+too small for the sort/route overhead to amortize (``cap < _MIN_CHUNK`` —
+the capacity-factor heuristic's degenerate regime). ``comm_bytes_model``
+is the analytic bytes line the bench record carries (ISSUE 13 acceptance).
 """
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..core.op_registry import register, get, put, env_flag
+from ..core.op_registry import register, get, put
 
 __all__ = ["sharded_lookup", "choose_strategy", "comm_bytes_model"]
 
-_MIN_CHUNK_DEFAULT = 8
-
-
-def _min_chunk():
-    import os
-
-    try:
-        return int(os.environ.get("PADDLE_TPU_EMB_MIN_CHUNK",
-                                  _MIN_CHUNK_DEFAULT))
-    except ValueError:
-        return _MIN_CHUNK_DEFAULT
+_MIN_CHUNK = 8
 
 
 def choose_strategy(n_ids, n_shards, width=None):
     """'alltoall' | 'psum' for a lookup of ``n_ids`` over ``n_shards``.
 
-    PADDLE_TPU_EMB_PSUM=1 forces the legacy psum A/B path. Otherwise the
-    routed path wins whenever each shard's id slice (= the skew-proof
+    The routed path wins whenever each shard's id slice (= the skew-proof
     per-destination capacity) is big enough to amortize the on-device
     binning sort and the collective hops; tiny slices (the degenerate
     capacity regime) keep the single fused psum."""
     del width  # volume ratio is width-independent; kept for future tuning
-    if env_flag("PADDLE_TPU_EMB_PSUM"):
-        return "psum"
     cap = -(-int(n_ids) // max(int(n_shards), 1))
-    if cap < _min_chunk():
+    if cap < _MIN_CHUNK:
         return "psum"
     return "alltoall"
 

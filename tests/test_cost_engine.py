@@ -429,26 +429,23 @@ def test_flash_kernel_plan_gates():
 
     # bf16 transformer-base at T=1024: the copy-free packed path
     plan = fa.kernel_plan((32, 1024, 512), (32, 1024, 512), 8, 2,
-                          causal=False, dropout_rate=0.1,
-                          platform_ok=True)
+                          causal=False, dropout_rate=0.1)
     assert plan.kernel == "packed_stream" and plan.admitted
     # the seq-2048 bench shape: its packed backward is past the chip's
     # scoped VMEM, so it streams head-split and says why
     plan = fa.kernel_plan((16, 2048, 512), (16, 2048, 512), 8, 2,
-                          causal=False, dropout_rate=0.1,
-                          platform_ok=True)
+                          causal=False, dropout_rate=0.1)
     assert plan.kernel == "head_split_stream" and plan.admitted
     assert plan.blocked_only_by("vmem")
     # f32 at a much longer context: falls back to head-split + copies,
     # and says the VMEM budget is why
     plan2 = fa.kernel_plan((16, 16384, 1024), (16, 16384, 1024), 8, 4,
-                           causal=False, dropout_rate=0.0,
-                           platform_ok=True)
+                           causal=False, dropout_rate=0.0)
     assert plan2.kernel == "head_split_stream"
     assert plan2.blocked_only_by("vmem")
     # rich bias form: reference path, reason says so
     plan3 = fa.kernel_plan((4, 64, 64), (4, 64, 64), 4, 4,
-                           bias_kind="rich", platform_ok=True)
+                           bias_kind="rich")
     assert plan3.kernel == "reference"
     assert any(r.check == "bias" for r in plan3.reasons)
 
@@ -604,8 +601,7 @@ def test_sparse_adam_records_scatter_choice(rng):
                 if op.type == "adam" and "_kernel_choice" in op.attrs]
     assert recorded, "sparse adam did not record its scatter choice"
     ch = recorded[0]
-    assert ch["kernel"] in ("xla_at_add", "pallas_rowbin",
-                            "pallas_sorted_segment")
+    assert ch["kernel"] in ("xla_at_add", "pallas_rowbin")
     if ch["kernel"] == "xla_at_add":
         assert ch["reasons"], "refusal must carry structured reasons"
 

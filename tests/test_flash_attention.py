@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import pytest
 
 import paddle_tpu.ops.flash_attention as fa
+from paddle_tpu.ops.gates import GateDecision
 
 
 @pytest.fixture(autouse=True)
@@ -153,24 +154,21 @@ def test_flash_2d_and_broadcast_bias_fallback(rng):
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("packed", [False, True],
+@pytest.mark.parametrize("family", ["head_split_stream", "packed_stream"],
                          ids=["head-split", "packed"])
 @pytest.mark.parametrize("causal,t,tk", [
     (False, 136, 104),   # unaligned kv tail, multi-block both axes
     (True, 136, 136),    # causal diagonal + unaligned tails
     (False, 72, 136),    # q shorter than kv, kv tail masked
 ])
-def test_flash_multiblock_unaligned_tails(rng, causal, t, tk, packed,
-                                          monkeypatch):
+def test_flash_multiblock_unaligned_tails(rng, causal, t, tk, family):
     """Sequences spanning several blocks with t % block != 0 exercise the
     mask-specialized loop splits (unmasked interior / masked diagonal +
     padded tails) in BOTH streaming paths — the packed [B,T,H*D]
     heads-in-kernel one and the legacy head-split one — fwd and bwd, with
-    a key bias. The dense-path ceiling is lowered so the block path
-    engages at these (interpret-tractable) lengths."""
-    monkeypatch.setattr(fa, "_DENSE_MAX_Q", 0)
-    monkeypatch.setattr(fa, "_DENSE_MAX_KV", 0)
-    monkeypatch.setattr(fa, "_PACKED_STREAM", packed)
+    a key bias. The family is named (``plan=``): at these
+    (interpret-tractable) lengths the gate would pick the dense path."""
+    plan = GateDecision(True, family)
     b, h, d = 1, 2, 8
     q, k, v = _mk(rng, b, h, t, tk, d)
     lengths = np.array([tk - 5])
@@ -179,7 +177,7 @@ def test_flash_multiblock_unaligned_tails(rng, causal, t, tk, packed,
 
     def loss_flash(q, k, v):
         o = fa.flash_attention(q, k, v, num_heads=h, bias=bias4,
-                               causal=causal)
+                               causal=causal, plan=plan)
         return jnp.sum(o * jnp.cos(o))
 
     def loss_ref(q, k, v):
@@ -188,7 +186,7 @@ def test_flash_multiblock_unaligned_tails(rng, causal, t, tk, packed,
 
     np.testing.assert_allclose(
         np.asarray(fa.flash_attention(q, k, v, num_heads=h, bias=bias4,
-                                      causal=causal)),
+                                      causal=causal, plan=plan)),
         np.asarray(_ref(q, k, v, h, bias=bias4, causal=causal)),
         rtol=5e-4, atol=5e-4)
     gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
@@ -199,29 +197,26 @@ def test_flash_multiblock_unaligned_tails(rng, causal, t, tk, packed,
                                    err_msg="d%s" % name)
 
 
-def test_packed_stream_matches_head_split(rng, monkeypatch):
+def test_packed_stream_matches_head_split(rng):
     """The packed streaming kernels agree with the head-split streaming
     kernels (not just the reference) fwd+bwd at a multi-head,
     multi-block, biased shape — the copy-free path is a pure layout
     change."""
-    monkeypatch.setattr(fa, "_DENSE_MAX_Q", 0)
-    monkeypatch.setattr(fa, "_DENSE_MAX_KV", 0)
     b, h, t, d = 2, 2, 72, 8
     q, k, v = _mk(rng, b, h, t, t, d)
     lengths = np.array([t - 7, t])
     bias4 = np.where(np.arange(t)[None] < lengths[:, None], 0.0, -1e9)
     bias4 = jnp.asarray(bias4[:, None, None, :].astype("f4"))
 
-    def loss(q, k, v):
+    def loss(q, k, v, family):
         o = fa.flash_attention(q, k, v, num_heads=h, bias=bias4,
-                               causal=True)
+                               causal=True, plan=GateDecision(True, family))
         return jnp.sum(o * jnp.sin(o)), o
 
     outs = {}
     for packed in (False, True):
-        monkeypatch.setattr(fa, "_PACKED_STREAM", packed)
-        (l, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
-                                       has_aux=True)(q, k, v)
+        (l, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            q, k, v, "packed_stream" if packed else "head_split_stream")
         outs[packed] = (np.asarray(o), [np.asarray(x) for x in g])
     np.testing.assert_allclose(outs[True][0], outs[False][0],
                                rtol=2e-4, atol=2e-4)

@@ -634,52 +634,31 @@ def test_record_event_is_a_span_of_the_same_primitive():
     profiler.reset_profiler()
 
 
-@pytest.mark.parametrize("site", ["fused_ce", "fused_layer_norm"])
-def test_fused_ce_and_layer_norm_gates_answer_with_their_reason(
-        site, monkeypatch):
+def test_fused_ce_gate_answers_with_its_reason():
     import jax.numpy as jnp
-    from paddle_tpu.core.op_registry import placed
-    from paddle_tpu.ops import fused_ce, fused_layer_norm
-    from paddle_tpu.ops.gates import GateDecision
+    from paddle_tpu.ops import fused_ce
+    from paddle_tpu.ops.gates import GateDecision, placed
 
-    for env in ("PADDLE_TPU_FUSED_LN", "PADDLE_TPU_FUSED_CE",
-                "PADDLE_TPU_NO_FUSED_CE"):
-        monkeypatch.delenv(env, raising=False)
-    if site == "fused_ce":
-        w = jnp.zeros((512, 30000), jnp.bfloat16)
-        small = jnp.zeros((128 * 256, 512), jnp.bfloat16)  # cell 1's head
-        large = jnp.zeros((256 * 256, 512), jnp.bfloat16)
-        here = fused_ce._use_fused(small, w)
-        assert isinstance(here, GateDecision) and not here
-        assert here.blocked_only_by("platform")
-        with placed("tpu"):
-            refused = fused_ce._use_fused(small, w)
-            admitted = fused_ce._use_fused(large, w)
-        assert not refused and refused.kernel == "xla_projection_ce"
-        assert refused.blocked_only_by("size")
-        assert "9.83e+08 logits" in refused.describe()
-        assert admitted and admitted.kernel == "fused_ce"
-        monkeypatch.setenv("PADDLE_TPU_NO_FUSED_CE", "1")
-        with placed("tpu"):
-            assert fused_ce._use_fused(large, w).blocked_only_by("env")
-    else:
-        with placed("tpu"):
-            opt_in = fused_layer_norm._use_fused(512)
-        assert isinstance(opt_in, GateDecision) and not opt_in
-        assert opt_in.blocked_only_by("env")
-        monkeypatch.setenv("PADDLE_TPU_FUSED_LN", "1")
-        assert fused_layer_norm._use_fused(512).blocked_only_by("platform")
-        with placed("tpu"):
-            assert fused_layer_norm._use_fused(512).kernel == \
-                "fused_layer_norm"
-            assert fused_layer_norm._use_fused(8192).blocked_only_by("vmem")
+    w = jnp.zeros((512, 30000), jnp.bfloat16)
+    small = jnp.zeros((128 * 256, 512), jnp.bfloat16)  # cell 1's head
+    large = jnp.zeros((256 * 256, 512), jnp.bfloat16)
+    here = fused_ce._use_fused(small, w)
+    assert isinstance(here, GateDecision) and not here
+    assert here.blocked_only_by("platform")
+    with placed("tpu"):
+        refused = fused_ce._use_fused(small, w)
+        admitted = fused_ce._use_fused(large, w)
+    assert not refused and refused.kernel == "xla_projection_ce"
+    assert refused.blocked_only_by("size")
+    assert "9.83e+08 logits" in refused.describe()
+    assert admitted and admitted.kernel == "fused_ce"
 
 
 def test_gate_decisions_ride_the_trace_span_and_the_compile_record():
     import paddle_tpu as fluid
 
     x = fluid.layers.data("x", shape=[4, 16])
-    out = fluid.layers.layer_norm(x, begin_norm_axis=2)
+    out = fluid.layers.multi_head_attention(x, x, x, d_model=16, n_head=2)
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(fluid.default_startup_program())
     tracer = trace.start()
@@ -690,9 +669,9 @@ def test_gate_decisions_ride_the_trace_span_and_the_compile_record():
         trace.stop()
     staged, = [s for s in tracer.spans if s["name"] == "executor.trace"]
     gates = staged["tags"]["gates"]
-    assert list(gates) == ["layer_norm"]
-    (line, times), = gates["layer_norm"].items()
-    assert line.startswith("fell back to xla_layer_norm") and times == 1
+    assert list(gates) == ["flash_attention"]
+    (line, times), = gates["flash_attention"].items()
+    assert line.startswith("fell back to reference") and times == 1
     assert exe.compile_records[-1]["gates"] == gates
     compiled, = [s for s in tracer.spans
                  if s["name"] == "executor.backend_compile"]

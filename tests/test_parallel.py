@@ -145,16 +145,15 @@ def test_sharded_lookup_out_of_range_rows_zero():
         np.testing.assert_allclose(out[2:], 0.0)
 
 
-def test_sharded_lookup_strategy_selection(monkeypatch):
+def test_sharded_lookup_strategy_selection():
     from paddle_tpu.parallel.sharded_embedding import choose_strategy
 
-    monkeypatch.delenv("PADDLE_TPU_EMB_PSUM", raising=False)
-    monkeypatch.delenv("PADDLE_TPU_EMB_MIN_CHUNK", raising=False)
     assert choose_strategy(1024, 8) == "alltoall"
     # degenerate slices: the route/sort overhead can't amortize
     assert choose_strategy(8, 8) == "psum"
-    monkeypatch.setenv("PADDLE_TPU_EMB_PSUM", "1")  # A/B override
-    assert choose_strategy(1024, 8) == "psum"
+    # the threshold: 8 ids a shard
+    assert choose_strategy(57, 8) == "alltoall"
+    assert choose_strategy(56, 8) == "psum"
 
 
 def test_sharded_lookup_alltoall_grad_matches():
@@ -176,19 +175,19 @@ def test_sharded_lookup_alltoall_grad_matches():
                                rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.parametrize("force_psum", [False, True])
-def test_sharded_lookup_op_padding_idx(monkeypatch, force_psum):
+@pytest.mark.parametrize("n_ids,strategy", [(16, "alltoall"), (5, "psum")])
+def test_sharded_lookup_op_padding_idx(n_ids, strategy):
     """padding_idx through the SYMBOLIC op under a live mesh: padding
-    rows read as zeros on both formulations, matching the single-chip
+    rows read as zeros on both formulations (an id count on each side of
+    ``choose_strategy``'s threshold over mp=2), matching the single-chip
     lookup_table run of the same program."""
+    from paddle_tpu.parallel.sharded_embedding import choose_strategy
     from paddle_tpu.parallel.transpiler import DistributeTranspiler
     from paddle_tpu.parallel.mesh import DistStrategy, mesh_scope
 
-    if force_psum:
-        monkeypatch.setenv("PADDLE_TPU_EMB_PSUM", "1")
-    else:
-        monkeypatch.delenv("PADDLE_TPU_EMB_PSUM", raising=False)
-    ids_np = np.array([[0], [3], [7], [0], [15]], dtype="int64")
+    assert choose_strategy(n_ids, 2) == strategy
+    ids_np = np.resize(np.array([0, 3, 7, 0, 15], dtype="int64"),
+                       (n_ids, 1))
 
     def run(sharded):
         main, startup = fluid.Program(), fluid.Program()
@@ -222,10 +221,10 @@ def test_sharded_lookup_op_padding_idx(monkeypatch, force_psum):
     shard, _ = run(sharded=True)
     # padding rows exactly zero; non-padding rows match the plain run's
     # contract (w may differ across builds, so compare vs own table)
-    np.testing.assert_allclose(plain[[0, 3]], 0.0)
-    np.testing.assert_allclose(shard[[0, 3]], 0.0)
-    np.testing.assert_allclose(shard[[1, 2, 4]],
-                               w[[3, 7, 15]], rtol=1e-6)
+    pad = ids_np[:, 0] == 0
+    np.testing.assert_allclose(plain[pad], 0.0)
+    np.testing.assert_allclose(shard[pad], 0.0)
+    np.testing.assert_allclose(shard[~pad], w[ids_np[~pad, 0]], rtol=1e-6)
 
 
 def test_dryrun_sharded_embedding_stage():
@@ -233,7 +232,7 @@ def test_dryrun_sharded_embedding_stage():
     DeepFM trains with the table mp-sharded, the compiled HLO keeps the
     table sharded with no full-table all-gather, the step jaxpr carries
     the all-to-all lookup with NO full-output psum, and the
-    PADDLE_TPU_EMB_PSUM=1 negative control trips the audit."""
+    psum-of-partials negative control trips the audit."""
     import __graft_entry__ as graft
 
     graft._stage_sharded_embedding(fluid.Executor(fluid.XLAPlace(0)),

@@ -15,6 +15,7 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu.core import op_registry
 from paddle_tpu.distributed import launch
+from paddle_tpu.ops import gates
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -92,27 +93,66 @@ def test_serving_engine_devices_take_places(tmp_path):
 
 # -- the placement the gates read -------------------------------------------
 
-def test_gates_follow_the_declared_placement():
-    """One TPU chip gets its kernels whatever the host holds; a meshed
-    step and a CPU step do not, and the plan says why."""
-    from paddle_tpu.ops import flash_attention as fa
-    from paddle_tpu.ops import fused_conv, scatter
+def _sds(*shape):
+    return jax.ShapeDtypeStruct(shape, jax.numpy.bfloat16)
 
-    assert not op_registry.single_tpu()  # this process: CPU
-    with op_registry.placed("tpu"):
-        assert op_registry.single_tpu()
-        assert fa._use_pallas()
-        assert scatter.gate(1000, 16, 1024, "float32").admitted
-    with op_registry.placed("tpu", meshed=True):
-        assert not op_registry.single_tpu()
-        plan = fa.kernel_plan((2, 128, 64), (2, 128, 64), 4, 2,
-                              platform_ok=fa._use_pallas())
-        assert plan.kernel == "reference"
-        assert "mesh" in plan.reasons[0].detail
-        decision = fused_conv.gate((2, 8, 8, 8), (8, 8, 1, 1), (1, 1),
-                                   (0, 0), (1, 1), 1, 2, False)
-        assert decision.blocked_only_by("platform")
-    assert op_registry.placed_platform() == "cpu"  # restored
+
+def _flash_attention_gate():
+    from paddle_tpu.ops import flash_attention as fa
+
+    return fa.plan_for(_sds(2, 128, 64), _sds(2, 128, 64), None, 4, False,
+                       0.0, None)
+
+
+def _fused_conv_gate():
+    from paddle_tpu.ops import fused_conv
+
+    return fused_conv.gate((2, 8, 8, 8), (8, 8, 1, 1), (1, 1), (0, 0),
+                           (1, 1), 1, 2, False)
+
+
+def _fused_ce_gate():
+    from paddle_tpu.ops import fused_ce
+
+    # 2.1e9 logits: past the size threshold, and never made
+    return fused_ce._use_fused(_sds(65536, 8), _sds(8, 32768))
+
+
+def _scatter_gate():
+    from paddle_tpu.ops import scatter
+
+    return scatter.gate(1000, 16, 1024, "float32")
+
+
+def _gated_delta_gate():
+    from paddle_tpu.ops import gated_delta
+
+    return gated_delta.plan_for(_sds(1, 128, 2 * 128), _sds(1, 128, 4 * 128),
+                                2, 4, 64)
+
+
+@pytest.mark.parametrize("placement,one_chip", [
+    (("cpu",), False), (("tpu",), True), (("tpu", True), False)],
+    ids=["cpu", "one_tpu_chip", "tpu_under_a_mesh"])
+@pytest.mark.parametrize("family", [
+    _flash_attention_gate, _fused_conv_gate, _fused_ce_gate, _scatter_gate,
+    _gated_delta_gate], ids=lambda f: f.__name__[1:-5])
+def test_gates_follow_the_declared_placement(family, placement, one_chip):
+    """One TPU chip gets its kernels whatever the host holds; a meshed
+    step and a CPU step do not, and all that stands in the way of a shape
+    the family admits is ``gates``' own 'platform' reason."""
+    with gates.placed(*placement):
+        assert gates.single_tpu() == one_chip
+        decision = family()
+        own = gates.platform_reason()
+    assert decision.admitted == one_chip, decision
+    if one_chip:
+        assert own is None and not decision.blocking_reasons
+    else:
+        assert [r.to_dict() for r in decision.blocking_reasons] == [
+            own.to_dict()], decision
+        assert ("mesh" in own.detail) == (len(placement) == 2)
+    assert gates.placed_platform() == "cpu"  # this process; restored
 
 
 def test_executor_declares_placement_while_tracing():
@@ -122,8 +162,7 @@ def test_executor_declares_placement_while_tracing():
 
     @op_registry.register("_probe_placement")
     def _probe(env, op):
-        seen.append((op_registry.placed_platform(),
-                     op_registry.PLACEMENT.meshed))
+        seen.append((gates.placed_platform(), gates.PLACEMENT.meshed))
         op_registry.put(env, op.output("Out"),
                         op_registry.get(env, op.input("X")))
 
@@ -142,6 +181,60 @@ def test_executor_declares_placement_while_tracing():
     finally:
         del op_registry.OP_IMPLS["_probe_placement"]
     assert seen == [("cpu", False), ("cpu", True)]
+
+
+# -- one owner, and no switch beside it --------------------------------------
+
+def _sources(*parts):
+    root = os.path.join(_REPO, *parts)
+    for folder, _, files in os.walk(root):
+        for name in files:
+            if not name.endswith(".pyc"):
+                with open(os.path.join(folder, name), errors="replace") as f:
+                    yield os.path.relpath(f.name, _REPO), f.read()
+
+
+def test_environment_variables_are_deployment_settings_only():
+    """The ``PADDLE_TPU_*`` names under ``paddle_tpu/`` are paths, fault
+    plans, tracing, verification and ready lines: none selects a
+    performance path (the code chooses from shapes and placement)."""
+    import re
+
+    names = set()
+    for _, text in _sources("paddle_tpu"):
+        names.update(re.findall(r"PADDLE_TPU_[A-Z0-9_]+", text))
+    assert names == {"PADDLE_TPU_" + n for n in (
+        "CACHE", "COMPILE_CACHE_BUDGET", "DATASET_DOWNLOAD", "DATA_HOME",
+        "DECODE_MAX_NEW", "PREFIX_CACHE_MB", "FAULTS", "FLIGHT", "TRACE",
+        "VERIFY", "XLA_OPTIONS", "ROUTER_READY", "TRAINER_READY",
+        "WORKER_READY")}
+
+
+def test_ops_ask_gates_where_they_run_and_nothing_above_them():
+    """``ops/`` is the lowest layer: placement lives in ``ops/gates.py``,
+    and no module there reaches up into ``paddle_tpu.core`` for it or for
+    a switch."""
+    import ast
+
+    for attr in ("placed", "single_tpu", "placement_reason", "PLACEMENT",
+                 "placed_platform"):
+        assert not hasattr(op_registry, attr), attr
+    reached = []
+    for path, text in _sources("paddle_tpu", "ops"):
+        if not path.endswith(".py"):
+            continue
+        for node in ast.walk(ast.parse(text)):
+            if isinstance(node, ast.ImportFrom) and (
+                    (node.level == 2 and (node.module or "").startswith(
+                        "core")) or (node.module or "").startswith(
+                        "paddle_tpu.core")):
+                reached += [(path, a.name) for a in node.names]
+    assert not {n for _, n in reached} & {
+        "placed", "single_tpu", "placement_reason", "placed_platform",
+        "PLACEMENT", "env_flag"}, reached
+    # what is left, by name (ROADMAP D15)
+    assert reached == [(os.path.join("paddle_tpu", "ops", "scatter.py"),
+                        "merge_sparse_rows")]
 
 
 # -- one process for each chip ----------------------------------------------

@@ -498,20 +498,18 @@ def test_gated_delta_gate_admits_the_published_shape_and_says_why_not():
     assert narrow.blocked_only_by("geometry") and "128" in narrow.describe()
     assert gated_delta.kernel_plan(8192, 16, 32, 128, 128,
                                    32).blocked_only_by("geometry")
-    from paddle_tpu.core.op_registry import placed
+    from paddle_tpu.ops.gates import placed, platform_reason
 
     for where in (dict(platform="tpu", meshed=True), dict(platform="cpu")):
         with placed(**where):
             off = gated_delta.kernel_plan(
-                8192, 16, 32, 128, 128, 64,
-                platform_ok=gated_delta._use_pallas())
+                8192, 16, 32, 128, 128, 64, platform=platform_reason())
         assert off.kernel == "chunked_scan_xla"
         assert off.blocked_only_by("platform")
         assert ("mesh" in off.describe()) == bool(where.get("meshed"))
     with placed("tpu"):
         assert gated_delta.kernel_plan(
-            8192, 16, 32, 128, 128, 64,
-            platform_ok=gated_delta._use_pallas()).admitted
+            8192, 16, 32, 128, 128, 64, platform=platform_reason()).admitted
     # the shape-only pass reads the same gate
     from paddle_tpu.analysis import resources
 

@@ -8,7 +8,7 @@ than the chip has, a slice Mosaic cannot tile, a program that does not fit
 HBM. A compile is not a run — numerics and time on the chip are
 ``chip_smoke.py``'s business.
 
-The gates are steered from here with ``op_registry.placed("tpu")`` (code
+The gates are steered from here with ``ops.gates.placed("tpu")`` (code
 that asks JAX for its devices still sees the CPU). The whole train step of
 every BASELINE config is compiled the same way in the ``slow`` cases.
 Describing the topology takes libtpu's process lock: one such process at a
@@ -26,10 +26,9 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from paddle_tpu.core.op_registry import placed  # noqa: E402
 from paddle_tpu.ops import flash_attention as fa  # noqa: E402
-from paddle_tpu.ops import fused_ce, fused_conv, fused_layer_norm  # noqa: E402
-from paddle_tpu.ops import scatter  # noqa: E402
+from paddle_tpu.ops import fused_ce, fused_conv, scatter  # noqa: E402
+from paddle_tpu.ops.gates import placed, platform_reason  # noqa: E402
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -183,7 +182,7 @@ def test_gated_delta_core_and_routed_experts_fit_at_published_widths(chip):
     with placed("tpu"):
         assert gated_delta.kernel_plan(
             t, 16, 32, 128, 128, 64,
-            platform_ok=gated_delta._use_pallas()).kernel == "gated_delta"
+            platform=platform_reason()).kernel == "gated_delta"
 
     def core(q, k, v, a, b, a_log, dt_bias):
         return jnp.sum(gated_delta.gated_delta_attention(
@@ -213,12 +212,12 @@ def test_packed_stream_gate_counts_what_mosaic_allocates():
     """The packed backward at the seq-2048 bench shape is what the chip's
     compiler refused (16.66M of 16M scoped VMEM alone, 19.16M inside the
     step): the gate refuses it too, and admits T=1024 (compiled above)."""
-    assert not fa._packed_stream_fits(2048, 2048, 512, 2, 8, dropout=0.1)
-    assert fa._packed_stream_fits(1024, 1024, 512, 2, 8, dropout=0.1)
+    assert not fa._packed_stream_fits(2048, 2048, 512, 2, 8)
+    assert fa._packed_stream_fits(1024, 1024, 512, 2, 8)
 
 
 # ---------------------------------------------------------------------------
-# fused CE, fused LN
+# fused CE
 # ---------------------------------------------------------------------------
 
 def test_fused_ce_compiles(chip):
@@ -235,19 +234,6 @@ def test_fused_ce_compiles(chip):
                         sds((v,), BF16), sds((t,), I32))
     assert _kernel_calls(compiled) >= 1  # fwd kernel; bwd is an XLA scan
     _assert_named(compiled, {"fused_ce.fwd"})
-
-
-def test_fused_layer_norm_compiles(chip):
-    t, d = 128 * 256, 512
-
-    def loss(x, g, b):
-        y, _, _ = fused_layer_norm._fused_ln(x, g, b, 1e-5)
-        return jnp.sum(y.astype(F32))
-
-    compiled = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
-                        sds((t, d), BF16), sds((d,), F32), sds((d,), F32))
-    assert _kernel_calls(compiled) == 2
-    _assert_named(compiled, {"fused_layer_norm.fwd", "fused_layer_norm.bwd"})
 
 
 # ---------------------------------------------------------------------------
@@ -553,7 +539,7 @@ def test_meshed_train_step_compiles_for_four_chips(topology):
         return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
                                     sharding=sharding)
 
-    step = build_step_fn(program, (loss,), persist, fuse_opt=False)
+    step = build_step_fn(program, (loss,), persist)
     with placed("tpu", meshed=True):
         lowered = jax.jit(step, donate_argnums=(0,), in_shardings=in_sh,
                           out_shardings=out_sh).lower(
