@@ -119,6 +119,10 @@ def _check_flash(ctx, op, region, diags):
         causal=op.attr("causal", False),
         dropout_rate=op.attr("dropout_rate", 0.0) or 0.0,
         bias_kind=bias_kind, rng_available=True)
+    # a site that only the VMEM budget keeps off the copy-free kernels: one
+    # lane window's full-T q/do/dq no longer fit (bf16: past T=3072 at
+    # D=64 and D=128, past T=1024 at D=256), so the head-split kernels run,
+    # with their [B,T,H,D] relayout copies round the site
     if plan.kernel in ("reference", "head_split_stream") and \
             plan.blocked_only_by("vmem"):
         diags.append(_gate_diag(op, plan, region, "packed_stream"))

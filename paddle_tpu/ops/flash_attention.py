@@ -534,27 +534,45 @@ def _flash_bwd_impl(q, k, v, bias, seed, out, lse, do, causal, scale,
 # packed STREAMING kernels — [B, T, H*D] layout, heads looped in-kernel
 # ---------------------------------------------------------------------------
 #
-# The head-split streaming path below reshapes [B,T,H*D] -> [B*H,T,D] around
-# the custom calls, and XLA materializes those relayouts as real HBM copies
-# (7 per attention site). These kernels keep the packed layout the projection
-# matmuls produce END TO END: the grid stays (batch, block), each program
-# loops the heads over static lane slices (like the dense kernels), and the
-# online-softmax k-loop streams K/V blocks exactly as the head-split
-# kernels do. The price is VMEM: K/V (fwd) and q/do/dq-f32 (bwd) are
-# full-T refs of width H*D rather than D, which caps the single-chip
-# packed path near T~1k for bf16 transformer-base; longer contexts keep
-# the head-split path (gate: _packed_stream_fits).
+# The head-split streaming path reshapes [B,T,H*D] -> [B*H,T,D] around the
+# custom calls, and XLA materializes those relayouts as real HBM copies
+# (7 per attention site; 22.9 ms of a 218.7 ms step at 16 x 2048, 8 heads of
+# 64). These kernels keep the packed layout the projection matmuls produce
+# END TO END. The grid is (batch, lane window, block): a program holds ONE
+# window of the packed head dimension, ``_lane_window`` lanes wide (the
+# least run of whole heads that fills whole 128-lane tiles: two heads of 64,
+# one head of 128 or 256; the whole H*D where no such run divides it), taken
+# by the BlockSpecs straight out of the packed arrays. It loops the window's
+# heads over static lane slices (like the dense kernels), and the
+# online-softmax k-loop streams K/V blocks exactly as the head-split kernels
+# do. VMEM holds one window's full-T K/V (fwd) and q/do/dq-f32 (bwd), as
+# wide as the window and not as H*D, so the path reaches as far as one
+# head's streaming does (gate: _packed_stream_fits; the lengths are in
+# kernel_plan's docstring).
+
+def _lane_window(hd, num_heads):
+    """Lanes of the packed head dimension one program of the packed
+    streaming kernels holds: lcm(D, 128) where that divides H*D, else all
+    of H*D (then the one window is the whole array, a legal block at any
+    width)."""
+    w = math.lcm(hd // num_heads, 128)
+    return w if hd % w == 0 else hd
+
 
 def _packed_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref,
-                       lse_ref, *, num_heads, block_k, causal, scale,
-                       kv_len, dropout_rate):
+                       lse_ref, *, num_heads, total_heads, block_k, causal,
+                       scale, kv_len, dropout_rate):
+    """``num_heads``: the heads of this program's lane window;
+    ``total_heads``: all H, for the dropout tile tag."""
     from jax.experimental import pallas as pl
 
-    block_q, hd = q_ref.shape
-    d = hd // num_heads
+    block_q, w = q_ref.shape
+    d = w // num_heads
     kv_pad = k_ref.shape[0]
-    b_idx = pl.program_id(0)
-    q_idx = pl.program_id(1)
+    # the window's first head among all B*H: dropout tiles are tagged by
+    # the global head, the tags the head-split kernels draw from
+    head0 = pl.program_id(0) * total_heads + pl.program_id(1) * num_heads
+    q_idx = pl.program_id(2)
 
     num_kb = kv_pad // block_k
     if causal:
@@ -599,7 +617,7 @@ def _packed_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref,
                 if dropout_rate > 0.0:
                     keep = _dropout_keep(
                         (block_k, block_q), dropout_rate, seed_ref[0, 0],
-                        (b_idx * num_heads + h, q_idx, kb))
+                        (head0 + h, q_idx, kb))
                     p_use = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
                 acc_new = acc * alpha + jax.lax.dot_general(
                     v, p_use.astype(v.dtype), (((0,), (0,)), ((), ())),
@@ -624,25 +642,33 @@ def _packed_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref,
 
 def _packed_bwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, do_ref,
                        lse_ref, delta_ref, dk_ref, dv_ref, db_ref, dq_ref,
-                       *, num_heads, block_q, causal, scale, kv_len, kv_pad,
-                       q_len, dropout_rate):
+                       dq_acc_ref, *, num_heads, total_heads, block_q,
+                       causal, scale, kv_len, kv_pad, q_len, dropout_rate):
+    """``num_heads`` / ``total_heads``: as in :func:`_packed_fwd_kernel`.
+    ``dq_acc_ref``: the float32 scratch dq adds up in, or None where
+    ``dq_ref`` is float32 itself."""
     from jax.experimental import pallas as pl
 
-    block_k, hd = k_ref.shape
-    d = hd // num_heads
+    block_k, w = k_ref.shape
+    d = w // num_heads
     q_pad = q_ref.shape[0]
-    b_idx = pl.program_id(0)
-    k_idx = pl.program_id(1)
+    head0 = pl.program_id(0) * total_heads + pl.program_id(1) * num_heads
+    k_idx = pl.program_id(2)
 
     bias_blk = None
     if bias_ref is not None:
         bias_blk = bias_ref[0, pl.dslice(k_idx * block_k, block_k)]
 
-    # dq accumulates into the SAME revisited full-T packed buffer for
-    # every k step (cf. _bwd_dkv_kernel); zero it on the first
+    # dq adds up in float32 over the k steps, the innermost grid axis, in
+    # ONE full-T buffer of this lane window (cf. _bwd_dkv_kernel): zeroed
+    # on the first step, and on the last rounded once into the revisited
+    # output block, which Mosaic writes back once per (batch, window) — in
+    # q's dtype, so no float32 dq goes through HBM to be converted there
+    dq_acc = dq_ref if dq_acc_ref is None else dq_acc_ref
+
     @pl.when(k_idx == 0)
     def _init_dq():
-        dq_ref[...] = jnp.zeros_like(dq_ref)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     kvalid = None
     if kv_len < kv_pad:
@@ -696,7 +722,7 @@ def _packed_bwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, do_ref,
                 if dropout_rate > 0.0:
                     keep = _dropout_keep(
                         (block_k, block_q), dropout_rate, seed_ref[0, 0],
-                        (b_idx * num_heads + h, qb, k_idx))
+                        (head0 + h, qb, k_idx))
                     inv = 1.0 / (1.0 - dropout_rate)
                     p_drop = jnp.where(keep, p * inv, 0.0)
                     dp = jnp.where(keep, dp * inv, 0.0)
@@ -713,7 +739,7 @@ def _packed_bwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, do_ref,
                         ds, jnp.ones((1, block_q), jnp.float32),
                         (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32)
-                dq_ref[qsl, sl] += jax.lax.dot_general(
+                dq_acc[qsl, sl] += jax.lax.dot_general(
                     ds.astype(k.dtype), k, (((0,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
                 return dk, dv, db
@@ -743,9 +769,15 @@ def _packed_bwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, do_ref,
         dv_ref[:, sl] = dv.astype(dv_ref.dtype)
         if db_total is not None:
             db_total = db_total + db  # bias is shared across heads
+    # this window's heads' part of the bias gradient; the caller adds the
+    # windows up
     if db_ref is not None:
         db_ref[0, pl.dslice(k_idx * block_k, block_k)] = \
             db_total[:, 0].astype(db_ref.dtype)
+    if dq_acc_ref is not None:
+        @pl.when(k_idx == pl.num_programs(2) - 1)
+        def _emit_dq():
+            dq_ref[...] = dq_acc_ref[...].astype(dq_ref.dtype)
 
 
 @traced_once("packed_stream.fwd",
@@ -755,7 +787,9 @@ def _packed_stream_fwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
                             dropout_rate, blocks, interpret):
     """q,k,v: packed [B, T, H*D]; bias [B, Tk] or None; ``blocks``:
     :func:`_block_sizes` of the forward.
-    Returns (out [B, T, H*D], lse [B, nh_pad, T])."""
+    Returns (out [B, T, H*D], lse [B, G, rows, T_pad]): the row logsumexp
+    as the kernels hold it, one sublane-padded block a lane window, heads
+    ``g * (H // G) + row`` in its first rows (what the backward takes)."""
     from jax.experimental import pallas as pl
 
     b, t, hd = q.shape
@@ -763,23 +797,27 @@ def _packed_stream_fwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
     block_q, block_k = blocks
     qp, kp, vp = _pad_t(q, block_q), _pad_t(k, block_k), _pad_t(v, block_k)
     t_pad, tk_pad = qp.shape[1], kp.shape[1]
-    nh_pad = max(num_heads, 8)
+    w = _lane_window(hd, num_heads)
+    windows = hd // w
+    heads = num_heads // windows
+    rows = max(heads, 8)
 
     kernel = functools.partial(
-        _packed_fwd_kernel, num_heads=num_heads, block_k=block_k,
-        causal=causal, scale=scale, kv_len=t_k, dropout_rate=dropout_rate)
+        _packed_fwd_kernel, num_heads=heads, total_heads=num_heads,
+        block_k=block_k, causal=causal, scale=scale, kv_len=t_k,
+        dropout_rate=dropout_rate)
     in_specs = [
-        pl.BlockSpec((None, block_q, hd), lambda b, qi: (b, qi, 0)),
-        pl.BlockSpec((None, tk_pad, hd), lambda b, qi: (b, 0, 0)),
-        pl.BlockSpec((None, tk_pad, hd), lambda b, qi: (b, 0, 0)),
+        pl.BlockSpec((None, block_q, w), lambda b, g, qi: (b, qi, g)),
+        pl.BlockSpec((None, tk_pad, w), lambda b, g, qi: (b, 0, g)),
+        pl.BlockSpec((None, tk_pad, w), lambda b, g, qi: (b, 0, g)),
     ]
     args = [qp, kp, vp]
     if bias is not None:
         in_specs.append(pl.BlockSpec((None, 8, tk_pad),
-                                     lambda b, qi: (b, 0, 0)))
+                                     lambda b, g, qi: (b, 0, 0)))
         bp = _pad_vec(bias, block_k)
         args.append(jnp.broadcast_to(bp[:, None, :], (b, 8, tk_pad)))
-    in_specs.append(pl.BlockSpec((1, 1), lambda b, qi: (0, 0)))
+    in_specs.append(pl.BlockSpec((1, 1), lambda b, g, qi: (0, 0)))
     args.append(jnp.asarray([[seed]], jnp.uint32))
 
     def entry(*refs):
@@ -793,19 +831,20 @@ def _packed_stream_fwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
     out, lse = named_pallas_call(
         "packed_stream.fwd",
         entry,
-        grid=(b, t_pad // block_q),
+        grid=(b, windows, t_pad // block_q),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((None, block_q, hd), lambda b, qi: (b, qi, 0)),
-            pl.BlockSpec((None, nh_pad, t_pad), lambda b, qi: (b, 0, 0)),
+            pl.BlockSpec((None, block_q, w), lambda b, g, qi: (b, qi, g)),
+            pl.BlockSpec((None, None, rows, t_pad),
+                         lambda b, g, qi: (b, g, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, t_pad, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, nh_pad, t_pad), jnp.float32),
+            jax.ShapeDtypeStruct((b, windows, rows, t_pad), jnp.float32),
         ],
         interpret=interpret,
     )(*args)
-    return out[:, :t], lse[:, :, :t]
+    return out[:, :t], lse
 
 
 @traced_once("packed_stream.bwd",
@@ -813,33 +852,27 @@ def _packed_stream_fwd_impl(q, k, v, bias, seed, num_heads, causal, scale,
               "interpret"))
 def _packed_stream_bwd_impl(q, k, v, bias, seed, out, lse, do, num_heads,
                             causal, scale, dropout_rate, blocks, interpret):
-    """``blocks``: :func:`_block_sizes`, the forward's."""
+    """``blocks``: :func:`_block_sizes`, the forward's; ``lse``: as
+    :func:`_packed_stream_fwd_impl` returns it."""
     from jax.experimental import pallas as pl
 
     b, t, hd = q.shape
     t_k = k.shape[1]
-    d = hd // num_heads
     block_q, block_k = blocks
     qp, kp, vp = _pad_t(q, block_q), _pad_t(k, block_k), _pad_t(v, block_k)
     dop = _pad_t(do, block_q)
     t_pad, tk_pad = qp.shape[1], kp.shape[1]
-    nh_pad = lse.shape[1]
-    # per-(b, h, t) delta = rowsum_d(do * o) over this head's lanes; the
-    # [B,T,H] reduce + transpose is tiny next to the old full [B,T,H,D]
-    # relayouts
-    prod = jnp.sum(
+    _, windows, rows, _ = lse.shape
+    w = hd // windows
+    heads = num_heads // windows
+    # per-(b, h, t) delta = rowsum_d(do * o) over this head's lanes, laid
+    # out like lse; the [B,T,H] reduce + transpose is tiny next to a
+    # [B,T,H,D] relayout
+    delta = jnp.sum(
         (do.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
-            b, t, num_heads, d), axis=-1)
-    delta = prod.transpose(0, 2, 1)  # [B, H, T]
-
-    def pad_stats(x):  # [B, nh?, T] -> [B, nh_pad, T_pad]
-        if x.shape[1] < nh_pad:
-            x = jnp.pad(x, ((0, 0), (0, nh_pad - x.shape[1]), (0, 0)))
-        r = (-x.shape[2]) % block_q
-        return jnp.pad(x, ((0, 0), (0, 0), (0, r))) if r else x
-
-    lsep = pad_stats(lse)
-    deltap = pad_stats(delta)
+            b, t, windows, heads, hd // num_heads), axis=-1)
+    deltap = jnp.pad(delta.transpose(0, 2, 3, 1),
+                     ((0, 0), (0, 0), (0, rows - heads), (0, t_pad - t)))
     if bias is not None:
         bp = _pad_vec(bias, block_k)
         biasp = jnp.broadcast_to(bp[:, None, :], (b, 8, bp.shape[1]))
@@ -847,11 +880,16 @@ def _packed_stream_bwd_impl(q, k, v, bias, seed, out, lse, do, num_heads,
         biasp = None
 
     kernel = functools.partial(
-        _packed_bwd_kernel, num_heads=num_heads, block_q=block_q,
-        causal=causal, scale=scale, kv_len=t_k, kv_pad=tk_pad, q_len=t,
-        dropout_rate=dropout_rate)
+        _packed_bwd_kernel, num_heads=heads, total_heads=num_heads,
+        block_q=block_q, causal=causal, scale=scale, kv_len=t_k,
+        kv_pad=tk_pad, q_len=t, dropout_rate=dropout_rate)
+
+    # dq's float32 sum lives in a scratch unless the output is float32
+    narrow = q.dtype != jnp.float32
 
     def entry(*refs):
+        refs = list(refs)
+        acc_ref = refs.pop() if narrow else None
         if biasp is not None:
             (q_ref, k_ref, v_ref, b_ref, s_ref, do_ref, l_ref, de_ref,
              dk_ref, dv_ref, db_ref, dq_ref) = refs
@@ -860,57 +898,75 @@ def _packed_stream_bwd_impl(q, k, v, bias, seed, out, lse, do, num_heads,
              dk_ref, dv_ref, dq_ref) = refs
             b_ref = db_ref = None
         kernel(q_ref, k_ref, v_ref, b_ref, s_ref, do_ref, l_ref, de_ref,
-               dk_ref, dv_ref, db_ref, dq_ref)
+               dk_ref, dv_ref, db_ref, dq_ref, acc_ref)
+
+    def whole(b, g, ki):     # a window's full-T block: q, do, dq
+        return b, 0, g
+
+    def block(b, g, ki):     # a window's k block: k, v, dk, dv
+        return b, ki, g
+
+    def stats(b, g, ki):     # a window's per-head rows: lse, delta, db
+        return b, g, 0, 0
 
     in_specs = [
-        pl.BlockSpec((None, t_pad, hd), lambda b, ki: (b, 0, 0)),
-        pl.BlockSpec((None, block_k, hd), lambda b, ki: (b, ki, 0)),
-        pl.BlockSpec((None, block_k, hd), lambda b, ki: (b, ki, 0)),
+        pl.BlockSpec((None, t_pad, w), whole),
+        pl.BlockSpec((None, block_k, w), block),
+        pl.BlockSpec((None, block_k, w), block),
     ]
     args = [qp, kp, vp]
     if biasp is not None:
         in_specs.append(pl.BlockSpec((None, 8, tk_pad),
-                                     lambda b, ki: (b, 0, 0)))
+                                     lambda b, g, ki: (b, 0, 0)))
         args.append(biasp)
-    in_specs.append(pl.BlockSpec((1, 1), lambda b, ki: (0, 0)))
+    in_specs.append(pl.BlockSpec((1, 1), lambda b, g, ki: (0, 0)))
     args.append(jnp.asarray([[seed]], jnp.uint32))
     in_specs += [
-        pl.BlockSpec((None, t_pad, hd), lambda b, ki: (b, 0, 0)),
-        pl.BlockSpec((None, nh_pad, t_pad), lambda b, ki: (b, 0, 0)),
-        pl.BlockSpec((None, nh_pad, t_pad), lambda b, ki: (b, 0, 0)),
+        pl.BlockSpec((None, t_pad, w), whole),
+        pl.BlockSpec((None, None, rows, t_pad), stats),
+        pl.BlockSpec((None, None, rows, t_pad), stats),
     ]
-    args += [dop, lsep, deltap]
+    args += [dop, lse, deltap]
     out_specs = [
-        pl.BlockSpec((None, block_k, hd), lambda b, ki: (b, ki, 0)),
-        pl.BlockSpec((None, block_k, hd), lambda b, ki: (b, ki, 0)),
+        pl.BlockSpec((None, block_k, w), block),
+        pl.BlockSpec((None, block_k, w), block),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((b, tk_pad, hd), k.dtype),
         jax.ShapeDtypeStruct((b, tk_pad, hd), v.dtype),
     ]
     if biasp is not None:
-        out_specs.append(pl.BlockSpec((None, 8, tk_pad),
-                                      lambda b, ki: (b, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((b, 8, tk_pad), jnp.float32))
-    out_specs.append(pl.BlockSpec((None, t_pad, hd),
-                                  lambda b, ki: (b, 0, 0)))
-    out_shape.append(jax.ShapeDtypeStruct((b, t_pad, hd), jnp.float32))
+        # every head adds to the per-key bias gradient: one partial a
+        # window (row 0 of its block), summed below
+        out_specs.append(pl.BlockSpec((None, None, 8, tk_pad), stats))
+        out_shape.append(jax.ShapeDtypeStruct((b, windows, 8, tk_pad),
+                                              jnp.float32))
+    # dq: a window's full-T block, the SAME for every k step (Mosaic
+    # revisiting: written back once per (b, window))
+    out_specs.append(pl.BlockSpec((None, t_pad, w), whole))
+    out_shape.append(jax.ShapeDtypeStruct((b, t_pad, hd), q.dtype))
+    scratch = []
+    if narrow:
+        from jax.experimental.pallas import tpu as pltpu
+
+        scratch.append(pltpu.VMEM((t_pad, w), jnp.float32))
     res = named_pallas_call(
         "packed_stream.bwd",
         entry,
-        grid=(b, tk_pad // block_k),
+        grid=(b, windows, tk_pad // block_k),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
     )(*args)
     if biasp is not None:
         dk, dv, db, dq = res
-        db = db[:, 0, :t_k]
+        db = jnp.sum(db[:, :, 0, :t_k], axis=1)
     else:
         dk, dv, dq = res
         db = None
-    return dq[:, :t].astype(q.dtype), dk[:, :t_k], dv[:, :t_k], db
+    return dq[:, :t], dk[:, :t_k], dv[:, :t_k], db
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
@@ -942,35 +998,62 @@ _packed_stream_attention.defvjp(_packed_stream_fwd, _packed_stream_bwd)
 
 # The chip's compiler gives one kernel 16 MiB of scoped VMEM and refuses
 # the whole program when a kernel asks for more. What a kernel asks for
-# also depends on the program around it: the bf16 T=2048 H*D=512 backward
-# was refused at 16.66M compiled alone and at 19.16M inside the seq-2048
-# train step. So 3 MiB stay free for what the surrounding step adds.
+# also depends on the program around it: the bf16 T=2048 backward of one
+# 128-lane window compiles alone under 9.375M and inside the seq-2048 train
+# step under 10.25M, its forward under 4.25M and 5.0M (the least
+# ``vmem_limit_bytes`` that compiles; the size a refusal prints is the
+# running sum at the allocation that failed, not the kernel's whole need).
+# So 3 MiB stay free for what the surrounding step adds and for what the
+# counts below miss.
 _STREAM_VMEM_BUDGET = 13 * 1024 * 1024
 
 
-def _packed_stream_fits(t, t_k, hd, esize, num_heads):
-    """VMEM the packed streaming kernels allocate, against the budget.
-    Mosaic double-buffers every operand whose block index changes
-    anywhere in the grid — the full-T q/do (bwd) and K/V (fwd) blocks
-    change with the batch index, so they count twice like the streamed
-    blocks do; only the revisited f32 dq accumulator is held once."""
+def _packed_stream_vmem(t, t_k, hd, esize, num_heads):
+    """(forward, backward) bytes of VMEM the packed streaming kernels
+    allocate for one lane window. Mosaic double-buffers every operand
+    whose block index changes anywhere in the grid — the full-T q/do
+    (bwd) and K/V (fwd) blocks change with the batch and window indices,
+    so they count twice like the streamed blocks do, and so does the
+    revisited dq block. The body's own values are counted as the chip's
+    compiler was found to count them (the least ``vmem_limit_bytes`` a
+    kernel compiles under, bf16, blocks of 512, in MiB, with this count
+    in brackets): backward D=64 T=1024 7.25 (7.5), T=2048 9.375 (9.75),
+    T=3072 11.375 (12.0), T=4096 13.0 (14.25); D=128 T=3072 10.75 (12.0);
+    D=256 T=1024 12.375 (12.75), T=2048 over 16 (17.0); forward D=64
+    T=2048 4.125 (4.75), T=4096 6.5 (7.0), D=256 T=1024 6.0 (6.125). The
+    full width (8 heads of 40, T=1024) reads 6.375 against 12.0 here: too
+    high, on the safe side (T=4096 read with the family forced past the
+    gate). tests/test_tpu_compile.py compiles at these counts."""
     block_q, block_k = _block_sizes(t, t_k)
-    nh_pad = max(num_heads, 8)
+    w = _lane_window(hd, num_heads)
+    d = hd // num_heads
+    rows = max(w // d, 8)
 
     def pad(x, m):
         return ((x + m - 1) // m) * m
 
     t_pad, tk_pad = pad(t, block_q), pad(t_k, block_k)
-    fwd = (4 * tk_pad * hd * esize              # K/V, two buffers each
-           + 4 * block_q * hd * esize           # q/o blocks, two each
-           + 2 * nh_pad * t_pad * 4             # lse out
-           + 2 * 8 * tk_pad * 4)                # key bias
-    bwd = (4 * t_pad * hd * esize               # q/do, two buffers each
-           + t_pad * hd * 4                     # f32 dq accumulator
-           + 8 * block_k * hd * esize           # k/v/dk/dv blocks, two each
-           + 4 * nh_pad * t_pad * 4             # lse/delta
-           + 4 * 8 * tk_pad * 4)                # key bias + its grad
-    return max(fwd, bwd) <= _STREAM_VMEM_BUDGET
+    tile = block_q * block_k * 4                # [block_k, block_q] f32
+    head = max(block_q, block_k) * max(d, 128) * 4   # [block, D] f32
+    fwd = (4 * tk_pad * w * esize               # K/V, two buffers each
+           + 4 * block_q * w * esize            # q/o blocks, two each
+           + 2 * rows * t_pad * 4               # lse out
+           + 2 * 8 * tk_pad * 4                 # key bias
+           + tile + 4 * head)                   # scores; q, k, v, acc
+    bwd = (4 * t_pad * w * esize                # q/do, two buffers each
+           + (t_pad * w * 4 if esize < 4 else 0)   # dq's f32 sum, and
+           + 2 * t_pad * w * esize              # its block, two buffers
+           + 8 * block_k * w * esize            # k/v/dk/dv blocks, two each
+           + 4 * rows * t_pad * 4               # lse/delta
+           + 4 * 8 * tk_pad * 4                 # key bias + its grad
+           + 2 * tile + 9 * head)               # p, ds; q, do, k, v, dk,
+    #                                             dv and the dq update
+    return fwd, bwd
+
+
+def _packed_stream_fits(t, t_k, hd, esize, num_heads):
+    return max(_packed_stream_vmem(t, t_k, hd, esize, num_heads)) \
+        <= _STREAM_VMEM_BUDGET
 
 
 # ---------------------------------------------------------------------------
@@ -1469,14 +1552,26 @@ def kernel_plan(q_shape, k_shape, num_heads, esize, causal=False,
     """The attention dispatch decision as a structured
     ``ops.gates.GateDecision`` (ISSUE 15): ``kernel`` is which path runs
     — ``dense_vmem`` (whole-sequence VMEM-resident, packed layout),
-    ``packed_stream`` (copy-free streaming), ``head_split_stream``
-    (legacy streaming + the [B,T,H,D] relayout copies),
+    ``packed_stream`` (copy-free streaming, a lane window of the packed
+    heads a program), ``head_split_stream`` (streaming one head a
+    program, with the [B,T,H,D] relayout copies round every site),
     ``segmented_stream`` (the head-split kernels on segments of a causal
-    square whose head does not fit them whole), or ``reference`` — and ``reasons`` records every check that demoted the
-    choice. This IS the dispatch logic :func:`flash_attention` runs
-    (single source). ``platform``: what ``gates.platform_reason`` says
-    of where the step runs; the static resource pass evaluates the gate
-    shape-only and leaves it ``None``.
+    square whose head does not fit them whole), or ``reference`` — and
+    ``reasons`` records every check that demoted the choice. This IS the
+    dispatch logic :func:`flash_attention` runs (single source).
+
+    Who takes which length (self-attention, bf16, the chip's blocks of
+    512; float32 halves the streaming lengths): ``dense_vmem`` to T=512
+    (keys to 1024); ``packed_stream`` from there to T=3072 at D=64 and
+    D=128 and to T=1024 at D=256, as far as one lane window's full-T
+    q/do/dq fit the VMEM budget; beyond that ``head_split_stream`` (its
+    own count holds to T=5632 at D=64), and for a causal square without
+    bias or dropout that it cannot hold either, ``segmented_stream``
+    (D=128 from T=4096, D=256 from T=2048).
+
+    ``platform``: what ``gates.platform_reason`` says of where the step
+    runs; the static resource pass evaluates the gate shape-only and
+    leaves it ``None``.
 
     ``bias_kind``: None | 'key' (padding-mask form) | 'rich' (anything
     else — reference path only)."""
@@ -1517,10 +1612,13 @@ def kernel_plan(q_shape, k_shape, num_heads, esize, causal=False,
     if _packed_stream_fits(t, t_k, hd, esize, num_heads):
         return GateDecision(True, "packed_stream")
     reasons.append(GateReason(
-        "vmem", "packed streaming working set for T=%d Tk=%d H*D=%d "
-        "exceeds the %.0f MB VMEM budget — falls back to the "
-        "head-split path (+[B,T,H,D] relayout copies around every "
-        "attention site)" % (t, t_k, hd, _STREAM_VMEM_BUDGET / 2**20)))
+        "vmem", "packed streaming working set for T=%d Tk=%d, one %d-lane "
+        "window of H*D=%d (%.1f MB), exceeds the %.0f MB VMEM budget — "
+        "falls back to the head-split path (+[B,T,H,D] relayout copies "
+        "around every attention site)"
+        % (t, t_k, _lane_window(hd, num_heads), hd,
+           max(_packed_stream_vmem(t, t_k, hd, esize, num_heads)) / 2**20,
+           _STREAM_VMEM_BUDGET / 2**20)))
     if not _head_split_fits(t, t_k, d, esize) and causal \
             and bias_kind is None and dropout_rate == 0.0:
         segmented = _segment_plan(t, d, esize)
@@ -1608,9 +1706,9 @@ def flash_attention(q, k, v, num_heads, bias=None, causal=False,
                                 scale, float(dropout_rate))
 
     if plan.kernel == "packed_stream":
-        # copy-free streaming path: the packed layout goes straight into
-        # the kernels — no [B,T,H,D] head-split relayouts around the
-        # custom calls
+        # copy-free streaming path: the kernels' BlockSpecs take lane
+        # windows of the packed layout as it is — no [B,T,H,D] head-split
+        # relayouts around the custom calls
         return _packed_stream_attention(q, k, v, key_bias, seed, num_heads,
                                         causal, scale, float(dropout_rate))
 

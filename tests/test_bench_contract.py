@@ -312,9 +312,13 @@ def test_seq2048_record_carries_stream_config(monkeypatch):
     cfg = rec["config"]
     assert cfg["flash_block"] == 512
     assert cfg["packed_stream"] is True  # bf16 seq-1024 fits the gate
-    # seq-2048 does not: its packed backward is past the chip's VMEM
+    # and so does seq-2048 since the kernels take one lane window of the
+    # packed heads a program (ISSUE 29); seq-4096 is past the chip's VMEM
     rec = bench._bench_static("transformer", on_tpu=False,
                               seq_override=2048)
+    assert rec["config"]["packed_stream"] is True
+    rec = bench._bench_static("transformer", on_tpu=False,
+                              seq_override=4096)
     assert rec["config"]["packed_stream"] is False
 
 

@@ -431,9 +431,16 @@ def test_flash_kernel_plan_gates():
     plan = fa.kernel_plan((32, 1024, 512), (32, 1024, 512), 8, 2,
                           causal=False, dropout_rate=0.1)
     assert plan.kernel == "packed_stream" and plan.admitted
-    # the seq-2048 bench shape: its packed backward is past the chip's
-    # scoped VMEM, so it streams head-split and says why
+    # the seq-2048 bench shape: one 128-lane window of the packed head
+    # dimension (two heads of 64) fits the chip's scoped VMEM where all
+    # eight heads did not, so it stays copy-free too (ISSUE 29)
     plan = fa.kernel_plan((16, 2048, 512), (16, 2048, 512), 8, 2,
+                          causal=False, dropout_rate=0.1)
+    assert plan.kernel == "packed_stream" and plan.admitted
+    assert not plan.reasons
+    # past 3072 tokens a window's full-T q/do/dq do not fit: head-split
+    # streaming + its relayout copies, and the plan says why
+    plan = fa.kernel_plan((8, 4096, 512), (8, 4096, 512), 8, 2,
                           causal=False, dropout_rate=0.1)
     assert plan.kernel == "head_split_stream" and plan.admitted
     assert plan.blocked_only_by("vmem")

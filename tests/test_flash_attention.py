@@ -154,22 +154,34 @@ def test_flash_2d_and_broadcast_bias_fallback(rng):
                                rtol=2e-4, atol=2e-4)
 
 
+# (heads, head dim) of the packed streaming kernels' lane windows
+# (``fa._lane_window``): a program holds lcm(D, 128) lanes where that
+# divides H*D, else all of H*D
+_WINDOW_GEOMETRIES = [
+    pytest.param(2, 8, id="full-width-h2d8"),        # 128 does not divide 16
+    pytest.param(4, 64, id="two-windows-of-two-heads"),
+    pytest.param(2, 128, id="one-head-a-window"),
+]
+
+
 @pytest.mark.parametrize("family", ["head_split_stream", "packed_stream"],
                          ids=["head-split", "packed"])
+@pytest.mark.parametrize("h,d", _WINDOW_GEOMETRIES)
 @pytest.mark.parametrize("causal,t,tk", [
     (False, 136, 104),   # unaligned kv tail, multi-block both axes
     (True, 136, 136),    # causal diagonal + unaligned tails
     (False, 72, 136),    # q shorter than kv, kv tail masked
 ])
-def test_flash_multiblock_unaligned_tails(rng, causal, t, tk, family):
+def test_flash_multiblock_unaligned_tails(rng, causal, t, tk, h, d, family):
     """Sequences spanning several blocks with t % block != 0 exercise the
     mask-specialized loop splits (unmasked interior / masked diagonal +
     padded tails) in BOTH streaming paths — the packed [B,T,H*D]
-    heads-in-kernel one and the legacy head-split one — fwd and bwd, with
-    a key bias. The family is named (``plan=``): at these
-    (interpret-tractable) lengths the gate would pick the dense path."""
+    heads-in-kernel one, at each geometry of its lane windows, and the
+    head-split one — fwd and bwd, with a key bias. The family is named
+    (``plan=``): at these (interpret-tractable) lengths the gate would
+    pick the dense path."""
     plan = GateDecision(True, family)
-    b, h, d = 1, 2, 8
+    b = 1
     q, k, v = _mk(rng, b, h, t, tk, d)
     lengths = np.array([tk - 5])
     bias4 = np.where(np.arange(tk)[None] < lengths[:, None], 0.0, -1e9)
@@ -197,44 +209,83 @@ def test_flash_multiblock_unaligned_tails(rng, causal, t, tk, family):
                                    err_msg="d%s" % name)
 
 
-def test_packed_stream_matches_head_split(rng):
+@pytest.mark.parametrize("with_bias", [True, False],
+                         ids=["key-bias", "no-bias"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("h,d,window", [
+    (2, 8, 16),      # 128 does not divide H*D = 16: the full width
+    (8, 64, 128),    # transformer-base's heads: four windows of two heads
+    (2, 128, 128),   # one head a window
+    (3, 40, 120),    # lcm(40, 128) = 640 does not divide 120: the full width
+], ids=["h2d8-full-width", "h8d64-four-windows", "h2d128-head-a-window",
+        "h3d40-full-width"])
+def test_packed_stream_matches_head_split(rng, h, d, window, causal,
+                                          with_bias):
     """The packed streaming kernels agree with the head-split streaming
     kernels (not just the reference) fwd+bwd at a multi-head,
-    multi-block, biased shape — the copy-free path is a pure layout
-    change."""
-    b, h, t, d = 2, 2, 72, 8
+    multi-block shape whose T is no multiple of the block — the copy-free
+    path is a pure layout change, whatever its lane window. The key bias
+    is differentiated too: every head adds to its gradient, which the
+    packed backward puts out one partial a window and sums outside."""
+    assert fa._lane_window(h * d, h) == window
+    b, t = 2, 72
     q, k, v = _mk(rng, b, h, t, t, d)
-    lengths = np.array([t - 7, t])
-    bias4 = np.where(np.arange(t)[None] < lengths[:, None], 0.0, -1e9)
-    bias4 = jnp.asarray(bias4[:, None, None, :].astype("f4"))
+    bias = None
+    if with_bias:
+        lengths = np.array([t - 7, t])
+        bias = np.where(np.arange(t)[None] < lengths[:, None], 0.0, -1e9)
+        bias = jnp.asarray(
+            bias.astype("f4") + rng.normal(0, 0.5, (b, t)).astype("f4"))
 
-    def loss(q, k, v, family):
-        o = fa.flash_attention(q, k, v, num_heads=h, bias=bias4,
-                               causal=True, plan=GateDecision(True, family))
+    def loss(q, k, v, bias, family):
+        o = fa.flash_attention(q, k, v, num_heads=h, bias=bias,
+                               causal=causal,
+                               plan=GateDecision(True, family))
         return jnp.sum(o * jnp.sin(o)), o
 
+    wrt = (0, 1, 2, 3) if with_bias else (0, 1, 2)
     outs = {}
-    for packed in (False, True):
-        (l, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
-            q, k, v, "packed_stream" if packed else "head_split_stream")
-        outs[packed] = (np.asarray(o), [np.asarray(x) for x in g])
-    np.testing.assert_allclose(outs[True][0], outs[False][0],
+    for family in ("packed_stream", "head_split_stream"):
+        (_, o), g = jax.value_and_grad(loss, argnums=wrt, has_aux=True)(
+            q, k, v, bias, family)
+        outs[family] = (np.asarray(o), [np.asarray(x) for x in g])
+    np.testing.assert_allclose(outs["packed_stream"][0],
+                               outs["head_split_stream"][0],
                                rtol=2e-4, atol=2e-4)
-    for a, b_, name in zip(outs[True][1], outs[False][1], "qkv"):
+    for a, b_, name in zip(outs["packed_stream"][1],
+                           outs["head_split_stream"][1],
+                           ("q", "k", "v", "bias")):
         np.testing.assert_allclose(a, b_, rtol=2e-3, atol=2e-4,
                                    err_msg="d%s" % name)
+    if with_bias:
+        # and against the plain reference: column sums of dS over all heads
+        def loss_ref(bias):
+            o = _ref(q, k, v, h, bias=bias[:, None, None, :], causal=causal)
+            return jnp.sum(o * jnp.sin(o))
+
+        np.testing.assert_allclose(
+            outs["packed_stream"][1][3], np.asarray(jax.grad(loss_ref)(bias)),
+            rtol=5e-3, atol=5e-4)
 
 
 def test_packed_stream_vmem_gate(monkeypatch):
-    """The packed-stream gate declines shapes whose full-T packed refs
-    exceed the VMEM budget (those keep the head-split path). At the
-    chip's 512-blocks the seq-2048 transformer-base bench geometry is one
-    of them: the chip's compiler refused it (tests/test_tpu_compile.py)."""
+    """The packed-stream gate counts ONE lane window of the packed head
+    dimension (ISSUE 29), so the seq-2048 transformer-base bench geometry
+    fits at the chip's 512-blocks (the chip's compiler agrees:
+    tests/test_tpu_compile.py). It declines what one window's full-T refs
+    do not fit: a wide head at 8192 (``qwen3next.train.s8192``'s, which
+    stays segmented) and 16k tokens at any head."""
     monkeypatch.setattr(fa, "_INTERPRET", False)  # the chip's block sizes
     assert fa._packed_stream_fits(1024, 1024, 512, 2, 8)
-    assert not fa._packed_stream_fits(2048, 2048, 512, 2, 8)  # bench config
+    assert fa._packed_stream_fits(2048, 2048, 512, 2, 8)  # bench config
+    assert fa._packed_stream_fits(2048, 2048, 4096, 2, 32)  # 16 windows
+    assert not fa._packed_stream_fits(8192, 8192, 4096, 2, 16)
     assert not fa._packed_stream_fits(16384, 16384, 512, 2, 8)
-    assert not fa._packed_stream_fits(2048, 2048, 4096, 2, 32)
+    # the full-width case (128 does not divide 8 heads of 40) is as wide as
+    # before, and stops where it did
+    assert fa._lane_window(320, 8) == 320
+    assert fa._packed_stream_fits(1024, 1024, 320, 2, 8)
+    assert not fa._packed_stream_fits(4096, 4096, 320, 2, 8)
 
 
 def test_flash_causal_multiblock_grads(rng):

@@ -477,13 +477,17 @@ def test_kernel_plan_segments_a_wide_head_at_8192_and_leaves_the_rest():
     assert plan.kernel == "segmented_stream" and plan.admitted
     assert plan.blocked_only_by("vmem")
     assert fa._segment_plan(8192, 256, 2) == (2048, 256)
-    # what the benchmark's transformer cells take is what it was
+    # what the benchmark's transformer cells take: s256 is what it was;
+    # PR 29 moved s2048 from head_split_stream to packed_stream (a lane
+    # window of the packed head dimension fits where its whole width did
+    # not), and that window at D=256, T=8192 is far from fitting
+    assert not fa._packed_stream_fits(8192, 8192, 4096, 2, 16)
     assert fa.kernel_plan((128, 256, 512), (128, 256, 512), 8, 2,
                           causal=True).kernel == "dense_vmem"
     assert fa.kernel_plan((16, 2048, 512), (16, 2048, 512), 8, 2,
-                          causal=True).kernel == "head_split_stream"
+                          causal=True).kernel == "packed_stream"
     assert fa.kernel_plan((16, 2048, 512), (16, 2048, 512), 8, 2,
-                          causal=False).kernel == "head_split_stream"
+                          causal=False).kernel == "packed_stream"
 
 
 def test_gated_delta_gate_admits_the_published_shape_and_says_why_not():

@@ -15,6 +15,7 @@ Describing the topology takes libtpu's process lock: one such process at a
 time.
 """
 
+import contextlib
 import os
 import re
 
@@ -110,10 +111,13 @@ _FLASH_CASES = [
     ("transformer_b128_t256_dec", (128, 256, 512, 8), True, False,
      "dense_vmem"),
     ("stream_b32_t1024", (32, 1024, 512, 8), False, True, "packed_stream"),
-    ("seq2048_b16_enc", (16, 2048, 512, 8), False, True,
-     "head_split_stream"),
-    ("seq2048_b16_dec", (16, 2048, 512, 8), True, False,
-     "head_split_stream"),
+    # ISSUE 29: a 128-lane window of the packed heads fits at T=2048 ...
+    ("seq2048_b16_enc", (16, 2048, 512, 8), False, True, "packed_stream"),
+    ("seq2048_b16_dec", (16, 2048, 512, 8), True, False, "packed_stream"),
+    # ... one head of 128 a window as well; past 3072 the head-split
+    # kernels and their relayout copies take over
+    ("seq3072_b4_d128", (4, 3072, 1024, 8), True, False, "packed_stream"),
+    ("seq4096_b8_dec", (8, 4096, 512, 8), True, False, "head_split_stream"),
 ]
 
 
@@ -208,12 +212,102 @@ def test_gated_delta_core_and_routed_experts_fit_at_published_widths(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
-def test_packed_stream_gate_counts_what_mosaic_allocates():
-    """The packed backward at the seq-2048 bench shape is what the chip's
-    compiler refused (16.66M of 16M scoped VMEM alone, 19.16M inside the
-    step): the gate refuses it too, and admits T=1024 (compiled above)."""
-    assert not fa._packed_stream_fits(2048, 2048, 512, 2, 8)
-    assert fa._packed_stream_fits(1024, 1024, 512, 2, 8)
+@contextlib.contextmanager
+def _vmem_limits(limits):
+    """Inside the block every Pallas call named in ``limits`` ({kernel
+    name: bytes}) is given that ``vmem_limit_bytes`` in place of the
+    compiler's 16 MiB of scoped VMEM."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if not limits:
+        yield
+        return
+    pallas_call = pl.pallas_call
+
+    def limited(kernel, **kwargs):
+        limit = limits.get(kwargs.get("name"))
+        if limit is not None:
+            kwargs["compiler_params"] = pltpu.CompilerParams(
+                vmem_limit_bytes=int(limit))
+        return pallas_call(kernel, **kwargs)
+
+    jax.clear_caches()  # a body traced before carries no limit
+    pl.pallas_call = limited
+    try:
+        yield
+    finally:
+        pl.pallas_call = pallas_call
+        jax.clear_caches()
+
+
+def _attention_grad(b, t, hd, heads, causal=True, with_bias=False,
+                    dtype=BF16):
+    """(d(loss)/d(q, k, v) of one attention site, its abstract
+    arguments)."""
+    def loss(q, k, v, g, bias):
+        out = fa.flash_attention(q, k, v, heads, bias=bias, causal=causal)
+        return jnp.sum(out.astype(F32) * g.astype(F32))
+
+    x = sds((b, t, hd), dtype)
+    return jax.grad(loss, argnums=(0, 1, 2)), (
+        x, x, x, x, sds((b, t), F32) if with_bias else None)
+
+
+def _least_vmem(chip, name, shape, causal, with_bias, step=1 << 17):
+    """The compiler's own scoped-VMEM count for the kernel ``name`` of one
+    attention site: the least ``vmem_limit_bytes``, to ``step`` bytes,
+    under which the site compiles."""
+    refused, fits = 0, 16 << 20
+    while fits - refused > step:
+        mid = (refused + fits) // 2 // step * step
+        try:
+            fn, avals = _attention_grad(*shape, causal, with_bias)
+            with _vmem_limits({name: mid}):
+                _compile(chip, fn, *avals)
+            fits = mid
+        except Exception as e:  # noqa: BLE001 — jaxlib's own error type
+            assert "vmem" in str(e), e
+            refused = mid
+    return fits
+
+
+_GATE_COUNT_CASES = [
+    # id, (B, T, H*D, heads), causal, key bias, dtype
+    ("seq2048_d64_bias", (2, 2048, 512, 8), False, True, BF16),
+    ("seq2048_d64_causal", (2, 2048, 512, 8), True, False, BF16),
+    ("seq3072_d128_causal", (2, 3072, 1024, 8), True, False, BF16),
+    ("seq1024_d256_causal", (2, 1024, 2048, 8), True, False, BF16),
+    ("seq1024_8x40_full_width", (2, 1024, 320, 8), False, True, BF16),
+    ("seq2048_d64_f32", (2, 2048, 512, 8), False, True, F32),
+]
+
+
+@pytest.mark.parametrize("shape,causal,with_bias,dtype",
+                         [c[1:] for c in _GATE_COUNT_CASES],
+                         ids=[c[0] for c in _GATE_COUNT_CASES])
+def test_packed_stream_gate_counts_what_mosaic_allocates(
+        chip, shape, causal, with_bias, dtype):
+    """The packed kernels compile when each is given no more scoped VMEM
+    than ``_packed_stream_vmem`` counts for it: the gate's count is at
+    least the chip's compiler's own, at every lane-window geometry (two
+    heads of 64, one head of 128 or 256, the full width) and close to the
+    budget where a shape is admitted close to it. What the gate admits
+    and refuses at the benchmark's shapes: the seq-2048 transformer-base
+    site fits (ISSUE 29; 9.375M by the compiler, 9.75M by the gate),
+    ``qwen3next.train.s8192``'s wide head and 16k tokens do not."""
+    b, t, hd, heads = shape
+    esize = jnp.dtype(dtype).itemsize
+    assert fa._packed_stream_fits(t, t, hd, esize, heads)
+    assert fa._packed_stream_fits(2048, 2048, 512, 2, 8)
+    assert not fa._packed_stream_fits(8192, 8192, 4096, 2, 16)
+    assert not fa._packed_stream_fits(16384, 16384, 512, 2, 8)
+    fwd, bwd = fa._packed_stream_vmem(t, t, hd, esize, heads)
+    assert max(fwd, bwd) <= fa._STREAM_VMEM_BUDGET
+    fn, avals = _attention_grad(*shape, causal, with_bias, dtype)
+    with _vmem_limits({"packed_stream.fwd": fwd, "packed_stream.bwd": bwd}):
+        compiled = _compile(chip, fn, *avals)
+    _assert_named(compiled, {"packed_stream.fwd", "packed_stream.bwd"})
 
 
 # ---------------------------------------------------------------------------
@@ -315,15 +409,6 @@ def test_scatter_gate_bounds_prefetched_ids(n):
 # names: what a trace on the chip can tell a kernel by (ISSUE 24)
 # ---------------------------------------------------------------------------
 
-def _attention_grad(b, t, hd, heads):
-    def loss(q, k, v, g):
-        out = fa.flash_attention(q, k, v, heads, causal=True)
-        return jnp.sum(out.astype(F32) * g.astype(F32))
-
-    x = sds((b, t, hd), BF16)
-    return jax.grad(loss, argnums=(0, 1, 2)), (x, x, x, x)
-
-
 def _conv_infer():
     def infer(x, w, gamma, beta, mean, var):
         return fused_conv.fused_conv_bn_act(
@@ -342,7 +427,7 @@ _NAME_CASES = [
      {"dense_vmem.fwd", "dense_vmem.bwd"}),
     ("packed_stream", lambda: _attention_grad(2, 1024, 256, 2),
      {"packed_stream.fwd", "packed_stream.bwd"}),
-    ("head_split_stream", lambda: _attention_grad(1, 2048, 512, 8),
+    ("head_split_stream", lambda: _attention_grad(1, 4096, 512, 8),
      {"head_split_stream.fwd", "head_split_stream.bwd"}),
     ("fused_conv_infer", _conv_infer, {"fused_conv.infer"}),
 ]
@@ -449,9 +534,9 @@ _STEP_CASES = [
     # the [100000, 32] fused table is over the scatter kernel's VMEM
     # budget and its 851,968 ids over the SMEM bound: XLA scatter
     ("deepfm_b32768", "deepfm", None, {}, None),
+    # ISSUE 29: every site copy-free, a 128-lane window a program
     ("transformer_b16_s2048", "transformer", 2048,
-     {"head_split_stream.fwd": 18, "head_split_stream.bwd": 18},
-     "head_split_stream[vmem]"),
+     {"packed_stream.fwd": 18, "packed_stream.bwd": 18}, "packed_stream"),
 ]
 
 
@@ -466,7 +551,18 @@ def test_whole_train_step_compiles(chip, model, seq, kernels, attn_plan):
     avals, program, loss, persist = _abstract_step(
         lambda: bench._build(model, True, seq)[:2])
     step = build_step_fn(program, (loss,), persist)
-    compiled = _compile(chip, step, *avals, donate_argnums=(0,))
+    limits = {}
+    if attn_plan == "packed_stream":
+        # a kernel's need depends on the program round it: inside this step
+        # the packed kernels get by on 1 MiB over what the gate counts for
+        # them (found: backward 10.25M against 9.375M alone, forward 5.0M
+        # against 4.25M), well inside the 3 MiB the budget leaves free
+        fwd, bwd = fa._packed_stream_vmem(seq, seq, 512, 2, 8)
+        limits = {"packed_stream.fwd": fwd + (1 << 20),
+                  "packed_stream.bwd": bwd + (1 << 20)}
+        assert max(limits.values()) <= fa._STREAM_VMEM_BUDGET
+    with _vmem_limits(limits):
+        compiled = _compile(chip, step, *avals, donate_argnums=(0,))
     mem = compiled.memory_analysis()
     need = mem.temp_size_in_bytes + mem.argument_size_in_bytes
     print("%s seq=%s: %d tpu_custom_call, %.2f GB temporaries + %.2f GB "
@@ -481,6 +577,18 @@ def test_whole_train_step_compiles(chip, model, seq, kernels, attn_plan):
 
         plans = kernel_plans(program)["flash_attention"]
         assert set(plans) == {attn_plan}, plans
+    if limits:
+        # the record: the compiler's count for the backward of one site
+        # compiled alone, beside the gate's own and the budget
+        alone = max(_least_vmem(chip, "packed_stream.bwd",
+                                (2, seq, 512, 8), causal, not causal)
+                    for causal in (False, True))
+        print("packed_stream.bwd at T=%d: %.3f MiB of scoped VMEM by the "
+              "compiler (a site alone), %.3f MiB by the gate, budget %.0f "
+              "MiB; the step compiles with 1 MiB over the gate's count as "
+              "the limit" % (seq, alone / 2**20, bwd / 2**20,
+                             fa._STREAM_VMEM_BUDGET / 2**20))
+        assert alone <= bwd
 
 
 @pytest.mark.slow
