@@ -27,6 +27,8 @@ in-repo reference:
               < 1e-1 (elements a rounding error from the ReLU's zero flip)
   fused CE    |loss - ref| < 5e-2 (losses ~10), grads relative < 5e-2
   scatter     |out - ref| < 1e-4 (f32 adds in another order)
+  state-space scan, routed ReLU^2 experts
+              |a - ref|_2 / |ref|_2 < 3e-2, output and every gradient
   dropout     keep rate within 5 sigma; backward's dV equals the one
               predicted from the forward's OBSERVED mask (rate 0.5 and a
               power-of-two T make every term exact) to 1e-4
@@ -96,6 +98,12 @@ def sizes(rehearse):
             ],
             "ce": dict(t=65536, d=512, v=30000, ref_chunk=8192),
             "scatter": dict(k=32, v=None, n=None),  # None: gate's bounds
+            # nemotron3super.train.s8192's shapes: 16 Mamba-2 heads of 64
+            # in one group of state 128; 8 of 512 experts of 2688 in a
+            # latent of 1024, 22 picks, the router at the model's 4096
+            "ssd": dict(t=8192, heads=16, p=64, groups=1, n=128, chunk=128),
+            "experts": dict(t=8192, d_model=4096, latent=1024, f=2688,
+                            experts=512, held=8, top_k=22, scale=5.0),
             # bench.py's on-TPU widths and batches. Depth is cut to 2
             # layers where a layer repeats (BERT 12, seq-2048 6+6): the
             # host that compiles for the chip is shared and slow, the
@@ -134,6 +142,9 @@ def sizes(rehearse):
         ],
         "ce": dict(t=256, d=32, v=300, ref_chunk=64),
         "scatter": dict(k=16, v=600, n=2048),
+        "ssd": dict(t=70, heads=4, p=8, groups=2, n=16, chunk=16),
+        "experts": dict(t=96, d_model=32, latent=16, f=24, experts=16,
+                        held=4, top_k=5, scale=2.5),
         "configs": dict(
             bert=dict(kw=dict(vocab_size=1000, seq_len=32, d_model=128,
                               d_ff=256, n_layer=2), batch=4),
@@ -583,6 +594,113 @@ def _scatter_case(ctx, k, v, n):
             "ids": n, "err": err}
 
 
+def _ssd_case(ctx, t, heads, p, groups, n, chunk):
+    """Op ``mamba2_ssd``'s chunked form, bfloat16 operands, against the
+    token-by-token recurrence in float32."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import mamba2
+
+    rng = np.random.RandomState(ctx["seed"])
+
+    def rand(*shape):
+        return jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+
+    x, bm, cm, raw = (rand(1, t, heads * p), rand(1, t, groups * n),
+                      rand(1, t, groups * n), rand(1, t, heads))
+    a_log, dt_bias, d = (jnp.asarray(rng.randn(heads), jnp.float32)
+                         for _ in range(3))
+    g = jnp.asarray(rng.randn(1, t, heads * p), jnp.float32)
+
+    # every array is an argument: one closed over becomes a constant of
+    # the executable, which XLA then folds on the host
+    def ours(x, bm, cm, raw, a_log, dt_bias, d, g):
+        out = mamba2.mamba2_ssd(x, bm, cm, raw, a_log, dt_bias, d, heads,
+                                groups, chunk, jnp.bfloat16)
+        return jnp.sum(out * g), out
+
+    def plain(x, bm, cm, raw, a_log, dt_bias, d, g):
+        f32 = jnp.float32
+        out = mamba2.recurrent_mamba2(
+            x.reshape(1, t, heads, p),
+            jax.nn.softplus(raw.astype(f32) + dt_bias), -jnp.exp(a_log),
+            bm.reshape(1, t, groups, n), cm.reshape(1, t, groups, n), d)
+        out = out.reshape(1, t, heads * p)
+        return jnp.sum(out * g), out
+
+    args = (x, bm, cm, raw, a_log, dt_bias, d, g)
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        ours, argnums=(0, 1, 2, 3), has_aux=True))(*args)
+    (_, want), wanted = jax.jit(jax.value_and_grad(
+        plain, argnums=(0, 1, 2, 3), has_aux=True))(*args)
+    fwd = l2_err(out, want)
+    bwd = max(l2_err(a, w) for a, w in zip(grads, wanted))
+    check(fwd < 3e-2, "mamba2_ssd forward error %g" % fwd)
+    check(bwd < 3e-2, "mamba2_ssd backward error %g" % bwd)
+    return {"case": "mamba2_ssd", "plan": "chunked_jnp", "tokens": t,
+            "fwd_err": fwd, "bwd_err": bwd}
+
+
+def _experts_case(ctx, t, d_model, latent, f, experts, held, top_k, scale):
+    """``parallel/moe.py``'s binned sigmoid-routed ReLU-squared experts in
+    a latent against every held expert on every token under a one-hot
+    weight, both from the same routing."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.parallel import moe
+
+    rng = np.random.RandomState(ctx["seed"])
+    bf16 = jnp.bfloat16
+    x = jnp.asarray(rng.randn(t, latent), bf16)
+    router_x = jnp.asarray(rng.randn(t, d_model), bf16)
+    router = jnp.asarray(rng.randn(d_model, experts) / d_model ** 0.5,
+                         jnp.float32)
+    up = jnp.asarray(rng.randn(held, f, latent) / latent ** 0.5, bf16)
+    down = jnp.asarray(rng.randn(held, latent, f) / f ** 0.5, bf16)
+    bias = jnp.zeros((experts,), jnp.float32)
+    g = jnp.asarray(rng.randn(t, latent), jnp.float32)
+
+    def ours(x, up, down, router_x, router, bias, g):
+        out, counts = moe.routed_experts(
+            x, router, None, up, down, top_k, 0, form="relu2",
+            score="sigmoid", bias=bias, scale=scale, router_x=router_x)
+        return jnp.sum(out * g), (out, counts)
+
+    def plain(x, up, down, router_x, router, bias, g):
+        weights, picks = moe.route_topk(router_x, router, top_k, True,
+                                        "sigmoid", bias, scale)
+
+        def expert(out, per):
+            e, up_e, down_e = per
+            w = jnp.sum(jnp.where(picks == e, weights, 0.0), -1)
+            h = jnp.square(jax.nn.relu(jnp.matmul(
+                x, up_e.T, preferred_element_type=jnp.float32)))
+            y = jnp.matmul(h.astype(bf16), down_e.T,
+                           preferred_element_type=jnp.float32)
+            return out + w[:, None] * y, None
+
+        out, _ = jax.lax.scan(expert, jnp.zeros((t, latent), jnp.float32),
+                              (jnp.arange(held), up, down))
+        return jnp.sum(out * g), out
+
+    args = (x, up, down, router_x, router, bias, g)
+    (_, (out, counts)), grads = jax.jit(jax.value_and_grad(
+        ours, argnums=(0, 1, 2), has_aux=True))(*args)
+    (_, want), wanted = jax.jit(jax.value_and_grad(
+        plain, argnums=(0, 1, 2), has_aux=True))(*args)
+    fwd = l2_err(out, want)
+    bwd = max(l2_err(a, w) for a, w in zip(grads, wanted))
+    check(int(counts.sum()) > 0, "no pick fell on a held expert")
+    check(fwd < 3e-2, "routed relu2 experts forward error %g" % fwd)
+    check(bwd < 3e-2, "routed relu2 experts backward error %g" % bwd)
+    return {"case": "routed_experts_relu2_sigmoid", "plan": "slab_and_blocks",
+            "load": [int(c) for c in counts], "fwd_err": fwd, "bwd_err": bwd}
+
+
 def phase_kernels(ctx):
     cfg = ctx["sizes"]
     cases = []
@@ -598,6 +716,10 @@ def phase_kernels(ctx):
     cases.append(_ce_case(ctx, **cfg["ce"]))
     log("kernels: %s" % cases[-1])
     cases.append(_scatter_case(ctx, **cfg["scatter"]))
+    log("kernels: %s" % cases[-1])
+    cases.append(_ssd_case(ctx, **cfg["ssd"]))
+    log("kernels: %s" % cases[-1])
+    cases.append(_experts_case(ctx, **cfg["experts"]))
     log("kernels: %s" % cases[-1])
     return {"cases": cases}
 
@@ -901,6 +1023,9 @@ def main():
     ap.add_argument("--seed", type=int, default=1,
                     help="weights and data (> 0: Program.random_seed 0 "
                          "means nondeterministic)")
+    ap.add_argument("--phase", action="append", metavar="NAME",
+                    help="run only this phase (may be given again); "
+                         "default: all of them")
     args = ap.parse_args()
     if args.seed <= 0:
         ap.error("--seed must be positive")
@@ -957,6 +1082,11 @@ def main():
     phases = ([("multichip", phase_multichip)] if args.multichip else
               [("train", phase_train), ("kernels", phase_kernels),
                ("configs", phase_configs), ("serve", phase_serve)])
+    if args.phase:
+        unknown = set(args.phase) - {name for name, _ in phases}
+        if unknown:
+            ap.error("no phase %s" % ", ".join(sorted(unknown)))
+        phases = [(name, fn) for name, fn in phases if name in args.phase]
     ok = True
     for name, fn in phases:
         log("phase %s" % name)

@@ -81,6 +81,33 @@ def _gated_delta_rule(env, op):
     put(env, op.output("Out"), out.astype(v.dtype))
 
 
+@register("mamba2_ssd")
+def _mamba2_ssd(env, op):
+    """The Mamba-2 state-space core (``ops/mamba2.py``): X [B, T, H*P] and
+    the input and output maps Bm, Cm [B, T, G*N] after the causal
+    convolution, the raw step projection Dt [B, T, H], the heads' ALog,
+    DtBias and D; ``dt = softplus(Dt + DtBias)``, ``A = -exp(ALog)``, a
+    [P, N] state a head in chunks of ``chunk`` tokens, ``+ D x``. Out
+    [B, T, H*P] in X's dtype. One form computes it everywhere, the chunked
+    ``jnp`` form, and the site's decision says so."""
+    from ...ops import mamba2
+    from ...ops.gates import note
+    from ..op_registry import amp_enabled
+
+    x = get(env, op.input("X"))
+    heads, chunk = int(op.attr("num_heads")), int(op.attr("chunk", 128))
+    plan = mamba2.plan_for(x, heads, chunk)
+    op.attrs["_kernel_choice"] = plan.to_dict()
+    note("mamba2_ssd", plan)
+    out = mamba2.mamba2_ssd(
+        x, get(env, op.input("Bm")), get(env, op.input("Cm")),
+        get(env, op.input("Dt")), get(env, op.input("ALog")),
+        get(env, op.input("DtBias")), get(env, op.input("D")), heads,
+        int(op.attr("num_groups")), chunk,
+        mxu_dtype=jnp.bfloat16 if amp_enabled() else None)
+    put(env, op.output("Out"), out.astype(x.dtype))
+
+
 @register("kv_cache_write")
 def _kv_cache_write(env, op):
     """Per-row cache update: Cache [B, C, ...], X [B, ...], Pos [B] ->

@@ -490,7 +490,7 @@ register_cost("ftrl")(_opt_cost(6))
 
 
 # ---------------------------------------------------------------------------
-# the hybrid linear-attention / routed-experts block (models/qwen3_next.py)
+# the hybrid blocks (models/qwen3_next.py, models/nemotron_h.py)
 # ---------------------------------------------------------------------------
 
 @register_cost("rms_norm")
@@ -555,25 +555,48 @@ def _gated_delta_rule_cost(ctx, op):
             bwd_flops=2 * f, bwd_hbm_bytes=2 * stream + states)
 
 
+@register_cost("mamba2_ssd")
+def _mamba2_ssd_cost(ctx, op):
+    xs, bs = ctx.shape(op.input("X")), ctx.shape(op.input("Bm"))
+    if xs is None or bs is None or len(xs) != 3 or -1 in xs:
+        ctx.add(op, unresolved=True)
+        return
+    b, t, hp = xs
+    heads, groups = int(op.attr("num_heads")), int(op.attr("num_groups"))
+    p, n = hp // heads, bs[-1] // groups
+    chunk = int(op.attr("chunk", 128))
+    # a token: C B^T a group (C * N), the masked product with dt x a head
+    # (C * P), the chunk's addition to the state and the read of the state
+    # that entered it (2 * P * N a head)
+    f = 2.0 * b * t * (groups * chunk * n + heads * (chunk * p + 2 * p * n))
+    e = ctx.esize(op.input("X"))
+    # x read and y written, B and C read, the step in float32
+    stream = b * t * ((2 * hp + 2 * bs[-1]) * e + heads * 4)
+    ctx.add(op, flops=f, hbm_bytes=stream, bwd_flops=2 * f,
+            bwd_hbm_bytes=2 * stream)
+
+
 @register_cost("routed_experts")
 def _routed_experts_cost(ctx, op):
     xs = ctx.shape(op.input("X"))
-    gs = ctx.shape(op.input("ExpertGate"))
+    us = ctx.shape(op.input("ExpertUp"))
     rs = ctx.shape(op.input("Router"))
-    if xs is None or gs is None or rs is None or -1 in xs:
+    if xs is None or us is None or rs is None or -1 in xs:
         ctx.add(op, unresolved=True)
         return
-    tokens, d = _prod(xs[:-1]), xs[-1]
-    held, f = gs[0], gs[1]
+    tokens, d_model = _prod(xs[:-1]), xs[-1]
+    held, f, d = us                 # d: the width the experts read
+    mats = 2 if op.attr("form", "swiglu") == "relu2" else 3
     experts, top_k = rs[1], int(op.attr("top_k"))
     e = ctx.esize(op.input("X"))
     # the expected share of the picks falls on the held experts
     rows = tokens * top_k * held / float(experts)
-    flops = 2.0 * tokens * d * experts + 2.0 * rows * 3 * d * f
-    nbytes = 3 * held * d * f * e + 2 * tokens * d * e + 2 * rows * d * e
-    ss = ctx.shape(op.input("SharedGate"))
+    flops = 2.0 * tokens * d_model * experts + 2.0 * rows * mats * d * f
+    nbytes = mats * held * d * f * e + 2 * tokens * d * e + 2 * rows * d * e
+    ss = ctx.shape(op.input("SharedUp"))
     if ss is not None:
-        flops += 2.0 * tokens * (3 * d * ss[1] + d)
-        nbytes += 3 * d * ss[1] * e
+        gated = op.input("SharedExpertGate") is not None
+        flops += 2.0 * tokens * (mats * d_model * ss[1] + d_model * gated)
+        nbytes += mats * d_model * ss[1] * e
     ctx.add(op, flops=flops, hbm_bytes=nbytes, bwd_flops=2 * flops,
             bwd_hbm_bytes=2 * nbytes)

@@ -803,22 +803,31 @@ def _pixel_shuffle(env, op):
 @register("rms_norm")
 def _rms_norm(env, op):
     """RMS norm over the trailing ``norm_dim`` elements (the whole last axis,
-    or one head of a packed [.., H*D] axis): ``x * rsqrt(mean(x^2) + eps)``
-    in float32, times ``Scale`` (``1 + Scale`` when ``zero_centered``), times
-    ``silu(Gate)`` when a gate is given (the gated norm on a Gated DeltaNet
-    output). Y is stored in X's dtype."""
+    or one head or group of a packed [.., H*D] axis): ``x * rsqrt(mean(x^2)
+    + eps)`` in float32, times ``Scale`` (``1 + Scale`` when
+    ``zero_centered``; [norm_dim], shared by the slices, or as long as the
+    last axis). With a ``Gate`` the result is multiplied by ``silu(Gate)``
+    (the gated norm on a Gated DeltaNet output), or, ``gate_first``, x is
+    before the norm (the gated norm of a Mamba-2 mixer). Y is stored in X's
+    dtype."""
     x = get(env, op.input("X"))
     scale = get(env, op.input("Scale"))
     gate = get(env, op.input("Gate"))
     eps = op.attr("epsilon", 1e-6)
     dim = int(op.attr("norm_dim", 0)) or x.shape[-1]
-    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, dim))
+    gate_first = gate is not None and op.attr("gate_first", False)
+    xf = x.astype(jnp.float32)
+    if gate_first:
+        xf = xf * jax.nn.silu(gate.astype(jnp.float32))
+    xf = xf.reshape(x.shape[:-1] + (-1, dim))
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     if scale is not None:
         w = scale.astype(jnp.float32)
+        if w.shape[0] != dim:       # a weight a slice, not one shared
+            w = w.reshape(-1, dim)
         y = y * (1.0 + w if op.attr("zero_centered", False) else w)
     y = y.reshape(x.shape)
-    if gate is not None:
+    if gate is not None and not gate_first:
         y = y * jax.nn.silu(gate.astype(jnp.float32))
     put(env, op.output("Y"), y.astype(x.dtype))
 
@@ -851,17 +860,21 @@ def _rotary(env, op):
 
 @register("causal_conv1d")
 def _causal_conv1d(env, op):
-    """Causal depthwise convolution along T without bias: X [B, T, C],
-    Filter [C, K]; ``out[t] = sum_j Filter[:, j] * x[t - (K-1) + j]`` with
-    zeros before the row's start, then SiLU when ``act == 'silu'``. A sum
-    of K shifted products (K is 4), float32 accumulation."""
+    """Causal depthwise convolution along T: X [B, T, C], Filter [C, K],
+    optional Bias [C]; ``out[t] = sum_j Filter[:, j] * x[t - (K-1) + j] +
+    Bias`` with zeros before the row's start, then SiLU when ``act ==
+    'silu'``. A sum of K shifted products (K is 4), float32
+    accumulation."""
     x = get(env, op.input("X"))
     w = get(env, op.input("Filter")).astype(jnp.float32)
+    bias = get(env, op.input("Bias"))
     k = w.shape[1]
     t = x.shape[1]
     xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     out = sum(xp[:, j:j + t].astype(jnp.float32) * w[:, j]
               for j in range(k))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     if op.attr("act", "") == "silu":
         out = jax.nn.silu(out)
     put(env, op.output("Out"), out.astype(x.dtype))
@@ -870,17 +883,26 @@ def _causal_conv1d(env, op):
 @register("routed_experts")
 def _routed_experts(env, op):
     """The MoE block of a layer on a chip that holds a share of the routed
-    experts (``parallel/moe.py``): router over all ``num_experts``, top-k
-    with renormalised weights, the held experts' SwiGLU products in a static
-    pass and binned blocks (no token dropped), plus the shared expert behind
-    its sigmoid gate. ``Load`` [held] int32: the tokens each held expert took
-    in this step, a persistable counter written inside the step."""
+    experts (``parallel/moe.py``): router over all ``num_experts`` (attr
+    ``score``: a ``softmax`` over them or a ``sigmoid`` each, then with
+    input ``RouterBias`` [num_experts] added for the choice alone), top-k
+    with renormalised weights times ``scale``, the held experts' products
+    (attr ``form``: ``swiglu`` with ExpertGate/Up/Down, ``relu2`` with
+    ExpertUp/Down) in a static pass and binned blocks (no token dropped),
+    plus the shared expert of the same form (behind a sigmoid gate where
+    ``SharedExpertGate`` is given). With input ``ExpertX`` the experts read
+    and write that (a latent of X, their own width) while the router and the
+    shared expert read X; the shared expert then goes to output
+    ``SharedOut`` (X's width) and not into ``Out`` (ExpertX's). ``Load``
+    [held] int32: the tokens each held expert took in this step, a
+    persistable counter written inside the step."""
     from ...ops.gates import GateDecision, GateReason, note
     from ...parallel import moe
     from ..op_registry import mxu_cast
 
     x = get(env, op.input("X"))
     router = get(env, op.input("Router"))
+    form = op.attr("form", "swiglu")
     wg, wu, wd = mxu_cast(get(env, op.input("ExpertGate")),
                           get(env, op.input("ExpertUp")),
                           get(env, op.input("ExpertDown")))
@@ -892,18 +914,23 @@ def _routed_experts(env, op):
         GateReason("shape", "%d of %d experts held, %d assignments: a "
                    "static pass of %d rows an expert, beyond it blocks of "
                    "%d rows under a dynamic trip count; no capacity factor"
-                   % (wg.shape[0], router.shape[1], tokens * top_k,
+                   % (wu.shape[0], router.shape[1], tokens * top_k,
                       moe.slab_rows_for(tokens * top_k, router.shape[1],
                                         rows), rows), blocking=False)]))
     xc = mxu_cast(x)
+    latent = get(env, op.input("ExpertX"))
     routed, counts = moe.routed_experts(
-        xc, router, wg, wu, wd, top_k, lo,
-        renormalize=op.attr("norm_topk_prob", True))
+        xc if latent is None else mxu_cast(latent), router, wg, wu, wd,
+        top_k, lo, renormalize=op.attr("norm_topk_prob", True), form=form,
+        score=op.attr("score", "softmax"),
+        bias=get(env, op.input("RouterBias")),
+        scale=float(op.attr("scale", 1.0)),
+        router_x=None if latent is None else xc)
     out = routed
-    sg = get(env, op.input("SharedGate"))
-    if sg is not None:
-        su, sd, gate_w = mxu_cast(get(env, op.input("SharedUp")),
-                                  get(env, op.input("SharedDown")),
+    su = get(env, op.input("SharedUp"))
+    if su is not None:
+        sg = get(env, op.input("SharedGate"))
+        su, sd, gate_w = mxu_cast(su, get(env, op.input("SharedDown")),
                                   get(env, op.input("SharedExpertGate")))
         sg = mxu_cast(sg)
         f32 = jnp.float32
@@ -912,10 +939,16 @@ def _routed_experts(env, op):
             return jnp.matmul(a.astype(w.dtype), w,
                               preferred_element_type=f32)
 
-        h = jax.nn.silu(mm(xc, sg)) * mm(xc, su)
+        if form == "relu2":
+            h = jnp.square(jax.nn.relu(mm(xc, su)))
+        else:
+            h = jax.nn.silu(mm(xc, sg)) * mm(xc, su)
         shared = mm(h, sd)
         if gate_w is not None:
             shared = shared * jax.nn.sigmoid(mm(xc, gate_w))
-        out = out + shared
+        if op.output("SharedOut") is not None:
+            put(env, op.output("SharedOut"), shared.astype(x.dtype))
+        else:
+            out = out + shared
     put(env, op.output("Out"), out.astype(x.dtype))
     put(env, op.output("Load"), counts)

@@ -877,7 +877,7 @@ def _cached_attention_chunk_shape(ctx, op):
 
 
 # ---------------------------------------------------------------------------
-# the hybrid linear-attention / routed-experts block (models/qwen3_next.py)
+# the hybrid blocks (models/qwen3_next.py, models/nemotron_h.py)
 # ---------------------------------------------------------------------------
 
 @register_shape("rms_norm")
@@ -894,7 +894,7 @@ def _rms_norm_shape(ctx, op):
         raise ShapeError("rms_norm norm_dim %d does not divide the last "
                          "axis of %s" % (dim, list(xs)))
     want = dim or last
-    if ss is not None and want != -1 and tuple(ss) != (want,):
+    if ss is not None and want != -1 and tuple(ss) not in ((want,), (last,)):
         raise ShapeError("rms_norm Scale has shape %s, the normalised "
                          "slice has %d elements" % (list(ss), want))
 
@@ -921,6 +921,11 @@ def _causal_conv1d_shape(ctx, op):
             and ws[0] != xs[-1]:
         raise ShapeError("causal_conv1d Filter %s does not match %d "
                          "channels" % (list(ws), xs[-1]))
+    bs = ctx.shape(op.input("Bias"))
+    if xs is not None and bs is not None and xs[-1] != -1 \
+            and tuple(bs) != (xs[-1],):
+        raise ShapeError("causal_conv1d Bias %s does not match %d "
+                         "channels" % (list(bs), xs[-1]))
 
 
 @register_shape("gated_delta_rule")
@@ -945,22 +950,67 @@ def _gated_delta_rule_shape(ctx, op):
                              "value heads" % (slot, s[-1], hv))
 
 
+@register_shape("mamba2_ssd")
+def _mamba2_ssd_shape(ctx, op):
+    xv = op.input("X")
+    xs = ctx.shape(xv)
+    ctx.set(op.output("Out"), xs, ctx.dtype(xv))
+    if xs is None or xs[-1] == -1:
+        return
+    heads, groups = int(op.attr("num_heads")), int(op.attr("num_groups"))
+    if heads % groups or xs[-1] % heads:
+        raise ShapeError("mamba2_ssd: %d heads in %d groups do not fit X %s"
+                         % (heads, groups, list(xs)))
+    bs, cs = ctx.shape(op.input("Bm")), ctx.shape(op.input("Cm"))
+    if bs is not None and cs is not None and (
+            tuple(bs) != tuple(cs) or (bs[-1] != -1 and bs[-1] % groups)):
+        raise ShapeError("mamba2_ssd Bm %s and Cm %s do not fit %d groups"
+                         % (list(bs), list(cs), groups))
+    ds = ctx.shape(op.input("Dt"))
+    if ds is not None and ds[-1] != -1 and ds[-1] != heads:
+        raise ShapeError("mamba2_ssd Dt has %d columns for %d heads"
+                         % (ds[-1], heads))
+    for slot in ("ALog", "DtBias", "D"):
+        ps = ctx.shape(op.input(slot))
+        if ps is not None and tuple(ps) != (heads,):
+            raise ShapeError("mamba2_ssd %s %s is not one value a head of "
+                             "%d" % (slot, list(ps), heads))
+
+
 @register_shape("routed_experts")
 def _routed_experts_shape(ctx, op):
     xv = op.input("X")
     xs = ctx.shape(xv)
-    ctx.set(op.output("Out"), xs, ctx.dtype(xv))
-    gs = ctx.shape(op.input("ExpertGate"))
+    latent = op.input("ExpertX")
+    es = xs if latent is None else ctx.shape(latent)
+    ctx.set(op.output("Out"), es, ctx.dtype(xv))
+    if op.output("SharedOut") is not None:
+        ctx.set(op.output("SharedOut"), xs, ctx.dtype(xv))
+    us = ctx.shape(op.input("ExpertUp"))
     rs = ctx.shape(op.input("Router"))
-    if gs is not None:
-        ctx.set(op.output("Load"), (gs[0],), np.dtype("int32"))
-    if xs is None or gs is None or rs is None:
+    if us is not None:
+        ctx.set(op.output("Load"), (us[0],), np.dtype("int32"))
+    form = op.attr("form", "swiglu")
+    if (op.input("ExpertGate") is None) != (form == "relu2"):
+        raise ShapeError("routed_experts: form %r takes %s ExpertGate"
+                         % (form, "no" if form == "relu2" else "an"))
+    if op.input("SharedUp") is not None \
+            and (latent is None) != (op.output("SharedOut") is None):
+        raise ShapeError("routed_experts: the shared expert has its own "
+                         "output exactly where the experts read ExpertX")
+    if xs is None or es is None or us is None or rs is None:
         return
-    if xs[-1] != -1 and (gs[2] != xs[-1] or rs[0] != xs[-1]):
-        raise ShapeError("routed_experts: X %s against Router %s and "
-                         "ExpertGate %s" % (list(xs), list(rs), list(gs)))
+    if (xs[-1] != -1 and rs[0] != xs[-1]) \
+            or (es[-1] != -1 and us[2] != es[-1]):
+        raise ShapeError("routed_experts: X %s and the experts' input %s "
+                         "against Router %s and ExpertUp %s"
+                         % (list(xs), list(es), list(rs), list(us)))
     first, top_k = int(op.attr("first_expert", 0)), int(op.attr("top_k"))
-    if first < 0 or first + gs[0] > rs[1] or top_k > rs[1]:
+    if first < 0 or first + us[0] > rs[1] or top_k > rs[1]:
         raise ShapeError("routed_experts holds experts [%d, %d) and takes "
                          "the top %d of a router over %d"
-                         % (first, first + gs[0], top_k, rs[1]))
+                         % (first, first + us[0], top_k, rs[1]))
+    bs = ctx.shape(op.input("RouterBias"))
+    if bs is not None and tuple(bs) != (rs[1],):
+        raise ShapeError("routed_experts RouterBias %s against a router "
+                         "over %d" % (list(bs), rs[1]))

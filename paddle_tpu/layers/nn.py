@@ -39,8 +39,8 @@ __all__ = [
     "split", "warpctc", "nce", "hsigmoid", "cumsum",
     "linear_chain_crf", "crf_decoding",
     "dynamic_lstm", "dynamic_gru", "lstm", "gru_unit",
-    "rms_norm", "rotary", "gated_attention", "causal_conv1d",
-    "gated_delta_net", "routed_experts",
+    "rms_norm", "rotary", "causal_self_attention",
+    "causal_conv1d", "gated_delta_net", "mamba2_mixer", "routed_experts",
     "beam_search", "beam_search_gather", "beam_search_decode",
 ]
 
@@ -1577,28 +1577,34 @@ def gru_unit(input, hidden, size, param_attr=None, bias_attr=None,
 
 
 def rms_norm(input, epsilon=1e-6, zero_centered=False, norm_dim=None,
-             gate=None, param_attr=None, name=None):
+             gate=None, gate_first=False, shared_weight=True,
+             param_attr=None, name=None):
     """RMS norm over the last axis, or over each trailing group of
     ``norm_dim`` elements of it (one head of a packed [.., H*D] axis, with
-    one weight of ``norm_dim`` shared by the heads): ``x * rsqrt(mean(x^2) +
-    epsilon) * w`` in float32; ``zero_centered`` applies ``1 + w`` (the
-    weight then starts at 0). ``gate``: a tensor shaped like ``input``; the
-    result is multiplied by ``silu(gate)`` (the gated norm)."""
+    one weight of ``norm_dim`` shared by the heads, or, ``shared_weight``
+    False, a weight as long as the axis): ``x * rsqrt(mean(x^2) + epsilon)
+    * w`` in float32; ``zero_centered`` applies ``1 + w`` (the weight then
+    starts at 0). ``gate``: a tensor shaped like ``input``; the result is
+    multiplied by ``silu(gate)`` (the gated norm), or, ``gate_first``, the
+    input is, before the norm."""
     helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
     dim = int(norm_dim or input.shape[-1])
     w = helper.create_parameter(
-        helper.param_attr, shape=[dim], dtype=_dtype(input),
+        helper.param_attr,
+        shape=[dim if shared_weight else int(input.shape[-1])],
+        dtype=_dtype(input),
         default_initializer=ConstantInitializer(
             0.0 if zero_centered else 1.0))
     out = helper.create_variable_for_type_inference(dtype=_dtype(input),
                                                     shape=input.shape)
     inputs = {"X": input, "Scale": w}
+    attrs = {"epsilon": float(epsilon), "zero_centered": bool(zero_centered),
+             "norm_dim": dim}
     if gate is not None:
         inputs["Gate"] = gate
-    helper.append_op("rms_norm", inputs, {"Y": out},
-                     {"epsilon": float(epsilon),
-                      "zero_centered": bool(zero_centered),
-                      "norm_dim": dim})
+        if gate_first:
+            attrs["gate_first"] = True
+    helper.append_op("rms_norm", inputs, {"Y": out}, attrs)
     return out
 
 
@@ -1631,37 +1637,45 @@ def _projection(helper, x, dout, name, sharding=None):
     return out
 
 
-def gated_attention(x, num_heads, num_kv_heads, head_dim, rotary_dim,
-                    rope_theta=10000.0, epsilon=1e-6, name=None):
-    """Causal self-attention with grouped-query heads and an output gate.
-    ``q_proj`` gives query and gate, interleaved a head ([.., H, 2*D], the
-    halves of the last axis); ``num_kv_heads`` key/value heads serve
-    ``num_heads`` query heads (head ``h`` reads ``h // (H / Hkv)``); q and k
-    take a zero-centred RMS norm over the head dimension and rotary
-    positions on its first ``rotary_dim`` dims; the attention output is
-    multiplied by ``sigmoid(gate)`` before ``o_proj``. x: [B, T, D_model].
-    Parameters: ``<name>.{q_proj,k_proj,v_proj,o_proj,q_norm.w,k_norm.w}``."""
+def causal_self_attention(x, num_heads, num_kv_heads, head_dim,
+                          rotary_dim=0, rope_theta=10000.0, epsilon=1e-6,
+                          qk_norm=False, output_gate=False, name=None):
+    """Causal self-attention with grouped-query heads: ``num_kv_heads``
+    key/value heads serve ``num_heads`` query heads (head ``h`` reads ``h //
+    (H / Hkv)``). ``qk_norm``: q and k take a zero-centred RMS norm over the
+    head dimension; ``rotary_dim`` > 0: rotary positions on the first
+    ``rotary_dim`` dims of each head (0: no position encoding);
+    ``output_gate``: ``q_proj`` gives query and gate, interleaved a head
+    ([.., H, 2*D], the halves of the last axis), and the attention output is
+    multiplied by ``sigmoid(gate)`` before ``o_proj``. The head counts are
+    those this chip holds: given a share of a layer's heads, ``o_proj``
+    gives that share's addend of the layer's output. x: [B, T, D_model].
+    Parameters: ``<name>.{q_proj,k_proj,v_proj,o_proj}`` and, with
+    ``qk_norm``, ``<name>.{q_norm.w,k_norm.w}``."""
     from . import ops as op_layers
     from . import tensor
 
-    helper = LayerHelper("gated_attention", name=name)
+    helper = LayerHelper("causal_self_attention", name=name)
     b, t = x.shape[0], x.shape[1]
-    qg = _projection(helper, x, num_heads * head_dim * 2,
-                     _named(name, "q_proj"), (None, "mp"))
-    qg = tensor.reshape(qg, [-1, t, num_heads, 2 * head_dim])
-    q, g = split(qg, 2, dim=-1)
-    q = tensor.reshape(q, [-1, t, num_heads * head_dim])
-    g = tensor.reshape(g, [-1, t, num_heads * head_dim])
+    q = _projection(helper, x, num_heads * head_dim * (1 + bool(output_gate)),
+                    _named(name, "q_proj"), (None, "mp"))
+    if output_gate:
+        qg = tensor.reshape(q, [-1, t, num_heads, 2 * head_dim])
+        q, g = split(qg, 2, dim=-1)
+        q = tensor.reshape(q, [-1, t, num_heads * head_dim])
+        g = tensor.reshape(g, [-1, t, num_heads * head_dim])
     k = _projection(helper, x, num_kv_heads * head_dim,
                     _named(name, "k_proj"), (None, "mp"))
     v = _projection(helper, x, num_kv_heads * head_dim,
                     _named(name, "v_proj"), (None, "mp"))
-    q = rms_norm(q, epsilon, zero_centered=True, norm_dim=head_dim,
-                 param_attr=ParamAttr(name=_named(name, "q_norm.w")))
-    k = rms_norm(k, epsilon, zero_centered=True, norm_dim=head_dim,
-                 param_attr=ParamAttr(name=_named(name, "k_norm.w")))
-    q = rotary(q, num_heads, rotary_dim, rope_theta)
-    k = rotary(k, num_kv_heads, rotary_dim, rope_theta)
+    if qk_norm:
+        q = rms_norm(q, epsilon, zero_centered=True, norm_dim=head_dim,
+                     param_attr=ParamAttr(name=_named(name, "q_norm.w")))
+        k = rms_norm(k, epsilon, zero_centered=True, norm_dim=head_dim,
+                     param_attr=ParamAttr(name=_named(name, "k_norm.w")))
+    if rotary_dim:
+        q = rotary(q, num_heads, rotary_dim, rope_theta)
+        k = rotary(k, num_kv_heads, rotary_dim, rope_theta)
     ctx = helper.create_variable_for_type_inference(
         dtype=_dtype(x), shape=(b, t, num_heads * head_dim))
     helper.append_op("flash_attention", {"Q": q, "K": k, "V": v},
@@ -1669,7 +1683,8 @@ def gated_attention(x, num_heads, num_kv_heads, head_dim, rotary_dim,
                      {"num_heads": int(num_heads),
                       "num_kv_heads": int(num_kv_heads),
                       "dropout_rate": 0.0, "causal": True})
-    ctx = elementwise_mul(ctx, op_layers.sigmoid(g))
+    if output_gate:
+        ctx = elementwise_mul(ctx, op_layers.sigmoid(g))
     wo = helper.create_parameter(
         ParamAttr(name=_named(name, "o_proj"),
                   initializer=XavierInitializer(), sharding=("mp", None)),
@@ -1680,19 +1695,83 @@ def gated_attention(x, num_heads, num_kv_heads, head_dim, rotary_dim,
     return out
 
 
-def causal_conv1d(x, kernel=4, act="silu", param_attr=None, name=None):
+def causal_conv1d(x, kernel=4, act="silu", param_attr=None, bias_attr=None,
+                  name=None):
     """Causal depthwise convolution along T of x [B, T, C], filter
-    [C, kernel] without bias, then ``act`` ('silu' or None)."""
-    helper = LayerHelper("causal_conv1d", param_attr=param_attr, name=name)
+    [C, kernel], a bias [C] where ``bias_attr`` is given, then ``act``
+    ('silu' or None)."""
+    helper = LayerHelper("causal_conv1d", param_attr=param_attr,
+                         bias_attr=bias_attr, name=name)
     c = int(x.shape[-1])
     lim = (3.0 / kernel) ** 0.5
     w = helper.create_parameter(
         helper.param_attr, shape=[c, int(kernel)], dtype=_dtype(x),
         default_initializer=UniformInitializer(-lim, lim))
+    inputs = {"X": x, "Filter": w}
+    if bias_attr:
+        inputs["Bias"] = helper.create_parameter(
+            helper.bias_attr, shape=[c], dtype=_dtype(x), is_bias=True)
     out = helper.create_variable_for_type_inference(dtype=_dtype(x),
                                                     shape=x.shape)
-    helper.append_op("causal_conv1d", {"X": x, "Filter": w}, {"Out": out},
+    helper.append_op("causal_conv1d", inputs, {"Out": out},
                      {"act": act or ""})
+    return out
+
+
+def mamba2_mixer(x, num_heads, head_dim, num_groups, state_size,
+                 conv_kernel=4, epsilon=1e-5, chunk=128, name=None):
+    """Mamba-2 mixer (x: [B, T, D_model]) over the heads this chip holds:
+    ``in_proj`` gives ``[z | x B C | dt]`` (H*P, H*P + 2*G*N and H
+    columns); ``[x B C]`` pass a causal depthwise convolution with a bias
+    and SiLU; the state-space scan (op ``mamba2_ssd``, ``ops/mamba2.py``)
+    over a [P, N] state a head with ``dt = softplus(dt + dt_bias)``, ``A =
+    -exp(A_log)`` and the skip ``D x``; the output times ``silu(z)`` takes
+    an RMS norm in groups of ``H*P / G`` channels (the gate BEFORE the
+    norm), then ``out_proj``. ``num_heads`` of ``head_dim`` in
+    ``num_groups`` groups: given a share of a layer's heads with their
+    groups, ``out_proj`` gives that share's addend of the layer's output.
+    Parameters: ``<name>.{in_proj,conv,conv_bias,A_log,dt_bias,D,norm.w,
+    out_proj}``."""
+    helper = LayerHelper("mamba2_mixer", name=name)
+    b, t = x.shape[0], x.shape[1]
+    inner, bc = num_heads * head_dim, num_groups * state_size
+    proj = _projection(helper, x, 2 * inner + 2 * bc + num_heads,
+                       _named(name, "in_proj"), (None, "mp"))
+    z, xbc, dt = split(proj, [inner, inner + 2 * bc, num_heads], dim=-1)
+    xbc = causal_conv1d(
+        xbc, conv_kernel, "silu",
+        param_attr=ParamAttr(name=_named(name, "conv")),
+        bias_attr=ParamAttr(name=_named(name, "conv_bias")))
+    xs, bm, cm = split(xbc, [inner, bc, bc], dim=-1)
+
+    def per_head(tag, initializer):
+        return helper.create_parameter(
+            ParamAttr(name=_named(name, tag)), shape=[num_heads],
+            dtype="float32", default_initializer=initializer)
+
+    # decays start slow, as state-space layers are started: A in (1, 16],
+    # softplus(dt_bias) in about (0.001, 0.1)
+    core = helper.create_variable_for_type_inference(
+        dtype=_dtype(x), shape=(b, t, inner))
+    helper.append_op(
+        "mamba2_ssd",
+        {"X": xs, "Bm": bm, "Cm": cm, "Dt": dt,
+         "ALog": per_head("A_log", UniformInitializer(0.0, 2.77)),
+         "DtBias": per_head("dt_bias", UniformInitializer(-6.9, -2.25)),
+         "D": per_head("D", ConstantInitializer(1.0))},
+        {"Out": core},
+        {"num_heads": int(num_heads), "num_groups": int(num_groups),
+         "chunk": int(chunk)})
+    core = rms_norm(core, epsilon, norm_dim=inner // num_groups, gate=z,
+                    gate_first=True, shared_weight=False,
+                    param_attr=ParamAttr(name=_named(name, "norm.w")))
+    wo = helper.create_parameter(
+        ParamAttr(name=_named(name, "out_proj"),
+                  initializer=XavierInitializer(), sharding=("mp", None)),
+        shape=[inner, x.shape[-1]], dtype=_dtype(x))
+    out = helper.create_variable_for_type_inference(dtype=_dtype(x),
+                                                    shape=x.shape)
+    helper.append_op("matmul", {"X": core, "Y": wo}, {"Out": out}, {})
     return out
 
 
@@ -1763,21 +1842,32 @@ def gated_delta_net(x, num_k_heads, num_v_heads, head_k_dim, head_v_dim,
 
 def routed_experts(x, num_experts, top_k, moe_intermediate_size,
                    shared_intermediate_size=0, experts_held=None,
-                   norm_topk_prob=True, name=None):
+                   norm_topk_prob=True, score="softmax",
+                   selection_bias=False, scale=1.0, form="swiglu",
+                   shared_gate=True, latent_size=0, name=None):
     """Mixture-of-experts block with routed and shared experts on a chip that
     holds a share of the routed ones (``parallel/moe.py``): a router over
-    all ``num_experts``, the ``top_k`` largest softmax weights (divided by
-    their sum with ``norm_topk_prob``), SwiGLU experts of width
-    ``moe_intermediate_size``. ``experts_held``: ``(first, count)`` or a
-    ``range`` of the expert ids this chip holds (default: all); the routed
-    sum runs over the picks that fall on them, and no token is dropped
-    whatever the routing. ``shared_intermediate_size`` > 0 adds one shared
-    SwiGLU expert behind a sigmoid gate. Returns ``(out, load)``; ``load``
-    [count] int32 is a persistable counter of the tokens each held expert
-    took in the last step. Parameters: ``<name>.router``,
+    all ``num_experts`` (``score``: a ``softmax`` over them or a ``sigmoid``
+    each; ``selection_bias``: a persistable [num_experts] buffer, zeros, no
+    gradient, added to the scores for the choice alone), the ``top_k``
+    largest weights (divided by their sum with ``norm_topk_prob``, times
+    ``scale``), experts of width ``moe_intermediate_size`` and ``form``
+    ``swiglu`` (three matrices) or ``relu2`` (``down(relu(up x)^2)``).
+    ``latent_size`` > 0: the routed experts read and write a latent of that
+    width, ``x @ latent_down`` in and ``@ latent_up`` out (plain matmuls
+    round the op), while the router and the shared expert read x.
+    ``experts_held``: ``(first, count)`` or a ``range`` of the expert ids
+    this chip holds (default: all); the routed sum runs over the picks that
+    fall on them, and no token is dropped whatever the routing.
+    ``shared_intermediate_size`` > 0 adds one shared expert of the same
+    form, that many units wide (those this chip holds), behind a sigmoid
+    gate if ``shared_gate``. Returns ``(out, load)``; ``load`` [count] int32
+    is a persistable counter of the tokens each held expert took in the
+    last step. Parameters: ``<name>.router`` (``.router_bias``),
     ``<name>.experts.{gate,up,down}`` ([count, out, in], the published
-    per-expert layout), ``<name>.shared.{gate_proj,up_proj,down_proj}``,
-    ``<name>.shared_gate``."""
+    per-expert layout; no ``gate`` for ``relu2``),
+    ``<name>.shared.{gate_proj,up_proj,down_proj}``, ``<name>.shared_gate``,
+    ``<name>.{latent_down,latent_up}``."""
     from . import tensor
 
     helper = LayerHelper("routed_experts", name=name)
@@ -1792,6 +1882,8 @@ def routed_experts(x, num_experts, top_k, moe_intermediate_size,
     if first < 0 or held < 1 or first + held > num_experts:
         raise ValueError("experts_held [%d, %d) is not within the %d experts"
                          % (first, first + held, num_experts))
+    if form not in ("swiglu", "relu2"):
+        raise ValueError("unknown expert form %r" % (form,))
 
     def p(tag, shape, fan_in, fan_out):
         lim = (6.0 / (fan_in + fan_out)) ** 0.5
@@ -1800,31 +1892,48 @@ def routed_experts(x, num_experts, top_k, moe_intermediate_size,
                       initializer=UniformInitializer(-lim, lim)),
             shape=shape, dtype=dtype)
 
-    inputs = {
-        "X": x,
-        "Router": p("router", [d, num_experts], d, num_experts),
-        "ExpertGate": p("experts.gate", [held, f, d], d, f),
-        "ExpertUp": p("experts.up", [held, f, d], d, f),
-        "ExpertDown": p("experts.down", [held, d, f], f, d),
-    }
+    de = int(latent_size) or d       # the width the routed experts work in
+    inputs = {"X": x, "Router": p("router", [d, num_experts], d,
+                                  num_experts)}
+    if form == "swiglu":
+        inputs["ExpertGate"] = p("experts.gate", [held, f, de], de, f)
+    inputs["ExpertUp"] = p("experts.up", [held, f, de], de, f)
+    inputs["ExpertDown"] = p("experts.down", [held, de, f], f, de)
+    if selection_bias:
+        inputs["RouterBias"] = helper.create_parameter(
+            ParamAttr(name=_named(name, "router_bias"), trainable=False,
+                      initializer=ConstantInitializer(0.0)),
+            shape=[num_experts], dtype="float32")
     if shared_intermediate_size:
         fs = int(shared_intermediate_size)
-        inputs.update({
-            "SharedGate": p("shared.gate_proj", [d, fs], d, fs),
-            "SharedUp": p("shared.up_proj", [d, fs], d, fs),
-            "SharedDown": p("shared.down_proj", [fs, d], fs, d),
-            "SharedExpertGate": p("shared_gate", [d, 1], d, 1),
-        })
-    out = helper.create_variable_for_type_inference(dtype=dtype,
-                                                    shape=x.shape)
+        if form == "swiglu":
+            inputs["SharedGate"] = p("shared.gate_proj", [d, fs], d, fs)
+        inputs["SharedUp"] = p("shared.up_proj", [d, fs], d, fs)
+        inputs["SharedDown"] = p("shared.down_proj", [fs, d], fs, d)
+        if shared_gate:
+            inputs["SharedExpertGate"] = p("shared_gate", [d, 1], d, 1)
+    attrs = {"top_k": int(top_k), "first_expert": first,
+             "norm_topk_prob": bool(norm_topk_prob), "score": score,
+             "scale": float(scale), "form": form}
+    out = helper.create_variable_for_type_inference(
+        dtype=dtype, shape=tuple(x.shape[:-1]) + (de,))
+    outputs = {"Out": out}
+    if latent_size:
+        inputs["ExpertX"] = _projection(helper, x, de,
+                                        _named(name, "latent_down"))
+        if shared_intermediate_size:
+            outputs["SharedOut"] = helper.create_variable_for_type_inference(
+                dtype=dtype, shape=x.shape)
     load = tensor.create_global_var(
         shape=[held], value=0, dtype="int32", persistable=True,
         name=_named(name, "load"))
     load.stop_gradient = True
-    helper.append_op(
-        "routed_experts", inputs, {"Out": out, "Load": load},
-        {"top_k": int(top_k), "first_expert": first,
-         "norm_topk_prob": bool(norm_topk_prob)})
+    outputs["Load"] = load
+    helper.append_op("routed_experts", inputs, outputs, attrs)
+    if latent_size:
+        out = _projection(helper, out, d, _named(name, "latent_up"))
+        if shared_intermediate_size:
+            out = elementwise_add(out, outputs["SharedOut"])
     return out, load
 
 

@@ -16,6 +16,7 @@ from . import se_resnext  # noqa: F401
 from . import stacked_lstm  # noqa: F401
 from . import transformer  # noqa: F401
 from . import qwen3_next  # noqa: F401
+from . import nemotron_h  # noqa: F401
 from . import bert  # noqa: F401
 from . import deepfm  # noqa: F401
 from . import word2vec  # noqa: F401

@@ -55,10 +55,11 @@ def qwen3_next(seq_len=8192, vocab_size=151936, hidden_size=2048,
         nm = "l%d" % i
         h = norm(x, nm + ".input_norm")
         if (i + 1) % full_attention_interval == 0:
-            h = layers.gated_attention(
+            h = layers.causal_self_attention(
                 h, num_attention_heads, num_key_value_heads, head_dim,
                 int(head_dim * partial_rotary_factor), rope_theta,
-                rms_norm_eps, name=nm + ".attn")
+                rms_norm_eps, qk_norm=True, output_gate=True,
+                name=nm + ".attn")
         else:
             h = layers.gated_delta_net(
                 h, linear_num_key_heads, linear_num_value_heads,

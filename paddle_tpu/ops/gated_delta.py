@@ -57,6 +57,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .chunk_scan import in_chunk_decays, scan_groups
 from .gates import GateDecision, GateReason, platform_reason
 from .kernel_names import named_pallas_call, traced_once
 
@@ -149,14 +150,9 @@ def _chunks_from(state, q, k, v, g, beta, chunk, mx):
 
     q, k, v = (chunks(x.astype(f32)) for x in (q, k, v))
     g, beta = chunks(g.astype(f32)), chunks(beta.astype(f32))
-    gc = jnp.cumsum(g, axis=-1)                          # [B, H, N, C]
+    gc, decay = in_chunk_decays(g)           # [B, H, N, C], [.., C, C]
     at = jnp.arange(chunk)
-    lower = at[:, None] >= at[None, :]
     strict = at[:, None] > at[None, :]
-    # decay from token j to token i of a chunk, i >= j (elsewhere the
-    # difference is positive and is never exponentiated)
-    diff = gc[..., :, None] - gc[..., None, :]
-    decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
     k_beta = k * beta[..., None]
     a = jnp.where(strict, mm("bhnik,bhnjk->bhnij", k_beta, k) * decay, 0.0)
     rhs = jnp.concatenate(
@@ -203,29 +199,15 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=64, mxu_dtype=None,
     mx = mxu_dtype or f32
     b, t = q.shape[:2]
     h, dk, dv = heads or q.shape[2], q.shape[-1], v.shape[-1]
-    n = -(-t // chunk)
-    group = min(group, n)
-    span = group * chunk
-    pad = (-t) % span
-    if pad:
-        q, k, v, g, beta = (
-            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-            for x in (q, k, v, g, beta))
+    group = min(group, -(-t // chunk))
 
-    def groups(x):  # [B, T, ...] -> [T / span, B, span, ...]
-        return jnp.moveaxis(
-            x.reshape((b, (t + pad) // span, span) + x.shape[2:]), 1, 0)
-
-    @jax.checkpoint
     def one_group(state, xs):
         if prepare is not None:
             xs = prepare(*xs)
         return _chunks_from(state, *xs, chunk, mx)
 
-    _, out = jax.lax.scan(one_group, jnp.zeros((b, h, dk, dv), f32),
-                          tuple(groups(x) for x in (q, k, v, g, beta)))
-    out = jnp.moveaxis(out, 0, 1).reshape(b, t + pad, h, dv)
-    return out[:, :t]
+    return scan_groups(one_group, jnp.zeros((b, h, dk, dv), f32),
+                       (q, k, v, g, beta), group * chunk)
 
 
 # ---------------------------------------------------------------------------

@@ -2,8 +2,11 @@
 chip holding a contiguous share of them, no token ever dropped.
 
 One routing implementation. The router scores every expert (its width is
-the whole model's), the ``top_k`` largest are taken and, with
-``renormalize``, their weights divided by their sum over ALL the picks.
+the whole model's) by a softmax over all of them or by a sigmoid each (then
+a selection bias, which no gradient reaches, may be added for the choice
+alone), the ``top_k`` largest are taken and, with ``renormalize``, their
+weights divided by their sum over ALL the picks, then multiplied by
+``scale``.
 The chip then computes only the picks that fall on the experts it holds
 (``[lo, lo + held)``): what the absent experts would add is left out, which
 is this chip's addend of the layer's sum over the chips that share it.
@@ -11,8 +14,9 @@ is this chip's addend of the layer's sum over the chips that share it.
 The work is binned, not one-hot: every (token, pick) assignment that falls
 on a held expert gets a row. The first ``slab_rows`` rows of each expert
 (four times its mean load, so what seeded weights send it at the first
-step) are one STATIC pass an expert: a row of the slab, three dense matrix
-products (SwiGLU), the expert's weight gradients written once. What a
+step) are one STATIC pass an expert: a row of the slab, the expert's dense
+matrix products (three for SwiGLU, two for ReLU squared: ``_FORMS``), the
+expert's weight gradients written once. What a
 routing sends an expert beyond its slab goes to a table laid out expert
 after expert, each expert's rows padded up to a multiple of ``block_rows``;
 a block belongs to ONE expert, the table is sized for the worst case (every
@@ -27,10 +31,14 @@ whose backward walks the same rows again (recomputing their hidden
 activations).
 
 Expert weights are stacked in the published per-expert layout
-``[held, out, in]``: ``gate``/``up`` [held, F, D], ``down`` [held, D, F].
+``[held, out, in]``: ``gate``/``up`` [held, F, D], ``down`` [held, D, F]
+(``swiglu``: ``down(silu(gate x) * up x)``); ``up``, ``down`` alone for
+``relu2`` (``down(relu(up x)^2)``). ``D`` is the width the experts read and
+write, which need not be the router's (experts that work in a latent).
 """
 
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -60,16 +68,31 @@ def slab_rows_for(assignments, num_experts, block_rows):
     return rows
 
 
-def route_topk(x, router_w, top_k, renormalize=True):
-    """x: [T, D]; router_w: [D, E]. Softmax over all E in float32, the
-    ``top_k`` largest. Returns (weights [T, k] float32, experts [T, k]
-    int32)."""
+def route_topk(x, router_w, top_k, renormalize=True, score="softmax",
+               bias=None, scale=1.0):
+    """x: [T, D]; router_w: [D, E]. Scores over all E in float32
+    (``score``: ``softmax`` over the experts, or ``sigmoid`` of each), the
+    ``top_k`` largest of them (of ``score + bias`` where a selection bias
+    [E] is given: it chooses, the weights are the scores themselves).
+    Returns (weights [T, k] float32, experts [T, k] int32)."""
     logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
                         router_w.astype(jnp.float32), precision=_HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, top_k)
+    if score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError("unknown router score %r" % (score,))
+    if bias is None:
+        weights, experts = jax.lax.top_k(probs, top_k)
+    else:
+        _, experts = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
     if renormalize:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, experts.astype(jnp.int32)
 
 
@@ -139,124 +162,170 @@ def _mm(x, w, dims):
                                preferred_element_type=jnp.float32)
 
 
-def _rows_fwd(x, weights_flat, ids, top_k, wg_e, wu_e, wd_e, out):
+class _SwiGLU:
+    """``down(silu(gate x) * up x)``; mats = (gate, up, down)."""
+
+    @staticmethod
+    def act(pre):
+        g, u = pre
+        return jax.nn.silu(g) * u
+
+    @staticmethod
+    def act_saved(pre):
+        g, u = pre
+        sg = jax.nn.sigmoid(g)
+        return g * sg * u, sg
+
+    @staticmethod
+    def dact(dh, pre, sg, dtype):
+        g, u = pre
+        dg = (dh * u * sg * (1.0 + g * (1.0 - sg))).astype(dtype)
+        return dg, (dh * g * sg).astype(dtype)
+
+
+class _ReLU2:
+    """``down(relu(up x)^2)``; mats = (up, down)."""
+
+    @staticmethod
+    def act(pre):
+        return jnp.square(jax.nn.relu(pre[0]))
+
+    @staticmethod
+    def act_saved(pre):
+        r = jax.nn.relu(pre[0])
+        return r * r, r
+
+    @staticmethod
+    def dact(dh, pre, r, dtype):
+        return ((dh * 2.0 * r).astype(dtype),)
+
+
+_FORMS = {"swiglu": _SwiGLU, "relu2": _ReLU2}
+
+
+def _rows_fwd(form, x, weights_flat, ids, top_k, mats, out):
+    """``mats``: one expert's matrices, those that read x first, ``down``
+    last."""
     tokens, w, xb = _rows(x, weights_flat, ids, top_k)
-    g = _mm(xb, wg_e, ((1,), (1,)))                           # [R, F]
-    u = _mm(xb, wu_e, ((1,), (1,)))
-    y = _mm(jax.nn.silu(g) * u, wd_e, ((1,), (1,)))           # [R, D]
+    pre = [_mm(xb, m, ((1,), (1,))) for m in mats[:-1]]       # [R, F]
+    y = _mm(form.act(pre), mats[-1], ((1,), (1,)))            # [R, D]
     return out.at[tokens].add(y * w[:, None], mode="drop")
 
 
-def _rows_bwd(x, weights_flat, dout, ids, top_k, wg_e, wu_e, wd_e, dx, dw):
+def _rows_bwd(form, x, weights_flat, dout, ids, top_k, mats, dx, dw):
     """Returns (dx, dw with this run's rows added, this run's addends to
-    the expert's weight gradients)."""
+    the expert's weight gradients, in the order of ``mats``)."""
     tokens, w, xb = _rows(x, weights_flat, ids, top_k)
-    g = _mm(xb, wg_e, ((1,), (1,)))
-    u = _mm(xb, wu_e, ((1,), (1,)))
-    sg = jax.nn.sigmoid(g)
-    h = g * sg * u
+    wd_e = mats[-1]
+    pre = [_mm(xb, m, ((1,), (1,))) for m in mats[:-1]]
+    h, saved = form.act_saved(pre)
     dyb = dout[jnp.minimum(tokens, x.shape[0] - 1)]           # [R, D]
     y = _mm(h, wd_e, ((1,), (1,)))
     dw = dw.at[ids].add(jnp.sum(y * dyb, -1), mode="drop")
     dy = (dyb * w[:, None]).astype(wd_e.dtype)
     dh = _mm(dy, wd_e, ((1,), (0,)))                          # [R, F]
-    dg = (dh * u * sg * (1.0 + g * (1.0 - sg))).astype(wg_e.dtype)
-    du = (dh * g * sg).astype(wg_e.dtype)
-    xbc = xb.astype(wg_e.dtype)
-    dxb = _mm(dg, wg_e, ((1,), (0,))) + _mm(du, wu_e, ((1,), (0,)))
+    dpre = form.dact(dh, pre, saved, mats[0].dtype)
+    xbc = xb.astype(mats[0].dtype)
+    dxb = functools.reduce(operator.add, (
+        _mm(d, m, ((1,), (0,))) for d, m in zip(dpre, mats)))
     return (dx.at[tokens].add(dxb, mode="drop"), dw,
-            _mm(dg.T, xbc, ((1,), (0,))), _mm(du.T, xbc, ((1,), (0,))),
+            *(_mm(d.T, xbc, ((1,), (0,))) for d in dpre),
             _mm(dy.T, h.astype(wd_e.dtype), ((1,), (0,))))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
-def held_experts(x, weights, experts, wg, wu, wd, lo, block_rows,
-                 slab_rows):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def held_experts(x, weights, experts, mats, lo, block_rows, slab_rows,
+                 form="swiglu"):
     """The held experts' part of the routed sum. x: [T, D]; weights,
-    experts: [T, k] from :func:`route_topk`; wg, wu: [held, F, D]; wd:
-    [held, D, F]; ``lo``: id of the first held expert. Returns (out [T, D]
-    float32, counts [held] int32)."""
-    (out, counts), _ = _held_fwd(x, weights, experts, wg, wu, wd, lo,
-                                 block_rows, slab_rows)
+    experts: [T, k] from :func:`route_topk`; ``mats``: the stacked expert
+    matrices of ``form`` (``swiglu``: gate, up [held, F, D] and down [held,
+    D, F]; ``relu2``: up, down); ``lo``: id of the first held expert.
+    Returns (out [T, D] float32, counts [held] int32)."""
+    (out, counts), _ = _held_fwd(x, weights, experts, mats, lo, block_rows,
+                                 slab_rows, form)
     return out, counts
 
 
-def _held_fwd(x, weights, experts, wg, wu, wd, lo, block_rows, slab_rows):
-    held = wg.shape[0]
+def _held_fwd(x, weights, experts, mats, lo, block_rows, slab_rows, form):
+    held = mats[0].shape[0]
     top_k = weights.shape[1]
+    rows_fwd = functools.partial(_rows_fwd, _FORMS[form])
     slab_assign, row_assign, block_expert, blocks, counts = bin_assignments(
         experts, lo, held, block_rows, slab_rows)
     weights_flat = weights.reshape(-1).astype(jnp.float32)
 
     def slab(out, per):
-        ids, wg_e, wu_e, wd_e = per
-        return _rows_fwd(x, weights_flat, ids, top_k, wg_e, wu_e, wd_e,
-                         out), None
+        ids, *mats_e = per
+        return rows_fwd(x, weights_flat, ids, top_k, mats_e, out), None
 
     out, _ = jax.lax.scan(slab, jnp.zeros(x.shape, jnp.float32),
-                          (slab_assign, wg, wu, wd))
+                          (slab_assign, *mats))
 
     def body(bi, out):
         ids = jax.lax.dynamic_slice(row_assign, (bi * block_rows,),
                                     (block_rows,))
         e = block_expert[bi]
-        return _rows_fwd(x, weights_flat, ids, top_k, wg[e], wu[e], wd[e],
-                         out)
+        return rows_fwd(x, weights_flat, ids, top_k, [m[e] for m in mats],
+                        out)
 
     out = jax.lax.fori_loop(0, blocks, body, out)
-    saved = (x, weights, wg, wu, wd, slab_assign, row_assign, block_expert,
-             blocks)
+    saved = (x, weights, mats, slab_assign, row_assign, block_expert, blocks)
     return (out, counts), saved
 
 
-def _held_bwd(lo, block_rows, slab_rows, saved, cotangent):
-    (x, weights, wg, wu, wd, slab_assign, row_assign, block_expert,
-     blocks) = saved
+def _held_bwd(lo, block_rows, slab_rows, form, saved, cotangent):
+    x, weights, mats, slab_assign, row_assign, block_expert, blocks = saved
     dout = cotangent[0].astype(jnp.float32)
     top_k = weights.shape[1]
+    rows_bwd = functools.partial(_rows_bwd, _FORMS[form])
     weights_flat = weights.reshape(-1).astype(jnp.float32)
     f32 = jnp.float32
 
     def slab(carry, per):
-        ids, wg_e, wu_e, wd_e = per
-        dx, dw, *grads = _rows_bwd(x, weights_flat, dout, ids, top_k, wg_e,
-                                   wu_e, wd_e, *carry)
+        ids, *mats_e = per
+        dx, dw, *grads = rows_bwd(x, weights_flat, dout, ids, top_k, mats_e,
+                                  *carry)
         return (dx, dw), tuple(grads)
 
-    (dx, dw), (dwg, dwu, dwd) = jax.lax.scan(
+    (dx, dw), grads = jax.lax.scan(
         slab, (jnp.zeros(x.shape, f32), jnp.zeros(weights_flat.shape, f32)),
-        (slab_assign, wg, wu, wd))
+        (slab_assign, *mats))
 
     def body(bi, carry):
-        dx, dw, dwg, dwu, dwd = carry
+        dx, dw, *grads = carry
         ids = jax.lax.dynamic_slice(row_assign, (bi * block_rows,),
                                     (block_rows,))
         e = block_expert[bi]
-        dx, dw, g_e, u_e, d_e = _rows_bwd(x, weights_flat, dout, ids, top_k,
-                                          wg[e], wu[e], wd[e], dx, dw)
-        return (dx, dw, dwg.at[e].add(g_e), dwu.at[e].add(u_e),
-                dwd.at[e].add(d_e))
+        dx, dw, *more = rows_bwd(x, weights_flat, dout, ids, top_k,
+                                 [m[e] for m in mats], dx, dw)
+        return (dx, dw, *(g.at[e].add(a) for g, a in zip(grads, more)))
 
-    dx, dw, dwg, dwu, dwd = jax.lax.fori_loop(
-        0, blocks, body, (dx, dw, dwg, dwu, dwd))
+    dx, dw, *grads = jax.lax.fori_loop(0, blocks, body, (dx, dw, *grads))
     return (dx.astype(x.dtype), dw.reshape(weights.shape).astype(
-        weights.dtype), None, dwg.astype(wg.dtype), dwu.astype(wu.dtype),
-        dwd.astype(wd.dtype))
+        weights.dtype), None,
+        tuple(g.astype(m.dtype) for g, m in zip(grads, mats)))
 
 
 held_experts.defvjp(_held_fwd, _held_bwd)
 
 
 def routed_experts(x, router_w, wg, wu, wd, top_k, lo=0, renormalize=True,
-                   block_rows=None, slab_rows=None):
-    """x: [..., D]. Returns (routed [..., D] float32: the held experts' part
-    of the layer's routed sum; counts [held] int32)."""
+                   block_rows=None, slab_rows=None, form="swiglu",
+                   score="softmax", bias=None, scale=1.0, router_x=None):
+    """x: [..., D], what the experts read; ``wg`` is None for ``relu2``
+    experts, which have no gate matrix. The router reads ``router_x`` [...,
+    D_model] where given (experts that work in a latent), else x. Returns
+    (routed [..., D] float32: the held experts' part of the layer's routed
+    sum; counts [held] int32)."""
     lead, d = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, d)
-    weights, experts = route_topk(xt, router_w, top_k, renormalize)
+    rt = xt if router_x is None else router_x.reshape(-1, router_x.shape[-1])
+    weights, experts = route_topk(rt, router_w, top_k, renormalize, score,
+                                  bias, scale)
     a = xt.shape[0] * top_k
     rows = block_rows or block_rows_for(a)
     slab = slab_rows or slab_rows_for(a, router_w.shape[1], rows)
-    out, counts = held_experts(xt, weights, experts, wg, wu, wd, int(lo),
-                               int(rows), int(slab))
+    mats = (wu, wd) if form == "relu2" else (wg, wu, wd)
+    out, counts = held_experts(xt, weights, experts, mats, int(lo),
+                               int(rows), int(slab), form)
     return out.reshape(lead + (d,)), counts

@@ -163,7 +163,8 @@ ATTN = dict(num_attention_heads=4, num_key_value_heads=2, head_dim=16,
 def test_gated_gqa_attention_against_plain():
     rng = np.random.default_rng(4)
     _layer_against_plain(
-        lambda x: layers.gated_attention(x, 4, 2, 16, 4, 1e4, name="a"),
+        lambda x: layers.causal_self_attention(
+            x, 4, 2, 16, 4, 1e4, qk_norm=True, output_gate=True, name="a"),
         lambda p, x: REFERENCE._attention(EXACT, p, "a", x, ATTN),
         {"x": _randn(rng, 2, T, 64)}, perturb=0.1)
 

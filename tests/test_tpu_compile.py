@@ -212,6 +212,42 @@ def test_gated_delta_core_and_routed_experts_fit_at_published_widths(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
+def test_state_space_core_and_latent_experts_fit_at_published_widths(chip):
+    """``nemotron3super.train.s8192``'s share of a layer: the Mamba-2 scan
+    (16 heads of 64 x 128 state, one group, T = 8192, chunks of 128) keeps
+    under 0.5 GB of temporaries with its groups of chunks recomputed, and
+    the binned ReLU-squared experts in the 1024-wide latent (8 of 512,
+    top-22, router at 4096) under 0.5 GB."""
+    from paddle_tpu.ops import mamba2
+    from paddle_tpu.parallel import moe
+
+    t = 8192
+
+    def core(x, bm, cm, dt, a_log, dt_bias, d):
+        return jnp.sum(mamba2.mamba2_ssd(x, bm, cm, dt, a_log, dt_bias, d,
+                                         16, 1, 128, BF16))
+
+    compiled = _compile(
+        chip, jax.grad(core, argnums=(0, 1, 2, 3, 4, 5, 6)),
+        sds((1, t, 1024), BF16), sds((1, t, 128), BF16),
+        sds((1, t, 128), BF16), sds((1, t, 16), BF16), sds((16,), F32),
+        sds((16,), F32), sds((16,), F32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    assert _kernel_calls(compiled) == 0     # no kernel for it yet
+
+    def experts(x, h, router, bias, up, down):
+        return jnp.sum(moe.routed_experts(
+            x, router, None, up, down, 22, 0, form="relu2", score="sigmoid",
+            bias=bias, scale=5.0, router_x=h)[0])
+
+    compiled = _compile(
+        chip, jax.grad(experts, argnums=(0, 1, 2, 4, 5)),
+        sds((t, 1024), BF16), sds((t, 4096), BF16), sds((4096, 512), F32),
+        sds((512,), F32), sds((8, 2688, 1024), BF16),
+        sds((8, 1024, 2688), BF16))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
 @contextlib.contextmanager
 def _vmem_limits(limits):
     """Inside the block every Pallas call named in ``limits`` ({kernel
@@ -521,6 +557,46 @@ def test_transformer_base_step_keeps_its_kernel_sites_and_traces_each_once(
     assert {k: v["traced"] + v["reused"] for k, v in traces.items()} == {
         "dense_vmem.fwd": 36, "dense_vmem.bwd": 18}
     assert all(1 <= v["traced"] <= 3 for v in traces.values()), traces
+
+
+def test_nemotron3_super_step_compiles_and_fits_the_chip(chip):
+    """The benchmark's ``nemotron3super.train.s8192`` step (1 x 8192, the
+    share of a 64-chip-a-layer deployment at published widths: layers 36-46,
+    an eighth of every mixer's heads, 8 of 512 experts, 16384 rows of the
+    vocabulary), built from the configuration's own ``builder_args`` and
+    compiled for one chip: 508M parameters, their Adam state aliased in
+    place, and the whole under the chip's memory."""
+    import json
+
+    from paddle_tpu.core.executor import build_step_fn
+    from paddle_tpu.models import nemotron_h
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron-3-super-120b-a12b.json")) as f:
+        args = json.load(f)["builder_args"]
+    avals, program, loss, persist = _abstract_step(
+        lambda: (nemotron_h.nemotron_h(seq_len=8192, **args), 1))
+    params = sum(int(np.prod(p.shape))
+                 for p in program.global_block().all_parameters()
+                 if p.trainable)
+    assert abs(params - 508e6) < 0.01 * 508e6
+    step = build_step_fn(program, (loss,), persist)
+    compiled = _compile(chip, step, *avals, donate_argnums=(0,))
+    mem = compiled.memory_analysis()
+    need = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    print("nemotron3super step: %d tpu_custom_call, %.2f GB temporaries + "
+          "%.2f GB arguments" % (_kernel_calls(compiled),
+                                 mem.temp_size_in_bytes / 1e9,
+                                 mem.argument_size_in_bytes / 1e9))
+    assert 12 * params <= mem.argument_size_in_bytes < 12.1 * params
+    assert mem.alias_size_in_bytes >= 12 * params       # updated in place
+    assert 0.25 * _HBM_BYTES < need < 0.8 * _HBM_BYTES
+    # the attention layer's four heads of 128 run a Pallas kernel, forward
+    # and backward; the head's fused cross-entropy too
+    names = _step_kernel_names(compiled)
+    assert any(n.endswith(".fwd") for n in names) and any(
+        n.endswith(".bwd") for n in names), names
 
 
 _STEP_CASES = [
