@@ -100,10 +100,17 @@ def sizes(rehearse):
             "scatter": dict(k=32, v=None, n=None),  # None: gate's bounds
             # nemotron3super.train.s8192's shapes: 16 Mamba-2 heads of 64
             # in one group of state 128; 8 of 512 experts of 2688 in a
-            # latent of 1024, 22 picks, the router at the model's 4096
+            # latent of 1024, 22 picks, the router at the model's 4096;
+            # then qwen3next.train.s8192's: 16 of 512 SwiGLU experts of
+            # 512 at the model's 2048, 10 picks
             "ssd": dict(t=8192, heads=16, p=64, groups=1, n=128, chunk=128),
-            "experts": dict(t=8192, d_model=4096, latent=1024, f=2688,
-                            experts=512, held=8, top_k=22, scale=5.0),
+            "experts": [
+                dict(form="relu2", t=8192, d_model=4096, latent=1024,
+                     f=2688, experts=512, held=8, top_k=22, score="sigmoid",
+                     scale=5.0),
+                dict(form="swiglu", t=8192, d_model=2048, latent=0, f=512,
+                     experts=512, held=16, top_k=10, score="softmax",
+                     scale=1.0)],
             # bench.py's on-TPU widths and batches. Depth is cut to 2
             # layers where a layer repeats (BERT 12, seq-2048 6+6): the
             # host that compiles for the chip is shared and slow, the
@@ -143,8 +150,11 @@ def sizes(rehearse):
         "ce": dict(t=256, d=32, v=300, ref_chunk=64),
         "scatter": dict(k=16, v=600, n=2048),
         "ssd": dict(t=70, heads=4, p=8, groups=2, n=16, chunk=16),
-        "experts": dict(t=96, d_model=32, latent=16, f=24, experts=16,
-                        held=4, top_k=5, scale=2.5),
+        "experts": [
+            dict(form="relu2", t=96, d_model=32, latent=128, f=256,
+                 experts=16, held=4, top_k=5, score="sigmoid", scale=2.5),
+            dict(form="swiglu", t=96, d_model=128, latent=0, f=128,
+                 experts=16, held=4, top_k=5, score="softmax", scale=1.0)],
         "configs": dict(
             bert=dict(kw=dict(vocab_size=1000, seq_len=32, d_model=128,
                               d_ff=256, n_layer=2), batch=4),
@@ -643,62 +653,79 @@ def _ssd_case(ctx, t, heads, p, groups, n, chunk):
             "fwd_err": fwd, "bwd_err": bwd}
 
 
-def _experts_case(ctx, t, d_model, latent, f, experts, held, top_k, scale):
-    """``parallel/moe.py``'s binned sigmoid-routed ReLU-squared experts in
-    a latent against every held expert on every token under a one-hot
-    weight, both from the same routing."""
+def _experts_case(ctx, form, t, d_model, latent, f, experts, held, top_k,
+                  score, scale):
+    """``parallel/moe.py``'s routed experts (``form`` ``relu2`` behind a
+    sigmoid router in a latent, as ``nemotron3super.train.s8192`` runs them,
+    or ``swiglu`` behind a softmax router at the model's width, as
+    ``qwen3next.train.s8192``) against every held expert on every token
+    under a one-hot weight, both from the same routing. On the chip the
+    table is multiplied by the ``grouped_experts`` kernels (plan
+    ``grouped_rows``)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from paddle_tpu.ops import grouped_experts
     from paddle_tpu.parallel import moe
 
     rng = np.random.RandomState(ctx["seed"])
     bf16 = jnp.bfloat16
-    x = jnp.asarray(rng.randn(t, latent), bf16)
-    router_x = jnp.asarray(rng.randn(t, d_model), bf16)
+    d = latent or d_model
+    x = jnp.asarray(rng.randn(t, d), bf16)
+    router_x = jnp.asarray(rng.randn(t, d_model), bf16) if latent else x
     router = jnp.asarray(rng.randn(d_model, experts) / d_model ** 0.5,
                          jnp.float32)
-    up = jnp.asarray(rng.randn(held, f, latent) / latent ** 0.5, bf16)
-    down = jnp.asarray(rng.randn(held, latent, f) / f ** 0.5, bf16)
+    first = [jnp.asarray(rng.randn(held, f, d) / d ** 0.5, bf16)
+             for _ in range(1 if form == "relu2" else 2)]
+    mats = (*first, jnp.asarray(rng.randn(held, d, f) / f ** 0.5, bf16))
     bias = jnp.zeros((experts,), jnp.float32)
-    g = jnp.asarray(rng.randn(t, latent), jnp.float32)
+    g = jnp.asarray(rng.randn(t, d), jnp.float32)
+    rows = moe.block_rows_for(t * top_k)
+    plan = grouped_experts.plan_for(mats, rows)
+    act = grouped_experts.FORMS[form].act
 
-    def ours(x, up, down, router_x, router, bias, g):
+    def ours(x, mats, router_x, router, bias, g):
         out, counts = moe.routed_experts(
-            x, router, None, up, down, top_k, 0, form="relu2",
-            score="sigmoid", bias=bias, scale=scale, router_x=router_x)
+            x, router, *((None,) if form == "relu2" else ()), *mats, top_k,
+            0, block_rows=rows, form=form, score=score, bias=bias,
+            scale=scale, router_x=router_x, plan=plan)
         return jnp.sum(out * g), (out, counts)
 
-    def plain(x, up, down, router_x, router, bias, g):
+    def plain(x, mats, router_x, router, bias, g):
         weights, picks = moe.route_topk(router_x, router, top_k, True,
-                                        "sigmoid", bias, scale)
+                                        score, bias, scale)
 
         def expert(out, per):
-            e, up_e, down_e = per
+            e, *mats_e = per
             w = jnp.sum(jnp.where(picks == e, weights, 0.0), -1)
-            h = jnp.square(jax.nn.relu(jnp.matmul(
-                x, up_e.T, preferred_element_type=jnp.float32)))
-            y = jnp.matmul(h.astype(bf16), down_e.T,
+            h = act([jnp.matmul(x, m.T, preferred_element_type=jnp.float32)
+                     for m in mats_e[:-1]])
+            y = jnp.matmul(h.astype(bf16), mats_e[-1].T,
                            preferred_element_type=jnp.float32)
             return out + w[:, None] * y, None
 
-        out, _ = jax.lax.scan(expert, jnp.zeros((t, latent), jnp.float32),
-                              (jnp.arange(held), up, down))
+        out, _ = jax.lax.scan(expert, jnp.zeros((t, d), jnp.float32),
+                              (jnp.arange(held), *mats))
         return jnp.sum(out * g), out
 
-    args = (x, up, down, router_x, router, bias, g)
+    args = (x, mats, router_x, router, bias, g)
     (_, (out, counts)), grads = jax.jit(jax.value_and_grad(
-        ours, argnums=(0, 1, 2), has_aux=True))(*args)
+        ours, argnums=(0, 1), has_aux=True))(*args)
     (_, want), wanted = jax.jit(jax.value_and_grad(
-        plain, argnums=(0, 1, 2), has_aux=True))(*args)
+        plain, argnums=(0, 1), has_aux=True))(*args)
     fwd = l2_err(out, want)
-    bwd = max(l2_err(a, w) for a, w in zip(grads, wanted))
+    bwd = max(l2_err(a, w) for a, w in zip(jax.tree.leaves(grads),
+                                           jax.tree.leaves(wanted)))
+    name = "routed_experts_%s_%s" % (form, score)
     check(int(counts.sum()) > 0, "no pick fell on a held expert")
-    check(fwd < 3e-2, "routed relu2 experts forward error %g" % fwd)
-    check(bwd < 3e-2, "routed relu2 experts backward error %g" % bwd)
-    return {"case": "routed_experts_relu2_sigmoid", "plan": "slab_and_blocks",
-            "load": [int(c) for c in counts], "fwd_err": fwd, "bwd_err": bwd}
+    check(fwd < 3e-2, "%s forward error %g" % (name, fwd))
+    check(bwd < 3e-2, "%s backward error %g" % (name, bwd))
+    check(plan.kernel == "grouped_rows", "%s: %s" % (name, plan.describe()))
+    return {"case": name, "plan": plan.kernel, "block_rows": rows,
+            "load": [int(c) for c in counts],
+            "moe.rows": [int(r) for r in moe.table_rows(counts, rows)],
+            "fwd_err": fwd, "bwd_err": bwd}
 
 
 def phase_kernels(ctx):
@@ -719,8 +746,9 @@ def phase_kernels(ctx):
     log("kernels: %s" % cases[-1])
     cases.append(_ssd_case(ctx, **cfg["ssd"]))
     log("kernels: %s" % cases[-1])
-    cases.append(_experts_case(ctx, **cfg["experts"]))
-    log("kernels: %s" % cases[-1])
+    for case in cfg["experts"]:
+        cases.append(_experts_case(ctx, **case))
+        log("kernels: %s" % cases[-1])
     return {"cases": cases}
 
 
@@ -1059,9 +1087,10 @@ def main():
         # (the mesh rehearsal keeps the gates honest instead: interpret
         # mode would put kernels under the mesh that the chip never sees)
         from paddle_tpu.ops import (flash_attention, fused_ce, fused_conv,
-                                    scatter)
+                                    grouped_experts, scatter)
 
-        for mod in (flash_attention, fused_ce, fused_conv, scatter):
+        for mod in (flash_attention, fused_ce, fused_conv, grouped_experts,
+                    scatter):
             mod._INTERPRET = True
 
     ctx = {

@@ -888,15 +888,19 @@ def _routed_experts(env, op):
     input ``RouterBias`` [num_experts] added for the choice alone), top-k
     with renormalised weights times ``scale``, the held experts' products
     (attr ``form``: ``swiglu`` with ExpertGate/Up/Down, ``relu2`` with
-    ExpertUp/Down) in a static pass and binned blocks (no token dropped),
-    plus the shared expert of the same form (behind a sigmoid gate where
+    ExpertUp/Down) over one expert-sorted table of the rows that hold a
+    token (no token dropped; the ``grouped_experts`` kernels on a single
+    TPU, a ``jnp`` block loop elsewhere: the decision is noted), plus the
+    shared expert of the same form (behind a sigmoid gate where
     ``SharedExpertGate`` is given). With input ``ExpertX`` the experts read
     and write that (a latent of X, their own width) while the router and the
     shared expert read X; the shared expert then goes to output
     ``SharedOut`` (X's width) and not into ``Out`` (ExpertX's). ``Load``
-    [held] int32: the tokens each held expert took in this step, a
-    persistable counter written inside the step."""
-    from ...ops.gates import GateDecision, GateReason, note
+    [held] int32: the tokens each held expert took in this step; ``Rows``
+    [2] int32: the table's rows that hold a token and the rows the products
+    ran over; persistable counters written inside the step."""
+    from ...ops import grouped_experts
+    from ...ops.gates import note
     from ...parallel import moe
     from ..op_registry import mxu_cast
 
@@ -910,22 +914,17 @@ def _routed_experts(env, op):
     lo = int(op.attr("first_expert", 0))
     tokens = int(np.prod(x.shape[:-1]))
     rows = moe.block_rows_for(tokens * top_k)
-    note("routed_experts", GateDecision(True, "slab_and_blocks", reasons=[
-        GateReason("shape", "%d of %d experts held, %d assignments: a "
-                   "static pass of %d rows an expert, beyond it blocks of "
-                   "%d rows under a dynamic trip count; no capacity factor"
-                   % (wu.shape[0], router.shape[1], tokens * top_k,
-                      moe.slab_rows_for(tokens * top_k, router.shape[1],
-                                        rows), rows), blocking=False)]))
+    plan = note("routed_experts", grouped_experts.plan_for(
+        (wu, wd) if wg is None else (wg, wu, wd), rows))
     xc = mxu_cast(x)
     latent = get(env, op.input("ExpertX"))
     routed, counts = moe.routed_experts(
         xc if latent is None else mxu_cast(latent), router, wg, wu, wd,
-        top_k, lo, renormalize=op.attr("norm_topk_prob", True), form=form,
-        score=op.attr("score", "softmax"),
+        top_k, lo, renormalize=op.attr("norm_topk_prob", True),
+        block_rows=rows, form=form, score=op.attr("score", "softmax"),
         bias=get(env, op.input("RouterBias")),
         scale=float(op.attr("scale", 1.0)),
-        router_x=None if latent is None else xc)
+        router_x=None if latent is None else xc, plan=plan)
     out = routed
     su = get(env, op.input("SharedUp"))
     if su is not None:
@@ -939,16 +938,19 @@ def _routed_experts(env, op):
             return jnp.matmul(a.astype(w.dtype), w,
                               preferred_element_type=f32)
 
-        if form == "relu2":
-            h = jnp.square(jax.nn.relu(mm(xc, su)))
-        else:
-            h = jax.nn.silu(mm(xc, sg)) * mm(xc, su)
-        shared = mm(h, sd)
-        if gate_w is not None:
-            shared = shared * jax.nn.sigmoid(mm(xc, gate_w))
+        with jax.named_scope("moe.shared"):
+            if form == "relu2":
+                h = jnp.square(jax.nn.relu(mm(xc, su)))
+            else:
+                h = jax.nn.silu(mm(xc, sg)) * mm(xc, su)
+            shared = mm(h, sd)
+            if gate_w is not None:
+                shared = shared * jax.nn.sigmoid(mm(xc, gate_w))
         if op.output("SharedOut") is not None:
             put(env, op.output("SharedOut"), shared.astype(x.dtype))
         else:
             out = out + shared
     put(env, op.output("Out"), out.astype(x.dtype))
     put(env, op.output("Load"), counts)
+    if op.output("Rows") is not None:
+        put(env, op.output("Rows"), moe.table_rows(counts, rows))

@@ -990,6 +990,8 @@ def _routed_experts_shape(ctx, op):
     rs = ctx.shape(op.input("Router"))
     if us is not None:
         ctx.set(op.output("Load"), (us[0],), np.dtype("int32"))
+    if op.output("Rows") is not None:
+        ctx.set(op.output("Rows"), (2,), np.dtype("int32"))
     form = op.attr("form", "swiglu")
     if (op.input("ExpertGate") is None) != (form == "relu2"):
         raise ShapeError("routed_experts: form %r takes %s ExpertGate"

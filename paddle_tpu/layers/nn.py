@@ -1863,7 +1863,9 @@ def routed_experts(x, num_experts, top_k, moe_intermediate_size,
     form, that many units wide (those this chip holds), behind a sigmoid
     gate if ``shared_gate``. Returns ``(out, load)``; ``load`` [count] int32
     is a persistable counter of the tokens each held expert took in the
-    last step. Parameters: ``<name>.router`` (``.router_bias``),
+    last step, and ``<name>.rows`` [2] int32 one of the rows that held a
+    token and the rows the experts' products ran over. Parameters:
+    ``<name>.router`` (``.router_bias``),
     ``<name>.experts.{gate,up,down}`` ([count, out, in], the published
     per-expert layout; no ``gate`` for ``relu2``),
     ``<name>.shared.{gate_proj,up_proj,down_proj}``, ``<name>.shared_gate``,
@@ -1929,6 +1931,11 @@ def routed_experts(x, num_experts, top_k, moe_intermediate_size,
         name=_named(name, "load"))
     load.stop_gradient = True
     outputs["Load"] = load
+    rows = tensor.create_global_var(
+        shape=[2], value=0, dtype="int32", persistable=True,
+        name=_named(name, "rows"))
+    rows.stop_gradient = True
+    outputs["Rows"] = rows
     helper.append_op("routed_experts", inputs, outputs, attrs)
     if latent_size:
         out = _projection(helper, out, d, _named(name, "latent_up"))

@@ -184,9 +184,17 @@ def _one_hot_routed(x, router, bias, up, down, lo, scale, router_x=None):
     return out, picks
 
 
-@pytest.mark.parametrize("slab", [1, None], ids=["slab1", "auto"])
+@pytest.mark.parametrize("way", ["blocks_xla", "grouped_rows"])
 @pytest.mark.parametrize("lo,held", [(0, 8), (2, 4)])
-def test_sigmoid_routed_relu2_experts_against_a_one_hot_form(lo, held, slab):
+def test_sigmoid_routed_relu2_experts_against_a_one_hot_form(lo, held, way,
+                                                             monkeypatch):
+    """Both ways to multiply the table: the ``jnp`` block loop, and the
+    ``grouped_experts`` kernels in interpret mode."""
+    from paddle_tpu.ops import grouped_experts
+    from paddle_tpu.ops.gates import GateDecision
+
+    monkeypatch.setattr(grouped_experts, "_INTERPRET", True)
+    plan = GateDecision(way == "grouped_rows", way)
     rng = np.random.default_rng(7)
     x = jnp.asarray(_randn(rng, 37, L))
     router_x = jnp.asarray(_randn(rng, 37, D))
@@ -203,9 +211,9 @@ def test_sigmoid_routed_relu2_experts_against_a_one_hot_form(lo, held, slab):
 
     def ours(x, router_x, router, up, down):
         return moe.routed_experts(
-            x, router, None, up, down, K, lo, block_rows=4, slab_rows=slab,
-            form="relu2", score="sigmoid", bias=bias, scale=2.5,
-            router_x=router_x)
+            x, router, None, up, down, K, lo, block_rows=4, form="relu2",
+            score="sigmoid", bias=bias, scale=2.5, router_x=router_x,
+            plan=plan)
 
     got, counts = ours(x, router_x, router, up, down)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
@@ -487,7 +495,7 @@ def test_compile_record_shapes_and_costs_of_the_new_sites():
             fetch_list=[spec.loss], scope=scope)
     gates = [r["gates"] for r in exe.compile_records if r.get("gates")][-1]
     assert any("chunked_jnp" in line for line in gates["mamba2_ssd"])
-    assert any("slab_and_blocks" in line
+    assert any("blocks_xla" in line
                for line in gates["routed_experts"])
     assert "flash_attention" in gates
 

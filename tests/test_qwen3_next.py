@@ -326,58 +326,188 @@ def _dense_routed(x, router, wg, wu, wd, lo):
     return out
 
 
-@pytest.mark.parametrize("slab", [1, 8, None],
-                         ids=["slab1", "slab8", "slab_default"])
+def _grads_match(ours, dense, args, cot, rtol=2e-3, atol=2e-5):
+    wanted = jax.grad(lambda *a: jnp.sum(dense(*a) * cot),
+                      argnums=tuple(range(len(args))))(*args)
+    gotten = jax.grad(lambda *a: jnp.sum(ours(*a)[0] * cot),
+                      argnums=tuple(range(len(args))))(*args)
+    for a, w in zip(gotten, wanted):
+        np.testing.assert_allclose(a, w, rtol=rtol, atol=atol)
+
+
+@pytest.mark.parametrize("way", ["blocks_xla", "grouped_rows"])
+@pytest.mark.parametrize("rows", [4, 8], ids=["rows4", "rows8"])
 @pytest.mark.parametrize("lo,held", [(0, 8), (2, 4), (6, 2)])
-def test_routed_experts_against_a_dense_loop(lo, held, slab):
-    """Against the dense loop, forward and gradients, with an expert's 14
-    or so rows nearly all in the blocks (slab of 1 row), split between its
-    slab and the blocks (8), and all in its slab (the default, 64)."""
+def test_routed_experts_against_a_dense_loop(lo, held, rows, way,
+                                             monkeypatch):
+    """Against the dense loop, forward and gradients, both ways to multiply
+    the one table (the ``jnp`` block loop; the ``grouped_experts`` kernels
+    in interpret mode), an expert's 14 or so rows in blocks of 4 and of
+    8."""
+    from paddle_tpu.ops import grouped_experts
+    from paddle_tpu.ops.gates import GateDecision
+
+    monkeypatch.setattr(grouped_experts, "_INTERPRET", True)
+    plan = GateDecision(way == "grouped_rows", way)
     rng = np.random.default_rng(7)
     x = jnp.asarray(_randn(rng, 37, D))
     router = jnp.asarray(_randn(rng, D, E))
     wg, wu, wd = _expert_weights(rng, held)
     want = _dense_routed(x, router, wg, wu, wd, lo)
-    got, counts = moe.routed_experts(x, router, wg, wu, wd, K, lo,
-                                     block_rows=4, slab_rows=slab)
+
+    def ours(*a):
+        return moe.routed_experts(*a, K, lo, block_rows=rows, plan=plan)
+
+    got, counts = ours(x, router, wg, wu, wd)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     _, picks = moe.route_topk(x, router, K)
     np.testing.assert_array_equal(
         counts, [(np.asarray(picks) == lo + e).sum() for e in range(held)])
-    cot = _randn(rng, *want.shape)
-    wanted = jax.grad(lambda *a: jnp.sum(_dense_routed(*a, lo) * cot),
-                      argnums=(0, 1, 2, 3, 4))(x, router, wg, wu, wd)
-    gotten = jax.grad(lambda *a: jnp.sum(moe.routed_experts(
-        *a, K, lo, block_rows=4, slab_rows=slab)[0] * cot),
-        argnums=(0, 1, 2, 3, 4))(x, router, wg, wu, wd)
-    for a, w in zip(gotten, wanted):
+    _grads_match(ours, lambda *a: _dense_routed(*a, lo),
+                 (x, router, wg, wu, wd), _randn(rng, *want.shape))
+
+
+def _sent_to(tokens, held_picks, experts, rng):
+    """x > 0 [tokens, D] and a router over ``experts`` that sends every
+    token's first picks to ``held_picks`` (in that order of preference)."""
+    x = jnp.asarray(np.abs(_randn(rng, tokens, D)) + 0.1)
+    router = np.asarray(0.01 * _randn(rng, D, experts))
+    for rank, e in enumerate(held_picks):
+        router[:, e] += 1.0 - 0.1 * rank
+    return x, jnp.asarray(router)
+
+
+# load patterns at the edges of the layout; (experts, first held, held, the experts
+# every token picks first, tokens, rows of a block)
+_LOADS = {
+    # held expert 0 of this share takes nothing at all
+    "an_expert_with_no_token": (8, 2, 2, (3, 5, 6), 50, 4),
+    # one held expert takes every token, the other its leftovers
+    "every_token_on_one_expert": (8, 2, 2, (3,), 50, 4),
+    # 48 tokens in blocks of 8: six whole blocks, no padding ...
+    "an_exact_multiple_of_the_block": (8, 2, 2, (3,), 48, 8),
+    # ... and 49: one row in a seventh
+    "one_more_than_a_multiple": (8, 2, 2, (3,), 49, 8),
+    # 3 x 50 rows on three of four held experts of 32: four times the mean
+    # load is 75 rows, so the kernels' calls (whole experts, at most 96
+    # rows) are three
+    "past_one_calls_capacity": (32, 4, 4, (4, 6, 7), 50, 4),
+}
+
+
+@pytest.mark.parametrize("way", ["blocks_xla", "grouped_rows"])
+@pytest.mark.parametrize("load", sorted(_LOADS))
+def test_load_patterns_drop_nothing_either_way(load, way, monkeypatch):
+    from paddle_tpu.ops import grouped_experts
+    from paddle_tpu.ops.gates import GateDecision
+
+    monkeypatch.setattr(grouped_experts, "_INTERPRET", True)
+    experts, lo, held, picked, tokens, rows = _LOADS[load]
+    rng = np.random.default_rng(11)
+    x, router = _sent_to(tokens, picked, experts, rng)
+    wg, wu, wd = _expert_weights(rng, held)
+    plan = GateDecision(way == "grouped_rows", way)
+
+    def ours(*a):
+        return moe.routed_experts(*a, K, lo, block_rows=rows, plan=plan)
+
+    got, counts = jax.jit(ours)(x, router, wg, wu, wd)
+    want = _dense_routed(x, router, wg, wu, wd, lo)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    for e in picked:
+        if lo <= e < lo + held:
+            assert int(counts[e - lo]) == tokens
+    _grads_match(ours, lambda *a: _dense_routed(*a, lo),
+                 (x, router, wg, wu, wd), _randn(rng, *want.shape))
+    table = moe.bin_assignments(
+        moe.route_topk(x, router, K)[1], lo, held, rows,
+        moe.call_rows_for(tokens * K, experts, held, tokens, rows))
+    np.testing.assert_array_equal(table.counts, counts)
+    if load == "an_expert_with_no_token":
+        assert int(counts[0]) == 0
+    if load == "past_one_calls_capacity":
+        assert int(table.calls) == 3
+    else:
+        assert int(table.calls) == 1
+    if load == "an_exact_multiple_of_the_block":
+        assert int(moe.table_rows(counts, rows)[1]) - int(counts[0]) == 48
+    if load == "one_more_than_a_multiple":
+        assert int(moe.table_rows(counts, rows)[1]) \
+            - -(-int(counts[0]) // rows) * rows == 56
+
+
+def test_kernels_tile_the_experts_width_and_the_columns_under_a_small_budget(
+        monkeypatch):
+    """Where the matrices do not fit VMEM the kernels walk ``F`` in tiles
+    (the backward here in two, the forward whole) and ``combine`` the
+    columns of the sum in tiles (two): the same results as the block
+    loop, forward and every gradient."""
+    from paddle_tpu.ops import grouped_experts
+    from paddle_tpu.ops.gates import GateDecision
+
+    monkeypatch.setattr(grouped_experts, "_INTERPRET", True)
+    d, f, held, rows = 256, 256, 4, 8
+    rng = np.random.default_rng(12)
+    x = jnp.asarray(_randn(rng, 37, d))
+    router = jnp.asarray(_randn(rng, d, E))
+    mats = tuple(jnp.asarray(0.1 * _randn(rng, *s))
+                 for s in ((held, f, d), (held, f, d), (held, d, f)))
+    cot = _randn(rng, 37, d)
+
+    def run(way):
+        def ours(*a):
+            return moe.routed_experts(*a, K, 2, block_rows=rows,
+                                      plan=GateDecision(way, "forced"))
+        return (ours(x, router, *mats)[0],) + jax.grad(
+            lambda *a: jnp.sum(ours(*a)[0] * cot),
+            argnums=(0, 1, 2, 3, 4))(x, router, *mats)
+
+    want = run(False)
+    monkeypatch.setattr(grouped_experts, "_VMEM_BUDGET", 2 * 1024 * 1024)
+    assert grouped_experts.f_tile("fwd", 3, d, f, rows, 4) == f
+    assert grouped_experts.f_tile("bwd", 3, d, f, rows, 4) == f // 2
+    tiled_f = run(True)
+    monkeypatch.setattr(grouped_experts, "_VMEM_BUDGET", 64 * 1024)
+    assert grouped_experts.column_tile(37, d, 1, rows) == d // 2
+    sums = jnp.asarray(_randn(rng, 37, d))
+    parts = jnp.asarray(_randn(rng, 1, 4 * rows, d))
+    tokens = jnp.asarray(rng.integers(0, 38, 4 * rows), jnp.int32)  # 37: empty
+    np.testing.assert_allclose(
+        grouped_experts.combine(tokens, jnp.int32(3), parts, sums, rows),
+        sums.at[tokens[:3 * rows]].add(parts[0, :3 * rows], mode="drop"),
+        rtol=1e-6, atol=1e-6)
+    for a, w in zip(tiled_f, want):
         np.testing.assert_allclose(a, w, rtol=2e-3, atol=2e-5)
 
 
 def test_every_token_to_one_held_expert_drops_nothing():
     """The worst case for a capacity: every token's first pick is the same
-    held expert. Its counter reads every token and the result is the dense
-    loop's."""
+    held expert. Its counter reads every token, the result is the dense
+    loop's, and the table holds every one of its rows, block after block,
+    sized for every pick of every token being held."""
     rng = np.random.default_rng(8)
     tokens = 50
-    x = jnp.asarray(np.abs(_randn(rng, tokens, D)) + 0.1)
-    router = np.asarray(0.01 * _randn(rng, D, E))
-    router[:, 3] += 1.0                     # positive x: expert 3 wins
-    router = jnp.asarray(router)
+    x, router = _sent_to(tokens, (3,), E, rng)
     wg, wu, wd = _expert_weights(rng, 2)
-    rows = moe.block_rows_for(tokens * K)
     got, counts = jax.jit(lambda *a: moe.routed_experts(
-        *a, K, 2, slab_rows=rows))(x, router, wg, wu, wd)
+        *a, K, 2))(x, router, wg, wu, wd)
     assert int(counts[1]) == tokens
     np.testing.assert_allclose(
         got, _dense_routed(x, router, wg, wu, wd, 2), rtol=1e-4, atol=1e-5)
-    # the expert's slab is full, the rest of its tokens are in the blocks,
-    # and the table is sized for every pick of every token being held
-    slab, table, _, blocks, _ = moe.bin_assignments(
-        moe.route_topk(x, router, K)[1], 2, 2, rows, rows)
-    assert int((np.asarray(slab[1]) < tokens * K).sum()) == rows
-    assert int(blocks) * rows >= tokens - rows
-    assert table.shape[0] >= tokens * K
+    rows = moe.block_rows_for(tokens * K)
+    call_rows = moe.call_rows_for(tokens * K, E, 2, tokens, rows)
+    table = moe.bin_assignments(moe.route_topk(x, router, K)[1], 2, 2, rows,
+                                call_rows)
+    held_rows = np.asarray(table.row_assign) < tokens * K
+    assert int(held_rows.sum()) == int(counts.sum())
+    first = -(-int(counts[0]) // rows) * rows      # expert 3's first row
+    assert held_rows[first:first + tokens].all()
+    assert (np.asarray(table.block_expert)[
+        first // rows:(first + tokens) // rows] == 1).all()
+    assert int(table.blocks) * rows >= int(counts.sum())
+    assert table.row_assign.shape[0] >= tokens * K
+    assert int(moe.table_rows(counts, rows)[0]) == int(counts.sum())
+    assert call_rows >= tokens          # one expert always fits one call
 
 
 def test_shares_of_a_layer_add_up_to_the_uncut_reference():
@@ -660,7 +790,7 @@ def test_compile_record_names_the_new_sites_decisions():
     gates = [r["gates"] for r in exe.compile_records if r.get("gates")][-1]
     assert any("chunked_scan_xla" in line
                for line in gates["gated_delta_rule"])
-    assert any("slab_and_blocks" in line
+    assert any("blocks_xla" in line
                for line in gates["routed_experts"])
     assert "flash_attention" in gates
 

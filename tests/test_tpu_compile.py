@@ -177,8 +177,11 @@ def test_gated_delta_core_and_routed_experts_fit_at_published_widths(chip):
     runs as the two ``gated_delta`` kernels, whose in-chunk tensors never
     leave VMEM, and keeps under 0.5 GB of temporaries (2.9 GB as one
     stacked ``jnp`` form, 1 GB with its groups of chunks recomputed); and
-    the binned expert blocks (16 of 512 experts, top-10) under 0.5 GB:
-    what lets four layers fit one chip."""
+    the routed experts (16 of 512, top-10) run as the ``grouped_experts``
+    kernels (forward, backward, and ``combine`` after each) over one call's
+    gathered rows, under 0.7 GB (the float32 weight gradients 0.2, a call's
+    row buffers and partial outputs the rest): what lets four layers fit
+    one chip."""
     from paddle_tpu.ops import gated_delta
     from paddle_tpu.parallel import moe
 
@@ -204,20 +207,24 @@ def test_gated_delta_core_and_routed_experts_fit_at_published_widths(chip):
     def experts(x, router, wg, wu, wd):
         return jnp.sum(moe.routed_experts(x, router, wg, wu, wd, 10, 0)[0])
 
+    # the value too: the gradient alone does not need the forward kernel
     compiled = _compile(
-        chip, jax.grad(experts, argnums=(0, 1, 2, 3, 4)),
+        chip, jax.value_and_grad(experts, argnums=(0, 1, 2, 3, 4)),
         sds((t, 2048), BF16), sds((2048, 512), F32),
         sds((16, 512, 2048), BF16), sds((16, 512, 2048), BF16),
         sds((16, 2048, 512), BF16))
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
+    assert _kernel_calls(compiled) == 4
+    _assert_named(compiled, _EXPERT_KERNELS)
 
 
 def test_state_space_core_and_latent_experts_fit_at_published_widths(chip):
     """``nemotron3super.train.s8192``'s share of a layer: the Mamba-2 scan
     (16 heads of 64 x 128 state, one group, T = 8192, chunks of 128) keeps
     under 0.5 GB of temporaries with its groups of chunks recomputed, and
-    the binned ReLU-squared experts in the 1024-wide latent (8 of 512,
-    top-22, router at 4096) under 0.5 GB."""
+    the ReLU-squared experts in the 1024-wide latent (8 of 512, top-22,
+    router at 4096) run as the ``grouped_experts`` kernels under 0.7
+    GB."""
     from paddle_tpu.ops import mamba2
     from paddle_tpu.parallel import moe
 
@@ -241,11 +248,78 @@ def test_state_space_core_and_latent_experts_fit_at_published_widths(chip):
             bias=bias, scale=5.0, router_x=h)[0])
 
     compiled = _compile(
-        chip, jax.grad(experts, argnums=(0, 1, 2, 4, 5)),
+        chip, jax.value_and_grad(experts, argnums=(0, 1, 2, 4, 5)),
         sds((t, 1024), BF16), sds((t, 4096), BF16), sds((4096, 512), F32),
         sds((512,), F32), sds((8, 2688, 1024), BF16),
         sds((8, 1024, 2688), BF16))
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
+    assert _kernel_calls(compiled) == 4
+    _assert_named(compiled, _EXPERT_KERNELS)
+
+
+_EXPERT_KERNELS = {"grouped_experts.fwd", "grouped_experts.bwd",
+                   "grouped_experts.combine"}
+
+# (form, held, D, F, assignments, tokens) of the two cells' expert layers
+_EXPERT_CASES = {
+    "nemotron3super": ("relu2", 8, 1024, 2688, 8192 * 22, 8192),
+    "qwen3next": ("swiglu", 16, 2048, 512, 8192 * 10, 8192),
+}
+
+
+@pytest.mark.parametrize("part", ["fwd", "bwd", "combine"])
+@pytest.mark.parametrize("cell", sorted(_EXPERT_CASES))
+def test_grouped_experts_compile_within_the_vmem_the_gate_counts(
+        chip, cell, part):
+    """Each ``grouped_experts`` kernel at each hybrid cell's published
+    widths, with the tile rows, ``F`` tile and call size the program takes
+    there and ``vmem_limit_bytes`` set to the working set its gate counts:
+    Mosaic needs no more than is counted, the count is under the budget,
+    and the gate admits the shape. The grid's bound is a traced scalar."""
+    from paddle_tpu.ops import grouped_experts as ge
+    from paddle_tpu.parallel import moe
+
+    form, held, d, f, assignments, tokens = _EXPERT_CASES[cell]
+    n = 2 if form == "relu2" else 3         # matrices an expert
+    rows = moe.block_rows_for(assignments)
+    call_rows = moe.call_rows_for(assignments, 512, held, tokens, rows)
+    with placed("tpu"):
+        plan = ge.kernel_plan(n, d, f, rows, 2, platform=platform_reason())
+    assert plan.kernel == "grouped_rows", plan
+    if part == "combine":       # of the backward's partial sums of dx
+        parts = f // ge.f_tile("bwd", n, d, f, rows, 2)
+        dc = ge.column_tile(tokens, d, parts, rows)
+        counted = ge._combine_working_set(tokens, dc, parts, rows)
+        assert counted <= ge._VMEM_BUDGET
+        compiled = _compile(
+            chip, lambda tok, tiles, addends, sums: ge._combine_impl(
+                tok, tiles, addends, sums, rows=rows, dc=dc, vmem=counted,
+                interpret=False),
+            sds((call_rows,), I32), sds((), I32),
+            sds((parts, call_rows, d), F32), sds((tokens, d), F32))
+        _assert_named(compiled, {"grouped_experts.combine"})
+        return
+    tf = ge.f_tile(part, n, d, f, rows, 2)
+    counted = ge._working_set(part, n, d, tf, rows, 2)
+    assert counted <= ge._VMEM_BUDGET
+    mats = tuple([sds((held, f, d), BF16)] * (n - 1)
+                 + [sds((held, d, f), BF16)])
+    table = (sds((assignments // rows + held,), I32), sds((1,), I32),
+             sds((), I32))
+    x, w = sds((call_rows, d), BF16), sds((call_rows, 1), F32)
+    statics = dict(form=form, rows=rows, tf=tf, vmem=counted,
+                   interpret=False)
+    if part == "fwd":
+        compiled = _compile(
+            chip, lambda be, at, tiles, x, w, mats: ge._fwd_impl(
+                be, at, tiles, x, w, mats, **statics), *table, x, w, mats)
+    else:
+        grads = tuple(sds(m.shape, F32) for m in mats)
+        compiled = _compile(
+            chip, lambda be, at, tiles, x, dy, w, mats, grads: ge._bwd_impl(
+                be, at, tiles, x, dy, w, mats, grads, **statics),
+            *table, x, x, w, mats, grads)
+    _assert_named(compiled, {"grouped_experts." + part})
 
 
 @contextlib.contextmanager
@@ -456,6 +530,17 @@ def _conv_infer():
                    ch, ch, ch, ch)
 
 
+def _experts_grad():
+    from paddle_tpu.parallel import moe
+
+    def experts(x, router, wg, wu, wd):
+        return jnp.sum(moe.routed_experts(x, router, wg, wu, wd, 2, 0)[0])
+
+    return jax.value_and_grad(experts, argnums=(0, 2, 3, 4)), (
+        sds((1024, 128), BF16), sds((128, 8), F32), sds((4, 256, 128), BF16),
+        sds((4, 256, 128), BF16), sds((4, 128, 256), BF16))
+
+
 _NAME_CASES = [
     # family, (function, abstract arguments), the names its calls carry;
     # small shapes: a name does not depend on the size
@@ -466,6 +551,7 @@ _NAME_CASES = [
     ("head_split_stream", lambda: _attention_grad(1, 4096, 512, 8),
      {"head_split_stream.fwd", "head_split_stream.bwd"}),
     ("fused_conv_infer", _conv_infer, {"fused_conv.infer"}),
+    ("grouped_experts", lambda: _experts_grad(), _EXPERT_KERNELS),
 ]
 
 
