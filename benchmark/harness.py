@@ -212,10 +212,13 @@ def profile(directory, body):
 
 
 def emit(run, result):
-    """Print the compared numbers, then the result line as the last line of
-    standard output."""
+    """Print the compared numbers, each beside its limit (on standard
+    output with their notes, and as the last lines of standard error), then
+    the result line as the last line of standard output, the compared
+    numbers under its last key."""
     units = run.metric_units()
-    for row in result.get("compared", []):
+    compared = result.get("compared", [])
+    for row in compared:
         print("compared %-34s value %.6g  limit %.6g  %s  %s" % (
             row["name"], row["value"], row["limit"],
             "ok" if row["ok"] else "OVER", row.get("note", "")))
@@ -247,6 +250,14 @@ def emit(run, result):
         line["breakdown"] = result["breakdown"]
     if run.rehearse:
         line["rehearsal"] = True
+    line["compared"] = {row["name"]: {"value": row["value"],
+                                      "limit": row["limit"]}
+                        for row in compared}
     sys.stdout.flush()
+    for row in compared:
+        sys.stderr.write("compared %s value %.6g limit %.6g %s\n" % (
+            row["name"], row["value"], row["limit"],
+            "ok" if row["ok"] else "OVER"))
+    sys.stderr.flush()
     print(json.dumps(line))
     sys.stdout.flush()

@@ -2,8 +2,8 @@
 the persistent cache loading its executable (span
 ``executor.backend_compile``; the record's ``persistent_cache`` says which).
 From the compile record the executor keeps of the training step's variant
-(``Executor.compile_records``, written in ``Executor._stage``); on the chip
-only."""
+(``Executor.compile_records``, written in ``Executor._stage``), or summed
+over the executables of a serving cell's ladders; on the chip only."""
 
 from benchmark import program_spans
 

@@ -3,8 +3,9 @@ time the chip could take for the operations and bytes the core requires in
 one step (``ops_count_qwen3_next.gated_delta_core_step``; the larger of
 operations over the bf16 peak and bytes over the HBM peak) over ``gdn_ms``.
 The triangular halves, the carried states and recomputation are not
-counted, so the share cannot pass 100%. It is the XLA-lowered chunk scan's
-share while the program has no kernel for it."""
+counted, so the share cannot pass 100%. Since PR 27 the core is the two
+Pallas kernels ``gated_delta.fwd`` / ``.bwd`` on the chip (the ``jnp`` chunk
+scan on the CPU and under a mesh); ``gdn_ms`` holds both."""
 
 import os
 

@@ -1,8 +1,8 @@
 """Program -> step: seconds of lowering that jaxpr to StableHLO, the Mosaic
 lowering of every Pallas kernel included (span ``executor.lower``). From the
 compile record the executor keeps of the training step's variant
-(``Executor.compile_records``, written in ``Executor._stage``); on the chip
-only."""
+(``Executor.compile_records``, written in ``Executor._stage``), or summed
+over the executables of a serving cell's ladders; on the chip only."""
 
 from benchmark import program_spans
 

@@ -1,8 +1,9 @@
-"""XLA-lowered ops: the optimizer update, per parameter or in the fused
-groups of core/opt_fusion.py. Device milliseconds a step: self time of the
-events under these op scopes, from the device trace."""
+"""XLA-lowered ops: the optimizer update, one op a parameter. Device
+milliseconds a step: self time of the events under these op scopes, from the
+device trace: only what XLA did not fuse into the gradient product that
+feeds an update (PERF.md, Blind spots)."""
 
-OP_TYPES = ('adam', 'momentum', 'fused_adam', 'fused_momentum')
+OP_TYPES = ('adam', 'momentum')
 
 
 def read(ctx):

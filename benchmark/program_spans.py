@@ -15,7 +15,8 @@ the innermost span that covers them), tested on ``tests/benchmark/recorded_progr
 Compile record. ``Executor.compile_records`` holds one dict a compiled
 variant: seconds of tracing, lowering and backend compile, whether the
 persistent cache served the executable, its ``memory_analysis()``, the
-gates' decisions. ``compile_record`` finds the training step's.
+gates' decisions. ``compile_record`` finds the training step's; a serving
+job hands the records of all its executables as ``ctx["compile_records"]``.
 
 A program without these (the parent of the PR that brought them) gives
 ``None`` everywhere: the metric is then left out of the line.
@@ -185,9 +186,14 @@ def compile_record(ctx):
 
 def compile_seconds(ctx, phase):
     """Seconds of one phase (``trace_s``, ``lower_s``,
-    ``backend_compile_s``) of the training step's compile, on the chip. A
-    rehearsal's are the CPU backend's and are not reported."""
+    ``backend_compile_s``) of the training step's compile, or, where a job
+    hands its own ``compile_records`` (serving: every executable of the
+    ladders), of them all together; on the chip. A rehearsal's are the CPU
+    backend's and are not reported."""
     if ctx["run"].devices[0].platform != "tpu":
         return None
+    if "compile_records" in ctx:
+        seconds = [r[phase] for r in ctx["compile_records"] if phase in r]
+        return sum(seconds) if seconds else None
     record = compile_record(ctx)
     return None if record is None else record.get(phase)

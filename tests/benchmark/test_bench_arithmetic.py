@@ -293,8 +293,9 @@ def test_manifest_names_only_files_that_exist_and_keeps_the_contract():
         body = harness.load_json(os.path.join(ROOT, c["file"]))
         assert body["reduced"] == c["reduced"]
         assert os.path.exists(os.path.join(ROOT, body["reference"]))
-        assert os.path.exists(os.path.join(
-            bench, "optimizers", body["optimizer"]["name"] + ".py"))
+        if "optimizer" in body:  # a served configuration trains nothing
+            assert os.path.exists(os.path.join(
+                bench, "optimizers", body["optimizer"]["name"] + ".py"))
         file, function = body["ops_count"].split(":")
         assert hasattr(harness.load_module(os.path.join(bench, file)),
                        function)

@@ -262,7 +262,7 @@ def test_manifest_holds_the_cell_and_the_catalogs_widths():
     for name in ("gdn_ms", "gdn_roofline", "moe_ms", "moe_expert_load_max",
                  "rms_norm_ms", "conv1d_ms"):
         metric, = [m for m in manifest["per_layer"] if m["name"] == name]
-        assert metric["workloads"] == [CELL]
+        assert CELL in metric["workloads"]
         assert metric["moves"] == "train_samples_per_s"
     roofline, = [m for m in manifest["per_layer"]
                  if m["name"] == "attn_roofline"]
