@@ -319,6 +319,7 @@ class Executor:
         # 2 = raising). A warn-mode pass must NOT suppress a later strict
         # verify=True of the same variant.
         self._verified = {}
+        self._reads = None  # (program key, names its ops read)
         self._last = None  # the variant of the last run (lowered_hlo_text)
         # One record a compiled variant, always on (it happens once a
         # variant): seconds of tracing, lowering and backend compile,
@@ -505,6 +506,13 @@ class Executor:
         persist_names = sorted({v.name for v in program.list_vars()
                                 if v.persistable})
         state_in_names = tuple(n for n in persist_names if n in scope)
+        if not donate_state:
+            # a persistable the program only WRITES (a counter an op leaves
+            # in the scope) is no input: as one it would enter the variant's
+            # key with its first write-back and stage the step a second time
+            read = self._read_names(program)
+            state_in_names = tuple(n for n in state_in_names
+                                   if n in read or n in fetch_names)
 
         # multi-host mesh (jax.distributed): each process feeds its LOCAL
         # batch shard (the reference's per-trainer reader semantics) and the
@@ -640,6 +648,18 @@ class Executor:
             feed_arrays = jax.device_put(feed_arrays, self._device)
             rng = jax.device_put(rng, self._device)
         return state, feed_arrays, rng, moved
+
+    def _read_names(self, program):
+        """Names some op of the program reads (in any block), kept a program
+        version."""
+        key = (id(program), program._version)
+        if self._reads is None or self._reads[0] != key:
+            names = set()
+            for block in program.blocks:
+                for op in block.ops:
+                    names.update(op.input_arg_names)
+            self._reads = (key, names)
+        return self._reads[1]
 
     def _platform(self):
         """Platform an un-meshed step runs on: the place's device, else

@@ -111,7 +111,9 @@ def _mamba2_ssd(env, op):
 @register("kv_cache_write")
 def _kv_cache_write(env, op):
     """Per-row cache update: Cache [B, C, ...], X [B, ...], Pos [B] ->
-    Out[b, Pos[b]] = X[b], other entries untouched. Each row writes ONLY
+    Out[b, Pos[b]] = X[b], other entries untouched. Any tail: a key or a
+    value row of H*D, a latent row ``[c | k_pe]``, an index key. Each row
+    writes ONLY
     its own slot — the property the continuous batcher's solo-vs-batched
     bitwise-parity guarantee rests on (a dead slot's garbage write cannot
     leak into a live row). Out-of-range positions drop (a retired slot fed
@@ -200,6 +202,85 @@ def _cached_attention_chunk(env, op):
         q.dtype)
     ctx = jnp.einsum("bhqc,bchd->bqhd", probs, vh)
     put(env, op.output("Out"), ctx.reshape(b, kq, hd))
+
+
+@register("sparse_index")
+def _sparse_index(env, op):
+    """The indexer of a decode step (``ops/sparse_latent.py``): Q [B, H*D]
+    index queries, W [B, H] a weight a head, CacheK [B, C, D] the cached
+    index keys with this step's written, Pos [B]. ``I[b, s] = sum_j W[b, j]
+    relu(Q[b, j] . CacheK[b, s])`` for ``s <= Pos[b]`` in float32, then the
+    exact ``top_k`` largest. Index [B, min(top_k, C)] int32: the positions
+    row b attends, ``C`` (past the cache) where it has fewer. Count [2]
+    int32: positions selected and positions cached, summed over the rows.
+    Strictly per-row."""
+    from ...ops import sparse_latent
+
+    index, count = sparse_latent.sparse_index(
+        get(env, op.input("Q")), get(env, op.input("W")),
+        get(env, op.input("CacheK")), get(env, op.input("Pos")),
+        int(op.attr("num_heads")), int(op.attr("top_k")))
+    put(env, op.output("Index"), index)
+    put(env, op.output("Count"), count)
+
+
+@register("sparse_index_chunk")
+def _sparse_index_chunk(env, op):
+    """The indexer of a chunk: Q [B, K, H*D], W [B, K, H], CacheK [B, C, D]
+    with the chunk's keys written, Pos [B, K] (``C`` on a pad lane). The
+    same scores and the same exact top-k a query; the set is handed on as
+    Mask [B, K, C] bool (lane j of row b attends position s where it is
+    set), so that what reads it walks the cache in blocks and gathers
+    nothing. Count [2] int32 as ``sparse_index``'s, over the live lanes.
+    Strictly per-row; a row of pad lanes costs no block."""
+    from ...ops import sparse_latent
+
+    mask, count = sparse_latent.sparse_index_chunk(
+        get(env, op.input("Q")), get(env, op.input("W")),
+        get(env, op.input("CacheK")), get(env, op.input("Pos")),
+        int(op.attr("num_heads")), int(op.attr("top_k")))
+    put(env, op.output("Mask"), mask)
+    put(env, op.output("Count"), count)
+
+
+def _latent_attrs(op):
+    return (int(op.attr("num_heads")), int(op.attr("nope_dim")),
+            int(op.attr("v_dim")), float(op.attr("scale")))
+
+
+@register("latent_attention")
+def _latent_attention(env, op):
+    """One-token latent (MLA) attention over an index set, absorbed form
+    (``ops/sparse_latent.py``): Q [B, H*(N+P)] a head's no-position and
+    rotary parts, KvB [R, H*(N+V)] the up-projection of the latent, Cache
+    [B, C, R+P] ONE row ``[latent | rotary key]`` a position with this
+    step's written, Index [B, S] int32 from ``sparse_index`` (``>= C``:
+    none). Scores ``(q_nope W_uk^T . c + q_pe . k_pe) * scale`` in float32,
+    softmax over the set alone, the mixed latent times ``W_uv``. Out
+    [B, H*V]: the key/query width N+P, the cached width R+P and the value
+    width V all differ. Strictly per-row."""
+    from ...ops import sparse_latent
+
+    put(env, op.output("Out"), sparse_latent.latent_attention(
+        get(env, op.input("Q")), get(env, op.input("KvB")),
+        get(env, op.input("Cache")), get(env, op.input("Index")),
+        *_latent_attrs(op)))
+
+
+@register("latent_attention_chunk")
+def _latent_attention_chunk(env, op):
+    """K-query latent attention under ``sparse_index_chunk``'s Mask
+    [B, K, C]: Q [B, K, H*(N+P)], KvB, Cache [B, C, R+P] with the chunk's
+    rows written, Pos [B, K]. The step op's numerics applied K times, a
+    row's cache read in blocks up to its highest live position under a
+    streaming softmax: neither [K, H, C] scores nor a [K, S, R+P] gather
+    exist. Out [B, K, H*V], 0 on a pad lane. Strictly per-row."""
+    from ...ops import sparse_latent
+
+    put(env, op.output("Out"), sparse_latent.latent_attention_chunk(
+        get(env, op.input("Q")), get(env, op.input("KvB")),
+        get(env, op.input("Cache")), get(env, op.input("Mask")),
+        get(env, op.input("Pos")), *_latent_attrs(op)))
 
 
 @register("sampling_id")

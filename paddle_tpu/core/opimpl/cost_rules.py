@@ -463,6 +463,78 @@ def _cached_attention_chunk_cost(ctx, op):
             hbm_bytes=(2 * b * cap * hd + 2 * b * kq * hd) * e)
 
 
+def _index_cost(ctx, op, lanes_of):
+    qs, ks = ctx.shape(op.input("Q")), ctx.shape(op.input("CacheK"))
+    if qs is None or ks is None or -1 in qs or -1 in ks:
+        ctx.add(op, unresolved=True)
+        return
+    b, cap, d = ks
+    lanes = lanes_of(qs)
+    e, ek = ctx.esize(op.input("Q")), ctx.esize(op.input("CacheK"))
+    # every cached index key (in the type it is cached in) against every
+    # index head of every query (the bucket's capacity: a static rule cannot
+    # know the fill), then the selection's passes over the float32 scores
+    ctx.add(op, flops=2.0 * lanes * cap * qs[-1] + 32.0 * lanes * cap,
+            hbm_bytes=b * cap * d * ek + lanes * qs[-1] * e
+            + 4 * lanes * cap)
+
+
+@register_cost("sparse_index")
+def _sparse_index_cost(ctx, op):
+    _index_cost(ctx, op, lambda qs: qs[0])
+
+
+@register_cost("sparse_index_chunk")
+def _sparse_index_chunk_cost(ctx, op):
+    _index_cost(ctx, op, lambda qs: qs[0] * qs[1])
+
+
+def _latent_cost(ctx, op, lanes_of):
+    qs, cs = ctx.shape(op.input("Q")), ctx.shape(op.input("Cache"))
+    bs = ctx.shape(op.input("KvB"))
+    if qs is None or cs is None or bs is None or -1 in qs or -1 in cs:
+        ctx.add(op, unresolved=True)
+        return None
+    heads, nope = int(op.attr("num_heads")), int(op.attr("nope_dim"))
+    v_dim = int(op.attr("v_dim"))
+    b, cap, width = cs
+    r = bs[0]
+    lanes = lanes_of(qs)
+    e = ctx.esize(op.input("Q"))
+    return lanes, heads, nope, v_dim, b, cap, width, r, e
+
+
+@register_cost("latent_attention")
+def _latent_attention_cost(ctx, op):
+    got = _latent_cost(ctx, op, lambda qs: qs[0])
+    if got is None:
+        return
+    lanes, heads, nope, v_dim, b, cap, width, r, e = got
+    ss = ctx.shape(op.input("Index"))
+    picks = cap if ss is None or ss[-1] == -1 else ss[-1]
+    # the query into the latent, scores and mix over the set, W_uv
+    flops = 2.0 * lanes * heads * (r * (nope + v_dim)
+                                   + picks * (width + r))
+    ctx.add(op, flops=flops,
+            hbm_bytes=(r * heads * (nope + v_dim) + lanes * picks * width
+                       + lanes * heads * (width - r + nope + v_dim)) * e)
+
+
+@register_cost("latent_attention_chunk")
+def _latent_attention_chunk_cost(ctx, op):
+    got = _latent_cost(ctx, op, lambda qs: qs[0] * qs[1])
+    if got is None:
+        return
+    lanes, heads, nope, v_dim, b, cap, width, r, e = got
+    # blocks of the whole capacity under the mask (a static rule cannot
+    # know the fill; the op stops at a row's highest live position)
+    flops = 2.0 * lanes * heads * (r * (nope + v_dim) + cap * (width + r))
+    ctx.add(op, flops=flops,
+            hbm_bytes=(r * heads * (nope + v_dim) + b * cap * width
+                       + lanes * heads * (width - r + nope + v_dim)) * e
+            + lanes * cap)
+
+
 # ---------------------------------------------------------------------------
 # optimizer updates: master-precision (f32) state passes, batch-amortized
 # ---------------------------------------------------------------------------

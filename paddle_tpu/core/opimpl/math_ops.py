@@ -278,6 +278,9 @@ def _norm(env, op):
 def _mul(env, op):
     """Reference ``mul_op``: flatten x at x_num_col_dims, y at y_num_col_dims,
     then 2-D matmul (``operators/mul_op.cc``). Lowers to a single MXU matmul.
+    Attr ``precision`` (``"highest"``; absent by default): the product of
+    float32 operands in as many passes as keep it exact, where the MXU would
+    round them to bfloat16; operands of different types meet in the wider.
     """
     x = get(env, op.input("X"))
     y = get(env, op.input("Y"))
@@ -296,7 +299,7 @@ def _mul(env, op):
     y2 = y.reshape((_prod(ys[:ync]), _prod(ys[ync:])))
     from ..op_registry import mxu_cast
     x2, y2 = mxu_cast(x2, y2)
-    out = jnp.matmul(x2, y2)
+    out = jnp.matmul(x2, y2, precision=op.attr("precision", None))
     out_shape = xs[:xnc] + ys[ync:]
     put(env, op.output("Out"), out.reshape(out_shape))
 

@@ -834,28 +834,42 @@ def _rms_norm(env, op):
 
 @register("rotary")
 def _rotary(env, op):
-    """Rotary positions on the first ``rotary_dim`` dims of every head of a
-    packed [B, T, H*D] tensor, rotate-half pairing (j, j + rotary_dim/2),
-    position = index along T; the other dims pass through. float32 angles
-    and products, stored in X's dtype."""
+    """Rotary positions on ``rotary_dim`` dims of every head of a packed
+    [B, T, H*D] tensor (or [B, H*D] with Pos [B]: one token a row), from
+    dim ``offset`` of the head on; the other dims pass through. Pairing:
+    rotate-half (j, j + rotary_dim/2), or, ``interleaved``, neighbours
+    (2j, 2j + 1). Position: input ``Pos`` (shaped like X without its last
+    axis; a decode step's or a chunk's fed positions) where given, else the
+    index along T. float32 angles and products, stored in X's dtype."""
     x = get(env, op.input("X"))
+    pos = get(env, op.input("Pos"))
     heads = int(op.attr("num_heads"))
     rot = int(op.attr("rotary_dim"))
+    off = int(op.attr("offset", 0))
     theta = float(op.attr("theta", 10000.0))
-    b, t, hd = x.shape
+    lead, hd = x.shape[:-1], x.shape[-1]
     d = hd // heads
     half = rot // 2
     inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rot)
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.cos(angle)[None, :, None, :]
-    sin = jnp.sin(angle)[None, :, None, :]
-    xh = x.reshape(b, t, heads, d)
-    x1 = xh[..., :half].astype(jnp.float32)
-    x2 = xh[..., half:rot].astype(jnp.float32)
-    out = jnp.concatenate(
-        [(x1 * cos - x2 * sin).astype(x.dtype),
-         (x2 * cos + x1 * sin).astype(x.dtype), xh[..., rot:]], axis=-1)
-    put(env, op.output("Out"), out.reshape(b, t, hd))
+    if pos is None:
+        at = jnp.broadcast_to(jnp.arange(lead[-1], dtype=jnp.float32), lead)
+    else:
+        at = pos.reshape(lead).astype(jnp.float32)
+    angle = at[..., None, None] * inv
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    xh = x.reshape(lead + (heads, d))
+    part = xh[..., off:off + rot].astype(jnp.float32)
+    if op.attr("interleaved", False):
+        x1, x2 = part[..., 0::2], part[..., 1::2]
+        turned = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).reshape(part.shape)
+    else:
+        x1, x2 = part[..., :half], part[..., half:]
+        turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                                 axis=-1)
+    out = jnp.concatenate([xh[..., :off], turned.astype(x.dtype),
+                           xh[..., off + rot:]], axis=-1)
+    put(env, op.output("Out"), out.reshape(x.shape))
 
 
 @register("causal_conv1d")

@@ -876,6 +876,101 @@ def _cached_attention_chunk_shape(ctx, op):
     ctx.set(op.output("Out"), tuple(qs[:-1]) + (vs[-1],), dt)
 
 
+def _index_inputs(ctx, op, rank):
+    """(q, w, cache) shapes of an indexer op, checked; any may be None."""
+    heads = int(op.attr("num_heads"))
+    qs, ws = ctx.shape(op.input("Q")), ctx.shape(op.input("W"))
+    ks = ctx.shape(op.input("CacheK"))
+    for var, shape in ((op.input("Q"), qs), (op.input("W"), ws)):
+        if shape is not None and len(shape) != rank:
+            raise ShapeError("%s '%s' must have rank %d, got %s" % (
+                op.type, var.name, rank, list(shape)))
+    if ks is not None and len(ks) != 3:
+        raise ShapeError("%s CacheK '%s' must be [B, C, D], got %s" % (
+            op.type, op.input("CacheK").name, list(ks)))
+    if qs is not None and ks is not None and -1 not in (qs[-1], ks[-1]) \
+            and qs[-1] != heads * ks[-1]:
+        raise ShapeError("%s Q '%s' last dim %d is not %d heads of CacheK's "
+                         "%d" % (op.type, op.input("Q").name, qs[-1], heads,
+                                 ks[-1]))
+    if ws is not None and ws[-1] not in (-1, heads):
+        raise ShapeError("%s W '%s' last dim %d is not a weight for each of "
+                         "%d heads" % (op.type, op.input("W").name, ws[-1],
+                                       heads))
+    return qs, ws, ks
+
+
+@register_shape("sparse_index")
+def _sparse_index_shape(ctx, op):
+    qs, _, ks = _index_inputs(ctx, op, 2)
+    ctx.set(op.output("Count"), (2,), "int32")
+    if qs is None or ks is None:
+        ctx.set(op.output("Index"), None, "int32")
+        return
+    top_k = int(op.attr("top_k"))
+    ctx.set(op.output("Index"),
+            (qs[0], top_k if ks[1] == -1 else min(top_k, ks[1])), "int32")
+
+
+@register_shape("sparse_index_chunk")
+def _sparse_index_chunk_shape(ctx, op):
+    qs, _, ks = _index_inputs(ctx, op, 3)
+    ctx.set(op.output("Count"), (2,), "int32")
+    if qs is None or ks is None:
+        ctx.set(op.output("Mask"), None, "bool")
+        return
+    ctx.set(op.output("Mask"), (qs[0], qs[1], ks[1]), "bool")
+
+
+def _latent_attention_shape(ctx, op, rank):
+    heads, nope = int(op.attr("num_heads")), int(op.attr("nope_dim"))
+    v_dim = int(op.attr("v_dim"))
+    qs, cs = ctx.shape(op.input("Q")), ctx.shape(op.input("Cache"))
+    bs = ctx.shape(op.input("KvB"))
+    if qs is not None and len(qs) != rank:
+        raise ShapeError("%s Q '%s' must have rank %d, got %s" % (
+            op.type, op.input("Q").name, rank, list(qs)))
+    if cs is not None and len(cs) != 3:
+        raise ShapeError("%s Cache '%s' must be [B, C, R+P], got %s" % (
+            op.type, op.input("Cache").name, list(cs)))
+    if bs is not None and (len(bs) != 2
+                           or bs[1] != heads * (nope + v_dim)):
+        raise ShapeError("%s KvB '%s' %s is not [R, %d heads x (%d + %d)]"
+                         % (op.type, op.input("KvB").name, list(bs), heads,
+                            nope, v_dim))
+    if qs is not None and cs is not None and bs is not None \
+            and -1 not in (qs[-1], cs[-1]):
+        rope = cs[-1] - bs[0]
+        if rope < 0 or qs[-1] != heads * (nope + rope):
+            raise ShapeError(
+                "%s: a cached row of %d holds a latent of %d and a rotary "
+                "key of %d, so Q '%s' takes %d heads of %d + %d, not a last "
+                "dim of %d" % (op.type, cs[-1], bs[0], rope,
+                               op.input("Q").name, heads, nope, rope,
+                               qs[-1]))
+    ctx.set(op.output("Out"),
+            None if qs is None else tuple(qs[:-1]) + (heads * v_dim,),
+            ctx.dtype(op.input("Q")))
+
+
+@register_shape("latent_attention")
+def _latent_attention_step_shape(ctx, op):
+    _latent_attention_shape(ctx, op, 2)
+
+
+@register_shape("latent_attention_chunk")
+def _latent_attention_chunk_shape(ctx, op):
+    _latent_attention_shape(ctx, op, 3)
+    ms, qs = ctx.shape(op.input("Mask")), ctx.shape(op.input("Q"))
+    cs = ctx.shape(op.input("Cache"))
+    if ms is not None and qs is not None and cs is not None \
+            and tuple(ms[1:]) != (qs[1], cs[1]) and -1 not in ms[1:] \
+            and -1 not in (qs[1], cs[1]):
+        raise ShapeError("latent_attention_chunk Mask '%s' %s is not "
+                         "[B, %d lanes, %d positions]" % (
+                             op.input("Mask").name, list(ms), qs[1], cs[1]))
+
+
 # ---------------------------------------------------------------------------
 # the hybrid blocks (models/qwen3_next.py, models/nemotron_h.py)
 # ---------------------------------------------------------------------------
@@ -904,12 +999,21 @@ def _rotary_shape(ctx, op):
     xv = op.input("X")
     xs = ctx.shape(xv)
     ctx.set(op.output("Out"), xs, ctx.dtype(xv))
-    if xs is None or len(xs) != 3 or xs[-1] == -1:
+    if xs is None or len(xs) not in (2, 3) or xs[-1] == -1:
         return
     heads, rot = int(op.attr("num_heads")), int(op.attr("rotary_dim"))
-    if xs[-1] % heads or rot % 2 or rot > xs[-1] // heads:
-        raise ShapeError("rotary: %d heads with %d rotary dims do not fit "
-                         "a last axis of %d" % (heads, rot, xs[-1]))
+    off = int(op.attr("offset", 0))
+    if xs[-1] % heads or rot % 2 or off + rot > xs[-1] // heads:
+        raise ShapeError("rotary: %d heads with %d rotary dims from dim %d "
+                         "on do not fit a last axis of %d"
+                         % (heads, rot, off, xs[-1]))
+    ps = ctx.shape(op.input("Pos"))
+    if len(xs) == 2 and op.input("Pos") is None:
+        raise ShapeError("rotary: X %s has no axis of positions and no Pos "
+                         "is fed" % (list(xs),))
+    if ps is not None and len(ps) != len(xs) - 1:
+        raise ShapeError("rotary: Pos %s does not give a position to each "
+                         "row of X %s" % (list(ps), list(xs)))
 
 
 @register_shape("causal_conv1d")

@@ -35,6 +35,7 @@ __all__ = [
     "multi_head_attention", "scaled_dot_product_attention",
     "cached_multi_head_attention", "kv_cache_write",
     "cached_multi_head_attention_chunk", "kv_cache_write_chunk",
+    "sparse_index", "latent_attention",
     "row_conv", "autoincreased_step_counter", "cos_sim",
     "split", "warpctc", "nce", "hsigmoid", "cumsum",
     "linear_chain_crf", "crf_decoding",
@@ -64,9 +65,14 @@ def _pair(v):
 # ---------------------------------------------------------------------------
 
 def fc(input, size, num_flatten_dims=1, param_attr=None, bias_attr=None,
-       act=None, is_test=False, name=None):
+       act=None, is_test=False, name=None, param_dtype=None, precision=None):
     """Fully-connected layer (ref ``nn.py`` fc). Multiple inputs are summed
-    after projection, matching the reference."""
+    after projection, matching the reference. ``param_dtype``: the type the
+    weight is kept in where that is not the input's (a float32 input read
+    against a weight the rest of the program keeps in bfloat16; the product
+    comes out in the input's type). ``precision``: ``"highest"`` makes a
+    float32 product exact on an MXU, which by default rounds its operands to
+    bfloat16 (several passes)."""
     helper = LayerHelper("fc", param_attr=param_attr, bias_attr=bias_attr,
                          act=act, name=name)
     inputs = input if isinstance(input, (list, tuple)) else [input]
@@ -88,13 +94,14 @@ def fc(input, size, num_flatten_dims=1, param_attr=None, bias_attr=None,
         in_shape = inp.shape
         flat_dim = int(np.prod(in_shape[num_flatten_dims:]))
         w = helper.create_parameter(attr, shape=[flat_dim, size],
-                                    dtype=_dtype(inp))
+                                    dtype=param_dtype or _dtype(inp))
         out_shape = tuple(in_shape[:num_flatten_dims]) + (size,)
         tmp = helper.create_variable_for_type_inference(
             dtype=_dtype(inp), shape=out_shape)
-        helper.append_op("mul", {"X": inp, "Y": w}, {"Out": tmp},
-                         {"x_num_col_dims": num_flatten_dims,
-                          "y_num_col_dims": 1})
+        mul_attrs = {"x_num_col_dims": num_flatten_dims, "y_num_col_dims": 1}
+        if precision is not None:
+            mul_attrs["precision"] = str(precision)
+        helper.append_op("mul", {"X": inp, "Y": w}, {"Out": tmp}, mul_attrs)
         mul_results.append(tmp)
     if len(mul_results) == 1:
         pre_bias = mul_results[0]
@@ -332,7 +339,9 @@ def batch_norm(input, act=None, is_test=False, momentum=0.9, epsilon=1e-5,
 
 def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
                epsilon=1e-5, param_attr=None, bias_attr=None, act=None,
-               name=None):
+               name=None, param_dtype=None):
+    """``param_dtype``: the type scale and shift are kept in where that is
+    not the input's (see ``fc``)."""
     helper = LayerHelper("layer_norm", param_attr=param_attr,
                          bias_attr=bias_attr, act=act, name=name)
     dtype = _dtype(input)
@@ -340,12 +349,12 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
     inputs = {"X": input}
     if scale:
         s = helper.create_parameter(
-            helper.param_attr, shape=norm_shape, dtype=dtype,
+            helper.param_attr, shape=norm_shape, dtype=param_dtype or dtype,
             default_initializer=ConstantInitializer(1.0))
         inputs["Scale"] = s
     if shift:
         b = helper.create_parameter(helper.bias_attr, shape=norm_shape,
-                                    dtype=dtype, is_bias=True)
+                                    dtype=param_dtype or dtype, is_bias=True)
         inputs["Bias"] = b
     out = helper.create_variable_for_type_inference(dtype=dtype,
                                                     shape=input.shape)
@@ -1578,7 +1587,7 @@ def gru_unit(input, hidden, size, param_attr=None, bias_attr=None,
 
 def rms_norm(input, epsilon=1e-6, zero_centered=False, norm_dim=None,
              gate=None, gate_first=False, shared_weight=True,
-             param_attr=None, name=None):
+             param_attr=None, name=None, param_dtype=None):
     """RMS norm over the last axis, or over each trailing group of
     ``norm_dim`` elements of it (one head of a packed [.., H*D] axis, with
     one weight of ``norm_dim`` shared by the heads, or, ``shared_weight``
@@ -1586,13 +1595,14 @@ def rms_norm(input, epsilon=1e-6, zero_centered=False, norm_dim=None,
     * w`` in float32; ``zero_centered`` applies ``1 + w`` (the weight then
     starts at 0). ``gate``: a tensor shaped like ``input``; the result is
     multiplied by ``silu(gate)`` (the gated norm), or, ``gate_first``, the
-    input is, before the norm."""
+    input is, before the norm. ``param_dtype``: the type the weight is kept
+    in where that is not the input's (see ``fc``)."""
     helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
     dim = int(norm_dim or input.shape[-1])
     w = helper.create_parameter(
         helper.param_attr,
         shape=[dim if shared_weight else int(input.shape[-1])],
-        dtype=_dtype(input),
+        dtype=param_dtype or _dtype(input),
         default_initializer=ConstantInitializer(
             0.0 if zero_centered else 1.0))
     out = helper.create_variable_for_type_inference(dtype=_dtype(input),
@@ -1608,16 +1618,28 @@ def rms_norm(input, epsilon=1e-6, zero_centered=False, norm_dim=None,
     return out
 
 
-def rotary(x, num_heads, rotary_dim, theta=10000.0, name=None):
-    """Rotary positions on the first ``rotary_dim`` dims of each head of a
-    packed [B, T, H*D] tensor (rotate-half pairing, position = index along
-    T); the rest of each head passes through."""
+def rotary(x, num_heads, rotary_dim, theta=10000.0, pos=None,
+           interleaved=False, offset=0, name=None):
+    """Rotary positions on ``rotary_dim`` dims of each head of a packed
+    [B, T, H*D] tensor, from dim ``offset`` of the head on; the rest of each
+    head passes through. Pairing rotate-half (j, j + rotary_dim/2), or,
+    ``interleaved``, neighbours (2j, 2j + 1). Position: the index along T,
+    or, ``pos`` given, what it feeds: [B, T] int for a chunk whose lanes sit
+    anywhere in their rows' caches, [B] int with x [B, H*D] for a decode
+    step (one token a row)."""
     helper = LayerHelper("rotary", name=name)
     out = helper.create_variable_for_type_inference(dtype=_dtype(x),
                                                     shape=x.shape)
-    helper.append_op("rotary", {"X": x}, {"Out": out},
-                     {"num_heads": int(num_heads),
-                      "rotary_dim": int(rotary_dim), "theta": float(theta)})
+    inputs = {"X": x}
+    if pos is not None:
+        inputs["Pos"] = pos
+    attrs = {"num_heads": int(num_heads), "rotary_dim": int(rotary_dim),
+             "theta": float(theta)}
+    if interleaved:
+        attrs["interleaved"] = True
+    if offset:
+        attrs["offset"] = int(offset)
+    helper.append_op("rotary", inputs, {"Out": out}, attrs)
     return out
 
 
@@ -2057,9 +2079,11 @@ def multi_head_attention(queries, keys, values, attn_bias=None, d_key=None,
 
 
 def kv_cache_write(cache, x, pos, name=None):
-    """Per-row KV-cache update: ``cache[b, pos[b]] = x[b]`` (see
+    """Per-row cache update: ``cache[b, pos[b]] = x[b]`` (see
     ``core/opimpl/attention_ops.py``). ``cache``: [B, C, ...], ``x``:
-    [B, ...], ``pos``: [B] int. Returns the updated cache tensor."""
+    [B, ...], ``pos``: [B] int. The tail is the cache's own: a key or value
+    row of H*D, one latent row ``[c | k_pe]`` for all heads, an index key.
+    Returns the updated cache tensor."""
     helper = LayerHelper("kv_cache_write", name=name)
     out = helper.create_variable_for_type_inference(
         dtype=_dtype(cache), shape=cache.shape)
@@ -2114,10 +2138,11 @@ def cached_multi_head_attention(x, cache_k, cache_v, pos, d_model=None,
 
 
 def kv_cache_write_chunk(cache, x, pos, name=None):
-    """K-row KV-cache update: ``cache[b, pos[b, j]] = x[b, j]`` (see
-    ``core/opimpl/attention_ops.py``). ``cache``: [B, C, ...], ``x``:
-    [B, K, ...], ``pos``: [B, K] int. Out-of-range positions drop, so a
-    padded chunk lane writes nothing. Returns the updated cache."""
+    """K-row cache update: ``cache[b, pos[b, j]] = x[b, j]`` (see
+    ``core/opimpl/attention_ops.py``). ``cache``: [B, C, ...] of any tail
+    (as :func:`kv_cache_write`), ``x``: [B, K, ...], ``pos``: [B, K] int.
+    Out-of-range positions drop, so a padded chunk lane writes nothing.
+    Returns the updated cache."""
     helper = LayerHelper("kv_cache_write_chunk", name=name)
     out = helper.create_variable_for_type_inference(
         dtype=_dtype(cache), shape=cache.shape)
@@ -2171,3 +2196,78 @@ def cached_multi_head_attention_chunk(x, cache_k, cache_v, pos,
         dtype=dtype, shape=tuple(x.shape[:-1]) + (d_model,))
     helper.append_op("matmul", {"X": ctx, "Y": wo}, {"Out": out}, {})
     return out, new_k, new_v
+
+
+def sparse_index(q, w, cache_k, pos, num_heads, top_k, name=None):
+    """The learned indexer of sparse attention over a cache (ops
+    ``sparse_index`` / ``sparse_index_chunk``, ``ops/sparse_latent.py``):
+    ``q`` index queries of ``num_heads`` heads, ``w`` a weight a head,
+    ``cache_k`` [B, C, D] the cached index keys with the current ones
+    written, ``pos`` the positions fed. Scores ``sum_j w_j relu(q_j . k)``
+    in float32 over the positions up to ``pos`` (the products in ``q``'s
+    type: exact from float32 queries and keys, one MXU pass from bfloat16
+    queries whatever the keys are cached in), then the exact ``top_k``
+    largest. A step (q [B, H*D], pos [B]) names the set by positions:
+    [B, top_k] int32, ``C`` where fewer are cached; a chunk (q [B, K, H*D],
+    pos [B, K]) as a membership mask [B, K, C] bool. Returns ``(selected,
+    count)``; ``count`` [2] int32: positions selected and positions cached,
+    summed over the live rows or lanes. Several attention layers may read
+    one ``selected``."""
+    chunk = len(q.shape) == 3
+    helper = LayerHelper("sparse_index_chunk" if chunk else "sparse_index",
+                         name=name)
+    cap = int(cache_k.shape[1])
+    if chunk:
+        selected = helper.create_variable_for_type_inference(
+            dtype="bool", shape=tuple(q.shape[:2]) + (cap,))
+    else:
+        selected = helper.create_variable_for_type_inference(
+            dtype="int32",
+            shape=(q.shape[0], top_k if cap < 0 else min(top_k, cap)))
+    count = helper.create_variable_for_type_inference(dtype="int32",
+                                                      shape=(2,))
+    for var in (selected, count):
+        var.stop_gradient = True
+    helper.append_op(helper.layer_type,
+                     {"Q": q, "W": w, "CacheK": cache_k, "Pos": pos},
+                     {"Mask" if chunk else "Index": selected,
+                      "Count": count},
+                     {"num_heads": int(num_heads), "top_k": int(top_k)})
+    return selected, count
+
+
+def latent_attention(q, cache, selected, pos, num_heads, kv_lora_rank,
+                     qk_nope_head_dim, v_head_dim, scale, param_attr=None,
+                     name=None):
+    """Latent (MLA) attention over the set :func:`sparse_index` selected,
+    in the absorbed form (ops ``latent_attention`` /
+    ``latent_attention_chunk``): ``q`` heads of ``[q_nope | q_pe]`` with
+    the rotary part turned, ``cache`` [B, C, R+P] ONE row ``[normed latent |
+    rotary key]`` a position with the current rows written. Creates the
+    latent's up-projection ``kv_b`` [R, H * (N + V)] (``param_attr``),
+    whose two parts a head take the query into the latent and the mixed
+    latent out to V. Step: q [B, H*(N+P)], ``selected`` [B, S] positions;
+    chunk: q [B, K, H*(N+P)], ``selected`` [B, K, C] mask, ``pos`` [B, K].
+    Returns [.., H*V]."""
+    chunk = len(q.shape) == 3
+    helper = LayerHelper("latent_attention_chunk" if chunk
+                         else "latent_attention", param_attr=param_attr,
+                         name=name)
+    kv_b = helper.create_parameter(
+        helper.param_attr,
+        shape=[int(kv_lora_rank),
+               int(num_heads) * (int(qk_nope_head_dim) + int(v_head_dim))],
+        dtype=_dtype(q), default_initializer=XavierInitializer())
+    out = helper.create_variable_for_type_inference(
+        dtype=_dtype(q),
+        shape=tuple(q.shape[:-1]) + (int(num_heads) * int(v_head_dim),))
+    inputs = {"Q": q, "KvB": kv_b, "Cache": cache}
+    if chunk:
+        inputs.update(Mask=selected, Pos=pos)
+    else:
+        inputs["Index"] = selected
+    helper.append_op(helper.layer_type, inputs, {"Out": out},
+                     {"num_heads": int(num_heads),
+                      "nope_dim": int(qk_nope_head_dim),
+                      "v_dim": int(v_head_dim), "scale": float(scale)})
+    return out

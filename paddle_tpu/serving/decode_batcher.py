@@ -10,8 +10,12 @@ design the reference, whose serving story ends at
 
   * a **slot table** of ``bucket_batch`` rows, each row one in-flight
     request with its own fill level ``pos`` into fixed-capacity per-slot
-    KV-cache tensors ([B, C, ...] per layer, carried between steps as
-    device-resident fetch->feed state — never a host round trip);
+    cache tensors ([B, C, *tail] each, carried between steps as
+    device-resident fetch->feed state — never a host round trip). The
+    spec's ``cache_feeds`` name them one by one with their own tails and
+    types: a key and a value of one width a layer, or ONE latent row a
+    layer, with an index-key cache beside it on the layers that have an
+    indexer and none on the others; the scheduler never pairs them;
   * one compiled step per ``(bucket_batch, bucket_ctx)`` on the pow2
     ladders (``buckets.py``), so the XLA compile cache stays bounded at
     ``len(ladder) * len(ctx_ladder)`` executables;
@@ -260,7 +264,11 @@ class DecodeBatcher:
     step-program dir, an in-process ``ProgramPredictor``, or a test fake.
     ``spec``: the decode-spec dict a step builder returns
     (``models.transformer.transformer_lm_step``): token/pos feed names,
-    logits fetch, per-layer cache feed/fetch pairs with tail shapes.
+    logits fetch, cache feed/fetch pairs each with its own tail shape
+    and dtype (any number a layer), and optionally ``counter_fetch`` /
+    ``counters``: one small int vector the step program counts of itself
+    and the names of its entries, added after every step to the metrics'
+    ``program_<name>`` counters.
 
     ``start=False`` skips the loop thread: tests then call
     :meth:`drive` to run the scheduler synchronously — fully
@@ -278,6 +286,13 @@ class DecodeBatcher:
         self._pos_feed = self._spec["pos_feed"]
         fetch_names = list(predictor.fetch_names)
         self._logits_idx = fetch_names.index(self._spec["logits_fetch"])
+        # optional: ONE small int vector the step program counts of itself
+        # (``counter_fetch``), its entries named by ``counters``; read
+        # after a step's logits and added to the engine's metrics
+        self._counter_names = tuple(self._spec.get("counters") or ())
+        self._counter_idx = (
+            fetch_names.index(self._spec["counter_fetch"])
+            if self._counter_names else None)
         self._cache_feeds = []
         for cf in self._spec["cache_feeds"]:
             self._cache_feeds.append(
@@ -349,7 +364,10 @@ class DecodeBatcher:
             self._prefill = {
                 "pred": cpred, "tok": cspec["token_feed"],
                 "pos": cspec["pos_feed"],
-                "logits_idx": cfetch.index(cspec["logits_fetch"]),
+                # a chunk program that only ingests (no speculation reads
+                # its logits) may leave the head, and the fetch, out
+                "logits_idx": (cfetch.index(cspec["logits_fetch"])
+                               if cspec.get("logits_fetch") else None),
                 "cache_map": cmap}
         self._alt_chunk = False
 
@@ -360,6 +378,10 @@ class DecodeBatcher:
             if self._prefill is None:
                 raise ValueError("speculative decode needs the chunk "
                                  "program (pass prefill= as well)")
+            if self._prefill["logits_idx"] is None:
+                raise ValueError("speculative decode reads the chunk "
+                                 "program's logits; its spec names no "
+                                 "logits_fetch")
             s = dict(speculative)
             self._draft = s["draft"]
             k = int(s.get("k", 4))
@@ -921,6 +943,10 @@ class DecodeBatcher:
         for name, idx, _tail, _dtype in self._cache_feeds:
             self._caches[name] = outs[idx]
         logits = np.asarray(outs[self._logits_idx])
+        if self._counter_idx is not None:
+            self.metrics_.observe_program_counters(
+                self._counter_names,
+                np.asarray(outs[self._counter_idx]).ravel())
         now = self._clock()
         live = 0
         generated = 0

@@ -189,6 +189,17 @@ class ServingMetrics:
         self._c["prefill_chunks"].inc()
         self._c["prefill_tokens"].inc(int(tokens))
 
+    def observe_program_counters(self, names, values):
+        """What a decode step's program counted of itself (the spec's
+        ``counters`` / ``counter_fetch``: positions an indexer selected,
+        rows an expert layer's products ran over, ...), added to counters
+        ``paddle_tpu_serving_program_<name>`` made at first sight."""
+        for name, value in zip(names, values):
+            self.registry.counter(
+                _PREFIX + "program_" + name,
+                help="summed over decode steps, as the step program "
+                     "counts it").inc(int(value))
+
     def observe_spec(self, accepted, rejected):
         """One speculative verify outcome: ``accepted`` draft tokens
         matched the target model's greedy choice, ``rejected`` did not

@@ -218,6 +218,49 @@ def test_gated_delta_core_and_routed_experts_fit_at_published_widths(chip):
     _assert_named(compiled, _EXPERT_KERNELS)
 
 
+def test_sparse_selection_and_latent_attention_fit_at_published_widths(chip):
+    """``glm52.serve.longdoc.sat``'s sparse ops at its own sizes (8 slot
+    rows x 12288 positions; 32 index heads of 128, top-2048; 64 heads over
+    one cached row of 512 + 64), bfloat16 but the index path (float32 keys;
+    a step's float32 queries scored exactly, a chunk's bfloat16 ones in one
+    pass): a step's indexer and attention
+    over the gathered set, and a chunk's 512 lanes a row, whose scores exist
+    a block of positions at a time (no [lanes, heads, context] scores, no
+    [lanes, 2048, 576] gather: 9.7 GB), all of it plain XLA under 1 GB of
+    temporaries."""
+    from paddle_tpu.ops import sparse_latent
+
+    b, c, k, heads = 8, 12288, 512, 64
+
+    def step(qi, w, keys, q, kv_b, cache, pos):
+        index, count = sparse_latent.sparse_index(qi, w, keys, pos, 32, 2048)
+        return sparse_latent.latent_attention(
+            q, kv_b, cache, index, heads, 192, 256, 256 ** -0.5), count
+
+    compiled = _compile(
+        chip, step, sds((b, 4096), F32), sds((b, 32), F32),
+        sds((b, c, 128), F32), sds((b, heads * 256), BF16),
+        sds((512, heads * 448), BF16), sds((b, c, 576), BF16),
+        sds((b,), I32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+    assert _kernel_calls(compiled) == 0
+
+    def chunk(qi, w, keys, q, kv_b, cache, pos):
+        mask, count = sparse_latent.sparse_index_chunk(qi, w, keys, pos, 32,
+                                                       2048)
+        return sparse_latent.latent_attention_chunk(
+            q, kv_b, cache, mask, pos, heads, 192, 256, 256 ** -0.5), count
+
+    compiled = _compile(
+        chip, chunk, sds((b, k, 4096), BF16), sds((b, k, 32), BF16),
+        sds((b, c, 128), F32), sds((b, k, heads * 256), BF16),
+        sds((512, heads * 448), BF16), sds((b, c, 576), BF16),
+        sds((b, k), I32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    # the blocks run under loops, not unrolled: a dynamic trip count
+    assert compiled.as_text().count(" while(") >= 4
+
+
 def test_state_space_core_and_latent_experts_fit_at_published_widths(chip):
     """``nemotron3super.train.s8192``'s share of a layer: the Mamba-2 scan
     (16 heads of 64 x 128 state, one group, T = 8192, chunks of 128) keeps
