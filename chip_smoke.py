@@ -919,6 +919,9 @@ def phase_serve(ctx):
             metrics = engine.metrics()
             compiled = sum(engine.compiled_shape_counts())
             bound = engine.compile_cache_bound
+            # the step executable's record: whether the hand-over of the
+            # caches engages on this installation
+            step_record = engine.decode_compile_records()[0]
         finally:
             engine.shutdown()
 
@@ -962,6 +965,10 @@ def phase_serve(ctx):
             "executables_compiled": compiled, "compile_cache_bound": bound,
             "prefill_chunks": metrics["prefill_chunks"],
             "prefix_hits": metrics["prefix_hits"],
+            # engaged where alias_bytes >= donated_feed_bytes > 0
+            "step_alias_bytes": (step_record["memory"] or {}).get(
+                "alias_bytes"),
+            "step_donated_feed_bytes": step_record["donated_feed_bytes"],
             "attention_plans_replay": tally(
                 kernel_plans(replay._program).get("flash_attention", []))}
 

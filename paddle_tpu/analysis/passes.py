@@ -18,7 +18,9 @@ equivalent, run BEFORE lowering:
     ``infer_shape`` rules (``core/opimpl/shape_rules.py``) — mismatches
     surface at build time with op provenance, not as XLA trace errors;
   * donation-alias safety — proves the fetch list disjoint from donated
-    state (the PR-3 serving use-after-free class);
+    state (the PR-3 serving use-after-free class), and tells it from a feed
+    the caller handed over (``Executor.run(donate_feeds=...)``), whose
+    buffer a fetch is MEANT to take;
   * compiled-HLO sharding checks (wrapping ``parallel/sharding_check``) so
     mesh-strategy assertions share this diagnostic surface and the CLI.
 
@@ -370,14 +372,25 @@ ALIAS_OPS = frozenset({"assign", "reshape", "reshape2", "squeeze",
                        "flatten2"})
 
 
-def check_donation_alias(region, fetch_names, state_names, diags):
+def check_donation_alias(region, fetch_names, state_names, diags,
+                         donate_state=True, donate_feeds=(), block_vars=None):
     """Errors when a fetched var aliases DONATED state: the step donates
     the state pytree, so a fetch that resolves (possibly through
     view/identity ops) to a state input whose buffer no op rewrote returns
     an invalidated buffer — exactly the bug class ``Executor.run(
-    donate_state=False)`` exists for (serving from concurrent clones)."""
-    state = set(state_names or ())
-    if not state or not fetch_names:
+    donate_state=False)`` exists for (serving from concurrent clones).
+
+    A feed the caller HANDED OVER (``donate_feeds``) is the other case:
+    the scope and the clones hold donated state, but nobody holds a
+    handed-over feed except the caller who gave it up, so a fetch that
+    takes its buffer — a cache written in place, or the feed itself
+    through views — is what the hand-over is for and no finding. What IS
+    one there: a handed-over feed whose buffer no fetch can take (none of
+    its declared shape and dtype), which deletes the caller's array and
+    buys nothing (a warning)."""
+    state = set(state_names or ()) if donate_state else set()
+    handed = set(donate_feeds or ())
+    if (not state and not handed) or not fetch_names:
         return
     last_writer = {}
     for node in region.nodes:
@@ -396,7 +409,8 @@ def check_donation_alias(region, fetch_names, state_names, diags):
 
     for f in fetch_names:
         root = alias_root(f)
-        if root is None or root not in state:
+        # a handed-over feed shadows state of the same name in the step
+        if root is None or root in handed or root not in state:
             continue
         node = last_writer.get(f)
         if f == root:
@@ -413,6 +427,22 @@ def check_donation_alias(region, fetch_names, state_names, diags):
         diags.append(Diagnostic(
             "error", "donation-alias", msg,
             op=node.op if node else None, var=f, region=region.name))
+
+    def kind(name):
+        var = (block_vars or {}).get(name)
+        if var is None or var.shape is None:
+            return None
+        return tuple(var.shape), str(var.dtype)
+
+    takers = {kind(f) for f in fetch_names} - {None}
+    for name in sorted(handed):
+        if kind(name) is not None and kind(name) not in takers:
+            diags.append(Diagnostic(
+                "warning", "donation-alias",
+                "feed '%s' is handed over (donate_feeds) but no fetch has "
+                "its shape and dtype, so nothing can take its buffer: the "
+                "caller's array is deleted for no gain" % name,
+                var=name, region=region.name))
 
 
 # ---------------------------------------------------------------------------
@@ -451,13 +481,15 @@ def analyze_hlo_sharding(hlo_text, param_shapes=None, require_sharded=(),
 # ---------------------------------------------------------------------------
 
 def analyze_program(program, feed_names=None, fetch_names=None,
-                    state_names=None, donate_state=False, checks=None):
+                    state_names=None, donate_state=False, checks=None,
+                    donate_feeds=()):
     """Run the verification passes; returns an :class:`AnalysisResult`.
 
     ``feed_names`` defaults to the program's declared data vars;
     ``state_names`` defaults to all persistable vars (the executor passes
     the actual scope-resident state). ``donate_state=True`` additionally
-    runs the donation-alias check against ``fetch_names``."""
+    runs the donation-alias check against ``fetch_names``, and
+    ``donate_feeds`` (the feeds the caller hands over) its other half."""
     checks = set(DEFAULT_CHECKS if checks is None else checks)
     if feed_names is None:
         feed_names = [v.name for v in program.list_vars()
@@ -481,21 +513,23 @@ def analyze_program(program, feed_names=None, fetch_names=None,
                           diags)
     if "shape" in checks:
         check_shapes(region, diags)
-    if donate_state:
-        check_donation_alias(region, fetch_names, state_names, diags)
+    if donate_state or donate_feeds:
+        check_donation_alias(region, fetch_names, state_names, diags,
+                             donate_state, donate_feeds,
+                             program.global_block().vars)
     return AnalysisResult(diags)
 
 
 def verify_program(program, feed_names=None, fetch_names=None,
                    state_names=None, donate_state=False, checks=None,
-                   warn=False):
+                   warn=False, donate_feeds=()):
     """:func:`analyze_program` + raise :class:`VerificationError` on any
     error finding (warnings go through ``warnings.warn``). ``warn=True``
     downgrades errors to warnings (the ``PADDLE_TPU_VERIFY=warn`` mode)."""
     import warnings as _warnings
 
     result = analyze_program(program, feed_names, fetch_names, state_names,
-                             donate_state, checks)
+                             donate_state, checks, donate_feeds)
     for d in result.warnings:
         _warnings.warn("program verification: %s" % d)
     if warn:

@@ -11,7 +11,10 @@ launches + garbage collector with:
     ``(state, feed, rng) -> (fetches, new_state, rng')``;
   * ``jax.jit`` it with the persistable-state pytree DONATED — XLA's buffer
     assignment gives in-place parameter updates (the role of the reference's
-    inplace/memory-optimize passes and eager-deletion GC);
+    inplace/memory-optimize passes and eager-deletion GC); feeds the caller
+    hands over for good (``run(donate_feeds=...)``: a decode loop's carried
+    caches) go in as a fourth, donated argument and are updated in place the
+    same way;
   * a program cache keyed like the reference's (``executor.py:224``) but
     including feed shapes/dtypes, since XLA specializes on static shapes.
 
@@ -20,6 +23,7 @@ Randomness is a threaded functional PRNG key stored in the scope under
 """
 
 import os
+import threading
 import time
 import warnings
 
@@ -161,6 +165,14 @@ class scope_guard:
 # ---------------------------------------------------------------------------
 
 def _as_array(value, var=None):
+    if isinstance(value, jax.ShapeDtypeStruct):
+        # a shape in an array's place (``Executor.stage``): the dtype the
+        # program declares, as an array's would be coerced to
+        if var is not None and var.dtype is not None:
+            want = jax.dtypes.canonicalize_dtype(np.dtype(var.dtype))
+            if value.dtype != want:
+                value = jax.ShapeDtypeStruct(value.shape, want)
+        return value
     if isinstance(value, jax.Array):
         # already-staged device array (e.g. a py_reader prefetch slot or a
         # caller's jax.device_put): no host round-trip; coerce dtype
@@ -289,14 +301,34 @@ class _Variant:
     leaves behind — the ``jax.stages`` objects ``run`` calls and
     ``lowered_hlo_text`` reads."""
 
-    __slots__ = ("jfn", "in_shardings", "about", "lowered", "compiled")
+    __slots__ = ("jfn", "in_shardings", "about", "handed", "lowered",
+                 "compiled")
 
-    def __init__(self, jfn, in_shardings, about):
+    def __init__(self, jfn, in_shardings, about, handed=()):
         self.jfn = jfn
         self.in_shardings = in_shardings
         self.about = about  # what the compile record says of the variant
+        self.handed = handed  # names of the feeds the caller hands over
         self.lowered = None
         self.compiled = None
+
+    def arguments(self, state, feed, rng):
+        """The step's arguments: ``(state, feed, rng)``, and where the
+        caller hands feeds over, those as a fourth (donated) tuple of their
+        own, in the caller's order, and out of ``feed``."""
+        if not self.handed:
+            return state, feed, rng
+        handed = tuple(feed[n] for n in self.handed)
+        rest = dict(feed)
+        for n in self.handed:
+            del rest[n]
+        return state, rest, rng, handed
+
+
+def _nbytes(a):
+    """Bytes of an array, or of the array a ``jax.ShapeDtypeStruct`` stands
+    for."""
+    return int(np.prod(a.shape, dtype=np.int64)) * np.dtype(a.dtype).itemsize
 
 
 def _host_bytes(arrays):
@@ -334,18 +366,37 @@ class Executor:
         self.variant_hits = 0
         self.variant_misses = 0
         self.state_relayouts = 0
+        # ``stage`` may be called from other threads than the one that runs
+        self._counting = threading.Lock()
 
     # -- public API ---------------------------------------------------------
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
             return_numpy=True, use_program_cache=True, feed_var_name="feed",
             fetch_var_name="fetch", check_nan_inf=None, donate_state=True,
-            verify=None):
+            verify=None, donate_feeds=()):
         """``donate_state=False`` compiles the step WITHOUT donating the
         state pytree (and, off-mesh, without echoing unwritten state back
         out). Donation invalidates the input weight arrays mid-call — fine
         for a single-threaded training loop that re-sets the scope right
         after, but a use-after-free race when predictor clones serve the
         same scope from concurrent threads (``inference.py``/``serving``).
+
+        ``donate_feeds`` names the feeds the caller hands over for good:
+        arrays nobody else holds and the caller will not read again (a
+        decode loop's carried caches, which come back as fetches). They go
+        to the step as a fourth argument of their own, which is donated, so
+        XLA may write a fetch into a handed-over feed's buffer (an in-place
+        ``kv_cache_write``) where it would otherwise copy the whole array
+        first. After the call a handed-over ``jax.Array`` is deleted,
+        whether or not the call succeeded in full: keep what comes back.
+        Name them in the order of the fetches that are to take their
+        buffers: jit pairs the donated arguments of one shape and type with
+        the outputs of that shape and type in order, and a cache paired
+        with another cache's fetch costs the copy the hand-over was to
+        save. The names, in that order, are part of the variant's key; with
+        none given the step is the three-argument one it always was. A
+        step over a mesh takes no hand-over (its shardings are not
+        guessed): it raises.
 
         ``verify=True`` (or env ``PADDLE_TPU_VERIFY=1``) runs the static
         program verifier (``paddle_tpu.analysis``) once per compiled
@@ -387,7 +438,8 @@ class Executor:
             with obs_trace.span("executor.prepare"):
                 entry, hit, scope, state_in_names, feed_arrays, span = \
                     self._prepare(program, feed, fetch_list, scope,
-                                  use_program_cache, donate_state, verify)
+                                  use_program_cache, donate_state, verify,
+                                  tuple(donate_feeds))
             if run_sp:
                 run_sp.set(ordinal=ordinal, variant_hit=hit)
             with obs_trace.span("executor.feed_put") as sp:
@@ -397,13 +449,13 @@ class Executor:
                 self.state_relayouts += moved
                 if sp:
                     sp.set(bytes=put, state_relayouts=moved)
+            args = entry.arguments(state, feed_arrays, rng)
             if entry.compiled is None:
-                self._stage(entry, state, feed_arrays, rng, ordinal)
+                self._stage(entry, args, ordinal)
             self._last = entry
             with obs_trace.span("executor.dispatch"):
                 try:
-                    fetches, new_state, rng_out = entry.compiled(
-                        state, feed_arrays, rng)
+                    fetches, new_state, rng_out = entry.compiled(*args)
                 except (TypeError, ValueError):
                     # an argument no longer has the shape, type or
                     # placement the variant was staged for (a state array
@@ -411,10 +463,8 @@ class Executor:
                     # retrace. The check precedes execution, so nothing
                     # was donated; stage again for what is there, or raise
                     # what that raises.
-                    self._stage(entry, state, feed_arrays, rng, ordinal,
-                                restaged=True)
-                    fetches, new_state, rng_out = entry.compiled(
-                        state, feed_arrays, rng)
+                    self._stage(entry, args, ordinal, restaged=True)
+                    fetches, new_state, rng_out = entry.compiled(*args)
             with obs_trace.span("executor.writeback") as sp:
                 scope.set(RNG_KEY, rng_out)
                 for n, v in new_state.items():
@@ -425,8 +475,45 @@ class Executor:
                     return [np.asarray(f) for f in fetches]
                 return list(fetches)
 
+    def stage(self, program=None, feed=None, fetch_list=None, scope=None,
+              donate_state=True, donate_feeds=()):
+        """Make the executable that ``run`` with these arguments would use
+        (trace, lower, compile or load from the persistent cache: seconds)
+        and run nothing: the ``run`` that follows finds its variant staged.
+        A feed's value may be a ``jax.ShapeDtypeStruct`` in the array's
+        place, so that a caller can stage ahead of the arrays, and from
+        other threads than the one that runs: a variant a thread (a decode
+        loop has the step and every other chunk rung of a geometry staged
+        while its first chunk is staged and run; it waits for a variant's
+        thread before it runs that variant). Not for a step over a mesh,
+        whose feeds are laid out before they are staged."""
+        if program is None:
+            program = framework.default_main_program()
+        entry, _hit, scope, state_in_names, feed_arrays, span = \
+            self._prepare(program, feed, fetch_list, scope, True,
+                          donate_state, None, tuple(donate_feeds))
+        if span is not None:
+            raise NotImplementedError("Executor.stage: a step over a mesh "
+                                      "is staged by its first run")
+        if entry.compiled is None:
+            shapes = {n: a for n, a in feed_arrays.items()
+                      if isinstance(a, jax.ShapeDtypeStruct)}
+            if self._device is not None:
+                # as ``_feed_put`` commits arrays to the place's device
+                on = jax.sharding.SingleDeviceSharding(self._device)
+                shapes = {n: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                  sharding=on)
+                          for n, a in shapes.items()}
+            # off a mesh no state array is ever moved
+            state, arrays, rng, _moved = self._feed_put(
+                entry, scope, state_in_names,
+                {n: a for n, a in feed_arrays.items() if n not in shapes},
+                span)
+            self._stage(entry, entry.arguments(state, {**arrays, **shapes},
+                                               rng), self.runs)
+
     def _prepare(self, program, feed, fetch_list, scope, use_program_cache,
-                 donate_state, verify):
+                 donate_state, verify, donate_feeds=()):
         """``executor.prepare``: unwrap the CompiledProgram, normalise the
         feeds, seed the rng, name the state, look the variant up (making
         its jitted step and layout when it is new, staging nothing) and
@@ -492,6 +579,18 @@ class Executor:
             if program.global_block().has_var(name):
                 var = program.global_block().var(name)
             feed_arrays[name] = _as_array(value, var)
+        if donate_feeds:
+            missing = [n for n in donate_feeds if n not in feed_arrays]
+            if missing:
+                raise KeyError("donate_feeds names %s, which this call does "
+                               "not feed" % missing)
+            if span is not None:
+                raise NotImplementedError(
+                    "donate_feeds=%s: a step over a mesh takes no "
+                    "handed-over feed (which sharding a donated feed and "
+                    "the fetch that takes its buffer share is not guessed); "
+                    "run it on one device or feed without handing over"
+                    % list(donate_feeds))
 
         # seed rng on first use; random_seed=0 means nondeterministic
         # (reference Program.random_seed semantics)
@@ -554,6 +653,8 @@ class Executor:
         key = (id(program), program._version, feed_sig, tuple(fetch_names),
                state_in_names, id(scope), mesh, dp_axis, sp_axis, seq_feeds,
                pp, zero_state, grad_scale, donate_state, placement)
+        if donate_feeds:
+            key += (donate_feeds,)
         entry = self._cache.get(key) if use_program_cache else None
         if verify is None:
             mode = os.environ.get("PADDLE_TPU_VERIFY", "").strip().lower()
@@ -572,7 +673,8 @@ class Executor:
             verify_program(
                 program, feed_names=sorted(feed_arrays),
                 fetch_names=fetch_names, state_names=persist_names,
-                donate_state=donate_state, warn=(verify == "warn"))
+                donate_state=donate_state, warn=(verify == "warn"),
+                donate_feeds=donate_feeds)
             if strictness >= 3:
                 from ..analysis.resources import check_resources
 
@@ -585,15 +687,17 @@ class Executor:
                     warnings.warn("program verification: %s" % d)
             self._verified[key] = strictness
         hit = entry is not None
-        if hit:
-            self.variant_hits += 1
-        else:
-            self.variant_misses += 1
+        with self._counting:
+            if hit:
+                self.variant_hits += 1
+            else:
+                self.variant_misses += 1
+        if not hit:
             entry = self._compile(program, tuple(sorted(feed_arrays)),
                                   fetch_names, state_in_names, persist_names,
                                   mesh, dp_axis, sp_axis, seq_feeds, pp,
                                   zero_state, grad_scale, donate_state,
-                                  placement)
+                                  placement, donate_feeds)
             if use_program_cache:
                 self._cache[key] = entry
         return entry, hit, scope, state_in_names, feed_arrays, span
@@ -849,7 +953,8 @@ class Executor:
 
     def _compile(self, program, feed_names, fetch_names, state_in_names,
                  persist_names, mesh, dp_axis, sp_axis, seq_feeds, pp,
-                 zero_state, grad_scale, donate_state, placement):
+                 zero_state, grad_scale, donate_state, placement,
+                 donate_feeds=()):
         pp_cfg = None
         if pp is not None:
             pp_axis, pp_boundaries, pp_nmicro = pp
@@ -873,6 +978,16 @@ class Executor:
                  "feed_names": list(feed_names),
                  "ops": len(program.global_block().ops),
                  "meshed": mesh is not None}
+        if donate_feeds:
+            # feeds the caller hands over: an argument of their own, the
+            # only way jit can be told to donate them and not the rest
+            def step_handed(state, feed, rng, handed):
+                return step(state, {**feed, **dict(zip(donate_feeds, handed))},
+                            rng)
+
+            return _Variant(
+                jax.jit(step_handed, donate_argnums=donate + (3,), **extra),
+                None, about, donate_feeds)
         if mesh is None:
             return _Variant(jax.jit(step, donate_argnums=donate, **extra),
                             None, about)
@@ -884,8 +999,7 @@ class Executor:
                                 out_shardings=out_shardings, **extra),
                         in_shardings, about)
 
-    def _stage(self, entry, state, feed_arrays, rng, ordinal,
-               restaged=False):
+    def _stage(self, entry, args, ordinal, restaged=False):
         """Trace, lower and compile a variant's step for the arguments of
         this call, each stage once and under its span, keep what ``run``
         calls and ``lowered_hlo_text`` reads, and append the variant's
@@ -897,7 +1011,7 @@ class Executor:
         with obs_trace.span("executor.trace") as sp, \
                 gates.collect() as met, \
                 kernel_names.collect_traces() as bodies:
-            traced = entry.jfn.trace(state, feed_arrays, rng)
+            traced = entry.jfn.trace(*args)
             decisions = gates.tally(met)
             kernel_traces = kernel_names.tally_traces(bodies)
             if sp:
@@ -922,6 +1036,10 @@ class Executor:
             trace_s=t1 - t0, lower_s=t2 - t1, backend_compile_s=t3 - t2,
             persistent_cache=cache, gates=decisions,
             kernel_traces=kernel_traces,
+            # bytes of the feeds handed over (``donate_feeds``); the
+            # hand-over engages where ``memory["alias_bytes"]`` is no less
+            donated_feed_bytes=sum(_nbytes(a) for a in args[3])
+            if entry.handed else 0,
             memory=None if memory is None else {
                 "temp_bytes": int(memory.temp_size_in_bytes),
                 "argument_bytes": int(memory.argument_size_in_bytes),

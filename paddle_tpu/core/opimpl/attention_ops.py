@@ -135,7 +135,18 @@ def _cached_attention(env, op):
     positions past the row's own fill level (including every slot of a
     dead row) are masked out before the softmax. Numerics mirror
     ``ops.flash_attention.mha_reference``: logits * 1/sqrt(D), f32
-    softmax. Strictly per-row: no cross-row reduction anywhere."""
+    softmax. Strictly per-row: no cross-row reduction anywhere.
+
+    The caches are read AS THEY ARE STORED, ``[C, H*D]`` a row with the
+    heads side by side: the scores are the plain product of a row's cache
+    with its query laid out block-diagonally (``[H*D, H]``: head h's D
+    values in column h, zeros elsewhere), the mix the plain product of
+    the probabilities ``[H, C]`` with the cache, of which head h keeps its
+    own D columns. A product a head over ``[B, C, H, D]`` computes the
+    same sums, but on the TPU a compiler that is given it first copies
+    every cache into a layout with the positions innermost (0.27 ms an
+    array a step at [16, 1280, 2048]); the zeros cost MXU passes that hide
+    under the cache's read from HBM."""
     q = get(env, op.input("Q"))
     k = get(env, op.input("CacheK"))
     v = get(env, op.input("CacheV"))
@@ -143,17 +154,18 @@ def _cached_attention(env, op):
     h = int(op.attr("num_heads", 1))
     b, c, hd = k.shape
     d = hd // h
-    qh = q.reshape(b, h, d)
-    kh = k.reshape(b, c, h, d)
-    vh = v.reshape(b, c, h, d)
+    own = jnp.eye(h, dtype=bool)
+    q_blocks = jnp.where(own[:, None, :], q.reshape(b, h, d, 1),
+                         0).reshape(b, hd, h)
     scale = 1.0 / math.sqrt(d)
-    logits = jnp.einsum("bhd,bchd->bhc", qh, kh) * scale
+    logits = jnp.einsum("bck,bkh->bhc", k, q_blocks) * scale
     mask = jnp.arange(c)[None, None, :] <= pos[:, None, None]
     logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(
         q.dtype)
-    ctx = jnp.einsum("bhc,bchd->bhd", probs, vh)
-    put(env, op.output("Out"), ctx.reshape(b, hd))
+    mixed = jnp.einsum("bhc,bck->bhk", probs, v).reshape(b, h, h, d)
+    put(env, op.output("Out"),
+        jnp.einsum("bhhd->bhd", mixed).reshape(b, hd))
 
 
 @register("kv_cache_write_chunk")

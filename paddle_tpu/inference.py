@@ -96,9 +96,13 @@ class ProgramPredictor:
         self.fetch_names = [v.name if hasattr(v, "name") else str(v)
                             for v in fetch_vars]
 
-    def run(self, inputs, return_numpy=True):
+    def run(self, inputs, return_numpy=True, donate_feeds=()):
         """``inputs``: dict name->array, or a list/tuple in feed order.
-        Returns outputs in fetch order."""
+        Returns outputs in fetch order. ``donate_feeds`` names the inputs
+        the caller hands over for good (``Executor.run``): arrays of the
+        caller's own that it will not read again, such as a decode loop's
+        carried caches; after the call they are deleted and what the
+        program made of them is among the outputs."""
         if isinstance(inputs, (list, tuple)):
             if len(inputs) != len(self.feed_names):
                 raise ValueError("expected %d inputs (%s), got %d"
@@ -113,16 +117,29 @@ class ProgramPredictor:
         # scope passed explicitly (not via the global scope_guard stack):
         # clones serving concurrently from other threads must not race on
         # process-global scope resolution. donate_state=False for the same
-        # reason: donation would invalidate the scope's shared weight
-        # arrays mid-call, a use-after-free when another clone reads them
+        # reason: the weights are the SCOPE's, which clones share, and
+        # donating them would invalidate them mid-call, a use-after-free
+        # when another clone reads them. A handed-over feed is the
+        # caller's own array and nobody else's, so donating it is safe
+        # whatever else serves from this scope.
         return self._exe.run(self._compiled if self._compiled is not None
                              else self._program, feed=feed,
                              fetch_list=self._fetch_vars,
                              scope=self._scope,
                              return_numpy=return_numpy,
-                             donate_state=False)
+                             donate_state=False,
+                             donate_feeds=donate_feeds)
 
     predict = run
+
+    def stage(self, inputs, donate_feeds=()):
+        """Make the executable that ``run(inputs, donate_feeds=...)`` would
+        use, and run nothing (``Executor.stage``): ``inputs`` is a dict whose
+        values may be ``jax.ShapeDtypeStruct``s in the arrays' places."""
+        self._exe.stage(self._compiled if self._compiled is not None
+                        else self._program, feed=dict(inputs),
+                        fetch_list=self._fetch_vars, scope=self._scope,
+                        donate_state=False, donate_feeds=donate_feeds)
 
     def clone(self, device=None):
         """A predictor sharing this one's weights (ref

@@ -11,10 +11,16 @@ design the reference, whose serving story ends at
   * a **slot table** of ``bucket_batch`` rows, each row one in-flight
     request with its own fill level ``pos`` into fixed-capacity per-slot
     cache tensors ([B, C, *tail] each, carried between steps as
-    device-resident fetch->feed state — never a host round trip). The
-    spec's ``cache_feeds`` name them one by one with their own tails and
-    types: a key and a value of one width a layer, or ONE latent row a
-    layer, with an index-key cache beside it on the layers that have an
+    device-resident fetch->feed state — never a host round trip, and
+    HANDED OVER to every step and chunk run: the batcher names them as
+    ``donate_feeds`` of ``predictor.run``, so the executable writes a
+    token's row into the buffer it was given and hands that buffer back
+    as the fetch, where an executable that may not touch its feed copies
+    the whole array first; the batcher keeps only what comes back, and a
+    predictor whose ``run`` takes no ``donate_feeds`` is run as before).
+    The spec's ``cache_feeds`` name them one by one with their own tails
+    and types: a key and a value of one width a layer, or ONE latent row
+    a layer, with an index-key cache beside it on the layers that have an
     indexer and none on the others; the scheduler never pairs them;
   * one compiled step per ``(bucket_batch, bucket_ctx)`` on the pow2
     ladders (``buckets.py``), so the XLA compile cache stays bounded at
@@ -81,6 +87,7 @@ row: deterministic, per-row, and it keeps eos/length control flow out of
 the compiled step.
 """
 
+import inspect
 import threading
 import time
 from collections import deque
@@ -123,6 +130,55 @@ def default_prefill_ladder(spec):
     cap = int(spec.get("ctx_cap", 256) or 256)
     top = min(cap, max(4, cap // 2))
     return tuple(r for r in pow2_ladder(top) if r >= 4) or (min(4, cap),)
+
+
+class _Carrying:
+    """A step or chunk predictor with the caches it carries: their feed
+    names in the order of the fetches that carry them on, which is the
+    order they are handed over in, so that jit pairs each cache with its
+    own fetch. ``hands_over``: whether ``predictor.run`` has the
+    ``donate_feeds`` argument (``ProgramPredictor`` and ``Predictor`` do;
+    an exported-computation predictor, a wrapper or a test fake that
+    lacks it is fed as ever, and its cache writes copy)."""
+
+    def __init__(self, predictor, carried):
+        """``carried``: (feed name, index of its fetch) a cache."""
+        self.predictor = predictor
+        self.order = tuple(name for name, _idx in
+                           sorted(carried, key=lambda c: c[1]))
+        try:
+            self.hands_over = "donate_feeds" in inspect.signature(
+                predictor.run).parameters
+        except (TypeError, ValueError):
+            self.hands_over = False
+
+    def stage(self, feed, caches):
+        """Make the executable that :meth:`run` with such arguments would
+        use, and run nothing: ``caches`` may hold shapes in the arrays'
+        places. A predictor that takes no hand-over or has no ``stage`` is
+        left to its first run; so is one whose staging raises (the run will
+        raise it again, where the loop answers for it)."""
+        stage = getattr(self.predictor, "stage", None)
+        if stage is None or not self.hands_over:
+            return
+        try:
+            stage({**feed, **caches}, donate_feeds=self.order)
+        except Exception:  # noqa: BLE001: the run itself will raise it
+            pass
+
+    def run(self, feed, caches):
+        """One run over the quantum's own ``feed`` and the carried
+        ``caches``, which are handed over for good where the predictor
+        takes that: the executable may then write into them in place, and
+        after the call, failed or not, they must not be read again — only
+        what the run returns. Returns (outputs in fetch order, bytes
+        handed over)."""
+        feed.update(caches)
+        if not self.hands_over:
+            return self.predictor.run(feed, return_numpy=False), 0
+        outs = self.predictor.run(feed, return_numpy=False,
+                                  donate_feeds=self.order)
+        return outs, sum(int(a.nbytes) for a in caches.values())
 
 
 class DraftLM:
@@ -280,7 +336,6 @@ class DecodeBatcher:
                  default_timeout_s=None, default_max_new_tokens=64,
                  eos_id=None, clock=None, metrics=None, start=True,
                  prefix_cache=None, prefill=None, speculative=None):
-        self._predictor = predictor
         self._spec = dict(spec)
         self._tok_feed = self._spec["token_feed"]
         self._pos_feed = self._spec["pos_feed"]
@@ -298,6 +353,8 @@ class DecodeBatcher:
             self._cache_feeds.append(
                 (cf["feed"], fetch_names.index(cf["fetch"]),
                  tuple(cf["tail"]), np.dtype(cf.get("dtype", "float32"))))
+        self._step = _Carrying(predictor,
+                               [cf[:2] for cf in self._cache_feeds])
         self.ladder = tuple(sorted(set(
             ladder if ladder is not None else pow2_ladder(max_batch_size))))
         if ctx_ladder is None:
@@ -362,7 +419,7 @@ class DecodeBatcher:
                 pl = default_prefill_ladder(self._spec)
             self.prefill_ladder = tuple(sorted(set(int(k) for k in pl)))
             self._prefill = {
-                "pred": cpred, "tok": cspec["token_feed"],
+                "pred": _Carrying(cpred, cmap), "tok": cspec["token_feed"],
                 "pos": cspec["pos_feed"],
                 # a chunk program that only ingests (no speculation reads
                 # its logits) may leave the head, and the fetch, out
@@ -370,6 +427,7 @@ class DecodeBatcher:
                                if cspec.get("logits_fetch") else None),
                 "cache_map": cmap}
         self._alt_chunk = False
+        self._ahead = {}  # signature -> the thread staging its executable
 
         # -- speculative decode (optional, rides the chunk program)
         self._draft = None
@@ -469,6 +527,18 @@ class DecodeBatcher:
         — bounded at :meth:`compile_cache_bound` by construction."""
         return [len(self.seen_signatures)]
 
+    def compile_records(self):
+        """The compile records (``Executor.compile_records``) of the
+        executables the step predictor and the chunk predictor made, in
+        that order; a predictor that keeps no executor adds none. Each
+        says whether the hand-over of the caches engages in it:
+        ``memory["alias_bytes"]`` no less than ``donated_feed_bytes``."""
+        carrying = [self._step]
+        if self._prefill is not None:
+            carrying.append(self._prefill["pred"])
+        return [record for c in carrying for record in getattr(getattr(
+            c.predictor, "_exe", None), "compile_records", ())]
+
     def compile_cache_bound(self):
         """The PROVED executable-count bound (ISSUE 15): the static
         compile-cache verdict from the decode spec — dispatched
@@ -492,15 +562,15 @@ class DecodeBatcher:
         warmed = 0
         for b in self.ladder:
             for c in self.ctx_ladder:
-                feed = self._synth_feed(b, c)
-                self._predictor.run(feed, return_numpy=False)
+                self._step.run(self._synth_feed(b),
+                               self._synth_caches(b, c))
                 self.seen_signatures.add((b, c))
                 warmed += 1
                 if self._prefill is not None:
                     for k in self.prefill_ladder:
-                        cfeed = self._synth_chunk_feed(b, c, k)
-                        self._prefill["pred"].run(cfeed,
-                                                  return_numpy=False)
+                        self._prefill["pred"].run(
+                            self._synth_chunk_feed(b, c, k),
+                            self._synth_caches(b, c))
                         self.seen_signatures.add((b, c, k))
                         warmed += 1
         return warmed
@@ -514,12 +584,16 @@ class DecodeBatcher:
             raise RuntimeError("drive() requires start=False "
                                "(the loop thread owns the slot table)")
         steps = 0
-        while max_steps is None or steps < max_steps:
-            self._admit()
-            if not any(s is not None for s in self._slots):
-                break
-            self._tick()
-            steps += 1
+        try:
+            while max_steps is None or steps < max_steps:
+                self._admit()
+                if not any(s is not None for s in self._slots):
+                    break
+                self._tick()
+                steps += 1
+        except BaseException as e:
+            self._poison(e)
+            raise
         return steps
 
     def shutdown(self, drain=True, timeout_s=None):
@@ -535,15 +609,13 @@ class DecodeBatcher:
             self._cv.notify_all()
         if self._thread is not None:
             self._thread.join(timeout_s if timeout_s is not None else 30.0)
+        elif drain:
+            self.drive()
         else:
-            if drain:
-                while True:
-                    self._admit()
-                    if not any(s is not None for s in self._slots):
-                        break
-                    self._tick()
-            else:
-                self._abort_live()
+            self._abort_live()
+        for staging in list(self._ahead.values()):
+            # a compile left running at interpreter exit is a crash
+            staging.join(timeout_s if timeout_s is not None else 30.0)
         self._fail_pending()
 
     def __enter__(self):
@@ -573,19 +645,31 @@ class DecodeBatcher:
                     break
             except BaseException as e:  # noqa: BLE001 — fail loudly, once
                 self._poison(e)
-                return
+                if not isinstance(e, Exception):
+                    return
         if self._aborted:
             self._abort_live()
 
     def _poison(self, exc):
         """The step function itself threw (a replica fault, not a request
         fault): fail everything in flight — a decode loop cannot retry
-        mid-sequence without replaying the whole cache."""
+        mid-sequence without replaying the whole cache, and the caches
+        were handed over to the run that failed, so they are gone. The
+        slot table is dropped with them: whatever is submitted next is
+        served from fresh caches."""
         for i, slot in enumerate(self._slots):
             if slot is not None:
                 self._resolve_exc(slot.req, exc)
                 self._slots[i] = None
+        self._drop_table()
         self._fail_pending(exc)
+
+    def _drop_table(self):
+        """Forget the slot table and its caches (every slot is free): the
+        next admission re-buckets from nothing, into new zero caches."""
+        self._slots = []
+        self._caches = {}
+        self._bucket = (0, 0)
 
     def _fail_pending(self, exc=None):
         with self._cv:
@@ -603,6 +687,7 @@ class DecodeBatcher:
                     "DecodeBatcher aborted mid-generation"))
                 self.metrics_.observe_failed()
                 self._slots[i] = None
+        self._drop_table()
 
     def _release_prefix(self, req):
         """Drop a request's pinned prefix entry (cloned, failed, or
@@ -712,12 +797,13 @@ class DecodeBatcher:
                 self._caches[feed] = cache.at[i, :m].set(rows)
         self._release_prefix(req)
 
-    def _synth_feed(self, b, c):
-        feed = {self._tok_feed: np.zeros((b,), np.int64),
+    def _synth_feed(self, b):
+        return {self._tok_feed: np.zeros((b,), np.int64),
                 self._pos_feed: np.zeros((b,), np.int32)}
-        for name, _idx, tail, dtype in self._cache_feeds:
-            feed[name] = np.zeros((b, c) + tail, dtype)
-        return feed
+
+    def _synth_caches(self, b, c):
+        return {name: np.zeros((b, c) + tail, dtype)
+                for name, _idx, tail, dtype in self._cache_feeds}
 
     def _tick(self):
         """One scheduler quantum: a chunk dispatch (prefill and/or
@@ -743,6 +829,45 @@ class DecodeBatcher:
         with trace.span("spec.verify" if verifying
                         else "prefill.chunk") as sp:
             self._chunk_once(rows, sp)
+
+    def _stage_ahead(self, now):
+        """A chunk run of signature ``now`` is about to be dispatched: have
+        helper threads make (or load) meanwhile every other executable of
+        this geometry that has not run yet, the step's and the other chunk
+        rungs', so that the quanta which follow find theirs staged. Staging
+        takes seconds and most of them outside the interpreter's lock (a
+        handed-over chunk executable loads in 2-3 s); the threads share
+        nothing but the weights, which all only read, and an executor
+        whose variants they make one each."""
+        b, c = self._bucket
+        # speculation samples from the chunk's logits: no step follows
+        ahead = [] if self._spec_k else [(b, c)]
+        ahead += [(b, c, k) for k in self.prefill_ladder]
+        ahead = [sig for sig in ahead
+                 if sig != now and sig not in self.seen_signatures
+                 and sig not in self._ahead]
+        if not ahead:
+            return
+        import jax
+
+        shapes = {name: jax.ShapeDtypeStruct((b, c) + tail, dtype)
+                  for name, _idx, tail, dtype in self._cache_feeds}
+        for sig in ahead:
+            carrying, feed = ((self._step, self._synth_feed(b))
+                              if len(sig) == 2 else
+                              (self._prefill["pred"],
+                               self._synth_chunk_feed(*sig)))
+            self._ahead[sig] = threading.Thread(
+                target=carrying.stage, args=(feed, shapes),
+                name="paddle-tpu-decode-stage", daemon=True)
+            self._ahead[sig].start()
+
+    def _await_staged(self, sig):
+        """The executable of ``sig`` is about to run: if a helper thread is
+        staging it, let it finish (the run would stage it a second time)."""
+        staging = self._ahead.pop(sig, None)
+        if staging is not None:
+            staging.join()
 
     def _chunk_plan(self):
         """This tick's chunk rows as ``(rows, has_uncovered, verifying)``
@@ -824,13 +949,15 @@ class DecodeBatcher:
             n = len(tokens)
             tok[i, :n] = tokens
             cpos[i, :n] = np.arange(slot.pos, slot.pos + n, dtype=np.int32)
-        feed = dict(self._caches)
-        feed[pf["tok"]] = tok
-        feed[pf["pos"]] = cpos
-        outs = pf["pred"].run(feed, return_numpy=False)
+        self._stage_ahead((b, c, k))
+        self._await_staged((b, c, k))
+        # the carried caches are handed over: from here on only what the
+        # run returns may be read, and that is what the table keeps
+        outs, donated = pf["pred"].run({pf["tok"]: tok, pf["pos"]: cpos},
+                                       self._caches)
         self.seen_signatures.add((b, c, k))
-        for name, idx in pf["cache_map"]:
-            self._caches[name] = outs[idx]
+        self._caches = {name: outs[idx] for name, idx in pf["cache_map"]}
+        self.metrics_.observe_cache_donated(donated)
         greedy = None
         now = self._clock()
         live = sum(1 for s in self._slots if s is not None)
@@ -893,7 +1020,8 @@ class DecodeBatcher:
         if sp:
             sp.set(live=live, bucket=b, ctx=c, chunk=k,
                    generated=generated, accepted=accepted,
-                   rejected=rejected)
+                   rejected=rejected,
+                   donated=len(self._caches) if donated else 0)
 
     def _maybe_harvest(self, i, slot):
         """First full ingestion of this prompt: offer its KV rows [0:L]
@@ -913,13 +1041,11 @@ class DecodeBatcher:
 
     def _synth_chunk_feed(self, b, c, k):
         pf = self._prefill
-        feed = {pf["tok"]: np.zeros((b, k), np.int64),
+        return {pf["tok"]: np.zeros((b, k), np.int64),
                 pf["pos"]: np.full((b, k), c, np.int32)}
-        for name, _idx, tail, dtype in self._cache_feeds:
-            feed[name] = np.zeros((b, c) + tail, dtype)
-        return feed
 
     def _step_once(self):
+        self._await_staged(self._bucket)
         with trace.span("decode.step") as sp:
             self._step_once_traced(sp)
 
@@ -931,17 +1057,17 @@ class DecodeBatcher:
             if slot is not None:
                 toks[i] = slot.next_token
                 pos[i] = slot.pos
-        feed = dict(self._caches)
-        feed[self._tok_feed] = toks
-        feed[self._pos_feed] = pos
-        outs = self._predictor.run(feed, return_numpy=False)
+        # carried state: the caches are handed over to the step (it writes
+        # its rows into the buffers it is given), and the fetched arrays,
+        # device-resident and never on the host, are the table's caches
+        # from here on; the arrays fed are deleted and not read again
+        outs, donated = self._step.run(
+            {self._tok_feed: toks, self._pos_feed: pos}, self._caches)
         sig = (b, c)
         self.seen_signatures.add(sig)
-        # carried state: fetched cache arrays feed the next step as-is
-        # (device-resident jax arrays round-trip through the feed dict
-        # without touching the host)
-        for name, idx, _tail, _dtype in self._cache_feeds:
-            self._caches[name] = outs[idx]
+        self._caches = {name: outs[idx]
+                        for name, idx, _tail, _dtype in self._cache_feeds}
+        self.metrics_.observe_cache_donated(donated)
         logits = np.asarray(outs[self._logits_idx])
         if self._counter_idx is not None:
             self.metrics_.observe_program_counters(
@@ -977,7 +1103,8 @@ class DecodeBatcher:
         self.metrics_.observe_decode_step(live, b, generated)
         if sp:
             # slot occupancy rides on every step span (ISSUE 17)
-            sp.set(live=live, bucket=b, ctx=c, generated=generated)
+            sp.set(live=live, bucket=b, ctx=c, generated=generated,
+                   donated=len(self._caches) if donated else 0)
 
     def _retire(self, i, slot, now):
         """Finished sequence: resolve, free the slot IMMEDIATELY (the

@@ -542,6 +542,11 @@ class ServingEngine:
                     for c in d.compiled_shape_counts()]
         return [len(w.seen_signatures) for w in self._workers]
 
+    def decode_compile_records(self):
+        """Decode mode: the compile records of every replica's step and
+        chunk executables (:meth:`DecodeBatcher.compile_records`)."""
+        return [r for d in self._decoders or () for r in d.compile_records()]
+
     def shutdown(self, drain=True, timeout_s=None):
         """Stop intake; with ``drain`` serve everything queued, otherwise
         cancel it. Joins the worker threads (warning on any that outlive

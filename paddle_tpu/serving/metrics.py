@@ -88,6 +88,13 @@ class ServingMetrics:
                             help="bytes of KV rows held by the prefix "
                                  "cache",
                             fn=lambda: self._prefix_bytes_fn())
+        # bytes of carried caches the decode loop handed over to its last
+        # step or chunk run (0: the predictor takes no hand-over, so every
+        # cache write copies its whole array first)
+        self._cache_donated = self.registry.gauge(
+            _PREFIX + "cache_donated_bytes",
+            help="bytes of carried caches handed over (donated) to the "
+                 "last decode quantum's executable")
         self.registry.histogram(_PREFIX + "latency_seconds", self.latency,
                                 help="request latency (sliding window)")
         self.registry.histogram(_PREFIX + "ttft_seconds", self.ttft,
@@ -172,6 +179,11 @@ class ServingMetrics:
         self._c["decode_tokens"].inc(generated)
         self._c["slot_live"].inc(live)
         self._c["slot_total"].inc(bucket)
+
+    def observe_cache_donated(self, nbytes):
+        """One decode quantum ran with ``nbytes`` of carried caches handed
+        over to its executable (``Executor.run(donate_feeds=...)``)."""
+        self._cache_donated.set(int(nbytes))
 
     def observe_prefix_hit(self, tokens_reused):
         """One admission cloned a cached KV prefix instead of
@@ -264,6 +276,7 @@ class ServingMetrics:
             "prefix_tokens_reused": c["prefix_tokens_reused"],
             "prefix_evictions": c["prefix_evictions"],
             "prefix_bytes": self._prefix_bytes_fn(),
+            "cache_donated_bytes": self._cache_donated.value,
             "prefill_chunks": c["prefill_chunks"],
             "prefill_tokens": c["prefill_tokens"],
             "spec_accepted": c["spec_accepted"],
@@ -307,7 +320,8 @@ class ServingMetrics:
                     "compile_cache_misses", "compile_cache_hit_rate",
                     "decode_steps", "decode_tokens", "slot_occupancy",
                     "prefix_hits", "prefix_tokens_reused",
-                    "prefix_evictions", "prefix_bytes", "prefill_chunks",
+                    "prefix_evictions", "prefix_bytes",
+                    "cache_donated_bytes", "prefill_chunks",
                     "prefill_tokens", "spec_accepted", "spec_rejected",
                     "spec_accept_rate"):
             lines.append("%-32s %14s" % (key, fmt(s[key])))

@@ -302,6 +302,7 @@ def test_registry_snapshot_consistency():
     m.observe_spec(accepted=3, rejected=1)
     m.bind_gauges(lambda: 7, lambda: 1)
     m.bind_prefix_bytes(lambda: 4096)
+    m.observe_cache_donated(1 << 20)
     snap = m.snapshot()
     vals = m.registry.values()
     for field in ("requests_completed", "requests_failed",
@@ -312,8 +313,9 @@ def test_registry_snapshot_consistency():
                   "compile_cache_hits", "compile_cache_misses",
                   "decode_steps", "decode_tokens", "queue_depth",
                   "in_flight", "prefix_hits", "prefix_tokens_reused",
-                  "prefix_evictions", "prefix_bytes", "prefill_chunks",
-                  "prefill_tokens", "spec_accepted", "spec_rejected"):
+                  "prefix_evictions", "prefix_bytes", "cache_donated_bytes",
+                  "prefill_chunks", "prefill_tokens", "spec_accepted",
+                  "spec_rejected"):
         assert vals["paddle_tpu_serving_" + field] == snap[field], field
     # derived fields still derive from registry counters
     assert snap["batch_occupancy"] == 3 / 4
@@ -321,6 +323,7 @@ def test_registry_snapshot_consistency():
     assert snap["compile_cache_hit_rate"] == 1.0
     assert snap["spec_accept_rate"] == 3 / 4
     assert snap["prefix_bytes"] == 4096
+    assert snap["cache_donated_bytes"] == 1 << 20
     # the pinned snapshot field list itself is unchanged (the contract
     # test_bench_contract.py leans on)
     assert set(snap) == {
@@ -333,8 +336,9 @@ def test_registry_snapshot_consistency():
         "compile_cache_hit_rate", "decode_steps", "decode_tokens",
         "slot_occupancy", "latency_s", "ttft_s", "tpot_s",
         "prefix_hits", "prefix_tokens_reused", "prefix_evictions",
-        "prefix_bytes", "prefill_chunks", "prefill_tokens",
-        "spec_accepted", "spec_rejected", "spec_accept_rate"}
+        "prefix_bytes", "cache_donated_bytes", "prefill_chunks",
+        "prefill_tokens", "spec_accepted", "spec_rejected",
+        "spec_accept_rate"}
 
 
 # -- the Executor's own spans, through the one primitive (ISSUE 24) ----------
