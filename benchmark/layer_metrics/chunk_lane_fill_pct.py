@@ -1,0 +1,13 @@
+"""Scheduler: of the token lanes the chunk dispatches of the timed window
+ran over (every slot row of the bucket padded to the chunk rung: the
+engine's ``prefill_lanes``), the share that ingested a prompt token
+(``prefill_tokens``). The rest is a chunk run's work on pad lanes. Program
+counter (PR 37)."""
+
+from benchmark import decode_spans
+
+
+def read(ctx):
+    fill = decode_spans.counter_ratio(ctx, "prefill_tokens",
+                                      "prefill_lanes")
+    return None if fill is None else 100.0 * fill

@@ -1,0 +1,10 @@
+"""``idle_unspanned_pct`` in a cell judged on latency: the same reading
+(``idle_unspanned_pct.py``) under a name of its own, because there it moves
+``token_ms_mean`` and not ``serve_tokens_per_s``."""
+
+import os
+
+from benchmark import harness
+
+read = harness.load_module(os.path.join(os.path.dirname(
+    os.path.abspath(__file__)), "idle_unspanned_pct.py")).read

@@ -85,6 +85,18 @@ floating-point-deterministic, not contractual.
 Sampling is host-side greedy argmax over the fetched next-token logits
 row: deterministic, per-row, and it keeps eos/length control flow out of
 the compiled step.
+
+**The loop accounts for its own quantum** (``obs.trace.span``: the tracer
+and, under a ``jax.profiler`` trace, ``paddle_tpu.<name>`` on its host
+plane; the falsy no-op with neither). Between two quanta ``decode.idle``
+(the loop's wait while no slot is live and nothing is queued),
+``decode.admit`` and ``decode.plan``; a quantum is ``decode.step`` over
+``decode.feed``, ``executor.run``, ``decode.fetch`` (the wait for the
+device and the logits' way to the host) and ``decode.sample``, or
+``prefill.chunk`` / ``spec.verify`` over ``decode.feed`` and
+``executor.run`` (a chunk's span ends at its dispatch; one that samples
+also fetches). No span waits for the device on its own account. README,
+Observability, lists their tags and the counters beside them.
 """
 
 import inspect
@@ -166,19 +178,16 @@ class _Carrying:
         except Exception:  # noqa: BLE001: the run itself will raise it
             pass
 
-    def run(self, feed, caches):
-        """One run over the quantum's own ``feed`` and the carried
-        ``caches``, which are handed over for good where the predictor
-        takes that: the executable may then write into them in place, and
-        after the call, failed or not, they must not be read again — only
-        what the run returns. Returns (outputs in fetch order, bytes
-        handed over)."""
-        feed.update(caches)
+    def run(self, feed):
+        """One run over ``feed``, the quantum's own arrays with the carried
+        caches merged in. Where the predictor takes that, the caches are
+        handed over for good: the executable may then write into them in
+        place, and after the call, failed or not, they must not be read
+        again — only what the run returns (outputs in fetch order)."""
         if not self.hands_over:
-            return self.predictor.run(feed, return_numpy=False), 0
-        outs = self.predictor.run(feed, return_numpy=False,
+            return self.predictor.run(feed, return_numpy=False)
+        return self.predictor.run(feed, return_numpy=False,
                                   donate_feeds=self.order)
-        return outs, sum(int(a.nbytes) for a in caches.values())
 
 
 class DraftLM:
@@ -451,6 +460,7 @@ class DecodeBatcher:
         self._pending = deque()
         self._slots = []          # list[_Slot | None], len == bucket_batch
         self._caches = {}         # feed name -> [B, C, *tail] array
+        self._cache_bytes = 0     # of them all: moves with the geometry
         self._bucket = (0, 0)     # (bucket_batch, bucket_ctx)
         self.seen_signatures = set()
         self._cv = threading.Condition()
@@ -562,15 +572,15 @@ class DecodeBatcher:
         warmed = 0
         for b in self.ladder:
             for c in self.ctx_ladder:
-                self._step.run(self._synth_feed(b),
-                               self._synth_caches(b, c))
+                self._step.run({**self._synth_feed(b),
+                                **self._synth_caches(b, c)})
                 self.seen_signatures.add((b, c))
                 warmed += 1
                 if self._prefill is not None:
                     for k in self.prefill_ladder:
                         self._prefill["pred"].run(
-                            self._synth_chunk_feed(b, c, k),
-                            self._synth_caches(b, c))
+                            {**self._synth_chunk_feed(b, c, k),
+                             **self._synth_caches(b, c)})
                         self.seen_signatures.add((b, c, k))
                         warmed += 1
         return warmed
@@ -630,7 +640,12 @@ class DecodeBatcher:
             with self._cv:
                 while (not self._closed and not self._pending
                        and not any(s is not None for s in self._slots)):
-                    self._cv.wait()
+                    # no request to serve: the device idles because nothing
+                    # was asked of it, which the span and the counter say
+                    with trace.span("decode.idle"):
+                        since = self._clock()
+                        self._cv.wait()
+                        self.metrics_.observe_idle(self._clock() - since)
                 if self._closed:
                     if self._aborted:
                         break
@@ -669,6 +684,7 @@ class DecodeBatcher:
         next admission re-buckets from nothing, into new zero caches."""
         self._slots = []
         self._caches = {}
+        self._cache_bytes = 0
         self._bucket = (0, 0)
 
     def _fail_pending(self, exc=None):
@@ -706,25 +722,35 @@ class DecodeBatcher:
 
     # admission + re-bucketing — runs BETWEEN steps only (slot recycling)
     def _admit(self):
-        now = self._clock()
-        admitted = []
-        with self._cv:
-            live = sum(1 for s in self._slots if s is not None)
-            room = max(self.ladder) - live
-            while self._pending and room > 0:
-                req = self._pending.popleft()
-                if req.deadline is not None and now > req.deadline:
-                    self._resolve_exc(req, DeadlineExceededError(
-                        "request waited %.1f ms, deadline was %.1f ms"
-                        % ((now - req.enqueue_t) * 1e3,
-                           (req.deadline - req.enqueue_t) * 1e3)))
-                    self.metrics_.observe_expired()
-                    continue
-                admitted.append(req)
-                room -= 1
-        if not admitted and self._bucket == self._target_bucket([]):
-            return
-        self._rebucket(admitted)
+        with trace.span("decode.admit") as sp:
+            now = self._clock()
+            admitted = []
+            waited = 0.0
+            with self._cv:
+                live = sum(1 for s in self._slots if s is not None)
+                room = max(self.ladder) - live
+                while self._pending and room > 0:
+                    req = self._pending.popleft()
+                    if req.deadline is not None and now > req.deadline:
+                        self._resolve_exc(req, DeadlineExceededError(
+                            "request waited %.1f ms, deadline was %.1f ms"
+                            % ((now - req.enqueue_t) * 1e3,
+                               (req.deadline - req.enqueue_t) * 1e3)))
+                        self.metrics_.observe_expired()
+                        continue
+                    admitted.append(req)
+                    waited += now - req.enqueue_t
+                    room -= 1
+                pending = len(self._pending)
+            copied = None
+            if admitted:
+                self.metrics_.observe_admitted(len(admitted), waited)
+            if admitted or self._bucket != self._target_bucket([]):
+                copied = self._rebucket(admitted)
+            if sp:
+                moved = {} if copied is None else {"copied_bytes": copied}
+                sp.set(admitted=len(admitted), pending=pending,
+                       rebucketed=int(copied is not None), **moved)
 
     def _target_bucket(self, admitting):
         live = [s.req for s in self._slots if s is not None]
@@ -748,7 +774,9 @@ class DecodeBatcher:
 
         Geometry moved (occupancy crossed a ladder rung, or a longer
         request raised the ctx rung): live rows compact into fresh
-        zero arrays — the one host-side copy re-bucketing costs."""
+        zero arrays — the one host-side copy re-bucketing costs. Returns
+        the bytes of live rows that copy moved, or None where the geometry
+        stayed."""
         new_b, new_c = self._target_bucket(admitting)
         if (new_b, new_c) == self._bucket:
             if admitting:
@@ -756,7 +784,7 @@ class DecodeBatcher:
                 for req, i in zip(admitting, free):
                     self._slots[i] = _Slot(req)
                     self._install_prefix(i, req)
-            return
+            return None
         old_c = self._bucket[1]
         live = [(i, s) for i, s in enumerate(self._slots) if s is not None]
         new_slots = [s for _, s in live]
@@ -764,6 +792,7 @@ class DecodeBatcher:
             new_slots.append(_Slot(req))
         new_slots += [None] * (new_b - len(new_slots))
         copy_c = min(old_c, new_c)
+        copied = 0
         for feed, _idx, tail, dtype in self._cache_feeds:
             old = self._caches.get(feed)
             new = np.zeros((new_b, new_c) + tail, dtype)
@@ -771,11 +800,14 @@ class DecodeBatcher:
                 old = np.asarray(old)
                 for j, (i, _s) in enumerate(live):
                     new[j, :copy_c] = old[i, :copy_c]
+                copied += len(live) * new[0, :copy_c].nbytes
             self._caches[feed] = new
+        self._cache_bytes = sum(a.nbytes for a in self._caches.values())
         self._slots = new_slots
         self._bucket = (new_b, new_c)
         for j, req in enumerate(admitting, start=len(live)):
             self._install_prefix(j, req)
+        return copied
 
     def _install_prefix(self, i, req):
         """Clone a matched prefix's leading rows into slot row ``i`` and
@@ -815,7 +847,11 @@ class DecodeBatcher:
         if self._prefill is None:
             self._step_once()
             return
-        plan = self._chunk_plan()
+        with trace.span("decode.plan") as sp:
+            plan = self._chunk_plan()
+            if sp:
+                sp.set(rows=len(plan[0]) if plan else 0,
+                       verifying=bool(plan and plan[2]))
         if plan is None:
             self._alt_chunk = False
             self._step_once()
@@ -850,24 +886,28 @@ class DecodeBatcher:
             return
         import jax
 
-        shapes = {name: jax.ShapeDtypeStruct((b, c) + tail, dtype)
-                  for name, _idx, tail, dtype in self._cache_feeds}
-        for sig in ahead:
-            carrying, feed = ((self._step, self._synth_feed(b))
-                              if len(sig) == 2 else
-                              (self._prefill["pred"],
-                               self._synth_chunk_feed(*sig)))
-            self._ahead[sig] = threading.Thread(
-                target=carrying.stage, args=(feed, shapes),
-                name="paddle-tpu-decode-stage", daemon=True)
-            self._ahead[sig].start()
+        with trace.span("decode.plan") as sp:
+            shapes = {name: jax.ShapeDtypeStruct((b, c) + tail, dtype)
+                      for name, _idx, tail, dtype in self._cache_feeds}
+            for sig in ahead:
+                carrying, feed = ((self._step, self._synth_feed(b))
+                                  if len(sig) == 2 else
+                                  (self._prefill["pred"],
+                                   self._synth_chunk_feed(*sig)))
+                self._ahead[sig] = threading.Thread(
+                    target=carrying.stage, args=(feed, shapes),
+                    name="paddle-tpu-decode-stage", daemon=True)
+                self._ahead[sig].start()
+            if sp:
+                sp.set(staging=len(ahead))
 
     def _await_staged(self, sig):
         """The executable of ``sig`` is about to run: if a helper thread is
         staging it, let it finish (the run would stage it a second time)."""
         staging = self._ahead.pop(sig, None)
         if staging is not None:
-            staging.join()
+            with trace.span("decode.plan"):
+                staging.join()
 
     def _chunk_plan(self):
         """This tick's chunk rows as ``(rows, has_uncovered, verifying)``
@@ -943,21 +983,24 @@ class DecodeBatcher:
         b, c = self._bucket
         k = bucket_for(max(len(t) for _i, _s, t, _f in rows),
                        self.prefill_ladder)
-        tok = np.zeros((b, k), np.int64)
-        cpos = np.full((b, k), c, np.int32)
-        for i, slot, tokens, _f in rows:
-            n = len(tokens)
-            tok[i, :n] = tokens
-            cpos[i, :n] = np.arange(slot.pos, slot.pos + n, dtype=np.int32)
         self._stage_ahead((b, c, k))
         self._await_staged((b, c, k))
-        # the carried caches are handed over: from here on only what the
-        # run returns may be read, and that is what the table keeps
-        outs, donated = pf["pred"].run({pf["tok"]: tok, pf["pos"]: cpos},
-                                       self._caches)
+        with trace.span("decode.feed"):
+            tok = np.zeros((b, k), np.int64)
+            cpos = np.full((b, k), c, np.int32)
+            for i, slot, tokens, _f in rows:
+                n = len(tokens)
+                tok[i, :n] = tokens
+                cpos[i, :n] = np.arange(slot.pos, slot.pos + n,
+                                        dtype=np.int32)
+            # the carried caches are handed over: from here on only what
+            # the run returns may be read, and that is what the table keeps
+            feed = {pf["tok"]: tok, pf["pos"]: cpos, **self._caches}
+        outs = pf["pred"].run(feed)
         self.seen_signatures.add((b, c, k))
         self._caches = {name: outs[idx] for name, idx in pf["cache_map"]}
-        self.metrics_.observe_cache_donated(donated)
+        self.metrics_.observe_cache_donated(
+            self._cache_bytes if pf["pred"].hands_over else 0)
         greedy = None
         now = self._clock()
         live = sum(1 for s in self._slots if s is not None)
@@ -982,8 +1025,12 @@ class DecodeBatcher:
             # the chunk covered through the last prompt token (spec
             # prefill) or this is a verify row: emit the greedy chain
             if greedy is None:
-                greedy = np.argmax(
-                    np.asarray(outs[pf["logits_idx"]]), axis=-1)
+                # the one place a chunk waits for the device: it samples
+                with trace.span("decode.fetch") as fsp:
+                    logits = np.asarray(outs[pf["logits_idx"]])
+                    if fsp:
+                        fsp.set(bytes=int(logits.nbytes))
+                greedy = np.argmax(logits, axis=-1)
             req = slot.req
             j = n_forced - 1
             emitted = [int(greedy[i, j])]
@@ -1013,15 +1060,16 @@ class DecodeBatcher:
             else:
                 slot.next_token = slot.out[-1]
         if chunk_rows:
-            self.metrics_.observe_prefill_chunk(chunk_rows, chunk_toks)
+            self.metrics_.observe_prefill_chunk(chunk_rows, chunk_toks,
+                                                b * k)
         if accepted or rejected:
             self.metrics_.observe_spec(accepted, rejected)
         self.metrics_.observe_decode_step(live, b, generated)
         if sp:
             sp.set(live=live, bucket=b, ctx=c, chunk=k,
                    generated=generated, accepted=accepted,
-                   rejected=rejected,
-                   donated=len(self._caches) if donated else 0)
+                   rejected=rejected, rows=chunk_rows, tokens=chunk_toks,
+                   lanes=b * k)
 
     def _maybe_harvest(self, i, slot):
         """First full ingestion of this prompt: offer its KV rows [0:L]
@@ -1051,60 +1099,73 @@ class DecodeBatcher:
 
     def _step_once_traced(self, sp):
         b, c = self._bucket
-        toks = np.zeros((b,), np.int64)
-        pos = np.zeros((b,), np.int32)
-        for i, slot in enumerate(self._slots):
-            if slot is not None:
-                toks[i] = slot.next_token
-                pos[i] = slot.pos
-        # carried state: the caches are handed over to the step (it writes
-        # its rows into the buffers it is given), and the fetched arrays,
-        # device-resident and never on the host, are the table's caches
-        # from here on; the arrays fed are deleted and not read again
-        outs, donated = self._step.run(
-            {self._tok_feed: toks, self._pos_feed: pos}, self._caches)
+        with trace.span("decode.feed"):
+            toks = np.zeros((b,), np.int64)
+            pos = np.zeros((b,), np.int32)
+            for i, slot in enumerate(self._slots):
+                if slot is not None:
+                    toks[i] = slot.next_token
+                    pos[i] = slot.pos
+            # carried state: the caches are handed over to the step (it
+            # writes its rows into the buffers it is given), and the
+            # fetched arrays, device-resident and never on the host, are
+            # the table's caches from here on; the arrays fed are deleted
+            # and not read again
+            feed = {self._tok_feed: toks, self._pos_feed: pos,
+                    **self._caches}
+        outs = self._step.run(feed)
         sig = (b, c)
         self.seen_signatures.add(sig)
         self._caches = {name: outs[idx]
                         for name, idx, _tail, _dtype in self._cache_feeds}
-        self.metrics_.observe_cache_donated(donated)
-        logits = np.asarray(outs[self._logits_idx])
-        if self._counter_idx is not None:
-            self.metrics_.observe_program_counters(
-                self._counter_names,
-                np.asarray(outs[self._counter_idx]).ravel())
-        now = self._clock()
-        live = 0
-        generated = 0
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            live += 1
-            slot.pos += 1
-            if slot.forcing:
-                slot.next_token = slot.req.prompt[slot.k]
-                slot.k += 1
-                continue
-            if not slot.harvested and slot.pos >= len(slot.req.prompt):
-                self._maybe_harvest(i, slot)
-            nxt = int(np.argmax(logits[i]))
-            generated += 1
-            slot.out.append(nxt)
-            if slot.first_tok_t is None:
-                slot.first_tok_t = now
-                self.metrics_.observe_ttft(now - slot.req.enqueue_t)
-            done = (len(slot.out) >= slot.req.max_new
-                    or (slot.req.eos_id is not None
-                        and nxt == slot.req.eos_id))
-            if done:
-                self._retire(i, slot, now)
-            else:
-                slot.next_token = nxt
-        self.metrics_.observe_decode_step(live, b, generated)
+        self.metrics_.observe_cache_donated(
+            self._cache_bytes if self._step.hands_over else 0)
+        # the wait for the device and the logits' way to the host: the run
+        # above only dispatched the step
+        with trace.span("decode.fetch") as fsp:
+            logits = np.asarray(outs[self._logits_idx])
+            if self._counter_idx is not None:
+                self.metrics_.observe_program_counters(
+                    self._counter_names,
+                    np.asarray(outs[self._counter_idx]).ravel())
+            if fsp:
+                fsp.set(bytes=int(logits.nbytes))
+        with trace.span("decode.sample") as ssp:
+            now = self._clock()
+            live = 0
+            generated = 0
+            retired = 0
+            for i, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                live += 1
+                slot.pos += 1
+                if slot.forcing:
+                    slot.next_token = slot.req.prompt[slot.k]
+                    slot.k += 1
+                    continue
+                if not slot.harvested and slot.pos >= len(slot.req.prompt):
+                    self._maybe_harvest(i, slot)
+                nxt = int(np.argmax(logits[i]))
+                generated += 1
+                slot.out.append(nxt)
+                if slot.first_tok_t is None:
+                    slot.first_tok_t = now
+                    self.metrics_.observe_ttft(now - slot.req.enqueue_t)
+                done = (len(slot.out) >= slot.req.max_new
+                        or (slot.req.eos_id is not None
+                            and nxt == slot.req.eos_id))
+                if done:
+                    self._retire(i, slot, now)
+                    retired += 1
+                else:
+                    slot.next_token = nxt
+            self.metrics_.observe_decode_step(live, b, generated)
+            if ssp:
+                ssp.set(generated=generated, retired=retired)
         if sp:
             # slot occupancy rides on every step span (ISSUE 17)
-            sp.set(live=live, bucket=b, ctx=c, generated=generated,
-                   donated=len(self._caches) if donated else 0)
+            sp.set(live=live, bucket=b, ctx=c, generated=generated)
 
     def _retire(self, i, slot, now):
         """Finished sequence: resolve, free the slot IMMEDIATELY (the

@@ -54,6 +54,13 @@ _COUNTERS = (
     ("prefix_evictions", "prefix-cache entries evicted (LRU)"),
     ("prefill_chunks", "chunked-prefill / speculative-verify dispatches"),
     ("prefill_tokens", "prompt tokens ingested through chunk dispatches"),
+    ("prefill_lanes", "token lanes (slot rows x chunk rung) those chunk "
+                      "dispatches ran over"),
+    ("admitted", "requests the decode loop took from its queue into a slot"),
+    ("queue_wait_seconds", "seconds from submit to admission, summed over "
+                           "the admitted requests"),
+    ("idle_seconds", "seconds the decode loop waited with no request live "
+                     "or queued"),
     ("spec_accepted", "speculative draft tokens accepted by the verifier"),
     ("spec_rejected", "speculative draft tokens rejected by the verifier"),
 )
@@ -194,12 +201,32 @@ class ServingMetrics:
     def observe_prefix_eviction(self, n=1):
         self._c["prefix_evictions"].inc(n)
 
-    def observe_prefill_chunk(self, rows, tokens):
+    def observe_prefill_chunk(self, rows, tokens, lanes):
         """One chunk dispatch (prefill and/or speculative verify):
         ``rows`` slot rows participated, ``tokens`` prompt tokens were
-        ingested through it (verify lanes count under spec_*)."""
+        ingested through it (verify lanes count under spec_*), ``lanes``
+        token lanes the executable ran them over (every slot row of the
+        bucket padded to the chunk rung): tokens over lanes is the share
+        of a chunk run's work that ingested anything."""
         self._c["prefill_chunks"].inc()
         self._c["prefill_tokens"].inc(int(tokens))
+        self._c["prefill_lanes"].inc(int(lanes))
+
+    def observe_admitted(self, n, waited_s):
+        """The decode loop took ``n`` requests from its queue into slots;
+        ``waited_s``: their seconds since ``submit``, summed (``ttft``
+        starts at the same instant, so the difference of the two means is
+        prefill)."""
+        self._c["admitted"].inc(int(n))
+        self._c["queue_wait_seconds"].inc(float(waited_s))
+
+    def observe_idle(self, waited_s):
+        """The decode loop came back from a wait of ``waited_s`` seconds in
+        which no slot was live and nothing was queued: the device idled
+        because nothing was asked of it. Counted when the wait ENDS, so a
+        reading between two instants is off by at most the one wait under
+        way at each."""
+        self._c["idle_seconds"].inc(float(waited_s))
 
     def observe_program_counters(self, names, values):
         """What a decode step's program counted of itself (the spec's
@@ -279,6 +306,10 @@ class ServingMetrics:
             "cache_donated_bytes": self._cache_donated.value,
             "prefill_chunks": c["prefill_chunks"],
             "prefill_tokens": c["prefill_tokens"],
+            "prefill_lanes": c["prefill_lanes"],
+            "admitted": c["admitted"],
+            "queue_wait_seconds": c["queue_wait_seconds"],
+            "idle_seconds": c["idle_seconds"],
             "spec_accepted": c["spec_accepted"],
             "spec_rejected": c["spec_rejected"],
             "spec_accept_rate": (
@@ -322,8 +353,9 @@ class ServingMetrics:
                     "prefix_hits", "prefix_tokens_reused",
                     "prefix_evictions", "prefix_bytes",
                     "cache_donated_bytes", "prefill_chunks",
-                    "prefill_tokens", "spec_accepted", "spec_rejected",
-                    "spec_accept_rate"):
+                    "prefill_tokens", "prefill_lanes", "admitted",
+                    "queue_wait_seconds", "idle_seconds", "spec_accepted",
+                    "spec_rejected", "spec_accept_rate"):
             lines.append("%-32s %14s" % (key, fmt(s[key])))
         for group in ("latency_s", "ttft_s", "tpot_s"):
             prefix = group[:-2]  # strip the _s unit suffix

@@ -304,20 +304,35 @@ def test_variants_staged_from_many_threads_are_each_made_once():
     assert exe.runs == runs + 20 + len(rows)
 
 
-def test_spans_carry_the_number_of_caches_handed_over(family):
+def test_the_hand_over_is_told_by_the_gauge_and_the_records_not_a_tag(
+        family):
+    """The ``donated=`` tag PR 36 put on the quantum spans said what the
+    gauge and the compile records say, and nothing read it (ISSUE 37): the
+    spans carry what the quantum ran over, the gauge what was handed to
+    it, and each executable's record whether that engaged."""
     bat = _batcher(family)
     tracer = trace.start()
     try:
         bat.submit(LONG, max_new_tokens=3)
-        bat.drive()
+        bat._admit()
+        handed = []
+        while any(s is not None for s in bat._slots):
+            bat._tick()
+            handed.append((bat.metrics()["cache_donated_bytes"],
+                           _cache_bytes(bat)))
         spans = tracer.drain()
     finally:
         trace.stop()
+    assert handed and all(gauge == held > 0 for gauge, held in handed)
     for name in ("decode.step", "prefill.chunk"):
         found = [s for s in spans if s["name"] == name]
         assert found, name
-        assert all(s["tags"]["donated"] == len(bat._cache_feeds)
+        assert all("donated" not in s["tags"] and s["tags"]["bucket"] == 4
                    for s in found), name
+    ran = [r for r in bat.compile_records() if r["donated_feed_bytes"]]
+    assert len(ran) >= 2            # the step's and a chunk rung's
+    assert all(r["memory"]["alias_bytes"] >= r["donated_feed_bytes"]
+               == handed[0][1] for r in ran)
 
 
 # -- (iii) the same requests give the same tokens ----------------------------

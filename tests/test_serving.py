@@ -1300,3 +1300,290 @@ def test_decode_compile_cache_soak_within_bound():
         assert all(f.done() for f in futs)
     assert len(bat.seen_signatures) <= vbound
     assert all(c <= vbound for c in bat.compiled_shape_counts())
+
+
+# ---------------------------------------------------------------------------
+# the decode loop accounts for its own quantum (ISSUE 37): spans round
+# admission, planning, the feed, the wait for the logits and sampling, an
+# idle span where no request is live, counters for queue wait and chunk lanes
+# ---------------------------------------------------------------------------
+
+LONG_PROMPT = [3, 7, 11, 2, 5, 9, 4, 6, 1, 8, 2, 3]
+STEP_PARTS = ["decode.feed", "executor.run", "decode.fetch", "decode.sample"]
+LOOP_SITES = {"decode.admit", "decode.plan", "decode.feed", "decode.fetch",
+              "decode.sample", "decode.step", "prefill.chunk", "spec.verify",
+              "decode.idle"}
+
+
+@pytest.fixture(scope="module")
+def lm_family():
+    import paddle_tpu as fluid
+
+    return _build_lm_family(fluid.Scope())
+
+
+@pytest.fixture
+def no_tracer():
+    from paddle_tpu.obs import trace
+
+    trace.stop()
+    yield trace
+    trace.stop()
+
+
+def _ticking_clock():
+    import itertools
+
+    ticks = itertools.count()
+    return lambda: float(next(ticks))
+
+
+def _family_batcher(lm_family, speculate=False, **kw):
+    pred, dspec, prefill, draft = lm_family
+    kw.setdefault("ladder", (4,))
+    kw.setdefault("ctx_ladder", (32,))
+    if speculate:
+        kw["speculative"] = {"draft": draft, "k": 4}
+    return DecodeBatcher(pred, dspec, prefill=prefill, start=False, **kw)
+
+
+def _traced_drive(trace, bat, submits):
+    """Spans of one ``drive()`` under the tracer's ticking clock, in the
+    order they were opened, with the names of their children."""
+    for prompt, max_new in submits:     # every executable made or staged
+        bat.submit(prompt, max_new_tokens=max_new)
+    bat.drive()
+    tracer = trace.start(clock=_ticking_clock())
+    try:
+        for prompt, max_new in submits:
+            bat.submit(prompt, max_new_tokens=max_new)
+        bat.drive()
+        spans = sorted(tracer.drain(), key=lambda s: s["t0"])
+    finally:
+        trace.stop()
+    for s in spans:
+        s["kids"] = [k["name"] for k in spans
+                     if k["parent_id"] == s["span_id"]]
+    return spans
+
+
+@pytest.mark.parametrize("kind,speculate,submits,quantum,parts", [
+    ("step", False, [([5], 2)], "decode.step", STEP_PARTS),
+    ("chunk", False, [(LONG_PROMPT, 2)], "prefill.chunk",
+     ["decode.feed", "executor.run"]),
+    ("verify", True, [([5], 6)], "spec.verify",
+     ["decode.feed", "executor.run", "decode.fetch"]),
+])
+def test_a_quantum_of_each_kind_is_told_by_its_spans(
+        lm_family, no_tracer, kind, speculate, submits, quantum, parts):
+    bat = _family_batcher(lm_family, speculate)
+    spans = _traced_drive(no_tracer, bat, submits)
+    top = [s for s in spans if s["parent_id"] is None]
+    # admission, the plan, then the quantum: in that order, each time
+    at = next(i for i, s in enumerate(top) if s["name"] == quantum)
+    assert [s["name"] for s in top[at - 2:at + 1]] == [
+        "decode.admit", "decode.plan", quantum]
+    admit, plan, q = top[at - 2:at + 1]
+    # planning a verify chunk asks the draft model: its runs are the plan's
+    assert not admit["kids"] and set(plan["kids"]) == (
+        {"executor.run"} if kind == "verify" else set())
+    assert q["kids"] == parts
+    kids = [s for s in spans if s["parent_id"] == q["span_id"]]
+    # the ticking clock is read by the spans (and once by the loop's own
+    # ``now``): children follow each other inside their quantum
+    assert all(q["t0"] < k["t0"] and k["t0"] + k["dur"] < q["t0"] + q["dur"]
+               for k in kids)
+    assert all(a["t0"] + a["dur"] < b["t0"] for a, b in zip(kids, kids[1:]))
+    # a drive that ends drops the emptied table, so this one's first
+    # admission sets it up again (new zero arrays: nothing live to copy)
+    first = top[0]
+    assert first["name"] == "decode.admit" and first["tags"] == {
+        "admitted": 1, "pending": 0, "rebucketed": 1, "copied_bytes": 0}
+    assert top[3]["name"] == "decode.admit" and top[3]["tags"] == {
+        "admitted": 0, "pending": 0, "rebucketed": 0}
+    assert set(plan["tags"]) == {"rows", "verifying"}
+    assert "donated" not in q["tags"]
+    by_name = {k["name"]: k for k in kids}
+    if kind == "step":
+        assert plan["tags"] == {"rows": 0, "verifying": False}
+        assert q["tags"] == {"live": 1, "bucket": 4, "ctx": 32,
+                             "generated": 1}
+        logits = 4 * 29 * 4         # [bucket, vocabulary] float32
+        assert by_name["decode.fetch"]["tags"] == {"bytes": logits}
+        assert by_name["decode.sample"]["tags"] == {
+            "generated": 1, "retired": 0}
+        last = [s for s in top if s["name"] == "decode.step"][-1]
+        sample, = [s for s in spans if s["parent_id"] == last["span_id"]
+                   and s["name"] == "decode.sample"]
+        assert sample["tags"] == {"generated": 1, "retired": 1}
+    elif kind == "chunk":
+        assert plan["tags"] == {"rows": 1, "verifying": False}
+        assert {k: q["tags"][k] for k in (
+            "rows", "tokens", "lanes", "chunk", "bucket", "live")} == {
+            "rows": 1, "tokens": 11, "lanes": 64, "chunk": 16, "bucket": 4,
+            "live": 1}
+    else:
+        assert plan["tags"]["verifying"] is True
+        assert q["tags"]["lanes"] == 4 * q["tags"]["chunk"]
+        assert q["tags"]["generated"] >= 1
+        assert by_name["decode.fetch"]["tags"]["bytes"] == \
+            4 * q["tags"]["chunk"] * 29 * 4
+
+
+def test_admission_says_what_a_moved_geometry_copied(no_tracer):
+    model, bat = _fake_batcher(ladder=(1, 2), ctx_ladder=(8,))
+    tracer = no_tracer.start(clock=_ticking_clock())
+    bat.submit([1, 2, 3], max_new_tokens=4)
+    bat._admit()
+    bat._tick()
+    bat.submit([4], max_new_tokens=2)
+    bat.submit([5], max_new_tokens=2)           # no room: stays queued
+    bat._admit()
+    admits = [s["tags"] for s in tracer.drain()
+              if s["name"] == "decode.admit"]
+    bat.shutdown(drain=False)
+    assert admits[0] == {"admitted": 1, "pending": 0, "rebucketed": 1,
+                         "copied_bytes": 0}
+    # one live row of 8 positions x [2] float32 moves into the new arrays
+    assert admits[1] == {"admitted": 1, "pending": 1, "rebucketed": 1,
+                         "copied_bytes": 8 * 2 * 4}
+
+
+@pytest.mark.parametrize("case", ["queue_wait", "chunk_lanes"])
+def test_counters_move_by_what_the_clock_and_the_geometry_give(
+        lm_family, case):
+    now = [10.0]
+    bat = _family_batcher(lm_family, clock=lambda: now[0])
+    if case == "queue_wait":
+        bat.submit([5], max_new_tokens=1)
+        now[0] = 12.0
+        bat.submit([7], max_new_tokens=1)
+        now[0] = 15.0
+        bat._admit()
+        m = bat.metrics()
+        assert (m["admitted"], m["queue_wait_seconds"]) == (2, 5.0 + 3.0)
+        bat._admit()                # nobody new: nothing counted twice
+        assert bat.metrics()["admitted"] == 2
+        bat.drive()
+        m = bat.metrics()
+        assert (m["admitted"], m["queue_wait_seconds"]) == (2, 8.0)
+        # TTFT starts at the same instant: with the clock standing still
+        # after admission, every first token waited what its queue did
+        assert bat.metrics_.ttft.total == 8.0
+    else:
+        bat.submit(LONG_PROMPT, max_new_tokens=1)    # 11 of 12 by chunk
+        bat.submit(LONG_PROMPT[:6], max_new_tokens=1)  # 5 of 6
+        bat.drive()
+        m = bat.metrics()
+        # one dispatch: 4 slot rows padded to the 16-token rung
+        assert (m["prefill_chunks"], m["prefill_tokens"],
+                m["prefill_lanes"]) == (1, 16, 4 * 16)
+        bat.submit(LONG_PROMPT[:4], max_new_tokens=1)  # 3: the rung of 4
+        bat.drive()
+        m = bat.metrics()
+        assert (m["prefill_chunks"], m["prefill_tokens"],
+                m["prefill_lanes"]) == (2, 19, 4 * 16 + 4 * 4)
+    text = bat.metrics_.prometheus_text()
+    for name in ("admitted", "queue_wait_seconds", "prefill_lanes",
+                 "idle_seconds"):
+        assert "\npaddle_tpu_serving_%s " % name in text
+    # a batcher driven by hand never waits for a request
+    assert bat.metrics()["idle_seconds"] == 0
+
+
+@pytest.mark.parametrize("kind", ["step", "chunk", "verify", "loop"])
+def test_with_tracing_off_every_site_is_handed_the_null_span(
+        lm_family, no_tracer, monkeypatch, kind):
+    """No tracer, no profiler trace: ``trace.span`` hands each site of the
+    loop the one falsy ``_NULL_SPAN``, allocates nothing that stays, and
+    nothing in the loop waits for the device on a span's behalf."""
+    import inspect
+    import tracemalloc
+
+    import jax
+
+    from paddle_tpu.serving import decode_batcher
+
+    trace = no_tracer
+    handed = []
+    real = trace.span
+
+    def recording(name, *a, **k):
+        sp = real(name, *a, **k)
+        handed.append((name, sp))
+        return sp
+
+    def refuse(*a, **k):
+        raise AssertionError("the loop waited for the device")
+
+    monkeypatch.setattr(jax, "block_until_ready", refuse)
+    if kind == "loop":
+        model, bat = _fake_batcher(start=True)
+        monkeypatch.setattr(trace, "span", recording)
+        assert list(bat.predict([1, 2], max_new_tokens=3,
+                                timeout_s=30.0)) == _counting_seq(2, 3)
+        time.sleep(0.05)            # the loop goes back to its wait
+        assert list(bat.predict([4], max_new_tokens=2,
+                                timeout_s=30.0)) == _counting_seq(4, 2)
+        bat.shutdown()
+        wanted = {"decode.idle", "decode.admit", "decode.step",
+                  "decode.feed", "decode.fetch", "decode.sample"}
+    else:
+        bat = _family_batcher(lm_family, speculate=kind == "verify")
+        submits = [(LONG_PROMPT, 2)] if kind == "chunk" else [([5], 3)]
+
+        def drive():
+            for prompt, max_new in submits:
+                bat.submit(prompt, max_new_tokens=max_new)
+            bat.drive()
+
+        drive()                     # executables made, lazy caches warm
+        tracemalloc.start()
+        try:
+            before = tracemalloc.take_snapshot()
+            drive()
+            after = tracemalloc.take_snapshot()
+        finally:
+            tracemalloc.stop()
+        leaks = [s for s in after.compare_to(before, "lineno")
+                 if s.traceback[0].filename == trace.__file__
+                 and s.size_diff > 0]
+        assert not leaks, "spans that are off allocated: %s" % leaks
+        monkeypatch.setattr(trace, "span", recording)
+        drive()
+        wanted = {"decode.admit", "decode.plan", "decode.feed",
+                  "executor.run"} | {
+            "step": {"decode.step", "decode.fetch", "decode.sample"},
+            "chunk": {"prefill.chunk", "decode.step"},
+            "verify": {"spec.verify", "decode.fetch"}}[kind]
+    names = {name for name, _ in handed}
+    assert wanted <= names, wanted - names
+    assert all(sp is trace._NULL_SPAN for _, sp in handed)
+    assert {n for n in names if not n.startswith("executor.")} <= LOOP_SITES
+    assert "block_until_ready" not in inspect.getsource(decode_batcher)
+
+
+def test_the_loops_wait_for_a_request_is_a_span_of_its_own(no_tracer):
+    """The loop thread with nothing to serve sits in ``decode.idle``; the
+    span ends when a request arrives, before its admission, and
+    ``idle_seconds`` counts the wait as it ends."""
+    tracer = no_tracer.start()
+    model, bat = _fake_batcher(start=True)
+    time.sleep(0.05)
+    assert list(bat.predict([1, 2], max_new_tokens=3,
+                            timeout_s=30.0)) == _counting_seq(2, 3)
+    bat.shutdown()
+    spans = sorted(tracer.drain(), key=lambda s: s["t0"])
+    idle = [s for s in spans if s["name"] == "decode.idle"]
+    admit = [s for s in spans if s["name"] == "decode.admit"]
+    assert idle and admit and idle[0]["dur"] >= 0.04
+    # the counter's two clock reads lie inside the span, round the wait
+    waited = sum(s["dur"] for s in idle)
+    assert 0.04 <= bat.metrics()["idle_seconds"] <= waited
+    assert bat.metrics()["idle_seconds"] > waited - 0.01 * len(idle)
+    assert idle[0]["t0"] + idle[0]["dur"] <= admit[0]["t0"]
+    assert all(s["parent_id"] is None for s in idle + admit)
+    # between two quanta with a live request the loop does not wait
+    steps = [s for s in spans if s["name"] == "decode.step"]
+    assert len(steps) == 4
+    assert not [s for s in idle if steps[0]["t0"] < s["t0"] < steps[-1]["t0"]]

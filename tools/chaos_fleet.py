@@ -343,6 +343,11 @@ def main(argv=None):
                                    sleep=lambda _t: None)
         s.close()
         trainer.run(s, max_steps=4)
+        # the periodic publish writes in the background, and the drill's
+        # clean round needs that version ON DISK: on a loaded machine the
+        # write lost its race with the engines' start-up and the round
+        # found nothing to swap to (swap_ok false). close() joins it.
+        trainer.close()
         engines = {"a": serving.ServingEngine(trainer.serve_dir,
                                               num_replicas=1),
                    "b": serving.ServingEngine(trainer.serve_dir,
