@@ -225,7 +225,12 @@ def decode_cache_verdict(spec, ladder, ctx_ladder, budget=None,
     executable per (batch rung, ctx rung, prefill rung) triple, so the
     bound is ``len(ladder) * len(ctx_ladder) * (1 + len(prefill_ladder))``
     — structural, not empirical (duplicate rungs are deduped the way
-    ``DecodeBatcher`` dedups them). Returns ``(bound, AnalysisResult)``:
+    ``DecodeBatcher`` dedups them). A chunk executable is made for the
+    rung's own height, ``decode_batcher.chunk_rows(k, b)`` rows of the
+    bucket's ``b`` (``[rows, k]`` tokens, ``[rows, c, *tail]`` caches),
+    and for nothing else, so sub-batched chunks add no triple; the two
+    jitted row copies beside a sub-batched rung are not step programs
+    and are not counted. Returns ``(bound, AnalysisResult)``:
     a finding when the bound exceeds the budget, plus one for each ctx
     rung above the decode spec's ``ctx_cap`` and one for each prefill
     rung above it (suspect ladder config: the programs were sized for

@@ -52,12 +52,27 @@ configured:
   * **chunked prefill** (``prefill=``): a K-token chunk program
     (``transformer_lm_chunk``) ingests K prompt tokens per dispatch on
     its own pow2 prefill ladder, interleaved chunk-by-chunk with decode
-    steps (when some live rows aren't covered by a chunk, the scheduler
-    alternates chunk/step ticks) so a long prompt neither pays
-    step-per-token TTFT nor stalls its co-riders. The compile cache
-    gains one executable per (batch rung, ctx rung, prefill rung) —
-    proved by ``analysis.resources.decode_cache_verdict`` via
-    :meth:`DecodeBatcher.compile_cache_bound`.
+    steps (when some live rows generate and so cannot ride a chunk, the
+    scheduler alternates chunk/step ticks) so a long prompt neither pays
+    step-per-token TTFT nor stalls its co-riders. A chunk run computes
+    the rows that INGEST, not the slot table: each rung ``k`` has ONE
+    height, ``chunk_rows(k, b)`` (the power of two whose lanes stay
+    inside ``CHUNK_TOKEN_BUDGET``, at most the bucket), its executable is
+    made for ``[rows, k]`` tokens and ``[rows, c, *tail]`` caches, and
+    where that is under the bucket the loop gathers the oldest ingesting
+    rows' caches into a sub-batch on the device
+    (``serve_rows_gather``), hands the chunk run those, and scatters the
+    lanes it wrote back into the table in place
+    (``serve_rows_scatter``, the table handed over); rows the sub-batch
+    has no room for keep their place and ride the next chunk tick,
+    oldest admission first. Where the height is the bucket the chunk
+    runs over the table itself, and a batcher that verifies drafts keeps
+    every rung at the bucket (a verifying tick reads every live row's
+    logits). The compile cache gains one executable per (batch rung,
+    ctx rung, prefill rung) — proved by
+    ``analysis.resources.decode_cache_verdict`` via
+    :meth:`DecodeBatcher.compile_cache_bound` — and the two small copies
+    beside each sub-batched one.
   * **speculative decode** (``speculative=``): a small draft LM proposes
     k-1 tokens per generating row; ONE pass of the chunk program scores
     all k positions (the weight-sharing family makes the verifier free)
@@ -80,7 +95,10 @@ batched-with-strangers output is BITWISE-identical to solo decode —
 ``tests/test_serving.py`` pins this for greedy (here) and beam (the
 one-shot path). Across different bucket geometries the math is identical
 per row but runs in different executables, so parity there is
-floating-point-deterministic, not contractual.
+floating-point-deterministic, not contractual. A chunk's sub-batch is
+another geometry in that sense: a row's lanes see the same cache row and
+the same positions as they would in the slot table, computed by the
+executable of ``[rows, k]`` and not of ``[b, k]``.
 
 Sampling is host-side greedy argmax over the fetched next-token logits
 row: deterministic, per-row, and it keeps eos/length control flow out of
@@ -99,6 +117,7 @@ also fetches). No span waits for the device on its own account. README,
 Observability, lists their tags and the counters beside them.
 """
 
+import functools
 import inspect
 import threading
 import time
@@ -116,7 +135,7 @@ from .prefix_cache import PrefixCache
 
 __all__ = ["DecodeBatcher", "DecodeRequest", "DraftLM", "save_decode_spec",
            "load_decode_spec", "default_ctx_ladder",
-           "default_prefill_ladder"]
+           "default_prefill_ladder", "chunk_rows"]
 
 DECODE_SPEC_FILE = "decode_spec.json"
 
@@ -142,6 +161,77 @@ def default_prefill_ladder(spec):
     cap = int(spec.get("ctx_cap", 256) or 256)
     top = min(cap, max(4, cap // 2))
     return tuple(r for r in pow2_ladder(top) if r >= 4) or (min(4, cap),)
+
+
+# The token lanes one chunk run computes, at most: rows x rung; one value for
+# every geometry. Settled by a sweep of 256 / 512 / 1024 / 2048 on the chip in
+# the three serving cells (PERF.md section 6, PR 38): the one value under
+# which every cell gains. A backlog of short prompts, where one row ingests
+# at a time, wants it smaller still; long prompts several rows at a time
+# want 1024, because a decode step runs between two chunk ticks whatever
+# they ingest.
+CHUNK_TOKEN_BUDGET = 512
+
+
+def chunk_rows(k, b):
+    """The rows a chunk run of rung ``k`` computes in a bucket of ``b`` slot
+    rows: the largest power of two whose lanes stay inside
+    ``CHUNK_TOKEN_BUDGET``, at least one row and at most the bucket. ONE
+    height a rung, so the chunk executables stay one a ``(b, c, k)``: the
+    plan, the feeds, the staging and the warm-up all ask here. Where it
+    gives ``b`` the chunk runs over the slot table itself, as it did before
+    there were sub-batches."""
+    rows = max(1, CHUNK_TOKEN_BUDGET // int(k))
+    return min(1 << (rows.bit_length() - 1), int(b))
+
+
+@functools.lru_cache(maxsize=None)
+def _rows_helpers(lanes):
+    """The two jitted copies round a sub-batched chunk run (the jit names
+    are what a device trace shows), each a loop over the ``n`` sub-rows
+    that hold a slot row, so a pad sub-row costs nothing:
+    ``serve_rows_gather(table, idx, n)`` takes the slot table's caches
+    ``{name: [b, c, *tail]}`` to the sub-batch's ``[len(idx), c, *tail]``,
+    sub-row ``j < n`` a copy of table row ``idx[j]`` and the pad sub-rows
+    zeros (every lane of theirs is a pad lane, so nothing of them is
+    kept); ``serve_rows_scatter(table, sub, idx, start, n)``, the TABLE
+    donated, writes the lanes ``[start[j], start[j] + lanes)`` of sub-row
+    ``j < n`` back into row ``idx[j]`` in place: the lanes its chunk
+    wrote, slid down where they would pass the capacity (a lane the run
+    left alone holds what the gather read, so writing it back changes
+    nothing). Whole rows written back measured 2.5-10.3 ms for OPT-1.3B's
+    48 caches where the lanes measure 2.0-2.6 (PERF.md section 6, PR 38)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def serve_rows_gather(table, idx, n):
+        def copy_row(j, sub):
+            return {name: lax.dynamic_update_slice_in_dim(
+                sub[name], lax.dynamic_slice_in_dim(a, idx[j], 1, axis=0),
+                j, axis=0) for name, a in table.items()}
+
+        return lax.fori_loop(0, n, copy_row, {
+            name: jnp.zeros(idx.shape + a.shape[1:], a.dtype)
+            for name, a in table.items()})
+
+    def serve_rows_scatter(table, sub, idx, start, n):
+        def write_row(j, table):
+            out = {}
+            for name, a in table.items():
+                width = min(lanes, a.shape[1])
+                at = jnp.clip(start[j], 0, a.shape[1] - width)
+                rest = (0,) * (a.ndim - 2)
+                out[name] = lax.dynamic_update_slice(
+                    a, lax.dynamic_slice(sub[name], (j, at) + rest,
+                                         (1, width) + a.shape[2:]),
+                    (idx[j], at) + rest)
+            return out
+
+        return lax.fori_loop(0, n, write_row, table)
+
+    return (jax.jit(serve_rows_gather),
+            jax.jit(serve_rows_scatter, donate_argnums=(0,)))
 
 
 class _Carrying:
@@ -269,7 +359,7 @@ class DecodeRequest:
     and the admission timestamps the TTFT/TPOT metrics read."""
 
     __slots__ = ("prompt", "max_new", "eos_id", "future", "enqueue_t",
-                 "deadline", "n_ctx", "prefix")
+                 "deadline", "n_ctx", "prefix", "order")
 
     def __init__(self, prompt, max_new, eos_id, future, enqueue_t,
                  deadline=None, prefix=None):
@@ -282,6 +372,9 @@ class DecodeRequest:
         # pinned PrefixEntry matched at submit (cloned + released at
         # admission), or None
         self.prefix = prefix
+        # its place among the admissions (set when a slot takes it): a
+        # sub-batched chunk takes the oldest ingesting rows first
+        self.order = 0
         # cache capacity this request needs: every prompt token is written
         # once, then at most max_new-1 generated tokens are fed back (the
         # last sampled token never re-enters the cache), so the highest
@@ -437,6 +530,8 @@ class DecodeBatcher:
                 "cache_map": cmap}
         self._alt_chunk = False
         self._ahead = {}  # signature -> the thread staging its executable
+        self._rows_staged = {}  # chunk signature -> its two copies, compiled
+        self._admissions = 0
 
         # -- speculative decode (optional, rides the chunk program)
         self._draft = None
@@ -566,7 +661,8 @@ class DecodeBatcher:
     def warmup(self):
         """Pre-compile every (batch rung, ctx rung) step geometry — and,
         when a chunk program rides along, every (batch, ctx, chunk rung)
-        chunk geometry — with a zero-token synthetic dispatch, so live
+        chunk geometry at its sub-batch height, with the two copies round
+        a sub-batched run — with a zero-token synthetic dispatch, so live
         traffic never compiles. Returns the number of geometries
         warmed."""
         warmed = 0
@@ -580,7 +676,9 @@ class DecodeBatcher:
                     for k in self.prefill_ladder:
                         self._prefill["pred"].run(
                             {**self._synth_chunk_feed(b, c, k),
-                             **self._synth_caches(b, c)})
+                             **self._synth_caches(
+                                 self._chunk_height(b, k), c)})
+                        self._rows_copies((b, c, k))
                         self.seen_signatures.add((b, c, k))
                         warmed += 1
         return warmed
@@ -738,6 +836,8 @@ class DecodeBatcher:
                                (req.deadline - req.enqueue_t) * 1e3)))
                         self.metrics_.observe_expired()
                         continue
+                    req.order = self._admissions
+                    self._admissions += 1
                     admitted.append(req)
                     waited += now - req.enqueue_t
                     room -= 1
@@ -843,7 +943,9 @@ class DecodeBatcher:
         decode step. When some live rows can't ride the chunk (they are
         generating and speculation is off), chunk and step ticks
         ALTERNATE so a long prompt is ingested chunk-by-chunk without
-        stalling its co-riders."""
+        stalling its co-riders. Alternation is for rows that generate: an
+        ingesting row the sub-batch had no room for rides the next chunk,
+        and where no row generates that chunk is the next tick."""
         if self._prefill is None:
             self._step_once()
             return
@@ -856,7 +958,7 @@ class DecodeBatcher:
             self._alt_chunk = False
             self._step_once()
             return
-        rows, has_uncovered, verifying = plan
+        rows, has_uncovered, verifying, deferred = plan
         if has_uncovered and self._alt_chunk:
             self._alt_chunk = False
             self._step_once()
@@ -864,7 +966,7 @@ class DecodeBatcher:
         self._alt_chunk = True
         with trace.span("spec.verify" if verifying
                         else "prefill.chunk") as sp:
-            self._chunk_once(rows, sp)
+            self._chunk_once(rows, deferred, sp)
 
     def _stage_ahead(self, now):
         """A chunk run of signature ``now`` is about to be dispatched: have
@@ -884,22 +986,66 @@ class DecodeBatcher:
                  and sig not in self._ahead]
         if not ahead:
             return
-        import jax
-
         with trace.span("decode.plan") as sp:
-            shapes = {name: jax.ShapeDtypeStruct((b, c) + tail, dtype)
-                      for name, _idx, tail, dtype in self._cache_feeds}
             for sig in ahead:
-                carrying, feed = ((self._step, self._synth_feed(b))
-                                  if len(sig) == 2 else
-                                  (self._prefill["pred"],
-                                   self._synth_chunk_feed(*sig)))
                 self._ahead[sig] = threading.Thread(
-                    target=carrying.stage, args=(feed, shapes),
+                    target=self._stage, args=(sig,),
                     name="paddle-tpu-decode-stage", daemon=True)
                 self._ahead[sig].start()
             if sp:
                 sp.set(staging=len(ahead))
+
+    def _cache_shapes(self, rows, c):
+        """{cache feed: the shape and type of its ``rows`` x ``c`` array}."""
+        import jax
+
+        return {name: jax.ShapeDtypeStruct((rows, c) + tail, dtype)
+                for name, _idx, tail, dtype in self._cache_feeds}
+
+    def _stage(self, sig):
+        """Make (or load) the executable of ``sig`` from shapes, nothing
+        run: the step's at the slot table's shapes, a chunk rung's at its
+        sub-batch's, and with it the two copies round its runs."""
+        b, c = sig[:2]
+        if len(sig) == 2:
+            self._step.stage(self._synth_feed(b), self._cache_shapes(b, c))
+            return
+        self._prefill["pred"].stage(
+            self._synth_chunk_feed(*sig),
+            self._cache_shapes(self._chunk_height(b, sig[2]), c))
+        try:
+            self._rows_copies(sig)
+        except Exception:  # noqa: BLE001: the run itself will raise it
+            pass
+
+    def _chunk_height(self, b, k):
+        """The rows the chunk executable of rung ``k`` is made for in a
+        bucket of ``b``: :func:`chunk_rows`; the whole bucket where drafts
+        are verified, since a verifying tick reads every live row's
+        logits and one height a rung is all the bound allows."""
+        return b if self._spec_k else chunk_rows(k, b)
+
+    def _rows_copies(self, sig):
+        """(gather, scatter) compiled for the chunk signature ``sig``, or
+        None where its chunk runs over the slot table itself. Compiled
+        from shapes (a staging thread does it beside the rung's own
+        executable), once a signature."""
+        b, c, k = sig
+        r = self._chunk_height(b, k)
+        if r >= b:
+            return None
+        made = self._rows_staged.get(sig)
+        if made is None:
+            import jax
+
+            gather, scatter = _rows_helpers(min(k, c))
+            table, sub = self._cache_shapes(b, c), self._cache_shapes(r, c)
+            idx = jax.ShapeDtypeStruct((r,), np.dtype("int32"))
+            n = jax.ShapeDtypeStruct((), np.dtype("int32"))
+            made = self._rows_staged[sig] = (
+                gather.lower(table, idx, n).compile(),
+                scatter.lower(table, sub, idx, idx, n).compile())
+        return made
 
     def _await_staged(self, sig):
         """The executable of ``sig`` is about to run: if a helper thread is
@@ -910,13 +1056,20 @@ class DecodeBatcher:
                 staging.join()
 
     def _chunk_plan(self):
-        """This tick's chunk rows as ``(rows, has_uncovered, verifying)``
-        — or None when no live row wants the chunk program. Each row is
-        ``(i, slot, tokens, n_forced)``: lane j of the chunk feeds
-        ``tokens[j]`` at cache index ``slot.pos + j``; the first
+        """This tick's chunk rows as ``(rows, has_uncovered, verifying,
+        deferred)`` — or None when no live row wants the chunk program.
+        Each row is ``(i, slot, tokens, n_forced)``: lane j of the chunk
+        feeds ``tokens[j]`` at cache index ``slot.pos + j``; the first
         ``n_forced`` tokens are committed (prompt or already-emitted),
         the rest are speculative drafts judged against the chunk's own
-        logits."""
+        logits.
+
+        Without speculation the chunk is the rung of the OLDEST ingesting
+        row's pending prompt, and as many ingesting rows ride as that
+        rung's sub-batch is high (:func:`chunk_rows`), oldest admission
+        first, each ingesting up to the rung; ``deferred`` counts those
+        left for the next chunk tick. No row waits behind a younger one,
+        and the head always rides, so nobody starves."""
         spec = self._spec_k > 0
         top = self.prefill_ladder[-1]
         ingest = []     # (i, slot, want) — rows with prompt left
@@ -939,6 +1092,14 @@ class DecodeBatcher:
                 uncovered += 1
         if not ingest and not verify:
             return None
+        deferred = 0
+        if not spec:
+            ingest.sort(key=lambda row: row[1].req.order)
+            k = bucket_for(ingest[0][2], self.prefill_ladder)
+            height = self._chunk_height(self._bucket[0], k)
+            deferred = max(0, len(ingest) - height)
+            ingest = [(i, slot, min(want, k))
+                      for i, slot, want in ingest[:height]]
         rows = []
         for i, slot, want in ingest:
             toks = slot.req.prompt[slot.pos:slot.pos + want]
@@ -952,7 +1113,7 @@ class DecodeBatcher:
             drafts = self._draft_for([s for _i, s in verify], max(needs))
             for (i, slot), d, n in zip(verify, drafts, needs):
                 rows.append((i, slot, [slot.next_token] + d[:n], 1))
-        return rows, uncovered > 0, bool(verify)
+        return rows, uncovered > 0, bool(verify), deferred
 
     def _draft_for(self, slots, n):
         """``n`` draft continuations per generating slot, from the small
@@ -968,12 +1129,19 @@ class DecodeBatcher:
         except Exception:
             return [[] for _ in slots]
 
-    def _chunk_once(self, rows, sp):
+    def _chunk_once(self, rows, deferred, sp):
         """Dispatch one chunk: K-token lanes per covered row, pad lanes
         carry the pad sentinel ``pos == bucket_ctx`` (their cache writes
         drop via the op's out-of-range mode and their logits are
-        ignored). Commits forced tokens, then emits each verify row's
-        greedy chain: drafts are accepted while they equal the chunk's
+        ignored). Where the rung's height (:meth:`_chunk_height`) is
+        under the bucket, the run is over a SUB-BATCH: one jitted gather
+        copies the covered rows' caches out of the slot table, the chunk
+        executable is handed those, and one jitted scatter, the table
+        handed over, writes the lanes the run wrote back in place; all
+        three are dispatched and none is waited for. A pad sub-row
+        carries the row index ``b``, past the table, and the copies' loops
+        end before it: it reads and writes nothing. Commits forced
+        tokens, then emits each verify row's greedy chain: drafts are accepted while they equal the chunk's
         own argmax, the first disagreement is replaced by the argmax
         itself (always >= 1 token of progress), and rejected lanes are
         REWOUND by pointer arithmetic — rows past a slot's fill level
@@ -983,24 +1151,47 @@ class DecodeBatcher:
         b, c = self._bucket
         k = bucket_for(max(len(t) for _i, _s, t, _f in rows),
                        self.prefill_ladder)
-        self._stage_ahead((b, c, k))
-        self._await_staged((b, c, k))
+        sig = (b, c, k)
+        r = self._chunk_height(b, k)
+        self._stage_ahead(sig)
+        self._await_staged(sig)
+        copies = self._rows_copies(sig)
         with trace.span("decode.feed"):
-            tok = np.zeros((b, k), np.int64)
-            cpos = np.full((b, k), c, np.int32)
-            for i, slot, tokens, _f in rows:
+            tok = np.zeros((r, k), np.int64)
+            cpos = np.full((r, k), c, np.int32)
+            # sub-row j holds table row at[j]; the pads' lies past the
+            # table, and neither copy's loop reaches them
+            at = np.full((r,), b, np.int32)
+            start = np.zeros((r,), np.int32)
+            ordered = sorted(rows, key=lambda row: row[0])
+            for j, (i, slot, tokens, _f) in enumerate(ordered):
+                sub = i if copies is None else j
                 n = len(tokens)
-                tok[i, :n] = tokens
-                cpos[i, :n] = np.arange(slot.pos, slot.pos + n,
-                                        dtype=np.int32)
+                at[sub], start[sub] = i, slot.pos
+                tok[sub, :n] = tokens
+                cpos[sub, :n] = np.arange(slot.pos, slot.pos + n,
+                                          dtype=np.int32)
+            table, caches, held = None, self._caches, np.int32(len(rows))
+            if copies is not None:
+                table = caches
+                if isinstance(next(iter(table.values())), np.ndarray):
+                    import jax
+
+                    # a fresh table is the host's zeros: placed once, not
+                    # by each of the two copies
+                    table = jax.device_put(table)
+                caches = copies[0](table, at, held)
             # the carried caches are handed over: from here on only what
             # the run returns may be read, and that is what the table keeps
-            feed = {pf["tok"]: tok, pf["pos"]: cpos, **self._caches}
+            feed = {pf["tok"]: tok, pf["pos"]: cpos, **caches}
         outs = pf["pred"].run(feed)
-        self.seen_signatures.add((b, c, k))
-        self._caches = {name: outs[idx] for name, idx in pf["cache_map"]}
+        self.seen_signatures.add(sig)
+        caches = {name: outs[idx] for name, idx in pf["cache_map"]}
+        if copies is not None:
+            caches = copies[1](table, caches, at, start, held)
+        self._caches = caches
         self.metrics_.observe_cache_donated(
-            self._cache_bytes if pf["pred"].hands_over else 0)
+            self._cache_bytes * r // b if pf["pred"].hands_over else 0)
         greedy = None
         now = self._clock()
         live = sum(1 for s in self._slots if s is not None)
@@ -1061,7 +1252,7 @@ class DecodeBatcher:
                 slot.next_token = slot.out[-1]
         if chunk_rows:
             self.metrics_.observe_prefill_chunk(chunk_rows, chunk_toks,
-                                                b * k)
+                                                r * k, deferred)
         if accepted or rejected:
             self.metrics_.observe_spec(accepted, rejected)
         self.metrics_.observe_decode_step(live, b, generated)
@@ -1069,7 +1260,7 @@ class DecodeBatcher:
             sp.set(live=live, bucket=b, ctx=c, chunk=k,
                    generated=generated, accepted=accepted,
                    rejected=rejected, rows=chunk_rows, tokens=chunk_toks,
-                   lanes=b * k)
+                   lanes=r * k, sub_rows=r)
 
     def _maybe_harvest(self, i, slot):
         """First full ingestion of this prompt: offer its KV rows [0:L]
@@ -1088,9 +1279,11 @@ class DecodeBatcher:
         self.prefix_cache.insert(key, rows)
 
     def _synth_chunk_feed(self, b, c, k):
+        """A chunk feed of pad lanes only, at the rung's own height."""
         pf = self._prefill
-        return {pf["tok"]: np.zeros((b, k), np.int64),
-                pf["pos"]: np.full((b, k), c, np.int32)}
+        r = self._chunk_height(b, k)
+        return {pf["tok"]: np.zeros((r, k), np.int64),
+                pf["pos"]: np.full((r, k), c, np.int32)}
 
     def _step_once(self):
         self._await_staged(self._bucket)

@@ -54,8 +54,10 @@ _COUNTERS = (
     ("prefix_evictions", "prefix-cache entries evicted (LRU)"),
     ("prefill_chunks", "chunked-prefill / speculative-verify dispatches"),
     ("prefill_tokens", "prompt tokens ingested through chunk dispatches"),
-    ("prefill_lanes", "token lanes (slot rows x chunk rung) those chunk "
-                      "dispatches ran over"),
+    ("prefill_lanes", "token lanes (computed rows x chunk rung) those "
+                      "chunk dispatches ran over"),
+    ("prefill_deferred_rows", "ingesting rows a chunk dispatch left for the "
+                              "next one, summed over the dispatches"),
     ("admitted", "requests the decode loop took from its queue into a slot"),
     ("queue_wait_seconds", "seconds from submit to admission, summed over "
                            "the admitted requests"),
@@ -201,16 +203,19 @@ class ServingMetrics:
     def observe_prefix_eviction(self, n=1):
         self._c["prefix_evictions"].inc(n)
 
-    def observe_prefill_chunk(self, rows, tokens, lanes):
+    def observe_prefill_chunk(self, rows, tokens, lanes, deferred=0):
         """One chunk dispatch (prefill and/or speculative verify):
         ``rows`` slot rows participated, ``tokens`` prompt tokens were
         ingested through it (verify lanes count under spec_*), ``lanes``
-        token lanes the executable ran them over (every slot row of the
-        bucket padded to the chunk rung): tokens over lanes is the share
-        of a chunk run's work that ingested anything."""
+        token lanes the executable ran them over (the rows it computed,
+        the rung's sub-batch or the whole bucket, each padded to the chunk
+        rung): tokens over lanes is the share of a chunk run's work that
+        ingested anything. ``deferred``: ingesting rows the sub-batch had
+        no room for, which ride the next chunk."""
         self._c["prefill_chunks"].inc()
         self._c["prefill_tokens"].inc(int(tokens))
         self._c["prefill_lanes"].inc(int(lanes))
+        self._c["prefill_deferred_rows"].inc(int(deferred))
 
     def observe_admitted(self, n, waited_s):
         """The decode loop took ``n`` requests from its queue into slots;
@@ -307,6 +312,7 @@ class ServingMetrics:
             "prefill_chunks": c["prefill_chunks"],
             "prefill_tokens": c["prefill_tokens"],
             "prefill_lanes": c["prefill_lanes"],
+            "prefill_deferred_rows": c["prefill_deferred_rows"],
             "admitted": c["admitted"],
             "queue_wait_seconds": c["queue_wait_seconds"],
             "idle_seconds": c["idle_seconds"],
@@ -353,7 +359,8 @@ class ServingMetrics:
                     "prefix_hits", "prefix_tokens_reused",
                     "prefix_evictions", "prefix_bytes",
                     "cache_donated_bytes", "prefill_chunks",
-                    "prefill_tokens", "prefill_lanes", "admitted",
+                    "prefill_tokens", "prefill_lanes",
+                    "prefill_deferred_rows", "admitted",
                     "queue_wait_seconds", "idle_seconds", "spec_accepted",
                     "spec_rejected", "spec_accept_rate"):
             lines.append("%-32s %14s" % (key, fmt(s[key])))

@@ -175,6 +175,71 @@ def test_lowered_text_aliases_every_carried_cache_to_its_own_fetch():
     assert len(bat.seen_signatures) == 1 + len(bat.prefill_ladder)
 
 
+def test_a_sub_batched_chunk_hands_over_its_rows_and_the_scatter_the_table(
+        monkeypatch):
+    """ISSUE 38: a chunk rung whose height is under the bucket is handed
+    the SUB-BATCH's caches (its compile record says so, and that the
+    hand-over engages at that size), and the scatter that writes its lanes
+    back is handed the table and aliases every array of it: no whole cache
+    is copied in a chunk quantum, as PR 36 left it."""
+    from paddle_tpu.serving import decode_batcher
+
+    monkeypatch.setattr(decode_batcher, "CHUNK_TOKEN_BUDGET", 16)
+    family = _build_lm_family(fluid.Scope())  # its executors' records alone
+    pred, dspec, prefill, _ = family
+    bat = _batcher(family)
+    heights = [decode_batcher.chunk_rows(k, 4) for k in bat.prefill_ladder]
+    assert bat.prefill_ladder == (4, 8, 16) and heights == [4, 2, 1]
+    assert bat.warmup() == 1 + len(bat.prefill_ladder)
+    row_bytes = sum(
+        32 * int(np.prod(cf["tail"])) * np.dtype(
+            cf.get("dtype", "float32")).itemsize
+        for cf in dspec["cache_feeds"])
+    records = bat.compile_records()
+    assert [r["donated_feed_bytes"] for r in records] == [
+        rows * row_bytes for rows in [4] + heights]
+    assert all(r["memory"]["alias_bytes"] >= r["donated_feed_bytes"] > 0
+               for r in records)
+    # the copies: one pair a sub-batched rung, the scatter's table donated
+    assert sorted(bat._rows_staged) == [(4, 32, 8), (4, 32, 16)]
+    n_caches = len(dspec["cache_feeds"])
+    for (b, c, k), (gather, scatter) in bat._rows_staged.items():
+        rows = decode_batcher.chunk_rows(k, b)
+        assert scatter.memory_analysis().alias_size_in_bytes == 4 * row_bytes
+        assert gather.memory_analysis().alias_size_in_bytes == 0
+        assert gather.memory_analysis().output_size_in_bytes >= \
+            rows * row_bytes
+        # every table array is an argument the lowering aliases to its output
+        table, sub = bat._cache_shapes(b, c), bat._cache_shapes(rows, c)
+        import jax
+
+        idx = jax.ShapeDtypeStruct((rows,), np.dtype("int32"))
+        n = jax.ShapeDtypeStruct((), np.dtype("int32"))
+        lowered = decode_batcher._rows_helpers(k)[1].lower(
+            table, sub, idx, idx, n)
+        aliased = [alias for _, alias in _handed_arguments(
+            lowered.as_text()) if alias is not None]
+        assert sorted(aliased) == list(range(n_caches))
+        assert "jit_serve_rows_scatter" in scatter.as_text()
+        assert "jit_serve_rows_gather" in gather.as_text()
+    # a live sub-batched quantum: the table handed to the scatter is gone,
+    # what the table keeps is live, and the gauge reads the sub-batch
+    bat.submit([5, 9], max_new_tokens=8)
+    bat.submit(list(range(1, 15)), max_new_tokens=2)
+    bat._admit()
+    bat._tick()             # rung 4 over the whole table: device arrays back
+    bat._tick()             # a step
+    assert bat.metrics()["cache_donated_bytes"] == 4 * row_bytes
+    held = dict(bat._caches)
+    bat._tick()             # 8 of the 9 left: rung 8, a sub-batch of two
+    assert bat.metrics()["prefill_lanes"] == 4 * 4 + 2 * 8
+    assert all(a.is_deleted() for a in held.values())
+    assert not any(a.is_deleted() for a in bat._caches.values())
+    assert bat.metrics()["cache_donated_bytes"] == 2 * row_bytes
+    bat.drive()
+    assert len(bat.compile_records()) == 1 + len(bat.prefill_ladder)
+
+
 def test_a_geometrys_executables_are_staged_while_its_first_chunk_runs():
     """The first chunk of a geometry: helper threads make the executables
     of the step and of the other chunk rungs meanwhile (``Executor.stage``,

@@ -302,7 +302,8 @@ def test_registry_snapshot_consistency():
     m.observe_decode_step(live=2, bucket=4, generated=1)
     m.observe_prefix_hit(5)
     m.observe_prefix_eviction()
-    m.observe_prefill_chunk(2, 9, 32)
+    m.observe_prefill_chunk(2, 9, 32, deferred=3)
+    m.observe_prefill_chunk(1, 4, 8)
     m.observe_admitted(2, 0.75)
     m.observe_idle(0.5)
     m.observe_idle(1.25)
@@ -322,8 +323,8 @@ def test_registry_snapshot_consistency():
                   "in_flight", "prefix_hits", "prefix_tokens_reused",
                   "prefix_evictions", "prefix_bytes", "cache_donated_bytes",
                   "prefill_chunks", "prefill_tokens", "prefill_lanes",
-                  "admitted", "queue_wait_seconds", "idle_seconds",
-                  "spec_accepted", "spec_rejected"):
+                  "prefill_deferred_rows", "admitted", "queue_wait_seconds",
+                  "idle_seconds", "spec_accepted", "spec_rejected"):
         assert vals["paddle_tpu_serving_" + field] == snap[field], field
     # derived fields still derive from registry counters
     assert snap["batch_occupancy"] == 3 / 4
@@ -334,7 +335,12 @@ def test_registry_snapshot_consistency():
     assert snap["cache_donated_bytes"] == 1 << 20
     assert (snap["prefill_lanes"], snap["admitted"],
             snap["queue_wait_seconds"], snap["idle_seconds"]) == (
-        32, 2, 0.75, 1.75)
+        40, 2, 0.75, 1.75)
+    # rows a sub-batched chunk had no room for; a dispatch that names none
+    # adds none
+    assert (snap["prefill_chunks"], snap["prefill_deferred_rows"]) == (2, 3)
+    assert "paddle_tpu_serving_prefill_deferred_rows 3" in \
+        m.prometheus_text()
     # the pinned snapshot field list itself is unchanged (the contract
     # test_bench_contract.py leans on)
     assert set(snap) == {
@@ -348,12 +354,45 @@ def test_registry_snapshot_consistency():
         "slot_occupancy", "latency_s", "ttft_s", "tpot_s",
         "prefix_hits", "prefix_tokens_reused", "prefix_evictions",
         "prefix_bytes", "cache_donated_bytes", "prefill_chunks",
-        "prefill_tokens", "prefill_lanes", "admitted",
-        "queue_wait_seconds", "idle_seconds", "spec_accepted",
+        "prefill_tokens", "prefill_lanes", "prefill_deferred_rows",
+        "admitted", "queue_wait_seconds", "idle_seconds", "spec_accepted",
         "spec_rejected", "spec_accept_rate"}
     # and the report names every scalar of the snapshot, these four too
     rows = {line.split()[0] for line in m.report().splitlines()[1:]}
     assert {k for k in snap if not k.endswith("_s")} <= rows
+
+
+def test_a_sub_batched_chunk_says_its_height_and_counts_its_own_lanes(
+        monkeypatch):
+    """ISSUE 38: ``prefill_lanes`` adds the rows a chunk run COMPUTED times
+    the rung, the ``prefill.chunk`` span carries them as ``sub_rows`` beside
+    ``rows``, ``tokens`` and ``lanes``, and ``prefill_deferred_rows`` counts
+    the ingesting rows a dispatch left for the next."""
+    import paddle_tpu as fluid
+    from paddle_tpu.serving import DecodeBatcher, decode_batcher
+    from test_serving import _build_lm_family
+
+    monkeypatch.setattr(decode_batcher, "CHUNK_TOKEN_BUDGET", 8)
+    pred, dspec, prefill, _ = _build_lm_family(fluid.Scope())
+    bat = DecodeBatcher(pred, dspec, ladder=(4,), ctx_ladder=(32,),
+                        prefill=prefill, start=False)
+    tracer = trace.start()
+    try:
+        for first in (1, 2, 3):             # rung 4: two rows a chunk
+            bat.submit([first, 7, 11, 2, 5], max_new_tokens=2)
+        bat.drive()
+        spans = tracer.drain()
+    finally:
+        trace.stop()
+    chunks = [s["tags"] for s in spans if s["name"] == "prefill.chunk"]
+    assert [(t["rows"], t["sub_rows"], t["chunk"], t["lanes"], t["bucket"])
+            for t in chunks] == [(2, 2, 4, 8, 4), (1, 2, 4, 8, 4)]
+    m = bat.metrics()
+    assert m["prefill_lanes"] == sum(t["lanes"] for t in chunks) == 16
+    assert m["prefill_tokens"] == sum(t["tokens"] for t in chunks)
+    assert m["prefill_deferred_rows"] == 1
+    assert "\npaddle_tpu_serving_prefill_deferred_rows 1" in \
+        bat.metrics_.prometheus_text()
 
 
 # -- the Executor's own spans, through the one primitive (ISSUE 24) ----------

@@ -545,6 +545,57 @@ def test_scatter_compiles_at_largest_admitted_shape(chip):
     _assert_named(compiled, {"pallas_rowbin.scatter"})
 
 
+# ---------------------------------------------------------------------------
+# the decode loop's row copies round a sub-batched chunk run (ISSUE 38)
+# ---------------------------------------------------------------------------
+
+_SERVED_CACHES = {
+    # cell geometry: (bucket, capacity, rung), {tail and type: arrays}
+    "opt1p3b_rung256": ((16, 1280, 256), {((2048,), BF16): 48}),
+    "glm52_rung256": ((8, 12288, 256), {((576,), BF16): 5,
+                                       ((128,), F32): 2}),
+}
+
+
+@pytest.mark.parametrize("geometry,caches", list(_SERVED_CACHES.values()),
+                         ids=list(_SERVED_CACHES))
+def test_row_copies_compile_and_the_scatter_updates_the_table_in_place(
+        chip, geometry, caches):
+    """At the serving cells' own cache shapes: the gather's output is the
+    sub-batch and nothing more, the scatter aliases the whole table and
+    holds no copy of a cache array, and both carry their jit's name (what
+    a device trace tells them from the chunk executable by)."""
+    from paddle_tpu.serving.decode_batcher import _rows_helpers, chunk_rows
+
+    b, c, k = geometry
+    rows = chunk_rows(k, b)
+    assert rows < b
+    table, sub = {}, {}
+    for (tail, dtype), count in caches.items():
+        for i in range(count):
+            name = "cache_%d_%d" % (len(tail) + tail[0], i)
+            table[name] = sds((b, c) + tail, dtype)
+            sub[name] = sds((rows, c) + tail, dtype)
+    gather, scatter_back = (jitted.__wrapped__
+                            for jitted in _rows_helpers(min(k, c)))
+    idx, n = sds((rows,), I32), sds((), I32)
+    table_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in table.values())
+    gathered = _compile(chip, gather, table, idx, n)
+    assert "jit_serve_rows_gather" in gathered.as_text()
+    memory = gathered.memory_analysis()
+    assert memory.alias_size_in_bytes == 0
+    assert memory.output_size_in_bytes <= table_bytes * rows // b + (1 << 16)
+    scattered = _compile(chip, scatter_back, table, sub, idx, idx, n,
+                         donate_argnums=(0,))
+    assert "jit_serve_rows_scatter" in scattered.as_text()
+    memory = scattered.memory_analysis()
+    assert memory.alias_size_in_bytes == table_bytes
+    assert memory.temp_size_in_bytes < table_bytes // len(table)
+    whole = re.compile(r"= \w+\[%d,%d,\d+\][^ ]* copy\(" % (b, c))
+    assert not whole.search(scattered.as_text())
+
+
 @pytest.mark.parametrize("n", [scatter._SMEM_IDS_BYTES // 4 + 1,
                                32768 * 26],
                          ids=["one_past_bound", "deepfm_bench_ids"])
