@@ -15,42 +15,19 @@ rows, ``trace_reduce``'s busy intervals) and are tested on
 A program without these spans and counters (the parent of PR 37) gives
 ``None`` everywhere: the metric is then left out of the line.
 
-The readers are NOT in ``BENCHMARK.json`` yet. A PR that changes the program
-may only append to ``per_layer``, and ``tests/benchmark/test_glm52_cell.py``
-holds the last eight entries to GLM-5.2's own, so the entries wait in
-``decode_loop_metrics.json`` beside this file for a ``benchmark`` PR
-(``PERF.md``, Open questions). Until then
-
-    python3 benchmark/decode_spans.py BENCHMARK.proposed.json
-
-writes the manifest with them appended, for ``run.py --manifest``.
+The fifteen readers' entries are in ``BENCHMARK.json`` since PR 39. The
+rule that splits a device's idle time by span is ``program_spans``'s
+(``idle_by_span``): one reader for training and serving traces.
 """
 
-import json
-import os
-import sys
-
-if __name__ == "__main__":  # run as a script: make the package importable
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-
 from benchmark import serve_trace
-from benchmark.program_spans import NO_SPAN
+from benchmark.program_spans import NO_SPAN, idle_by_span
 
 PREFIX = serve_trace.PREFIX
 STEP, CHUNK = serve_trace.STEP, serve_trace.CHUNK
 QUANTA = (STEP, CHUNK, "spec.verify")
 IDLE, ADMIT, PLAN = "decode.idle", "decode.admit", "decode.plan"
 FETCH = "decode.fetch"
-HERE = os.path.dirname(os.path.abspath(__file__))
-
-
-def proposed(manifest):
-    """``manifest`` (the parsed ``BENCHMARK.json``) with the entries of
-    ``decode_loop_metrics.json`` appended to ``per_layer``."""
-    with open(os.path.join(HERE, "decode_loop_metrics.json")) as f:
-        waiting = json.load(f)
-    return dict(manifest, per_layer=manifest["per_layer"] + waiting)
 
 
 def accounts(host):
@@ -92,48 +69,6 @@ def chunk_wait_ms(trace):
         return None
     return sum(max(0.0, min(e, hi) - max(s, lo))
                for s, e in fetches for lo, hi in runs) / 1e6
-
-
-def innermost(rows):
-    """Sorted, disjoint [(start, end, name)]: at every instant that some
-    span of ``rows`` covers, the innermost one (the shortest, where several
-    do: ``program_spans.ProgramSpans.idle_by_span``'s rule)."""
-    edges = sorted({t for _, s, d in rows for t in (s, s + d)})
-    opening = sorted(((s, d, name) for name, s, d in rows), reverse=True)
-    active, out = [], []
-    for lo, hi in zip(edges, edges[1:]):
-        while opening and opening[-1][0] <= lo:
-            s, d, name = opening.pop()
-            active.append((d, name, s + d))
-        active = [a for a in active if a[2] > lo]
-        if active:
-            out.append((lo, hi, min(active)[1]))
-    return out
-
-
-def idle_by_span(host, busy):
-    """{span name: idle seconds of the first chip under it} as
-    ``ProgramSpans.idle_by_span`` gives it (every nanosecond of every gap
-    between two busy intervals goes to the innermost ``paddle_tpu.*`` span
-    that covers it, or to ``NO_SPAN``), in one sweep: a serving trace holds
-    a hundred quanta and as many gaps as device events."""
-    rows = [r for r in host if r[0].startswith(PREFIX)]
-    segments, out, k = innermost(rows), {}, 0
-    for (_, lo), (hi, _) in zip(busy, busy[1:]):
-        if hi <= lo:
-            continue
-        while k < len(segments) and segments[k][1] <= lo:
-            k += 1
-        covered, j = 0.0, k
-        while j < len(segments) and segments[j][0] < hi:
-            a, b, name = segments[j]
-            part = min(b, hi) - max(a, lo)
-            out[name] = out.get(name, 0.0) + part / 1e9
-            covered += part
-            j += 1
-        if hi - lo > covered:
-            out[NO_SPAN] = out.get(NO_SPAN, 0.0) + (hi - lo - covered) / 1e9
-    return out
 
 
 def accounted(host, busy):
@@ -235,10 +170,3 @@ def cache_alias_pct(records):
             handed += given
             aliased += memory["alias_bytes"]
     return 100.0 * aliased / handed if handed else None
-
-
-if __name__ == "__main__":
-    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
-        accepted = json.load(f)
-    with open(sys.argv[1], "w") as f:
-        json.dump(proposed(accepted), f, indent=1)

@@ -1,6 +1,8 @@
 """Scheduler: of the token lanes the chunk dispatches of the timed window
-ran over (every slot row of the bucket padded to the chunk rung: the
-engine's ``prefill_lanes``), the share that ingested a prompt token
+ran over (the engine's ``prefill_lanes``: the rows a chunk run computed
+times the chunk rung, which since PR 38 is a sub-batch of
+``chunk_rows(k, b)`` rows and before it every slot row of the bucket), the
+share that ingested a prompt token
 (``prefill_tokens``). The rest is a chunk run's work on pad lanes. Program
 counter (PR 37)."""
 

@@ -15,9 +15,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import harness, ops_count, seeded, trace_reduce  # noqa: E402
-
 HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+
+import appended  # noqa: E402
+from benchmark import harness, ops_count, seeded, trace_reduce  # noqa: E402
 
 
 def recorded():
@@ -281,18 +283,21 @@ def test_norm_gaps_by_the_worst_leaf_and_over_all_leaves():
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
 
 
-def test_manifest_names_only_files_that_exist_and_keeps_the_contract():
-    m = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+@pytest.mark.parametrize("case", appended.CASES)
+def test_manifest_names_only_files_that_exist_and_keeps_the_contract(
+        case, tmp_path):
+    root = appended.root(case, tmp_path)
+    m = harness.load_json(os.path.join(root, "BENCHMARK.json"))
     assert set(m) == {"command", "paths", "run_seconds", "configs",
                       "workloads", "end_to_end", "per_layer"}
-    bench = os.path.join(ROOT, "benchmark")
-    assert os.path.exists(os.path.join(ROOT, m["command"][1]))
+    bench = os.path.join(root, "benchmark")
+    assert os.path.exists(os.path.join(root, m["command"][1]))
     configs = {}
     for c in m["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
-        body = harness.load_json(os.path.join(ROOT, c["file"]))
+        body = harness.load_json(os.path.join(root, c["file"]))
         assert body["reduced"] == c["reduced"]
-        assert os.path.exists(os.path.join(ROOT, body["reference"]))
+        assert os.path.exists(os.path.join(root, body["reference"]))
         if "optimizer" in body:  # a served configuration trains nothing
             assert os.path.exists(os.path.join(
                 bench, "optimizers", body["optimizer"]["name"] + ".py"))

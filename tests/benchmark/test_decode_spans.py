@@ -20,6 +20,7 @@ if ROOT not in sys.path:
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
+import appended  # noqa: E402
 import tiny_serve  # noqa: E402
 from benchmark import (decode_spans, harness, program_spans,  # noqa: E402
                        serve_trace)
@@ -119,13 +120,10 @@ def test_chunk_wait_takes_only_the_chunks_run_out_of_the_fetch():
 def test_idle_time_is_split_between_no_request_the_host_and_no_span():
     trace, rec = recorded()
     want = rec["expect"]
-    got = decode_spans.idle_by_span(trace.host, trace.busy[0])
+    got = program_spans.idle_by_span(trace.host, trace.busy[0])
     assert set(got) == set(want["idle_ns_by_span"])
     for name, ns in want["idle_ns_by_span"].items():
         assert got[name] == pytest.approx(ns / 1e9), name
-    # the sweep gives what the benchmark's reader of PR 24 gives gap by gap
-    slow = program_spans.ProgramSpans(trace.host, trace.busy[0])
-    assert got == pytest.approx(slow.idle_by_span())
     assert sum(got.values()) == pytest.approx(want["idle_ns"] / 1e9)
     assert trace.window_s == pytest.approx(want["window_ns"] / 1e9)
     waiting, host, unspanned = decode_spans.idle_split(trace)
@@ -148,7 +146,7 @@ def test_the_quantum_under_way_when_the_profiler_started_is_left_out():
         cut.host, key=lambda r: r[1])]
     assert names[:4] == ["executor.run", "decode.fetch", "decode.sample",
                          "decode.admit"]
-    whole = decode_spans.idle_by_span(cut.host, cut.busy[0])
+    whole = program_spans.idle_by_span(cut.host, cut.busy[0])
     # 1900-1910 and 1990-2000 lay under the step that is not on record
     assert whole[program_spans.NO_SPAN] == pytest.approx(80e-9)
     assert decode_spans.accounted(cut.host, cut.busy[0]) == [
@@ -162,9 +160,40 @@ def test_the_quantum_under_way_when_the_profiler_started_is_left_out():
 def test_innermost_is_the_shortest_span_that_covers_an_instant():
     rows = [("a", 0, 100), ("b", 10, 50), ("c", 20, 10), ("d", 55, 100),
             ("e", 300, 0)]
-    assert decode_spans.innermost(rows) == [
+    assert program_spans.innermost(rows) == [
         (0, 10, "a"), (10, 20, "b"), (20, 30, "c"), (30, 55, "b"),
         (55, 60, "b"), (60, 100, "a"), (100, 155, "d")]
+
+
+def test_idle_time_goes_to_the_innermost_of_nested_and_overlapping_spans():
+    """The one reader of the rule (``program_spans.idle_by_span``, which
+    ``ProgramSpans.idle_by_span`` calls) against a trace made by hand:
+    ``run`` 0-1000 holds ``prepare`` 100-400, which holds ``lookup``
+    150-250; ``dispatch`` 350-700 overlaps ``prepare`` and is the longer
+    of the two; ``other`` 900-1300 overlaps the end of ``run`` and is the
+    shorter; a span of another prefix is no span of the program. The
+    device is busy 0-120, 200-380, 650-950, 1250-1260, 1500-1510."""
+    rows = [(P + "run", 0, 1000), (P + "prepare", 100, 300),
+            (P + "lookup", 150, 100), (P + "dispatch", 350, 350),
+            (P + "other", 900, 400), ("bench.step", 0, 2000)]
+    busy = [(0, 120), (200, 380), (650, 950), (1250, 1260), (1500, 1510)]
+    want = {
+        # 120-200: prepare 120-150, lookup 150-200
+        # 380-650: prepare 380-400 (300 long, under dispatch's 350), then
+        #          dispatch 400-650
+        # 950-1250: other 950-1250 (400 long, under run's 1000 to 1000)
+        # 1260-1500: other 1260-1300, then no span of the program
+        P + "prepare": 30 + 20, P + "lookup": 50, P + "dispatch": 250,
+        P + "other": 300 + 40, program_spans.NO_SPAN: 200}
+    got = program_spans.idle_by_span(rows, busy)
+    assert got == pytest.approx({k: v / 1e9 for k, v in want.items()})
+    spans = program_spans.ProgramSpans(
+        [r for r in rows if r[0].startswith(P)], busy, steps=2)
+    assert spans.idle_by_span() == got
+    assert sum(got.values()) == pytest.approx(
+        sum(b - a for a, b in spans.idle_gaps()) / 1e9)
+    # per step, under the spans of one prefix
+    assert spans.idle_ms_a_step_under("p") == pytest.approx(50e-6 / 2)
 
 
 # -- what the parent gives ----------------------------------------------------
@@ -250,18 +279,10 @@ def test_cache_alias_pct_is_what_was_aliased_of_what_was_handed_over(
 
 @pytest.fixture(scope="module")
 def checkout(tmp_path_factory):
-    """The tiny serving checkout, its manifest with the waiting entries
-    appended (``decode_spans.proposed``), each under the tiny cell of its
-    traffic as ``tiny_serve`` carries the accepted ones over."""
-    root, path = tiny_serve.make_checkout(
-        tmp_path_factory.mktemp("decode_spans"))
-    swap = {CHAT: "tiny.serve.chat", SAT: "tiny.serve.chat.sat"}
-    tiny = decode_spans.proposed(harness.load_json(path))
-    for x in tiny["per_layer"][-len(NEW):]:
-        x["workloads"] = [swap[c] for c in x["workloads"] if c in swap]
-    with open(path, "w") as f:
-        json.dump(tiny, f, indent=1)
-    return root, path
+    """The tiny serving checkout: its manifest carries the decode loop's
+    entries over from ``BENCHMARK.json``, each under the tiny cell of its
+    traffic, as ``tiny_serve`` carries every accepted entry."""
+    return tiny_serve.make_checkout(tmp_path_factory.mktemp("decode_spans"))
 
 
 def rehearse(manifest, cell, seconds):
@@ -316,37 +337,40 @@ def test_traced_rehearsal_reads_the_loops_spans_and_counters(
                 "idle_host_ms.chat", "idle_unspanned_pct.chat"} & set(m)
 
 
-# -- the entries that wait for a benchmark PR -----------------------------------
+# -- the entries in the manifest --------------------------------------------------
 
-def test_the_waiting_entries_append_to_the_manifest_and_name_their_readers():
-    """``BENCHMARK.json`` does not list the readers yet (a PR that changes
-    the program may only append to ``per_layer``, and ``test_glm52_cell``
-    holds its last eight entries): ``decode_loop_metrics.json`` holds their
-    entries as they would be appended, under the manifest's own rules."""
-    m = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    both = decode_spans.proposed(m)
-    assert both["per_layer"][:len(m["per_layer"])] == m["per_layer"]
-    added = both["per_layer"][len(m["per_layer"]):]
-    assert sorted(x["name"] for x in added) == sorted(NEW)
-    names = [x["name"] for x in both["per_layer"]]
+@pytest.mark.parametrize("case", appended.CASES)
+def test_the_manifest_holds_the_loops_entries_and_names_their_readers(
+        case, tmp_path):
+    """``BENCHMARK.json`` lists the fifteen readers since PR 39 (PR 37
+    wrote them and could register none: ``PERF.md``, Findings). Each entry
+    is found by name, wherever it stands and whoever else is on its list."""
+    root = appended.root(case, tmp_path)
+    m = harness.load_json(os.path.join(root, "BENCHMARK.json"))
+    names = [x["name"] for x in m["per_layer"]]
     assert len(set(names)) == len(names)
+    layers = dict(zip(names, m["per_layer"]))
+    assert set(NEW) <= set(layers)
     e2e = {x["name"]: x for x in m["end_to_end"]}
-    for x in added:
-        name = x["name"]
+    others = {x["layer"] for x in m["per_layer"] if x["name"] not in NEW}
+    for name in NEW:
+        x = layers[name]
         assert set(x) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         assert os.path.exists(os.path.join(
-            ROOT, "benchmark", "layer_metrics", name + ".py")), name
+            root, "benchmark", "layer_metrics", name + ".py")), name
         if name.endswith(".chat"):
-            assert (x["workloads"], x["moves"]) == ([CHAT], "token_ms_mean")
+            assert CHAT in x["workloads"] and x["moves"] == "token_ms_mean"
+            assert not {SAT, GLM} & set(x["workloads"])
         else:
-            assert (x["workloads"], x["moves"]) == (
-                [SAT, GLM], "serve_tokens_per_s")
+            assert {SAT, GLM} <= set(x["workloads"])
+            assert CHAT not in x["workloads"]
+            assert x["moves"] == "serve_tokens_per_s"
         # every cell it lists reports the end-to-end metric it moves
         assert set(x["workloads"]) <= set(e2e[x["moves"]]["workloads"])
         assert x["unit"] == ("%" if "pct" in name else "ms")
         assert x["better"] in ("lower", "higher")
-        assert x["layer"] in {y["layer"] for y in m["per_layer"]}
+        assert x["layer"] in others
         assert x["source"] == ("program_counter" if name in COUNTER_READERS
                                + ["cache_alias_pct"] else "program_span")
     for name in TWINS:  # a twin is its sibling's reader, not a copy

@@ -15,7 +15,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import appended  # noqa: E402
 from benchmark import harness, trace_reduce  # noqa: E402
 
 CELLS = ["qwen3next.train.s8192", "nemotron3super.train.s8192"]
@@ -94,9 +96,11 @@ def test_moe_row_fill_pct_reads_the_rows_counters_of_every_layer():
         {"l0.moe.rows": np.zeros(2, np.int32)})}) is None
 
 
+@pytest.mark.parametrize("case", appended.CASES)
 @pytest.mark.parametrize("name", NAMES)
-def test_manifest_lists_both_hybrid_cells_for(name):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+def test_manifest_lists_both_hybrid_cells_for(name, case, tmp_path):
+    root = appended.root(case, tmp_path)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     metric, = [m for m in manifest["per_layer"] if m["name"] == name]
     assert set(CELLS) <= set(metric["workloads"])
@@ -111,4 +115,4 @@ def test_manifest_lists_both_hybrid_cells_for(name):
     assert all("workloads" not in moved or c in moved["workloads"]
                for c in CELLS)
     assert os.path.exists(os.path.join(
-        ROOT, "benchmark", "layer_metrics", name + ".py"))
+        root, "benchmark", "layer_metrics", name + ".py"))

@@ -19,6 +19,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import appended  # noqa: E402
 import tiny  # noqa: E402
 from benchmark import control, harness, trace_reduce  # noqa: E402
 
@@ -231,8 +232,10 @@ def test_new_readers_on_recorded_rows():
         assert not _reader(name).read(other)
 
 
-def test_manifest_holds_the_cell_and_the_catalogs_widths():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+@pytest.mark.parametrize("case", appended.CASES)
+def test_manifest_holds_the_cell_and_the_catalogs_widths(case, tmp_path):
+    with open(os.path.join(appended.root(case, tmp_path),
+                           "BENCHMARK.json")) as f:
         manifest = json.load(f)
     cell, = [c for c in manifest["workloads"] if c["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (

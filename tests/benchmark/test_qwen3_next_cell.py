@@ -18,6 +18,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import appended  # noqa: E402
 import tiny  # noqa: E402
 from benchmark import control, harness, trace_reduce  # noqa: E402
 
@@ -242,8 +243,10 @@ def test_new_readers_on_recorded_rows():
     assert _reader("moe_expert_load_max").read({"trainer": holder}) is None
 
 
-def test_manifest_holds_the_cell_and_the_catalogs_widths():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+@pytest.mark.parametrize("case", appended.CASES)
+def test_manifest_holds_the_cell_and_the_catalogs_widths(case, tmp_path):
+    root = appended.root(case, tmp_path)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     cell, = [c for c in manifest["workloads"] if c["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
@@ -253,7 +256,7 @@ def test_manifest_holds_the_cell_and_the_catalogs_widths():
     cfg = _real_config()
     assert entry["reduced"] == cfg["reduced"] == [
         "num_hidden_layers", "experts_held", "vocab_size"]
-    with open(os.path.join(ROOT, "benchmark", "traffic",
+    with open(os.path.join(root, "benchmark", "traffic",
                            "train.s8192.json")) as f:
         mix = json.load(f)
     assert mix["sizes"] == {"batch": 1, "seq_len": 8192}

@@ -20,6 +20,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import appended  # noqa: E402
 import tiny_serve  # noqa: E402
 from benchmark import harness, ops_count_opt, serve_control  # noqa: E402
 from benchmark import serve_trace  # noqa: E402
@@ -546,11 +547,13 @@ def test_operation_counts_against_a_hand_count():
 
 # -- the manifest -------------------------------------------------------------
 
-def test_manifest_holds_the_serving_cells():
-    m = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+@pytest.mark.parametrize("case", appended.CASES)
+def test_manifest_holds_the_serving_cells(case, tmp_path):
+    root = appended.root(case, tmp_path)
+    m = harness.load_json(os.path.join(root, "BENCHMARK.json"))
     config = {c["name"]: c for c in m["configs"]}["opt-1.3b"]
     assert config["reduced"] == [] and "facebook/opt-1.3b" in config["source"]
-    body = harness.load_json(os.path.join(ROOT, config["file"]))
+    body = harness.load_json(os.path.join(root, config["file"]))
     assert (body["num_hidden_layers"], body["hidden_size"], body["ffn_dim"],
             body["num_attention_heads"], body["vocab_size"],
             body["max_position_embeddings"]) == (24, 2048, 8192, 32, 50272,
