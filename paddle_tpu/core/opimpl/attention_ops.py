@@ -108,6 +108,22 @@ def _mamba2_ssd(env, op):
     put(env, op.output("Out"), out.astype(x.dtype))
 
 
+def _extended(env, op, k, v):
+    """(kv_heads, window, ring, sink) where a cached-attention op
+    asks for more than one head count and one width over every cached
+    position (``ops/cache_attention.py``), else None: the plain form below
+    then lowers as it always has."""
+    h = int(op.attr("num_heads", 1))
+    kv_heads = int(op.attr("num_kv_heads", 0) or h)
+    window, ring = int(op.attr("window", 0)), bool(op.attr("ring", False))
+    sink = op.input("Sink")
+    if (kv_heads == h and not window and not ring and sink is None
+            and v.shape[-1] == k.shape[-1] and op.output("Count") is None
+            and op.input("NewK") is None):
+        return None
+    return kv_heads, window, ring, get(env, sink)
+
+
 @register("kv_cache_write")
 def _kv_cache_write(env, op):
     """Per-row cache update: Cache [B, C, ...], X [B, ...], Pos [B] ->
@@ -117,11 +133,14 @@ def _kv_cache_write(env, op):
     its own slot — the property the continuous batcher's solo-vs-batched
     bitwise-parity guarantee rests on (a dead slot's garbage write cannot
     leak into a live row). Out-of-range positions drop (a retired slot fed
-    a zero position is harmless either way)."""
+    a zero position is harmless either way). ``ring``: the cache is a ring
+    of C positions and position p lives at slot ``p % C``."""
     cache = get(env, op.input("Cache"))
     x = get(env, op.input("X"))
     pos = get(env, op.input("Pos")).reshape(-1).astype(jnp.int32)
     b = cache.shape[0]
+    if op.attr("ring", False):
+        pos = jnp.mod(pos, cache.shape[1])
     put(env, op.output("Out"),
         cache.at[jnp.arange(b), pos].set(x.astype(cache.dtype),
                                          mode="drop"))
@@ -146,12 +165,31 @@ def _cached_attention(env, op):
     same sums, but on the TPU a compiler that is given it first copies
     every cache into a layout with the positions innermost (0.27 ms an
     array a step at [16, 1280, 2048]); the zeros cost MXU passes that hide
-    under the cache's read from HBM."""
+    under the cache's read from HBM.
+
+    ``num_kv_heads`` < ``num_heads`` (grouped heads: query head h reads
+    key/value head ``h // (H / Hkv)``, the caches ``[B, C, Hkv*Dk]`` and
+    ``[B, C, Hkv*Dv]`` of their own widths), ``window`` (only the
+    ``window`` positions up to Pos), ``ring`` (position p at slot ``p %
+    C``), a ``Sink`` [H] input (a learned scalar a head in the softmax's
+    denominator) or a ``Count`` [1] int32 output (the positions the rows
+    read) take the op to ``ops/cache_attention.py``: the same block-diagonal
+    read of the caches as stored, a group at a time."""
     q = get(env, op.input("Q"))
     k = get(env, op.input("CacheK"))
     v = get(env, op.input("CacheV"))
     pos = get(env, op.input("Pos")).reshape(-1).astype(jnp.int32)
     h = int(op.attr("num_heads", 1))
+    more = _extended(env, op, k, v)
+    if more is not None:
+        from ...ops import cache_attention
+
+        kv_heads, window, ring, sink = more
+        out, count = cache_attention.attend_step(
+            q, k, v, pos, h, kv_heads, window, sink, ring)
+        put(env, op.output("Out"), out)
+        put(env, op.output("Count"), count)
+        return
     b, c, hd = k.shape
     d = hd // h
     own = jnp.eye(h, dtype=bool)
@@ -176,11 +214,21 @@ def _kv_cache_write_chunk(env, op):
     row b scatters only into its own cache rows, so the batcher's
     solo-vs-batched parity property carries over to chunk dispatches.
     Out-of-range positions drop: the scheduler pads partial chunks with
-    Pos = capacity, so a padded lane writes nothing."""
+    Pos = capacity, so a padded lane writes nothing. ``ring``: the cache is
+    a ring of C positions, a lane of Pos ``>= pad_pos`` is a pad lane, and
+    of a row's live lanes (consecutive positions from lane 0 on) only those
+    land, at ``Pos % C``, that no later lane of the chunk overwrites
+    (``cache_attention.ring_slots``): a scatter that names a slot twice
+    resolves in no defined order."""
     cache = get(env, op.input("Cache"))
     x = get(env, op.input("X"))
     pos = get(env, op.input("Pos")).astype(jnp.int32)
     b = cache.shape[0]
+    if op.attr("ring", False):
+        from ...ops import cache_attention
+
+        pos = cache_attention.ring_slots(pos, cache.shape[1],
+                                         int(op.attr("pad_pos")))
     put(env, op.output("Out"),
         cache.at[jnp.arange(b)[:, None], pos].set(x.astype(cache.dtype),
                                                   mode="drop"))
@@ -194,12 +242,34 @@ def _cached_attention_chunk(env, op):
     <= Pos[b, j] — per-query causal masking over the filled prefix plus
     the chunk's own earlier tokens, exactly the step op's semantics
     applied K times. Numerics mirror ``cached_attention`` (1/sqrt(D)
-    scale, f32 softmax); still strictly per-row."""
+    scale, f32 softmax); still strictly per-row.
+
+    ``num_kv_heads``, ``window`` and ``Sink`` as ``cached_attention``'s;
+    the caches are then read in blocks up to a row's highest live position
+    under a streaming softmax (``cache_attention.attend_chunk``). ``ring``
+    with ``NewK`` / ``NewV`` [B, K, ..]: CacheK / CacheV are rings as they
+    were BEFORE the chunk, the chunk's own keys and values come beside
+    them, and a lane reads both inside its window
+    (``cache_attention.attend_chunk_ring``); the rings are written after."""
     q = get(env, op.input("Q"))
     k = get(env, op.input("CacheK"))
     v = get(env, op.input("CacheV"))
     pos = get(env, op.input("Pos")).astype(jnp.int32)
     h = int(op.attr("num_heads", 1))
+    more = _extended(env, op, k, v)
+    if more is not None:
+        from ...ops import cache_attention
+
+        kv_heads, window, ring, sink = more
+        if ring:
+            out = cache_attention.attend_chunk_ring(
+                q, k, v, get(env, op.input("NewK")),
+                get(env, op.input("NewV")), pos, h, kv_heads, window, sink)
+        else:
+            out = cache_attention.attend_chunk(q, k, v, pos, h, kv_heads,
+                                               window, sink)
+        put(env, op.output("Out"), out)
+        return
     b, c, hd = k.shape
     kq = q.shape[1]
     d = hd // h

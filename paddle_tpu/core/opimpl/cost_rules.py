@@ -423,17 +423,35 @@ def _kv_cache_write_cost(ctx, op):
     ctx.add(op, hbm_bytes=2 * n * e)  # read token slice, write rows
 
 
+def _cached_attention_sizes(ctx, op):
+    """(B, positions a query reads at most, Hkv*Dk, Hkv*Dv, H*Dk, H*Dv) of a
+    cached-attention op, or None where a shape is open. A window bounds the
+    positions a query reads; the caches are read once whatever the number
+    of query heads a group."""
+    ks = ctx.shape(op.input("CacheK"))
+    vs = ctx.shape(op.input("CacheV"))
+    qs = ctx.shape(op.input("Q"))
+    if ks is None or qs is None or len(ks) != 3 or -1 in ks:
+        return None
+    b, cap, kd = ks
+    vd = kd if vs is None or vs[-1] == -1 else vs[-1]
+    h = int(op.attr("num_heads", 1))
+    kv = int(op.attr("num_kv_heads", 0) or h)
+    window = int(op.attr("window", 0))
+    read = min(cap, window) if window else cap
+    return b, read, kd, vd, kd // kv * h, vd // kv * h
+
+
 @register_cost("cached_attention")
 def _cached_attention_cost(ctx, op):
-    ks = ctx.shape(op.input("CacheK"))
-    qs = ctx.shape(op.input("Q"))
-    if ks is None or qs is None or len(ks) != 3:
+    sizes = _cached_attention_sizes(ctx, op)
+    if sizes is None:
         ctx.add(op, unresolved=True)
         return
-    b, cap, hd = ks
+    b, read, kd, vd, qd, od = sizes
     e = ctx.esize(op.input("Q"))
-    ctx.add(op, flops=4.0 * b * cap * hd,
-            hbm_bytes=(2 * b * cap * hd + 2 * b * hd) * e)
+    ctx.add(op, flops=2.0 * b * read * (qd + od),
+            hbm_bytes=(b * read * (kd + vd) + b * (qd + od)) * e)
 
 
 @register_cost("kv_cache_write_chunk")
@@ -448,19 +466,18 @@ def _kv_cache_write_chunk_cost(ctx, op):
 
 @register_cost("cached_attention_chunk")
 def _cached_attention_chunk_cost(ctx, op):
-    ks = ctx.shape(op.input("CacheK"))
+    sizes = _cached_attention_sizes(ctx, op)
     qs = ctx.shape(op.input("Q"))
-    if ks is None or qs is None or len(ks) != 3 or len(qs) != 3:
+    if sizes is None or len(qs) != 3 or qs[1] == -1:
         ctx.add(op, unresolved=True)
         return
-    b, cap, hd = ks
+    b, read, kd, vd, qd, od = sizes
     kq = qs[1]
-    if kq == -1 or cap == -1:
-        ctx.add(op, unresolved=True)
-        return
     e = ctx.esize(op.input("Q"))
-    ctx.add(op, flops=4.0 * b * kq * cap * hd,
-            hbm_bytes=(2 * b * cap * hd + 2 * b * kq * hd) * e)
+    # a ring is read beside the chunk's own K rows
+    rows = read + (kq if op.attr("ring", False) else 0)
+    ctx.add(op, flops=2.0 * b * kq * read * (qd + od),
+            hbm_bytes=(b * rows * (kd + vd) + b * kq * (qd + od)) * e)
 
 
 def _index_cost(ctx, op, lanes_of):

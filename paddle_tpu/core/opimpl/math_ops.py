@@ -240,6 +240,17 @@ def _scale(env, op):
     put(env, op.output("Out"), out)
 
 
+@register("optimization_barrier")
+def _optimization_barrier(env, op):
+    """X as it is computed (``jax.lax.optimization_barrier``): the compiler
+    fuses nothing across it and carries no layout through it. For a
+    product that the ops after it view a head at a time, where the heads
+    are no multiple of 128 wide: left alone, the TPU compiler lays the
+    WEIGHT out for that view and copies it anew in every run."""
+    put(env, op.output("Out"),
+        jax.lax.optimization_barrier(get(env, op.input("X"))))
+
+
 @register("clip")
 def _clip(env, op):
     put(env, op.output("Out"),

@@ -90,7 +90,7 @@ _LIKE_X = (
     # shape-preserving tensor/nn ops
     "scale", "clip", "softmax", "log_softmax", "label_smooth",
     "sigmoid_cross_entropy_with_logits", "increment", "fill_zeros_like",
-    "square_error_cost", "assign",
+    "square_error_cost", "assign", "optimization_barrier",
 )
 
 
@@ -794,31 +794,79 @@ def _kv_cache_write_shape(ctx, op):
     ctx.set(op.output("Out"), cs, dt)
 
 
-@register_shape("cached_attention")
-def _cached_attention_shape(ctx, op):
+def _cached_attention_out(ctx, op, q_rank):
+    """Checks and output shape of ``cached_attention`` (Q [B, H*Dk]) and
+    ``cached_attention_chunk`` (Q [B, K, H*Dk]): CacheK [B, C, Hkv*Dk],
+    CacheV [B, C, Hkv*Dv] of a width of its own, Out [.., H*Dv]; with
+    ``ring`` in a chunk, NewK / NewV [B, K, ..] of the caches' tails and a
+    window the ring can hold."""
+    kind = op.type
     qs = ctx.shape(op.input("Q"))
     ks = ctx.shape(op.input("CacheK"))
     vs = ctx.shape(op.input("CacheV"))
     dt = ctx.dtype(op.input("Q"))
     h = int(op.attr("num_heads", 1))
-    if ks is not None:
-        if len(ks) != 3:
-            raise ShapeError("cached_attention CacheK '%s' must be "
-                             "[B, C, H*D], got %s"
-                             % (op.input("CacheK").name, list(ks)))
-        if ks[-1] != -1 and ks[-1] % h != 0:
+    kv = int(op.attr("num_kv_heads", 0) or h)
+    if kv < 1 or h % kv:
+        raise ShapeError("%s num_heads=%d is not a multiple of "
+                         "num_kv_heads=%d" % (kind, h, kv))
+    if qs is not None and q_rank == 3 and len(qs) != 3:
+        raise ShapeError("%s Q '%s' must be [B, K, H*D], got %s" % (
+            kind, op.input("Q").name, list(qs)))
+    for slot, shape in (("CacheK", ks), ("CacheV", vs)):
+        if shape is None:
+            continue
+        if len(shape) != 3:
+            raise ShapeError("%s %s '%s' must be [B, C, H*D], got %s" % (
+                kind, slot, op.input(slot).name, list(shape)))
+        if shape[-1] != -1 and shape[-1] % kv != 0:
             raise ShapeError(
-                "cached_attention CacheK '%s' last dim %d is not divisible "
-                "by num_heads=%d" % (op.input("CacheK").name, ks[-1], h))
+                "%s %s '%s' last dim %d is not divisible by num_heads=%d"
+                % (kind, slot, op.input(slot).name, shape[-1], kv))
     if qs is not None and ks is not None and qs[-1] != -1 \
-            and ks[-1] != -1 and qs[-1] != ks[-1]:
+            and ks[-1] != -1 and qs[-1] * kv != ks[-1] * h:
         raise ShapeError(
-            "cached_attention Q '%s' feature dim %d != CacheK '%s' dim %d"
-            % (op.input("Q").name, qs[-1], op.input("CacheK").name, ks[-1]))
+            "%s Q '%s' feature dim %d != CacheK '%s' dim %d%s" % (
+                kind, op.input("Q").name, qs[-1], op.input("CacheK").name,
+                ks[-1], "" if kv == h else " x %d/%d heads" % (h, kv)))
+    sink = op.input("Sink")
+    if sink is not None and ctx.shape(sink) is not None \
+            and tuple(ctx.shape(sink)) != (h,):
+        raise ShapeError("%s Sink '%s' must be [num_heads=%d], got %s" % (
+            kind, sink.name, h, list(ctx.shape(sink))))
+    if op.attr("ring", False) and q_rank == 3:
+        window = int(op.attr("window", 0))
+        if ks is not None and ks[1] != -1 and not 1 <= window <= ks[1]:
+            raise ShapeError(
+                "%s CacheK '%s' is a ring of %d positions: it serves a "
+                "window of 1 to %d, not %d" % (
+                    kind, op.input("CacheK").name, ks[1], ks[1], window))
+        for slot, cache in (("NewK", ks), ("NewV", vs)):
+            new = op.input(slot)
+            if new is None:
+                raise ShapeError("%s with ring needs %s: the chunk's own "
+                                 "rows, which the ring does not hold yet"
+                                 % (kind, slot))
+            ns = ctx.shape(new)
+            if ns is not None and cache is not None and (
+                    len(ns) != 3 or (ns[-1] != -1 and cache[-1] != -1
+                                     and ns[-1] != cache[-1])):
+                raise ShapeError("%s %s '%s' %s does not slot into its "
+                                 "cache %s" % (kind, slot, new.name,
+                                               list(ns), list(cache)))
+    count = op.output("Count")
+    if count is not None:
+        ctx.set(count, (1,), np.dtype(np.int32))
     if vs is None or qs is None:
         ctx.set(op.output("Out"), qs, dt)
         return
-    ctx.set(op.output("Out"), tuple(qs[:-1]) + (vs[-1],), dt)
+    ctx.set(op.output("Out"), tuple(qs[:-1]) + (
+        vs[-1] if vs[-1] == -1 else vs[-1] // kv * h,), dt)
+
+
+@register_shape("cached_attention")
+def _cached_attention_shape(ctx, op):
+    _cached_attention_out(ctx, op, 2)
 
 
 @register_shape("kv_cache_write_chunk")
@@ -845,35 +893,7 @@ def _kv_cache_write_chunk_shape(ctx, op):
 
 @register_shape("cached_attention_chunk")
 def _cached_attention_chunk_shape(ctx, op):
-    qs = ctx.shape(op.input("Q"))
-    ks = ctx.shape(op.input("CacheK"))
-    vs = ctx.shape(op.input("CacheV"))
-    dt = ctx.dtype(op.input("Q"))
-    h = int(op.attr("num_heads", 1))
-    if qs is not None and len(qs) != 3:
-        raise ShapeError("cached_attention_chunk Q '%s' must be "
-                         "[B, K, H*D], got %s"
-                         % (op.input("Q").name, list(qs)))
-    if ks is not None:
-        if len(ks) != 3:
-            raise ShapeError("cached_attention_chunk CacheK '%s' must be "
-                             "[B, C, H*D], got %s"
-                             % (op.input("CacheK").name, list(ks)))
-        if ks[-1] != -1 and ks[-1] % h != 0:
-            raise ShapeError(
-                "cached_attention_chunk CacheK '%s' last dim %d is not "
-                "divisible by num_heads=%d"
-                % (op.input("CacheK").name, ks[-1], h))
-    if qs is not None and ks is not None and qs[-1] != -1 \
-            and ks[-1] != -1 and qs[-1] != ks[-1]:
-        raise ShapeError(
-            "cached_attention_chunk Q '%s' feature dim %d != CacheK "
-            "'%s' dim %d" % (op.input("Q").name, qs[-1],
-                             op.input("CacheK").name, ks[-1]))
-    if vs is None or qs is None:
-        ctx.set(op.output("Out"), qs, dt)
-        return
-    ctx.set(op.output("Out"), tuple(qs[:-1]) + (vs[-1],), dt)
+    _cached_attention_out(ctx, op, 3)
 
 
 def _index_inputs(ctx, op, rank):

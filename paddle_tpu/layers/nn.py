@@ -33,7 +33,8 @@ __all__ = [
     "one_hot", "lod_reset", "pad", "pad2d", "image_resize", "resize_bilinear",
     "resize_nearest", "grid_sampler", "pixel_shuffle", "im2sequence",
     "multi_head_attention", "scaled_dot_product_attention",
-    "cached_multi_head_attention", "kv_cache_write",
+    "cached_multi_head_attention", "kv_cache_write", "cached_attention",
+    "optimization_barrier",
     "cached_multi_head_attention_chunk", "kv_cache_write_chunk",
     "sparse_index", "latent_attention",
     "row_conv", "autoincreased_step_counter", "cos_sim",
@@ -509,6 +510,17 @@ def scale(x, scale=1.0, bias=0.0, bias_after_scale=True, act=None, name=None):
                      {"scale": scale, "bias": bias,
                       "bias_after_scale": bias_after_scale})
     return helper.append_activation(out)
+
+
+def optimization_barrier(x, name=None):
+    """``x`` as it is computed: the compiler fuses nothing across this op
+    and carries no layout through it (``jax.lax.optimization_barrier``).
+    Put between a projection and ops that view its product a head at a
+    time where the heads are no multiple of 128 wide: the TPU compiler
+    otherwise lays the projection's weight out for that view, a copy of
+    the whole matrix in every run (MiMo-V2's 192-wide query heads: 100 MB
+    a layer a decode step)."""
+    return _unary_layer("optimization_barrier", x, name=name)
 
 
 def clip(x, min, max, name=None):
@@ -2078,18 +2090,75 @@ def multi_head_attention(queries, keys, values, attn_bias=None, d_key=None,
     return out
 
 
-def kv_cache_write(cache, x, pos, name=None):
+def kv_cache_write(cache, x, pos, ring=False, name=None):
     """Per-row cache update: ``cache[b, pos[b]] = x[b]`` (see
     ``core/opimpl/attention_ops.py``). ``cache``: [B, C, ...], ``x``:
     [B, ...], ``pos``: [B] int. The tail is the cache's own: a key or value
     row of H*D, one latent row ``[c | k_pe]`` for all heads, an index key.
-    Returns the updated cache tensor."""
+    ``ring``: the cache is a ring of C positions, ``cache[b, pos[b] % C] =
+    x[b]``. Returns the updated cache tensor."""
     helper = LayerHelper("kv_cache_write", name=name)
     out = helper.create_variable_for_type_inference(
         dtype=_dtype(cache), shape=cache.shape)
     helper.append_op("kv_cache_write",
-                     {"Cache": cache, "X": x, "Pos": pos}, {"Out": out}, {})
+                     {"Cache": cache, "X": x, "Pos": pos}, {"Out": out},
+                     {"ring": True} if ring else {})
     return out
+
+
+def cached_attention(q, cache_k, cache_v, pos, num_heads, num_kv_heads=None,
+                     window=0, sink_attr=None, ring=False, new_k=None,
+                     new_v=None, count=False, name=None):
+    """Attention of a step's or a chunk's queries over a slot table's
+    key/value caches (ops ``cached_attention`` / ``cached_attention_chunk``,
+    ``core/opimpl/attention_ops.py``): ``q`` [B, H*Dk] with ``pos`` [B], or
+    [B, K, H*Dk] with ``pos`` [B, K]; ``cache_k`` [B, C, Hkv*Dk] and
+    ``cache_v`` [B, C, Hkv*Dv], each of its own width, with the current
+    rows written. ``num_kv_heads`` < ``num_heads``: grouped heads, query
+    head h reads key/value head ``h // (H / Hkv)``. ``window`` > 0: a query
+    reads its own position and the ``window - 1`` before it. ``sink_attr``:
+    creates a learned scalar a query head ([H], the query's type) that
+    joins the softmax's denominator and takes no value. ``ring``: the
+    caches are rings of C positions (position p at slot ``p % C``, written
+    by ``kv_cache_write(.., ring=True)``); a step reads the ring with its
+    token written, a chunk reads the rings AS THEY WERE BEFORE it and its
+    own ``new_k`` / ``new_v`` [B, K, ..] beside them, and writes the rings
+    afterwards. ``count``: also return [1] int32, the positions the rows
+    read (a step only). Returns [.., H*Dv], or ``(out, count)``."""
+    chunk = len(q.shape) == 3
+    helper = LayerHelper("cached_attention_chunk" if chunk
+                         else "cached_attention", param_attr=sink_attr,
+                         name=name)
+    heads = int(num_heads)
+    kv_heads = int(num_kv_heads or heads)
+    inputs = {"Q": q, "CacheK": cache_k, "CacheV": cache_v, "Pos": pos}
+    attrs = {"num_heads": heads}
+    if kv_heads != heads:
+        attrs["num_kv_heads"] = kv_heads
+    if window:
+        attrs["window"] = int(window)
+    if ring:
+        attrs["ring"] = True
+        if chunk:
+            inputs.update(NewK=new_k, NewV=new_v)
+    if sink_attr is not None:
+        inputs["Sink"] = helper.create_parameter(
+            helper.param_attr, shape=[heads], dtype=_dtype(q),
+            default_initializer=ConstantInitializer(0.0))
+    width = int(cache_v.shape[-1])
+    out = helper.create_variable_for_type_inference(
+        dtype=_dtype(q), shape=tuple(q.shape[:-1]) + (
+            width if width < 0 else width // kv_heads * heads,))
+    outputs = {"Out": out}
+    if count:
+        if chunk:
+            raise ValueError("cached_attention counts the positions a step "
+                             "reads, not a chunk's")
+        outputs["Count"] = helper.create_variable_for_type_inference(
+            dtype="int32", shape=(1,))
+        outputs["Count"].stop_gradient = True
+    helper.append_op(helper.layer_type, inputs, outputs, attrs)
+    return (out, outputs["Count"]) if count else out
 
 
 def cached_multi_head_attention(x, cache_k, cache_v, pos, d_model=None,
@@ -2137,17 +2206,29 @@ def cached_multi_head_attention(x, cache_k, cache_v, pos, d_model=None,
     return out, new_k, new_v
 
 
-def kv_cache_write_chunk(cache, x, pos, name=None):
+def kv_cache_write_chunk(cache, x, pos, ring=False, pad_pos=None,
+                         name=None):
     """K-row cache update: ``cache[b, pos[b, j]] = x[b, j]`` (see
     ``core/opimpl/attention_ops.py``). ``cache``: [B, C, ...] of any tail
     (as :func:`kv_cache_write`), ``x``: [B, K, ...], ``pos``: [B, K] int.
     Out-of-range positions drop, so a padded chunk lane writes nothing.
-    Returns the updated cache."""
+    ``ring``: the cache is a ring of C positions; a lane whose position is
+    ``pad_pos`` or more is a pad lane (taken modulo C it would land on a
+    live slot, so the program is told what the scheduler pads with), and of
+    a row's live lanes only the last C land, at ``pos % C``. Returns the
+    updated cache."""
     helper = LayerHelper("kv_cache_write_chunk", name=name)
     out = helper.create_variable_for_type_inference(
         dtype=_dtype(cache), shape=cache.shape)
+    attrs = {}
+    if ring:
+        if pad_pos is None:
+            raise ValueError("a ring's chunk write needs pad_pos, the "
+                             "position the scheduler gives a pad lane")
+        attrs = {"ring": True, "pad_pos": int(pad_pos)}
     helper.append_op("kv_cache_write_chunk",
-                     {"Cache": cache, "X": x, "Pos": pos}, {"Out": out}, {})
+                     {"Cache": cache, "X": x, "Pos": pos}, {"Out": out},
+                     attrs)
     return out
 
 

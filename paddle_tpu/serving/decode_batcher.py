@@ -21,7 +21,15 @@ design the reference, whose serving story ends at
     The spec's ``cache_feeds`` name them one by one with their own tails
     and types: a key and a value of one width a layer, or ONE latent row
     a layer, with an index-key cache beside it on the layers that have an
-    indexer and none on the others; the scheduler never pairs them;
+    indexer and none on the others; the scheduler never pairs them. A
+    cache feed may state a ``capacity`` of its own: a RING of that many
+    positions a row whatever the context rung (a window layer's keys and
+    values; the program writes position p at slot ``p % capacity``), which
+    the scheduler allocates, warms, stages, re-buckets, gathers and
+    scatters at that capacity beside the caches that hold the rung. What
+    cannot hold with a ring is refused at construction: a prefix cache (a
+    prefix's rows are gone once the ring wraps) and speculation (a
+    rejected draft's writes cannot be rewound);
   * one compiled step per ``(bucket_batch, bucket_ctx)`` on the pow2
     ladders (``buckets.py``), so the XLA compile cache stays bounded at
     ``len(ladder) * len(ctx_ladder)`` executables;
@@ -186,7 +194,7 @@ def chunk_rows(k, b):
 
 
 @functools.lru_cache(maxsize=None)
-def _rows_helpers(lanes):
+def _rows_helpers(lanes, whole=frozenset()):
     """The two jitted copies round a sub-batched chunk run (the jit names
     are what a device trace shows), each a loop over the ``n`` sub-rows
     that hold a slot row, so a pad sub-row costs nothing:
@@ -200,7 +208,10 @@ def _rows_helpers(lanes):
     wrote, slid down where they would pass the capacity (a lane the run
     left alone holds what the gather read, so writing it back changes
     nothing). Whole rows written back measured 2.5-10.3 ms for OPT-1.3B's
-    48 caches where the lanes measure 2.0-2.6 (PERF.md section 6, PR 38)."""
+    48 caches where the lanes measure 2.0-2.6 (PERF.md section 6, PR 38).
+    The caches named in ``whole`` are rings: a chunk's lanes land at their
+    positions modulo the ring, not at ``[start, start + lanes)``, and a
+    ring row is small, so the whole row goes back."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -219,7 +230,8 @@ def _rows_helpers(lanes):
         def write_row(j, table):
             out = {}
             for name, a in table.items():
-                width = min(lanes, a.shape[1])
+                width = a.shape[1] if name in whole \
+                    else min(lanes, a.shape[1])
                 at = jnp.clip(start[j], 0, a.shape[1] - width)
                 rest = (0,) * (a.ndim - 2)
                 out[name] = lax.dynamic_update_slice(
@@ -423,7 +435,9 @@ class DecodeBatcher:
     ``spec``: the decode-spec dict a step builder returns
     (``models.transformer.transformer_lm_step``): token/pos feed names,
     logits fetch, cache feed/fetch pairs each with its own tail shape
-    and dtype (any number a layer), and optionally ``counter_fetch`` /
+    and dtype (any number a layer) and optionally a ``capacity`` (a ring of
+    that many positions a row; absent: the context rung), and optionally
+    ``counter_fetch`` /
     ``counters``: one small int vector the step program counts of itself
     and the names of its entries, added after every step to the metrics'
     ``program_<name>`` counters.
@@ -450,11 +464,20 @@ class DecodeBatcher:
         self._counter_idx = (
             fetch_names.index(self._spec["counter_fetch"])
             if self._counter_names else None)
+        # (feed, fetch index, tail, dtype, capacity): capacity None where
+        # the cache holds the context rung, a ring's own where it states one
         self._cache_feeds = []
         for cf in self._spec["cache_feeds"]:
+            cap = cf.get("capacity")
+            if cap is not None and int(cap) < 1:
+                raise ValueError("cache feed %r states a capacity of %r"
+                                 % (cf["feed"], cap))
             self._cache_feeds.append(
                 (cf["feed"], fetch_names.index(cf["fetch"]),
-                 tuple(cf["tail"]), np.dtype(cf.get("dtype", "float32"))))
+                 tuple(cf["tail"]), np.dtype(cf.get("dtype", "float32")),
+                 None if cap is None else int(cap)))
+        self._rings = frozenset(cf[0] for cf in self._cache_feeds
+                                if cf[4] is not None)
         self._step = _Carrying(predictor,
                                [cf[:2] for cf in self._cache_feeds])
         self.ladder = tuple(sorted(set(
@@ -482,6 +505,9 @@ class DecodeBatcher:
         self.prefix_cache = None
         # NOT a truthiness test: an EMPTY PrefixCache is len()==0/falsy
         if prefix_cache is not None and prefix_cache is not False:
+            self._refuse_with_rings(
+                "prefix_cache=", "a prefix's rows are gone from a ring "
+                "once it wraps, so they cannot be cloned into another slot")
             if isinstance(prefix_cache, PrefixCache):
                 self.prefix_cache = prefix_cache
             else:
@@ -498,10 +524,27 @@ class DecodeBatcher:
         # carried cache dict feeds both
         self._prefill = None
         self.prefill_ladder = ()
+        # what a pad lane of a chunk carries for a position: the context
+        # rung, so that its cache writes drop; a chunk program with a ring
+        # must tell a pad lane from a live one (modulo the ring it would
+        # land on a live slot) and states the one position it is given
+        self._pad_pos = None
         if prefill is not None:
             p = dict(prefill)
             cpred = p["predictor"]
             cspec = dict(p["spec"])
+            if cspec.get("pad_pos") is not None:
+                self._pad_pos = int(cspec["pad_pos"])
+                if self._pad_pos < max(self.ctx_ladder):
+                    raise ValueError(
+                        "the chunk program takes position %d for a pad "
+                        "lane, inside the context rung %d" % (
+                            self._pad_pos, max(self.ctx_ladder)))
+            elif self._rings:
+                raise ValueError(
+                    "cache %r is a ring and the chunk program's spec "
+                    "states no pad_pos: it could not tell a pad lane from "
+                    "a live one" % min(self._rings))
             cfetch = list(cpred.fetch_names)
             step_feeds = {cf["feed"] for cf in self._spec["cache_feeds"]}
             cmap = []
@@ -537,6 +580,9 @@ class DecodeBatcher:
         self._draft = None
         self._spec_k = 0
         if speculative is not None:
+            self._refuse_with_rings(
+                "speculative=", "a rejected draft's writes into a ring "
+                "overwrite positions that cannot be rewound")
             if self._prefill is None:
                 raise ValueError("speculative decode needs the chunk "
                                  "program (pass prefill= as well)")
@@ -566,6 +612,14 @@ class DecodeBatcher:
             self._thread = threading.Thread(
                 target=self._loop, name="paddle-tpu-decode", daemon=True)
             self._thread.start()
+
+    def _refuse_with_rings(self, option, why):
+        for feed, _idx, _tail, _dtype, cap in self._cache_feeds:
+            if cap is not None:
+                raise ValueError(
+                    "%s cannot be used with this decode spec: cache %r is "
+                    "a ring of %d positions, and %s" % (option, feed, cap,
+                                                        why))
 
     # -- client surface -----------------------------------------------------
     def now(self):
@@ -891,11 +945,12 @@ class DecodeBatcher:
         for req in admitting:
             new_slots.append(_Slot(req))
         new_slots += [None] * (new_b - len(new_slots))
-        copy_c = min(old_c, new_c)
         copied = 0
-        for feed, _idx, tail, dtype in self._cache_feeds:
+        for feed, _idx, tail, dtype, cap in self._cache_feeds:
             old = self._caches.get(feed)
-            new = np.zeros((new_b, new_c) + tail, dtype)
+            # a ring keeps its capacity whatever the rung, and goes whole
+            copy_c = cap or min(old_c, new_c)
+            new = np.zeros((new_b, cap or new_c) + tail, dtype)
             if old is not None and live:
                 old = np.asarray(old)
                 for j, (i, _s) in enumerate(live):
@@ -917,7 +972,7 @@ class DecodeBatcher:
         if match is None:
             return
         m = match.length
-        for feed, _idx, _tail, _dtype in self._cache_feeds:
+        for feed, *_ in self._cache_feeds:
             rows = match.entry.rows.get(feed)
             if rows is None:
                 continue
@@ -934,8 +989,8 @@ class DecodeBatcher:
                 self._pos_feed: np.zeros((b,), np.int32)}
 
     def _synth_caches(self, b, c):
-        return {name: np.zeros((b, c) + tail, dtype)
-                for name, _idx, tail, dtype in self._cache_feeds}
+        return {name: np.zeros((b, cap or c) + tail, dtype)
+                for name, _idx, tail, dtype, cap in self._cache_feeds}
 
     def _tick(self):
         """One scheduler quantum: a chunk dispatch (prefill and/or
@@ -996,11 +1051,12 @@ class DecodeBatcher:
                 sp.set(staging=len(ahead))
 
     def _cache_shapes(self, rows, c):
-        """{cache feed: the shape and type of its ``rows`` x ``c`` array}."""
+        """{cache feed: the shape and type of its ``rows`` x ``c`` array,
+        or ``rows`` x its own capacity where it is a ring}."""
         import jax
 
-        return {name: jax.ShapeDtypeStruct((rows, c) + tail, dtype)
-                for name, _idx, tail, dtype in self._cache_feeds}
+        return {name: jax.ShapeDtypeStruct((rows, cap or c) + tail, dtype)
+                for name, _idx, tail, dtype, cap in self._cache_feeds}
 
     def _stage(self, sig):
         """Make (or load) the executable of ``sig`` from shapes, nothing
@@ -1038,7 +1094,7 @@ class DecodeBatcher:
         if made is None:
             import jax
 
-            gather, scatter = _rows_helpers(min(k, c))
+            gather, scatter = _rows_helpers(min(k, c), self._rings)
             table, sub = self._cache_shapes(b, c), self._cache_shapes(r, c)
             idx = jax.ShapeDtypeStruct((r,), np.dtype("int32"))
             n = jax.ShapeDtypeStruct((), np.dtype("int32"))
@@ -1133,7 +1189,8 @@ class DecodeBatcher:
         """Dispatch one chunk: K-token lanes per covered row, pad lanes
         carry the pad sentinel ``pos == bucket_ctx`` (their cache writes
         drop via the op's out-of-range mode and their logits are
-        ignored). Where the rung's height (:meth:`_chunk_height`) is
+        ignored; the chunk spec's ``pad_pos`` in its place where it states
+        one). Where the rung's height (:meth:`_chunk_height`) is
         under the bucket, the run is over a SUB-BATCH: one jitted gather
         copies the covered rows' caches out of the slot table, the chunk
         executable is handed those, and one jitted scatter, the table
@@ -1158,7 +1215,7 @@ class DecodeBatcher:
         copies = self._rows_copies(sig)
         with trace.span("decode.feed"):
             tok = np.zeros((r, k), np.int64)
-            cpos = np.full((r, k), c, np.int32)
+            cpos = np.full((r, k), self._pad(c), np.int32)
             # sub-row j holds table row at[j]; the pads' lies past the
             # table, and neither copy's loop reaches them
             at = np.full((r,), b, np.int32)
@@ -1273,7 +1330,7 @@ class DecodeBatcher:
         if len(key) < 2 or key in self.prefix_cache:
             return
         rows = {}
-        for feed, _idx, _tail, _dtype in self._cache_feeds:
+        for feed, *_ in self._cache_feeds:
             rows[feed] = np.array(np.asarray(self._caches[feed])[i,
                                                                  :len(key)])
         self.prefix_cache.insert(key, rows)
@@ -1283,7 +1340,11 @@ class DecodeBatcher:
         pf = self._prefill
         r = self._chunk_height(b, k)
         return {pf["tok"]: np.zeros((r, k), np.int64),
-                pf["pos"]: np.full((r, k), c, np.int32)}
+                pf["pos"]: np.full((r, k), self._pad(c), np.int32)}
+
+    def _pad(self, c):
+        """The position a pad lane carries in a bucket of context ``c``."""
+        return c if self._pad_pos is None else self._pad_pos
 
     def _step_once(self):
         self._await_staged(self._bucket)
@@ -1310,7 +1371,7 @@ class DecodeBatcher:
         sig = (b, c)
         self.seen_signatures.add(sig)
         self._caches = {name: outs[idx]
-                        for name, idx, _tail, _dtype in self._cache_feeds}
+                        for name, idx, *_ in self._cache_feeds}
         self.metrics_.observe_cache_donated(
             self._cache_bytes if self._step.hands_over else 0)
         # the wait for the device and the logits' way to the host: the run

@@ -261,6 +261,112 @@ def test_sparse_selection_and_latent_attention_fit_at_published_widths(chip):
     assert compiled.as_text().count(" while(") >= 4
 
 
+def test_grouped_window_and_ring_attention_fit_at_published_widths(chip):
+    """``mimo2flash.serve.mixedlen.sat``'s attention at its own sizes (16
+    slot rows x 16384 positions; 64 query heads of 192 on 4 key heads and 4
+    value heads of 128 a full layer, on 8 and 8 over a ring of 128 a window
+    layer, a sink a head), bfloat16: a step reads each cache as it is
+    stored (no copy of a cache, no repeat to the query heads), a chunk's 512
+    lanes walk a full layer's cache in blocks under a dynamic trip count (no
+    [lanes, heads, context] scores: 2.1 GB) and read a ring beside their own
+    rows in blocks of the ring's size; all of it plain XLA."""
+    from paddle_tpu.ops import cache_attention as ca
+
+    b, c, heads, ring = 16, 16384, 64, 128
+
+    def full_step(q, k, v, pos):
+        return ca.attend_step(q, k, v, pos, heads, 4)
+
+    compiled = _compile(
+        chip, full_step, sds((b, heads * 192), BF16), sds((b, c, 768), BF16),
+        sds((b, c, 512), BF16), sds((b,), I32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+    assert not re.search(r"= bf16\[16,16384,\d+\]\S* (copy|transpose)\(",
+                         compiled.as_text())
+
+    def window_step(q, k, v, pos, sink):
+        return ca.attend_step(q, k, v, pos, heads, 8, ring, sink, True)
+
+    compiled = _compile(
+        chip, window_step, sds((b, heads * 192), BF16),
+        sds((b, ring, 1536), BF16), sds((b, ring, 1024), BF16),
+        sds((b,), I32), sds((heads,), BF16))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9
+
+    def full_chunk(q, k, v, pos):
+        return ca.attend_chunk(q, k, v, pos, heads, 4)
+
+    compiled = _compile(
+        chip, full_chunk, sds((1, 512, heads * 192), BF16),
+        sds((1, c, 768), BF16), sds((1, c, 512), BF16), sds((1, 512), I32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+    # the blocks run under a loop, not unrolled: a dynamic trip count
+    assert compiled.as_text().count(" while(") >= 1
+
+    def ring_chunk(q, rk, rv, nk, nv, pos, sink):
+        return ca.attend_chunk_ring(q, rk, rv, nk, nv, pos, heads, 8, ring,
+                                    sink)
+
+    compiled = _compile(
+        chip, ring_chunk, sds((1, 512, heads * 192), BF16),
+        sds((1, ring, 1536), BF16), sds((1, ring, 1024), BF16),
+        sds((1, 512, 1536), BF16), sds((1, 512, 1024), BF16),
+        sds((1, 512), I32), sds((heads,), BF16))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+    assert _kernel_calls(compiled) == 0
+
+
+def test_mimo_v2_step_reads_its_weights_where_they_lie_and_fits_the_chip(
+        chip):
+    """The cell's step program, built from the configuration's own keys
+    and compiled for one chip at 16 slot rows x 16384: the caches it is
+    handed are written in place (1.4 GB: two layers at the rung, five rings
+    of 128), and NO weight is copied. Without the ``optimization_barrier``
+    between the query/key projections and the ops that view their product
+    a head at a time (192 wide, no multiple of 128), the compiler lays each
+    projection's weight out for that view: a copy of 100 MB a layer a
+    step."""
+    import json
+
+    import paddle_tpu as fluid
+    from paddle_tpu.core.executor import build_step_fn
+    from paddle_tpu.models import mimo_v2
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mimo-v2-flash.json")) as f:
+        body = json.load(f)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        fetch, spec = mimo_v2.mimo_v2_step(
+            dtype="bfloat16", **{k: body[k] for k in body["builder_keys"]})
+    gb = main.global_block()
+    persist = sorted({v.name for v in main.list_vars() if v.persistable})
+    state = {n: sds(tuple(gb.var(n).shape),
+                    BF16 if gb.var(n).dtype == "bfloat16"
+                    else np.dtype(gb.var(n).dtype)) for n in persist}
+    b, c = 16, 16384
+    feed = {spec["token_feed"]: sds((b,), I32),
+            spec["pos_feed"]: sds((b,), I32)}
+    cache_bytes = 0
+    for cf in spec["cache_feeds"]:
+        shape = (b, cf.get("capacity") or c) + tuple(cf["tail"])
+        feed[cf["feed"]] = sds(shape, BF16)
+        cache_bytes += 2 * int(np.prod(shape))
+    assert cache_bytes == 1342177280 + 52428800
+    rng = jax.eval_shape(lambda: jax.random.key(0, impl="rbg"))
+    step = build_step_fn(main, [v.name for v in fetch], persist,
+                         infer_only=True)
+    compiled = _compile(chip, step, state, feed, rng, donate_argnums=(1,))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes       # written in place
+    assert mem.temp_size_in_bytes < 0.3e9
+    need = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    assert 0.25 * _HBM_BYTES < need < 0.6 * _HBM_BYTES
+    assert not re.search(r"= bf16\[\d+,\d+\]\S* (copy|transpose)\(%?state",
+                         compiled.as_text())
+
+
 def test_state_space_core_and_latent_experts_fit_at_published_widths(chip):
     """``nemotron3super.train.s8192``'s share of a layer: the Mamba-2 scan
     (16 heads of 64 x 128 state, one group, T = 8192, chunks of 128) keeps
