@@ -648,8 +648,13 @@ class Executor:
                 scope.set(n, globalize(state_sh[n], scope.get(n)))
             scope.set(RNG_KEY, globalize(repl_sh, scope.get(RNG_KEY)))
 
+        # a host array is named by the type jit gives it (int64 is int32
+        # without x64), so that the same feed as a device array, such as a
+        # fetch of the step before, finds the same variant
         feed_sig = tuple(sorted(
-            (n, a.shape, str(a.dtype)) for n, a in feed_arrays.items()))
+            (n, a.shape, str(jax.dtypes.canonicalize_dtype(a.dtype)
+                             if isinstance(a, np.ndarray) else a.dtype))
+            for n, a in feed_arrays.items()))
         key = (id(program), program._version, feed_sig, tuple(fetch_names),
                state_in_names, id(scope), mesh, dp_axis, sp_axis, seq_feeds,
                pp, zero_state, grad_scale, donate_state, placement)

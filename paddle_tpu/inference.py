@@ -141,6 +141,31 @@ class ProgramPredictor:
                         fetch_list=self._fetch_vars, scope=self._scope,
                         donate_state=False, donate_feeds=donate_feeds)
 
+    def fetch_argmax(self, fetch_name):
+        """Ask for one more fetch: the ``argmax`` over the last axis of the
+        fetch called ``fetch_name`` (int32, the first maximum, as
+        ``np.argmax`` takes it), computed inside the same executable.
+        Returns the new fetch's name; asked again, or of a clone that shares
+        the program, it gives the same one. The op is appended to the
+        program this predictor holds, so ask before the first ``run`` or
+        ``stage``: a variant made before lacks the fetch and is made again.
+        A decode loop asks this of its step predictor and reads ``[b]`` ids
+        where it would copy ``[b, vocabulary]`` logits to the host."""
+        name = fetch_name + "@argmax"
+        if name not in self.fetch_names:
+            block = self._program.global_block()
+            if not block.has_var(name):
+                x = block.var(fetch_name)
+                out = block.create_var(
+                    name=name, dtype="int32", stop_gradient=True,
+                    shape=None if x.shape is None else x.shape[:-1])
+                block.append_op("argmax", {"X": x}, {"Out": out},
+                                {"axis": -1})
+            # new lists: a clone shares the old ones
+            self._fetch_vars = self._fetch_vars + [block.var(name)]
+            self.fetch_names = self.fetch_names + [name]
+        return name
+
     def clone(self, device=None):
         """A predictor sharing this one's weights (ref
         ``AnalysisPredictor::Clone``): same scope/program, fresh exe cache.

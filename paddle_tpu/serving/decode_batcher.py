@@ -108,28 +108,78 @@ another geometry in that sense: a row's lanes see the same cache row and
 the same positions as they would in the slot table, computed by the
 executable of ``[rows, k]`` and not of ``[b, k]``.
 
-Sampling is host-side greedy argmax over the fetched next-token logits
-row: deterministic, per-row, and it keeps eos/length control flow out of
-the compiled step.
+Sampling is greedy argmax, per row and deterministic, and it is made
+INSIDE the step executable: the loop asks its step predictor for one more
+fetch, the ``argmax`` of the logits fetch (``ProgramPredictor.
+fetch_argmax``, appended to the program before its first compile, so a
+geometry still has ONE step executable and a step ONE dispatch), and reads
+``[b]`` int32 ids where it would copy ``[b, vocabulary]`` float32 logits
+to the host; ``jnp.argmax`` and ``np.argmax`` both take the first maximum
+of the same float32 values, so the tokens are the same bit for bit. Eos
+and length control flow stay on the host. A predictor that cannot be asked
+(an exported computation, a wrapper, a test fake) is served from its
+logits, ``np.argmax`` a live row, as every predictor was before; the loop
+decides by what it finds (``fetch_argmax``, and a ``run`` that takes the
+caches' hand-over: one that does not is a wrapper, which may rewrite the
+logits it passes on), no option. A chunk that samples (speculation) reads
+its logits on the host.
+
+**The loop runs one step ahead.** The next step's one data dependence on
+this one, beside the carried caches, is the sampled id; every other input
+(positions, which rows are live, which end by ``max_new``) the host knows
+before the step has run. So after dispatching step n the loop dispatches
+step n+1 at once, step n's id array (on the device, unread) its token
+feed and the positions one on, and only then reads step n's ids, delivers
+the tokens and retires what is done: the device runs n+1 while the host
+does that. Depth one, never more; and only where the quantum to come is
+again a plain step over the same slot table (:meth:`DecodeBatcher.
+_rows_after`): no row ingests (a chunk or a forced prompt token is the
+host's), no row awaits the prefix cache's harvest, speculation is off, no
+re-bucketing is due, and no request waits that a slot free after step n
+could take (rows that end by ``max_new`` at step n are known now); then
+the loop reads first and admits, as it always did. While a step is in
+flight nobody is admitted (``_admit`` returns at once), so a request that
+arrives in the middle of a run waits for the step running and the one
+queued, and no more: the quantum that finds it waiting only reads. The end
+of a run that can be foreseen is settled one step early, from the same
+knowledge: a step dispatched ahead that will have no follower is read in
+the quantum that dispatched it, after the step before it. The one thing
+the host learns late is a row that ends on an end-of-sequence id: it
+rode step n+1 as a live lane, its output there is dropped, and its cache
+write landed at its own row's next position (modulo its own row's ring),
+which a later occupant of the slot writes before its attention reaches it:
+the property slot recycling rests on. ``drive(max_steps)``, ``shutdown``
+and a step that raises leave no step unread.
 
 **The loop accounts for its own quantum** (``obs.trace.span``: the tracer
 and, under a ``jax.profiler`` trace, ``paddle_tpu.<name>`` on its host
 plane; the falsy no-op with neither). Between two quanta ``decode.idle``
 (the loop's wait while no slot is live and nothing is queued),
-``decode.admit`` and ``decode.plan``; a quantum is ``decode.step`` over
-``decode.feed``, ``executor.run``, ``decode.fetch`` (the wait for the
-device and the logits' way to the host) and ``decode.sample``, or
-``prefill.chunk`` / ``spec.verify`` over ``decode.feed`` and
-``executor.run`` (a chunk's span ends at its dispatch; one that samples
-also fetches). No span waits for the device on its own account. README,
-Observability, lists their tags and the counters beside them.
+``decode.admit`` and ``decode.plan`` (no plan before a step that is in
+flight already); a quantum is ``decode.step`` over ``decode.feed``,
+``executor.run``, ``decode.fetch`` (the wait for the device and the ids'
+way to the host) and ``decode.sample``, or ``prefill.chunk`` /
+``spec.verify`` over ``decode.feed`` and ``executor.run`` (a chunk's span
+ends at its dispatch; one that samples also fetches). ONE ``decode.step``
+span a step, ending with the read of exactly one step: in a run of steps a
+span's feed and ``executor.run`` are step n+1's and its fetch and sample
+are step n's; the first span of a run holds two dispatches; and the run's
+last step, read in the quantum that dispatched it, has a span of its own
+that opens before that quantum's and closes after it (it holds that span,
+then its own fetch and sample). So every span lies over a dispatch, but
+one kind: a run cut short by what the host could not foresee (an arrival
+there is a slot for) ends with a span of a fetch and a sample alone. The
+spans' period is still the loop's period a step. No span waits for the
+device on its own account. README, Observability, lists their tags and
+the counters beside them.
 """
 
+import contextlib
 import functools
 import inspect
 import threading
 import time
-from collections import deque
+from collections import deque, namedtuple
 from concurrent.futures import Future
 
 import numpy as np
@@ -424,6 +474,13 @@ class _Slot:
         return self.k < len(self.req.prompt)
 
 
+# A step dispatched and not read yet: what the run returned (``outs``, device
+# arrays in fetch order), the rows that rode it as live lanes, each ``(slot
+# row, its _Slot, the position it was fed at)``, and whether it was
+# dispatched ``ahead`` of the read of the step before.
+_Flight = namedtuple("_Flight", "outs rows ahead")
+
+
 class DecodeBatcher:
     """The continuous batcher. Same client surface as ``ServingEngine``
     (``submit``/``predict``/``metrics``/``warmup``/``shutdown``) so the
@@ -480,6 +537,19 @@ class DecodeBatcher:
                                 if cf[4] is not None)
         self._step = _Carrying(predictor,
                                [cf[:2] for cf in self._cache_feeds])
+        # the greedy choice inside the step executable: a predictor that
+        # can be asked (``ProgramPredictor.fetch_argmax``) returns ``[b]``
+        # ids as one more fetch, and the loop reads those; one that cannot
+        # (an exported computation, a test fake), or whose ``run`` takes no
+        # hand-over (a wrapper round it, which may as well rewrite the
+        # logits it passes on: ``tests/benchmark/test_serve_cell.py`` breaks
+        # a step so), is served from its logits
+        self._ids_idx = None
+        ask = getattr(predictor, "fetch_argmax", None)
+        if ask is not None and self._step.hands_over:
+            ids_fetch = ask(self._spec["logits_fetch"])
+            self._ids_idx = list(predictor.fetch_names).index(ids_fetch)
+        self._flight = None  # the step dispatched and not read yet
         self.ladder = tuple(sorted(set(
             ladder if ladder is not None else pow2_ladder(max_batch_size))))
         if ctx_ladder is None:
@@ -739,9 +809,10 @@ class DecodeBatcher:
 
     def drive(self, max_steps=None):
         """Run the scheduler loop synchronously on the CALLING thread
-        until idle (or ``max_steps`` decode steps). Only valid with
+        until idle (or ``max_steps`` quanta: a quantum is a chunk run or a
+        step read, two steps where a run of steps ends). Only valid with
         ``start=False`` — the deterministic test/bench mode. Returns the
-        number of steps executed."""
+        number of quanta executed. No step is left in flight."""
         if self._thread is not None:
             raise RuntimeError("drive() requires start=False "
                                "(the loop thread owns the slot table)")
@@ -751,8 +822,10 @@ class DecodeBatcher:
                 self._admit()
                 if not any(s is not None for s in self._slots):
                     break
-                self._tick()
+                # the last quantum asked for dispatches nothing ahead:
+                # every step dispatched is read before this returns
                 steps += 1
+                self._tick(last=steps == max_steps)
         except BaseException as e:
             self._poison(e)
             raise
@@ -833,7 +906,14 @@ class DecodeBatcher:
 
     def _drop_table(self):
         """Forget the slot table and its caches (every slot is free): the
-        next admission re-buckets from nothing, into new zero caches."""
+        next admission re-buckets from nothing, into new zero caches. A
+        step in flight over the table is waited for and dropped with it."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            try:
+                np.asarray(flight.outs[self._ids_idx])
+            except Exception:  # noqa: BLE001: its rows have failed already
+                pass
         self._slots = []
         self._caches = {}
         self._cache_bytes = 0
@@ -875,6 +955,14 @@ class DecodeBatcher:
     # admission + re-bucketing — runs BETWEEN steps only (slot recycling)
     def _admit(self):
         with trace.span("decode.admit") as sp:
+            if self._flight is not None:
+                # a step is in flight over this slot table: whoever waits
+                # is admitted once it has been read (a request there is
+                # room for ends the run: nothing is dispatched ahead of it)
+                if sp:
+                    sp.set(admitted=0, pending=len(self._pending),
+                           rebucketed=0)
+                return
             now = self._clock()
             admitted = []
             waited = 0.0
@@ -992,7 +1080,7 @@ class DecodeBatcher:
         return {name: np.zeros((b, cap or c) + tail, dtype)
                 for name, _idx, tail, dtype, cap in self._cache_feeds}
 
-    def _tick(self):
+    def _tick(self, last=False):
         """One scheduler quantum: a chunk dispatch (prefill and/or
         speculative verify) when the chunk program has work, else one
         decode step. When some live rows can't ride the chunk (they are
@@ -1000,9 +1088,13 @@ class DecodeBatcher:
         ALTERNATE so a long prompt is ingested chunk-by-chunk without
         stalling its co-riders. Alternation is for rows that generate: an
         ingesting row the sub-batch had no room for rides the next chunk,
-        and where no row generates that chunk is the next tick."""
-        if self._prefill is None:
-            self._step_once()
+        and where no row generates that chunk is the next tick. Where a
+        step is in flight (:meth:`_step_once` dispatched it ahead) the
+        quantum is that step's: no row ingested when it was dispatched, and
+        nobody was admitted since. ``last``: the caller stops after this
+        quantum, so no step is dispatched ahead of it."""
+        if self._prefill is None or self._flight is not None:
+            self._step_once(last)
             return
         with trace.span("decode.plan") as sp:
             plan = self._chunk_plan()
@@ -1011,12 +1103,12 @@ class DecodeBatcher:
                        verifying=bool(plan and plan[2]))
         if plan is None:
             self._alt_chunk = False
-            self._step_once()
+            self._step_once(last)
             return
         rows, has_uncovered, verifying, deferred = plan
         if has_uncovered and self._alt_chunk:
             self._alt_chunk = False
-            self._step_once()
+            self._step_once(last)
             return
         self._alt_chunk = True
         with trace.span("spec.verify" if verifying
@@ -1346,20 +1438,103 @@ class DecodeBatcher:
         """The position a pad lane carries in a bucket of context ``c``."""
         return c if self._pad_pos is None else self._pad_pos
 
-    def _step_once(self):
-        self._await_staged(self._bucket)
-        with trace.span("decode.step") as sp:
-            self._step_once_traced(sp)
+    def _step_once(self, last=False):
+        """One step quantum. It reads the step in flight, dispatched ahead
+        by the quantum before, or else dispatches one over the live slots
+        and reads that; and where the quantum to come is again a plain step
+        over this slot table (:meth:`_rows_after`), that step is dispatched
+        BEFORE the read, the ids of the step about to be read its tokens:
+        the device runs it while the host reads, delivers and retires.
+        Depth one, never more.
 
-    def _step_once_traced(self, sp):
+        The run's end is settled one step early, from the same knowledge:
+        where the step dispatched ahead here will have no follower (a row
+        of its own ends by ``max_new`` with somebody waiting for the slot,
+        all of them end, a re-bucketing falls due), it is read here too,
+        after the step before it, under a ``decode.step`` span of its own
+        that opens before this quantum's (it holds this quantum's
+        dispatches and ends with the read of exactly that step): ONE span a
+        step, each ending with the read of one step, and each over a
+        dispatch but one kind: a step left in flight whose follower is
+        called off by what happened since (a request arrived that there is
+        a slot for, a row ended on its eos id with somebody waiting) is
+        read by a quantum that dispatches nothing. ``last`` (:meth:`drive`
+        with a budget) dispatches nothing ahead."""
+        self._await_staged(self._bucket)
+        flight, self._flight = self._flight, None
+        rows = flight.rows if flight is not None else [
+            (i, slot, slot.pos) for i, slot in enumerate(self._slots)
+            if slot is not None]
+        ahead = None if last else self._rows_after(rows, 1)
+        ends = ahead is not None and self._rows_after(ahead, 2) is None
+        with (trace.span("decode.step") if ends
+              else contextlib.nullcontext()) as outer:
+            with trace.span("decode.step") as sp:
+                if flight is None:
+                    flight = self._dispatch(rows)
+                if ahead is not None:
+                    self._flight = self._dispatch(
+                        ahead, flight.outs[self._ids_idx])
+                self._land(flight, sp)
+            if ends:
+                flight, self._flight = self._flight, None
+                if flight is not None:
+                    self._land(flight, outer)
+
+    def _rows_after(self, rows, k):
+        """The rows of the step after one over ``rows``, each one position
+        on, if the quantum after that step is again a plain step over the
+        same slot table; else None. ``rows``: ``(slot row, its _Slot, the
+        position fed)`` of a step whose token will be each slot's ``k``-th
+        from what its ``out`` holds now (1: the step not read yet; 2: the
+        one after it). Decided from the loop's own state, all of it known
+        before the ids are. Rows known to end at that step by ``max_new``
+        ride no further (dead lanes, like the table's holes). The one
+        thing the host learns late is a row that ends on an end-of-sequence
+        id: it rides the step ahead as a live lane and its output there is
+        dropped (:meth:`_land`); its cache write lands at its own row's
+        next position (modulo its own row's ring), which a later occupant
+        of the slot writes before its attention reaches it."""
+        if self._ids_idx is None or self._spec_k:
+            return None  # the next tokens are the host's to make
+        harvests = self.prefix_cache is not None
+        after = []
+        for i, slot, p in rows:
+            if self._slots[i] is not slot:
+                continue  # ended on its eos id a step ago: a dead lane
+            if slot.forcing or (harvests and not slot.harvested):
+                # its next token is the prompt's, not the device's; or the
+                # prefix cache is to read rows the step ahead is handed
+                return None
+            if len(slot.out) + k < slot.req.max_new:
+                after.append((i, slot, p + 1))
+        if not after:
+            return None
+        if self._pending and len(after) < max(self.ladder):
+            return None  # read first, admit; the chunk's quantum follows
+        if self._bucket != (
+                bucket_for(len(after), self.ladder),
+                bucket_for(max(slot.req.n_ctx for _i, slot, _p in after),
+                           self.ctx_ladder)):
+            return None  # a re-bucketing is due
+        return after
+
+    def _dispatch(self, rows, ids=None):
+        """Dispatch one step over ``rows`` and return it as a
+        :class:`_Flight`; nothing is waited for. Each row is fed the token
+        the host holds for it, or, with ``ids`` (the id array of the step
+        before, unread and on the device), that array is the token feed
+        itself, of the shape and type the executable was made for."""
         b, c = self._bucket
         with trace.span("decode.feed"):
-            toks = np.zeros((b,), np.int64)
             pos = np.zeros((b,), np.int32)
-            for i, slot in enumerate(self._slots):
-                if slot is not None:
+            toks = ids
+            if ids is None:
+                toks = np.zeros((b,), np.int64)
+                for i, slot, _p in rows:
                     toks[i] = slot.next_token
-                    pos[i] = slot.pos
+            for i, _slot, p in rows:
+                pos[i] = p
             # carried state: the caches are handed over to the step (it
             # writes its rows into the buffers it is given), and the
             # fetched arrays, device-resident and never on the host, are
@@ -1368,39 +1543,49 @@ class DecodeBatcher:
             feed = {self._tok_feed: toks, self._pos_feed: pos,
                     **self._caches}
         outs = self._step.run(feed)
-        sig = (b, c)
-        self.seen_signatures.add(sig)
+        self.seen_signatures.add((b, c))
         self._caches = {name: outs[idx]
                         for name, idx, *_ in self._cache_feeds}
         self.metrics_.observe_cache_donated(
             self._cache_bytes if self._step.hands_over else 0)
-        # the wait for the device and the logits' way to the host: the run
-        # above only dispatched the step
+        return _Flight(outs, rows, ids is not None)
+
+    def _land(self, flight, sp):
+        """Read a dispatched step's result and keep the slots' books: the
+        wait for the device, the ids' (or, from a predictor that gives
+        none, the logits') way to the host, then each row's token
+        delivered and what is done retired. A row that is no longer its
+        slot's (it ended on its eos id while this step was in flight) is
+        dropped, counted neither live nor generated."""
+        b, c = self._bucket
+        # the wait for the device: the run only dispatched the step
         with trace.span("decode.fetch") as fsp:
-            logits = np.asarray(outs[self._logits_idx])
+            by_id = self._ids_idx is not None
+            read = np.asarray(flight.outs[
+                self._ids_idx if by_id else self._logits_idx])
             if self._counter_idx is not None:
                 self.metrics_.observe_program_counters(
                     self._counter_names,
-                    np.asarray(outs[self._counter_idx]).ravel())
+                    np.asarray(flight.outs[self._counter_idx]).ravel())
             if fsp:
-                fsp.set(bytes=int(logits.nbytes))
+                fsp.set(bytes=int(read.nbytes))
         with trace.span("decode.sample") as ssp:
             now = self._clock()
             live = 0
             generated = 0
             retired = 0
-            for i, slot in enumerate(self._slots):
-                if slot is None:
+            for i, slot, p in flight.rows:
+                if self._slots[i] is not slot:
                     continue
                 live += 1
-                slot.pos += 1
+                slot.pos = p + 1
                 if slot.forcing:
                     slot.next_token = slot.req.prompt[slot.k]
                     slot.k += 1
                     continue
                 if not slot.harvested and slot.pos >= len(slot.req.prompt):
                     self._maybe_harvest(i, slot)
-                nxt = int(np.argmax(logits[i]))
+                nxt = int(read[i] if by_id else np.argmax(read[i]))
                 generated += 1
                 slot.out.append(nxt)
                 if slot.first_tok_t is None:
@@ -1414,12 +1599,20 @@ class DecodeBatcher:
                     retired += 1
                 else:
                     slot.next_token = nxt
-            self.metrics_.observe_decode_step(live, b, generated)
+            ahead = self._flight
+            if ahead is not None and not any(
+                    self._slots[i] is slot for i, slot, _p in ahead.rows):
+                # every row of the step ahead has ended meanwhile: nothing
+                # of it is anyone's, and no quantum would come to read it
+                self._flight = None
+            self.metrics_.observe_decode_step(live, b, generated,
+                                              ahead=flight.ahead)
             if ssp:
                 ssp.set(generated=generated, retired=retired)
         if sp:
             # slot occupancy rides on every step span (ISSUE 17)
-            sp.set(live=live, bucket=b, ctx=c, generated=generated)
+            sp.set(live=live, bucket=b, ctx=c, generated=generated,
+                   ahead=int(flight.ahead))
 
     def _retire(self, i, slot, now):
         """Finished sequence: resolve, free the slot IMMEDIATELY (the

@@ -46,6 +46,9 @@ _COUNTERS = (
     ("compile_cache_hits", "dispatches on an already-seen signature"),
     ("compile_cache_misses", "dispatches that compiled a new signature"),
     ("decode_steps", "continuous-batching decode loop passes"),
+    ("decode_steps_ahead_total", "decode steps dispatched before the step "
+                                 "before them was read, its ids their "
+                                 "tokens on the device"),
     ("decode_tokens", "tokens sampled by the decode loop"),
     ("slot_live", "occupied slots summed over decode steps"),
     ("slot_total", "total slots summed over decode steps"),
@@ -179,12 +182,15 @@ class ServingMetrics:
         executing work nobody is waiting for)."""
         self._c["deadline_refused"].inc(n)
 
-    def observe_decode_step(self, live, bucket, generated):
+    def observe_decode_step(self, live, bucket, generated, ahead=False):
         """One pass of the continuous-batching decode loop: ``live``
         occupied slots out of ``bucket`` (the padded slot-table size),
         ``generated`` tokens actually sampled this step (forced prompt
-        ingestion doesn't count)."""
+        ingestion doesn't count). ``ahead``: the step was dispatched
+        before the one before it was read."""
         self._c["decode_steps"].inc()
+        if ahead:
+            self._c["decode_steps_ahead_total"].inc()
         self._c["decode_tokens"].inc(generated)
         self._c["slot_live"].inc(live)
         self._c["slot_total"].inc(bucket)
@@ -301,6 +307,7 @@ class ServingMetrics:
             "compile_cache_hit_rate": (c["compile_cache_hits"] / lookups
                                        if lookups else None),
             "decode_steps": c["decode_steps"],
+            "decode_steps_ahead_total": c["decode_steps_ahead_total"],
             "decode_tokens": c["decode_tokens"],
             "slot_occupancy": (c["slot_live"] / c["slot_total"]
                                if c["slot_total"] else None),
@@ -355,7 +362,8 @@ class ServingMetrics:
                     "in_flight", "batches", "avg_batch_size",
                     "batch_occupancy", "compile_cache_hits",
                     "compile_cache_misses", "compile_cache_hit_rate",
-                    "decode_steps", "decode_tokens", "slot_occupancy",
+                    "decode_steps", "decode_steps_ahead_total",
+                    "decode_tokens", "slot_occupancy",
                     "prefix_hits", "prefix_tokens_reused",
                     "prefix_evictions", "prefix_bytes",
                     "cache_donated_bytes", "prefill_chunks",

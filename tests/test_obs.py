@@ -299,7 +299,7 @@ def test_registry_snapshot_consistency():
     m.observe_heartbeat_miss(4)
     m.observe_deadline_refused()
     m.observe_batch(actual=3, bucket=4, cache_hit=True)
-    m.observe_decode_step(live=2, bucket=4, generated=1)
+    m.observe_decode_step(live=2, bucket=4, generated=1, ahead=True)
     m.observe_prefix_hit(5)
     m.observe_prefix_eviction()
     m.observe_prefill_chunk(2, 9, 32, deferred=3)
@@ -319,7 +319,8 @@ def test_registry_snapshot_consistency():
                   "workers_respawned", "door_shed", "rerouted", "respawns",
                   "heartbeat_misses", "deadline_refused", "batches",
                   "compile_cache_hits", "compile_cache_misses",
-                  "decode_steps", "decode_tokens", "queue_depth",
+                  "decode_steps", "decode_steps_ahead_total",
+                  "decode_tokens", "queue_depth",
                   "in_flight", "prefix_hits", "prefix_tokens_reused",
                   "prefix_evictions", "prefix_bytes", "cache_donated_bytes",
                   "prefill_chunks", "prefill_tokens", "prefill_lanes",
@@ -350,7 +351,8 @@ def test_registry_snapshot_consistency():
         "respawns", "heartbeat_misses", "deadline_refused", "queue_depth",
         "in_flight", "batches", "batch_occupancy", "avg_batch_size",
         "compile_cache_hits", "compile_cache_misses",
-        "compile_cache_hit_rate", "decode_steps", "decode_tokens",
+        "compile_cache_hit_rate", "decode_steps",
+        "decode_steps_ahead_total", "decode_tokens",
         "slot_occupancy", "latency_s", "ttft_s", "tpot_s",
         "prefix_hits", "prefix_tokens_reused", "prefix_evictions",
         "prefix_bytes", "cache_donated_bytes", "prefill_chunks",
