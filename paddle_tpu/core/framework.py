@@ -38,6 +38,7 @@ __all__ = [
     "switch_startup_program",
     "program_guard",
     "name_scope",
+    "trace_scope",
     "convert_np_dtype",
     "grad_var_name",
     "in_dygraph_mode",
@@ -608,6 +609,21 @@ def program_guard(main_program, startup_program=None):
 
 
 _name_scope_stack = []
+
+
+@contextlib.contextmanager
+def trace_scope(name):
+    """The ops appended to the current main program's global block inside
+    this context run under ``jax.named_scope(name)``, round each op's own
+    scope of its type: a module of several ops then has one name in a
+    device trace (``mtp.block/routed_experts/...``)."""
+    block = default_main_program().global_block()
+    start = len(block.ops)
+    try:
+        yield
+    finally:
+        for op in block.ops[start:]:
+            op.attrs["_trace_scope"] = name
 
 
 @contextlib.contextmanager

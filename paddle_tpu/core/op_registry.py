@@ -10,6 +10,7 @@ op list is traced into a single XLA computation, so "kernel dispatch" and
 the reference's per-op kernel launch + ir fuse passes.
 """
 
+import contextlib
 import threading
 
 import jax
@@ -149,7 +150,10 @@ def run_op(env, op):
     if cond_name is not None:
         old = {n: env[n] for n in op.output_arg_names if n in env}
     try:
-        with jax.named_scope(op.type):
+        # ``framework.trace_scope``: a module of several ops under one name
+        scope = op.attrs.get("_trace_scope")
+        with jax.named_scope(scope) if scope else contextlib.nullcontext(), \
+                jax.named_scope(op.type):
             impl(env, op)
     except NotImplementedError:
         raise  # already names the op type

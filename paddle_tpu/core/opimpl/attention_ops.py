@@ -206,6 +206,34 @@ def _cached_attention(env, op):
         jnp.einsum("bhhd->bhd", mixed).reshape(b, hd))
 
 
+def _write_few(cache, x, pos):
+    """``cache[b, pos[b, j]] = x[b, j]`` one dynamic update a lane, a lane
+    past the cache writing back what is there. A scatter over [B, K] wants
+    the cache row-major; where a row's tail is no multiple of 128 (a latent
+    row of 576) the device keeps [B, C, tail] with the POSITIONS innermost,
+    and the compiler turned every cache round for the scatter and back for
+    the fetch, two copies of the whole cache a layer a step (2.4 GB a step
+    at 32 rows x 4096 x 576 x 8 layers). A dynamic update of one row
+    writes the cache where it lies."""
+    b, c = cache.shape[:2]
+    k = x.shape[1]
+    tail = (0,) * (cache.ndim - 2)
+    one = (1, 1) + cache.shape[2:]
+    x = x.astype(cache.dtype)
+
+    def row(i, cache):
+        for j in range(k):  # a row's lanes in their order, K is 1 or 2
+            p = pos[i, j]
+            at = jnp.minimum(p, c - 1)
+            new = jnp.where(
+                p < c, jax.lax.dynamic_slice(x, (i, j) + tail, one),
+                jax.lax.dynamic_slice(cache, (i, at) + tail, one))
+            cache = jax.lax.dynamic_update_slice(cache, new, (i, at) + tail)
+        return cache
+
+    return jax.lax.fori_loop(0, b, row, cache)
+
+
 @register("kv_cache_write_chunk")
 def _kv_cache_write_chunk(env, op):
     """K-row cache update (the chunked-prefill / speculative-verify
@@ -219,11 +247,19 @@ def _kv_cache_write_chunk(env, op):
     of a row's live lanes (consecutive positions from lane 0 on) only those
     land, at ``Pos % C``, that no later lane of the chunk overwrites
     (``cache_attention.ring_slots``): a scatter that names a slot twice
-    resolves in no defined order."""
+    resolves in no defined order. ``few`` (a step's one or two lanes): one
+    dynamic update a lane (:func:`_write_few`); a lane below 0 is not
+    given."""
     cache = get(env, op.input("Cache"))
     x = get(env, op.input("X"))
     pos = get(env, op.input("Pos")).astype(jnp.int32)
     b = cache.shape[0]
+    if op.attr("few", False):
+        # a STEP's write, so under the step write's scope, which the
+        # readers of a step's cache writes look for
+        with jax.named_scope("kv_cache_write"):
+            put(env, op.output("Out"), _write_few(cache, x, pos))
+        return
     if op.attr("ring", False):
         from ...ops import cache_attention
 
@@ -356,13 +392,80 @@ def _latent_attention_chunk(env, op):
     rows written, Pos [B, K]. The step op's numerics applied K times, a
     row's cache read in blocks up to its highest live position under a
     streaming softmax: neither [K, H, C] scores nor a [K, S, R+P] gather
-    exist. Out [B, K, H*V], 0 on a pad lane. Strictly per-row."""
+    exist. Without Mask (a model that has no indexer) a lane reads every
+    position up to its own. Out [B, K, H*V], 0 on a pad lane. Strictly
+    per-row."""
     from ...ops import sparse_latent
 
     put(env, op.output("Out"), sparse_latent.latent_attention_chunk(
         get(env, op.input("Q")), get(env, op.input("KvB")),
         get(env, op.input("Cache")), get(env, op.input("Mask")),
         get(env, op.input("Pos")), *_latent_attrs(op)))
+
+
+@register("latent_attention_dense")
+def _latent_attention_dense(env, op):
+    """A step's latent attention where nothing selects: Q [B, K, H*(N+P)]
+    one or two queries a row, KvB, Cache [B, C, R+P] with the step's rows
+    written, Pos [B, K]. Lane k reads every position ``<= Pos[b, k]`` of
+    its row's cache, scored whole under that mask: no index, no gather.
+    Out [B, K, H*V], 0 on a pad lane. Strictly per-row."""
+    from ...ops import sparse_latent
+
+    put(env, op.output("Out"), sparse_latent.latent_attention_dense(
+        get(env, op.input("Q")), get(env, op.input("KvB")),
+        get(env, op.input("Cache")), get(env, op.input("Pos")),
+        *_latent_attrs(op)))
+
+
+@register("last_live_lane")
+def _last_live_lane(env, op):
+    """X [B, K, D], Pos [B, K], Cache [B, C, ..]: Out [B, D], each row's
+    lane of highest position inside the cache (``Pos < C``; lane 0 of a row
+    of pad lanes alone). What a chunk program builds a head on where only a
+    row's last prompt lane needs one."""
+    x, pos = get(env, op.input("X")), get(env, op.input("Pos"))
+    c = get(env, op.input("Cache")).shape[1]
+    lane = jnp.argmax(jnp.where(pos < c, pos, -1), axis=1)
+    put(env, op.output("Out"),
+        jnp.take_along_axis(x, lane[:, None, None], axis=1)[:, 0])
+
+
+@register("self_draft_accept")
+def _self_draft_accept(env, op):
+    """The greedy accept rule of a step that verifies ONE draft a row,
+    inside the executable. Tok [B, 2]: the committed token and the draft of
+    the next; Greedy [B, 2] the model's own best token after each lane;
+    Draft [B, 2] the prediction module's best token after each lane, given
+    that lane's greedy token; Pos [B, 2] the lanes' positions in Cache
+    [B, C, ..] (a lane 1 past it is a pad lane: no draft was fed, and none
+    stands). The draft stands iff it IS the token the model puts after the
+    committed one; the row then yields both greedy tokens and drafts on
+    from lane 1, else lane 0's alone and from lane 0. Yield [B, 4] int32:
+    count (1 or 2), the two tokens, the next draft. Judged [2] int32: the
+    drafts fed to live rows, and those that stood. NextTok, NextPos [B, 2],
+    of Tok's and Pos's types: what the step to come is fed if every row
+    goes on, the last token that stands and the next draft at the positions
+    after those that stand (a row whose lane 0 is a pad lane stays one), so
+    that a loop may feed it before it has read this step."""
+    fed_tok, pos = get(env, op.input("Tok")), get(env, op.input("Pos"))
+    tok = fed_tok.astype(jnp.int32)
+    greedy = get(env, op.input("Greedy")).astype(jnp.int32)
+    draft = get(env, op.input("Draft")).astype(jnp.int32)
+    c = get(env, op.input("Cache")).shape[1]
+    stands = (pos[:, 1] < c) & (tok[:, 1] == greedy[:, 0])
+    count = 1 + stands.astype(jnp.int32)
+    after = jnp.where(stands, draft[:, 1], draft[:, 0])
+    put(env, op.output("Yield"), jnp.stack(
+        [count, greedy[:, 0], greedy[:, 1], after], axis=1))
+    put(env, op.output("Judged"), jnp.stack(
+        [jnp.sum(pos[:, 1] < c), jnp.sum(stands)]).astype(jnp.int32))
+    put(env, op.output("NextTok"), jnp.stack(
+        [jnp.where(stands, greedy[:, 1], greedy[:, 0]), after],
+        axis=1).astype(fed_tok.dtype))
+    moved = pos[:, :1] + count[:, None].astype(pos.dtype)
+    put(env, op.output("NextPos"), jnp.where(
+        pos[:, :1] < c, jnp.concatenate([moved, moved + 1], axis=1), pos))
 
 
 @register("sampling_id")

@@ -537,6 +537,24 @@ def _latent_attention_cost(ctx, op):
                        + lanes * heads * (width - r + nope + v_dim)) * e)
 
 
+@register_cost("latent_attention_dense")
+def _latent_attention_dense_cost(ctx, op):
+    got = _latent_cost(ctx, op, lambda qs: qs[0] * qs[1])
+    if got is None:
+        return
+    lanes, heads, nope, v_dim, b, cap, width, r, e = got
+    # every lane scores and mixes the whole capacity; the cache is read once
+    flops = 2.0 * lanes * heads * (r * (nope + v_dim) + cap * (width + r))
+    ctx.add(op, flops=flops,
+            hbm_bytes=(r * heads * (nope + v_dim) + b * cap * width
+                       + lanes * heads * (width - r + nope + v_dim)) * e)
+
+
+# a row's lane picked out of [B, K, D]; [B, 2] tokens judged and counted:
+# bookkeeping beside the products they stand between
+register_zero_cost("last_live_lane", "self_draft_accept")
+
+
 @register_cost("latent_attention_chunk")
 def _latent_attention_chunk_cost(ctx, op):
     got = _latent_cost(ctx, op, lambda qs: qs[0] * qs[1])

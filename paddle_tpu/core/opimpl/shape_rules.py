@@ -978,9 +978,47 @@ def _latent_attention_step_shape(ctx, op):
     _latent_attention_shape(ctx, op, 2)
 
 
+@register_shape("latent_attention_dense")
+def _latent_attention_dense_shape(ctx, op):
+    _latent_attention_shape(ctx, op, 3)
+
+
+@register_shape("last_live_lane")
+def _last_live_lane_shape(ctx, op):
+    xs, ps = ctx.shape(op.input("X")), ctx.shape(op.input("Pos"))
+    if xs is not None and len(xs) != 3:
+        raise ShapeError("last_live_lane X '%s' must be [B, K, D], got %s"
+                         % (op.input("X").name, list(xs)))
+    if ps is not None and len(ps) != 2:
+        raise ShapeError("last_live_lane Pos '%s' must be [B, K], got %s"
+                         % (op.input("Pos").name, list(ps)))
+    ctx.set(op.output("Out"),
+            None if xs is None else (xs[0], xs[2]), ctx.dtype(op.input("X")))
+
+
+@register_shape("self_draft_accept")
+def _self_draft_accept_shape(ctx, op):
+    rows = None
+    for name in ("Tok", "Greedy", "Draft"):
+        shape = ctx.shape(op.input(name))
+        if shape is None:
+            continue
+        if len(shape) != 2 or shape[1] not in (-1, 2):
+            raise ShapeError("self_draft_accept %s '%s' must be [B, 2], got "
+                             "%s" % (name, op.input(name).name, list(shape)))
+        rows = shape[0]
+    ctx.set(op.output("Yield"), None if rows is None else (rows, 4), "int32")
+    ctx.set(op.output("Judged"), (2,), "int32")
+    for out, like in (("NextTok", "Tok"), ("NextPos", "Pos")):
+        ctx.set(op.output(out), None if rows is None else (rows, 2),
+                ctx.dtype(op.input(like)))
+
+
 @register_shape("latent_attention_chunk")
 def _latent_attention_chunk_shape(ctx, op):
     _latent_attention_shape(ctx, op, 3)
+    if op.input("Mask") is None:
+        return
     ms, qs = ctx.shape(op.input("Mask")), ctx.shape(op.input("Q"))
     cs = ctx.shape(op.input("Cache"))
     if ms is not None and qs is not None and cs is not None \

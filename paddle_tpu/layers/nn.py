@@ -36,7 +36,8 @@ __all__ = [
     "cached_multi_head_attention", "kv_cache_write", "cached_attention",
     "optimization_barrier",
     "cached_multi_head_attention_chunk", "kv_cache_write_chunk",
-    "sparse_index", "latent_attention",
+    "sparse_index", "latent_attention", "last_live_lane",
+    "self_draft_accept",
     "row_conv", "autoincreased_step_counter", "cos_sim",
     "split", "warpctc", "nce", "hsigmoid", "cumsum",
     "linear_chain_crf", "crf_decoding",
@@ -2207,7 +2208,7 @@ def cached_multi_head_attention(x, cache_k, cache_v, pos, d_model=None,
 
 
 def kv_cache_write_chunk(cache, x, pos, ring=False, pad_pos=None,
-                         name=None):
+                         few=False, name=None):
     """K-row cache update: ``cache[b, pos[b, j]] = x[b, j]`` (see
     ``core/opimpl/attention_ops.py``). ``cache``: [B, C, ...] of any tail
     (as :func:`kv_cache_write`), ``x``: [B, K, ...], ``pos``: [B, K] int.
@@ -2215,12 +2216,18 @@ def kv_cache_write_chunk(cache, x, pos, ring=False, pad_pos=None,
     ``ring``: the cache is a ring of C positions; a lane whose position is
     ``pad_pos`` or more is a pad lane (taken modulo C it would land on a
     live slot, so the program is told what the scheduler pads with), and of
-    a row's live lanes only the last C land, at ``pos % C``. Returns the
-    updated cache."""
+    a row's live lanes only the last C land, at ``pos % C``. ``few``: K is
+    a step's one or two lanes, and each is written by a dynamic update of
+    its one row, which writes the cache as it lies on the device whatever
+    its layout (not with ``ring``). Returns the updated cache."""
     helper = LayerHelper("kv_cache_write_chunk", name=name)
     out = helper.create_variable_for_type_inference(
         dtype=_dtype(cache), shape=cache.shape)
     attrs = {}
+    if few:
+        if ring:
+            raise ValueError("a ring's chunk write is one scatter")
+        attrs = {"few": True}
     if ring:
         if pad_pos is None:
             raise ValueError("a ring's chunk write needs pad_pos, the "
@@ -2319,7 +2326,7 @@ def sparse_index(q, w, cache_k, pos, num_heads, top_k, name=None):
 
 def latent_attention(q, cache, selected, pos, num_heads, kv_lora_rank,
                      qk_nope_head_dim, v_head_dim, scale, param_attr=None,
-                     name=None):
+                     dense=False, name=None):
     """Latent (MLA) attention over the set :func:`sparse_index` selected,
     in the absorbed form (ops ``latent_attention`` /
     ``latent_attention_chunk``): ``q`` heads of ``[q_nope | q_pe]`` with
@@ -2329,11 +2336,19 @@ def latent_attention(q, cache, selected, pos, num_heads, kv_lora_rank,
     whose two parts a head take the query into the latent and the mixed
     latent out to V. Step: q [B, H*(N+P)], ``selected`` [B, S] positions;
     chunk: q [B, K, H*(N+P)], ``selected`` [B, K, C] mask, ``pos`` [B, K].
-    Returns [.., H*V]."""
+    ``selected`` None (a model that has no indexer; q [B, K, H*(N+P)],
+    ``pos`` [B, K]): lane k reads every position up to ``pos[b, k]``, a
+    chunk's lanes in blocks under a streaming softmax, and with ``dense``
+    (a step's one or two lanes) against the whole cache under the mask
+    (op ``latent_attention_dense``). Returns [.., H*V]."""
     chunk = len(q.shape) == 3
-    helper = LayerHelper("latent_attention_chunk" if chunk
-                         else "latent_attention", param_attr=param_attr,
-                         name=name)
+    if selected is None and not chunk:
+        raise ValueError("latent attention without a selection takes q "
+                         "[B, K, H*(N+P)] and pos [B, K]")
+    helper = LayerHelper(
+        "latent_attention_dense" if selected is None and dense
+        else "latent_attention_chunk" if chunk else "latent_attention",
+        param_attr=param_attr, name=name)
     kv_b = helper.create_parameter(
         helper.param_attr,
         shape=[int(kv_lora_rank),
@@ -2344,7 +2359,9 @@ def latent_attention(q, cache, selected, pos, num_heads, kv_lora_rank,
         shape=tuple(q.shape[:-1]) + (int(num_heads) * int(v_head_dim),))
     inputs = {"Q": q, "KvB": kv_b, "Cache": cache}
     if chunk:
-        inputs.update(Mask=selected, Pos=pos)
+        inputs["Pos"] = pos
+        if selected is not None:
+            inputs["Mask"] = selected
     else:
         inputs["Index"] = selected
     helper.append_op(helper.layer_type, inputs, {"Out": out},
@@ -2352,3 +2369,49 @@ def latent_attention(q, cache, selected, pos, num_heads, kv_lora_rank,
                       "nope_dim": int(qk_nope_head_dim),
                       "v_dim": int(v_head_dim), "scale": float(scale)})
     return out
+
+
+def last_live_lane(x, pos, cache, name=None):
+    """``x`` [B, K, D] -> [B, D]: each row's lane of highest position inside
+    ``cache`` [B, C, ..] (``pos`` [B, K] < C; lane 0 of a row of pad lanes
+    alone). What a chunk program builds a head on where only a row's last
+    prompt lane needs one."""
+    helper = LayerHelper("last_live_lane", name=name)
+    out = helper.create_variable_for_type_inference(
+        dtype=_dtype(x), shape=(x.shape[0], x.shape[2]))
+    helper.append_op("last_live_lane", {"X": x, "Pos": pos, "Cache": cache},
+                     {"Out": out}, {})
+    return out
+
+
+def self_draft_accept(tok, greedy, draft, pos, cache, name=None):
+    """The greedy accept rule of a step that verifies one draft a row,
+    inside the executable (op ``self_draft_accept``): ``tok`` [B, 2] the
+    committed token and the draft of the next, ``greedy`` [B, 2] the model's
+    best token after each lane, ``draft`` [B, 2] the prediction module's
+    after each lane, ``pos`` [B, 2] the lanes' positions in ``cache``
+    [B, C, ..] (a lane past it is a pad lane: no draft was fed). Returns
+    ``(yield, judged, next_tok, next_pos)``: [B, 4] int32, how many tokens
+    the row yields (2 iff a draft was fed and is the model's own token after
+    the committed one), the two tokens, and the draft of the token after
+    those that stand; [2] int32, the drafts judged and those that stood;
+    and [B, 2] each, ``tok`` and ``pos`` of the step to come if every row
+    goes on (for a loop that feeds it before it has read this one)."""
+    helper = LayerHelper("self_draft_accept", name=name)
+    rows = tok.shape[0]
+    out = helper.create_variable_for_type_inference(dtype="int32",
+                                                    shape=(rows, 4))
+    judged = helper.create_variable_for_type_inference(dtype="int32",
+                                                       shape=(2,))
+    next_tok = helper.create_variable_for_type_inference(
+        dtype=_dtype(tok), shape=(rows, 2))
+    next_pos = helper.create_variable_for_type_inference(
+        dtype=_dtype(pos), shape=(rows, 2))
+    for var in (out, judged, next_tok, next_pos):
+        var.stop_gradient = True
+    helper.append_op("self_draft_accept",
+                     {"Tok": tok, "Greedy": greedy, "Draft": draft,
+                      "Pos": pos, "Cache": cache},
+                     {"Yield": out, "Judged": judged, "NextTok": next_tok,
+                      "NextPos": next_pos}, {})
+    return out, judged, next_tok, next_pos

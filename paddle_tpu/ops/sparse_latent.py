@@ -34,13 +34,19 @@ Both chunk forms walk a row's cache in blocks under a DYNAMIC trip count
 that ends at the row's highest live position: a row none of whose lanes
 ingests (every ``pos`` past the cache) costs nothing but its projections,
 and a row early in its prompt reads only what is cached.
+
+**Without an indexer** (a model whose attention reads the whole latent
+cache) nothing selects: :func:`latent_attention_chunk` with no mask lets a
+lane read every position up to its own, and a step is
+:func:`latent_attention_dense`, a few queries a row against the row's whole
+cache under that same causal rule, no index and no gather.
 """
 
 import jax
 import jax.numpy as jnp
 
 __all__ = ["sparse_index", "sparse_index_chunk", "latent_attention",
-           "latent_attention_chunk", "select_top"]
+           "latent_attention_chunk", "latent_attention_dense", "select_top"]
 
 INDEX_BLOCK = 1024   # cache positions an indexer block scores at a time
 ATTN_BLOCK = 512     # cache positions an attention block reads at a time
@@ -212,11 +218,60 @@ def latent_attention(q, kv_b, cache, index, heads, nope, v_dim, scale):
     return out.reshape(b, heads * v_dim).astype(q.dtype)
 
 
+def latent_attention_dense(q, kv_b, cache, pos, heads, nope, v_dim, scale):
+    """A step's one or two queries a row over EVERY live position of the
+    row's cache: no index, no gather. q [B, K, H*(N+P)] (rotary applied),
+    kv_b [R, H*(N+V)], cache [B, C, R+P] (the step's rows already written),
+    pos [B, K]: lane k reads the positions ``<= pos[b, k]`` (``>= C``: a pad
+    lane; its output is 0). Returns [B, K, H*V] in q's dtype. The whole
+    capacity is scored and masked, so K stays small: a chunk's lanes go
+    through :func:`latent_attention_chunk`.
+
+    The cache is read AS IT IS STORED, ``[C, R+P]`` a row: the scores are
+    the plain product of a row's cache with its K x H absorbed queries laid
+    side by side (``[R+P, K*H]``), the mix the plain product of the
+    probabilities ``[K*H, C]`` with the cache, of which the latent's R
+    columns are kept. Written with the queries first
+    (``bkhw,bsw->bkhs``), the compiler turned every cache round so that
+    the positions lie innermost, and back for the next step's write: 2.4
+    GB of copies a step at 32 rows x 4096 (``cached_attention`` reads its
+    caches so for the same reason)."""
+    b, c, width = cache.shape
+    kq = q.shape[1]
+    r = kv_b.shape[0]
+    w_uk, w_uv = _split_kv_b(kv_b, heads, nope, v_dim)
+    pos = pos.astype(jnp.int32)
+    # under the scope of the step form's op, so that what reads a step's
+    # ``latent_attention`` from a device trace finds this one too
+    with jax.named_scope("latent_attention"):
+        with jax.named_scope("latent_attention.absorb"):
+            qa = _absorb(q.reshape(b, kq, heads, -1), w_uk, nope)
+            side = qa.reshape(b, kq * heads, width).transpose(0, 2, 1)
+        with jax.named_scope("latent_attention.core"):
+            s = jnp.einsum("bsw,bwm->bms", cache, side,
+                           preferred_element_type=_F32) * scale
+            reach = jnp.where(pos < c, pos, -1)   # a pad lane reaches nothing
+            member = jnp.repeat(
+                jnp.arange(c, dtype=jnp.int32)[None, None, :]
+                <= reach[:, :, None], heads, axis=1)         # [B, K*H, C]
+            s = jnp.where(member, s, jnp.finfo(_F32).min)
+            probs = jnp.where(member, jax.nn.softmax(s, axis=-1),
+                              0.0).astype(q.dtype)
+            mixed = jnp.einsum("bms,bsw->bmw", probs, cache,
+                               preferred_element_type=_F32)[..., :r]
+            mixed = mixed.reshape(b, kq, heads, r).astype(q.dtype)
+        with jax.named_scope("latent_attention.expand"):
+            out = jnp.einsum("bkhr,rhv->bkhv", mixed, w_uv,
+                             preferred_element_type=_F32)
+    return out.reshape(b, kq, heads * v_dim).astype(q.dtype)
+
+
 def latent_attention_chunk(q, kv_b, cache, mask, pos, heads, nope, v_dim,
                            scale):
     """K queries a row under a membership mask. q [B, K, H*(N+P)], kv_b
     [R, H*(N+V)], cache [B, C, R+P] (the chunk's rows already written), mask
-    [B, K, C] bool, pos [B, K] (``>= C``: a pad lane; its output is 0).
+    [B, K, C] bool, or None where nothing selects: a lane then reads every
+    position up to its own; pos [B, K] (``>= C``: a pad lane; its output is 0).
     Returns [B, K, H*V] in q's dtype. A row's cache is read in blocks of
     ``ATTN_BLOCK`` positions up to its highest live one, with a running
     maximum and sum (the streaming softmax), so no [K, H, C] scores
@@ -228,6 +283,7 @@ def latent_attention_chunk(q, kv_b, cache, mask, pos, heads, nope, v_dim,
     pos = pos.astype(jnp.int32)
     size = _block(c, ATTN_BLOCK)
     low = jnp.finfo(_F32).min
+    at = jnp.arange(size, dtype=jnp.int32)
 
     def row(bi, out):
         p = jax.lax.dynamic_index_in_dim(pos, bi, 0, keepdims=False)
@@ -235,13 +291,18 @@ def latent_attention_chunk(q, kv_b, cache, mask, pos, heads, nope, v_dim,
             .reshape(kq, heads, -1)
         with jax.named_scope("latent_attention.absorb"):
             qa = _absorb(qh, w_uk, nope)                     # [K, H, R+P]
+        reach = jnp.where(p < c, p, -1)     # a pad lane reaches nothing
 
         def block(j, carry):
             top, total, acc = carry
             rows = jax.lax.dynamic_slice(cache, (bi, j * size, 0),
                                          (1, size, width))[0]
-            member = jax.lax.dynamic_slice(mask, (bi, 0, j * size),
-                                           (1, kq, size))[0][:, None, :]
+            if mask is None:
+                member = ((j * size + at)[None, :]
+                          <= reach[:, None])[:, None, :]
+            else:
+                member = jax.lax.dynamic_slice(
+                    mask, (bi, 0, j * size), (1, kq, size))[0][:, None, :]
             s = jnp.einsum("khw,sw->khs", qa, rows,
                            preferred_element_type=_F32) * scale
             s = jnp.where(member, s, low)

@@ -45,7 +45,7 @@ design the reference, whose serving story ends at
     everyone else.
 
 Three optional fast paths ride on top (ISSUE 20), all off unless
-configured:
+configured, and a fourth that a decode spec turns on:
 
   * **prefix cache** (``prefix_cache=``): a hash-trie over token-id
     prefixes (``prefix_cache.PrefixCache``) maps shared prompt prefixes
@@ -93,6 +93,36 @@ configured:
     target model's greedy chain: output is identical to plain decode
     (pinned bitwise on CPU by ``tests/test_serving.py``), regardless of
     how bad the draft is — draft quality only moves throughput.
+
+  * **a step program that drafts for itself** (no option: the decode spec
+    states it, ``spec["self_draft"]``): the step takes two lanes a row, the
+    committed token and the draft of the next at positions ``p``, ``p +
+    1``, runs the target model over both, chooses the greedy token after
+    each and judges the draft INSIDE the executable, runs its own proposer
+    (a multi-token-prediction module with a cache of its own among the
+    spec's ``cache_feeds``) over the lanes, and returns ``[b, 4]`` int32 a
+    row: how many tokens it yields (1 or 2), the two tokens, the next
+    draft. The loop advances a row by what came back; ``max_new`` and an
+    eos id cut a pair short; ``decode_tokens`` counts tokens delivered,
+    never lanes. The chunk program ingests prompts as ever, the proposer's
+    layer with them (its token feed is ``[rows, k + 1]``: a lane's own
+    token and the one after it), and fetches the draft a row's first step
+    verifies; a row that still ingests rides no step (a forced lane would
+    write the proposer's cache with a guess). A lane that did not stand
+    leaves a row in the caches at the position the row's next step writes
+    first: the property speculation's rewind rests on. ONE accept rule,
+    :func:`greedy_chain`, serves this and ``speculative=``, so here too the
+    output is the plain greedy chain whatever is drafted. A spec with a
+    ring refuses it as it refuses ``speculative=``, and a spec that drafts
+    refuses a second proposer. Where the spec names ``next_token_fetch`` /
+    ``next_pos_fetch`` (the step to come's two feeds, made on the device)
+    the loop runs one step ahead as below; the one more thing it learns
+    late is a yield of two: a row with two tokens of room rides the step
+    ahead where nobody waits for its slot (its lane there is dropped if it
+    ended), and is read first where somebody does; and a run's end is
+    settled one step early on the same terms as below, a row counted as
+    near its end while it MAY be (by the tokens its unread steps may yet
+    yield), so that no quantum is left a read alone.
 
 **Exact-parity guarantee.** Every op in a step program is strictly
 per-row (``cached_attention`` masks each row to its own fill level;
@@ -452,7 +482,7 @@ class _Slot:
     """One occupied slot-table row."""
 
     __slots__ = ("req", "pos", "k", "out", "next_token", "first_tok_t",
-                 "harvested")
+                 "harvested", "draft")
 
     def __init__(self, req):
         self.req = req
@@ -467,6 +497,10 @@ class _Slot:
         self.next_token = req.prompt[m]
         self.first_tok_t = None
         self.harvested = False  # this prompt's rows offered to the cache
+        # a self-drafting loop: the draft of the token AFTER ``next_token``,
+        # an int, or ``(array on the device, row)`` not read yet (a chunk
+        # run's), or None where nothing has drafted it
+        self.draft = None
 
     @property
     def forcing(self):
@@ -478,7 +512,30 @@ class _Slot:
 # arrays in fetch order), the rows that rode it as live lanes, each ``(slot
 # row, its _Slot, the position it was fed at)``, and whether it was
 # dispatched ``ahead`` of the read of the step before.
-_Flight = namedtuple("_Flight", "outs rows ahead")
+# ``drafts``: in a self-drafting loop, {slot row: the draft its lane 1 was fed,
+# or None where the lane was a pad lane}.
+_Flight = namedtuple("_Flight", "outs rows ahead drafts", defaults=(None,))
+
+
+def greedy_chain(fed, forced, greedy, room, eos_id):
+    """THE accept rule, of ``speculative=`` (a proposer over histories, the
+    chunk program the verifier) and of a spec's ``self_draft`` (a proposer
+    inside the step) alike. ``fed``: the tokens of a row's lanes, the first
+    ``forced`` committed and the rest drafts; ``greedy[j]``: the model's own
+    best token after lane j. Returns the tokens the row emits: the model's
+    token after the last committed lane, then after each draft for as long
+    as that draft IS the token just emitted, at most ``room`` of them and
+    none past ``eos_id``. So what is emitted is the model's own greedy
+    chain whatever was drafted; a draft only decides how far one run gets
+    along it."""
+    j = forced - 1
+    emitted = [int(greedy[j])]
+    while (j + 1 < len(fed) and len(emitted) < room
+           and (eos_id is None or emitted[-1] != eos_id)
+           and int(fed[j + 1]) == emitted[-1]):
+        j += 1
+        emitted.append(int(greedy[j]))
+    return emitted
 
 
 class DecodeBatcher:
@@ -513,7 +570,32 @@ class DecodeBatcher:
         self._tok_feed = self._spec["token_feed"]
         self._pos_feed = self._spec["pos_feed"]
         fetch_names = list(predictor.fetch_names)
-        self._logits_idx = fetch_names.index(self._spec["logits_fetch"])
+        # a spec may state that its step program drafts for itself
+        # (``self_draft``): two lanes a row, the committed token and the
+        # draft of the next; the greedy tokens, the accept rule and the next
+        # draft inside the executable; ONE small fetch, ``yield_fetch``
+        # [b, 4] int32 (tokens yielded, the two tokens, the next draft). Such
+        # a program hands no logits over. The loop turns it on from the
+        # spec alone
+        self._self = None
+        stated = self._spec.get("self_draft")
+        if stated:
+            if int(stated.get("lanes", 2)) != 2:
+                raise ValueError("self_draft with %r lanes: the loop "
+                                 "verifies one draft a row" % stated["lanes"])
+            self._self = {"yield_idx": fetch_names.index(
+                stated["yield_fetch"])}
+            # where the program also makes the step to come's two feeds
+            # (each row's last token that stands with the next draft, and
+            # the positions after those that stand), the loop can feed them
+            # unread and stay one step ahead
+            if stated.get("next_token_fetch") and stated.get(
+                    "next_pos_fetch"):
+                self._self["carried"] = (
+                    fetch_names.index(stated["next_token_fetch"]),
+                    fetch_names.index(stated["next_pos_fetch"]))
+        self._logits_idx = (None if self._self else fetch_names.index(
+            self._spec["logits_fetch"]))
         # optional: ONE small int vector the step program counts of itself
         # (``counter_fetch``), its entries named by ``counters``; read
         # after a step's logits and added to the engine's metrics
@@ -546,10 +628,17 @@ class DecodeBatcher:
         # a step so), is served from its logits
         self._ids_idx = None
         ask = getattr(predictor, "fetch_argmax", None)
-        if ask is not None and self._step.hands_over:
+        if ask is not None and self._step.hands_over and not self._self:
             ids_fetch = ask(self._spec["logits_fetch"])
             self._ids_idx = list(predictor.fetch_names).index(ids_fetch)
         self._flight = None  # the step dispatched and not read yet
+        # a self-drafting loop, where somebody set a list here: every
+        # verifying step appends ``(request, position of lane 0, the draft
+        # lane 1 was fed or None, the step's [4] row)``, so that the drafts
+        # the steps made can be held against a reference after the run
+        # (``benchmark/serve_drafts.py``); the served tokens do not depend
+        # on them, so nothing else can see them
+        self.draft_log = None
         self.ladder = tuple(sorted(set(
             ladder if ladder is not None else pow2_ladder(max_batch_size))))
         if ctx_ladder is None:
@@ -641,6 +730,31 @@ class DecodeBatcher:
                 "logits_idx": (cfetch.index(cspec["logits_fetch"])
                                if cspec.get("logits_fetch") else None),
                 "cache_map": cmap}
+            drafts = cspec.get("self_draft")
+            if self._self is not None:
+                if not drafts:
+                    raise ValueError(
+                        "the step program drafts for itself and the chunk "
+                        "program's spec states no self_draft: the module's "
+                        "cache would not hold the prompt")
+                self._self["chunk_draft_idx"] = cfetch.index(
+                    drafts["draft_fetch"])
+                # token lanes its feed holds past the rung: each lane's
+                # own token and, for the proposer, the one after it
+                self._self["chunk_extra"] = int(
+                    drafts.get("next_token_lane", 0))
+        if self._self is not None:
+            self._refuse_with_rings(
+                "self_draft", "a draft that does not stand leaves a row in "
+                "a ring that cannot be rewound")
+            if self._prefill is None:
+                raise ValueError("a self-drafting step program needs the "
+                                 "chunk program (pass prefill= as well): "
+                                 "prompts are ingested through it, the "
+                                 "module's layer with them")
+            if speculative is not None:
+                raise ValueError("speculative= with a decode spec that "
+                                 "states self_draft: one proposer a loop")
         self._alt_chunk = False
         self._ahead = {}  # signature -> the thread staging its executable
         self._rows_staged = {}  # chunk signature -> its two copies, compiled
@@ -911,7 +1025,8 @@ class DecodeBatcher:
         flight, self._flight = self._flight, None
         if flight is not None:
             try:
-                np.asarray(flight.outs[self._ids_idx])
+                np.asarray(flight.outs[self._self["yield_idx"] if self._self
+                                       else self._ids_idx])
             except Exception:  # noqa: BLE001: its rows have failed already
                 pass
         self._slots = []
@@ -1073,8 +1188,9 @@ class DecodeBatcher:
         self._release_prefix(req)
 
     def _synth_feed(self, b):
-        return {self._tok_feed: np.zeros((b,), np.int64),
-                self._pos_feed: np.zeros((b,), np.int32)}
+        lanes = (b, 2) if self._self else (b,)
+        return {self._tok_feed: np.zeros(lanes, np.int64),
+                self._pos_feed: np.zeros(lanes, np.int32)}
 
     def _synth_caches(self, b, c):
         return {name: np.zeros((b, cap or c) + tail, dtype)
@@ -1306,18 +1422,23 @@ class DecodeBatcher:
         self._await_staged(sig)
         copies = self._rows_copies(sig)
         with trace.span("decode.feed"):
-            tok = np.zeros((r, k), np.int64)
+            tok = np.zeros((r, k + self._chunk_extra), np.int64)
             cpos = np.full((r, k), self._pad(c), np.int32)
             # sub-row j holds table row at[j]; the pads' lies past the
             # table, and neither copy's loop reaches them
             at = np.full((r,), b, np.int32)
             start = np.zeros((r,), np.int32)
             ordered = sorted(rows, key=lambda row: row[0])
+            sub_of = {}
             for j, (i, slot, tokens, _f) in enumerate(ordered):
-                sub = i if copies is None else j
+                sub = sub_of[i] = i if copies is None else j
                 n = len(tokens)
                 at[sub], start[sub] = i, slot.pos
                 tok[sub, :n] = tokens
+                if self._chunk_extra:
+                    # the token after the row's last lane: a chunk stops
+                    # short of the prompt's last token, so there is one
+                    tok[sub, n] = slot.req.prompt[slot.pos + n]
                 cpos[sub, :n] = np.arange(slot.pos, slot.pos + n,
                                           dtype=np.int32)
             table, caches, held = None, self._caches, np.int32(len(rows))
@@ -1361,6 +1482,11 @@ class DecodeBatcher:
                 slot.pos = base + real
                 slot.k = slot.pos + 1
                 slot.next_token = slot.req.prompt[slot.pos]
+                if self._self and slot.pos == L - 1:
+                    # the module's head on this row's last lane: the draft
+                    # its first step verifies, read when that step is fed
+                    slot.draft = (outs[self._self["chunk_draft_idx"]],
+                                  sub_of[i])
                 continue
             # the chunk covered through the last prompt token (spec
             # prefill) or this is a verify row: emit the greedy chain
@@ -1372,14 +1498,9 @@ class DecodeBatcher:
                         fsp.set(bytes=int(logits.nbytes))
                 greedy = np.argmax(logits, axis=-1)
             req = slot.req
-            j = n_forced - 1
-            emitted = [int(greedy[i, j])]
-            while (j + 1 < real
-                   and len(slot.out) + len(emitted) < req.max_new
-                   and (req.eos_id is None or emitted[-1] != req.eos_id)
-                   and tokens[j + 1] == emitted[-1]):
-                j += 1
-                emitted.append(int(greedy[i, j]))
+            emitted = greedy_chain(tokens, n_forced, greedy[i],
+                                   req.max_new - len(slot.out), req.eos_id)
+            j = n_forced - 2 + len(emitted)
             if n_forced < real:
                 accepted += j - (n_forced - 1)
                 rejected += (real - n_forced) - (j - (n_forced - 1))
@@ -1431,8 +1552,15 @@ class DecodeBatcher:
         """A chunk feed of pad lanes only, at the rung's own height."""
         pf = self._prefill
         r = self._chunk_height(b, k)
-        return {pf["tok"]: np.zeros((r, k), np.int64),
+        return {pf["tok"]: np.zeros((r, k + self._chunk_extra), np.int64),
                 pf["pos"]: np.full((r, k), self._pad(c), np.int32)}
+
+    @property
+    def _chunk_extra(self):
+        """Token lanes a chunk feed holds past its rung: one where the
+        programs draft for themselves (lane j's own token and, for the
+        prediction module, lane j + 1's)."""
+        return self._self["chunk_extra"] if self._self else 0
 
     def _pad(self, c):
         """The position a pad lane carries in a bucket of context ``c``."""
@@ -1462,9 +1590,12 @@ class DecodeBatcher:
         with a budget) dispatches nothing ahead."""
         self._await_staged(self._bucket)
         flight, self._flight = self._flight, None
+        # a self-drafting step takes no forced prompt token: a row that
+        # still ingests waits for its chunk (the module's cache holds what
+        # the chunk program wrote, and a forced lane would write another)
         rows = flight.rows if flight is not None else [
             (i, slot, slot.pos) for i, slot in enumerate(self._slots)
-            if slot is not None]
+            if slot is not None and not (self._self and slot.forcing)]
         ahead = None if last else self._rows_after(rows, 1)
         ends = ahead is not None and self._rows_after(ahead, 2) is None
         with (trace.span("decode.step") if ends
@@ -1474,7 +1605,9 @@ class DecodeBatcher:
                     flight = self._dispatch(rows)
                 if ahead is not None:
                     self._flight = self._dispatch(
-                        ahead, flight.outs[self._ids_idx])
+                        ahead, tuple(flight.outs[i]
+                                     for i in self._self["carried"])
+                        if self._self else flight.outs[self._ids_idx])
                 self._land(flight, sp)
             if ends:
                 flight, self._flight = self._flight, None
@@ -1495,7 +1628,11 @@ class DecodeBatcher:
         dropped (:meth:`_land`); its cache write lands at its own row's
         next position (modulo its own row's ring), which a later occupant
         of the slot writes before its attention reaches it."""
-        if self._ids_idx is None or self._spec_k:
+        if self._self:
+            if "carried" not in self._self or any(
+                    s is not None and s.forcing for s in self._slots):
+                return None  # the host's to feed; or a chunk comes next
+        elif self._ids_idx is None or self._spec_k:
             return None  # the next tokens are the host's to make
         harvests = self.prefix_cache is not None
         after = []
@@ -1506,6 +1643,24 @@ class DecodeBatcher:
                 # its next token is the prompt's, not the device's; or the
                 # prefix cache is to read rows the step ahead is handed
                 return None
+            if self._self:
+                # a verifying step yields one token or two, and which the
+                # host learns a step late: of the ``k`` steps not read yet
+                # the row has at least ``most`` tokens of room left before
+                # the last of them and at most ``least`` (all it has, for
+                # k = 1). With one token of room it ends there for certain;
+                # with two it ends iff its draft stands (it then rides one
+                # step for nothing, dropped when read, unless somebody
+                # waits for its slot: then read first); and where it will
+                # stand is the device's to carry
+                room = slot.req.max_new - len(slot.out)
+                most, least = room - (k - 1), room - 2 * (k - 1)
+                if most <= 1:
+                    continue
+                if least <= 2 and self._pending:
+                    return None
+                after.append((i, slot, None))
+                continue
             if len(slot.out) + k < slot.req.max_new:
                 after.append((i, slot, p + 1))
         if not after:
@@ -1526,6 +1681,8 @@ class DecodeBatcher:
         before, unread and on the device), that array is the token feed
         itself, of the shape and type the executable was made for."""
         b, c = self._bucket
+        if self._self:
+            return self._dispatch_drafting(rows, ids)
         with trace.span("decode.feed"):
             pos = np.zeros((b,), np.int32)
             toks = ids
@@ -1558,6 +1715,9 @@ class DecodeBatcher:
         slot's (it ended on its eos id while this step was in flight) is
         dropped, counted neither live nor generated."""
         b, c = self._bucket
+        if self._self:
+            self._land_drafting(flight, sp)
+            return
         # the wait for the device: the run only dispatched the step
         with trace.span("decode.fetch") as fsp:
             by_id = self._ids_idx is not None
@@ -1613,6 +1773,122 @@ class DecodeBatcher:
             # slot occupancy rides on every step span (ISSUE 17)
             sp.set(live=live, bucket=b, ctx=c, generated=generated,
                    ahead=int(flight.ahead))
+
+    def _dispatch_drafting(self, rows, carried=None):
+        """Dispatch one verifying step over ``rows``: lane 0 of a row the
+        token the host holds for it at its position, lane 1 the draft of
+        the next one position on. A row nobody has drafted for, or with room
+        for one token more, rides with lane 1 a pad lane (and so does every
+        lane of a row that does not ride): it writes nothing, and no draft
+        is judged. With ``carried`` (the two feeds the step before made for
+        this one, unread and on the device) those are the feeds themselves:
+        every row that rode that step rides this one where it will stand,
+        its draft with it, and what has ended meanwhile is dropped when this
+        step is read."""
+        b, c = self._bucket
+        with trace.span("decode.feed"):
+            drafts = None
+            if carried is not None:
+                toks, pos = carried
+            else:
+                toks = np.zeros((b, 2), np.int64)
+                pos = np.full((b, 2), self._pad(c), np.int32)
+                drafts = {}
+                for i, slot, p in rows:
+                    toks[i, 0], pos[i, 0] = slot.next_token, p
+                    draft = slot.draft
+                    if isinstance(draft, tuple):    # a chunk run's, unread
+                        draft = int(np.asarray(draft[0])[draft[1]])
+                    if slot.req.max_new - len(slot.out) < 2:
+                        draft = None
+                    drafts[i] = draft
+                    if draft is not None:
+                        toks[i, 1], pos[i, 1] = draft, p + 1
+            feed = {self._tok_feed: toks, self._pos_feed: pos,
+                    **self._caches}
+        outs = self._step.run(feed)
+        self.seen_signatures.add((b, c))
+        self._caches = {name: outs[idx]
+                        for name, idx, *_ in self._cache_feeds}
+        self.metrics_.observe_cache_donated(
+            self._cache_bytes if self._step.hands_over else 0)
+        return _Flight(outs, rows, carried is not None, drafts)
+
+    def _land_drafting(self, flight, sp):
+        """Read a verifying step's ``[b, 4]`` (tokens yielded, the two
+        tokens, the next draft) and keep the books: each row emits what
+        :func:`greedy_chain` gives of its two lanes, one token or two, and
+        moves on by as many positions; a lane that did not stand left a
+        row in the caches at the position the row's next step writes first.
+        ``decode_tokens`` counts the tokens delivered, never the lanes."""
+        b, c = self._bucket
+        with trace.span("decode.fetch") as fsp:
+            read = np.asarray(flight.outs[self._self["yield_idx"]])
+            if self._counter_idx is not None:
+                self.metrics_.observe_program_counters(
+                    self._counter_names,
+                    np.asarray(flight.outs[self._counter_idx]).ravel())
+            if fsp:
+                fsp.set(bytes=int(read.nbytes))
+        with trace.span("decode.sample") as ssp:
+            now = self._clock()
+            live = generated = retired = drafted = accepted = 0
+            for i, slot, p in flight.rows:
+                if self._slots[i] is not slot:
+                    continue  # it ended while this step was in flight
+                live += 1
+                req = slot.req
+                if flight.drafts is None:
+                    # fed by the step before: where the row stood when that
+                    # one was read, and the draft it made
+                    p, draft = slot.pos, slot.draft
+                else:
+                    draft = flight.drafts[i]
+                if not slot.harvested and p + 1 >= len(req.prompt):
+                    slot.pos = p + 1
+                    self._maybe_harvest(i, slot)
+                if self.draft_log is not None:
+                    self.draft_log.append((req, p, draft, tuple(read[i])))
+                fed = [slot.next_token] + ([] if draft is None else [draft])
+                emitted = greedy_chain(fed, 1, read[i, 1:3],
+                                       req.max_new - len(slot.out),
+                                       req.eos_id)
+                if draft is not None:
+                    drafted += 1
+                    accepted += len(emitted) - 1
+                slot.pos = p + len(emitted)
+                for t in emitted:
+                    slot.out.append(t)
+                    generated += 1
+                    if slot.first_tok_t is None:
+                        slot.first_tok_t = now
+                        self.metrics_.observe_ttft(now - req.enqueue_t)
+                done = (len(slot.out) >= req.max_new
+                        or (req.eos_id is not None
+                            and slot.out[-1] == req.eos_id))
+                if done:
+                    self._retire(i, slot, now)
+                    retired += 1
+                else:
+                    # the device judged as the host did unless the host cut
+                    # the chain short, and then the row is done
+                    slot.next_token = slot.out[-1]
+                    slot.draft = int(read[i, 3])
+            ahead = self._flight
+            if ahead is not None and not any(
+                    self._slots[i] is slot for i, slot, _p in ahead.rows):
+                # every row of the step ahead has ended meanwhile: nothing
+                # of it is anyone's, and no quantum would come to read it
+                self._flight = None
+            self.metrics_.observe_spec(accepted, drafted - accepted)
+            self.metrics_.observe_decode_step(live, b, generated,
+                                              ahead=flight.ahead)
+            if ssp:
+                ssp.set(generated=generated, retired=retired)
+        if sp:
+            sp.set(live=live, bucket=b, ctx=c, generated=generated,
+                   ahead=int(flight.ahead), drafted=drafted,
+                   accepted=accepted)
 
     def _retire(self, i, slot, now):
         """Finished sequence: resolve, free the slot IMMEDIATELY (the

@@ -66,6 +66,9 @@ _COUNTERS = (
                            "the admitted requests"),
     ("idle_seconds", "seconds the decode loop waited with no request live "
                      "or queued"),
+    ("spec_steps", "verifying runs: chunk runs that judged drafts, or steps "
+                   "of a program that drafts for itself"),
+    ("spec_drafted", "draft tokens the verifier judged"),
     ("spec_accepted", "speculative draft tokens accepted by the verifier"),
     ("spec_rejected", "speculative draft tokens rejected by the verifier"),
 )
@@ -254,6 +257,8 @@ class ServingMetrics:
         """One speculative verify outcome: ``accepted`` draft tokens
         matched the target model's greedy choice, ``rejected`` did not
         (the bonus token the verifier emits itself counts in neither)."""
+        self._c["spec_steps"].inc()
+        self._c["spec_drafted"].inc(int(accepted) + int(rejected))
         self._c["spec_accepted"].inc(int(accepted))
         self._c["spec_rejected"].inc(int(rejected))
 
@@ -323,6 +328,8 @@ class ServingMetrics:
             "admitted": c["admitted"],
             "queue_wait_seconds": c["queue_wait_seconds"],
             "idle_seconds": c["idle_seconds"],
+            "spec_steps": c["spec_steps"],
+            "spec_drafted": c["spec_drafted"],
             "spec_accepted": c["spec_accepted"],
             "spec_rejected": c["spec_rejected"],
             "spec_accept_rate": (
@@ -369,8 +376,9 @@ class ServingMetrics:
                     "cache_donated_bytes", "prefill_chunks",
                     "prefill_tokens", "prefill_lanes",
                     "prefill_deferred_rows", "admitted",
-                    "queue_wait_seconds", "idle_seconds", "spec_accepted",
-                    "spec_rejected", "spec_accept_rate"):
+                    "queue_wait_seconds", "idle_seconds", "spec_steps",
+                    "spec_drafted", "spec_accepted", "spec_rejected",
+                    "spec_accept_rate"):
             lines.append("%-32s %14s" % (key, fmt(s[key])))
         for group in ("latency_s", "ttft_s", "tpot_s"):
             prefix = group[:-2]  # strip the _s unit suffix

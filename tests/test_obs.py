@@ -325,8 +325,11 @@ def test_registry_snapshot_consistency():
                   "prefix_evictions", "prefix_bytes", "cache_donated_bytes",
                   "prefill_chunks", "prefill_tokens", "prefill_lanes",
                   "prefill_deferred_rows", "admitted", "queue_wait_seconds",
-                  "idle_seconds", "spec_accepted", "spec_rejected"):
+                  "idle_seconds", "spec_steps", "spec_drafted",
+                  "spec_accepted", "spec_rejected"):
         assert vals["paddle_tpu_serving_" + field] == snap[field], field
+    # one verifying run that judged four drafts
+    assert (snap["spec_steps"], snap["spec_drafted"]) == (1, 4)
     # derived fields still derive from registry counters
     assert snap["batch_occupancy"] == 3 / 4
     assert snap["slot_occupancy"] == 2 / 4
@@ -357,8 +360,9 @@ def test_registry_snapshot_consistency():
         "prefix_hits", "prefix_tokens_reused", "prefix_evictions",
         "prefix_bytes", "cache_donated_bytes", "prefill_chunks",
         "prefill_tokens", "prefill_lanes", "prefill_deferred_rows",
-        "admitted", "queue_wait_seconds", "idle_seconds", "spec_accepted",
-        "spec_rejected", "spec_accept_rate"}
+        "admitted", "queue_wait_seconds", "idle_seconds", "spec_steps",
+        "spec_drafted", "spec_accepted", "spec_rejected",
+        "spec_accept_rate"}
     # and the report names every scalar of the snapshot, these four too
     rows = {line.split()[0] for line in m.report().splitlines()[1:]}
     assert {k for k in snap if not k.endswith("_s")} <= rows

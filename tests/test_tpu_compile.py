@@ -367,6 +367,83 @@ def test_mimo_v2_step_reads_its_weights_where_they_lie_and_fits_the_chip(
                          compiled.as_text())
 
 
+@pytest.mark.parametrize("kind", ["step", "chunk"])
+def test_glm_lite_programs_fit_the_chip_beside_every_expert(chip, kind):
+    """``glm47flash.serve.reason.sat``'s two programs, built from the
+    configuration's own keys and compiled for one chip at 32 slot rows x
+    4096 (the chunk at its rung's sub-batch): 10.35 GB of weights, every
+    expert and the whole vocabulary among them, 1.21 GB of latent caches
+    written in place, and temporaries that leave room: a verifying step's
+    two heads of [32, 2, 154880] float32 logits, a chunk's lanes through
+    64 experts."""
+    import json
+
+    import paddle_tpu as fluid
+    from paddle_tpu.core.executor import build_step_fn
+    from paddle_tpu.models import glm_lite
+    from paddle_tpu.serving.decode_batcher import chunk_rows
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "glm-4.7-flash.json")) as f:
+        body = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "serve.reason.sat.json")) as f:
+        engine = json.load(f)["engine"]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        fetch, spec = getattr(glm_lite, "glm_lite_" + kind)(
+            dtype="bfloat16", **{k: body[k] for k in body["builder_keys"]})
+    gb = main.global_block()
+    persist = sorted({v.name for v in main.list_vars() if v.persistable})
+    state = {n: sds(tuple(gb.var(n).shape),
+                    BF16 if gb.var(n).dtype == "bfloat16"
+                    else np.dtype(gb.var(n).dtype)) for n in persist}
+    b, c = engine["ladder"][0], engine["seq_ladder"][0]
+    assert (b, c) == (32, 4096)
+    if kind == "step":
+        rows = b
+        feed = {spec["token_feed"]: sds((b, 2), I32),
+                spec["pos_feed"]: sds((b, 2), I32)}
+    else:
+        k = engine["prefill_ladder"][0]
+        rows = chunk_rows(k, b)
+        feed = {spec["token_feed"]: sds((rows, k + 1), I32),
+                spec["pos_feed"]: sds((rows, k), I32)}
+    cache_bytes = 0
+    for cf in spec["cache_feeds"]:
+        shape = (rows, c) + tuple(cf["tail"])
+        feed[cf["feed"]] = sds(shape, BF16)
+        cache_bytes += 2 * int(np.prod(shape))
+    assert cache_bytes == 8 * 1152 * rows * c
+    rng = jax.eval_shape(lambda: jax.random.key(0, impl="rbg"))
+    step = build_step_fn(main, [v.name for v in fetch], persist,
+                         infer_only=True)
+    compiled = _compile(chip, step, state, feed, rng, donate_argnums=(1,))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes       # written in place
+    weights = 2 * body["parameters"]
+    assert mem.argument_size_in_bytes >= weights
+    # the slot table stays beside a chunk's sub-batch while it runs
+    table = 8 * 1152 * b * c if kind == "chunk" else 0
+    need = mem.temp_size_in_bytes + mem.argument_size_in_bytes + table
+    print("glm_lite %s: temporaries %.2f GB, arguments %.2f GB" % (
+        kind, mem.temp_size_in_bytes / 1e9, mem.argument_size_in_bytes / 1e9))
+    # whole-cache copies: none round the writes (a scatter over two lanes
+    # turned each of the eight caches round twice: 2.4 GB of temporaries)
+    assert mem.temp_size_in_bytes < 0.6e9
+    assert 0.6 * _HBM_BYTES < need < 0.8 * _HBM_BYTES
+    if kind == "step":
+        # a step's two-lane writes run under the step write's own scope, as
+        # the chip's compiler leaves it: what ``cache_write_ms`` looks up
+        from benchmark import trace_reduce
+
+        wanted = trace_reduce.scope_pattern(("kv_cache_write",))
+        found = [s for s in trace_reduce.hlo_scopes(
+            compiled.as_text()).values() if wanted.search(s)]
+        assert found, "no instruction under kv_cache_write"
+
+
 def test_state_space_core_and_latent_experts_fit_at_published_widths(chip):
     """``nemotron3super.train.s8192``'s share of a layer: the Mamba-2 scan
     (16 heads of 64 x 128 state, one group, T = 8192, chunks of 128) keeps
