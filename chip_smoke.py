@@ -104,6 +104,14 @@ def sizes(rehearse):
             # then qwen3next.train.s8192's: 16 of 512 SwiGLU experts of
             # 512 at the model's 2048, 10 picks
             "ssd": dict(t=8192, heads=16, p=64, groups=1, n=128, chunk=128),
+            # a decode step's attention at opt1p3b.serve.chat*'s shape
+            # (plain heads) and at a full layer's of
+            # mimo2flash.serve.mixedlen.sat (64 heads on 4, a sink)
+            "cache_step": [
+                dict(rows=16, c=1280, heads=32, kv_heads=32, dk=64, dv=64,
+                     sink=False),
+                dict(rows=16, c=16384, heads=64, kv_heads=4, dk=192, dv=128,
+                     sink=True)],
             "experts": [
                 dict(form="relu2", t=8192, d_model=4096, latent=1024,
                      f=2688, experts=512, held=8, top_k=22, score="sigmoid",
@@ -150,6 +158,11 @@ def sizes(rehearse):
         "ce": dict(t=256, d=32, v=300, ref_chunk=64),
         "scatter": dict(k=16, v=600, n=2048),
         "ssd": dict(t=70, heads=4, p=8, groups=2, n=16, chunk=16),
+        "cache_step": [
+            dict(rows=3, c=256, heads=16, kv_heads=16, dk=16, dv=16,
+                 sink=False),
+            dict(rows=3, c=256, heads=16, kv_heads=2, dk=64, dv=64,
+                 sink=True)],
         "experts": [
             dict(form="relu2", t=96, d_model=32, latent=128, f=256,
                  experts=16, held=4, top_k=5, score="sigmoid", scale=2.5),
@@ -728,6 +741,40 @@ def _experts_case(ctx, form, t, d_model, latent, f, experts, held, top_k,
             "fwd_err": fwd, "bwd_err": bwd}
 
 
+def _cache_step_case(ctx, rows, c, heads, kv_heads, dk, dv, sink):
+    """A decode step's attention over a slot table's caches: the kernel
+    ``cache_step.fwd`` (a row's blocks up to its own position) against the
+    ``jnp`` form that reads the rung under a mask, rows at positions from 0
+    to the rung's last."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import cache_attention as ca
+
+    rng = np.random.RandomState(ctx["seed"])
+    bf16 = jnp.bfloat16
+    q = jnp.asarray(rng.randn(rows, heads * dk), bf16)
+    k = jnp.asarray(rng.randn(rows, c, kv_heads * dk), bf16)
+    v = jnp.asarray(rng.randn(rows, c, kv_heads * dv), bf16)
+    s = jnp.asarray(rng.randn(heads), bf16) if sink else None
+    pos = jnp.asarray(np.linspace(0, c - 1, rows).astype(np.int32))
+    plan = ca.plan_for(q, k, v, heads, kv_heads)
+    if ctx["on_chip"]:
+        check(plan.kernel == "cache_step",
+              "cache_step fell back: %s" % plan)
+    ours, count = jax.jit(lambda q, k, v, pos, s: ca.step_blocks(
+        q, k, v, pos, heads, kv_heads, s))(q, k, v, pos, s)
+    rung, rung_count = jax.jit(lambda q, k, v, pos, s: ca.attend_step(
+        q, k, v, pos, heads, kv_heads, 0, s))(q, k, v, pos, s)
+    err = max_err(ours, rung)
+    check(err < 3e-2, "cache_step error %g" % err)
+    check(int(count[0]) == int(rung_count[0]), "cache_step counts %d "
+          "positions, the rung form %d" % (count[0], rung_count[0]))
+    return {"case": "cache_step", "plan": plan.kernel, "heads": heads,
+            "kv_heads": kv_heads, "c": c, "err": err}
+
+
 def phase_kernels(ctx):
     cfg = ctx["sizes"]
     cases = []
@@ -748,6 +795,9 @@ def phase_kernels(ctx):
     log("kernels: %s" % cases[-1])
     for case in cfg["experts"]:
         cases.append(_experts_case(ctx, **case))
+        log("kernels: %s" % cases[-1])
+    for case in cfg["cache_step"]:
+        cases.append(_cache_step_case(ctx, **case))
         log("kernels: %s" % cases[-1])
     return {"cases": cases}
 
@@ -1093,11 +1143,12 @@ def main():
     if args.rehearse and not args.multichip:
         # (the mesh rehearsal keeps the gates honest instead: interpret
         # mode would put kernels under the mesh that the chip never sees)
-        from paddle_tpu.ops import (flash_attention, fused_ce, fused_conv,
-                                    grouped_experts, scatter)
+        from paddle_tpu.ops import (cache_attention, flash_attention,
+                                    fused_ce, fused_conv, grouped_experts,
+                                    scatter)
 
-        for mod in (flash_attention, fused_ce, fused_conv, grouped_experts,
-                    scatter):
+        for mod in (cache_attention, flash_attention, fused_ce, fused_conv,
+                    grouped_experts, scatter):
             mod._INTERPRET = True
 
     ctx = {

@@ -174,19 +174,39 @@ def _cached_attention(env, op):
     C``), a ``Sink`` [H] input (a learned scalar a head in the softmax's
     denominator) or a ``Count`` [1] int32 output (the positions the rows
     read) take the op to ``ops/cache_attention.py``: the same block-diagonal
-    read of the caches as stored, a group at a time."""
+    read of the caches as stored, a group at a time.
+
+    **Which form runs where.** Both forms above read the whole rung under
+    a mask. On ONE TPU a step over a cache that holds the context (no
+    window, no ring) reads the blocks of positions up to each row's own
+    ``Pos`` instead, by the Pallas kernel ``cache_step.fwd``
+    (``cache_attention.step_blocks``: the same block-diagonal queries, a
+    streaming softmax over a row's blocks); the extended form binds it under
+    its scope ``attn.full``. ``cache_attention.plan_for`` decides it from
+    the placement, the widths and the rung's length, and the decision is
+    recorded in ``op.attrs["_kernel_choice"]`` and handed to the trace's
+    gate count (``gates.note``). The CPU, a mesh, a window, a ring and a
+    shape the gate refuses lower as they always have."""
+    from ...ops import cache_attention
+    from ...ops.gates import note
+
     q = get(env, op.input("Q"))
     k = get(env, op.input("CacheK"))
     v = get(env, op.input("CacheV"))
     pos = get(env, op.input("Pos")).reshape(-1).astype(jnp.int32)
     h = int(op.attr("num_heads", 1))
     more = _extended(env, op, k, v)
-    if more is not None:
-        from ...ops import cache_attention
-
-        kv_heads, window, ring, sink = more
-        out, count = cache_attention.attend_step(
-            q, k, v, pos, h, kv_heads, window, sink, ring)
+    kv_heads, window, ring, sink = more or (h, 0, False, None)
+    plan = cache_attention.plan_for(q, k, v, h, kv_heads, window, ring)
+    op.attrs["_kernel_choice"] = plan.to_dict()
+    note("cached_attention", plan)
+    if plan or more is not None:
+        if plan:
+            out, count = cache_attention.step_blocks(
+                q, k, v, pos, h, kv_heads, sink, plain=more is None)
+        else:
+            out, count = cache_attention.attend_step(
+                q, k, v, pos, h, kv_heads, window, sink, ring)
         put(env, op.output("Out"), out)
         put(env, op.output("Count"), count)
         return

@@ -35,17 +35,37 @@ Scores are scaled by ``Dk ** -0.5`` and kept in float32, the softmax is
 float32, products accumulate in float32. Every form is strictly per-row.
 Device events run under the scope ``attn.window`` (a window or a ring) or
 ``attn.full``.
+
+**Which step form runs where.** A step over a cache that holds the whole
+context (no window, no ring; plain heads are the case ``kv_heads ==
+heads``) on ONE TPU runs the Pallas kernel ``cache_step.fwd``
+(:func:`step_blocks`): it walks a row's caches in blocks of positions under
+a streaming softmax and stops at the block that holds the row's own
+position, so a step reads the positions the rows hold and not the rung.
+:func:`step_plan` decides it from what the trace sees (placement, widths,
+the rung's length, the VMEM two blocks of keys and values take
+double-buffered). A ring, a window, the CPU, a mesh and every shape the
+gate refuses keep the ``jnp`` forms (:func:`attend_step`, and the plain
+form in ``core/opimpl/attention_ops.py``), which read the whole rung under
+a mask and are the kernel's reference.
 """
 
+import contextlib
+import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from .gates import GateDecision, GateReason, platform_reason
+from .kernel_names import named_pallas_call, traced_once
 from .sparse_latent import _block
 
 __all__ = ["attend_step", "attend_chunk", "attend_chunk_ring",
-           "ring_positions", "ring_slots"]
+           "ring_positions", "ring_slots", "step_plan", "plan_for",
+           "step_blocks"]
 
 ATTN_BLOCK = 512     # cache positions a chunk's block reads at a time
 
@@ -261,3 +281,272 @@ def attend_chunk_ring(q, ring_k, ring_v, new_k, new_v, pos, heads, kv_heads,
                          vals.reshape(b, n, -1, g, dv),
                          preferred_element_type=_F32)
     return out.reshape(b, kq, g * r * dv).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# a step on one TPU: the kernel ``cache_step.fwd``
+# ---------------------------------------------------------------------------
+
+_INTERPRET = False  # tests flip this to run the kernel on the CPU
+
+_VMEM_BUDGET = 32 * 1024 * 1024
+# cache bytes the chip reads in the time a pass of the kernel's loop costs
+# beside its copies (0.3 us at 700 GB/s, TPU v5e)
+_PASS_BYTES = 200 * 1024
+
+
+def step_block(c, row_bytes):
+    """Positions a block of the step kernel reads, a multiple of 128 that
+    divides ``c`` and is shorter than it; None where ``c`` has no such
+    divisor (a rung of one block has nothing to cut short). A row reads
+    half a block past its position and pays a pass a block, so a row of
+    ``p`` positions of ``row_bytes`` (keys and values) is cheapest at
+    ``sqrt(2 p _PASS_BYTES / row_bytes)``: the largest divisor under that
+    for a row a quarter of the rung long, the smallest where none is."""
+    fits = [n for n in range(128, c, 128) if c % n == 0]
+    if not fits:
+        return None
+    want = math.sqrt(c / 2 * _PASS_BYTES / row_bytes)
+    return max([n for n in fits if n <= want] or fits[:1])
+
+
+def _working_set(b, block, heads, kd, vd, itemsize):
+    """Bytes the step kernel holds in VMEM, counted generously: two blocks
+    of keys and of values (double-buffered), the rows' queries laid out a
+    head a row and the columns a head keeps, the output, the float32
+    accumulator, and the [heads, block] and [heads, vd] float32 tiles live
+    in a pass."""
+    blocks = 2 * block * (kd + vd) * itemsize
+    whole = 2 * b * heads * kd * itemsize + 2 * heads * vd * 4 \
+        + 2 * b * heads * vd * 4
+    live = 4 * heads * block * 4 + 4 * heads * vd * 4
+    return blocks + whole + live
+
+
+def step_plan(b, c, heads, kv_heads, kd, vd, itemsize, window=0, ring=False,
+              platform=None):
+    """Which way a ``cached_attention`` site reads its caches, as a
+    ``GateDecision``: ``cache_step`` (the kernel: the blocks up to each
+    row's own position) or ``rung_xla`` (the ``jnp`` forms: the whole rung
+    under a mask) with the blocking reasons. ``kd`` / ``vd``: the width of a
+    cached key / value row, all heads; ``itemsize``: of the one floating
+    type the queries and the caches have, None where they have not one;
+    ``platform``: what
+    ``gates.platform_reason`` says of where the step runs
+    (:func:`plan_for`)."""
+    reasons = []
+    if platform is not None:
+        reasons.append(platform)
+    if window or ring:
+        reasons.append(GateReason(
+            "shape", "a %s of %d positions is read whole: no rung to cut "
+            "short" % ("ring" if ring else "window", c if ring else window)))
+    if itemsize not in (2, 4):
+        reasons.append(GateReason(
+            "dtype", "queries and caches are not of one 2- or 4-byte "
+            "floating type"))
+    else:
+        r = heads // max(kv_heads, 1)
+        sublanes = 32 // itemsize
+        if kd % 128 or vd % 128 or heads % sublanes or (r > 1 and r % 8):
+            reasons.append(GateReason(
+                "geometry", "rows of %d and %d are not multiples of 128, or "
+                "%d heads no multiple of %d sublanes, or groups of %d query "
+                "heads no multiple of 8" % (kd, vd, heads, sublanes, r)))
+    block = None
+    if not reasons:
+        block = step_block(c, (kd + vd) * itemsize)
+        if block is None:
+            reasons.append(GateReason(
+                "geometry", "a rung of %d positions is no longer than one "
+                "block of a multiple of 128" % c))
+        elif _working_set(b, block, heads, kd, vd, itemsize) > _VMEM_BUDGET:
+            reasons.append(GateReason(
+                "vmem", "two blocks of %d positions of %d + %d wide rows, "
+                "double-buffered, beside %d rows' queries exceed the %.0f "
+                "MB VMEM budget" % (block, kd, vd, b, _VMEM_BUDGET / 2**20)))
+    if reasons:
+        return GateDecision(False, "rung_xla", fallback="cache_step",
+                            reasons=reasons)
+    return GateDecision(True, "cache_step", reasons=[GateReason(
+        "shape", "blocks of %d of %d positions, each row's up to its own"
+        % (block, c), blocking=False)])
+
+
+def plan_for(q, k, v, heads, kv_heads, window=0, ring=False):
+    """:func:`step_plan` of a site's arrays, where the step being traced is
+    placed."""
+    one = q.dtype == k.dtype == v.dtype and jnp.issubdtype(k.dtype,
+                                                           jnp.floating)
+    itemsize = k.dtype.itemsize if one else None
+    return step_plan(k.shape[0], k.shape[1], int(heads), int(kv_heads),
+                     k.shape[2], v.shape[2], itemsize, window, ring,
+                     platform=platform_reason(_INTERPRET))
+
+
+def _step_kernel(pos_ref, q_ref, own_ref, *refs, block, scale, groups,
+                 with_sink):
+    """One pass a (row, block) pair, the rows in their order and a row's
+    blocks from 0 to the one that holds its position: the copy of the next
+    pair's blocks runs under this pair's products, across rows too. pos_ref
+    [B] int32 (SMEM); q_ref [B, heads, kd]: a row's queries laid out
+    block-diagonally; own_ref [heads, vd] float32: 1 in the value columns
+    of a head's own group; then sink_ref [heads, 1] float32 if there is a
+    sink; k_hbm [B, C, kd], v_hbm [B, C, vd] where they are stored; out_ref
+    [B, r, vd] float32: head ``gi * r + ri`` in columns ``gi`` of row
+    ``ri``; two slots of a block of keys and of values, their copy
+    semaphores, and a row's running maximum, sum and accumulator."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    sink_ref = None
+    if with_sink:
+        sink_ref, *refs = refs
+    (k_hbm, v_hbm, out_ref, k_buf, v_buf, sems, top_ref, total_ref,
+     acc_ref) = refs
+    rows, heads, _ = q_ref.shape
+    c = k_hbm.shape[1]
+    r = heads // groups
+
+    def last(row):          # the block that holds the row's own position
+        return jnp.clip(pos_ref[row], 0, c - 1) // block
+
+    passes = jax.lax.fori_loop(
+        0, rows, lambda row, n: n + last(row) + 1, jnp.int32(0))
+
+    def copies(row, j, slot):
+        at = pl.ds(pl.multiple_of(j * block, block), block)
+        return (pltpu.make_async_copy(k_hbm.at[row, at], k_buf.at[slot],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[row, at], v_buf.at[slot],
+                                      sems.at[1, slot]))
+
+    for copy in copies(0, 0, 0):
+        copy.start()
+
+    def one(i, carry):
+        row, j = carry
+        slot = jax.lax.rem(i, 2)
+        done = j == last(row)
+        next_row = jnp.where(done, row + 1, row)
+        next_j = jnp.where(done, 0, j + 1)
+
+        @pl.when(i + 1 < passes)
+        def _():
+            for copy in copies(next_row, next_j, 1 - slot):
+                copy.start()
+
+        @pl.when(j == 0)
+        def _():
+            if sink_ref is None:
+                top_ref[...] = jnp.full(top_ref.shape, _LOW, _F32)
+                total_ref[...] = jnp.zeros(total_ref.shape, _F32)
+            else:
+                # the sink opens the streaming softmax: one term of weight
+                # 1 at its own height, and no value
+                top_ref[...] = sink_ref[...]
+                total_ref[...] = jnp.ones(total_ref.shape, _F32)
+            acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
+
+        for copy in copies(row, j, slot):
+            copy.wait()
+        kb, vb = k_buf[slot], v_buf[slot]
+        s = jax.lax.dot_general(q_ref[row], kb, (((1,), (1,)), ((), ())),
+                                preferred_element_type=_F32) * scale
+        at = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        live = at <= pos_ref[row]
+        s = jnp.where(live, s, _LOW)
+        top = top_ref[...]
+        new_top = jnp.maximum(top, jnp.max(s, axis=-1, keepdims=True))
+        probs = jnp.where(live, jnp.exp(s - new_top), 0.0)
+        keep = jnp.exp(top - new_top)
+        top_ref[...] = new_top
+        total_ref[...] = total_ref[...] * keep \
+            + jnp.sum(probs, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * keep + jnp.dot(
+            probs.astype(vb.dtype), vb, preferred_element_type=_F32)
+
+        @pl.when(done)
+        def _():
+            y = acc_ref[...] / jnp.maximum(total_ref[...], 1e-30)
+            y = jnp.where(own_ref[...] != 0, y, 0.0)
+            if r == 1:
+                out_ref[row] = jnp.sum(y, axis=0, keepdims=True)
+            else:
+                out_ref[row] = functools.reduce(operator.add, (
+                    y[gi * r:(gi + 1) * r] for gi in range(groups)))
+
+        return next_row, next_j
+
+    jax.lax.fori_loop(0, passes, one, (jnp.int32(0), jnp.int32(0)))
+
+
+@traced_once("cache_step.fwd", ("groups", "block", "vmem", "interpret"))
+def _step_impl(pos, q_blocks, own, sink, k, v, groups, block, vmem,
+               interpret):
+    """pos [B] int32; q_blocks [B, heads, kd]; own [heads, vd] float32; sink
+    [heads, 1] float32 or None; k [B, C, kd], v [B, C, vd]. Returns [B,
+    heads / groups, vd] float32 (:func:`_step_kernel`). ``vmem``: the
+    kernel's ``vmem_limit_bytes``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, heads, kd = q_blocks.shape
+    vd = v.shape[2]
+    held = pl.BlockSpec(memory_space=pltpu.VMEM)
+    stored = pl.BlockSpec(memory_space=pl.ANY)
+    arrays = [q_blocks, own] + ([] if sink is None else [sink]) + [k, v]
+    return named_pallas_call(
+        "cache_step.fwd",
+        functools.partial(_step_kernel, block=block, groups=groups,
+                          scale=1.0 / math.sqrt(kd // groups),
+                          with_sink=sink is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[held] * (len(arrays) - 2) + [stored, stored],
+            out_specs=held,
+            scratch_shapes=[pltpu.VMEM((2, block, kd), k.dtype),
+                            pltpu.VMEM((2, block, vd), v.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.VMEM((heads, 1), _F32),
+                            pltpu.VMEM((heads, 1), _F32),
+                            pltpu.VMEM((heads, vd), _F32)]),
+        out_shape=jax.ShapeDtypeStruct((b, heads // groups, vd), _F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem),
+        interpret=interpret,
+    )(pos, *arrays)
+
+
+def step_blocks(q, k, v, pos, heads, kv_heads, sink=None, plain=False):
+    """:func:`attend_step` without a window or a ring, by the kernel
+    ``cache_step.fwd`` (what :func:`plan_for` admits): the same arguments,
+    the same two results, the same sums in another order (a row's blocks one
+    after the other under a running maximum and sum, the probabilities cast
+    to the cache's type for the mix, one division at the end). A row reads
+    the blocks up to the one that holds ``pos``, a row fed 0 one block; the
+    count is the positions ``<= pos``, not the positions fetched. ``plain``:
+    for the op's plain form, which runs under no scope but the op's."""
+    b, c, kd = k.shape
+    g, r = int(kv_heads), int(heads) // int(kv_heads)
+    dk, dv = kd // g, v.shape[-1] // g
+    pos = pos.reshape(-1).astype(jnp.int32)
+    keeps = np.kron(np.eye(g, dtype=np.float32),
+                    np.ones((r, dv), np.float32))
+    with contextlib.nullcontext() if plain else _scope(0):
+        # the queries block-diagonally, a head a row: [heads, kv_heads *
+        # Dk], head h's Dk values in the columns of its group, zeros
+        # elsewhere
+        own = jnp.eye(g, dtype=bool)
+        q_blocks = jnp.where(
+            own[None, :, None, :, None], q.reshape(b, g, r, 1, dk),
+            0).reshape(b, g * r, kd)
+        out = _step_impl(
+            pos, q_blocks, jnp.asarray(keeps),
+            None if sink is None else sink.astype(_F32).reshape(g * r, 1),
+            k, v, groups=g,
+            block=step_block(c, (kd + g * dv) * k.dtype.itemsize),
+            vmem=_VMEM_BUDGET, interpret=_INTERPRET)
+        out = jnp.transpose(out.reshape(b, r, g, dv), (0, 2, 1, 3))
+        count = jnp.sum(jnp.clip(pos + 1, 0, c), dtype=jnp.int32).reshape(1)
+    return out.reshape(b, g * r * dv).astype(q.dtype), count

@@ -316,6 +316,48 @@ def test_grouped_window_and_ring_attention_fit_at_published_widths(chip):
     assert _kernel_calls(compiled) == 0
 
 
+# (rows, capacity, heads, key/value heads, Dk, Dv, sink) of the step ops of
+# ``opt1p3b.serve.chat*`` and of ``mimo2flash.serve.mixedlen.sat``'s full
+# layers
+_CACHE_STEP_CASES = {
+    "opt1p3b": (16, 1280, 32, 32, 64, 64, False),
+    "mimo2flash_full": (16, 16384, 64, 4, 192, 128, False),
+    "mimo2flash_full_sink": (16, 16384, 64, 4, 192, 128, True),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CACHE_STEP_CASES))
+def test_cache_step_compiles_within_the_vmem_the_gate_counts(
+        chip, cell, monkeypatch):
+    """The step kernel behind ``cached_attention`` at each serving cell's
+    own shape, ``vmem_limit_bytes`` set to the working set its gate counts:
+    the gate admits the shape, Mosaic needs no more than is counted, the
+    kernel is there by name, and no cache is copied or transposed on its
+    way in (the kernel reads the caches where and as they are stored)."""
+    from paddle_tpu.ops import cache_attention as ca
+
+    b, c, heads, g, dk, dv, with_sink = _CACHE_STEP_CASES[cell]
+    q, k, v = (sds((b, heads * dk), BF16), sds((b, c, g * dk), BF16),
+               sds((b, c, g * dv), BF16))
+    with placed("tpu"):
+        plan = ca.plan_for(q, k, v, heads, g)
+    assert plan.kernel == "cache_step", plan
+    block = ca.step_block(c, 2 * g * (dk + dv))
+    counted = ca._working_set(b, block, heads, g * dk, g * dv, 2)
+    assert counted <= ca._VMEM_BUDGET
+    monkeypatch.setattr(ca, "_VMEM_BUDGET", counted)
+
+    def step(q, k, v, pos, sink):
+        return ca.step_blocks(q, k, v, pos, heads, g, sink)
+
+    compiled = _compile(chip, step, q, k, v, sds((b,), I32),
+                        sds((heads,), BF16) if with_sink else None)
+    _assert_named(compiled, {"cache_step.fwd"})
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.02e9
+    assert not re.search(r"= bf16\[%d,%d,\d+\]\S* (copy|transpose)\(" % (b, c),
+                         compiled.as_text())
+
+
 def test_mimo_v2_step_reads_its_weights_where_they_lie_and_fits_the_chip(
         chip):
     """The cell's step program, built from the configuration's own keys
@@ -365,6 +407,14 @@ def test_mimo_v2_step_reads_its_weights_where_they_lie_and_fits_the_chip(
     assert 0.25 * _HBM_BYTES < need < 0.6 * _HBM_BYTES
     assert not re.search(r"= bf16\[\d+,\d+\]\S* (copy|transpose)\(%?state",
                          compiled.as_text())
+    # the two full layers read their caches by the step kernel, where they
+    # lie; the five rings keep the jnp form
+    assert _step_kernel_names(compiled) == {"cache_step.fwd": 2}
+    assert not re.search(r"= bf16\[16,16384,\d+\]\S* (copy|transpose)\(",
+                         compiled.as_text())
+    choices = [op.attrs["_kernel_choice"]["kernel"]
+               for op in gb.ops if op.type == "cached_attention"]
+    assert sorted(choices) == ["cache_step"] * 2 + ["rung_xla"] * 5
 
 
 @pytest.mark.parametrize("kind", ["step", "chunk"])
