@@ -112,6 +112,11 @@ def sizes(rehearse):
                      sink=False),
                 dict(rows=16, c=16384, heads=64, kv_heads=4, dk=192, dv=128,
                      sink=True)],
+            # a verifying step's latent attention at
+            # glm47flash.serve.reason.sat's shape (two lanes of 20 heads
+            # over rows of 512 + 64)
+            "latent_step": dict(rows=32, c=4096, lanes=2, heads=20, r=512,
+                                rope=64, nope=192, v=256),
             "experts": [
                 dict(form="relu2", t=8192, d_model=4096, latent=1024,
                      f=2688, experts=512, held=8, top_k=22, score="sigmoid",
@@ -163,6 +168,8 @@ def sizes(rehearse):
                  sink=False),
             dict(rows=3, c=256, heads=16, kv_heads=2, dk=64, dv=64,
                  sink=True)],
+        "latent_step": dict(rows=4, c=256, lanes=2, heads=20, r=128, rope=64,
+                            nope=24, v=32),
         "experts": [
             dict(form="relu2", t=96, d_model=32, latent=128, f=256,
                  experts=16, held=4, top_k=5, score="sigmoid", scale=2.5),
@@ -775,6 +782,45 @@ def _cache_step_case(ctx, rows, c, heads, kv_heads, dk, dv, sink):
             "kv_heads": kv_heads, "c": c, "err": err}
 
 
+def _latent_step_case(ctx, rows, c, lanes, heads, r, rope, nope, v):
+    """A verifying step's latent attention over a slot table's latent
+    caches: the kernel ``latent_step.fwd`` (a row's blocks up to the highest
+    position its lanes hold) against the ``jnp`` form that scores the rung
+    under a mask, rows at positions from 0 to the rung's last, the last
+    row's second lane a pad lane."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import cache_attention as ca
+    from paddle_tpu.ops import sparse_latent
+
+    rng = np.random.RandomState(ctx["seed"])
+    bf16 = jnp.bfloat16
+    q = jnp.asarray(rng.randn(rows, lanes, heads * (nope + rope)), bf16)
+    kv_b = jnp.asarray(rng.randn(r, heads * (nope + v)) * r ** -0.5, bf16)
+    cache = jnp.asarray(rng.randn(rows, c, r + rope), bf16)
+    first = np.linspace(0, c - 1, rows).astype(np.int32)
+    pos = jnp.asarray(first[:, None] + np.arange(lanes, dtype=np.int32))
+    plan = ca.latent_plan_for(q, cache, r, heads)
+    if ctx["on_chip"]:
+        check(plan.kernel == "latent_step",
+              "latent_step fell back: %s" % plan)
+    def attend(plan):
+        return jax.jit(lambda q, kv_b, cache, pos:
+                       sparse_latent.latent_attention_dense(
+                           q, kv_b, cache, pos, heads, nope, v,
+                           (nope + rope) ** -0.5, plan=plan))(
+                               q, kv_b, cache, pos)
+
+    ours, rung = attend(plan), attend(None)
+    err = rel_err(ours, rung)
+    check(err < 2e-2, "latent_step error %g" % err)
+    check(not np.asarray(ours[-1, -1]).any(), "a pad lane is not 0")
+    return {"case": "latent_step", "plan": plan.kernel, "heads": heads,
+            "lanes": lanes, "c": c, "err": err}
+
+
 def phase_kernels(ctx):
     cfg = ctx["sizes"]
     cases = []
@@ -799,6 +845,8 @@ def phase_kernels(ctx):
     for case in cfg["cache_step"]:
         cases.append(_cache_step_case(ctx, **case))
         log("kernels: %s" % cases[-1])
+    cases.append(_latent_step_case(ctx, **cfg["latent_step"]))
+    log("kernels: %s" % cases[-1])
     return {"cases": cases}
 
 

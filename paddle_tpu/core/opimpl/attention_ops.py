@@ -428,14 +428,33 @@ def _latent_attention_dense(env, op):
     """A step's latent attention where nothing selects: Q [B, K, H*(N+P)]
     one or two queries a row, KvB, Cache [B, C, R+P] with the step's rows
     written, Pos [B, K]. Lane k reads every position ``<= Pos[b, k]`` of
-    its row's cache, scored whole under that mask: no index, no gather.
-    Out [B, K, H*V], 0 on a pad lane. Strictly per-row."""
-    from ...ops import sparse_latent
+    its row's cache: no index, no gather. Out [B, K, H*V], 0 on a pad
+    lane. Strictly per-row.
 
+    **Which form runs where.** On ONE TPU the cache is read in blocks of
+    positions up to the highest position a row's lanes hold, by the Pallas
+    kernel ``latent_step.fwd`` (``cache_attention.latent_blocks``: the step
+    kernel behind ``cached_attention`` with one group whose values are the
+    latent columns of its keys, a block fetched once for the scores and
+    the mix, under a streaming softmax).
+    ``cache_attention.latent_plan_for`` decides it from the placement, the
+    widths and the rung's length, and the decision is recorded in
+    ``op.attrs["_kernel_choice"]`` and handed to the trace's gate count
+    (``gates.note``). The CPU, a mesh and a shape the gate refuses score
+    the whole rung under a mask (``sparse_latent.latent_attention_dense``'s
+    ``jnp`` form, the kernel's reference)."""
+    from ...ops import cache_attention, sparse_latent
+    from ...ops.gates import note
+
+    q, kv_b = get(env, op.input("Q")), get(env, op.input("KvB"))
+    cache = get(env, op.input("Cache"))
+    heads = int(op.attr("num_heads"))
+    plan = cache_attention.latent_plan_for(q, cache, kv_b.shape[0], heads)
+    op.attrs["_kernel_choice"] = plan.to_dict()
+    note("latent_attention_dense", plan)
     put(env, op.output("Out"), sparse_latent.latent_attention_dense(
-        get(env, op.input("Q")), get(env, op.input("KvB")),
-        get(env, op.input("Cache")), get(env, op.input("Pos")),
-        *_latent_attrs(op)))
+        q, kv_b, cache, get(env, op.input("Pos")), *_latent_attrs(op),
+        plan=plan))
 
 
 @register("last_live_lane")

@@ -543,7 +543,9 @@ def _latent_attention_dense_cost(ctx, op):
     if got is None:
         return
     lanes, heads, nope, v_dim, b, cap, width, r, e = got
-    # every lane scores and mixes the whole capacity; the cache is read once
+    # every lane scores and mixes the whole capacity, the cache read once
+    # (a static rule cannot know the fill; on one TPU the step kernel stops
+    # at the highest position a row's lanes hold)
     flops = 2.0 * lanes * heads * (r * (nope + v_dim) + cap * (width + r))
     ctx.add(op, flops=flops,
             hbm_bytes=(r * heads * (nope + v_dim) + b * cap * width

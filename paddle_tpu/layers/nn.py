@@ -2339,8 +2339,10 @@ def latent_attention(q, cache, selected, pos, num_heads, kv_lora_rank,
     ``selected`` None (a model that has no indexer; q [B, K, H*(N+P)],
     ``pos`` [B, K]): lane k reads every position up to ``pos[b, k]``, a
     chunk's lanes in blocks under a streaming softmax, and with ``dense``
-    (a step's one or two lanes) against the whole cache under the mask
-    (op ``latent_attention_dense``). Returns [.., H*V]."""
+    (a step's one or two lanes) all of a row's queries at once (op
+    ``latent_attention_dense``: on one TPU a kernel that reads a row's
+    cache up to the highest position its lanes hold, elsewhere the whole
+    cache under the mask). Returns [.., H*V]."""
     chunk = len(q.shape) == 3
     if selected is None and not chunk:
         raise ValueError("latent attention without a selection takes q "

@@ -3,7 +3,8 @@ of zai-org/GLM-4.7-Flash, ``model_type`` ``glm4_moe_lite``): DeepSeek-V3's
 block at small widths, built of ``models/glm_dsa.py``'s ONE block definition
 with no indexer (every layer's ``indexer_types`` entry is ``none``: latent
 attention reads every live position of the latent cache,
-``ops/sparse_latent.py::latent_attention_dense`` a step and
+``ops/sparse_latent.py::latent_attention_dense`` a step (on one TPU by the
+kernel ``latent_step.fwd``, ``ops/cache_attention.py``) and
 ``latent_attention_chunk`` without a mask a chunk), one leading dense layer,
 then sigmoid-routed experts beside one shared expert, and **the
 multi-token-prediction module kept and drafting**.

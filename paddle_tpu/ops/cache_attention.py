@@ -48,6 +48,20 @@ double-buffered). A ring, a window, the CPU, a mesh and every shape the
 gate refuses keep the ``jnp`` forms (:func:`attend_step`, and the plain
 form in ``core/opimpl/attention_ops.py``), which read the whole rung under
 a mask and are the kernel's reference.
+
+A step of LATENT attention with no selection (``latent_attention_dense``: K
+lanes a row over one cached row ``[latent | rotary key]`` a position) is the
+same walk with other parameters, the kernel ``latent_step.fwd``
+(:func:`latent_blocks`): one group whose values are the latent columns of
+its keys, so one buffer and one copy a block, and a position a query row,
+the row's blocks ending at the highest position its live lanes hold. The
+two kernels share the loop over the (row, block) pairs and the streaming
+softmax (:func:`_walk_blocks`, :func:`_stream`), the block rule and the VMEM
+count (:func:`step_block`, :func:`_working_set`); their bodies differ
+because the device stores a cache of 576-wide rows with the POSITIONS
+innermost, so a latent block is ``[R+P, block]`` and both products run the
+other way round. :func:`latent_plan` decides it; the CPU, a mesh and a
+refused shape keep ``sparse_latent.latent_attention_dense``'s ``jnp`` form.
 """
 
 import contextlib
@@ -65,7 +79,7 @@ from .sparse_latent import _block
 
 __all__ = ["attend_step", "attend_chunk", "attend_chunk_ring",
            "ring_positions", "ring_slots", "step_plan", "plan_for",
-           "step_blocks"]
+           "step_blocks", "latent_plan", "latent_plan_for", "latent_blocks"]
 
 ATTN_BLOCK = 512     # cache positions a chunk's block reads at a time
 
@@ -310,13 +324,20 @@ def step_block(c, row_bytes):
     return max([n for n in fits if n <= want] or fits[:1])
 
 
-def _working_set(b, block, heads, kd, vd, itemsize):
+def _up(n, multiple):
+    """``n`` up to the next multiple of ``multiple``: a width as VMEM holds
+    it (128 lanes), query rows as their type tiles them (sublanes)."""
+    return -(-n // multiple) * multiple
+
+
+def _working_set(b, block, heads, kd, vd, itemsize, own_values=True):
     """Bytes the step kernel holds in VMEM, counted generously: two blocks
-    of keys and of values (double-buffered), the rows' queries laid out a
-    head a row and the columns a head keeps, the output, the float32
-    accumulator, and the [heads, block] and [heads, vd] float32 tiles live
-    in a pass."""
-    blocks = 2 * block * (kd + vd) * itemsize
+    of keys and (``own_values``: where they are not columns of the keys) of
+    values (double-buffered), the rows' queries laid out a head a row and
+    the columns a head keeps, the output, the float32 accumulator, and the
+    [heads, block] and [heads, vd] float32 tiles live in a pass."""
+    kd, vd = _up(kd, 128), _up(vd, 128)
+    blocks = 2 * block * (kd + (vd if own_values else 0)) * itemsize
     whole = 2 * b * heads * kd * itemsize + 2 * heads * vd * 4 \
         + 2 * b * heads * vd * 4
     live = 4 * heads * block * 4 + 4 * heads * vd * 4
@@ -384,15 +405,68 @@ def plan_for(q, k, v, heads, kv_heads, window=0, ring=False):
                      platform=platform_reason(_INTERPRET))
 
 
+def _walk_blocks(rows, last, copies, open_row, products, close_row):
+    """The step kernels' loop: one pass a (row, block) pair, the rows in
+    their order and a row's blocks from 0 to ``last(row)``, in ONE loop over
+    the pairs that exist, so that the copy of the next pair's block runs
+    under this pair's products, across rows too. ``copies(row, j, slot)``:
+    the async copies that bring block ``j`` of ``row`` into buffer ``slot``;
+    ``open_row()`` before a row's first block, ``products(row, j, slot)``
+    once the block is there, ``close_row(row)`` after its last."""
+    from jax.experimental import pallas as pl
+
+    passes = jax.lax.fori_loop(
+        0, rows, lambda row, n: n + last(row) + 1, jnp.int32(0))
+
+    for copy in copies(0, 0, 0):
+        copy.start()
+
+    def one(i, carry):
+        row, j = carry
+        slot = jax.lax.rem(i, 2)
+        done = j == last(row)
+        next_row = jnp.where(done, row + 1, row)
+        next_j = jnp.where(done, 0, j + 1)
+
+        @pl.when(i + 1 < passes)
+        def _():
+            for copy in copies(next_row, next_j, 1 - slot):
+                copy.start()
+
+        pl.when(j == 0)(open_row)
+        for copy in copies(row, j, slot):
+            copy.wait()
+        products(row, j, slot)
+        pl.when(done)(functools.partial(close_row, row))
+        return next_row, next_j
+
+    jax.lax.fori_loop(0, passes, one, (jnp.int32(0), jnp.int32(0)))
+
+
+def _stream(s, live, mix, top_ref, total_ref, acc_ref):
+    """A block's turn of the streaming softmax: s [heads, block] float32
+    scores, ``live`` where a query row reads the position, ``mix(probs)``
+    the block's [heads, vd] float32 product with its values; a row's
+    running maximum, sum and accumulator move on."""
+    s = jnp.where(live, s, _LOW)
+    top = top_ref[...]
+    new_top = jnp.maximum(top, jnp.max(s, axis=-1, keepdims=True))
+    probs = jnp.where(live, jnp.exp(s - new_top), 0.0)
+    keep = jnp.exp(top - new_top)
+    top_ref[...] = new_top
+    total_ref[...] = total_ref[...] * keep \
+        + jnp.sum(probs, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * keep + mix(probs)
+
+
 def _step_kernel(pos_ref, q_ref, own_ref, *refs, block, scale, groups,
                  with_sink):
-    """One pass a (row, block) pair, the rows in their order and a row's
-    blocks from 0 to the one that holds its position: the copy of the next
-    pair's blocks runs under this pair's products, across rows too. pos_ref
-    [B] int32 (SMEM); q_ref [B, heads, kd]: a row's queries laid out
-    block-diagonally; own_ref [heads, vd] float32: 1 in the value columns
-    of a head's own group; then sink_ref [heads, 1] float32 if there is a
-    sink; k_hbm [B, C, kd], v_hbm [B, C, vd] where they are stored; out_ref
+    """``cache_step.fwd`` (:func:`_walk_blocks`: a row's blocks from 0 to
+    the one that holds its position). pos_ref [B] int32 (SMEM); q_ref [B,
+    heads, kd]: a row's queries laid out block-diagonally; own_ref [heads,
+    vd] float32: 1 in the value columns of a head's own group; then
+    sink_ref [heads, 1] float32 if there is a sink; k_hbm [B, C, kd], v_hbm
+    [B, C, vd] where they are stored; out_ref
     [B, r, vd] float32: head ``gi * r + ri`` in columns ``gi`` of row
     ``ri``; two slots of a block of keys and of values, their copy
     semaphores, and a row's running maximum, sum and accumulator."""
@@ -411,9 +485,6 @@ def _step_kernel(pos_ref, q_ref, own_ref, *refs, block, scale, groups,
     def last(row):          # the block that holds the row's own position
         return jnp.clip(pos_ref[row], 0, c - 1) // block
 
-    passes = jax.lax.fori_loop(
-        0, rows, lambda row, n: n + last(row) + 1, jnp.int32(0))
-
     def copies(row, j, slot):
         at = pl.ds(pl.multiple_of(j * block, block), block)
         return (pltpu.make_async_copy(k_hbm.at[row, at], k_buf.at[slot],
@@ -421,64 +492,37 @@ def _step_kernel(pos_ref, q_ref, own_ref, *refs, block, scale, groups,
                 pltpu.make_async_copy(v_hbm.at[row, at], v_buf.at[slot],
                                       sems.at[1, slot]))
 
-    for copy in copies(0, 0, 0):
-        copy.start()
+    def open_row():
+        if sink_ref is None:
+            top_ref[...] = jnp.full(top_ref.shape, _LOW, _F32)
+            total_ref[...] = jnp.zeros(total_ref.shape, _F32)
+        else:
+            # the sink opens the streaming softmax: one term of weight
+            # 1 at its own height, and no value
+            top_ref[...] = sink_ref[...]
+            total_ref[...] = jnp.ones(total_ref.shape, _F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
 
-    def one(i, carry):
-        row, j = carry
-        slot = jax.lax.rem(i, 2)
-        done = j == last(row)
-        next_row = jnp.where(done, row + 1, row)
-        next_j = jnp.where(done, 0, j + 1)
-
-        @pl.when(i + 1 < passes)
-        def _():
-            for copy in copies(next_row, next_j, 1 - slot):
-                copy.start()
-
-        @pl.when(j == 0)
-        def _():
-            if sink_ref is None:
-                top_ref[...] = jnp.full(top_ref.shape, _LOW, _F32)
-                total_ref[...] = jnp.zeros(total_ref.shape, _F32)
-            else:
-                # the sink opens the streaming softmax: one term of weight
-                # 1 at its own height, and no value
-                top_ref[...] = sink_ref[...]
-                total_ref[...] = jnp.ones(total_ref.shape, _F32)
-            acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
-
-        for copy in copies(row, j, slot):
-            copy.wait()
+    def products(row, j, slot):
         kb, vb = k_buf[slot], v_buf[slot]
         s = jax.lax.dot_general(q_ref[row], kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=_F32) * scale
         at = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        live = at <= pos_ref[row]
-        s = jnp.where(live, s, _LOW)
-        top = top_ref[...]
-        new_top = jnp.maximum(top, jnp.max(s, axis=-1, keepdims=True))
-        probs = jnp.where(live, jnp.exp(s - new_top), 0.0)
-        keep = jnp.exp(top - new_top)
-        top_ref[...] = new_top
-        total_ref[...] = total_ref[...] * keep \
-            + jnp.sum(probs, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * keep + jnp.dot(
-            probs.astype(vb.dtype), vb, preferred_element_type=_F32)
+        _stream(s, at <= pos_ref[row],
+                lambda probs: jnp.dot(probs.astype(vb.dtype), vb,
+                                      preferred_element_type=_F32),
+                top_ref, total_ref, acc_ref)
 
-        @pl.when(done)
-        def _():
-            y = acc_ref[...] / jnp.maximum(total_ref[...], 1e-30)
-            y = jnp.where(own_ref[...] != 0, y, 0.0)
-            if r == 1:
-                out_ref[row] = jnp.sum(y, axis=0, keepdims=True)
-            else:
-                out_ref[row] = functools.reduce(operator.add, (
-                    y[gi * r:(gi + 1) * r] for gi in range(groups)))
+    def close_row(row):
+        y = acc_ref[...] / jnp.maximum(total_ref[...], 1e-30)
+        y = jnp.where(own_ref[...] != 0, y, 0.0)
+        if r == 1:
+            out_ref[row] = jnp.sum(y, axis=0, keepdims=True)
+        else:
+            out_ref[row] = functools.reduce(operator.add, (
+                y[gi * r:(gi + 1) * r] for gi in range(groups)))
 
-        return next_row, next_j
-
-    jax.lax.fori_loop(0, passes, one, (jnp.int32(0), jnp.int32(0)))
+    _walk_blocks(rows, last, copies, open_row, products, close_row)
 
 
 @traced_once("cache_step.fwd", ("groups", "block", "vmem", "interpret"))
@@ -550,3 +594,198 @@ def step_blocks(q, k, v, pos, heads, kv_heads, sink=None, plain=False):
         out = jnp.transpose(out.reshape(b, r, g, dv), (0, 2, 1, 3))
         count = jnp.sum(jnp.clip(pos + 1, 0, c), dtype=jnp.int32).reshape(1)
     return out.reshape(b, g * r * dv).astype(q.dtype), count
+
+
+# ---------------------------------------------------------------------------
+# a latent step on one TPU: the kernel ``latent_step.fwd``
+# ---------------------------------------------------------------------------
+
+def _query_rows(lanes, heads, itemsize):
+    """A row's ``lanes * heads`` query rows, up to the sublane multiple its
+    type tiles by."""
+    return _up(lanes * heads, 32 // itemsize)
+
+
+def latent_plan(b, c, lanes, heads, width, r, itemsize, platform=None):
+    """Which way a ``latent_attention_dense`` site reads its cache, as a
+    ``GateDecision``: ``latent_step`` (the kernel: a row's blocks up to
+    the highest position its lanes hold) or ``rung_xla`` (the ``jnp`` form:
+    the whole rung under a mask, twice) with the blocking reasons. ``width``
+    / ``r``: of a cached row ``[latent | rotary key]`` and of its latent
+    part; ``itemsize`` and ``platform`` as :func:`step_plan`'s.
+
+    The kernel reads a block as the DEVICE stores it: a cache whose rows
+    are no multiple of 128 wide lies with the positions innermost there
+    (``[B, R+P, C]``: nothing is padded so), and a block is ``[R+P,
+    block]``. A cache of rows that are a multiple of 128 lies row-major;
+    read this way it would be turned round first, so it keeps the ``jnp``
+    form."""
+    reasons = []
+    if platform is not None:
+        reasons.append(platform)
+    if itemsize not in (2, 4):
+        reasons.append(GateReason(
+            "dtype", "queries and cache are not of one 2- or 4-byte "
+            "floating type"))
+    elif r % 128 or not r < width:
+        reasons.append(GateReason(
+            "geometry", "a latent part of %d columns of a row of %d is no "
+            "multiple of 128 under a rotary tail" % (r, width)))
+    elif width % 128 == 0 or width % (32 // itemsize):
+        reasons.append(GateReason(
+            "geometry", "a cache of rows of %d columns lies row-major on "
+            "the device (a multiple of 128), or its rows are no multiple of "
+            "%d sublanes: no block with the positions innermost"
+            % (width, 32 // itemsize)))
+    block = None
+    if not reasons:
+        queries = _query_rows(lanes, heads, itemsize)
+        block = step_block(c, width * itemsize)
+        if block is None:
+            reasons.append(GateReason(
+                "geometry", "a rung of %d positions is no longer than one "
+                "block of a multiple of 128" % c))
+        elif _working_set(b, block, queries, width, r, itemsize,
+                          own_values=False) > _VMEM_BUDGET:
+            reasons.append(GateReason(
+                "vmem", "two blocks of %d positions of %d wide rows beside "
+                "%d rows' %d queries exceed the %.0f MB VMEM budget"
+                % (block, width, b, queries, _VMEM_BUDGET / 2**20)))
+    if reasons:
+        return GateDecision(False, "rung_xla", fallback="latent_step",
+                            reasons=reasons)
+    return GateDecision(True, "latent_step", reasons=[GateReason(
+        "shape", "blocks of %d of %d positions, each row's up to its lanes' "
+        "highest" % (block, c), blocking=False)])
+
+
+def latent_plan_for(q, cache, r, heads):
+    """:func:`latent_plan` of a site's arrays (q [B, K, ..], cache [B, C,
+    R+P]), where the step being traced is placed."""
+    one = q.dtype == cache.dtype and jnp.issubdtype(cache.dtype, jnp.floating)
+    return latent_plan(cache.shape[0], cache.shape[1], q.shape[1], int(heads),
+                       cache.shape[2], int(r),
+                       cache.dtype.itemsize if one else None,
+                       platform=platform_reason(_INTERPRET))
+
+
+def _latent_kernel(pos_ref, q_ref, cache_hbm, out_ref, buf, sems, top_ref,
+                   total_ref, acc_ref, *, block, scale, lane_heads):
+    """``latent_step.fwd`` (:func:`_walk_blocks`: a row's blocks from 0 to
+    the one that holds the highest position its live lanes hold; a row of
+    pad lanes alone one block). The step kernel's case of ONE group whose
+    values are the first ``r`` columns of its keys, so one buffer and one
+    copy a block, with K lanes a row, so a position a query row. pos_ref [B
+    * K] int32 (SMEM), ``>= C``: a pad lane, which reaches nothing; q_ref
+    [B, M, R+P]: a row's K x ``lane_heads`` absorbed queries lane after
+    lane, zero rows up to M; cache_hbm [B, R+P, C]: the cache as the device
+    stores it, the positions innermost; out_ref [B, M, r] in the cache's
+    type: a query row's mixed latent; two slots of a block ``[R+P,
+    block]``, their copy semaphores, and a row's running maximum, sum and
+    accumulator."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, m, _ = q_ref.shape
+    c = cache_hbm.shape[2]
+    r = acc_ref.shape[1]
+    lanes = pos_ref.shape[0] // rows
+
+    def held(row):          # its lanes' positions, -1 for a pad lane
+        return [jnp.where(p < c, p, -1) for p in (
+            pos_ref[row * lanes + k] for k in range(lanes))]
+
+    def last(row):
+        return jnp.maximum(functools.reduce(jnp.maximum, held(row)),
+                           0) // block
+
+    def copies(row, j, slot):
+        at = pl.ds(pl.multiple_of(j * block, block), block)
+        return (pltpu.make_async_copy(cache_hbm.at[row, :, at], buf.at[slot],
+                                      sems.at[slot]),)
+
+    def open_row():
+        top_ref[...] = jnp.full(top_ref.shape, _LOW, _F32)
+        total_ref[...] = jnp.zeros(total_ref.shape, _F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
+
+    def products(row, j, slot):
+        s = jnp.dot(q_ref[row], buf[slot],
+                    preferred_element_type=_F32) * scale
+        head = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
+        ends = jnp.full((m, 1), -1, jnp.int32)      # a pad row: nothing
+        for k, p in enumerate(held(row)):
+            ends = jnp.where((head >= k * lane_heads)
+                             & (head < (k + 1) * lane_heads), p, ends)
+        at = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        _stream(s, at <= ends,
+                lambda probs: jax.lax.dot_general(
+                    probs.astype(buf.dtype), buf[slot, :r],
+                    (((1,), (1,)), ((), ())), preferred_element_type=_F32),
+                top_ref, total_ref, acc_ref)
+
+    def close_row(row):
+        y = acc_ref[...] / jnp.maximum(total_ref[...], 1e-30)
+        out_ref[row] = y.astype(out_ref.dtype)
+
+    _walk_blocks(rows, last, copies, open_row, products, close_row)
+
+
+@traced_once("latent_step.fwd", ("lane_heads", "r", "scale", "block", "vmem",
+                                 "interpret"))
+def _latent_impl(pos, queries, stored, lane_heads, r, scale, block, vmem,
+                 interpret):
+    """pos [B * K] int32; queries [B, M, R+P]; stored [B, R+P, C]. Returns
+    the mixed latent [B, M, r] in the cache's type
+    (:func:`_latent_kernel`)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, m, width = queries.shape
+    return named_pallas_call(
+        "latent_step.fwd",
+        functools.partial(_latent_kernel, block=block, scale=scale,
+                          lane_heads=lane_heads),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM((2, width, block), stored.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.VMEM((m, 1), _F32),
+                            pltpu.VMEM((m, 1), _F32),
+                            pltpu.VMEM((m, r), _F32)]),
+        out_shape=jax.ShapeDtypeStruct((b, m, r), stored.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem),
+        interpret=interpret,
+    )(pos, queries, stored)
+
+
+def latent_blocks(qa, cache, pos, r, scale):
+    """The core of ``sparse_latent.latent_attention_dense`` by the kernel
+    ``latent_step.fwd`` (what :func:`latent_plan_for` admits): qa [B, K, H,
+    R+P] a row's absorbed queries, cache [B, C, R+P] with the step's rows
+    written, pos [B, K] (``>= C``: a pad lane). Returns the mixed latent [B,
+    K, H, r] in qa's type, 0 on a pad lane: the ``jnp`` form's sums in
+    another order (a row's blocks one after the other under a running
+    maximum and sum, a block fetched once for the scores and for the mix,
+    the probabilities cast to the cache's type, one division at the end).
+    A row reads the blocks up to the one that holds the highest position
+    of its live lanes, a row of pad lanes alone one block.
+
+    The kernel is handed the cache with its two last axes exchanged: on
+    the device that IS the cache where it lies (:func:`latent_plan`), and
+    the compiler makes no copy of it."""
+    b, kq, heads, width = qa.shape
+    c = cache.shape[1]
+    m = _query_rows(kq, heads, cache.dtype.itemsize)
+    queries = jnp.pad(qa.reshape(b, kq * heads, width),
+                      ((0, 0), (0, m - kq * heads), (0, 0)))
+    mixed = _latent_impl(
+        pos.reshape(-1).astype(jnp.int32), queries,
+        jnp.swapaxes(cache, 1, 2), lane_heads=heads, r=int(r),
+        scale=float(scale), block=step_block(c, width * cache.dtype.itemsize),
+        vmem=_VMEM_BUDGET, interpret=_INTERPRET)
+    return mixed[:, :kq * heads].reshape(b, kq, heads, r)

@@ -38,8 +38,13 @@ and a row early in its prompt reads only what is cached.
 **Without an indexer** (a model whose attention reads the whole latent
 cache) nothing selects: :func:`latent_attention_chunk` with no mask lets a
 lane read every position up to its own, and a step is
-:func:`latent_attention_dense`, a few queries a row against the row's whole
-cache under that same causal rule, no index and no gather.
+:func:`latent_attention_dense`, a few queries a row against every LIVE
+position of the row's cache under that same causal rule, no index and no
+gather. On one TPU its core is the Pallas kernel ``latent_step.fwd``
+(``cache_attention.latent_blocks``), which reads a row's cache in blocks up
+to the highest position its lanes hold; the ``jnp`` form here, which scores
+the whole rung under a mask, is what the CPU, a mesh and a shape the gate
+refuses run, and what the kernel is tested against.
 """
 
 import jax
@@ -218,16 +223,26 @@ def latent_attention(q, kv_b, cache, index, heads, nope, v_dim, scale):
     return out.reshape(b, heads * v_dim).astype(q.dtype)
 
 
-def latent_attention_dense(q, kv_b, cache, pos, heads, nope, v_dim, scale):
+def latent_attention_dense(q, kv_b, cache, pos, heads, nope, v_dim, scale,
+                           plan=None):
     """A step's one or two queries a row over EVERY live position of the
     row's cache: no index, no gather. q [B, K, H*(N+P)] (rotary applied),
     kv_b [R, H*(N+V)], cache [B, C, R+P] (the step's rows already written),
     pos [B, K]: lane k reads the positions ``<= pos[b, k]`` (``>= C``: a pad
-    lane; its output is 0). Returns [B, K, H*V] in q's dtype. The whole
-    capacity is scored and masked, so K stays small: a chunk's lanes go
-    through :func:`latent_attention_chunk`.
+    lane; its output is 0). Returns [B, K, H*V] in q's dtype. K stays small
+    (a row's K x H queries are held at once): a chunk's lanes go through
+    :func:`latent_attention_chunk`.
 
-    The cache is read AS IT IS STORED, ``[C, R+P]`` a row: the scores are
+    ``plan`` (``cache_attention.latent_plan_for``'s decision, admitted): the
+    core runs as the kernel ``latent_step.fwd``
+    (``cache_attention.latent_blocks``), a row's blocks of positions up to
+    the highest its lanes hold under a streaming softmax, a block fetched
+    once for the scores and the mix. Without it the ``jnp`` form: the whole
+    capacity is scored and masked, twice over the cache (scores, then the
+    mix). Absorb and expand are the same XLA either way.
+
+    The ``jnp`` form reads the cache AS IT IS STORED, ``[C, R+P]`` a row:
+    the scores are
     the plain product of a row's cache with its K x H absorbed queries laid
     side by side (``[R+P, K*H]``), the mix the plain product of the
     probabilities ``[K*H, C]`` with the cache, of which the latent's R
@@ -246,20 +261,26 @@ def latent_attention_dense(q, kv_b, cache, pos, heads, nope, v_dim, scale):
     with jax.named_scope("latent_attention"):
         with jax.named_scope("latent_attention.absorb"):
             qa = _absorb(q.reshape(b, kq, heads, -1), w_uk, nope)
-            side = qa.reshape(b, kq * heads, width).transpose(0, 2, 1)
         with jax.named_scope("latent_attention.core"):
-            s = jnp.einsum("bsw,bwm->bms", cache, side,
-                           preferred_element_type=_F32) * scale
-            reach = jnp.where(pos < c, pos, -1)   # a pad lane reaches nothing
-            member = jnp.repeat(
-                jnp.arange(c, dtype=jnp.int32)[None, None, :]
-                <= reach[:, :, None], heads, axis=1)         # [B, K*H, C]
-            s = jnp.where(member, s, jnp.finfo(_F32).min)
-            probs = jnp.where(member, jax.nn.softmax(s, axis=-1),
-                              0.0).astype(q.dtype)
-            mixed = jnp.einsum("bms,bsw->bmw", probs, cache,
-                               preferred_element_type=_F32)[..., :r]
-            mixed = mixed.reshape(b, kq, heads, r).astype(q.dtype)
+            if plan:
+                from .cache_attention import latent_blocks
+
+                mixed = latent_blocks(qa, cache, pos, r, scale)
+            else:
+                side = qa.reshape(b, kq * heads, width).transpose(0, 2, 1)
+                s = jnp.einsum("bsw,bwm->bms", cache, side,
+                               preferred_element_type=_F32) * scale
+                # a pad lane reaches nothing
+                reach = jnp.where(pos < c, pos, -1)
+                member = jnp.repeat(
+                    jnp.arange(c, dtype=jnp.int32)[None, None, :]
+                    <= reach[:, :, None], heads, axis=1)     # [B, K*H, C]
+                s = jnp.where(member, s, jnp.finfo(_F32).min)
+                probs = jnp.where(member, jax.nn.softmax(s, axis=-1),
+                                  0.0).astype(q.dtype)
+                mixed = jnp.einsum("bms,bsw->bmw", probs, cache,
+                                   preferred_element_type=_F32)[..., :r]
+                mixed = mixed.reshape(b, kq, heads, r).astype(q.dtype)
         with jax.named_scope("latent_attention.expand"):
             out = jnp.einsum("bkhr,rhv->bkhv", mixed, w_uv,
                              preferred_element_type=_F32)

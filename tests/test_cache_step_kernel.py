@@ -273,3 +273,47 @@ def test_the_block_follows_the_rung_and_the_rows_widths():
                             jnp.zeros((2, 1280, 2048), BF16),
                             jnp.zeros((2, 1280, 2048), BF16), 32, 32)
     assert mixed.blocked_only_by("dtype")
+
+
+# What ``step_blocks`` traces to, the kernel's body with it, as equations
+# by primitive (sub-programs included): (equations in all, then the ones a
+# step kernel is made of). PR 43's tree traces to the same counts: the loop
+# and the streaming softmax are shared with ``latent_step.fwd`` since PR 44
+# (``_walk_blocks``, ``_stream``), and ``cached_attention``'s
+# instantiations trace to what they traced to. A PR that changes this
+# kernel on purpose records new counts.
+_STEP_PARTS = {"pallas_call": 1, "scan": 1, "while": 1, "cond": 3,
+               "dma_start": 4, "dma_wait": 2, "dot_general": 2, "exp": 2,
+               "reduce_max": 1, "swap": 7}
+STEP_JAXPRS = {
+    "opt": (OPT, False, 158, dict(_STEP_PARTS, get=12, reduce_sum=3)),
+    "mimo_full": (MIMO_FULL, False, 163,
+                  dict(_STEP_PARTS, get=12, reduce_sum=2, slice=4)),
+    "mimo_full_sink": (MIMO_FULL, True, 165,
+                       dict(_STEP_PARTS, get=13, reduce_sum=2, slice=4)),
+}
+
+
+def _primitives(jaxpr, counts):
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] = counts.get(eqn.primitive.name, 0) + 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, counts)
+    return counts
+
+
+@pytest.mark.parametrize("case", list(STEP_JAXPRS))
+def test_the_shared_loop_traces_to_the_kernel_as_it_was(compiled_mode, case):
+    (b, c, heads, g, dk, dv), sink, equations, parts = STEP_JAXPRS[case]
+    args = (jax.ShapeDtypeStruct((b, heads * dk), BF16),
+            jax.ShapeDtypeStruct((b, c, g * dk), BF16),
+            jax.ShapeDtypeStruct((b, c, g * dv), BF16),
+            jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((heads,), BF16) if sink else None)
+    counts = _primitives(jax.make_jaxpr(lambda q, k, v, pos, s: ca.step_blocks(
+        q, k, v, pos, heads, g, s))(*args).jaxpr, {})
+    assert {name: counts.get(name, 0) for name in parts} == parts
+    assert sum(counts.values()) == equations

@@ -110,10 +110,10 @@ def _weights(leaves, scope):
     return weights
 
 
-def _batcher(predictors, specs, step=None):
+def _batcher(predictors, specs, step=None, context=64):
     return DecodeBatcher(
         step or predictors["step"], specs["step"], ladder=(2,),
-        ctx_ladder=(64,), start=False,
+        ctx_ladder=(context,), start=False,
         prefill={"predictor": predictors["chunk"], "spec": specs["chunk"],
                  "ladder": (8, 16)})
 
@@ -188,6 +188,47 @@ def test_chunks_then_steps_give_the_references_logits(served):
     layers_ = 3
     assert 0 < counters["moe_experts_touched"] <= len(steps) * layers_ * 8
     assert 0 < counters["moe_rows_held"] <= counters["moe_rows_run"]
+
+
+# the twin with a latent row the step kernel takes (a latent of 128 under a
+# rotary tail of 64, as the published 512 + 64) over a rung of two blocks
+WIDE = dict(TINY, kv_lora_rank=128, qk_rope_head_dim=64,
+            max_position_embeddings=256)
+
+
+@pytest.mark.parametrize("path", ["latent_step", "rung_xla"])
+def test_steps_give_the_references_logits_by_the_kernel_and_by_the_rung_form(
+        path, monkeypatch):
+    """A request whose verifying steps cross the border of a block of 128:
+    through the step kernel where its gate admits the site (the interpreter
+    stands in for the TPU here) and through the ``jnp`` form where it does
+    not (the CPU), the same rows of the reference."""
+    from paddle_tpu.ops import cache_attention
+
+    monkeypatch.setattr(cache_attention, "_INTERPRET", path == "latent_step")
+    jax.clear_caches()
+    predictors, specs, leaves, scope = _build(WIDE)
+    weights = _weights(leaves, scope)
+    step = Recorded(predictors["step"], specs["step"])
+    batcher = _batcher(predictors, specs, step, context=256)
+    prompt = np.random.default_rng(2).integers(0, VOCAB, size=121)
+    future = batcher.submit(prompt, max_new_tokens=ANSWER)
+    batcher.drive()
+    tokens = np.asarray(future.result())
+    jax.clear_caches()
+    for kind, sites in (("step", 4), ("chunk", 0)):
+        took = [op.attrs["_kernel_choice"]["kernel"]
+                for op in predictors[kind]._program.global_block().ops
+                if op.type == "latent_attention_dense"]
+        assert took == [path] * sites
+    full = np.concatenate([prompt, tokens])
+    rows = np.asarray(REFERENCE.logits(weights, full, WIDE, EXACT))
+    walked = _walk(prompt, tokens, [tuple(a[0] for a in run)
+                                    for run in step.runs])
+    assert walked[0][0] < 127 < walked[-1][0] + 1     # over the border
+    for p, _, _, _, gave, logits, _ in walked:
+        np.testing.assert_allclose(logits[0], rows[p], **TOL)
+        assert gave[1] == np.argmax(rows[p])
 
 
 def test_the_modules_drafts_are_the_references(served):

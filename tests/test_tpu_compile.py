@@ -358,6 +358,46 @@ def test_cache_step_compiles_within_the_vmem_the_gate_counts(
                          compiled.as_text())
 
 
+def test_latent_step_compiles_within_the_vmem_the_gate_counts(
+        chip, monkeypatch):
+    """The step kernel behind ``latent_attention_dense`` at
+    ``glm47flash.serve.reason.sat``'s own shape (32 rows x 4096 positions of
+    512 + 64, two lanes of 20 heads), ``vmem_limit_bytes`` set to the
+    working set its gate counts: the gate admits the shape, Mosaic needs no
+    more than is counted, the kernel is there by name, and the cache is
+    neither copied nor turned on its way in: the device keeps it with the
+    positions innermost, and that is what the kernel is handed."""
+    from paddle_tpu.ops import cache_attention as ca
+    from paddle_tpu.ops import sparse_latent
+
+    b, c, lanes, heads, r, p, nope, v = 32, 4096, 2, 20, 512, 64, 192, 256
+    q, cache = (sds((b, lanes, heads * (nope + p)), BF16),
+                sds((b, c, r + p), BF16))
+    with placed("tpu"):
+        plan = ca.latent_plan_for(q, cache, r, heads)
+    assert plan.kernel == "latent_step", plan
+    block = ca.step_block(c, 2 * (r + p))
+    counted = ca._working_set(b, block, ca._query_rows(lanes, heads, 2),
+                              r + p, r, 2, own_values=False)
+    assert block == 512 and counted <= ca._VMEM_BUDGET
+    monkeypatch.setattr(ca, "_VMEM_BUDGET", counted)
+
+    def step(q, kv_b, cache, pos):
+        return sparse_latent.latent_attention_dense(
+            q, kv_b, cache, pos, heads, nope, v, (nope + p) ** -0.5,
+            plan=plan)
+
+    compiled = _compile(chip, step, q, sds((r, heads * (nope + v)), BF16),
+                        cache, sds((b, lanes), I32))
+    _assert_named(compiled, {"latent_step.fwd"})
+    assert "latent_attention.core" in dict(
+        (re.sub(r"\.\d+$", "", n), path)
+        for n, path in _kernel_names(compiled))["latent_step.fwd"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.02e9
+    assert not re.search(r"= bf16\[%d,(%d,%d|%d,%d)\]\S* (copy|transpose)\("
+                         % (b, c, r + p, r + p, c), compiled.as_text())
+
+
 def test_mimo_v2_step_reads_its_weights_where_they_lie_and_fits_the_chip(
         chip):
     """The cell's step program, built from the configuration's own keys
@@ -483,7 +523,20 @@ def test_glm_lite_programs_fit_the_chip_beside_every_expert(chip, kind):
     # turned each of the eight caches round twice: 2.4 GB of temporaries)
     assert mem.temp_size_in_bytes < 0.6e9
     assert 0.6 * _HBM_BYTES < need < 0.8 * _HBM_BYTES
+    # a step's eight latent sites (seven layers and the module's) read
+    # their caches by the step kernel, one body traced for all; a chunk's
+    # lanes keep the blocks of ``latent_attention_chunk``
+    dense = [op.attrs["_kernel_choice"]["kernel"] for op in gb.ops
+             if op.type == "latent_attention_dense"]
+    assert _step_kernel_names(compiled).get("latent_step.fwd", 0) == \
+        len(dense) == (8 if kind == "step" else 0)
+    assert set(dense) <= {"latent_step"}
     if kind == "step":
+        # and none is turned or copied for the kernel: the four copies of a
+        # cache where it lies that the step made before are all there are
+        assert len(re.findall(r"= bf16\[%d,(?:%d,576|576,%d)\]\S* (?:copy|"
+                              r"transpose)\(" % (rows, c, c),
+                              compiled.as_text())) <= 4
         # a step's two-lane writes run under the step write's own scope, as
         # the chip's compiler leaves it: what ``cache_write_ms`` looks up
         from benchmark import trace_reduce
@@ -492,6 +545,52 @@ def test_glm_lite_programs_fit_the_chip_beside_every_expert(chip, kind):
         found = [s for s in trace_reduce.hlo_scopes(
             compiled.as_text()).values() if wanted.search(s)]
         assert found, "no instruction under kv_cache_write"
+
+
+def test_glm52_step_reads_a_selection_and_names_no_latent_step_kernel():
+    """``glm52.serve.longdoc.sat``'s step, traced as an Executor on one TPU
+    would trace it (nothing lowered): its latent attention reads an index
+    and a gather (op ``latent_attention``), so no site asks the
+    ``latent_attention_dense`` gate and no ``latent_step.fwd`` body is
+    traced."""
+    import json
+
+    import paddle_tpu as fluid
+    from paddle_tpu.core.executor import build_step_fn
+    from paddle_tpu.models import glm_dsa
+    from paddle_tpu.ops import gates
+    from paddle_tpu.ops.kernel_names import collect_traces, tally_traces
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "glm-5.2.json")) as f:
+        body = json.load(f)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        fetch, spec = glm_dsa.glm_dsa_step(
+            dtype="bfloat16", **{k: body[k] for k in body["builder_keys"]})
+    gb = main.global_block()
+    persist = sorted({v.name for v in main.list_vars() if v.persistable})
+    state = {n: sds(tuple(gb.var(n).shape),
+                    BF16 if gb.var(n).dtype == "bfloat16"
+                    else np.dtype(gb.var(n).dtype)) for n in persist}
+    b, c = 8, 12288
+    feed = {spec["token_feed"]: sds((b,), I32),
+            spec["pos_feed"]: sds((b,), I32)}
+    for cf in spec["cache_feeds"]:
+        feed[cf["feed"]] = sds((b, c) + tuple(cf["tail"]),
+                               BF16 if cf["dtype"] == "bfloat16"
+                               else np.dtype(cf["dtype"]))
+    rng = jax.eval_shape(lambda: jax.random.key(0, impl="rbg"))
+    step = build_step_fn(main, [v.name for v in fetch], persist,
+                         infer_only=True)
+    with placed("tpu"), gates.collect() as met, collect_traces() as bodies:
+        jax.jit(step).trace(state, feed, rng)
+    kinds = {op.type for op in gb.ops}
+    assert "latent_attention" in kinds
+    assert "latent_attention_dense" not in kinds
+    assert "latent_attention_dense" not in gates.tally(met)
+    assert "latent_step.fwd" not in tally_traces(bodies)
 
 
 def test_state_space_core_and_latent_experts_fit_at_published_widths(chip):
