@@ -75,7 +75,8 @@ def sizes(rehearse):
     if not rehearse:
         return {
             # transformer_base() at its defaults: d_model 512, d_ff 2048,
-            # 8 heads, 6+6 layers, vocab 30000 — bench.py's "transformer"
+            # 8 heads, 6+6 layers, vocab 30000: models.baseline's
+            # "transformer"
             "train": dict(model_kw={}, seq_len=256, batch=128, steps=5),
             "flash": [
                 # name, B, T, H*D, heads, causal, key bias
@@ -124,7 +125,7 @@ def sizes(rehearse):
                 dict(form="swiglu", t=8192, d_model=2048, latent=0, f=512,
                      experts=512, held=16, top_k=10, score="softmax",
                      scale=1.0)],
-            # bench.py's on-TPU widths and batches. Depth is cut to 2
+            # models.baseline's on-chip widths and batches. Depth is cut to 2
             # layers where a layer repeats (BERT 12, seq-2048 6+6): the
             # host that compiles for the chip is shared and slow, the
             # full-depth steps alone took 540 s of this script's 1200, and
@@ -253,8 +254,8 @@ def tally(names):
 
 
 def build_train_program(fluid, build, seed):
-    """A training program the way ``bench._bench_static`` builds it: the
-    model, then Adam(1e-4) under ``fluid.amp.decorate``."""
+    """A training program: the model, then Adam(1e-4) under
+    ``fluid.amp.decorate``."""
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = startup.random_seed = seed
     fluid.unique_name.switch()
@@ -279,8 +280,8 @@ def run_steps(ctx, main, startup, spec, batch, steps, program=None):
         exe.run(startup)
         log("startup program ran in %.1fs" % (time.time() - t0))
         feed = spec.sample_batch(batch, np.random.RandomState(ctx["seed"]))
-        # staged once, as bench.py does: the loop must not re-ship the
-        # batch over the host link every step
+        # staged once: the loop must not re-ship the batch over the host
+        # link every step
         feed = {k: jax.device_put(v) for k, v in feed.items()}
         losses = []
         t0 = time.time()

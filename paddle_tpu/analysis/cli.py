@@ -10,7 +10,7 @@ diagnostic surface).
     # a subset, without the optimizer/backward section
     python -m paddle_tpu.analysis --zoo mnist.mlp transformer --no-train
     # static roofline estimates (flops / HBM bytes / floor ms at the
-    # committed ceilings): per zoo model, or the 6 BASELINE bench configs
+    # cost engine's constants): per zoo model, or the 6 BASELINE configs
     python -m paddle_tpu.analysis --cost --zoo deepfm
     python -m paddle_tpu.analysis --cost --baseline
     # static SPMD pass on the transpiled DeepFM: sharding propagation,
@@ -138,54 +138,38 @@ def analyze_zoo_model(builder, train=True, with_cost=False):
     return out
 
 
-# the 6 BASELINE model configs (bench.py's matrix); bert_dygraph is
-# estimated on the static-equivalent program (same architecture — the
-# dygraph build has no Program IR to walk)
+# the 6 BASELINE model configs; bert_dygraph is estimated on the
+# static-equivalent program (same architecture — the dygraph build has no
+# Program IR to walk)
 BASELINE_CONFIGS = ("deepfm", "seq2048", "resnet50", "bert_dygraph",
                     "bert", "transformer")
 
 
-def _load_bench():
-    """Import the repo-root bench.py (the single source of the BASELINE
-    build configs) regardless of cwd."""
-    import importlib.util
-    import os
-
-    from .cost import repo_root
-
-    path = os.path.join(repo_root(), "bench.py")
-    spec = importlib.util.spec_from_file_location("_pt_bench", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def baseline_cost_records(names=None, on_tpu=True):
-    """Static roofline estimates for the BASELINE bench configs (ISSUE
-    15 acceptance: the cost engine covers all 6). Builds each config's
-    Program through ``bench._build`` — the SAME shapes the bench
-    measures — and prices it with ``estimate_program``; no execution, no
-    trace. Returns one record dict per config."""
+def baseline_cost_records(names=None, small=False):
+    """Static roofline estimates for the BASELINE configs (ISSUE 15
+    acceptance: the cost engine covers all 6). Builds each config's
+    Program through ``models.baseline`` and prices it with
+    ``estimate_program``; no execution, no trace. Returns one record dict
+    per config."""
     import paddle_tpu as fluid
+    from paddle_tpu import models
 
     from .cost import estimate_program
 
-    bench = _load_bench()
     records = []
     for name in names or BASELINE_CONFIGS:
         model = {"seq2048": "transformer",
                  "bert_dygraph": "bert"}.get(name, name)
-        seq_override = 2048 if name == "seq2048" else None
         main, startup = fluid.Program(), fluid.Program()
         with fluid.program_guard(main, startup):
             fluid.unique_name.switch()
-            spec, batch, metric, unit, per_example, seq = bench._build(
-                model, on_tpu, seq_override=seq_override)
+            spec, batch = models.baseline(
+                model, small=small,
+                seq_len=2048 if name == "seq2048" else None)
             fluid.optimizer.Adam(learning_rate=1e-4).minimize(spec.loss)
         est = estimate_program(main, batch=batch, amp=True)
         rec = dict(est.roofline())
-        rec.update(config=name, metric=metric, batch=batch, seq_len=seq,
-                   per_example=per_example)
+        rec.update(config=name, batch=batch)
         if name == "bert_dygraph":
             rec["note"] = ("static-equivalent program: the dygraph build "
                            "shares the architecture but has no Program "
@@ -379,11 +363,12 @@ def main(argv=None):
                     help="build a known-bad program and show its diagnostic")
     ap.add_argument("--cost", action="store_true",
                     help="print static roofline estimates (flops / HBM "
-                    "bytes / floor ms at the committed ceilings) for the "
-                    "selected zoo models / --baseline configs / model dir")
+                    "bytes / floor ms at the cost engine's constants) for "
+                    "the selected zoo models / --baseline configs / model "
+                    "dir")
     ap.add_argument("--baseline", action="store_true",
-                    help="with --cost: estimate the 6 BASELINE bench "
-                    "configs at their bench shapes")
+                    help="with --cost: estimate the 6 BASELINE "
+                    "configs at their on-chip shapes")
     ap.add_argument("--comm", action="store_true",
                     help="static SPMD pass on the transpiled DeepFM: "
                     "sharding lint, per-collective ICI volumes, "
@@ -433,7 +418,7 @@ def main(argv=None):
     if args.cost and args.baseline:
         for rec in baseline_cost_records():
             out = {k: rec[k] for k in
-                   ("config", "metric", "batch", "seq_len", "flops",
+                   ("config", "batch", "flops",
                     "hbm_bytes", "t_compute_s", "t_hbm_s", "t_row_s",
                     "roofline_s", "bound", "ceilings", "uncosted_ops")}
             print(json.dumps(out))

@@ -1,130 +1,53 @@
 """Static cost / roofline engine over the Program IR (ISSUE 15).
 
-The bytes/FLOP models that justify every BASELINE number used to be
-ad-hoc and scattered (``tools/attribute_resnet.py``'s floors,
-``models/deepfm.py``'s row-latency + comm models). This module is the
-single model they all delegate to: per-op cost rules registered beside
+One bytes/FLOP model of a program: per-op cost rules registered beside
 the shape rules (``core/op_registry.register_cost``, rules in
 ``core/opimpl/cost_rules.py``) roll up into a per-program
 :class:`CostEstimate`, and :meth:`CostEstimate.roofline` prices it at
-the MEASURED chip ceilings sourced live from ``CHIP_CEILING.json`` /
-``ROW_OP_FLOORS.json`` (the committed re-derivation records — a
-bench-chip re-measurement changes every estimate, no constant is ever
-hardcoded twice).
+the four constants below. They are round-5 readings of an installation
+that is gone, not the benchmark's peaks (``benchmark/peaks.json``), and
+no chip run has confirmed an estimate since: a lint, not a measurement
+(``ROADMAP.md`` D8).
 
-Modeling stance — a FLOOR model, exactly the stance the committed
-per-bucket rooflines take (``RESNET_ROOFLINE.json``'s note): each op is
-charged its *minimum achievable* HBM traffic under ideal XLA fusion, so
+Modeling stance — a FLOOR model: each op is charged its *minimum
+achievable* HBM traffic under ideal XLA fusion, so
 activations/casts/reductions that ride a producer's epilogue charge
 zero bytes, while genuinely irreducible passes (conv operand streams,
 residual merges reading a distant tensor, transposes, optimizer state
 passes, pooling) charge theirs. Embedding-bound ops are charged in
-ROWS, not bytes (TPU row ops are latency-bound — ``ROW_OP_FLOORS``),
-and the roofline adds the row term on top of max(compute, HBM), which
-is how the DeepFM floor has always been built.
+ROWS, not bytes (TPU row ops are latency-bound), and the roofline adds
+the row term on top of max(compute, HBM).
 
 The reference's analog is the inference-analysis pass tier
 (``paddle/fluid/inference/analysis``) — graph-level passes computing
 static properties before deployment; here the property is the roofline.
 """
 
-import json
-import os
-
 import numpy as np
 
 from ..core.op_registry import cost_rule
 
 __all__ = ["CostCtx", "OpCost", "CostEstimate", "estimate_program",
-           "chip_ceilings", "row_op_floors", "comm_bytes_model",
-           "repo_root"]
+           "comm_bytes_model"]
 
 # ops whose backward is replayed from an op-list attr (never walked as
 # region ops for cost; the engine charges their fwd_ops' bwd columns)
 _REPLAY_OPS = ("autodiff", "autodiff_vjp")
 
 
-def repo_root():
-    """The directory holding the committed measurement records
-    (CHIP_CEILING.json / ROW_OP_FLOORS.json, beside bench.py)."""
-    return os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-
-
-def chip_ceilings(path=None):
-    """The committed bench-chip ceiling record (``CHIP_CEILING.json``).
-    Floor constants are SOURCED from it, never hardcoded — a
-    ``tools/chip_ceiling.py`` re-derivation run propagates into every
-    subsequent estimate. Empty dict when absent."""
-    if path is None:
-        path = os.path.join(repo_root(), "CHIP_CEILING.json")
-    try:
-        with open(path) as f:
-            rec = json.load(f)
-        return rec if isinstance(rec, dict) else {}
-    except (OSError, ValueError):
-        return {}
-
-
-# last-resort constants when no committed record exists (the round-5
-# v5e measurements; a present record always wins)
-_FALLBACK_MM_TFLOPS = 185.3
-_FALLBACK_HBM_GBS = 552.2
-_FALLBACK_GATHER_NS = 2.0
-_FALLBACK_SCATTER_NS = 15.0
-
-
-def operative_rates(ceil=None):
-    """(matmul_flops_per_s, hbm_bytes_per_s, source) from the committed
-    ceiling record, with the legacy fallbacks when absent. ``source``
-    reflects the keys actually READ: a committed-negative-result record
-    whose rate entries are null (the pending-bench-run form) is honestly
-    labeled as using the builtin constants — never as measured."""
-    if ceil is None:
-        ceil = chip_ceilings()
-    mm_v = ceil.get("bf16_matmul_tflops")
-    hbm_v = ceil.get("hbm_operative_gbs") or ceil.get("hbm_stream_gbs")
-    mm = (mm_v or _FALLBACK_MM_TFLOPS) * 1e12
-    hbm = (hbm_v or _FALLBACK_HBM_GBS) * 1e9
-    if mm_v and hbm_v:
-        src = "CHIP_CEILING.json"
-    elif mm_v or hbm_v:
-        src = "CHIP_CEILING.json+builtin-r5"
-    else:
-        src = "builtin-r5"
-    return mm, hbm, src
-
-
-def row_op_floors(path=None, fallback=None, fallback_source="builtin-r5"):
-    """(gather_ns_per_row, scatter_ns_per_row, source): the measured
-    per-row latencies from ``ROW_OP_FLOORS.json`` beside bench.py,
-    falling back to ``fallback`` (default: the round-5 constants) with
-    ``source`` saying so. This is THE reader — ``models/deepfm.py``
-    delegates here, so the bench floor and the static estimate can never
-    read different constants."""
-    if path is None:
-        path = os.path.join(repo_root(), "ROW_OP_FLOORS.json")
-    if fallback is None:
-        fallback = (_FALLBACK_GATHER_NS, _FALLBACK_SCATTER_NS)
-    try:
-        with open(path) as f:
-            rec = json.load(f)
-        if isinstance(rec, dict):
-            gather = rec.get("gather_ns_per_row")
-            scatter = rec.get("scatter_ns_per_row")
-            if gather and scatter:
-                return float(gather), float(scatter), "ROW_OP_FLOORS.json"
-    except (OSError, ValueError, TypeError):
-        pass
-    return fallback[0], fallback[1], fallback_source
+# what roofline() prices at, and the label they ride under in its dict
+_MM_TFLOPS = 185.3
+_HBM_GBS = 552.2
+_GATHER_NS = 2.0
+_SCATTER_NS = 15.0
+_CEILING_SOURCE = ("round-5 v5e readings of an installation that is gone; "
+                  "not benchmark/peaks.json")
 
 
 def comm_bytes_model(n_ids, width, n_shards, esize=4):
     """Analytic per-step ICI bytes of both sharded-lookup formulations
-    (the DeepFM bench record's honesty line — re-derivable, not
-    measured). Moved here from ``parallel/sharded_embedding.py`` so the
-    bench line, the SPMD pass's per-collective volumes, and the roofline
-    all read ONE model.
+    (re-derivable, not measured): the ONE model the SPMD pass's
+    per-collective volumes and ``parallel/sharded_embedding.py`` read.
 
     psum: every shard contributes a FULL [n, D] partial; the reduction
     combines mp of them (total reduced volume mp*n*D*e; per-link on a
@@ -300,24 +223,14 @@ class CostEstimate:
                                if r.bwd_counted else 0))
         return out
 
-    def roofline(self, peak_flops=None, hbm_bytes_per_s=None,
-                 row_floors=None):
-        """Price the rollup at the committed chip ceilings: the step's
-        static floor is ``max(compute, HBM)`` overlapped, plus the
-        row-latency term on top (row DMAs serialize behind the streams —
-        the DeepFM floor construction). Every constant's source rides in
-        the dict so the estimate is re-derivable."""
-        ceil = chip_ceilings()
-        mm, hbm, ceil_src = operative_rates(ceil)
-        if peak_flops:
-            mm = peak_flops
-            ceil_src = "caller-override"
-        if hbm_bytes_per_s:
-            hbm = hbm_bytes_per_s
-            ceil_src = "caller-override"
-        if row_floors is None:
-            row_floors = row_op_floors()
-        g_ns, s_ns, row_src = row_floors
+    def roofline(self):
+        """Price the rollup at the module's constants: the step's static
+        floor is ``max(compute, HBM)`` overlapped, plus the row-latency
+        term on top (row DMAs serialize behind the streams). The
+        constants and what they are ride in the dict, so the estimate is
+        re-derivable."""
+        mm, hbm = _MM_TFLOPS * 1e12, _HBM_GBS * 1e9
+        g_ns, s_ns = _GATHER_NS, _SCATTER_NS
         t_c = self.flops / mm
         t_b = self.hbm_bytes / hbm
         t_r = (self.row_reads * g_ns + self.row_writes * s_ns) * 1e-9
@@ -340,7 +253,7 @@ class CostEstimate:
             "ceilings": {
                 "matmul_flops": mm, "hbm_bytes_per_s": hbm,
                 "gather_ns_per_row": g_ns, "scatter_ns_per_row": s_ns,
-                "source": ceil_src, "row_source": row_src},
+                "source": _CEILING_SOURCE},
             "uncosted_ops": self.uncosted,
             "unresolved_ops": sorted({r.op.type for r in self.unresolved}),
         }

@@ -11,7 +11,7 @@ not a correctness property of the program):
     ``ops/flash_attention.kernel_plan``, ``ops/gated_delta.kernel_plan``)
     evaluated SHAPE-ONLY
     (``static_only`` / no ``platform``): a program that will
-    silently fall off its fused kernel on the bench chip is reported at
+    silently fall off its fused kernel on the chip is reported at
     build time as a finding with op provenance and the gate's structured
     reasons, instead of a quiet perf cliff.
   * **recompile-hazard** — an op output with an unknown (-1) dim in a
@@ -50,7 +50,7 @@ def _gate_diag(op, decision, region, wanted):
 
 def check_vmem_gates(region, batch=None, amp=False, diags=None):
     """Evaluate every Pallas-family op's admission gate statically
-    (shape/VMEM checks only — platform checks assume the bench chip).
+    (shape/VMEM checks only — platform checks assume the chip).
     Findings:
 
       * ``fused_conv2d`` refused for ANY static reason — the epilogue
@@ -145,7 +145,7 @@ def _check_gated_delta(ctx, op, region, diags):
 def _check_sparse_table(ctx, op, region, diags):
     """The table this lookup's backward scatter-adds into: report when
     the ONLY thing keeping it off the VMEM-resident Pallas scatter is
-    the budget (the DeepFM [100k, 32] class — NOTES_r7 §2)."""
+    the budget (the DeepFM [100k, 32] class)."""
     from ..ops import scatter as scatter_mod
 
     ws = ctx.shape(op.input("W"))

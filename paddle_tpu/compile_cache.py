@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 Every step program is one XLA executable that takes seconds to minutes to
-compile, and a fresh process (a bench run, ``chip_smoke.py``, a respawned
+compile, and a fresh process (a benchmark run, ``chip_smoke.py``, a respawned
 serving worker) would otherwise compile all of them again. The directory is
 part of the cache key's environment, so it must not move between runs:
 

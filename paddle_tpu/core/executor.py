@@ -204,7 +204,7 @@ def build_step_fn(program, fetch_names, persist_names, pp_cfg=None,
                   grad_scale=None, infer_only=False):
     """Trace a program's global block into one pure function
     ``(state, feed, rng) -> (fetches, new_state, rng')`` — the unit the
-    Executor jits, ``__graft_entry__`` exposes, and bench.py times.
+    Executor jits and ``__graft_entry__`` exposes.
     ``pp_cfg`` routes the autodiff replay through the pipeline engine
     (see ``parallel/pipeline.py``). ``infer_only`` narrows ``new_state``
     to persistables some op actually writes: an inference program then

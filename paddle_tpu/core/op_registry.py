@@ -86,10 +86,10 @@ def shape_rule(name):
 # input shapes via ``ctx.shape`` / element sizes via ``ctx.esize`` and
 # charge the op via ``ctx.add(op, flops=..., hbm_bytes=..., bwd_flops=...,
 # bwd_hbm_bytes=..., row_reads=..., bwd_row_writes=...)``. The convention
-# is a FLOOR model (minimum achievable traffic under ideal XLA fusion) —
-# the same stance the committed per-bucket rooflines take, so the engine
-# IS the single bytes model behind bench.py --attribute,
-# tools/attribute_resnet.floors and the DeepFM comm line. Rules live in
+# is a FLOOR model (minimum achievable traffic under ideal XLA fusion):
+# the engine is the repo's single bytes model (the sharded embedding's
+# comm line and the SPMD pass's collective volumes read it too).
+# Rules live in
 # ``core/opimpl/cost_rules.py``; an op without a rule contributes zero and
 # is reported in the estimate's ``uncosted`` list (honesty over silence).
 # ---------------------------------------------------------------------------

@@ -6,9 +6,7 @@ registry-parity test (``tests/test_cost_engine.py``) holds every op with
 a shape rule to having a cost rule (or an explicit zero-cost
 registration), so a new op cannot silently fall out of the roofline.
 
-Modeling convention — the FLOOR stance of the committed per-bucket
-rooflines (``tools/attribute_resnet.py`` pre-refactor, now delegated
-here; ``RESNET_ROOFLINE.json``'s note):
+Modeling convention — a FLOOR stance (``analysis/cost.py``):
 
   * elementwise/activation/reduction/cast ops ride a producer's fusion
     epilogue: zero extra HBM traffic, FLOPs counted;
@@ -22,7 +20,7 @@ here; ``RESNET_ROOFLINE.json``'s note):
     ride dW) — batch_norm itself then charges zero, exactly the
     committed accounting;
   * embedding lookups / scatter-adds charge ROWS, not bytes (TPU row
-    ops are latency-bound — ``ROW_OP_FLOORS.json``); the roofline adds
+    ops are latency-bound); the roofline adds
     the row term on top of max(compute, HBM).
 
 Backward columns (``bwd_*``) are charged only for ops an ``autodiff``
@@ -207,9 +205,8 @@ def _conv2d_cost(ctx, op):
     dw_b = xb + yb + o * c * kh * kw * 4  # f32 dW
     # BN/relu ride the conv fusions: one extra full activation pass on
     # each of dX (relu mask + BN x-hat) and dW (dgamma/dbeta reads).
-    # The note carries the dx/dw split so the per-bucket attribution
-    # (tools/attribute_resnet.py) can rebuild its buckets from THESE
-    # numbers instead of a second model.
+    # The note carries the dx/dw split, so a per-bucket attribution
+    # reads THESE numbers and not a second model.
     ctx.add(op, flops=f, hbm_bytes=xb + wb + yb,
             bwd_flops=f + dx_f, bwd_hbm_bytes=dx_b + dw_b + 2 * yb,
             note={"kind": "conv", "dx_flops": dx_f, "dx_bytes": dx_b,

@@ -6,9 +6,9 @@ machine_translation, se_resnext; plus the BASELINE.json configs: Transformer
 Every model module exposes builder functions that construct a fluid-style
 symbolic program in the current default program and return a
 :class:`ModelSpec` with the loss var, feed list, and a synthetic-batch
-sampler (so tests and ``bench.py`` don't need real datasets)."""
+sampler (so tests and the benchmark don't need real datasets)."""
 
-from .common import ModelSpec  # noqa: F401
+from .common import ModelSpec, baseline  # noqa: F401
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401
 from . import vgg  # noqa: F401

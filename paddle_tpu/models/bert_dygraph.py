@@ -238,7 +238,7 @@ def bert_base_dygraph(vocab_size=30522, seq_len=128, d_model=768,
                       d_ff=3072, n_head=12, n_layer=12, dropout_rate=0.1,
                       amp=False):
     """Build the imperative BERT and return (layer, feed_order,
-    flops_per_example, tokens_per_example) — bench/driver plumbing."""
+    flops_per_example, tokens_per_example)."""
     model = BertPretrain(vocab_size, seq_len, d_model, d_ff, n_head,
                          n_layer, dropout_rate, amp=amp)
     per_layer_mac = (4 * d_model * d_model + 2 * d_model * d_ff

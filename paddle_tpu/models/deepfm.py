@@ -9,7 +9,8 @@ embeddings live in ONE fused ``[V, W]`` table (emb in cols 0..K-1, w1 in
 col K, zero-frozen padding up to W = the next power of two, which divides
 128 so the packed-row gather applies — ops/rowops.py). Embedding-bound
 CTR steps are PER-ROW-LATENCY-bound on TPU (gather ~2 ns/row packed,
-scatter-add ~15 ns/row regardless of width — ROW_OP_FLOORS.json), so
+scatter-add ~15 ns/row regardless of width: round-5 readings of an
+installation that is gone, ``analysis/cost.py`` holds them), so
 one fused table halves the row ops of the classic two-table formulation
 at the cost of inert padding columns (zero-init, zero-grad, frozen)."""
 
@@ -46,28 +47,6 @@ class _PaddedTableInitializer(Initializer):
                    + [0.0] * (w - self.used_cols)})
         block.append_op("elementwise_mul", {"X": var, "Y": mask},
                         {"Out": var}, {})
-
-# Fallback row-op latencies: the round-5 v5e measurements. These are NOT
-# the operative constants — the roofline sources them live from
-# ROW_OP_FLOORS.json (the sourcing is pinned by
-# tests/test_bench_contract.py). The 15 ns/row scatter figure is the floor
-# ISSUE 13's Pallas kernel (ops/scatter.py) exists to challenge; no cell
-# measures either yet (ROADMAP R9, W5).
-_GATHER_NS_PER_ROW = 2.0
-_SCATTER_NS_PER_ROW = 15.0
-
-
-def row_op_floors(path=None):
-    """(gather_ns, scatter_ns, source): the measured per-row latencies
-    from ``ROW_OP_FLOORS.json`` beside bench.py, falling back to the
-    round-5 constants above (source then says so). DELEGATES to the
-    single reader in ``analysis.cost`` (ISSUE 15), so this floor and
-    the static roofline can never read different constants."""
-    from ..analysis.cost import row_op_floors as reader
-
-    return reader(path, fallback=(_GATHER_NS_PER_ROW,
-                                  _SCATTER_NS_PER_ROW),
-                  fallback_source="builtin-r5")
 
 
 def deepfm(sparse_feature_dim=100000, num_fields=26, embedding_size=16,
@@ -121,17 +100,10 @@ def deepfm(sparse_feature_dim=100000, num_fields=26, embedding_size=16,
         layers.sigmoid_cross_entropy_with_logits(logits, label_f))
     prob = layers.ops.sigmoid(logits)
 
-    # analytic per-example roofline for bench.py: embedding-bound CTR is
-    # row-LATENCY-bound, not bytes-bound — the floor sums the MLP's MXU
-    # time with the measured per-row gather + scatter latencies for the
-    # F rows each example touches in the fused table (fwd packed gather
-    # + the backward densify scatter-add; the dense-Adam full-table pass
-    # is batch-amortized, <2% at the bench batch).
+    # analytic fwd+bwd FLOPs of the MLP an example
     dims = [num_fields * embedding_size + dense_dim] + list(hidden_sizes) \
         + [1]
     mlp_flops = 6 * sum(a * b for a, b in zip(dims[:-1], dims[1:]))
-    gather_ns, scatter_ns, floor_source = row_op_floors()
-    row_s = num_fields * (gather_ns + scatter_ns) * 1e-9
     return ModelSpec(
         loss,
         feeds={"feat_ids": FeedSpec([num_fields], "int64", 0,
@@ -139,14 +111,4 @@ def deepfm(sparse_feature_dim=100000, num_fields=26, embedding_size=16,
                "dense_value": FeedSpec([dense_dim], "float32", 0.0, 1.0),
                "label": FeedSpec([1], "int64", 0, 2)},
         fetches={"prob": prob},
-        flops_per_example=mlp_flops,
-        extras={"row_latency_s_per_example": row_s,
-                "row_floors": {"gather_ns_per_row": gather_ns,
-                               "scatter_ns_per_row": scatter_ns,
-                               "source": floor_source},
-                # the fused-table geometry consumers (bench.py's
-                # self-description) must not re-derive: width is the
-                # padded pow2, NOT embedding_size
-                "fused_table": {"vocab": sparse_feature_dim,
-                                "width": width,
-                                "num_fields": num_fields}})
+        flops_per_example=mlp_flops)

@@ -3,7 +3,8 @@ ImageNet layouts; bottleneck ResNet-50 per He et al.). BASELINE config 2.
 
 TPU-first notes: NCHW symbolic layout (XLA relayouts for the TPU conv
 units); batch_norm folds into conv epilogues under XLA fusion; all conv
-FLOPs land on the MXU in bf16 when the program is cast (see bench.py)."""
+FLOPs land on the MXU in bf16 when the program is cast
+(``fluid.amp.decorate``)."""
 
 from .. import layers
 from ..layers import metric_op
@@ -58,7 +59,7 @@ def resnet_imagenet(depth=50, class_num=1000, image_shape=(3, 224, 224)):
     stages, block_fn = cfg[depth]
     img = layers.data("img", shape=list(image_shape), dtype="float32")
     # int32 on purpose (TPU-native): jax without x64 truncates int64 feeds
-    # to int32 anyway, emitting a UserWarning on every bench step — request
+    # to int32 anyway, emitting a UserWarning on every step — request
     # the effective dtype instead of relying on silent truncation
     label = layers.data("label", shape=[1], dtype="int32")
     x = _conv_bn(img, 64, 7, 2, act="relu")

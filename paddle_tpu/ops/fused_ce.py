@@ -31,11 +31,11 @@ from .kernel_names import named_pallas_call
 _INTERPRET = False  # tests flip this to run the kernel on CPU
 
 
-# Above this many logit elements (T*V) the fused path engages. Below it,
-# the plain projection+CE wins on the bench chip: the fused backward
-# RECOMPUTES the projection (+2*T*D*V FLOPs) to avoid storing [T, V], and
-# with HBM to spare that trade loses (measured: batch 128 transformer-base
-# 199.9k tok/s plain vs 196.2k fused; batch 256 plain OOMs, fused runs).
+# Above this many logit elements (T*V) the fused path engages. Below it the
+# plain projection+CE won: the fused backward RECOMPUTES the projection
+# (+2*T*D*V FLOPs) to avoid storing [T, V], a trade that loses with HBM to
+# spare (batch 128 transformer-base 199.9k tok/s plain vs 196.2k fused). Set
+# on an installation that is gone; not measured since (ROADMAP.md W4).
 _FUSED_MIN_LOGITS = 1.5e9
 
 

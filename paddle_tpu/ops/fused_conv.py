@@ -4,7 +4,7 @@ kernels for the ResNet-50 bottleneck shapes.
 The unfused lowering pays a full HBM round trip per conv->BN->ReLU link:
 conv writes its output, the BN statistics pass re-reads it, and the
 normalize(+residual+relu) pass reads it again and writes the final
-activation (RESNET_ROOFLINE.json's relu-elementwise bucket; the reference
+activation (the reference
 framework fuses these chains as graph passes —
 ``framework/details/build_strategy.cc`` ``fuse_elewise_add_act`` /
 ``fuse_relu_depthwise_conv``). Here the conv runs as a Pallas blocked

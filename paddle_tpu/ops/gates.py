@@ -11,8 +11,8 @@ WHY. A :class:`GateDecision` carries the chosen kernel plus one
     a build, cloned with the program;
   * the static resource pass (``analysis/resources.py``) evaluates the
     SAME gates shape-only (``static_only=True`` skips the platform
-    checks) and surfaces refusals as findings with op provenance;
-  * bench records keep reporting the honest kernel name;
+    checks) and surfaces refusals as findings with op provenance,
+    under the honest kernel name;
   * every gated site also hands its decision to :func:`note`, and
     ``Executor._stage`` gathers them (:func:`collect`) round the trace of
     a step: a tag on its ``executor.trace`` span and an entry of the

@@ -1,11 +1,11 @@
 """TPU-native row scatter-add for narrow embedding tables.
 
 The backward of every embedding-bound model is a row scatter-add
-(``grad_table.at[ids].add(grad_rows)``), and round 5 measured XLA's
+(``grad_table.at[ids].add(grad_rows)``), and round 5 read XLA's
 lowering at ~15 ns/row regardless of row width — a per-row HBM
-read-modify-write DMA each, declared a "chip property" in
-``models/deepfm.py``. This module is the purpose-built
-challenge to that claim (ROADMAP item 3): for tables whose PACKED layout
+read-modify-write DMA each (set on an installation that is gone; not
+measured since: ROADMAP.md R9). This module is the purpose-built
+challenge to that figure: for tables whose PACKED layout
 fits VMEM, the scatter runs as a Pallas kernel that
 
   1. streams the table HBM->VMEM once (as the kernel's aliased output
@@ -18,12 +18,12 @@ fits VMEM, the scatter runs as a Pallas kernel that
   3. streams the table back VMEM->HBM once.
 
 Total HBM traffic: ``2*V*K + N*K`` bytes instead of N serialized row RMW
-DMAs — at the DeepFM bench shape (V=100k, K=16, N=212992) that is ~26 MB
+DMAs — at the DeepFM BASELINE shape (V=100k, K=16, N=212992) that is ~26 MB
 of streaming vs 212992 latency-bound DMAs, a ~50x headroom if the VMEM
 accumulate loop keeps up. The sorted-segment formulation the ISSUE names
 (sort ids, segment-reduce duplicates, one dense store per unique row) is
 kept as a variant a caller names (``sort=True``): sorting buys store
-locality but costs an argsort (~7 ms/step at the bench shape — see
+locality but costs an argsort (~7 ms/step at that shape then — see
 ``control_ops`` merge note), so the default path is unsorted and
 duplicate-safe by serial accumulation. Neither has been timed on the chip
 against ``.at[ids].add``: no cell runs a sparse step (ROADMAP R9, D17).
@@ -50,7 +50,7 @@ _INTERPRET = False  # tests flip this to run the kernel on CPU
 # The packed table + one double-buffered vals block must fit comfortably;
 # leave headroom for the vals stream and compiler temporaries. 10 MB
 # admits the [100k, 16] f32 microbench table (6.4 MB packed) but NOT the
-# DeepFM bench's [100k, 32] f32 fused table (12.8 MB packed).
+# DeepFM BASELINE shape's [100k, 32] f32 fused table (12.8 MB packed).
 _VMEM_BUDGET = 10 * 1024 * 1024
 _CHUNK = 1024  # (rows, vals) slots processed per grid step
 # The whole int32 row-id vector is the kernel's scalar-prefetch operand and

@@ -22,8 +22,7 @@ shard_map with two formulations:
   valid-slot counts, never correctness. (A sub-``cap`` MoE-style capacity
   factor would cut the padded-slot traffic by ~mp in the balanced case,
   but without ragged collectives overflowed rows would silently drop;
-  this framework does not trade correctness for bytes — see NOTES_r7.md
-  for the full accounting.)
+  this framework does not trade correctness for bytes.)
 * **psum-of-partials** (the path chosen for degenerate slices, and for a
   caller that names it with ``strategy="psum"``): every shard gathers ALL n
   ids against its local slice (zeros for rows it doesn't own) and one
@@ -34,7 +33,7 @@ shard_map with two formulations:
 ``choose_strategy`` picks per call: psum only when the per-shard slice is
 too small for the sort/route overhead to amortize (``cap < _MIN_CHUNK`` —
 the capacity-factor heuristic's degenerate regime). ``comm_bytes_model``
-is the analytic bytes line the bench record carries (ISSUE 13 acceptance).
+is the analytic bytes of both (re-derivable, not measured).
 """
 
 import jax
@@ -63,11 +62,10 @@ def choose_strategy(n_ids, n_shards, width=None):
 
 
 def comm_bytes_model(n_ids, width, n_shards, esize=4):
-    """Analytic per-step ICI bytes of both formulations (the bench
-    record's honesty line — re-derivable, not measured). DELEGATES to
-    the single comm model in ``analysis.cost`` (ISSUE 15): the bench
-    line, the static SPMD pass's per-collective volumes, and this
-    module can never disagree about the bytes."""
+    """Analytic per-step ICI bytes of both formulations (re-derivable,
+    not measured). DELEGATES to the single comm model in
+    ``analysis.cost`` (ISSUE 15): the static SPMD pass's per-collective
+    volumes and this module can never disagree about the bytes."""
     from ..analysis.cost import comm_bytes_model as model
 
     return model(n_ids, width, n_shards, esize=esize)

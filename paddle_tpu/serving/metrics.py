@@ -6,9 +6,8 @@ via ``profiler.Histogram``'s sliding window), queue depth, batch occupancy
 bounded compile cache), and the compile-cache hit rate (misses after
 warm-up mean a shape leaked past the bucketing). Exposed three ways:
 
-  * ``snapshot()`` — the plain dict the bench harness and tests pin
-    (field names are a CONTRACT with ``tests/test_bench_contract.py``;
-    do not rename);
+  * ``snapshot()`` — the plain dict tests pin (field names are a
+    CONTRACT with ``tests/test_obs.py``; do not rename);
   * ``report()`` — a formatted table shaped like ``profiler._report``;
   * ``prometheus_text()`` — the registry's Prometheus exposition (every
     counter under ``paddle_tpu_serving_*``, gauges, latency summaries),

@@ -93,6 +93,33 @@ def test_deepfm_trains():
            opt=fluid.optimizer.Adam(learning_rate=1e-2))
 
 
+def test_baseline_builds_each_shape_small_and_refuses_an_unknown_name():
+    import pytest
+
+    def build(name, **kw):
+        with fluid.program_guard(fluid.Program(), fluid.Program()):
+            spec, batch = models.baseline(name, **kw)
+        first = next(iter(spec.feeds.values()))
+        return spec, batch, first.shape
+
+    for name, batch, shape in [("transformer", 4, (64,)),
+                               ("bert", 4, (32,)),
+                               ("resnet50", 2, (3, 64, 64)),
+                               ("deepfm", 16, (26,))]:
+        spec, got, first = build(name, small=True)
+        assert (got, first) == (batch, shape), name
+        feed = spec.sample_batch(got)
+        assert set(feed) == set(spec.feed_names()), name
+        assert all(v.shape[0] == batch for v in feed.values()), name
+    assert build("transformer", small=True, seq_len=128)[1:] == (4, (128,))
+    # on the chip the transformer's batch holds 32,768 tokens a step
+    assert build("transformer", seq_len=2048)[1:] == (16, (2048,))
+    with pytest.raises(ValueError, match="unknown BASELINE shape"):
+        build("vgg")
+    with pytest.raises(ValueError, match="seq_len"):
+        build("bert", seq_len=64)
+
+
 def test_word2vec_trains():
     spec = models.word2vec.ngram_lm(dict_size=50, emb_dim=8, hidden_size=16)
     _train(spec, batch_size=8, steps=5, lr=0.1)

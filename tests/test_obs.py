@@ -345,8 +345,7 @@ def test_registry_snapshot_consistency():
     assert (snap["prefill_chunks"], snap["prefill_deferred_rows"]) == (2, 3)
     assert "paddle_tpu_serving_prefill_deferred_rows 3" in \
         m.prometheus_text()
-    # the pinned snapshot field list itself is unchanged (the contract
-    # test_bench_contract.py leans on)
+    # the pinned snapshot field list itself is unchanged
     assert set(snap) == {
         "requests_completed", "requests_failed", "requests_rejected",
         "requests_expired", "requests_shed", "requests_retried",

@@ -1,9 +1,8 @@
 """Where a computation runs is decided by its place and its mesh, never
 guessed from ``jax.devices()``: places, the trace-time placement the Pallas
 gates read, one process per chip, the compile cache's directory, and the
-entry points that refuse to run without a TPU."""
+entry point that refuses to run without a TPU."""
 
-import json
 import os
 import subprocess
 import sys
@@ -232,9 +231,7 @@ def test_ops_ask_gates_where_they_run_and_nothing_above_them():
     assert not {n for _, n in reached} & {
         "placed", "single_tpu", "placement_reason", "placed_platform",
         "PLACEMENT", "env_flag"}, reached
-    # what is left, by name (ROADMAP D15)
-    assert reached == [(os.path.join("paddle_tpu", "ops", "scatter.py"),
-                        "merge_sparse_rows")]
+    # what is left is held by name in tests/test_layering.py (ROADMAP D23)
 
 
 # -- one process for each chip ----------------------------------------------
@@ -282,46 +279,10 @@ def test_compile_cache_directory(env, want):
     assert out.stdout.strip().splitlines()[-1] == want
 
 
-# -- entry points that need the chip say so ---------------------------------
+# -- the entry point that needs the chip says so ----------------------------
 
 def test_chip_smoke_refuses_to_run_without_a_tpu():
     out = _run(["chip_smoke.py"])
     assert out.returncode not in (0, None)
     assert out.stdout == ""  # no result line that could be read as a run
     assert '"ok": false' in out.stderr
-
-
-def test_bench_refuses_to_run_without_a_tpu():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("BENCH_FORCE_CPU", None)
-    out = subprocess.run([sys.executable, "bench.py", "--model", "deepfm"],
-                         cwd=_REPO, env=env, capture_output=True, text=True,
-                         timeout=300)
-    assert out.returncode != 0 and out.stdout == ""
-    assert "no TPU" in out.stderr
-
-
-def test_bench_cpu_smoke_record_names_its_platform():
-    out = _run(["bench.py", "--model", "deepfm"], BENCH_FORCE_CPU="1",
-               BENCH_STEPS="1")
-    assert out.returncode == 0, out.stderr[-2000:]
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert (rec["platform"], rec["device_kind"]) == ("cpu", "cpu")
-    assert rec["device_count"] >= 1
-    # no utilization against an invented peak
-    assert rec["vs_baseline"] is None and rec["config"]["peak_flops"] is None
-
-
-def test_peak_flops_raises_on_unknown_device_kind():
-    import bench
-
-    class Device:
-        platform = "tpu"
-        device_kind = "TPU v9 imaginary"
-
-    with pytest.raises(ValueError, match="no published peak"):
-        bench._peak_flops(Device())
-    with pytest.raises(ValueError, match="no published peak"):
-        bench._peak_flops(jax.devices("cpu")[0])
-    Device.device_kind = "TPU v5 lite"
-    assert bench._peak_flops(Device()) == 197e12

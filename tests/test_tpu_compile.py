@@ -998,8 +998,8 @@ def test_compiled_hlo_carries_each_kernel_family_by_name(chip, build, names):
 # ---------------------------------------------------------------------------
 
 def _abstract_step(build):
-    """A training program built the way ``bench._bench_static`` builds one
-    (``build()`` returns the model spec and the batch; Adam runs under
+    """A training program as a BASELINE step is built (``build()`` returns
+    the model spec and the batch; Adam runs under
     ``fluid.amp.decorate``), as (abstract (state, feed, rng), program,
     loss name, persistable names) — no array is ever made."""
     import paddle_tpu as fluid
@@ -1133,11 +1133,11 @@ _STEP_CASES = [
                          [c[1:] for c in _STEP_CASES],
                          ids=[c[0] for c in _STEP_CASES])
 def test_whole_train_step_compiles(chip, model, seq, kernels, attn_plan):
-    import bench
+    from paddle_tpu import models
     from paddle_tpu.core.executor import build_step_fn
 
     avals, program, loss, persist = _abstract_step(
-        lambda: bench._build(model, True, seq)[:2])
+        lambda: models.baseline(model, seq_len=seq))
     step = build_step_fn(program, (loss,), persist)
     limits = {}
     if attn_plan == "packed_stream":
@@ -1182,7 +1182,7 @@ def test_whole_train_step_compiles(chip, model, seq, kernels, attn_plan):
 @pytest.mark.slow
 def test_bert_dygraph_train_step_compiles(chip):
     """BASELINE config 4: BERT-base through the dygraph build, the jitted
-    functional train step ``bench._bench_bert_dygraph`` times."""
+    functional train step."""
     import paddle_tpu as fluid
     from paddle_tpu.models import bert_dygraph
 

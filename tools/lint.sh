@@ -37,7 +37,7 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-TARGETS=(paddle_tpu tests tools bench.py chip_smoke.py)
+TARGETS=(paddle_tpu tests tools chip_smoke.py)
 PY="${PYTHON:-$(command -v python3 || command -v python)}"
 
 if command -v ruff >/dev/null 2>&1; then
