@@ -457,6 +457,83 @@ def _latent_attention_dense(env, op):
         plan=plan))
 
 
+def _eva_caches(env, op):
+    return [get(env, op.input(slot))
+            for slot in ("WinK", "WinV", "SumK", "SumV")]
+
+
+@register("eva_summary", "eva_summary_chunk")
+def _eva_summary(env, op):
+    """The summariser of EVA attention and its two cache writes
+    (``ops/eva_attention.py``): a cache written at a stride, derived from
+    another. WinK, WinV [B, W, H*D] the window caches (position p at slot ``p
+    % W``), SumK, SumV [B, L, H*D] the summary caches (chunk c at entry c),
+    Phi, Mu [H*D] the layer's learned pooling query and key offset. The
+    summary of the ``chunk`` positions of a chunk is a softmax-pooled key
+    (``+ Mu``) and value, the pooling logits ``D ** -0.5 * k . Phi`` a head,
+    in float32.
+
+    ``eva_summary`` (a step): Pos [B], the window caches with the step's
+    token written; the row whose position ends a chunk writes that chunk's
+    entry, the others nothing. ``eva_summary_chunk``: Pos [B, K], NewK, NewV
+    [B, K, H*D] the run's own keys and values, the window caches AS THEY
+    WERE BEFORE the run; every chunk whose last position is a live lane
+    (``Pos < pad_pos``) is written. SumKOut, SumVOut: the summary caches.
+    Strictly per-row; device events under ``eva.summary``."""
+    from ...ops import eva_attention
+
+    win_k, win_v, sum_k, sum_v = _eva_caches(env, op)
+    pos = get(env, op.input("Pos"))
+    args = (pos, get(env, op.input("Phi")), get(env, op.input("Mu")),
+            int(op.attr("num_heads")), int(op.attr("chunk")))
+    if op.type == "eva_summary":
+        out = eva_attention.summarise_step(win_k, win_v, sum_k, sum_v, *args)
+    else:
+        out = eva_attention.summarise_chunk(
+            win_k, win_v, get(env, op.input("NewK")),
+            get(env, op.input("NewV")), sum_k, sum_v, *args,
+            int(op.attr("pad_pos")))
+    put(env, op.output("SumKOut"), out[0])
+    put(env, op.output("SumVOut"), out[1])
+
+
+@register("eva_attention", "eva_attention_chunk")
+def _eva_attention(env, op):
+    """EVA attention: ONE softmax over two caches (``ops/eva_attention.py``).
+    A query at position p reads the window cache's positions of its own
+    block-aligned window, ``(p // window) * window .. p``, and the summary
+    cache's entries of every earlier window, ``c < (p // window) * (window /
+    chunk)``; scores ``D ** -0.5 * q . k`` in float32, normalised together.
+
+    ``eva_attention`` (a step): Q [B, H*D], Pos [B], WinK, WinV [B, W, H*D]
+    with the token written (slots above ``p % W`` are masked), SumK, SumV
+    [B, L, H*D]; Out [B, H*D] and Count [3] int32, the window slots and
+    summary entries the rows read and the context positions they hold.
+    ``eva_attention_chunk``: Q [B, K, H*D], Pos [B, K], the window caches AS
+    THEY WERE BEFORE the run with NewK, NewV [B, K, H*D] beside them (a
+    lane reads the lanes up to itself that lie in its own window; ``Pos >=
+    pad_pos``: a pad lane), the summary caches with the run's summaries
+    written; K <= window. Strictly per-row; device events under
+    ``attn.eva``. Both forms are ``jnp``: a step reads the caches whole
+    under the mask, a chunk run in blocks under a streaming softmax up to
+    the last entry a live lane reads."""
+    from ...ops import eva_attention
+
+    q, pos = get(env, op.input("Q")), get(env, op.input("Pos"))
+    caches = _eva_caches(env, op)
+    sizes = (int(op.attr("num_heads")), int(op.attr("window")),
+             int(op.attr("chunk")))
+    if op.type == "eva_attention":
+        out, count = eva_attention.attend_step(q, *caches, pos, *sizes)
+        put(env, op.output("Count"), count)
+    else:
+        out = eva_attention.attend_chunk(
+            q, *caches, get(env, op.input("NewK")),
+            get(env, op.input("NewV")), pos, *sizes,
+            int(op.attr("pad_pos")))
+    put(env, op.output("Out"), out)
+
+
 @register("last_live_lane")
 def _last_live_lane(env, op):
     """X [B, K, D], Pos [B, K], Cache [B, C, ..]: Out [B, D], each row's

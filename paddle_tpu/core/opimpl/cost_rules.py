@@ -569,6 +569,51 @@ def _latent_attention_chunk_cost(ctx, op):
             + lanes * cap)
 
 
+def _eva_sizes(ctx, op):
+    """(B, window slots, summary entries, H*D, lanes a row) of an EVA op, or
+    None where a shape is open."""
+    win, entries = ctx.shape(op.input("WinK")), ctx.shape(op.input("SumK"))
+    new = op.input("NewK")
+    lanes = 1 if new is None else (ctx.shape(new) or (0, -1))[1]
+    if win is None or entries is None or -1 in win or -1 in entries \
+            or lanes == -1:
+        return None
+    return win[0], win[1], entries[1], win[2], lanes
+
+
+@register_cost("eva_summary", "eva_summary_chunk")
+def _eva_summary_cost(ctx, op):
+    sizes = _eva_sizes(ctx, op)
+    if sizes is None:
+        ctx.add(op, unresolved=True)
+        return
+    b, _w, _entries, hd, lanes = sizes
+    chunk = int(op.attr("chunk"))
+    e = ctx.esize(op.input("WinK"))
+    # the positions pooled: a step's one chunk a row, a run's lanes and the
+    # chunk its first lane continues; keys and values read once, a summary
+    # key and value a chunk written; logits, the two pooled sums
+    rows = b * (lanes + chunk)
+    ctx.add(op, flops=6.0 * rows * hd,
+            hbm_bytes=2 * (rows + rows // chunk) * hd * e)
+
+
+@register_cost("eva_attention", "eva_attention_chunk")
+def _eva_attention_cost(ctx, op):
+    sizes = _eva_sizes(ctx, op)
+    if sizes is None:
+        ctx.add(op, unresolved=True)
+        return
+    b, w, entries, hd, lanes = sizes
+    e = ctx.esize(op.input("Q"))
+    # both caches whole under the mask (a static rule cannot know the fill),
+    # a run's own rows beside the window; each read once
+    own = lanes if op.input("NewK") is not None else 0
+    read = w + entries + own
+    ctx.add(op, flops=4.0 * b * lanes * read * hd,
+            hbm_bytes=(2 * b * read * hd + 2 * b * lanes * hd) * e)
+
+
 # ---------------------------------------------------------------------------
 # optimizer updates: master-precision (f32) state passes, batch-amortized
 # ---------------------------------------------------------------------------

@@ -1029,6 +1029,73 @@ def _latent_attention_chunk_shape(ctx, op):
                              op.input("Mask").name, list(ms), qs[1], cs[1]))
 
 
+def _eva_cache_shapes(ctx, op):
+    """The four caches of an EVA op, checked: WinK, WinV [B, W, H*D] and
+    SumK, SumV [B, L, H*D] of one tail, ``W`` a whole number of chunks.
+    Returns the window caches' shape, or None."""
+    kind = op.type
+    h, chunk = int(op.attr("num_heads", 1)), int(op.attr("chunk", 1))
+    shapes = {slot: ctx.shape(op.input(slot))
+              for slot in ("WinK", "WinV", "SumK", "SumV")}
+    tail = None
+    for slot, shape in shapes.items():
+        if shape is None:
+            continue
+        if len(shape) != 3:
+            raise ShapeError("%s %s '%s' must be [B, C, H*D], got %s" % (
+                kind, slot, op.input(slot).name, list(shape)))
+        if shape[-1] != -1:
+            if shape[-1] % h or (tail is not None and shape[-1] != tail):
+                raise ShapeError(
+                    "%s %s '%s' rows of %d: the four caches hold rows of "
+                    "one width, %d heads side by side" % (
+                        kind, slot, op.input(slot).name, shape[-1], h))
+            tail = shape[-1]
+    win = shapes["WinK"]
+    if win is not None and win[1] != -1:
+        window = int(op.attr("window", win[1]))
+        if chunk < 1 or win[1] % chunk or win[1] != window:
+            raise ShapeError(
+                "%s WinK '%s' holds %d positions a row: the window is %d, a "
+                "whole number of chunks of %d" % (
+                    kind, op.input("WinK").name, win[1], window, chunk))
+    for slot in ("Phi", "Mu", "NewK", "NewV", "Q"):
+        var = op.input(slot)
+        shape = None if var is None else ctx.shape(var)
+        if shape is not None and tail is not None and shape[-1] not in (
+                -1, tail):
+            raise ShapeError("%s %s '%s' %s is not %d wide as the caches' "
+                             "rows are" % (kind, slot, var.name, list(shape),
+                                           tail))
+    return win
+
+
+@register_shape("eva_summary", "eva_summary_chunk")
+def _eva_summary_shape(ctx, op):
+    _eva_cache_shapes(ctx, op)
+    for slot in ("SumK", "SumV"):
+        ctx.set(op.output(slot + "Out"), ctx.shape(op.input(slot)),
+                ctx.dtype(op.input(slot)))
+
+
+@register_shape("eva_attention", "eva_attention_chunk")
+def _eva_attention_shape(ctx, op):
+    win = _eva_cache_shapes(ctx, op)
+    qs = ctx.shape(op.input("Q"))
+    if op.type == "eva_attention_chunk" and qs is not None:
+        if len(qs) != 3:
+            raise ShapeError("eva_attention_chunk Q '%s' must be [B, K, "
+                             "H*D], got %s" % (op.input("Q").name, list(qs)))
+        if win is not None and -1 not in (qs[1], win[1]) and qs[1] > win[1]:
+            raise ShapeError(
+                "eva_attention_chunk Q '%s': %d lanes cross more than one "
+                "multiple of the window of %d" % (op.input("Q").name, qs[1],
+                                                  win[1]))
+    if op.output("Count") is not None:
+        ctx.set(op.output("Count"), (3,), np.dtype(np.int32))
+    ctx.set(op.output("Out"), qs, ctx.dtype(op.input("Q")))
+
+
 # ---------------------------------------------------------------------------
 # the hybrid blocks (models/qwen3_next.py, models/nemotron_h.py)
 # ---------------------------------------------------------------------------

@@ -34,6 +34,7 @@ __all__ = [
     "resize_nearest", "grid_sampler", "pixel_shuffle", "im2sequence",
     "multi_head_attention", "scaled_dot_product_attention",
     "cached_multi_head_attention", "kv_cache_write", "cached_attention",
+    "eva_summary", "eva_attention", "gated_feed_forward",
     "optimization_barrier",
     "cached_multi_head_attention_chunk", "kv_cache_write_chunk",
     "sparse_index", "latent_attention", "last_live_lane",
@@ -2097,7 +2098,11 @@ def kv_cache_write(cache, x, pos, ring=False, name=None):
     [B, ...], ``pos``: [B] int. The tail is the cache's own: a key or value
     row of H*D, one latent row ``[c | k_pe]`` for all heads, an index key.
     ``ring``: the cache is a ring of C positions, ``cache[b, pos[b] % C] =
-    x[b]``. Returns the updated cache tensor."""
+    x[b]``; what reads it decides what the slots mean: a sliding window
+    (:func:`cached_attention`) or a window that restarts at every multiple
+    of C and masks the stale slots above ``pos % C`` (:func:`eva_attention`).
+    A cache written at a stride and derived from another is
+    :func:`eva_summary`'s. Returns the updated cache tensor."""
     helper = LayerHelper("kv_cache_write", name=name)
     out = helper.create_variable_for_type_inference(
         dtype=_dtype(cache), shape=cache.shape)
@@ -2125,7 +2130,10 @@ def cached_attention(q, cache_k, cache_v, pos, num_heads, num_kv_heads=None,
     token written, a chunk reads the rings AS THEY WERE BEFORE it and its
     own ``new_k`` / ``new_v`` [B, K, ..] beside them, and writes the rings
     afterwards. ``count``: also return [1] int32, the positions the rows
-    read (a step only). Returns [.., H*Dv], or ``(out, count)``."""
+    read (a step only). Returns [.., H*Dv], or ``(out, count)``. ONE key and
+    one value cache, and a ring's window SLIDES (``p - window < s <= p``);
+    one softmax over two caches of different kinds, a block-aligned window
+    and a cache of chunk summaries, is :func:`eva_attention`."""
     chunk = len(q.shape) == 3
     helper = LayerHelper("cached_attention_chunk" if chunk
                          else "cached_attention", param_attr=sink_attr,
@@ -2160,6 +2168,114 @@ def cached_attention(q, cache_k, cache_v, pos, num_heads, num_kv_heads=None,
         outputs["Count"].stop_gradient = True
     helper.append_op(helper.layer_type, inputs, outputs, attrs)
     return (out, outputs["Count"]) if count else out
+
+
+def _eva_inputs(chunk, win_k, win_v, sum_k, sum_v, pos, new_k, new_v):
+    inputs = {"WinK": win_k, "WinV": win_v, "SumK": sum_k, "SumV": sum_v,
+              "Pos": pos}
+    if chunk:
+        if new_k is None or new_v is None:
+            raise ValueError("a chunk run reads the window caches as they "
+                             "were before it: it needs new_k and new_v, "
+                             "its own rows")
+        inputs.update(NewK=new_k, NewV=new_v)
+    return inputs
+
+
+def _pad_pos(pad_pos):
+    if pad_pos is None:
+        raise ValueError("a chunk run over a window cache needs pad_pos, "
+                         "the position the scheduler gives a pad lane")
+    return int(pad_pos)
+
+
+def eva_summary(win_k, win_v, sum_k, sum_v, pos, num_heads, chunk_size,
+                phi_attr=None, mu_attr=None, new_k=None, new_v=None,
+                pad_pos=None, name=None):
+    """The summariser of EVA attention and its two cache writes (ops
+    ``eva_summary`` / ``eva_summary_chunk``, ``ops/eva_attention.py``): a
+    cache written at a stride, derived from another. ``win_k``, ``win_v``
+    [B, W, H*D]: the window caches (position p at slot ``p % W``, written by
+    ``kv_cache_write(.., ring=True)``); ``sum_k``, ``sum_v`` [B, L, H*D]: the
+    summary caches, chunk c (positions ``chunk_size * c`` on) at entry c.
+    Creates the layer's pooling query ``phi`` and key offset ``mu``, [H*D]
+    each. A step (``pos`` [B], the window caches with its token written)
+    writes the chunk its position ends, if it ends one; a chunk run (``pos``
+    [B, K], the window caches AS THEY WERE BEFORE it, ``new_k`` / ``new_v``
+    [B, K, H*D] its own rows, ``pad_pos`` what the scheduler gives a pad
+    lane) every chunk whose last position is a live lane. Returns the two
+    summary caches."""
+    chunk = len(pos.shape) == 2
+    helper = LayerHelper("eva_summary_chunk" if chunk else "eva_summary",
+                         name=name)
+    width = int(win_k.shape[-1])
+    inputs = _eva_inputs(chunk, win_k, win_v, sum_k, sum_v, pos, new_k,
+                         new_v)
+    for slot, attr in (("Phi", phi_attr), ("Mu", mu_attr)):
+        inputs[slot] = helper.create_parameter(
+            ParamAttr._to_attr(attr), shape=[width], dtype=_dtype(win_k),
+            default_initializer=ConstantInitializer(0.0))
+    attrs = {"num_heads": int(num_heads), "chunk": int(chunk_size)}
+    if chunk:
+        attrs["pad_pos"] = _pad_pos(pad_pos)
+    outs = {slot + "Out": helper.create_variable_for_type_inference(
+        dtype=_dtype(cache), shape=cache.shape)
+        for slot, cache in (("SumK", sum_k), ("SumV", sum_v))}
+    helper.append_op(helper.layer_type, inputs, outs, attrs)
+    return outs["SumKOut"], outs["SumVOut"]
+
+
+def eva_attention(q, win_k, win_v, sum_k, sum_v, pos, num_heads, window_size,
+                  chunk_size, new_k=None, new_v=None, pad_pos=None,
+                  name=None):
+    """EVA attention, ONE softmax over two caches (ops ``eva_attention`` /
+    ``eva_attention_chunk``, ``ops/eva_attention.py``): the query at position
+    p reads the window cache's positions of its own block-aligned window,
+    ``(p // window_size) * window_size .. p``, and the summary cache's
+    entries of every earlier window. A step: ``q`` [B, H*D], ``pos`` [B],
+    the window caches with its token written; returns ``(out, count)``,
+    ``count`` [3] int32: the window slots and the summary entries the rows
+    read and the context positions they hold. A chunk run: ``q`` [B, K,
+    H*D], ``pos`` [B, K], the window caches AS THEY WERE BEFORE the run with
+    ``new_k`` / ``new_v`` beside them, the summary caches with the run's
+    summaries written (:func:`eva_summary` first), ``pad_pos`` what the
+    scheduler gives a pad lane, ``K <= window_size``; returns ``out``."""
+    chunk = len(q.shape) == 3
+    helper = LayerHelper("eva_attention_chunk" if chunk else "eva_attention",
+                         name=name)
+    inputs = _eva_inputs(chunk, win_k, win_v, sum_k, sum_v, pos, new_k,
+                         new_v)
+    inputs["Q"] = q
+    out = helper.create_variable_for_type_inference(dtype=_dtype(q),
+                                                    shape=q.shape)
+    outputs = {"Out": out}
+    if not chunk:
+        outputs["Count"] = helper.create_variable_for_type_inference(
+            dtype="int32", shape=(3,))
+        outputs["Count"].stop_gradient = True
+    attrs = {"num_heads": int(num_heads), "window": int(window_size),
+             "chunk": int(chunk_size)}
+    if chunk:
+        attrs["pad_pos"] = _pad_pos(pad_pos)
+    helper.append_op(helper.layer_type, inputs, outputs, attrs)
+    return out if chunk else (out, outputs["Count"])
+
+
+def gated_feed_forward(x, intermediate_size, size, num_flatten_dims=1,
+                       name=None):
+    """The dense gated feed-forward layer (SwiGLU): ``(swish(x W_gate) * (x
+    W_up)) W_down``, no bias; the parameters ``<name>.gate`` and
+    ``<name>.up`` [D, intermediate_size] and ``<name>.down``
+    [intermediate_size, size]."""
+    def linear(y, width, tag):
+        site = None if name is None else name + "." + tag
+        return fc(y, size=width, num_flatten_dims=num_flatten_dims,
+                  param_attr=ParamAttr(name=site), bias_attr=False,
+                  name=site)
+
+    hidden = elementwise_mul(swish(linear(x, intermediate_size, "gate")),
+                             linear(x, intermediate_size, "up"))
+    return linear(hidden, size, "down")
 
 
 def cached_multi_head_attention(x, cache_k, cache_v, pos, d_model=None,
@@ -2219,7 +2335,9 @@ def kv_cache_write_chunk(cache, x, pos, ring=False, pad_pos=None,
     a row's live lanes only the last C land, at ``pos % C``. ``few``: K is
     a step's one or two lanes, and each is written by a dynamic update of
     its one row, which writes the cache as it lies on the device whatever
-    its layout (not with ``ring``). Returns the updated cache."""
+    its layout (not with ``ring``). A chunk program whose window restarts
+    (:func:`eva_attention`) writes its window caches with ``ring`` too, after
+    its lanes have read them as they were. Returns the updated cache."""
     helper = LayerHelper("kv_cache_write_chunk", name=name)
     out = helper.create_variable_for_type_inference(
         dtype=_dtype(cache), shape=cache.shape)

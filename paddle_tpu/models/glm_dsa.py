@@ -223,11 +223,9 @@ def _decoder(chunk, dtype, vocab_size, hidden_size, num_attention_heads,
             x, linear(a, hidden_size, nm + ".attn.o"))
         y = norm(x, nm + ".post_norm")
         if mlp == "dense":
-            h = layers.elementwise_mul(
-                layers.swish(linear(y, intermediate_size,
-                                    nm + ".mlp.gate")),
-                linear(y, intermediate_size, nm + ".mlp.up"))
-            h = linear(h, hidden_size, nm + ".mlp.down")
+            h = layers.gated_feed_forward(
+                y, intermediate_size, hidden_size, num_flatten_dims=flat,
+                name=nm + ".mlp")
         else:
             h, load = layers.routed_experts(
                 y, n_routed_experts, num_experts_per_tok,

@@ -26,10 +26,17 @@ design the reference, whose serving story ends at
     positions a row whatever the context rung (a window layer's keys and
     values; the program writes position p at slot ``p % capacity``), which
     the scheduler allocates, warms, stages, re-buckets, gathers and
-    scatters at that capacity beside the caches that hold the rung. What
-    cannot hold with a ring is refused at construction: a prefix cache (a
-    prefix's rows are gone once the ring wraps) and speculation (a
-    rejected draft's writes cannot be rewound);
+    scatters at that capacity beside the caches that hold the rung. Or it
+    may state a ``stride``: a cache that FOLLOWS the rung, ``rung /
+    stride`` entries a row (one entry for every ``stride`` positions, written
+    at ``p // stride``: a summary of the positions, which the program
+    derives from its other caches), allocated, re-bucketed, gathered and
+    scattered at that length (a chunk run's lanes ``[start, start + K)``
+    land on the entries ``start // stride`` on). What cannot hold with
+    either is refused at construction: a prefix cache (a prefix's rows are
+    gone once the ring wraps, and an entry would need every derived cache
+    at its own length) and speculation (a rejected draft's writes into a
+    ring or into a derived cache cannot be rewound);
   * one compiled step per ``(bucket_batch, bucket_ctx)`` on the pow2
     ladders (``buckets.py``), so the XLA compile cache stays bounded at
     ``len(ladder) * len(ctx_ladder)`` executables;
@@ -274,7 +281,7 @@ def chunk_rows(k, b):
 
 
 @functools.lru_cache(maxsize=None)
-def _rows_helpers(lanes, whole=frozenset()):
+def _rows_helpers(lanes, whole=frozenset(), strided=frozenset()):
     """The two jitted copies round a sub-batched chunk run (the jit names
     are what a device trace shows), each a loop over the ``n`` sub-rows
     that hold a slot row, so a pad sub-row costs nothing:
@@ -291,7 +298,10 @@ def _rows_helpers(lanes, whole=frozenset()):
     48 caches where the lanes measure 2.0-2.6 (PERF.md section 6, PR 38).
     The caches named in ``whole`` are rings: a chunk's lanes land at their
     positions modulo the ring, not at ``[start, start + lanes)``, and a
-    ring row is small, so the whole row goes back."""
+    ring row is small, so the whole row goes back. ``strided``: (name,
+    stride) of the caches that hold one entry for every ``stride``
+    positions; the lanes land on the entries ``start // stride .. (start +
+    lanes - 1) // stride``."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -306,13 +316,19 @@ def _rows_helpers(lanes, whole=frozenset()):
             name: jnp.zeros(idx.shape + a.shape[1:], a.dtype)
             for name, a in table.items()})
 
+    strides = dict(strided)
+
     def serve_rows_scatter(table, sub, idx, start, n):
         def write_row(j, table):
             out = {}
             for name, a in table.items():
+                stride = strides.get(name, 1)
+                # lanes that start anywhere touch one entry more than
+                # their share
+                span = lanes if stride == 1 else -(-lanes // stride) + 1
                 width = a.shape[1] if name in whole \
-                    else min(lanes, a.shape[1])
-                at = jnp.clip(start[j], 0, a.shape[1] - width)
+                    else min(span, a.shape[1])
+                at = jnp.clip(start[j] // stride, 0, a.shape[1] - width)
                 rest = (0,) * (a.ndim - 2)
                 out[name] = lax.dynamic_update_slice(
                     a, lax.dynamic_slice(sub[name], (j, at) + rest,
@@ -550,7 +566,9 @@ class DecodeBatcher:
     (``models.transformer.transformer_lm_step``): token/pos feed names,
     logits fetch, cache feed/fetch pairs each with its own tail shape
     and dtype (any number a layer) and optionally a ``capacity`` (a ring of
-    that many positions a row; absent: the context rung), and optionally
+    that many positions a row) or a ``stride`` (``rung / stride`` entries a
+    row, one for every ``stride`` positions; neither: the context rung), and
+    optionally
     ``counter_fetch`` /
     ``counters``: one small int vector the step program counts of itself
     and the names of its entries, added after every step to the metrics'
@@ -603,20 +621,29 @@ class DecodeBatcher:
         self._counter_idx = (
             fetch_names.index(self._spec["counter_fetch"])
             if self._counter_names else None)
-        # (feed, fetch index, tail, dtype, capacity): capacity None where
-        # the cache holds the context rung, a ring's own where it states one
+        # (feed, fetch index, tail, dtype, capacity, stride): capacity None
+        # where the cache follows the context rung, a ring's own where it
+        # states one; stride 1 where it holds the rung, the positions an
+        # entry stands for where it states one
         self._cache_feeds = []
         for cf in self._spec["cache_feeds"]:
-            cap = cf.get("capacity")
-            if cap is not None and int(cap) < 1:
-                raise ValueError("cache feed %r states a capacity of %r"
-                                 % (cf["feed"], cap))
+            cap, stride = cf.get("capacity"), cf.get("stride")
+            for what, stated in (("capacity", cap), ("stride", stride)):
+                if stated is not None and int(stated) < 1:
+                    raise ValueError("cache feed %r states a %s of %r"
+                                     % (cf["feed"], what, stated))
+            if cap is not None and stride is not None:
+                raise ValueError("cache feed %r states a capacity and a "
+                                 "stride: a ring does not follow the rung"
+                                 % cf["feed"])
             self._cache_feeds.append(
                 (cf["feed"], fetch_names.index(cf["fetch"]),
                  tuple(cf["tail"]), np.dtype(cf.get("dtype", "float32")),
-                 None if cap is None else int(cap)))
+                 None if cap is None else int(cap), int(stride or 1)))
         self._rings = frozenset(cf[0] for cf in self._cache_feeds
                                 if cf[4] is not None)
+        self._strided = frozenset((cf[0], cf[5]) for cf in self._cache_feeds
+                                  if cf[5] > 1)
         self._step = _Carrying(predictor,
                                [cf[:2] for cf in self._cache_feeds])
         # the greedy choice inside the step executable: a predictor that
@@ -644,6 +671,13 @@ class DecodeBatcher:
         if ctx_ladder is None:
             ctx_ladder = default_ctx_ladder(self._spec)
         self.ctx_ladder = tuple(sorted(set(int(c) for c in ctx_ladder)))
+        for feed, stride in sorted(self._strided):
+            odd = [c for c in self.ctx_ladder if c % stride]
+            if odd:
+                raise ValueError(
+                    "cache %r holds one entry for every %d positions: the "
+                    "context rung %d is no multiple of that" % (
+                        feed, stride, odd[0]))
         self.default_max_new_tokens = int(default_max_new_tokens)
         self.default_timeout_s = default_timeout_s
         self.eos_id = eos_id
@@ -798,12 +832,28 @@ class DecodeBatcher:
             self._thread.start()
 
     def _refuse_with_rings(self, option, why):
-        for feed, _idx, _tail, _dtype, cap in self._cache_feeds:
+        """``option`` needs caches that hold the context position by
+        position: refuse it where a cache is a ring (``why``) or is derived
+        from the others at a stride."""
+        for feed, _idx, _tail, _dtype, cap, stride in self._cache_feeds:
             if cap is not None:
                 raise ValueError(
                     "%s cannot be used with this decode spec: cache %r is "
                     "a ring of %d positions, and %s" % (option, feed, cap,
                                                         why))
+            if stride > 1:
+                raise ValueError(
+                    "%s cannot be used with this decode spec: cache %r "
+                    "holds one entry for every %d positions, derived from "
+                    "the program's other caches: it can neither be cut at "
+                    "a prefix's length nor rewound past a write" % (
+                        option, feed, stride))
+
+    @staticmethod
+    def _entries(cap, stride, c):
+        """The entries a row of a cache holds in a bucket of context ``c``:
+        a ring's own capacity, else the rung at the cache's stride."""
+        return cap or c // stride
 
     # -- client surface -----------------------------------------------------
     def now(self):
@@ -1149,11 +1199,12 @@ class DecodeBatcher:
             new_slots.append(_Slot(req))
         new_slots += [None] * (new_b - len(new_slots))
         copied = 0
-        for feed, _idx, tail, dtype, cap in self._cache_feeds:
+        for feed, _idx, tail, dtype, cap, stride in self._cache_feeds:
             old = self._caches.get(feed)
             # a ring keeps its capacity whatever the rung, and goes whole
-            copy_c = cap or min(old_c, new_c)
-            new = np.zeros((new_b, cap or new_c) + tail, dtype)
+            copy_c = self._entries(cap, stride, min(old_c, new_c))
+            new = np.zeros((new_b, self._entries(cap, stride, new_c)) + tail,
+                           dtype)
             if old is not None and live:
                 old = np.asarray(old)
                 for j, (i, _s) in enumerate(live):
@@ -1193,8 +1244,8 @@ class DecodeBatcher:
                 self._pos_feed: np.zeros(lanes, np.int32)}
 
     def _synth_caches(self, b, c):
-        return {name: np.zeros((b, cap or c) + tail, dtype)
-                for name, _idx, tail, dtype, cap in self._cache_feeds}
+        return {name: np.zeros(a.shape, a.dtype)
+                for name, a in self._cache_shapes(b, c).items()}
 
     def _tick(self, last=False):
         """One scheduler quantum: a chunk dispatch (prefill and/or
@@ -1260,11 +1311,13 @@ class DecodeBatcher:
 
     def _cache_shapes(self, rows, c):
         """{cache feed: the shape and type of its ``rows`` x ``c`` array,
-        or ``rows`` x its own capacity where it is a ring}."""
+        ``rows`` x its own capacity where it is a ring, ``rows`` x ``c /
+        stride`` where it states a stride}."""
         import jax
 
-        return {name: jax.ShapeDtypeStruct((rows, cap or c) + tail, dtype)
-                for name, _idx, tail, dtype, cap in self._cache_feeds}
+        return {name: jax.ShapeDtypeStruct(
+            (rows, self._entries(cap, stride, c)) + tail, dtype)
+            for name, _idx, tail, dtype, cap, stride in self._cache_feeds}
 
     def _stage(self, sig):
         """Make (or load) the executable of ``sig`` from shapes, nothing
@@ -1302,7 +1355,8 @@ class DecodeBatcher:
         if made is None:
             import jax
 
-            gather, scatter = _rows_helpers(min(k, c), self._rings)
+            gather, scatter = _rows_helpers(min(k, c), self._rings,
+                                            self._strided)
             table, sub = self._cache_shapes(b, c), self._cache_shapes(r, c)
             idx = jax.ShapeDtypeStruct((r,), np.dtype("int32"))
             n = jax.ShapeDtypeStruct((), np.dtype("int32"))

@@ -547,6 +547,101 @@ def test_glm_lite_programs_fit_the_chip_beside_every_expert(chip, kind):
         assert found, "no instruction under kv_cache_write"
 
 
+@pytest.mark.parametrize("kind", ["step", "chunk"])
+def test_evabyte_programs_fit_the_chip_at_the_rung_of_32768(chip, kind):
+    """``evabyte.serve.bytes.sat``'s two programs, built from the
+    configuration's own keys and compiled for one chip at 16 slot rows x
+    32768 (the chunk at its rung's sub-batch, one row of 1024 lanes): 3.24
+    GB of weights, and 8.59 GB of caches that are no rung long (a window
+    cache of 2048 positions and a summary cache of 2048 entries a row and
+    layer where a full cache would hold 32768), written in place; a chunk
+    run's scores over window, lanes and summaries leave room beside the
+    slot table."""
+    import json
+
+    import paddle_tpu as fluid
+    from paddle_tpu.core.executor import build_step_fn
+    from paddle_tpu.models import evabyte
+    from paddle_tpu.serving.decode_batcher import chunk_rows
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "evabyte-6.5b.json")) as f:
+        body = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "serve.bytes.sat.json")) as f:
+        engine = json.load(f)["engine"]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        fetch, spec = getattr(evabyte, "evabyte_" + kind)(
+            dtype="bfloat16", **{k: body[k] for k in body["builder_keys"]})
+    gb = main.global_block()
+    persist = sorted({v.name for v in main.list_vars() if v.persistable})
+    state = {n: sds(tuple(gb.var(n).shape),
+                    BF16 if gb.var(n).dtype == "bfloat16"
+                    else np.dtype(gb.var(n).dtype)) for n in persist}
+    b, c = engine["ladder"][0], engine["seq_ladder"][0]
+    assert (b, c) == (16, 32768)
+    if kind == "step":
+        rows = b
+        feed = {spec["token_feed"]: sds((b,), I32),
+                spec["pos_feed"]: sds((b,), I32)}
+    else:
+        k = engine["prefill_ladder"][0]
+        rows = chunk_rows(k, b)
+        assert (k, rows) == (1024, 1)
+        feed = {spec["token_feed"]: sds((rows, k), I32),
+                spec["pos_feed"]: sds((rows, k), I32)}
+    cache_bytes, order, handed = 0, [], []
+    for cf in spec["cache_feeds"]:
+        entries = cf.get("capacity") or c // cf["stride"]
+        assert entries == 2048
+        order.append(cf["feed"])
+        handed.append(sds((rows, entries) + tuple(cf["tail"]), BF16))
+        cache_bytes += 2 * rows * entries * cf["tail"][0]
+    assert cache_bytes == body["cache_bytes_per_row"] * rows
+    rng = jax.eval_shape(lambda: jax.random.key(0, impl="rbg"))
+    step = build_step_fn(main, [v.name for v in fetch], persist,
+                         infer_only=True)
+
+    def step_handed(state, feed, rng, handed):
+        # the caches handed over in the order of the fetches that carry
+        # them on, as ``Executor`` hands a decode loop's: 32 arrays of one
+        # shape, which jit pairs with the outputs by their order
+        return step(state, {**feed, **dict(zip(order, handed))}, rng)
+
+    compiled = _compile(chip, step_handed, state, feed, rng, tuple(handed),
+                        donate_argnums=(3,))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes       # written in place
+    assert mem.argument_size_in_bytes >= 2 * body["parameters"]
+    # the slot table stays beside a chunk's sub-batch while it runs
+    table = body["cache_bytes_per_row"] * b if kind == "chunk" else 0
+    need = mem.temp_size_in_bytes + mem.argument_size_in_bytes + table
+    print("evabyte %s: temporaries %.2f GB, arguments %.2f GB" % (
+        kind, mem.temp_size_in_bytes / 1e9, mem.argument_size_in_bytes / 1e9))
+    assert 0.65 * _HBM_BYTES < need < 0.85 * _HBM_BYTES
+    text = compiled.as_text()
+    # no cache is copied round a write (a step's scatters land in the
+    # buffers it was handed; a chunk run turns its ONE row's sixteen window
+    # caches round for the scores a head, 16 MB each, and nothing else), and
+    # no weight is turned round for a view: without the barrier before
+    # rotary the compiler lays the q and k matrices out a head at a time,
+    # 32 MB each in every run
+    copies = re.findall(r"= bf16\[%d,2048,4096\]\S* copy\(" % rows, text)
+    assert len(copies) <= (0 if kind == "step" else 16), len(copies)
+    assert not re.search(r"= bf16\[(4096,4096|4096,11008|11008,4096)\]\S* "
+                         r"(copy|transpose)\(", text)
+    assert mem.temp_size_in_bytes < (0.1e9 if kind == "step" else 1.2e9)
+    from benchmark import trace_reduce
+
+    scopes = trace_reduce.hlo_scopes(text).values()
+    for scope in ("attn.eva", "eva.summary", "kv_cache_write" + (
+            "" if kind == "step" else "_chunk")):
+        wanted = trace_reduce.scope_pattern((scope,))
+        assert any(wanted.search(s) for s in scopes), scope
+
+
 def test_glm52_step_reads_a_selection_and_names_no_latent_step_kernel():
     """``glm52.serve.longdoc.sat``'s step, traced as an Executor on one TPU
     would trace it (nothing lowered): its latent attention reads an index
