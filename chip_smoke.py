@@ -118,6 +118,11 @@ def sizes(rehearse):
             # over rows of 512 + 64)
             "latent_step": dict(rows=32, c=4096, lanes=2, heads=20, r=512,
                                 rope=64, nope=192, v=256),
+            # a step's EVA attention at evabyte.serve.bytes.sat's shape
+            # (32 heads of 128 over a window of 2048 slots and the 2048
+            # summaries of a rung of 32768)
+            "eva_step": dict(rows=16, window=2048, chunk=16, entries=2048,
+                             heads=32, d=128),
             "experts": [
                 dict(form="relu2", t=8192, d_model=4096, latent=1024,
                      f=2688, experts=512, held=8, top_k=22, score="sigmoid",
@@ -171,6 +176,8 @@ def sizes(rehearse):
                  sink=True)],
         "latent_step": dict(rows=4, c=256, lanes=2, heads=20, r=128, rope=64,
                             nope=24, v=32),
+        "eva_step": dict(rows=4, window=256, chunk=16, entries=512, heads=16,
+                         d=16),
         "experts": [
             dict(form="relu2", t=96, d_model=32, latent=128, f=256,
                  experts=16, held=4, top_k=5, score="sigmoid", scale=2.5),
@@ -822,6 +829,53 @@ def _latent_step_case(ctx, rows, c, lanes, heads, r, rope, nope, v):
             "lanes": lanes, "c": c, "err": err}
 
 
+def _eva_step_case(ctx, rows, window, chunk, entries, heads, d):
+    """A step's EVA attention over a slot table's window and summary
+    caches: the kernel ``eva_step.fwd`` (a row's window blocks up to its
+    slot, then its summary blocks up to the entries it reads) against the
+    ``jnp`` form that reads both caches whole under the mask, rows at
+    positions from 0 to the rung's last, and the wall time of each."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import eva_attention as ea
+
+    rng = np.random.RandomState(ctx["seed"])
+    bf16 = jnp.bfloat16
+    q = jnp.asarray(rng.randn(rows, heads * d), bf16)
+    caches = [jnp.asarray(rng.randn(rows, n, heads * d), bf16)
+              for n in (window, window, entries, entries)]
+    pos = jnp.asarray(np.linspace(0, entries * chunk - 1, rows).astype(
+        np.int32))
+    plan = ea.plan_for(q, *caches, heads)
+    if ctx["on_chip"]:
+        check(plan.kernel == "eva_step", "eva_step fell back: %s" % plan)
+
+    def timed(form):
+        run = jax.jit(lambda q, pos, *caches: form(
+            q, *caches, pos, heads, window, chunk))
+        out = jax.block_until_ready(run(q, pos, *caches))
+        # calls sent ahead of the device, so that the wall is the device's
+        # time and not one call's dispatch
+        t0 = time.perf_counter()
+        for _ in range(20):
+            last = run(q, pos, *caches)
+        jax.block_until_ready(last)
+        return out, (time.perf_counter() - t0) / 20 * 1e3
+
+    (ours, count), ours_ms = timed(ea.step_blocks)
+    (rung, rung_count), rung_ms = timed(ea.attend_step)
+    err = max_err(ours, rung)
+    check(err < 3e-2, "eva_step error %g" % err)
+    check(np.array_equal(np.asarray(count), np.asarray(rung_count)),
+          "eva_step counts %s, the rung form %s" % (count, rung_count))
+    return {"case": "eva_step", "plan": plan.kernel, "heads": heads,
+            "window": window, "entries": entries, "err": err,
+            "count": [int(n) for n in count], "wall_ms": ours_ms,
+            "rung_wall_ms": rung_ms}
+
+
 def phase_kernels(ctx):
     cfg = ctx["sizes"]
     cases = []
@@ -847,6 +901,8 @@ def phase_kernels(ctx):
         cases.append(_cache_step_case(ctx, **case))
         log("kernels: %s" % cases[-1])
     cases.append(_latent_step_case(ctx, **cfg["latent_step"]))
+    log("kernels: %s" % cases[-1])
+    cases.append(_eva_step_case(ctx, **cfg["eva_step"]))
     log("kernels: %s" % cases[-1])
     return {"cases": cases}
 
@@ -1192,12 +1248,12 @@ def main():
     if args.rehearse and not args.multichip:
         # (the mesh rehearsal keeps the gates honest instead: interpret
         # mode would put kernels under the mesh that the chip never sees)
-        from paddle_tpu.ops import (cache_attention, flash_attention,
-                                    fused_ce, fused_conv, grouped_experts,
-                                    scatter)
+        from paddle_tpu.ops import (cache_attention, eva_attention,
+                                    flash_attention, fused_ce, fused_conv,
+                                    grouped_experts, scatter)
 
-        for mod in (cache_attention, flash_attention, fused_ce, fused_conv,
-                    grouped_experts, scatter):
+        for mod in (cache_attention, eva_attention, flash_attention,
+                    fused_ce, fused_conv, grouped_experts, scatter):
             mod._INTERPRET = True
 
     ctx = {

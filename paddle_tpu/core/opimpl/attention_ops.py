@@ -514,17 +514,34 @@ def _eva_attention(env, op):
     lane reads the lanes up to itself that lie in its own window; ``Pos >=
     pad_pos``: a pad lane), the summary caches with the run's summaries
     written; K <= window. Strictly per-row; device events under
-    ``attn.eva``. Both forms are ``jnp``: a step reads the caches whole
-    under the mask, a chunk run in blocks under a streaming softmax up to
-    the last entry a live lane reads."""
+    ``attn.eva``.
+
+    **Which form runs where.** On ONE TPU a step reads the entries its rows
+    hold, by the Pallas kernel ``eva_step.fwd``
+    (``eva_attention.step_blocks``: a row's window blocks up to slot ``p %
+    W``, then its summary blocks up to the entries it reads, under one
+    streaming softmax). ``eva_attention.plan_for`` decides it from the
+    placement, the types and the caches' lengths, and the decision is
+    recorded in ``op.attrs["_kernel_choice"]`` and handed to the trace's
+    gate count (``gates.note``). The CPU, a mesh and a shape the gate
+    refuses read both caches whole under the mask
+    (``eva_attention.attend_step``, the kernel's reference). A chunk run is
+    ``jnp``: the caches in blocks under a streaming softmax up to the last
+    entry a live lane reads."""
     from ...ops import eva_attention
+    from ...ops.gates import note
 
     q, pos = get(env, op.input("Q")), get(env, op.input("Pos"))
     caches = _eva_caches(env, op)
     sizes = (int(op.attr("num_heads")), int(op.attr("window")),
              int(op.attr("chunk")))
     if op.type == "eva_attention":
-        out, count = eva_attention.attend_step(q, *caches, pos, *sizes)
+        plan = eva_attention.plan_for(q, *caches, sizes[0])
+        op.attrs["_kernel_choice"] = plan.to_dict()
+        note("eva_attention", plan)
+        step = (eva_attention.step_blocks if plan
+                else eva_attention.attend_step)
+        out, count = step(q, *caches, pos, *sizes)
         put(env, op.output("Count"), count)
     else:
         out = eva_attention.attend_chunk(

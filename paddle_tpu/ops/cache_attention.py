@@ -344,6 +344,38 @@ def _working_set(b, block, heads, kd, vd, itemsize, own_values=True):
     return blocks + whole + live
 
 
+def _one_type(itemsize, what):
+    """The reason that blocks a site whose queries and ``what`` are not of
+    one 2- or 4-byte floating type (``itemsize`` None), else None."""
+    if itemsize not in (2, 4):
+        return GateReason("dtype", "queries and %s are not of one 2- or "
+                          "4-byte floating type" % what)
+
+
+def _gate(kernel, reasons, block, no_block, working_set, over, admitted):
+    """A step kernel's ``GateDecision``: ``kernel``, or ``rung_xla`` with
+    the blocking reasons. ``reasons``: what the site's placement, types and
+    widths block it by (None: a check that passed). Where nothing does,
+    ``block()`` is the block the site's lengths are cut into: None blocks by
+    geometry (``no_block``), a ``working_set(block)`` over the budget by
+    vmem (``over``, a format of the block: what is held), and what neither
+    blocks is admitted with ``admitted``, a format of the block."""
+    reasons = [r for r in reasons if r is not None]
+    if not reasons:
+        size = block()
+        if size is None:
+            reasons.append(GateReason("geometry", no_block))
+        elif working_set(size) > _VMEM_BUDGET:
+            reasons.append(GateReason(
+                "vmem", "%s exceed the %.0f MB VMEM budget"
+                % (over % size, _VMEM_BUDGET / 2**20)))
+    if reasons:
+        return GateDecision(False, "rung_xla", fallback=kernel,
+                            reasons=reasons)
+    return GateDecision(True, kernel, reasons=[GateReason(
+        "shape", admitted % size, blocking=False)])
+
+
 def step_plan(b, c, heads, kv_heads, kd, vd, itemsize, window=0, ring=False,
               platform=None):
     """Which way a ``cached_attention`` site reads its caches, as a
@@ -355,18 +387,12 @@ def step_plan(b, c, heads, kv_heads, kd, vd, itemsize, window=0, ring=False,
     ``platform``: what
     ``gates.platform_reason`` says of where the step runs
     (:func:`plan_for`)."""
-    reasons = []
-    if platform is not None:
-        reasons.append(platform)
-    if window or ring:
-        reasons.append(GateReason(
-            "shape", "a %s of %d positions is read whole: no rung to cut "
-            "short" % ("ring" if ring else "window", c if ring else window)))
-    if itemsize not in (2, 4):
-        reasons.append(GateReason(
-            "dtype", "queries and caches are not of one 2- or 4-byte "
-            "floating type"))
-    else:
+    whole = GateReason(
+        "shape", "a %s of %d positions is read whole: no rung to cut short"
+        % ("ring" if ring else "window", c if ring else window))
+    reasons = [platform, whole if window or ring else None,
+               _one_type(itemsize, "caches")]
+    if reasons[-1] is None:
         r = heads // max(kv_heads, 1)
         sublanes = 32 // itemsize
         if kd % 128 or vd % 128 or heads % sublanes or (r > 1 and r % 8):
@@ -374,24 +400,14 @@ def step_plan(b, c, heads, kv_heads, kd, vd, itemsize, window=0, ring=False,
                 "geometry", "rows of %d and %d are not multiples of 128, or "
                 "%d heads no multiple of %d sublanes, or groups of %d query "
                 "heads no multiple of 8" % (kd, vd, heads, sublanes, r)))
-    block = None
-    if not reasons:
-        block = step_block(c, (kd + vd) * itemsize)
-        if block is None:
-            reasons.append(GateReason(
-                "geometry", "a rung of %d positions is no longer than one "
-                "block of a multiple of 128" % c))
-        elif _working_set(b, block, heads, kd, vd, itemsize) > _VMEM_BUDGET:
-            reasons.append(GateReason(
-                "vmem", "two blocks of %d positions of %d + %d wide rows, "
-                "double-buffered, beside %d rows' queries exceed the %.0f "
-                "MB VMEM budget" % (block, kd, vd, b, _VMEM_BUDGET / 2**20)))
-    if reasons:
-        return GateDecision(False, "rung_xla", fallback="cache_step",
-                            reasons=reasons)
-    return GateDecision(True, "cache_step", reasons=[GateReason(
-        "shape", "blocks of %d of %d positions, each row's up to its own"
-        % (block, c), blocking=False)])
+    return _gate(
+        "cache_step", reasons, lambda: step_block(c, (kd + vd) * itemsize),
+        "a rung of %d positions is no longer than one block of a multiple "
+        "of 128" % c,
+        lambda block: _working_set(b, block, heads, kd, vd, itemsize),
+        "two blocks of %%d positions of %d + %d wide rows, double-buffered, "
+        "beside %d rows' queries" % (kd, vd, b),
+        "blocks of %%d of %d positions, each row's up to its own" % c)
 
 
 def plan_for(q, k, v, heads, kv_heads, window=0, ring=False):
@@ -620,43 +636,30 @@ def latent_plan(b, c, lanes, heads, width, r, itemsize, platform=None):
     block]``. A cache of rows that are a multiple of 128 lies row-major;
     read this way it would be turned round first, so it keeps the ``jnp``
     form."""
-    reasons = []
-    if platform is not None:
-        reasons.append(platform)
-    if itemsize not in (2, 4):
-        reasons.append(GateReason(
-            "dtype", "queries and cache are not of one 2- or 4-byte "
-            "floating type"))
-    elif r % 128 or not r < width:
-        reasons.append(GateReason(
-            "geometry", "a latent part of %d columns of a row of %d is no "
-            "multiple of 128 under a rotary tail" % (r, width)))
-    elif width % 128 == 0 or width % (32 // itemsize):
-        reasons.append(GateReason(
-            "geometry", "a cache of rows of %d columns lies row-major on "
-            "the device (a multiple of 128), or its rows are no multiple of "
-            "%d sublanes: no block with the positions innermost"
-            % (width, 32 // itemsize)))
-    block = None
-    if not reasons:
+    reasons = [platform, _one_type(itemsize, "cache")]
+    queries = None
+    if reasons[-1] is None:
         queries = _query_rows(lanes, heads, itemsize)
-        block = step_block(c, width * itemsize)
-        if block is None:
+        if r % 128 or not r < width:
             reasons.append(GateReason(
-                "geometry", "a rung of %d positions is no longer than one "
-                "block of a multiple of 128" % c))
-        elif _working_set(b, block, queries, width, r, itemsize,
-                          own_values=False) > _VMEM_BUDGET:
+                "geometry", "a latent part of %d columns of a row of %d is "
+                "no multiple of 128 under a rotary tail" % (r, width)))
+        elif width % 128 == 0 or width % (32 // itemsize):
             reasons.append(GateReason(
-                "vmem", "two blocks of %d positions of %d wide rows beside "
-                "%d rows' %d queries exceed the %.0f MB VMEM budget"
-                % (block, width, b, queries, _VMEM_BUDGET / 2**20)))
-    if reasons:
-        return GateDecision(False, "rung_xla", fallback="latent_step",
-                            reasons=reasons)
-    return GateDecision(True, "latent_step", reasons=[GateReason(
-        "shape", "blocks of %d of %d positions, each row's up to its lanes' "
-        "highest" % (block, c), blocking=False)])
+                "geometry", "a cache of rows of %d columns lies row-major on "
+                "the device (a multiple of 128), or its rows are no multiple "
+                "of %d sublanes: no block with the positions innermost"
+                % (width, 32 // itemsize)))
+    return _gate(
+        "latent_step", reasons, lambda: step_block(c, width * itemsize),
+        "a rung of %d positions is no longer than one block of a multiple "
+        "of 128" % c,
+        lambda block: _working_set(b, block, queries, width, r, itemsize,
+                                   own_values=False),
+        "two blocks of %%d positions of %d wide rows beside %d rows' %s "
+        "queries" % (width, b, queries),
+        "blocks of %%d of %d positions, each row's up to its lanes' "
+        "highest" % c)
 
 
 def latent_plan_for(q, cache, r, heads):

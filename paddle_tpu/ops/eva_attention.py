@@ -48,19 +48,42 @@ Scores are scaled by ``D ** -0.5`` and kept in float32, both softmaxes and
 the pooled sums are float32; the probabilities meet the caches in the
 caches' type and products accumulate in float32. Every form is strictly
 per-row. Device events run under the scopes ``attn.eva`` and
-``eva.summary``. These are the ``jnp`` forms: a step reads both caches whole
-under the mask, whatever the rows hold; a chunk run walks them in blocks
-under a streaming softmax, up to the last entry a live lane reads.
+``eva.summary``.
+
+**Which step form runs where.** On ONE TPU a step runs the Pallas kernel
+``eva_step.fwd`` (:func:`step_blocks`): a third client of the step kernels'
+loop and streaming softmax (``cache_attention._walk_blocks``, ``_stream``),
+whose row walks TWO sources one after the other, the window cache's blocks
+``0 .. (p % window) // block`` and then the summary cache's blocks that hold
+the entries below ``(p // window) * (window / chunk)``, none where ``p <
+window``; a pass copies a block of whichever source it falls in from where
+the cache is stored, and one running maximum, sum and accumulator go through
+both. So a step reads the entries the rows hold and not both caches whole.
+:func:`step_plan` decides it from what the trace sees (placement, types, the
+two lengths' common block, the VMEM the kernel holds). The CPU, a mesh and
+every shape the gate refuses keep :func:`attend_step`, the ``jnp`` form that
+reads both caches whole under the mask, whatever the rows hold, and is the
+kernel's reference. A chunk run and both summarisers are ``jnp``: a chunk run
+walks the caches in blocks under a streaming softmax, up to the last entry a
+live lane reads.
 """
+
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
 
+from . import cache_attention as _steps
+from .gates import GateReason, platform_reason
+from .kernel_names import named_pallas_call, traced_once
 
 __all__ = ["attend_step", "attend_chunk", "summarise_step",
-           "summarise_chunk", "pool"]
+           "summarise_chunk", "pool", "step_plan", "plan_for", "step_blocks"]
 
 CHUNK_BLOCK = 256   # cache entries a chunk run's block reads at a time
+
+_INTERPRET = False  # tests flip this to run the kernel on the CPU
 
 _F32 = jnp.float32
 _LOW = float(jnp.finfo(jnp.float32).min)
@@ -192,6 +215,19 @@ def _closed(carry):
     return acc / jnp.maximum(total, 1e-30)
 
 
+def _reach(pos, window, chunk, entries):
+    """What a step's rows at ``pos`` [B] read: the window slots ``<= slot``
+    [B], the summary entries ``< read`` [B], and the step's ``Count`` [3]
+    int32 (window slots, summary entries, context positions, summed over
+    the rows)."""
+    pos = pos.reshape(-1).astype(jnp.int32)
+    slot = jnp.mod(pos, window)
+    read = (pos // window) * (window // chunk)
+    count = jnp.stack([jnp.sum(slot + 1), jnp.sum(jnp.minimum(read, entries)),
+                       jnp.sum(pos + 1)]).astype(jnp.int32)
+    return slot, read, count
+
+
 def attend_step(q, win_k, win_v, sum_k, sum_v, pos, heads, window, chunk):
     """One query a row over both caches. q [B, H*D], win_k, win_v [B, W,
     H*D] with this step's token written, sum_k, sum_v [B, L, H*D], pos [B].
@@ -209,13 +245,11 @@ def attend_step(q, win_k, win_v, sum_k, sum_v, pos, heads, window, chunk):
     _check(w, chunk, window)
     d = hd // int(heads)
     entries = sum_k.shape[1]
-    pos = pos.reshape(-1).astype(jnp.int32)
     with jax.named_scope("attn.eva"):
         own = jnp.eye(heads, dtype=bool)
         q_blocks = jnp.where(own[:, None, :], q.reshape(b, heads, d, 1),
                              0).reshape(b, hd, heads)
-        slot = jnp.mod(pos, window)
-        read = (pos // window) * (window // chunk)
+        slot, read, count = _reach(pos, window, chunk, entries)
         carry = _opened((b, heads), hd)
         for keys, values, mask in (
                 (win_k, win_v, jnp.arange(w, dtype=jnp.int32)[None]
@@ -231,9 +265,6 @@ def attend_step(q, win_k, win_v, sum_k, sum_v, pos, heads, window, chunk):
                     preferred_element_type=_F32))
         out = jnp.einsum("bhhd->bhd",
                          _closed(carry).reshape(b, heads, heads, d))
-        count = jnp.stack([jnp.sum(slot + 1),
-                           jnp.sum(jnp.minimum(read, entries)),
-                           jnp.sum(pos + 1)]).astype(jnp.int32)
     return out.reshape(b, hd).astype(q.dtype), count
 
 
@@ -321,3 +352,224 @@ def attend_chunk(q, win_k, win_v, sum_k, sum_v, new_k, new_v, pos, heads,
                      (own <= lane) & (own // window == lane // window))
         out = jnp.transpose(_closed(carry), (0, 2, 1, 3))
     return out.reshape(b, kq, hd).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# a step on one TPU: the kernel ``eva_step.fwd``
+# ---------------------------------------------------------------------------
+
+def step_block(w, entries, row_bytes):
+    """Entries a block of the step kernel reads, from either cache: what
+    ``cache_attention.step_block`` cuts the two lengths' common measure
+    into, a multiple of 128 that divides both; None where they have none.
+    For EvaByte's rows of 16 KB that rule gives the smallest, 128: a pass's
+    two copies take 2.6 us beside 0.3 us of loop, so a longer block buys
+    nothing and reads further past what a row holds, in both caches (on the
+    chip 128 is the fastest of 128, 256 and 512 at every fill: ``PERF.md``
+    section 6, PR 47)."""
+    return _steps.step_block(math.gcd(w, entries), row_bytes)
+
+
+def _working_set(b, block, heads, hd, itemsize):
+    """Bytes the kernel holds in VMEM, counted generously: two blocks of
+    keys and of values (double-buffered), the rows' queries and outputs (a
+    row a tile of sublanes, twice as operands may be held), a row's queries
+    laid out a head a row and its float32 accumulator, and the [heads,
+    block] and [heads, hd] float32 tiles live in a pass."""
+    hd = _steps._up(hd, 128)
+    blocks = 2 * 2 * block * hd * itemsize
+    whole = 2 * b * hd * ((32 // itemsize) * itemsize + 8 * 4)
+    row = heads * hd * (itemsize + 4)
+    live = 4 * heads * block * 4 + 4 * heads * hd * 4
+    return blocks + whole + row + live
+
+
+def step_plan(b, w, entries, heads, hd, itemsize, platform=None):
+    """Which way an ``eva_attention`` site reads its caches, as a
+    ``GateDecision``: ``eva_step`` (the kernel: a row's window blocks up to
+    its slot, then its summary blocks up to the entries it reads) or
+    ``rung_xla`` (the ``jnp`` form: both caches whole under the mask) with
+    the blocking reasons. ``w`` / ``entries``: the window and the summary
+    cache's lengths; ``hd``: the width of a cached row, all heads;
+    ``itemsize`` and ``platform`` as ``cache_attention.step_plan``'s."""
+    reasons = [platform, _steps._one_type(itemsize, "caches")]
+    if reasons[-1] is None and (hd % 128 or heads % (32 // itemsize)):
+        reasons.append(GateReason(
+            "geometry", "rows of %d are no multiple of 128, or %d heads no "
+            "multiple of %d sublanes" % (hd, heads, 32 // itemsize)))
+    return _steps._gate(
+        "eva_step", reasons,
+        lambda: step_block(w, entries, 2 * hd * itemsize),
+        "a window of %d slots and %d summary entries share no block of a "
+        "multiple of 128 shorter than both" % (w, entries),
+        lambda block: _working_set(b, block, heads, hd, itemsize),
+        "two blocks of %%d entries of 2 x %d wide rows, double-buffered, "
+        "beside %d rows' queries" % (hd, b),
+        "blocks of %%d of %d window slots and %d summary entries, each "
+        "row's up to what it holds" % (w, entries))
+
+
+def plan_for(q, win_k, win_v, sum_k, sum_v, heads):
+    """:func:`step_plan` of a site's arrays, where the step being traced is
+    placed."""
+    one = all(x.dtype == q.dtype for x in (win_k, win_v, sum_k, sum_v)) \
+        and jnp.issubdtype(q.dtype, jnp.floating)
+    return step_plan(win_k.shape[0], win_k.shape[1], sum_k.shape[1],
+                     int(heads), win_k.shape[2],
+                     q.dtype.itemsize if one else None,
+                     platform=platform_reason(_INTERPRET))
+
+
+class _OneOf:
+    """The async copy a pass makes: ``first()`` where ``cond`` (a traced
+    scalar), else ``second()``. Both land in the same buffer under the same
+    semaphore with the same bytes, so one wait serves either."""
+
+    def __init__(self, cond, first, second):
+        self.cond, self.first, self.second = cond, first, second
+
+    def start(self):
+        from jax.experimental import pallas as pl
+
+        pl.when(self.cond)(lambda: self.first().start())
+        pl.when(jnp.logical_not(self.cond))(lambda: self.second().start())
+
+    def wait(self):
+        self.first().wait()
+
+
+def _step_kernel(slot_ref, read_ref, q_ref, wk_hbm, wv_hbm, sk_hbm, sv_hbm,
+                 out_ref, k_buf, v_buf, sems, q_blocks, top_ref, total_ref,
+                 acc_ref, *, block, scale):
+    """``eva_step.fwd`` (``cache_attention._walk_blocks``: a row's pass
+    ``j`` is block ``j`` of its window cache while ``j < windows(row)`` and
+    block ``j - windows(row)`` of its summary cache after). slot_ref,
+    read_ref [B] int32 (SMEM): a row reads the window slots ``<= slot`` and
+    the summary entries ``< read``; q_ref [B, 1, hd]; wk_hbm, wv_hbm [B, W,
+    hd] and sk_hbm, sv_hbm [B, L, hd] where they are stored; out_ref [B, 1,
+    hd] float32; two slots of a block of keys and of values, which both
+    sources share, their copy semaphores, a row's queries laid out
+    block-diagonally ([heads, hd]: head h's values in its own columns, zeros
+    elsewhere), and its running maximum, sum and accumulator."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows = q_ref.shape[0]
+    heads, hd = acc_ref.shape
+    d = hd // heads
+
+    def own():              # the columns a head keeps
+        col = jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 1)
+        first = jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 0) * d
+        return (col >= first) & (col < first + d)
+
+    def windows(row):       # window blocks up to the one that holds its slot
+        return slot_ref[row] // block + 1
+
+    def last(row):
+        return windows(row) + (read_ref[row] + block - 1) // block - 1
+
+    def source(row, j):     # is pass j a window block, and its first entry
+        n = windows(row)
+        in_window = j < n
+        return in_window, jnp.where(in_window, j, j - n) * block
+
+    def copies(row, j, slot):
+        in_window, first = source(row, j)
+        at = pl.ds(pl.multiple_of(first, block), block)
+
+        def copy(cache, buf, i):
+            return pltpu.make_async_copy(cache.at[row, at], buf.at[slot],
+                                         sems.at[i, slot])
+
+        return tuple(
+            _OneOf(in_window, functools.partial(copy, win, buf, i),
+                   functools.partial(copy, summ, buf, i))
+            for i, (win, summ, buf) in enumerate((
+                (wk_hbm, sk_hbm, k_buf), (wv_hbm, sv_hbm, v_buf))))
+
+    def open_row():
+        top_ref[...] = jnp.full(top_ref.shape, _LOW, _F32)
+        total_ref[...] = jnp.zeros(total_ref.shape, _F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
+
+    def products(row, j, slot):
+        @pl.when(j == 0)
+        def _():
+            q_blocks[...] = jnp.where(own(), q_ref[row].astype(_F32),
+                                      0.0).astype(q_blocks.dtype)
+
+        kb, vb = k_buf[slot], v_buf[slot]
+        s = jax.lax.dot_general(q_blocks[...], kb, (((1,), (1,)), ((), ())),
+                                preferred_element_type=_F32) * scale
+        in_window, first = source(row, j)
+        at = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        end = jnp.where(in_window, slot_ref[row] + 1, read_ref[row])
+        _steps._stream(s, at < end,
+                       lambda probs: jnp.dot(probs.astype(vb.dtype), vb,
+                                             preferred_element_type=_F32),
+                       top_ref, total_ref, acc_ref)
+
+    def close_row(row):
+        y = acc_ref[...] / jnp.maximum(total_ref[...], 1e-30)
+        out_ref[row] = jnp.sum(jnp.where(own(), y, 0.0), axis=0,
+                               keepdims=True)
+
+    _steps._walk_blocks(rows, last, copies, open_row, products, close_row)
+
+
+@traced_once("eva_step.fwd", ("heads", "block", "vmem", "interpret"))
+def _step_impl(slot, read, q, win_k, win_v, sum_k, sum_v, heads, block, vmem,
+               interpret):
+    """slot, read [B] int32; q [B, 1, hd]; win_k, win_v [B, W, hd]; sum_k,
+    sum_v [B, L, hd]. Returns [B, 1, hd] float32 (:func:`_step_kernel`).
+    ``vmem``: the kernel's ``vmem_limit_bytes``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, _, hd = q.shape
+    held = pl.BlockSpec(memory_space=pltpu.VMEM)
+    stored = pl.BlockSpec(memory_space=pl.ANY)
+    return named_pallas_call(
+        "eva_step.fwd",
+        functools.partial(_step_kernel, block=block,
+                          scale=(hd // heads) ** -0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(1,),
+            in_specs=[held] + [stored] * 4, out_specs=held,
+            scratch_shapes=[pltpu.VMEM((2, block, hd), win_k.dtype),
+                            pltpu.VMEM((2, block, hd), win_v.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.VMEM((heads, hd), q.dtype),
+                            pltpu.VMEM((heads, 1), _F32),
+                            pltpu.VMEM((heads, 1), _F32),
+                            pltpu.VMEM((heads, hd), _F32)]),
+        out_shape=jax.ShapeDtypeStruct((b, 1, hd), _F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem),
+        interpret=interpret,
+    )(slot, read, q, win_k, win_v, sum_k, sum_v)
+
+
+def step_blocks(q, win_k, win_v, sum_k, sum_v, pos, heads, window, chunk):
+    """:func:`attend_step` by the kernel ``eva_step.fwd`` (what
+    :func:`plan_for` admits): the same arguments, the same two results, the
+    same sums in another order (a row's blocks of both caches one after the
+    other under a running maximum and sum, the probabilities cast to the
+    caches' type for the mix, one division at the end). A row reads its
+    window cache's blocks up to the one that holds slot ``pos % window`` and
+    the summary cache's up to entry ``(pos // window) * (window / chunk)``,
+    none inside its first window; the count is the entries the rows read and
+    hold, not the entries fetched."""
+    b, w, hd = win_k.shape
+    window, chunk, heads = int(window), int(chunk), int(heads)
+    _check(w, chunk, window)
+    entries = sum_k.shape[1]
+    with jax.named_scope("attn.eva"):
+        slot, read, count = _reach(pos, window, chunk, entries)
+        out = _step_impl(
+            slot, jnp.clip(read, 0, entries), q.reshape(b, 1, hd), win_k,
+            win_v, sum_k, sum_v, heads=heads,
+            block=step_block(w, entries, 2 * hd * win_k.dtype.itemsize),
+            vmem=_steps._VMEM_BUDGET, interpret=_INTERPRET)
+    return out.reshape(b, hd).astype(q.dtype), count

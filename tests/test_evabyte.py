@@ -13,6 +13,7 @@ refused at construction."""
 import os
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -103,7 +104,7 @@ def _programs(dtype="float32", sizes=TINY):
 
 
 def _serve(ladder, prompts=PROMPTS, budget=None, monkeypatch=None,
-           dtype="float32", slots=4, ctx_ladder=(128,), new=NEW):
+           dtype="float32", slots=4, ctx_ladder=(128,), new=NEW, sizes=TINY):
     """Requests in a bucket of ``slots`` rows, prompts by chunks of
     ``ladder`` (None: by forced steps alone) and answers by steps. Returns
     (weights, [(prompt, served tokens, {position: the step program's logits
@@ -111,7 +112,7 @@ def _serve(ladder, prompts=PROMPTS, budget=None, monkeypatch=None,
     chunk runs' fed positions)."""
     if budget is not None:
         monkeypatch.setattr(decode_batcher, "CHUNK_TOKEN_BUDGET", budget)
-    predictors, specs, weights = _programs(dtype)
+    predictors, specs, weights = _programs(dtype, sizes)
     step = Recorded(predictors["step"], specs["step"]["pos_feed"])
     chunk = Recorded(predictors["chunk"], specs["chunk"]["pos_feed"])
     prefill = None if ladder is None else {
@@ -123,6 +124,7 @@ def _serve(ladder, prompts=PROMPTS, budget=None, monkeypatch=None,
                for i, n in enumerate(prompts)]
     futures = [batcher.submit(p, max_new_tokens=new) for p in prompts]
     batcher.drive()
+    batcher.step_ops = predictors["step"]._program.global_block().ops
     served = []
     for row, (prompt, future) in enumerate(zip(prompts, futures)):
         tokens = np.asarray(future.result())
@@ -180,6 +182,38 @@ def test_chunks_then_steps_give_the_references_logits(case, monkeypatch):
         assert batcher._rows_staged
     _hold(weights, served)
     assert specs["chunk"].get("logits_fetch") is None   # it only ingests
+
+
+# the twin with caches the step kernel takes: rows of 128 (8 heads of 16), a
+# window of two blocks of 128 slots, and as many summary entries a rung
+WIDE = dict(TINY, hidden_size=128, num_attention_heads=8,
+            num_key_value_heads=8, window_size=256, chunk_size=16,
+            max_position_embeddings=4096)
+
+
+@pytest.mark.parametrize("path", ["eva_step", "rung_xla"])
+def test_steps_give_the_references_logits_by_the_kernel_and_by_the_jnp_form(
+        path, monkeypatch):
+    """Requests whose steps read a window's second block and the summaries
+    of one and of two earlier windows: through the step kernel where its
+    gate admits the site (the interpreter stands in for the TPU here) and
+    through the ``jnp`` form where it does not (the CPU), the same rows of
+    the reference."""
+    monkeypatch.setattr(eva_attention, "_INTERPRET", path == "eva_step")
+    jax.clear_caches()
+    weights, served, batcher, _, _ = _serve(
+        (256,), prompts=(300, 650), new=4, slots=2, ctx_ladder=(4096,),
+        sizes=WIDE)
+    jax.clear_caches()
+    took = [op.attrs["_kernel_choice"]["kernel"] for op in batcher.step_ops
+            if op.type == "eva_attention"]
+    assert took == [path] * TINY["num_hidden_layers"]
+    for prompt, tokens, logits in served:
+        full = _reference(weights, prompt, tokens, **WIDE)
+        at = slice(len(prompt) - 1, len(prompt) - 1 + len(tokens))
+        np.testing.assert_allclose(_sampled(prompt, tokens, logits),
+                                   full[at], **TOL)
+        assert (np.argmax(full[at], -1) == tokens).all()
 
 
 @pytest.fixture(scope="module")

@@ -398,6 +398,40 @@ def test_latent_step_compiles_within_the_vmem_the_gate_counts(
                          % (b, c, r + p, r + p, c), compiled.as_text())
 
 
+def test_eva_step_compiles_within_the_vmem_the_gate_counts(chip, monkeypatch):
+    """The step kernel behind ``eva_attention`` at
+    ``evabyte.serve.bytes.sat``'s own shape (16 rows, a window of 2048 slots
+    and 2048 summary entries of 32 heads of 128), ``vmem_limit_bytes`` set
+    to the working set its gate counts: the gate admits the shape, Mosaic
+    needs no more than is counted, the kernel is there by name under
+    ``attn.eva``, and none of the four caches is copied or transposed on its
+    way in (the kernel reads them where and as they are stored)."""
+    from paddle_tpu.ops import cache_attention as ca
+    from paddle_tpu.ops import eva_attention as ea
+
+    b, w, entries, heads, d, chunk = 16, 2048, 2048, 32, 128, 16
+    q = sds((b, heads * d), BF16)
+    caches = [sds((b, n, heads * d), BF16) for n in (w, w, entries, entries)]
+    with placed("tpu"):
+        plan = ea.plan_for(q, *caches, heads)
+    assert plan.kernel == "eva_step", plan
+    block = ea.step_block(w, entries, 2 * heads * d * 2)
+    counted = ea._working_set(b, block, heads, heads * d, 2)
+    assert block == 128 and counted <= ca._VMEM_BUDGET
+    monkeypatch.setattr(ca, "_VMEM_BUDGET", counted)
+
+    def step(q, wk, wv, sk, sv, pos):
+        return ea.step_blocks(q, wk, wv, sk, sv, pos, heads, w, chunk)
+
+    compiled = _compile(chip, step, q, *caches, sds((b,), I32))
+    _assert_named(compiled, {"eva_step.fwd"})
+    (_, path), = _kernel_names(compiled)
+    assert "attn.eva" in path.split("/")
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.02e9
+    assert not re.search(r"= bf16\[%d,%d,\d+\]\S* (copy|transpose)\("
+                         % (b, w), compiled.as_text())
+
+
 def test_mimo_v2_step_reads_its_weights_where_they_lie_and_fits_the_chip(
         chip):
     """The cell's step program, built from the configuration's own keys
@@ -633,6 +667,13 @@ def test_evabyte_programs_fit_the_chip_at_the_rung_of_32768(chip, kind):
     assert not re.search(r"= bf16\[(4096,4096|4096,11008|11008,4096)\]\S* "
                          r"(copy|transpose)\(", text)
     assert mem.temp_size_in_bytes < (0.1e9 if kind == "step" else 1.2e9)
+    # a step's attention is the step kernel, a call a layer, under its
+    # scope; a chunk run's stays ``jnp``
+    calls = _kernel_names(compiled)
+    assert len(calls) == (body["num_hidden_layers"] if kind == "step" else 0)
+    for instruction, path in calls:
+        assert instruction.startswith("eva_step.fwd")
+        assert {"attn.eva", "eva_step.fwd"} <= set(path.split("/"))
     from benchmark import trace_reduce
 
     scopes = trace_reduce.hlo_scopes(text).values()
