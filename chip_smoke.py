@@ -123,6 +123,10 @@ def sizes(rehearse):
             # summaries of a rung of 32768)
             "eva_step": dict(rows=16, window=2048, chunk=16, entries=2048,
                              heads=32, d=128),
+            # a chunk run's attention at a full layer's shape of
+            # mimo2flash.serve.mixedlen.sat (one row of 1024 lanes)
+            "cache_chunk": dict(rows=1, lanes=1024, c=16384, heads=64,
+                                kv_heads=4, dk=192, dv=128, sink=False),
             "experts": [
                 dict(form="relu2", t=8192, d_model=4096, latent=1024,
                      f=2688, experts=512, held=8, top_k=22, score="sigmoid",
@@ -178,6 +182,8 @@ def sizes(rehearse):
                             nope=24, v=32),
         "eva_step": dict(rows=4, window=256, chunk=16, entries=512, heads=16,
                          d=16),
+        "cache_chunk": dict(rows=2, lanes=256, c=512, heads=8, kv_heads=2,
+                            dk=192, dv=128, sink=True),
         "experts": [
             dict(form="relu2", t=96, d_model=32, latent=128, f=256,
                  experts=16, held=4, top_k=5, score="sigmoid", scale=2.5),
@@ -790,6 +796,54 @@ def _cache_step_case(ctx, rows, c, heads, kv_heads, dk, dv, sink):
             "kv_heads": kv_heads, "c": c, "err": err}
 
 
+def _cache_chunk_case(ctx, rows, lanes, c, heads, kv_heads, dk, dv, sink):
+    """A chunk run's attention over a slot table's caches: the kernel
+    ``cache_chunk.fwd`` (a tile of lanes' scores in VMEM) against the ``jnp``
+    form that walks the same blocks as XLA loops, the rows' chunks ending
+    at positions up to the rung's last, the last row's last lanes pad
+    lanes; with a wall time of each."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import cache_attention as ca
+
+    rng = np.random.RandomState(ctx["seed"])
+    bf16 = jnp.bfloat16
+    q = jnp.asarray(rng.randn(rows, lanes, heads * dk), bf16)
+    k = jnp.asarray(rng.randn(rows, c, kv_heads * dk), bf16)
+    v = jnp.asarray(rng.randn(rows, c, kv_heads * dv), bf16)
+    s = jnp.asarray(rng.randn(heads), bf16) if sink else None
+    first = np.linspace(c // 2 - lanes, c - lanes, rows).astype(np.int32)
+    pos = first[:, None] + np.arange(lanes, dtype=np.int32)[None]
+    pos[-1, lanes - 37:] = c
+    live = pos < c
+    pos = jnp.asarray(pos)
+    plan = ca.chunk_plan_for(q, k, v, heads, kv_heads)
+    if ctx["on_chip"]:
+        check(plan.kernel == "cache_chunk",
+              "cache_chunk fell back: %s" % plan)
+    forms = {"kernel": jax.jit(lambda q, k, v, pos, s: ca.chunk_blocks(
+                 q, k, v, pos, heads, kv_heads, s)),
+             "jnp": jax.jit(lambda q, k, v, pos, s: ca.attend_chunk(
+                 q, k, v, pos, heads, kv_heads, 0, s))}
+    outs, ms = {}, {}
+    for name, form in forms.items():
+        form(q, k, v, pos, s).block_until_ready()
+        t = time.perf_counter()
+        outs[name] = form(q, k, v, pos, s).block_until_ready()
+        ms[name] = (time.perf_counter() - t) * 1e3
+    err = float(np.max(np.abs(
+        np.asarray(outs["kernel"].astype(jnp.float32))
+        - np.asarray(outs["jnp"].astype(jnp.float32)))[live]))
+    check(err < 3e-2, "cache_chunk error %g" % err)
+    return {"case": "cache_chunk", "plan": plan.kernel, "heads": heads,
+            "kv_heads": kv_heads, "lanes": lanes, "c": c, "err": err,
+            "wall_ms": ms}
+
+
 def _latent_step_case(ctx, rows, c, lanes, heads, r, rope, nope, v):
     """A verifying step's latent attention over a slot table's latent
     caches: the kernel ``latent_step.fwd`` (a row's blocks up to the highest
@@ -903,6 +957,8 @@ def phase_kernels(ctx):
     cases.append(_latent_step_case(ctx, **cfg["latent_step"]))
     log("kernels: %s" % cases[-1])
     cases.append(_eva_step_case(ctx, **cfg["eva_step"]))
+    log("kernels: %s" % cases[-1])
+    cases.append(_cache_chunk_case(ctx, **cfg["cache_chunk"]))
     log("kernels: %s" % cases[-1])
     return {"cases": cases}
 

@@ -306,7 +306,17 @@ def _cached_attention_chunk(env, op):
     with ``NewK`` / ``NewV`` [B, K, ..]: CacheK / CacheV are rings as they
     were BEFORE the chunk, the chunk's own keys and values come beside
     them, and a lane reads both inside its window
-    (``cache_attention.attend_chunk_ring``); the rings are written after."""
+    (``cache_attention.attend_chunk_ring``); the rings are written after.
+
+    **Which form runs where.** On ONE TPU the extended form over a cache
+    that holds the context (no window, no ring) is the Pallas kernel
+    ``cache_chunk.fwd`` (``cache_attention.chunk_blocks``: the same blocks,
+    a tile of lanes' scores in VMEM and never in HBM), under its scope
+    ``attn.full``. ``cache_attention.chunk_plan_for`` decides it from the
+    placement, the types, the widths and the lengths, recorded as
+    ``cached_attention``'s decision is. The CPU, a mesh, a window, a ring
+    and a shape the gate refuses lower as they always have, and so does the
+    plain form below."""
     q = get(env, op.input("Q"))
     k = get(env, op.input("CacheK"))
     v = get(env, op.input("CacheV"))
@@ -315,9 +325,17 @@ def _cached_attention_chunk(env, op):
     more = _extended(env, op, k, v)
     if more is not None:
         from ...ops import cache_attention
+        from ...ops.gates import note
 
         kv_heads, window, ring, sink = more
-        if ring:
+        plan = cache_attention.chunk_plan_for(q, k, v, h, kv_heads, window,
+                                              ring)
+        op.attrs["_kernel_choice"] = plan.to_dict()
+        note("cached_attention_chunk", plan)
+        if plan:
+            out = cache_attention.chunk_blocks(q, k, v, pos, h, kv_heads,
+                                               sink)
+        elif ring:
             out = cache_attention.attend_chunk_ring(
                 q, k, v, get(env, op.input("NewK")),
                 get(env, op.input("NewV")), pos, h, kv_heads, window, sink)

@@ -62,6 +62,30 @@ because the device stores a cache of 576-wide rows with the POSITIONS
 innermost, so a latent block is ``[R+P, block]`` and both products run the
 other way round. :func:`latent_plan` decides it; the CPU, a mesh and a
 refused shape keep ``sparse_latent.latent_attention_dense``'s ``jnp`` form.
+
+**Which chunk form runs where.** A chunk of K lanes over a cache that holds
+the whole context (no window, no ring) on ONE TPU runs the Pallas kernel
+``cache_chunk.fwd`` (:func:`chunk_blocks`), a fourth client of the same loop
+and streaming softmax: :func:`attend_chunk`'s walk, a row's blocks from 0 up,
+with a block's scores in VMEM where XLA's loops send them through HBM
+several times ([4, 16, 1024, 512] float32, 134 MB a block, at
+``mimo2flash.serve.mixedlen.sat``'s full layers). One pass of its grid is a
+(row, tile of ``CHUNK_TILE`` lanes, key/value group): the group's query heads
+x the tile's lanes are one operand, a block of the group's keys is read once
+for all of them, and the walk ends at the block that holds the highest
+position a live lane OF THE TILE holds, so a tile early in the chunk does not
+pay for the chunk's later lanes and a tile of pad lanes costs no block. A
+group's key columns begin at ``gi * Dk``, no multiple of 128 where Dk is 192;
+the kernel copies the aligned window of the row that holds them and the
+queries carry zeros in the window's other columns (:func:`_key_windows`: 256
+for 192, which costs the MXU what 192 does; not the step kernels'
+block-diagonal queries over the whole row, which would cost it 4x).
+:func:`chunk_plan` decides it from what the trace sees (placement, one type,
+no window and no ring, K a multiple of the tile, a block that divides the
+rung, widths of whole 128s, the working set). The CPU, a mesh, a window
+without a ring, a ring and every refused shape keep the ``jnp`` forms
+(:func:`attend_chunk`, :func:`attend_chunk_ring`), which are the kernel's
+reference. A sink opens the kernel's softmax as it opens the step kernel's.
 """
 
 import contextlib
@@ -79,7 +103,8 @@ from .sparse_latent import _block
 
 __all__ = ["attend_step", "attend_chunk", "attend_chunk_ring",
            "ring_positions", "ring_slots", "step_plan", "plan_for",
-           "step_blocks", "latent_plan", "latent_plan_for", "latent_blocks"]
+           "step_blocks", "latent_plan", "latent_plan_for", "latent_blocks",
+           "chunk_plan", "chunk_plan_for", "chunk_blocks"]
 
 ATTN_BLOCK = 512     # cache positions a chunk's block reads at a time
 
@@ -344,6 +369,15 @@ def _working_set(b, block, heads, kd, vd, itemsize, own_values=True):
     return blocks + whole + live
 
 
+def _itemsize(*arrays):
+    """The item size of the one floating type ``arrays`` have, None where
+    they have not one: what the plans take as ``itemsize``."""
+    first = arrays[0].dtype
+    one = all(a.dtype == first for a in arrays) \
+        and jnp.issubdtype(first, jnp.floating)
+    return first.itemsize if one else None
+
+
 def _one_type(itemsize, what):
     """The reason that blocks a site whose queries and ``what`` are not of
     one 2- or 4-byte floating type (``itemsize`` None), else None."""
@@ -413,12 +447,9 @@ def step_plan(b, c, heads, kv_heads, kd, vd, itemsize, window=0, ring=False,
 def plan_for(q, k, v, heads, kv_heads, window=0, ring=False):
     """:func:`step_plan` of a site's arrays, where the step being traced is
     placed."""
-    one = q.dtype == k.dtype == v.dtype and jnp.issubdtype(k.dtype,
-                                                           jnp.floating)
-    itemsize = k.dtype.itemsize if one else None
     return step_plan(k.shape[0], k.shape[1], int(heads), int(kv_heads),
-                     k.shape[2], v.shape[2], itemsize, window, ring,
-                     platform=platform_reason(_INTERPRET))
+                     k.shape[2], v.shape[2], _itemsize(q, k, v), window,
+                     ring, platform=platform_reason(_INTERPRET))
 
 
 def _walk_blocks(rows, last, copies, open_row, products, close_row):
@@ -459,20 +490,35 @@ def _walk_blocks(rows, last, copies, open_row, products, close_row):
     jax.lax.fori_loop(0, passes, one, (jnp.int32(0), jnp.int32(0)))
 
 
-def _stream(s, live, mix, top_ref, total_ref, acc_ref):
+def _stream(s, live, mix, top_ref, total_ref, acc_ref, axis=-1):
     """A block's turn of the streaming softmax: s [heads, block] float32
     scores, ``live`` where a query row reads the position, ``mix(probs)``
     the block's [heads, vd] float32 product with its values; a row's
-    running maximum, sum and accumulator move on."""
+    running maximum, sum and accumulator move on. ``axis`` 0: the same
+    turned round, s [block, heads] and the product [vd, heads], the
+    positions along the sublanes and a query row a lane."""
     s = jnp.where(live, s, _LOW)
     top = top_ref[...]
-    new_top = jnp.maximum(top, jnp.max(s, axis=-1, keepdims=True))
+    new_top = jnp.maximum(top, jnp.max(s, axis=axis, keepdims=True))
     probs = jnp.where(live, jnp.exp(s - new_top), 0.0)
     keep = jnp.exp(top - new_top)
     top_ref[...] = new_top
     total_ref[...] = total_ref[...] * keep \
-        + jnp.sum(probs, axis=-1, keepdims=True)
+        + jnp.sum(probs, axis=axis, keepdims=True)
     acc_ref[...] = acc_ref[...] * keep + mix(probs)
+
+
+def _open(sink_ref, top_ref, total_ref, acc_ref):
+    """Before a query row's first block: nothing read yet, or, where there
+    is a sink (``sink_ref`` of ``top_ref``'s shape), one term of weight 1 at
+    its own height, and no value."""
+    if sink_ref is None:
+        top_ref[...] = jnp.full(top_ref.shape, _LOW, _F32)
+        total_ref[...] = jnp.zeros(total_ref.shape, _F32)
+    else:
+        top_ref[...] = sink_ref[...]
+        total_ref[...] = jnp.ones(total_ref.shape, _F32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
 
 
 def _step_kernel(pos_ref, q_ref, own_ref, *refs, block, scale, groups,
@@ -508,16 +554,7 @@ def _step_kernel(pos_ref, q_ref, own_ref, *refs, block, scale, groups,
                 pltpu.make_async_copy(v_hbm.at[row, at], v_buf.at[slot],
                                       sems.at[1, slot]))
 
-    def open_row():
-        if sink_ref is None:
-            top_ref[...] = jnp.full(top_ref.shape, _LOW, _F32)
-            total_ref[...] = jnp.zeros(total_ref.shape, _F32)
-        else:
-            # the sink opens the streaming softmax: one term of weight
-            # 1 at its own height, and no value
-            top_ref[...] = sink_ref[...]
-            total_ref[...] = jnp.ones(total_ref.shape, _F32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
+    open_row = functools.partial(_open, sink_ref, top_ref, total_ref, acc_ref)
 
     def products(row, j, slot):
         kb, vb = k_buf[slot], v_buf[slot]
@@ -665,10 +702,8 @@ def latent_plan(b, c, lanes, heads, width, r, itemsize, platform=None):
 def latent_plan_for(q, cache, r, heads):
     """:func:`latent_plan` of a site's arrays (q [B, K, ..], cache [B, C,
     R+P]), where the step being traced is placed."""
-    one = q.dtype == cache.dtype and jnp.issubdtype(cache.dtype, jnp.floating)
     return latent_plan(cache.shape[0], cache.shape[1], q.shape[1], int(heads),
-                       cache.shape[2], int(r),
-                       cache.dtype.itemsize if one else None,
+                       cache.shape[2], int(r), _itemsize(q, cache),
                        platform=platform_reason(_INTERPRET))
 
 
@@ -707,10 +742,7 @@ def _latent_kernel(pos_ref, q_ref, cache_hbm, out_ref, buf, sems, top_ref,
         return (pltpu.make_async_copy(cache_hbm.at[row, :, at], buf.at[slot],
                                       sems.at[slot]),)
 
-    def open_row():
-        top_ref[...] = jnp.full(top_ref.shape, _LOW, _F32)
-        total_ref[...] = jnp.zeros(total_ref.shape, _F32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
+    open_row = functools.partial(_open, None, top_ref, total_ref, acc_ref)
 
     def products(row, j, slot):
         s = jnp.dot(q_ref[row], buf[slot],
@@ -792,3 +824,259 @@ def latent_blocks(qa, cache, pos, r, scale):
         scale=float(scale), block=step_block(c, width * cache.dtype.itemsize),
         vmem=_VMEM_BUDGET, interpret=_INTERPRET)
     return mixed[:, :kq * heads].reshape(b, kq, heads, r)
+
+
+# ---------------------------------------------------------------------------
+# a chunk on one TPU: the kernel ``cache_chunk.fwd``
+# ---------------------------------------------------------------------------
+
+CHUNK_TILE = 128     # lanes of a chunk whose scores VMEM holds at a time
+
+
+def chunk_block(c):
+    """Positions a block of the chunk kernel reads: the largest multiple of
+    128 that divides ``c`` and is at most ``ATTN_BLOCK``, None where ``c``
+    has none. The work is the MXU's and not the cache's read, so what bounds
+    a block is the [query rows, block] float32 scores VMEM holds, not
+    :func:`step_block`'s price of a pass."""
+    fits = [n for n in range(128, min(c, ATTN_BLOCK) + 1, 128) if c % n == 0]
+    return max(fits, default=None)
+
+
+def _key_windows(g, dk):
+    """Where the chunk kernel finds each group's key columns in a cached row
+    of ``g * dk`` (a multiple of 128): ``(width, [first column a group])``,
+    the narrowest windows of one width that begin at a multiple of 128 and
+    hold a group's ``dk`` columns. A group's columns begin at ``gi * dk``,
+    which is no multiple of 128 where ``dk`` is none (192: 0, 192, 384,
+    576); a window of ``width`` (256: from 0, 128, 384, 512) is a slice a
+    copy can take, and the queries carry zeros in the window's other
+    columns. The MXU contracts 128 columns a pass, so 256 costs what 192
+    does."""
+    width = max(_up(gi * dk % 128 + dk, 128) for gi in range(g))
+    return width, [min(gi * dk // 128 * 128, g * dk - width)
+                   for gi in range(g)]
+
+
+def chunk_plan(b, c, lanes, heads, kv_heads, kd, vd, itemsize, window=0,
+               ring=False, platform=None):
+    """Which way a ``cached_attention_chunk`` site with grouped heads or a
+    sink reads its caches, as a ``GateDecision``: ``cache_chunk`` (the
+    kernel: the scores of a tile of lanes and a block of positions stay in
+    VMEM) or ``rung_xla`` (:func:`attend_chunk` / :func:`attend_chunk_ring`:
+    the same blocks as XLA loops, a block's scores through HBM) with the
+    blocking reasons. ``lanes``: K; the other arguments as
+    :func:`step_plan`'s."""
+    whole = GateReason(
+        "shape", "a %s of %d positions: the kernel walks a cache that holds "
+        "the context from position 0" % ("ring" if ring else "window",
+                                         c if ring else window))
+    reasons = [platform, whole if window or ring else None,
+               _one_type(itemsize, "caches")]
+    g = max(kv_heads, 1)
+    r, dv = heads // g, vd // g
+    if reasons[-1] is None and (kd % 128 or dv % 128 or lanes % CHUNK_TILE):
+        reasons.append(GateReason(
+            "geometry", "key rows of %d or a group's values of %d are no "
+            "multiple of 128, or %d lanes no multiple of the tile of %d"
+            % (kd, dv, lanes, CHUNK_TILE)))
+    width = _key_windows(g, kd // g)[0] if kd % 128 == 0 else kd
+    return _gate(
+        "cache_chunk", reasons, lambda: chunk_block(c),
+        "a cache of %d positions has no block of a multiple of 128" % c,
+        lambda block: _working_set(1, block, r * CHUNK_TILE, width, dv,
+                                   itemsize),
+        "two blocks of %%d positions of %d + %d columns, double-buffered, "
+        "beside %d heads' scores of %d lanes" % (width, dv, r, CHUNK_TILE),
+        "blocks of %%d of %d positions, each tile of %d lanes up to its "
+        "highest" % (c, CHUNK_TILE))
+
+
+def chunk_plan_for(q, k, v, heads, kv_heads, window=0, ring=False):
+    """:func:`chunk_plan` of a site's arrays (q [B, K, ..]), where the chunk
+    run being traced is placed."""
+    return chunk_plan(k.shape[0], k.shape[1], q.shape[1], int(heads),
+                      int(kv_heads), k.shape[2], v.shape[2],
+                      _itemsize(q, k, v), window, ring,
+                      platform=platform_reason(_INTERPRET))
+
+
+def _chunk_kernel(high_ref, low_ref, first_ref, pos_ref, q_ref, *refs, block,
+                  scale, with_sink):
+    """``cache_chunk.fwd``, one pass of its grid a (row, tile of lanes,
+    key/value group): :func:`_walk_blocks` over the row's blocks from 0 to
+    the one that holds the highest position a live lane of the tile holds,
+    none where the tile has no live lane. The group's ``r`` query heads x
+    the tile's lanes are ONE operand of ``m = r * tile`` query rows, and a
+    query row is a LANE: the scores of a block are ``[block, m]``, so the
+    softmax's maximum and sum run down the sublanes (the VPU, a vreg at a
+    time) and a row's running statistics are ``[1, m]``, 16 vregs and not
+    256; with the query rows along the sublanes the kernel spent its time
+    in reductions across lanes and in ``[m, 1]`` columns, whatever the
+    block (PERF.md, PR 48).
+
+    high_ref [B * tiles] int32 (SMEM): the tile's highest live position, -1
+    for none; low_ref [B * tiles] int32 (SMEM): the lowest position a lane
+    of the tile holds (a block that ends at or before it is read whole by
+    every lane: its turn of the softmax needs no mask); first_ref [groups]
+    int32 (SMEM): the first column of a group's key window
+    (:func:`_key_windows`); pos_ref [1, m] int32: the query rows' positions;
+    q_ref [width, m]: the group's queries turned round, a head's Dk values
+    in the rows where its keys lie in the window, zeros elsewhere; then
+    sink_ref [1, m] float32 if there is a sink; k_hbm [B, C, kd], v_hbm [B,
+    C, vd] where they are stored; out_ref [r, tile, dv]; two slots of a
+    block of the group's key window and of its values, their copy
+    semaphores, and the query rows' running maximum and sum [1, m] and
+    accumulator [dv, m]."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    sink_ref = None
+    if with_sink:
+        sink_ref, *refs = refs
+    (k_hbm, v_hbm, out_ref, k_buf, v_buf, sems, top_ref, total_ref,
+     acc_ref) = refs
+    r, tile, dv = out_ref.shape
+    width = q_ref.shape[0]
+    row, gi = pl.program_id(0), pl.program_id(2)
+    tile_at = row * pl.num_programs(1) + pl.program_id(1)
+    high, low = high_ref[tile_at], low_ref[tile_at]
+
+    def copies(_, j, slot):
+        at = pl.ds(pl.multiple_of(j * block, block), block)
+        keys = pl.ds(pl.multiple_of(first_ref[gi], 128), width)
+        values = pl.ds(pl.multiple_of(gi * dv, 128), dv)
+        return (pltpu.make_async_copy(k_hbm.at[row, at, keys],
+                                      k_buf.at[slot], sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[row, at, values],
+                                      v_buf.at[slot], sems.at[1, slot]))
+
+    open_row = functools.partial(_open, sink_ref, top_ref, total_ref, acc_ref)
+
+    def products(_, j, slot):
+        vb = v_buf[slot]
+        s = jnp.dot(k_buf[slot], q_ref[...],
+                    preferred_element_type=_F32) * scale
+
+        def turn(live):
+            _stream(s, live,
+                    lambda probs: jax.lax.dot_general(
+                        vb, probs.astype(vb.dtype), (((0,), (0,)), ((), ())),
+                        preferred_element_type=_F32),
+                    top_ref, total_ref, acc_ref, axis=0)
+
+        inside = (j + 1) * block <= low + 1
+
+        @pl.when(inside)
+        def _():
+            turn(True)
+
+        @pl.when(jnp.logical_not(inside))
+        def _():
+            at = j * block + jax.lax.broadcasted_iota(
+                jnp.int32, (block, 1), 0)
+            turn(at <= pos_ref[...])
+
+    def close_row(_):
+        y = acc_ref[...] / jnp.maximum(total_ref[...], 1e-30)
+        out_ref[...] = y.T.reshape(r, tile, dv).astype(out_ref.dtype)
+
+    @pl.when(high >= 0)
+    def _():
+        _walk_blocks(1, lambda _: high // block, copies, open_row, products,
+                     close_row)
+
+    @pl.when(high < 0)
+    def _():
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+
+@traced_once("cache_chunk.fwd", ("r", "block", "scale", "vmem", "interpret"))
+def _chunk_impl(high, low, first, pos, queries, sink, k, v, r, block, scale,
+                vmem, interpret):
+    """high, low [B * tiles] int32; first [groups] int32; pos [B, tiles, 1,
+    m] int32 (``m = r * tile``); queries [B, groups, tiles, width, m]; sink
+    [groups, 1, m] float32 or None; k [B, C, kd], v [B, C, vd]. Returns [B,
+    groups, r, K, dv] in the queries' type (:func:`_chunk_kernel`).
+    ``vmem``: the kernel's ``vmem_limit_bytes``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, g, tiles, width, m = queries.shape
+    dv = v.shape[2] // g
+    tile = m // r
+    stored = pl.BlockSpec(memory_space=pl.ANY)
+    specs = [pl.BlockSpec((None, None, 1, m),
+                          lambda bi, t, gi, *_: (bi, t, 0, 0)),
+             pl.BlockSpec((None, None, None, width, m),
+                          lambda bi, t, gi, *_: (bi, gi, t, 0, 0))]
+    if sink is not None:
+        specs.append(pl.BlockSpec((None, 1, m),
+                                  lambda bi, t, gi, *_: (gi, 0, 0)))
+    arrays = [pos, queries] + ([] if sink is None else [sink]) + [k, v]
+    return named_pallas_call(
+        "cache_chunk.fwd",
+        functools.partial(_chunk_kernel, block=block, scale=scale,
+                          with_sink=sink is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, tiles, g),
+            in_specs=specs + [stored, stored],
+            out_specs=pl.BlockSpec((None, None, r, tile, dv),
+                                   lambda bi, t, gi, *_: (bi, gi, 0, t, 0)),
+            scratch_shapes=[pltpu.VMEM((2, block, width), k.dtype),
+                            pltpu.VMEM((2, block, dv), v.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.VMEM((1, m), _F32),
+                            pltpu.VMEM((1, m), _F32),
+                            pltpu.VMEM((dv, m), _F32)]),
+        out_shape=jax.ShapeDtypeStruct((b, g, r, tiles * tile, dv),
+                                       queries.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3, vmem_limit_bytes=vmem),
+        interpret=interpret,
+    )(high, low, first, *arrays)
+
+
+def chunk_blocks(q, k, v, pos, heads, kv_heads, sink=None):
+    """:func:`attend_chunk` without a window, by the kernel
+    ``cache_chunk.fwd`` (what :func:`chunk_plan_for` admits): the same
+    arguments, the same result on every live lane, the same sums in the
+    same order of blocks (the probabilities cast to the cache's type for
+    the mix, one division at the end). A tile of ``CHUNK_TILE`` lanes reads
+    the blocks up to the one that holds the highest position its live lanes
+    hold and a tile of pad lanes alone reads nothing and comes out 0; a pad
+    lane beside live ones reads what the tile reads.
+
+    The queries are handed over a group and a tile at a time and turned
+    round, [B, groups, tiles, width, r * tile] (:func:`_key_windows`; a
+    query row a lane, :func:`_chunk_kernel`), and the result comes back a
+    head at a time: two transpositions of the chunk's own activations that
+    XLA makes, none of a cache."""
+    b, c, kd = k.shape
+    kq = q.shape[1]
+    g, r = int(kv_heads), int(heads) // int(kv_heads)
+    dk, dv = kd // g, v.shape[-1] // g
+    tile, tiles = CHUNK_TILE, kq // CHUNK_TILE
+    width, first = _key_windows(g, dk)
+    pos = pos.astype(jnp.int32).reshape(b, tiles, 1, tile)
+    with _scope(0):
+        high = jnp.max(jnp.where(pos < c, pos, -1), axis=-1).reshape(-1)
+        low = jnp.min(pos, axis=-1).reshape(-1)
+        # [B, groups, tiles, Dk, r, tile]: query row ``ri * tile + lane``
+        queries = jnp.transpose(q.reshape(b, tiles, tile, g, r, dk),
+                                (0, 3, 1, 5, 4, 2))
+        if width != dk:
+            # a group's Dk rows where its columns lie in its window
+            queries = jnp.stack([jnp.pad(queries[:, gi], (
+                (0, 0), (0, 0), (gi * dk - at, at + width - (gi + 1) * dk),
+                (0, 0), (0, 0))) for gi, at in enumerate(first)], axis=1)
+        if sink is not None:
+            sink = jnp.repeat(sink.astype(_F32).reshape(g, 1, r), tile, 2)
+        out = _chunk_impl(
+            high, low, jnp.asarray(np.asarray(first, np.int32)),
+            jnp.tile(pos, (1, 1, 1, r)),
+            queries.reshape(b, g, tiles, width, r * tile), sink, k, v, r=r,
+            block=chunk_block(c), scale=1.0 / math.sqrt(dk),
+            vmem=_VMEM_BUDGET, interpret=_INTERPRET)
+        out = jnp.transpose(out, (0, 3, 1, 2, 4))
+    return out.reshape(b, kq, g * r * dv)

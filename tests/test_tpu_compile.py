@@ -269,7 +269,11 @@ def test_grouped_window_and_ring_attention_fit_at_published_widths(chip):
     stored (no copy of a cache, no repeat to the query heads), a chunk's 512
     lanes walk a full layer's cache in blocks under a dynamic trip count (no
     [lanes, heads, context] scores: 2.1 GB) and read a ring beside their own
-    rows in blocks of the ring's size; all of it plain XLA."""
+    rows in blocks of the ring's size. These are the ``jnp`` forms, plain
+    XLA: what the window layers' ops run on the chip, and what a full
+    layer's ops run on the CPU and under a mesh; on one TPU a full layer's
+    step is the kernel ``cache_step.fwd`` and its chunk ``cache_chunk.fwd``,
+    whose reference these are (both compiled below)."""
     from paddle_tpu.ops import cache_attention as ca
 
     b, c, heads, ring = 16, 16384, 64, 128
@@ -356,6 +360,50 @@ def test_cache_step_compiles_within_the_vmem_the_gate_counts(
     assert compiled.memory_analysis().temp_size_in_bytes < 0.02e9
     assert not re.search(r"= bf16\[%d,%d,\d+\]\S* (copy|transpose)\(" % (b, c),
                          compiled.as_text())
+
+
+@pytest.mark.parametrize("with_sink", [False, True], ids=["plain", "sink"])
+@pytest.mark.parametrize("lanes", [1024, 512])
+def test_cache_chunk_compiles_within_the_vmem_the_gate_counts(
+        chip, lanes, with_sink, monkeypatch):
+    """The chunk kernel behind ``cached_attention_chunk`` at a full layer's
+    shape of ``mimo2flash.serve.mixedlen.sat`` (one row of 1024 or 512 lanes
+    of 64 heads of 192 over 16384 positions of 4 key heads and 4 value heads
+    of 128), ``vmem_limit_bytes`` set to the working set its gate counts:
+    the gate admits the shape, Mosaic needs no more than is counted, the
+    kernel is there by name under ``attn.full``, no block's float32 scores
+    exist outside it (``[.., lanes, 512]``: 134 MB at 1024 lanes), and no
+    cache is copied or transposed on its way in (the kernel takes each
+    group's columns from the caches where and as they are stored)."""
+    from paddle_tpu.ops import cache_attention as ca
+
+    c, heads, g, dk, dv = 16384, 64, 4, 192, 128
+    q, k, v = (sds((1, lanes, heads * dk), BF16), sds((1, c, g * dk), BF16),
+               sds((1, c, g * dv), BF16))
+    with placed("tpu"):
+        plan = ca.chunk_plan_for(q, k, v, heads, g)
+    assert plan.kernel == "cache_chunk", plan
+    width, first = ca._key_windows(g, dk)
+    block = ca.chunk_block(c)
+    counted = ca._working_set(1, block, heads // g * ca.CHUNK_TILE, width,
+                              dv, 2)
+    assert (block, width, first) == (512, 256, [0, 128, 384, 512])
+    assert counted <= ca._VMEM_BUDGET
+    monkeypatch.setattr(ca, "_VMEM_BUDGET", counted)
+
+    def chunk(q, k, v, pos, sink):
+        return ca.chunk_blocks(q, k, v, pos, heads, g, sink)
+
+    compiled = _compile(chip, chunk, q, k, v, sds((1, lanes), I32),
+                        sds((heads,), BF16) if with_sink else None)
+    _assert_named(compiled, {"cache_chunk.fwd"})
+    (_, path), = _kernel_names(compiled)
+    assert "attn.full" in path.split("/")
+    text = compiled.as_text()
+    assert not re.search(r"f32\[[\d,]*%d,%d\]" % (lanes, block), text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+    assert not re.search(r"= bf16\[1,%d,\d+\]\S* (copy|transpose)\(" % c,
+                         text)
 
 
 def test_latent_step_compiles_within_the_vmem_the_gate_counts(
@@ -1103,6 +1151,42 @@ def _experts_grad():
         sds((4, 256, 128), BF16), sds((4, 128, 256), BF16))
 
 
+def _mimo_chunk():
+    """``mimo-v2-flash``'s chunk program of 1024 lanes over one slot row at
+    the rung of 16384, of its two full layers alone (layer 0 with the dense
+    feed-forward, layer 11 with its experts): the configuration's own
+    widths, so the gate admits the kernel as it does in the cell."""
+    import json
+
+    import paddle_tpu as fluid
+    from paddle_tpu.core.executor import build_step_fn
+    from paddle_tpu.models import mimo_v2
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mimo-v2-flash.json")) as f:
+        body = json.load(f)
+    sizes = dict({k: body[k] for k in body["builder_keys"]},
+                 layers_held=[0, 11])
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        fetch, spec = mimo_v2.mimo_v2_chunk(dtype="bfloat16", **sizes)
+    gb = main.global_block()
+    persist = sorted({v.name for v in main.list_vars() if v.persistable})
+    state = {n: sds(tuple(gb.var(n).shape),
+                    BF16 if gb.var(n).dtype == "bfloat16"
+                    else np.dtype(gb.var(n).dtype)) for n in persist}
+    lanes, c = 1024, 16384
+    feed = {spec["token_feed"]: sds((1, lanes), I32),
+            spec["pos_feed"]: sds((1, lanes), I32)}
+    for cf in spec["cache_feeds"]:
+        feed[cf["feed"]] = sds((1, cf.get("capacity") or c)
+                               + tuple(cf["tail"]), BF16)
+    rng = jax.eval_shape(lambda: jax.random.key(0, impl="rbg"))
+    return build_step_fn(main, [v.name for v in fetch], persist,
+                         infer_only=True), (state, feed, rng)
+
+
 _NAME_CASES = [
     # family, (function, abstract arguments), the names its calls carry;
     # small shapes: a name does not depend on the size
@@ -1114,6 +1198,7 @@ _NAME_CASES = [
      {"head_split_stream.fwd", "head_split_stream.bwd"}),
     ("fused_conv_infer", _conv_infer, {"fused_conv.infer"}),
     ("grouped_experts", lambda: _experts_grad(), _EXPERT_KERNELS),
+    ("mimo_v2_chunk", _mimo_chunk, {"cache_chunk.fwd"}),
 ]
 
 
